@@ -118,9 +118,10 @@ help:
 	@echo "                      attribution, /debug/loadz + /metrics"
 	@echo "  make load-soak      minutes-scale open-loop soak (slow-marked):"
 	@echo "                      steady-state span found, zero demotions"
-	@echo "  make trend          per-case bench trend table over the committed"
-	@echo "                      BENCH_r*.json trajectory with per-stage"
-	@echo "                      regression attribution (tools/benchtrend.py)"
+	@echo "  make trend          per-case bench trend table over saved"
+	@echo "                      BENCH_OUT runs (none is committed) with"
+	@echo "                      per-stage regression attribution"
+	@echo "                      (tools/benchtrend.py)"
 	@echo "  make trace          run the pipelined drain with the flight"
 	@echo "                      recorder armed, write PIPELINE_TRACE.json +"
 	@echo "                      .perfetto.json, print the text flame summary"

@@ -9,7 +9,10 @@ populates an in-process store the way hollow kubelets register themselves
 apiserver to dial.
 
 Exit codes: 0 clean shutdown; 1 lease lost (server.go:217 — losing the
-lease is fatal so a standby takes over); 2 bad flags/config.
+lease is fatal so a standby takes over); 2 bad flags/config; 3 ``--once``
+only: a cycle was recovered (the self-healing path ran — see
+``Scheduler.recovery_log``) or the drain timed out with pods still
+retryable, so the summary line does not describe a clean drain.
 """
 
 from __future__ import annotations
@@ -166,12 +169,19 @@ def main(argv: Optional[List[str]] = None) -> int:
                         break
                 sched.wait_for_inflight_binds()
                 bound = sum(1 for o in outcomes if o.node and not o.err)
+                # pods the drain gave up on while they could still retry
+                active = (len(sched.queue.active_q)
+                          + len(sched.queue.backoff_q))
                 print(json.dumps({
                     "scheduled": bound,
                     "attempts": len(outcomes),
                     "unschedulable": len(sched.queue.unschedulable_q),
+                    "active": active,
+                    "recoveries": sched.recoveries_total,
                     "seconds": round(time.time() - t0, 3),
                 }), flush=True)
+                if active or sched.recoveries_total:
+                    exit_code[0] = 3
             else:
                 sched.run()
                 stop.wait()

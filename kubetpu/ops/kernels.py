@@ -79,7 +79,8 @@ def exact_pmin(x, axis_name):
     return jax.lax.pmin(x, axis_name)
 
 
-def gumbel_tiebreak_argmax(total, f, gumbel, col_offset, neg):
+def gumbel_tiebreak_argmax(total, f, gumbel, col_offset, neg,
+                           keepdims: bool = False):
     """Per-tile propose half of the selectHost decomposition.
 
     Masks infeasible columns to ``neg``, takes the tile max, then breaks
@@ -88,13 +89,18 @@ def gumbel_tiebreak_argmax(total, f, gumbel, col_offset, neg):
     reservoir draw).  Returns (tile_best, tile_h, tile_arg) with
     tile_arg offset into global column space by ``col_offset``;
     jnp.argmax keeps the lowest index on exact gumbel ties, which is the
-    first-index contract the cross-axis fold preserves."""
+    first-index contract the cross-axis fold preserves.  ``keepdims``
+    returns [B, 1] columns (the Pallas kernel's layout: Mosaic has no
+    1-D vectors) instead of [B]."""
     masked = jnp.where(f, total, neg)
-    tile_best = jnp.max(masked, axis=1)
-    h = jnp.where((masked == tile_best[:, None]) & f, gumbel, neg)
-    tile_h = jnp.max(h, axis=1)
-    tile_arg = jnp.argmax(h, axis=1).astype(jnp.int32) + col_offset
-    return tile_best, tile_h, tile_arg
+    tile_best = jnp.max(masked, axis=1, keepdims=True)
+    h = jnp.where((masked == tile_best) & f, gumbel, neg)
+    tile_h = jnp.max(h, axis=1, keepdims=True)
+    tile_arg = (jnp.argmax(h, axis=1, keepdims=True).astype(jnp.int32)
+                + col_offset)
+    if keepdims:
+        return tile_best, tile_h, tile_arg
+    return tile_best[:, 0], tile_h[:, 0], tile_arg[:, 0]
 
 
 def crossaxis_first_index_argmax(tile_best, tile_h, tile_arg, axis_name,

@@ -72,14 +72,8 @@ from . import kernels as K
 from ..state.tensors import CH_CPU, CH_MEM, CH_PODS, N_FIXED_CHANNELS
 from ..utils.intern import pow2_bucket
 
-try:  # capability probe: pallas is absent on some jaxlib builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover - environment-dependent
-    pl = None
-    pltpu = None
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = float(-2**62)
 _BIG = float(2**62)
@@ -181,6 +175,31 @@ def gather_bundle(bundle: Dict[str, jnp.ndarray], rows: jnp.ndarray,
     return out
 
 
+def kernel_layout(bundle: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
+    """Re-lay the round-invariant bundle the way Mosaic tiles it, once
+    per auction: the node axis is the LANE axis everywhere, so it is
+    zero-padded to a whole number of 128-wide tiles (the kernel masks
+    columns >= N itself) and the node-side tables are transposed to
+    [channel, node] rows — a channel then reads as one sublane row that
+    broadcasts down the pod axis, never a lane->sublane relayout."""
+    N = bundle["alloc"].shape[0]
+    pad = -N % _LANE
+
+    def padn(x, axis):
+        if not pad:
+            return x
+        widths = [(0, 0)] * x.ndim
+        widths[axis] = (0, pad)
+        return jnp.pad(x, widths)
+
+    out = dict(bundle)
+    out["planes"] = padn(bundle["planes"], 2)
+    out["mask"] = padn(bundle["mask"], 1)
+    out["alloc"] = padn(bundle["alloc"].T, 1)     # [R, Npad]
+    out["zone"] = padn(bundle["zone"].T, 1)       # [Z, Npad]
+    return out
+
+
 class _Layout(NamedTuple):
     """Static kernel layout, derived once per trace."""
     scores: Tuple[Tuple[str, float], ...]
@@ -199,6 +218,11 @@ class _Layout(NamedTuple):
     NT: int
 
 
+# int8/bool blocks tile (32, 128): the smallest pod block every operand
+# dtype accepts
+_MIN_TB = 32
+
+
 def _layout(cfg, has_bias: bool, W: int, N: int, R: int, P: int,
             Z: int) -> _Layout:
     filters = set(cfg.filters)
@@ -214,16 +238,15 @@ def _layout(cfg, has_bias: bool, W: int, N: int, R: int, P: int,
             cols += ["max_dps", "havez"]
     cols += ["act", "best", "hh"]
     stat_cols = tuple((c, i) for i, c in enumerate(dict.fromkeys(cols)))
-    TB = min(_LANE, pow2_bucket(max(W, 1), 1))
-    TN = min(_LANE, pow2_bucket(max(N, 1), 1))
+    TB = min(_LANE, max(_MIN_TB, pow2_bucket(max(W, 1), 1)))
     return _Layout(
         scores=tuple((n, float(w)) for n, w in cfg.scores),
         planes=plane_order(cfg, has_bias),
         use_fit="NodeResourcesFit" in filters,
         use_ports="NodePorts" in filters,
         stat_cols=stat_cols, n_stats=len(stat_cols),
-        W=W, N=N, R=R, P=P, Z=Z, TB=TB, TN=TN,
-        NT=-(-N // TN))
+        W=W, N=N, R=R, P=P, Z=Z, TB=TB, TN=_LANE,
+        NT=-(-N // _LANE))
 
 
 class Buf(NamedTuple):
@@ -238,34 +261,39 @@ class Buf(NamedTuple):
     index: Tuple[str, ...] = ()
 
 
+# columns of the per-pod int32 flag block
+_FLAG_LIVE, _FLAG_SKIP, _FLAG_IPA_ANY = range(3)
+
+
 def kernel_buffers(L: _Layout, WB: int) -> Tuple[Buf, ...]:
     """The kernel's full buffer table, in pallas_call operand order.
     Single source of truth: propose() builds its BlockSpecs/out_shape/
     scratch_shapes from this, and tools/kubeexact computes the static
     VMEM budget from the same rows — the gate can never drift from the
-    traced program."""
+    traced program.  Every buffer is 2-D or 3-D with the node axis (or a
+    full-width channel axis) minor: per-pod vectors are [TB, 1] columns
+    and node tables are [channel, TN] rows, the two shapes Mosaic
+    broadcasts without a relayout."""
     Wpad = WB * L.TB
     return (
         Buf("planes", "in", (len(L.planes), L.TB, L.TN), "float32",
             ("z", "b", "n")),
         Buf("mask", "in", (L.TB, L.TN), "bool", ("b", "n")),
-        Buf("alloc", "in", (L.TN, L.R), "float32", ("n", "z")),
-        Buf("zone", "in", (L.TN, L.Z), "float32", ("n", "z")),
-        Buf("req", "in", (L.TN, L.R), "float32", ("n", "z")),
-        Buf("nz", "in", (L.TN, 2), "float32", ("n", "z")),
-        Buf("ports_used", "in", (L.TN, L.P), "float32", ("n", "z")),
+        Buf("alloc", "in", (L.R, L.TN), "float32", ("z", "n")),
+        Buf("zone", "in", (L.Z, L.TN), "float32", ("z", "n")),
+        Buf("req", "in", (L.R, L.TN), "float32", ("z", "n")),
+        Buf("nz", "in", (2, L.TN), "float32", ("z", "n")),
+        Buf("ports_used", "in", (L.P, L.TN), "float32", ("z", "n")),
         Buf("breq", "in", (L.TB, L.R), "float32", ("b", "z")),
         Buf("bnz", "in", (L.TB, 2), "float32", ("b", "z")),
         Buf("bports", "in", (L.TB, L.P), "float32", ("b", "z")),
-        Buf("live", "in", (L.TB,), "bool", ("b",)),
-        Buf("skip", "in", (L.TB,), "bool", ("b",)),
-        Buf("ipa_any", "in", (L.TB,), "bool", ("b",)),
-        Buf("prop", "out", (L.TB,), "int32", ("b",)),
-        Buf("best", "out", (L.TB,), "float32", ("b",)),
-        Buf("act", "out", (L.TB,), "bool", ("b",)),
+        Buf("flags", "in", (L.TB, 3), "int32", ("b", "z")),
+        Buf("prop", "out", (L.TB, 1), "int32", ("b", "z")),
+        Buf("best", "out", (L.TB, 1), "float32", ("b", "z")),
+        Buf("act", "out", (L.TB, 1), "int32", ("b", "z")),
         Buf("stats", "scratch", (Wpad, L.n_stats), "float32"),
         Buf("czone", "scratch", (Wpad, L.Z), "float32"),
-        Buf("idxs", "scratch", (Wpad,), "int32"),
+        Buf("idxs", "scratch", (Wpad, 1), "int32"),
     )
 
 
@@ -278,40 +306,48 @@ def _make_kernel(L: _Layout):
     plane = {name: i for i, name in enumerate(L.planes)}
 
     def kernel(planes_ref, mask_ref, alloc_ref, zone_ref, req_ref, nz_ref,
-               pu_ref, breq_ref, bnz_ref, bports_ref, live_ref, skip_ref,
-               ipaany_ref, prop_ref, best_ref, act_ref, stats, czone, idxs):
+               pu_ref, breq_ref, bnz_ref, bports_ref, flags_ref,
+               prop_ref, best_ref, act_ref, stats, czone, idxs):
         p = pl.program_id(0)
         b = pl.program_id(1)
         n = pl.program_id(2)
-        sl = pl.ds(b * L.TB, L.TB)
+        sl = pl.ds(pl.multiple_of(b * L.TB, L.TB), L.TB)
         col_ok = (n * L.TN + jax.lax.broadcasted_iota(
             jnp.int32, (L.TB, L.TN), 1)) < L.N
+        flags = flags_ref[...]
+
+        def flag(i):                       # [TB, 1] bool
+            return flags[:, i:i + 1] != 0
+
+        def stat(name):                    # [TB, 1] f32
+            return stats[sl, col[name]:col[name] + 1]
 
         def feas_tile():
-            f = mask_ref[...] & live_ref[...][:, None] & col_ok
+            f = mask_ref[...] & flag(_FLAG_LIVE) & col_ok
             breq = breq_ref[...]
             if L.use_fit:
                 alloc = alloc_ref[...]
                 used = req_ref[...]
-                pods_ok = (alloc[:, CH_PODS][None, :]
-                           >= breq[:, CH_PODS][:, None]
-                           + used[:, CH_PODS][None, :])
+
+                def free_ok(r):            # [1, TN] >= [TB, 1] + [1, TN]
+                    return (alloc[r:r + 1, :]
+                            >= breq[:, r:r + 1] + used[r:r + 1, :])
+
+                pods_ok = free_ok(CH_PODS)
                 res_ok = jnp.ones((L.TB, L.TN), bool)
-                zero_req = jnp.ones((L.TB,), bool)
+                zero_req = jnp.ones((L.TB, 1), bool)
                 for r in range(L.R):
                     if r == CH_PODS:
                         continue
-                    free_ok = (alloc[:, r][None, :]
-                               >= breq[:, r][:, None] + used[:, r][None, :])
                     if r < N_FIXED_CHANNELS:
-                        res_ok = res_ok & free_ok
+                        res_ok = res_ok & free_ok(r)
                     else:
-                        res_ok = res_ok & (free_ok
-                                           | (breq[:, r] <= 0)[:, None])
-                    zero_req = zero_req & (breq[:, r] == 0)
-                f = f & pods_ok & (zero_req[:, None] | res_ok)
+                        res_ok = res_ok & (free_ok(r)
+                                           | (breq[:, r:r + 1] <= 0))
+                    zero_req = zero_req & (breq[:, r:r + 1] == 0)
+                f = f & pods_ok & (zero_req | res_ok)
             if L.use_ports:
-                conflict = jnp.dot(bports_ref[...], pu_ref[...].T,
+                conflict = jnp.dot(bports_ref[...], pu_ref[...],
                                    preferred_element_type=jnp.float32) > 0.5
                 f = f & ~conflict
             return f
@@ -320,19 +356,21 @@ def _make_kernel(L: _Layout):
             bnz = bnz_ref[...]
             nzc = nz_ref[...]
             alloc = alloc_ref[...]
-            req_cpu = nzc[:, 0][None, :] + bnz[:, 0][:, None]
-            req_mem = nzc[:, 1][None, :] + bnz[:, 1][:, None]
-            alloc_cpu = jnp.broadcast_to(alloc[:, CH_CPU][None, :],
+            req_cpu = nzc[0:1, :] + bnz[:, 0:1]
+            req_mem = nzc[1:2, :] + bnz[:, 1:2]
+            alloc_cpu = jnp.broadcast_to(alloc[CH_CPU:CH_CPU + 1, :],
                                          (L.TB, L.TN))
-            alloc_mem = jnp.broadcast_to(alloc[:, CH_MEM][None, :],
+            alloc_mem = jnp.broadcast_to(alloc[CH_MEM:CH_MEM + 1, :],
                                          (L.TB, L.TN))
             return req_cpu, req_mem, alloc_cpu, alloc_mem
 
-        def zone_tile():
-            ztile = zone_ref[...]
+        def zone_tile():                   # [Z, TN], padded columns zeroed
             cok = (n * L.TN + jax.lax.broadcasted_iota(
-                jnp.int32, (L.TN, 1), 0).reshape(L.TN)) < L.N
-            return jnp.where(cok[:, None], ztile, 0.0)
+                jnp.int32, (L.Z, L.TN), 1)) < L.N
+            return jnp.where(cok, zone_ref[...], 0.0)
+
+        def rowmax(x):
+            return jnp.max(x, axis=1, keepdims=True)
 
         # ---- phase 0: per-pod normalization statistics -----------------
         @pl.when(p == 0)
@@ -344,42 +382,39 @@ def _make_kernel(L: _Layout):
 
                 @pl.when(n == 0)
                 def _():
-                    stats[sl, c] = tile_val
+                    stats[sl, c:c + 1] = tile_val
 
                 @pl.when(n > 0)
                 def _():
-                    stats[sl, c] = comb(stats[sl, c], tile_val)
+                    stats[sl, c:c + 1] = comb(stats[sl, c:c + 1], tile_val)
 
             # bool -> f32 cast, not where(f, 1.0, 0.0): two python-float
             # branches commit the default float dtype, which is f64
             # wherever x64 is enabled (census/f64-promotion)
-            acc("act", jnp.max(f.astype(jnp.float32), axis=1),
-                jnp.maximum)
+            acc("act", rowmax(f.astype(jnp.float32)), jnp.maximum)
             if "max_na" in col:
                 raw = planes_ref[plane["raw:NodeAffinity"]]
-                acc("max_na", jnp.max(jnp.where(f, raw, _NEG), axis=1),
-                    jnp.maximum)
+                acc("max_na", rowmax(jnp.where(f, raw, _NEG)), jnp.maximum)
             if "max_tt" in col:
                 raw = planes_ref[plane["raw:TaintToleration"]]
-                acc("max_tt", jnp.max(jnp.where(f, raw, _NEG), axis=1),
-                    jnp.maximum)
+                acc("max_tt", rowmax(jnp.where(f, raw, _NEG)), jnp.maximum)
             if "max_ip" in col:
                 raw = planes_ref[plane["ipa_raw"]]
-                acc("max_ip", jnp.max(jnp.where(f, raw, _NEG), axis=1),
-                    jnp.maximum)
-                acc("min_ip", jnp.min(jnp.where(f, raw, _BIG), axis=1),
+                acc("max_ip", rowmax(jnp.where(f, raw, _NEG)), jnp.maximum)
+                acc("min_ip",
+                    jnp.min(jnp.where(f, raw, _BIG), axis=1, keepdims=True),
                     jnp.minimum)
             if "max_dps" in col:
                 raw = planes_ref[plane["dps_raw"]]
                 zt = zone_tile()
-                acc("max_dps", jnp.max(jnp.where(f, raw, _NEG), axis=1),
+                acc("max_dps", rowmax(jnp.where(f, raw, _NEG)), jnp.maximum)
+                has_zone = jnp.max(zt, axis=0, keepdims=True) > 0  # [1, TN]
+                acc("havez", rowmax((f & has_zone).astype(jnp.float32)),
                     jnp.maximum)
-                has_zone = jnp.any(zt > 0, axis=1)
-                acc("havez",
-                    jnp.max((f & has_zone[None, :]).astype(jnp.float32),
-                            axis=1), jnp.maximum)
-                cz = jnp.dot(jnp.where(f, raw, 0.0), zt,
-                             preferred_element_type=jnp.float32)
+                # [TB, TN] x [Z, TN]^T: per-zone sums of this tile
+                cz = jax.lax.dot_general(
+                    jnp.where(f, raw, 0.0), zt, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
                 @pl.when(n == 0)
                 def _():
@@ -411,28 +446,26 @@ def _make_kernel(L: _Layout):
                     s = planes_ref[plane["raw:NodePreferAvoidPods"]]
                 elif name == "NodeAffinity":
                     raw = planes_ref[plane["raw:NodeAffinity"]]
-                    max_c = jnp.maximum(stats[sl, col["max_na"]], 0.0)
+                    max_c = jnp.maximum(stat("max_na"), 0.0)
                     scaled = K._idiv(MAX_NODE_SCORE * raw,
-                                     jnp.maximum(max_c, 1.0)[:, None])
-                    s = jnp.where((max_c > 0)[:, None], scaled, 0.0)
+                                     jnp.maximum(max_c, 1.0))
+                    s = jnp.where(max_c > 0, scaled, 0.0)
                 elif name == "TaintToleration":
                     raw = planes_ref[plane["raw:TaintToleration"]]
-                    max_c = jnp.maximum(stats[sl, col["max_tt"]], 0.0)
+                    max_c = jnp.maximum(stat("max_tt"), 0.0)
                     scaled = MAX_NODE_SCORE - K._idiv(
-                        MAX_NODE_SCORE * raw,
-                        jnp.maximum(max_c, 1.0)[:, None])
-                    s = jnp.where((max_c > 0)[:, None], scaled,
-                                  MAX_NODE_SCORE)
+                        MAX_NODE_SCORE * raw, jnp.maximum(max_c, 1.0))
+                    s = jnp.where(max_c > 0, scaled, MAX_NODE_SCORE)
                 elif name == "InterPodAffinity":
                     raw = planes_ref[plane["ipa_raw"]]
-                    max_c = jnp.maximum(stats[sl, col["max_ip"]], 0.0)
-                    min_c = jnp.minimum(stats[sl, col["min_ip"]], 0.0)
+                    max_c = jnp.maximum(stat("max_ip"), 0.0)
+                    min_c = jnp.minimum(stat("min_ip"), 0.0)
                     diff = max_c - min_c
                     norm = jnp.where(
-                        (diff > 0)[:, None],
-                        K._idiv(MAX_NODE_SCORE * (raw - min_c[:, None]),
-                                jnp.maximum(diff, 1.0)[:, None]), 0.0)
-                    s = jnp.where(ipaany_ref[...][:, None], norm, raw)
+                        diff > 0,
+                        K._idiv(MAX_NODE_SCORE * (raw - min_c),
+                                jnp.maximum(diff, 1.0)), 0.0)
+                    s = jnp.where(flag(_FLAG_IPA_ANY), norm, raw)
                 elif name == "PodTopologySpread":
                     # no-soft-constraints constant path (scoring.go
                     # maxScore==0): MaxNodeScore on every feasible node
@@ -440,29 +473,28 @@ def _make_kernel(L: _Layout):
                 elif name == "DefaultPodTopologySpread":
                     raw = planes_ref[plane["dps_raw"]]
                     zt = zone_tile()
-                    max_node = jnp.maximum(stats[sl, col["max_dps"]], 0.0)
+                    max_node = jnp.maximum(stat("max_dps"), 0.0)
                     f_score = jnp.where(
-                        (max_node > 0)[:, None],
-                        MAX_NODE_SCORE * (max_node[:, None] - raw)
-                        / jnp.maximum(max_node, 1.0)[:, None],
+                        max_node > 0,
+                        MAX_NODE_SCORE * (max_node - raw)
+                        / jnp.maximum(max_node, 1.0),
                         MAX_NODE_SCORE)
                     cz = czone[sl, :]
-                    max_zone = jnp.maximum(jnp.max(cz, axis=1), 0.0)
-                    nzc = jnp.dot(cz, zt.T,
+                    max_zone = jnp.maximum(rowmax(cz), 0.0)
+                    nzc = jnp.dot(cz, zt,
                                   preferred_element_type=jnp.float32)
                     zone_score = jnp.where(
-                        (max_zone > 0)[:, None],
-                        MAX_NODE_SCORE * (max_zone[:, None] - nzc)
-                        / jnp.maximum(max_zone, 1.0)[:, None],
+                        max_zone > 0,
+                        MAX_NODE_SCORE * (max_zone - nzc)
+                        / jnp.maximum(max_zone, 1.0),
                         MAX_NODE_SCORE)
                     with_zone = (f_score * (1.0 - K.ZONE_WEIGHTING)
                                  + K.ZONE_WEIGHTING * zone_score)
-                    havez = stats[sl, col["havez"]] > 0
-                    has_zone = jnp.any(zt > 0, axis=1)
-                    out = jnp.where(havez[:, None] & has_zone[None, :],
-                                    with_zone, f_score)
+                    havez = stat("havez") > 0
+                    has_zone = jnp.max(zt, axis=0, keepdims=True) > 0
+                    out = jnp.where(havez & has_zone, with_zone, f_score)
                     out = jnp.floor(out)
-                    s = jnp.where(skip_ref[...][:, None], 0.0, out)
+                    s = jnp.where(flag(_FLAG_SKIP), 0.0, out)
                 else:  # pragma: no cover - unsupported_reason() gates this
                     raise ValueError("pallas backend: unsupported score "
                                      "kernel %s" % name)
@@ -473,37 +505,38 @@ def _make_kernel(L: _Layout):
             # blessed gumbel decomposition (ops/kernels.py): same tuple
             # the shard_map tiled surface folds across the node axis
             tile_best, tile_h, tile_arg = K.gumbel_tiebreak_argmax(
-                total, f, gum, n * L.TN, _NEG)
+                total, f, gum, n * L.TN, _NEG, keepdims=True)
 
             @pl.when(n == 0)
             def _():
-                stats[sl, col["best"]] = tile_best
-                stats[sl, col["hh"]] = tile_h
-                idxs[sl] = tile_arg
+                stats[sl, col["best"]:col["best"] + 1] = tile_best
+                stats[sl, col["hh"]:col["hh"] + 1] = tile_h
+                idxs[sl, :] = tile_arg
 
             @pl.when(n > 0)
             def _():
-                rb = stats[sl, col["best"]]
-                rh = stats[sl, col["hh"]]
-                ri = idxs[sl]
+                rb = stat("best")
+                rh = stat("hh")
+                ri = idxs[sl, :]
                 # first-index tie-break: update only on STRICT improvement
                 # (earlier tiles, and jnp.argmax within a tile, keep the
                 # lowest index on exact equality — matching the oracle)
                 upd = tile_best > rb
                 updh = (tile_best == rb) & (tile_h > rh)
-                stats[sl, col["best"]] = jnp.where(upd, tile_best, rb)
-                stats[sl, col["hh"]] = jnp.where(
+                stats[sl, col["best"]:col["best"] + 1] = jnp.where(
+                    upd, tile_best, rb)
+                stats[sl, col["hh"]:col["hh"] + 1] = jnp.where(
                     upd, tile_h, jnp.where(updh, tile_h, rh))
-                idxs[sl] = jnp.where(upd, tile_arg,
-                                     jnp.where(updh, tile_arg, ri))
+                idxs[sl, :] = jnp.where(upd, tile_arg,
+                                        jnp.where(updh, tile_arg, ri))
 
             @pl.when(n == L.NT - 1)
             def _():
-                act = stats[sl, col["act"]] > 0
-                best_ref[...] = stats[sl, col["best"]]
-                prop_ref[...] = jnp.where(act, idxs[sl], L.N).astype(
+                act = stat("act") > 0
+                best_ref[...] = stat("best")
+                prop_ref[...] = jnp.where(act, idxs[sl, :], L.N).astype(
                     jnp.int32)
-                act_ref[...] = act
+                act_ref[...] = act.astype(jnp.int32)
 
     return kernel
 
@@ -513,29 +546,33 @@ def propose(bundle: Dict[str, jnp.ndarray], cfg, live: jnp.ndarray,
             n_nodes: int, interpret: bool):
     """One fused propose step -> (prop [W] i32 in [0, N] with N = no-op,
     active [W] bool, best [W] f32) — bit-identical to the lax round's
-    propose half for supported configurations."""
+    propose half for supported configurations.  ``bundle`` is in
+    kernel_layout() form; the per-round carries (req, nz, ports_used)
+    arrive as the auction keeps them, [N, channel], and are re-laid
+    here — they are the only node-side tensors that change per round."""
     W = int(live.shape[0])
     N = int(n_nodes)
-    R = int(bundle["alloc"].shape[1])
+    R = int(bundle["alloc"].shape[0])
     P = int(bundle["bports"].shape[1])
-    Z = int(bundle["zone"].shape[1])
+    Z = int(bundle["zone"].shape[0])
     has_bias = bundle["planes"].shape[0] == len(plane_order(cfg, True))
     L = _layout(cfg, has_bias, W, N, R, P, Z)
     WB = -(-W // L.TB)
     Wpad = WB * L.TB
+    Npad = L.NT * L.TN
 
-    def padw(x, fill=0):
-        if Wpad == x.shape[0]:
+    def padw(x, axis=0):
+        if Wpad == x.shape[axis]:
             return x
-        pad = [(0, Wpad - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
-        return jnp.pad(x, pad, constant_values=fill)
+        widths = [(0, 0)] * x.ndim
+        widths[axis] = (0, Wpad - x.shape[axis])
+        return jnp.pad(x, widths)
 
-    def padw1(x, fill=0):  # planes: pad axis 1
-        if Wpad == x.shape[1]:
-            return x
-        return jnp.pad(x, [(0, 0), (0, Wpad - x.shape[1]), (0, 0)],
-                       constant_values=fill)
+    def node_rows(x):                      # [N, C] carry -> [C, Npad]
+        return jnp.pad(x.T, [(0, 0), (0, Npad - N)])
 
+    flags = jnp.stack([live, bundle["skip"], bundle["ipa_any"]],
+                      axis=1).astype(jnp.int32)
     kernel = _make_kernel(L)
     grid = (2, WB, L.NT)
     bufs = kernel_buffers(L, WB)
@@ -565,9 +602,10 @@ def propose(bundle: Dict[str, jnp.ndarray], cfg, live: jnp.ndarray,
             for bf in bufs if bf.kind == "scratch"],
         interpret=interpret,
     )(
-        padw1(bundle["planes"]), padw(bundle["mask"]),
-        bundle["alloc"], bundle["zone"], req, nz, ports_used,
+        padw(bundle["planes"], 1), padw(bundle["mask"]),
+        bundle["alloc"], bundle["zone"],
+        node_rows(req), node_rows(nz), node_rows(ports_used),
         padw(bundle["breq"]), padw(bundle["bnz"]), padw(bundle["bports"]),
-        padw(live), padw(bundle["skip"]), padw(bundle["ipa_any"]),
+        padw(flags),
     )
-    return prop[:W], act[:W], best[:W]
+    return prop[:W, 0], act[:W, 0] != 0, best[:W, 0]

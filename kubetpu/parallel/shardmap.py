@@ -77,7 +77,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -200,12 +199,12 @@ def _gang_replicated(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
             residual_window=residual_window, kernel_backend="lax", **dk)
 
     out_struct = jax.eval_shape(body, cluster, batch, rng, dyn)
-    return shard_map(
-        body, mesh,
+    return jax.shard_map(
+        body, mesh=mesh,
         in_specs=(_rep_spec(cluster), _rep_spec(batch), P(),
                   _rep_spec(dyn)),
         out_specs=_rep_spec(out_struct),
-        check_rep=False)(cluster, batch, rng, dyn)
+        check_vma=False)(cluster, batch, rng, dyn)
 
 
 def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
@@ -543,13 +542,13 @@ def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
 
     tile2 = P(AXIS_PODS, AXIS_NODES)
     (assigned, win_score, rounds, req, nz, ports_used, feas0, n_feas,
-     all_unres) = shard_map(
-        body, mesh,
+     all_unres) = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(P(None, AXIS_PODS, AXIS_NODES), tile2, tile2,
                   P(), P(), P(), P(), P(), P(), P(), P(), P(), P(),
                   P(), P()),
         out_specs=(P(), P(), P(), P(), P(), P(), tile2, P(), P()),
-        check_rep=False)(
+        check_vma=False)(
         bundle["planes"], bundle["mask"], static_unres,
         bundle["breq"], bundle["bnz"], bundle["bports"],
         batch.ports_asnode_hot, bundle["ipa_any"], bundle["skip"],
@@ -592,12 +591,12 @@ def _shardmap_sequential(cluster, batch, cfg, rng, mesh_key,
         return sequential._sequential_program(cl, b, cfg, r, **dk)
 
     out_struct = jax.eval_shape(body, cluster, batch, rng, dyn)
-    return shard_map(
-        body, mesh,
+    return jax.shard_map(
+        body, mesh=mesh,
         in_specs=(_rep_spec(cluster), _rep_spec(batch), P(),
                   _rep_spec(dyn)),
         out_specs=_rep_spec(out_struct),
-        check_rep=False)(cluster, batch, rng, dyn)
+        check_vma=False)(cluster, batch, rng, dyn)
 
 
 # --------------------------------------------------------------------------
@@ -643,9 +642,9 @@ def _apply_delta_body(cluster, delta, mesh_key):
         return programs._apply_cluster_delta(
             cl, d._replace(node_rows=nr, pod_rows=pr))
 
-    return shard_map(body, mesh,
-                     in_specs=(specs, _rep_spec(delta)),
-                     out_specs=specs, check_rep=False)(cluster, delta)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(specs, _rep_spec(delta)),
+                         out_specs=specs, check_vma=False)(cluster, delta)
 
 
 _shardmap_apply_delta_donated = jax.jit(

@@ -112,21 +112,15 @@ def device_signature() -> str:
     was compiled for)."""
     import jax
     devs = jax.devices()
-    kind = getattr(devs[0], "device_kind", devs[0].platform)
-    return "%s:%s x%d" % (devs[0].platform, kind, len(devs))
+    return "%s:%s x%d" % (devs[0].platform, devs[0].device_kind, len(devs))
 
 
 def env_signature() -> Dict[str, str]:
     """The environment an artifact set is valid for; any field drifting
     invalidates the whole index (serve arming refuses it)."""
     import jax
-    try:
-        import jaxlib
-        jl = getattr(getattr(jaxlib, "version", None), "__version__",
-                     jax.__version__)
-    except Exception:  # pragma: no cover - jaxlib always ships with jax
-        jl = jax.__version__
-    return {"jax": jax.__version__, "jaxlib": jl,
+    import jaxlib
+    return {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
             "backend": jax.default_backend(),
             "device_sig": device_signature(),
             "kernel_digest": kernel_digest()}
@@ -272,7 +266,7 @@ def _note_resident_executable(row: dict) -> None:
 class AotStore:
     """One artifact directory: ``<root>/<program>-<sha16>-<env8>.aotx``
     files plus ``<root>/index.json``.  Serialization format per artifact:
-    pickle of {"meta", "payload", "in_tree", "out_tree"}."""
+    pickle of {"meta", "payload", "in_tree", "out_tree", "device_ids"}."""
 
     def __init__(self, root: str):
         self.root = root
@@ -289,10 +283,11 @@ class AotStore:
                                   self._env_key(env))
 
     def save(self, name: str, meta: Dict[str, Any], payload: bytes,
-             in_tree, out_tree) -> int:
+             in_tree, out_tree, device_ids: List[int]) -> int:
         os.makedirs(self.root, exist_ok=True)
         blob = pickle.dumps({"meta": meta, "payload": payload,
-                             "in_tree": in_tree, "out_tree": out_tree})
+                             "in_tree": in_tree, "out_tree": out_tree,
+                             "device_ids": device_ids})
         path = os.path.join(self.root, name)
         tmp = path + ".tmp"
         with open(tmp, "wb") as f:
@@ -340,6 +335,19 @@ class AotStore:
                 return json.load(f)
         except (OSError, ValueError):
             return None
+
+
+def _load_executable(blob: Dict[str, Any]):
+    """deserialize-and-load one artifact onto exactly the devices it was
+    compiled for, in compile order — left to its default, the loader
+    takes EVERY device of the backend as the executable's device set, and
+    a one-device program then rejects its one-shard arguments."""
+    import jax
+    from jax.experimental import serialize_executable as se
+    by_id = {d.id: d for d in jax.devices()}
+    return se.deserialize_and_load(
+        blob["payload"], blob["in_tree"], blob["out_tree"],
+        execution_devices=[by_id[i] for i in blob["device_ids"]])
 
 
 # ---------------------------------------------------------------- runtime
@@ -493,8 +501,6 @@ class AotRuntime:
         pod_bucket, seconds, ok}; rows whose artifact is unreadable
         report ok=False and stay on the per-bucket fallback
         (persistent-cache/trace) path."""
-        from jax.experimental import serialize_executable as se
-
         from .trace import flight_span
         report: List[dict] = []
         for row in self.rows():
@@ -512,9 +518,7 @@ class AotRuntime:
             with flight_span("aot-load", program=row.get("program", "?"),
                              bucket=row.get("pod_bucket"), hit=True) as sp:
                 try:
-                    blob = self.store.load(name)
-                    fn = se.deserialize_and_load(
-                        blob["payload"], blob["in_tree"], blob["out_tree"])
+                    fn = _load_executable(self.store.load(name))
                 except Exception as e:
                     # a corrupt/unreadable artifact (truncated blob, torn
                     # deploy, chaos "aot-load") degrades THIS row to the
@@ -565,10 +569,7 @@ class AotRuntime:
         with flight_span("aot-load", program=program, hit=True,
                          bucket=bucket) as sp:
             try:
-                from jax.experimental import serialize_executable as se
-                blob = self.store.load(row["artifact"])
-                fn = se.deserialize_and_load(
-                    blob["payload"], blob["in_tree"], blob["out_tree"])
+                fn = _load_executable(self.store.load(row["artifact"]))
             except Exception:
                 LOG.warning("aot artifact %s unreadable; falling back",
                             row["artifact"], exc_info=True)
@@ -630,14 +631,18 @@ class AotRuntime:
             sha = _h.sha256(lowered.as_text().encode()).hexdigest()
             compiled = lowered.compile()
             payload, in_tree, out_tree = se.serialize(compiled)
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
             # build-time round trip: an executable that came back as a
             # PERSISTENT-CACHE HIT serializes to a blob referencing JIT
             # symbols it does not carry (CPU deserialize fails with
             # "Symbols not found"), and a blob that cannot load is a
             # build failure NOW, not a silent trace-path fallback at
-            # serve (tools/kubeaot captures under _fresh_compiles for
-            # this reason)
-            se.deserialize_and_load(payload, in_tree, out_tree)
+            # serve (tools/kubeaot captures under
+            # utils/compilation.cache_disabled for this reason)
+            _load_executable({"payload": payload, "in_tree": in_tree,
+                              "out_tree": out_tree,
+                              "device_ids": device_ids})
         except Exception:
             LOG.warning("aot capture failed for %s; serving keeps the "
                         "trace path for this variant", program,
@@ -655,7 +660,7 @@ class AotRuntime:
                "lowering_sha256": sha, "artifact": name,
                "pod_bucket": bucket}
         row["bytes"] = self.store.save(name, dict(row), payload, in_tree,
-                                       out_tree)
+                                       out_tree, device_ids)
         with self._lock:
             self._rows.append(row)
             self._rows_by_sig[key] = row

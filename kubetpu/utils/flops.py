@@ -7,7 +7,7 @@ registration), the existing-term contractions ([Et, W] x [Et, N]), and the
 per-node count matmul.  This module prices those per round, with the round
 width following the windowed-residual schedule (round 1 at B, residual
 rounds at the window width), so benchmarks can report achieved TFLOP/s and
-MFU against the chip's peak.
+MFU against the chip's peak (DEVICE_PEAKS below, keyed by device kind).
 
 The model counts the IN-ROUND matmul FLOPs only (2*m*n*k per contraction);
 the once-per-cycle precomputation (selector matches, static filters/scores)
@@ -20,14 +20,33 @@ podtopologyspread/scoring.go:108-169.
 
 from __future__ import annotations
 
-import os
+from typing import NamedTuple, Optional
 
 
-def peak_flops_per_s() -> float:
-    """Chip peak for the dtype the kernels contract in (bf16 inputs, f32
-    accumulate).  Default: TPU v5e, 197 TFLOP/s bf16.  Override with
-    KUBETPU_PEAK_TFLOPS for other parts."""
-    return float(os.environ.get("KUBETPU_PEAK_TFLOPS", "197")) * 1e12
+class DevicePeaks(NamedTuple):
+    flops_per_s: float       # matmul peak for bf16 inputs, f32 accumulate
+    hbm_bytes_per_s: float
+    hbm_bytes: float
+    source: str
+
+
+# Published per-chip peaks, keyed by the ``device_kind`` jax reports.  A
+# part that is not here has no row on purpose: see device_peaks.
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        197e12, 819e9, 16 * 2.0 ** 30,
+        'Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+        "16 GB HBM per chip at 819 GB/s"),
+}
+
+
+def device_peaks() -> Optional[DevicePeaks]:
+    """Peaks of the device the programs run on, or None when its
+    ``device_kind`` is not in DEVICE_PEAKS — the cpu backend included.
+    Callers then report no MFU, roofline or fits-in-HBM field at all: a
+    run divided by another part's peak is not a measurement."""
+    import jax
+    return DEVICE_PEAKS.get(jax.devices()[0].device_kind)
 
 
 def gang_cycle_flops(cluster, batch, cfg, rounds: int,

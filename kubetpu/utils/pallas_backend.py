@@ -4,10 +4,10 @@ ops/pallas_kernels.py is a kernel module and must stay pure (kubelint
 purity family); everything environment- or state-touching about the
 backend choice lives here instead:
 
-  * capability probe: is jax.experimental.pallas importable, and should
-    kernels run under ``interpret=True`` (any non-TPU backend, or the
-    KUBETPU_PALLAS_INTERPRET override — read ONCE at import so the
-    decision is process-stable and cannot silently flip between traces)?
+  * interpret switch: should kernels run under ``interpret=True`` (any
+    non-TPU backend, or the KUBETPU_PALLAS_INTERPRET override — read
+    ONCE at import so the decision is process-stable and cannot
+    silently flip between traces)?
   * support surface: ``unsupported_reason`` is the single authority on
     when ``kernel_backend="pallas"`` may engage; the gang dispatcher
     falls back to the lax path (and records why) on any non-None reason.
@@ -39,11 +39,6 @@ _fallbacks: Dict[str, int] = {}   # kubelint: guarded-by(_lock)
 # other profiles' — serves the lax oracle path instead of re-tripping
 # the same fault
 _demotion: Optional[str] = None   # kubelint: guarded-by(_lock)
-
-
-def available() -> bool:
-    from ..ops import pallas_kernels
-    return pallas_kernels.HAVE_PALLAS
 
 
 def interpret_mode() -> bool:
@@ -79,8 +74,6 @@ def unsupported_reason(cfg, intra_batch_topology: bool,
     demoted = demotion()
     if demoted is not None:
         return "demoted:%s" % demoted
-    if not available():
-        return "pallas-unavailable"
     if intra_batch_topology:
         return "intra-batch-topology"
     from ..ops import pallas_kernels

@@ -38,13 +38,16 @@ from typing import Dict, List, Optional, Set, Tuple
 
 ENV_FLAG = "KUBETPU_SANITIZE"
 
-# the logger jax routes compilation progress through (jax 0.4.x); records
-# look like "Compiling <name> with global shapes and types [ShapedArray(
-# f32[8,16])...]. Argument mapping: ..."
+# the logger jax routes compilation progress through; records look like
+# "Compiling jit(<name>) with global shapes and types (ShapedArray(
+# float32[8,16]), ...). Argument mapping: (...)." — one per jit-cache
+# miss, emitted at lowering (so a persistent-cache hit still counts: the
+# watchdog counts defeated IN-PROCESS jit caches, not XLA seconds)
 _PXLA_LOGGER = "jax._src.interpreters.pxla"
 _COMPILE_RE = re.compile(
-    r"Compiling (\S+) with global shapes and types (\[.*\])\.\s*"
+    r"Compiling (\S+) with global shapes and types (\(.*\))\.\s*"
     r"Argument mapping", re.DOTALL)
+_JIT_WRAPPER_RE = re.compile(r"jit\((.*)\)")
 _DONATION_RE = re.compile(r"[Dd]onated buffers? .*not usable|"
                           r"buffer donat\w+ .*mismatch")
 
@@ -79,14 +82,17 @@ class CompileWatchdog(logging.Handler):
             return
         m = _COMPILE_RE.search(msg)
         if m:
-            key = (m.group(1), m.group(2))
+            # module names are "jit(<program>)"; consumers (census
+            # matching, the per-program tests) key on the bare program
+            wrapped = _JIT_WRAPPER_RE.fullmatch(m.group(1))
+            key = (wrapped.group(1) if wrapped else m.group(1), m.group(2))
             with self._lock:
                 self.counts[key] = self.counts.get(key, 0) + 1
             # feed the flight recorder: a compile landing under a cycle's
             # open span (dispatch, audit, wave) is exactly the event the
             # recorder exists to attribute — no-op when disarmed
             from .trace import note_compile_event
-            note_compile_event(m.group(1), m.group(2))
+            note_compile_event(*key)
             return
         if _DONATION_RE.search(msg):
             with self._lock:

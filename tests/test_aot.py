@@ -29,9 +29,9 @@ pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 def captured(tmp_path_factory):
     """ONE cold capture of _schedule_gang at the manifest's smallest rung
     (n8_b8), shared by the round-trip tests — the registry builders
-    produce the exact serving input structures, and _fresh_compiles +
+    produce the exact serving input structures, and cache_disabled +
     clear_caches reproduce the census's cold-cache sha discipline."""
-    from tools.kubeaot.build import _fresh_compiles
+    from kubetpu.utils.compilation import cache_disabled
     from tools.kubecensus.registry import ENTRIES, build_world
 
     e = next(en for en in ENTRIES
@@ -42,7 +42,7 @@ def captured(tmp_path_factory):
     root = str(tmp_path_factory.mktemp("aot-store"))
     rt = aot.AotRuntime(aot.AotStore(root), mode="capture",
                         family="census")
-    with _fresh_compiles():
+    with cache_disabled():
         jax.clear_caches()
         row = rt.capture_call(e.program, fn, args, kwargs,
                               static_argnums=e.static_argnums,
@@ -360,7 +360,7 @@ def test_prune_drops_unserved_buckets_and_dead_census_rows(tmp_path):
             ("c1.aotx", "census", 8, "_schedule_gang@n8_b8"),
             ("c2.aotx", "census", 8, "_schedule_gang@n_gone"),
             ("c3.aotx", "census", 8, "_schedule_gang:dead@n8_b8")):
-        store.save(name, {}, b"payload", None, None)
+        store.save(name, {}, b"payload", None, None, [0])
         rows.append({"row": rid, "family": fam, "sig_key": name,
                      "artifact": name, "pod_bucket": bucket})
     store.write_index(aot.env_signature(), rows)
@@ -395,7 +395,7 @@ def test_prune_without_closure_skips_proof_join(tmp_path):
     census rows rather than treat every rung as unreachable."""
     from tools.kubeaot.build import prune
     store = aot.AotStore(str(tmp_path))
-    store.save("c1.aotx", {}, b"payload", None, None)
+    store.save("c1.aotx", {}, b"payload", None, None, [0])
     store.write_index(aot.env_signature(), [
         {"row": "_schedule_gang@n8_b8", "family": "census",
          "sig_key": "c1.aotx", "artifact": "c1.aotx", "pod_bucket": 8}])

@@ -220,8 +220,6 @@ def test_dispatch_error_demotes_pallas_backend():
     """A pallas-backed profile that takes a dispatch fault demotes to the
     lax oracle path with a recorded reason; later cycles serve lax and
     still place."""
-    if not PB.available():
-        pytest.skip("jax.experimental.pallas unavailable")
     store = ClusterStore()
     for n in hollow.make_nodes(3):
         store.add(n)
@@ -424,7 +422,7 @@ def test_truncated_artifact_degrades_with_reason(tmp_path):
     x = np.ones((2,), np.float32)
     key = aot.call_signature("f_chaos_trunc", f, (x,), {})[0]
     store = _aot_world(tmp_path, "f_chaos_trunc", key, "t.aotx")
-    store.save("t.aotx", {"m": 1}, b"payload" * 64, None, None)
+    store.save("t.aotx", {"m": 1}, b"payload" * 64, None, None, [0])
     blob = (tmp_path / "t.aotx").read_bytes()
     (tmp_path / "t.aotx").write_bytes(blob[:len(blob) // 2])  # torn write
     rt = aot.AotRuntime(store, mode="serve")
@@ -454,7 +452,7 @@ def test_chaos_aot_load_fault_degrades(tmp_path):
     x = np.ones((2,), np.float32)
     key = aot.call_signature("f_chaos", f, (x,), {})[0]
     store = _aot_world(tmp_path, "f_chaos", key, "c.aotx")
-    store.save("c.aotx", {"m": 1}, b"payload" * 64, None, None)
+    store.save("c.aotx", {"m": 1}, b"payload" * 64, None, None, [0])
     reg = chaos.arm(chaos.ChaosRegistry(seed=6).arm_point(
         "aot-load", "corrupt", n=1))
     rt = aot.AotRuntime(store, mode="serve")

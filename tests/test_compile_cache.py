@@ -47,25 +47,21 @@ def test_no_recompile_within_vocab_bucket():
     assert (np.asarray(res.chosen)[:16] >= 0).all()
 
 
-def test_serving_enables_persistent_cache(tmp_path, monkeypatch):
+def test_serving_enables_persistent_cache(tmp_path):
     """Scheduler construction turns the persistent compilation cache on
-    (warm restarts must not pay XLA again)."""
+    (warm restarts must not pay XLA again): at the fixed in-checkout path
+    when nothing is configured, at the configured directory otherwise."""
     import kubetpu.utils.compilation as comp
-    monkeypatch.setattr(comp, "_enabled", None)
-    monkeypatch.setenv("KUBETPU_XLA_CACHE_DIR", str(tmp_path / "xla"))
-    prior = jax.config.jax_compilation_cache_dir
-    jax.config.update("jax_compilation_cache_dir", None)
     from kubetpu.client.store import ClusterStore
     from kubetpu.scheduler import Scheduler
+    prior = jax.config.jax_compilation_cache_dir
     try:
-        sched = Scheduler(ClusterStore())
-        assert comp._enabled == str(tmp_path / "xla")
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "xla")
-        sched.close()
-        # an application-configured dir is RESPECTED, never clobbered
-        monkeypatch.setattr(comp, "_enabled", None)
-        jax.config.update("jax_compilation_cache_dir", "/already/set")
-        assert comp.enable_persistent_cache() == "/already/set"
-        assert jax.config.jax_compilation_cache_dir == "/already/set"
+        jax.config.update("jax_compilation_cache_dir", None)
+        Scheduler(ClusterStore()).close()
+        assert jax.config.jax_compilation_cache_dir == comp.DEFAULT_CACHE_DIR
+        placed = str(tmp_path / "placed")
+        jax.config.update("jax_compilation_cache_dir", placed)
+        Scheduler(ClusterStore()).close()
+        assert jax.config.jax_compilation_cache_dir == placed
     finally:
         jax.config.update("jax_compilation_cache_dir", prior)

@@ -145,9 +145,11 @@ def test_roofline_join_on_measured_programs(drains):
     # fenced seconds
     assert rl["flops_source"] == "analytic"
     assert rl["achieved_tflops"] > 0
-    assert 0 < rl["roofline_fraction"]
-    assert rl["regime"] in ("compute-bound", "memory-bound")
-    assert rl["manifest_variant"]
+    assert rl["manifest_variant"] and rl["arithmetic_intensity"] > 0
+    # this suite runs on the cpu backend, which has no peaks row: a share
+    # of some OTHER part's roofline must not be reported for it
+    assert not {"roofline_fraction", "regime",
+                "roofline_bound_tflops"} & set(rl)
     # explain_verdicts has no analytic model: scaled from the census row
     ev = drains["doc"]["programs"]["explain_verdicts"]
     assert ev["roofline"]["flops_source"] == "scaled-census"
@@ -173,11 +175,14 @@ def test_roofline_unit_math():
     costs = {"_schedule_gang": {"flops": 1e6, "bytes_accessed": 1e6,
                                 "in_bytes": 1000, "variant": "t",
                                 "lowering_sha256": "x"}}
-    rl = ud.roofline("run_auction", 0.001, flops=1e6, costs=costs)
+    from kubetpu.utils.flops import DEVICE_PEAKS
+    v5e = DEVICE_PEAKS["TPU v5 lite"]
+    rl = ud.roofline("run_auction", 0.001, flops=1e6, costs=costs,
+                     peaks=v5e)
     # AI = 1 flop/byte -> memory-bound on any realistic part
     assert rl["regime"] == "memory-bound"
     bound = rl["roofline_bound_tflops"] * 1e12
-    assert bound == pytest.approx(1.0 * ud.peak_membw_bytes_per_s())
+    assert bound == pytest.approx(1.0 * v5e.hbm_bytes_per_s)
     assert rl["achieved_tflops"] == pytest.approx(1e6 / 0.001 / 1e12)
     assert rl["roofline_fraction"] == pytest.approx(1e9 / bound)
     # scaled-census fallback: flops scale by operand bytes
@@ -244,8 +249,10 @@ def test_capacity_planner_sanity_gate_within_10pct(drains):
 
 
 def test_northstar_projection_answers_fit(drains):
+    from kubetpu.utils.flops import DEVICE_PEAKS
     proj = ud.project(drains["ledger_a"], 10000, 100000, shards=8,
-                      groups=("delta-resident", "chain"))
+                      groups=("delta-resident", "chain"),
+                      peaks=DEVICE_PEAKS["TPU v5 lite"])
     assert proj["pod_bucket"] == 131072
     assert proj["total_bytes"] > 0
     assert proj["per_shard_bytes"] < proj["total_bytes"]

@@ -13,7 +13,6 @@ import pytest
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
@@ -59,9 +58,9 @@ def test_noninteger_float_psum_fires():
     mesh = _mesh()
 
     def bad(x):
-        return shard_map(lambda t: jax.lax.psum(t * 0.5, "i"),
+        return jax.shard_map(lambda t: jax.lax.psum(t * 0.5, "i"),
                          mesh=mesh, in_specs=P("i"), out_specs=P(),
-                         check_rep=False)(x)
+                         check_vma=False)(x)
 
     proofs, findings = prove_callable(
         "bad:psum", bad, (np.zeros((4, 8), np.float32),),
@@ -79,9 +78,9 @@ def test_out_of_range_integer_sum_fires():
         # the north-star environment — past the exact f32 integer range
         y = jnp.floor(jnp.clip(x, 0.0, 4096.0))
         s = jnp.sum(y, axis=-1)
-        return shard_map(lambda t: jax.lax.psum(t, "i"),
+        return jax.shard_map(lambda t: jax.lax.psum(t, "i"),
                          mesh=mesh, in_specs=P("i"), out_specs=P(),
-                         check_rep=False)(s)
+                         check_vma=False)(s)
 
     proofs, findings = prove_callable(
         "bad:overflow", bad, (np.zeros((4, 8), np.float32),),
@@ -96,10 +95,10 @@ def test_shardmap_row_gather_fires():
     mesh = _mesh()
 
     def bad(x):
-        return shard_map(
+        return jax.shard_map(
             lambda t: jax.lax.all_gather(t, "i", tiled=True),
             mesh=mesh, in_specs=P("i"), out_specs=P("i"),
-            check_rep=False)(x)
+            check_vma=False)(x)
 
     _, findings = prove_callable(
         "bad:gather", bad, (np.zeros((4, 8), np.float32),),
@@ -138,9 +137,9 @@ def test_clean_snippet_is_empty():
 
     def good(x):
         counts = jnp.sum(jnp.where(x > 0, 1.0, 0.0), axis=-1)
-        return shard_map(lambda t: jax.lax.psum(t, "i"),
+        return jax.shard_map(lambda t: jax.lax.psum(t, "i"),
                          mesh=mesh, in_specs=P("i"), out_specs=P(),
-                         check_rep=False)(counts)
+                         check_vma=False)(counts)
 
     proofs, findings = prove_callable(
         "good:counts", good, (np.zeros((4, 8), np.float32),),

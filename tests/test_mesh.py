@@ -10,10 +10,11 @@ Two lowerings exist (parallel/mesh.py ``partitioner=``):
   with hand-placed collectives.  Exact on EVERY mesh shape, including
   the pod-axis (2, 4)/(4, 2) splits the legacy partitioner mis-lowers;
   the tests below assert it UNGATED.
-* ``gspmd`` (legacy) — the derive-everything lowering.  Exact on
-  node-axis (1, N) meshes only; the pod-axis cases keep their PR 6
-  env-gated skip markers (the documented legacy-partitioner fault: the
-  new path SIDESTEPS it, it does not fix the old lowering).
+* ``gspmd`` (legacy) — the derive-everything lowering, reachable only
+  by argument.  It mis-lowered pod-axis splits on jax 0.4.x and its
+  (2, 4) test was skipped there; on the installed jax 0.9.0 that test
+  runs and passes, so it is asserted like the rest (ROADMAP C2 decides
+  whether the old lowering stays at all).
 """
 import jax
 import numpy as np
@@ -30,23 +31,16 @@ cpu_devices = jax.devices("cpu")
 pytestmark = pytest.mark.skipif(len(cpu_devices) < 8,
                                 reason="needs 8 virtual CPU devices")
 
-# Pod-axis (2-D) sharding of the LEGACY GSPMD lowering is
-# environment-gated: on jax builds predating ``jax.set_mesh`` the legacy
-# SPMD partitioner mis-lowers cross-shard index/tie selection when the
-# POD axis is split (sequential's chosen rows come back scaled by the
-# nodes-shard count; gang contention winners flip and infeasible pods
-# come back placed).  Node-axis (1, N) sharding is exact on every
-# supported jax and stays asserted below.  The DEFAULT shard_map path
-# (parallel/shardmap.py) sidesteps the partitioner and is asserted
-# UNGATED at (2, 4)/(4, 2) further down — do not undo these markers;
-# they document the old lowering, which remains available for
-# comparison via partitioner="gspmd".
-mesh_2d = pytest.mark.skipif(
-    not hasattr(jax, "set_mesh"),
-    reason="env-gated: pod-axis (2,4) sharding of the LEGACY gspmd "
-           "partitioner needs the jax.set_mesh-era SPMD lowering; this "
-           "jax mis-lowers its cross-shard index selection (the default "
-           "shard_map path is asserted ungated instead)")
+
+def test_make_mesh_raises_on_a_shape_the_backend_cannot_satisfy():
+    """No quiet detour onto another platform's devices: a shape the
+    default backend's device set cannot fill is an error."""
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match="needs %d devices" % (n + 1)):
+        pmesh.make_mesh((1, n + 1))
+    with pytest.raises(ValueError, match="needs 4 devices"):
+        pmesh.make_mesh((2, 2), devices=cpu_devices[:3])
+    assert pmesh.make_mesh((2, n // 2)).devices.shape == (2, n // 2)
 
 
 def _inputs():
@@ -177,11 +171,10 @@ def test_sharded_gang_tiled_term_free():
     _assert_gang_equal(refw, resw)
 
 
-@mesh_2d
 def test_sharded_gang_matches_single_device_gspmd_legacy():
-    """The LEGACY gspmd lowering at (2, 4) — still env-gated (see
-    mesh_2d): this asserts the OLD partitioner, kept for comparison;
-    the default path is covered ungated above."""
+    """The LEGACY gspmd lowering at (2, 4): this asserts the OLD
+    partitioner, kept for comparison; the default shard_map path is
+    covered above."""
     cluster, batch, cfg, rng = _inputs()
     ref = schedule_gang(cluster, batch, cfg, rng)
 

@@ -3,10 +3,9 @@
 The lax gang auction is the BIT-MATCH ORACLE: for every supported
 (cfg, batch), ``kernel_backend="pallas"`` must reproduce the full
 GangResult — placements, win scores, rounds, carries, diagnostics —
-bit-for-bit.  Tier-1 runs the kernel under interpret=True on CPU
-(capability-probed skip when pallas is absent); real-backend compilation
-is exercised by the slow-marked test plus bench.py's backend_compare
-case.  Unsupported routings (topology batches, exotic score plugins)
+bit-for-bit.  Tier-1 runs the kernel under interpret=True on CPU;
+Mosaic compilation is exercised on the chip by chip_smoke.py's Pallas
+phase (and bench.py's backend_compare case).  Unsupported routings (topology batches, exotic score plugins)
 must FALL BACK to lax with a recorded reason — and still be
 bit-identical, trivially.
 """
@@ -23,11 +22,6 @@ from kubetpu.ops import pallas_kernels as PK
 from kubetpu.utils import pallas_backend as PB
 from tests.test_gang import build
 from tests.test_tensors import mknode, mkpod
-
-pytestmark = pytest.mark.skipif(
-    not PK.HAVE_PALLAS,
-    reason="jax.experimental.pallas unavailable in this environment "
-           "(reasoned skip, not a failure — see ISSUE 8 CI contract)")
 
 FULL_FILTERS = ("NodeUnschedulable", "NodeResourcesFit", "NodeName",
                 "NodePorts", "NodeAffinity", "TaintToleration",
@@ -168,22 +162,6 @@ def test_differential_randomized_property(seed, n_nodes, n_pods, rw):
     a, b = _both(cluster, batch, cfg, jax.random.PRNGKey(seed),
                  residual_window=rw)
     _assert_bitmatch(a, b, f"seed={seed}")
-
-
-@pytest.mark.slow
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="real-backend (Mosaic) compilation needs a TPU; "
-                           "CPU runs the interpret-mode suite instead")
-def test_differential_real_backend_tpu():
-    """On a TPU the megakernel compiles through Mosaic (interpret=False,
-    utils/pallas_backend.interpret_mode probes the backend): placements
-    must still match the lax oracle.  bench.py backend_compare carries
-    the perf side (device_wait_s / round histogram) under BENCH_GATE."""
-    cluster, batch, cfg, _ = churned_world(0, 150, 40)
-    a, b = _both(cluster, batch, cfg, jax.random.PRNGKey(0),
-                 residual_window=16)
-    np.testing.assert_array_equal(np.asarray(a.chosen),
-                                  np.asarray(b.chosen))
 
 
 @pytest.mark.slow

@@ -99,19 +99,50 @@ def test_watchdog_counts_and_flags_recompiles():
         return logging.LogRecord("jax._src.interpreters.pxla",
                                  logging.DEBUG, __file__, 1, msg, (), None)
 
-    msg_a = ("Compiling prog with global shapes and types "
-             "[ShapedArray(float32[8,4])]. Argument mapping: (x,).")
-    msg_b = ("Compiling prog with global shapes and types "
-             "[ShapedArray(float32[16,4])]. Argument mapping: (x,).")
+    msg_a = ("Compiling jit(prog) with global shapes and types "
+             "(ShapedArray(float32[8,4]),). Argument mapping: "
+             "(UnspecifiedValue,).")
+    msg_b = ("Compiling jit(prog) with global shapes and types "
+             "(ShapedArray(float32[16,4]),). Argument mapping: "
+             "(UnspecifiedValue,).")
     wd.emit(rec(msg_a))
     wd.emit(rec(msg_b))
     wd.assert_no_recompilation()  # two SHAPES, one compile each: fine
+    assert {name for name, _ in wd.counts} == {"prog"}  # jit() stripped
     wd.emit(rec(msg_a))           # same program+shape again: cache defeated
     assert wd.recompiled()
     with pytest.raises(AssertionError, match="jit cache defeated"):
         wd.assert_no_recompilation()
     wd.reset()
     assert wd.compile_count() == 0
+
+
+def test_watchdog_counts_a_fresh_jit_once_per_shape():
+    """Against the INSTALLED jax's real log record (not a hand-written
+    one): a fresh jit compiles exactly once per shape, a repeat call is a
+    jit-cache hit and counts nothing.  A watchdog whose pattern drifted
+    from the record sees zero compiles and every zero-recompile gate
+    built on it passes vacuously."""
+    import jax
+    wd = sanitize.install_compile_watchdog()
+    try:
+        @jax.jit
+        def watchdog_probe(x):
+            return x * 2 + 1
+
+        def probe_compiles():
+            return sum(c for (name, _), c in wd.counts.items()
+                       if name == "watchdog_probe")
+
+        np.asarray(watchdog_probe(np.ones((3, 5), np.float32)))
+        assert probe_compiles() == 1
+        np.asarray(watchdog_probe(np.ones((3, 5), np.float32)))
+        assert probe_compiles() == 1
+        np.asarray(watchdog_probe(np.ones((7, 5), np.float32)))
+        assert probe_compiles() == 2
+        wd.assert_no_recompilation()
+    finally:
+        sanitize.uninstall_compile_watchdog(wd)
 
 
 def test_watchdog_records_donation_mismatch():
@@ -226,7 +257,7 @@ def test_sanitized_joins_env_armed_sanitizer(monkeypatch):
     wd = sanitize.maybe_enable_from_env()
     assert wd is not None
     try:
-        wd.counts[("stale", "[f32[8]]")] = 2
+        wd.counts[("stale", "(f32[8],)")] = 2
         with sanitized() as wd2:
             assert wd2 is wd
             # joining resets counts so this scope judges only its own work
@@ -263,8 +294,9 @@ def test_watchdog_uninstall_restores_and_respects_active_sanitizer():
         assert logger.level == logging.DEBUG
         logger.handle(logging.LogRecord(
             sanitize._PXLA_LOGGER, logging.DEBUG, __file__, 1,
-            "Compiling prog with global shapes and types "
-            "[ShapedArray(float32[8,4])]. Argument mapping: (x,).",
+            "Compiling jit(prog) with global shapes and types "
+            "(ShapedArray(float32[8,4]),). Argument mapping: "
+            "(UnspecifiedValue,).",
             (), None))
         assert swd.compile_count() == 1
         sanitize.disable_sanitizer()

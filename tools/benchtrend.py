@@ -1,12 +1,14 @@
-"""Bench-trend tooling: the committed BENCH_r*.json trajectory as a
-per-case trend table, with per-stage regression ATTRIBUTION.
+"""Bench-trend tooling: a series of saved bench runs as a per-case trend
+table, with per-stage regression ATTRIBUTION.
 
-The repo commits one bench artifact per PR round (``BENCH_r01.json`` ..,
-plus the ``MULTICHIP_r*.json`` mesh runs); each carries the bench.py
+A bench run saved with ``BENCH_OUT=<path>`` carries the bench.py
 ``detail`` document — and, since the SLO layer (kubetpu/utils/slo.py),
 a per-case ``latency`` block (``pod_e2e_p50/p90/p99_s`` +
-``stage_shares``).  This tool reads that trajectory, optionally appends
-a fresh run (``--run`` pointing at a BENCH_OUT-format file), and prints:
+``stage_shares``).  No run is committed to the repo (the pre-PR-1 rounds
+were deleted in PR 23; the driver's PERF_LEDGER.jsonl is the record
+now), so the default trajectory is empty: point ``--glob`` at saved
+runs, oldest first by name, and/or append a fresh one with ``--run``.
+The tool prints:
 
   * a per-case trend table (pods/s per round, with the round-over-round
     delta), and
@@ -18,16 +20,16 @@ a fresh run (``--run`` pointing at a BENCH_OUT-format file), and prints:
     achieved roofline fraction fell, or whose resident HBM grew.
 
 ``--check`` is the CI mode (tools/ci_lint.sh): nonzero exit when a
-committed artifact is schema-INCOMPATIBLE (a case present but
-non-numeric where the trend table needs numbers) or when the newest
-parseable round regresses beyond the NORTHSTAR.json gate (bench.py's
-northstar_gate — the same floors/ceilings BENCH_GATE=1 enforces).
-Artifacts whose detail cannot be recovered (e.g. a tail-truncated
-capture) are reported and skipped, never a hard failure — the committed
-history is immutable.
+run is schema-INCOMPATIBLE (a case present but non-numeric where the
+trend table needs numbers) or when the newest parseable round regresses
+beyond the NORTHSTAR.json gate (bench.py's northstar_gate — the same
+floors/ceilings BENCH_GATE=1 enforces).  On an empty trajectory it
+degrades to the NORTHSTAR.json schema check.  Runs whose detail cannot
+be recovered (e.g. a tail-truncated capture) are reported and skipped,
+never a hard failure.
 
 Usage:
-  python -m tools.benchtrend [--glob 'BENCH_r*.json'] [--run FRESH.json]
+  python -m tools.benchtrend [--glob 'runs/BENCH_*.json'] [--run FRESH.json]
                              [--check] [--threshold 0.1]
 """
 from __future__ import annotations
@@ -93,14 +95,6 @@ def load_round(path: str) -> Dict[str, Any]:
                 "note": f"unreadable ({e.__class__.__name__})"}
     detail = _find_detail(doc)
     if detail is None:
-        if isinstance(doc, dict) and "n_devices" in doc and "rc" in doc:
-            # a MULTICHIP dryrun capture ({n_devices, rc, ok, tail}) —
-            # a pass/fail record, not a bench round; a MULTICHIP-only
-            # trajectory is a state, never an error
-            return {"round": name, "detail": None,
-                    "note": "multichip dryrun capture (ok=%s, %s "
-                            "devices) — no bench detail to trend"
-                            % (doc.get("ok"), doc.get("n_devices"))}
         return {"round": name, "detail": None,
                 "note": "no parseable detail document "
                         "(truncated capture or non-bench artifact)"}
@@ -313,9 +307,9 @@ def validate_northstar(path: str) -> List[str]:
     """Schema check of NORTHSTAR.json's gate section that needs NO
     committed round: every entry must carry a numeric pods_per_sec floor
     or seconds ceiling, and its fraction knobs must be numeric.  This is
-    what ``--check`` degrades to on an empty trajectory (a fresh repo,
-    or a re-anchor that dropped the BENCH_r* history) — the gate file
-    itself stays validated instead of the check erroring out."""
+    what ``--check`` degrades to on an empty trajectory (the repo's
+    state since PR 23) — the gate file itself stays validated instead of
+    the check erroring out."""
     try:
         with open(path) as f:
             doc = json.load(f)
@@ -402,9 +396,9 @@ def main(argv=None) -> int:
         prog="benchtrend",
         description="per-case trend table + regression attribution over "
                     "the committed bench JSON trajectory")
-    ap.add_argument("--glob", default="BENCH_r*.json,MULTICHIP_r*.json",
-                    help="comma-separated globs, resolved in the repo "
-                         "root (default: the committed round captures)")
+    ap.add_argument("--glob", default="BENCH_*.json",
+                    help="comma-separated globs of saved BENCH_OUT runs, "
+                         "resolved in the repo root (none is committed)")
     ap.add_argument("--run", default=None,
                     help="a fresh BENCH_OUT-format JSON appended as the "
                          "newest round")
@@ -436,8 +430,7 @@ def main(argv=None) -> int:
         # an empty repo history is a state, not an error.  --check still
         # validates the NORTHSTAR gate schema so the floors/ceilings
         # file can't rot while there are no rounds to trend.
-        print("no trajectory (no parseable BENCH_r*/MULTICHIP_r* rounds"
-              " committed yet)")
+        print("no trajectory (no parseable saved bench run matched)")
         if args.check:
             errs = validate_northstar(os.path.join(REPO_ROOT,
                                                    "NORTHSTAR.json"))

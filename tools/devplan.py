@@ -27,7 +27,12 @@ import json
 import sys
 from typing import Any, Dict, Optional
 
-from kubetpu.utils.devstats import hbm_bytes, project  # noqa: F401
+from kubetpu.utils.devstats import project
+from kubetpu.utils.flops import DEVICE_PEAKS
+
+# the planner's question is about one named part — it runs offline, on
+# whatever machine holds the ledger file
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def find_ledger(doc: Any) -> Optional[Dict[str, Any]]:
@@ -92,7 +97,8 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 1
 
-    proj = project(ledger, args.nodes, args.pods, shards=args.shards)
+    proj = project(ledger, args.nodes, args.pods, shards=args.shards,
+                   peaks=DEVICE_PEAKS[TARGET_DEVICE_KIND])
     if args.json:
         print(json.dumps(proj, indent=1, sort_keys=True))
     else:
@@ -112,7 +118,8 @@ def main(argv=None) -> int:
               f"{_fmt_bytes(proj['total_bytes']):>12}")
         print(f"  {'per shard (pod axis / %d)' % args.shards:<40} "
               f"{_fmt_bytes(proj['per_shard_bytes']):>12}")
-        print(f"  HBM per chip: {_fmt_bytes(proj['hbm_bytes_per_chip'])}"
+        print(f"  HBM per {TARGET_DEVICE_KIND} chip: "
+              f"{_fmt_bytes(proj['hbm_bytes_per_chip'])}"
               f" -> fits single chip: {proj['fits_single_chip']}, "
               f"fits per shard: {proj['fits_per_shard']}")
     return 0 if proj["fits_per_shard"] else 2

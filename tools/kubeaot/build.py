@@ -37,37 +37,13 @@ AotStore layout — ``*.aotx`` payloads + ``index.json``):
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import time
 from typing import Dict, List, Optional, Set
 
+from kubetpu.utils.compilation import cache_disabled
 
-@contextlib.contextmanager
-def _fresh_compiles():
-    """Disable the persistent compilation cache for the duration of a
-    capture.  An executable that came back as a CACHE HIT re-serializes
-    to a blob that references JIT symbols it does not carry — on the CPU
-    backend ``deserialize_executable`` then fails with "Symbols not
-    found" — so every artifact must come from a true backend compile.
-    (AotRuntime._capture additionally round-trips each artifact at build
-    time, so a regression here fails the build instead of silently
-    falling back at serve.)"""
-    import jax
-
-    # latch utils/compilation's idempotent enable FIRST: Scheduler's
-    # constructor calls enable_persistent_cache(), and with the config
-    # cleared below that call would otherwise re-enable the cache
-    # mid-capture
-    from kubetpu.utils.compilation import enable_persistent_cache
-    enable_persistent_cache()
-    prev = getattr(jax.config, "jax_compilation_cache_dir", None)
-    jax.config.update("jax_compilation_cache_dir", None)
-    try:
-        yield
-    finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
 
 # the seamed serving programs (kubetpu/utils/aot.py dispatch seams in
 # models/gang.py, models/sequential.py, models/programs.py, and the
@@ -145,7 +121,7 @@ def build_census(out_dir: str = DEFAULT_OUT,
                         family="census")
     manifest = {row_id(r): r for r in (load_manifest() or [])}
     report: List[dict] = []
-    with _fresh_compiles():
+    with cache_disabled():
         for e in ENTRIES:
             if e.program not in programs:
                 continue
@@ -201,7 +177,7 @@ def build_shape(out_dir: str, n_nodes: int, wave: int, ladder: int = 2,
     rt = aot.arm(aot.AotRuntime(aot.AotStore(out_dir), mode="capture",
                                 family="serving"))
     try:
-        with _fresh_compiles():
+        with cache_disabled():
             store = hollow.restart_world(
                 n_nodes, existing_per_node=existing_per_node)
             sched = Scheduler(store, config=KubeSchedulerConfiguration(

@@ -78,7 +78,7 @@ def _walk_jaxprs(jaxpr):
 
 
 def _sub_jaxprs(v):
-    from jax import core
+    from jax.extend import core
     if isinstance(v, core.Jaxpr):
         yield v
     elif isinstance(v, core.ClosedJaxpr):
@@ -147,10 +147,9 @@ def check_f64(program: str, jaxpr_fn, args) -> List[Finding]:
     config silently truncates."""
     import numpy as np
     import jax
-    from jax.experimental import enable_x64
     out = []
     try:
-        with enable_x64():
+        with jax.enable_x64(True):
             closed = jax.make_jaxpr(jaxpr_fn)(*args)
     except Exception as e:  # a trace that only works in x32 is itself news
         return [Finding("census/f64-promotion", program,

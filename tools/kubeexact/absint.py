@@ -643,7 +643,24 @@ def _t_gather(interp, eqn, ins):
 @_reg("pad")
 def _t_pad(interp, eqn, ins):
     a, pval = ins
-    return [_join(a, pval, shape=_shape(eqn)).drop_structure()]
+
+    def padded(v, shape=None):
+        j = _join(v, pval, shape=shape)
+        out = j.drop_structure()
+        if _is_zero(pval):
+            # zero padding adds nothing to any row sum
+            out.lastsum, out.lastsum_global = j.lastsum, j.lastsum_global
+        return out
+
+    out = padded(a, _shape(eqn))
+    if a.parts is not None and tuple(
+            eqn.params["padding_config"][a.parts_axis]) == (0, 0, 0):
+        # untouched along the stacking axis: every slice of the result is
+        # the pad of that slice, so the per-slice facts survive (the
+        # Pallas bundle pads its stacked [S, B, N] planes on the node axis)
+        out.parts = tuple((p0, p1, padded(v)) for p0, p1, v in a.parts)
+        out.parts_axis = a.parts_axis
+    return [out]
 
 
 # ---- arithmetic -------------------------------------------------------
@@ -940,27 +957,31 @@ def _t_dot(interp, eqn, ins):
         lo = hi.neg()
     out = AbsVal(_shape(eqn), _kind(eqn), int_valued, lo, hi,
                  random=_taint(ins))
-    # the exact-count refinement (2D matmul, contract A-last/B-first):
-    # out[s, z] = sum_p A[s, p] * B[p, z]
+    # the exact-count refinement (2D matmul contracting A's last axis,
+    # against B's first — or B's last, the transposed-rhs form the Pallas
+    # kernel's [channel, node] tables use):
+    # out[s, z] = sum_p A[s, p] * B[p, z]       (or B[z, p])
     #   per-element   <= rowsum(A) * max(B)        (one-hot dot rule)
-    #   per-row sum   <= rowsum(A) * rowsum(B)     (counts stay counts)
+    #   per-row sum   <= rowsum(A) * rowsum(B)     (counts stay counts;
+    #                    B-first form only — B's row sums run over z there)
     #   over tiles of a sharded p-dim: global rowsum(A) bounds the TOTAL
     if (nonneg and len(a.shape) == 2 and len(b.shape) == 2
-            and lc == (1,) and rc == (0,) and not lb and not rb):
+            and lc == (1,) and rc in ((0,), (1,)) and not lb and not rb):
         # effective row-sum bounds: explicit if derived (one-hot rows),
         # else the implicit size*max bound of the current (local) shape
         la = a.lastsum if a.lastsum is not None else kexpr * a.hi
         ga = a.lastsum_global if a.lastsum is not None \
             else interp._outside_body()
-        lbnd = (b.lastsum if b.lastsum is not None
-                else interp.size_expr(b.shape[-1]) * b.hi)
-        gb = b.lastsum_global if b.lastsum is not None \
-            else interp._outside_body()
         if la.is_finite:
             out.hi = out.hi.emin(la * b.hi)
-        if la.is_finite and lbnd.is_finite:
-            out.lastsum = la * lbnd
-            out.lastsum_global = ga and gb
+        if rc == (0,):
+            lbnd = (b.lastsum if b.lastsum is not None
+                    else interp.size_expr(b.shape[-1]) * b.hi)
+            gb = b.lastsum_global if b.lastsum is not None \
+                else interp._outside_body()
+            if la.is_finite and lbnd.is_finite:
+                out.lastsum = la * lbnd
+                out.lastsum_global = ga and gb
         if a.sharded and ga and la.is_finite:
             tt = {}
             for key, dim in a.sharded.items():
@@ -986,7 +1007,7 @@ def _t_random(interp, eqn, ins):
 
 # ---- control flow -----------------------------------------------------
 
-@_reg("pjit", "closed_call", "core_call", "remat", "checkpoint",
+@_reg("jit", "closed_call", "core_call", "remat", "checkpoint",
       "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr")
 def _t_call(interp, eqn, ins):
     for key in ("jaxpr", "call_jaxpr", "fun_jaxpr"):
@@ -1131,12 +1152,13 @@ def _t_cond(interp, eqn, ins):
 @_reg("shard_map")
 def _t_shard_map(interp, eqn, ins):
     body = eqn.params["jaxpr"]          # plain Jaxpr
-    in_names = eqn.params["in_names"]
     body_ins = []
-    for v, names in zip(ins, in_names):
+    for v, spec in zip(ins, eqn.params["in_specs"]):
         sharded = dict(v.sharded or {})
-        for dim, axes in names.items():
-            for ax in axes:
+        for dim, axes in enumerate(spec):
+            if axes is None:
+                continue
+            for ax in (axes if isinstance(axes, tuple) else (axes,)):
                 sharded[ax] = dim
         body_ins.append(v.replace(sharded=sharded or None, origin=None))
     interp.in_shardmap += 1
@@ -1411,9 +1433,9 @@ def _t_addupdate(interp, eqn, ins):
 def _t_pallas_call(interp, eqn, ins):
     gm = eqn.params["grid_mapping"]
     body = eqn.params["jaxpr"]           # kernel jaxpr (refs as invars)
-    if not hasattr(body, "consts"):      # plain Jaxpr in some versions
-        import jax
-        body = jax.core.ClosedJaxpr(body, ())
+    if not hasattr(body, "consts"):      # the kernel body is a plain Jaxpr
+        from jax.extend.core import ClosedJaxpr
+        body = ClosedJaxpr(body, ())
     grid = tuple(int(g) for g in gm.grid)
     block_ins: List[Optional[AbsVal]] = []
     mappings = list(gm.block_mappings)
