@@ -388,6 +388,14 @@ def schedule_gang(cluster, batch, cfg: ProgramConfig, rng,
                          "residual_window", "kernel_backend"))
 
 
+# What a profiler trace calls the served auction: the lowered module is
+# named for the jitted function ("jit__schedule_gang"), and the trace
+# readers (perfbench/lib/readers.py AUCTION_PROGRAM) match this
+# substring.  Rename the function and tests/test_program_names.py fails
+# instead of the metric going silently empty.
+AUCTION_PROGRAM = "schedule_gang"
+
+
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "max_rounds",
                                     "intra_batch_topology",

@@ -211,6 +211,14 @@ def explain_verdicts(cluster, batch, cfg: ProgramConfig, host_ok=None):
         static_argnums=(2,))
 
 
+# the served programs' names in a profiler trace / the compile watchdog:
+# substrings of the lowered modules' names, pinned by
+# tests/test_program_names.py (see models/gang.py AUCTION_PROGRAM)
+EXPLAIN_PROGRAM = "explain_verdicts"
+WHATIF_PROGRAM = "whatif_wave"
+DELTA_PROGRAM = "apply_cluster_delta"
+
+
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _explain_verdicts(cluster, batch, cfg: ProgramConfig, host_ok=None):
     """The per-pod decision audit program (DecisionLog feed): everything

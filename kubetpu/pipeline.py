@@ -57,7 +57,7 @@ from __future__ import annotations
 
 import os
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import List, Optional, Tuple
 
 from .utils import trace as utrace
 
@@ -223,14 +223,8 @@ class PipelinedExecutor:
         cycle_start = utrace.wallclock()
         ring.unpark(cycle_start)
         while True:
-            qpods = s.queue.pop_batch(max_batch,
-                                      timeout=self.pop_timeout(timeout))
-            by_profile: Dict[str, List] = {}
-            for qp in qpods:
-                if s._skip_pod_schedule(qp.pod):
-                    continue
-                by_profile.setdefault(qp.pod.spec.scheduler_name,
-                                      []).append(qp)
+            by_profile, pop = s._pop_grouped(max_batch,
+                                             self.pop_timeout(timeout))
             if len(by_profile) != 1:
                 # nothing schedulable: commit the OLDEST in-flight cycle
                 # (one per call keeps the outcome cadence).  Multi-profile
@@ -240,7 +234,8 @@ class PipelinedExecutor:
                     outcomes = returned + self.flush()
                     for name, group in by_profile.items():
                         outcomes.extend(s._schedule_group(
-                            s.profiles[name], group))
+                            s.profiles[name], group, pop=pop))
+                        pop = None
                 else:
                     outcomes = returned + self._commit_oldest()
                 if s.metrics and outcomes:
@@ -267,7 +262,7 @@ class PipelinedExecutor:
             # work (preemption wave, decision audit) runs
             prep, early = s._prepare_group(fwk, group,
                                            uncommitted=ring.preps(),
-                                           relevance=relevance)
+                                           relevance=relevance, pop=pop)
             returned += early
             if prep is None:
                 outcomes = returned + self.flush()
@@ -314,7 +309,7 @@ class PipelinedExecutor:
                         ring.park(utrace.wallclock())
                         return returned
             res = None
-            with prep.trace.stage(
+            with prep.trace.phase(
                     "dispatch",
                     pipelined=oldest is not None or len(ring) > 0):
                 try:
@@ -359,7 +354,7 @@ class PipelinedExecutor:
                                 len(returned), utrace.wallclock() - cycle_start)
                         ring.park(utrace.wallclock())
                         return returned
-                    with prep.trace.stage("dispatch"):
+                    with prep.trace.phase("dispatch"):
                         try:
                             res = s._dispatch_group(prep)
                         except Exception as e:
@@ -450,14 +445,14 @@ class PipelinedExecutor:
         ring entry here; the caller handles the un-ringed cycle."""
         s = self.sched
         t0 = utrace.wallclock()
-        with prep.trace.stage("commit"):
+        with prep.trace.phase("commit"):
             outs = s._commit_group(prep, packed)
-        failed = s._last_commit_failed
-        if s.config.mode == "gang":
-            prep.trace.finish(auction_rounds=s.last_gang_rounds,
-                              kernel_backend=s._gang_backend(prep))
-        else:
-            prep.trace.finish()
+            failed = s._last_commit_failed
+            if s.config.mode == "gang":
+                prep.trace.finish(auction_rounds=s.last_gang_rounds,
+                                  kernel_backend=s._gang_backend(prep))
+            else:
+                prep.trace.finish()
         dt = utrace.wallclock() - t0
         self.ring.exempt(dt)
         if exempt_prep is not None:
@@ -500,7 +495,7 @@ class PipelinedExecutor:
             outs += early
             if new_prep is None:
                 continue
-            with new_prep.trace.stage("dispatch", rerun=True):
+            with new_prep.trace.phase("dispatch", rerun=True):
                 try:
                     res = s._dispatch_group(new_prep)
                 except Exception as e:

@@ -30,6 +30,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 from ..api import types as api
 from ..framework.types import QueuedPodInfo, pod_with_affinity
 from ..utils import slo as uslo
+from ..utils import trace as utrace
 from ..utils.trace import wallclock
 from .heap import Heap
 
@@ -140,6 +141,11 @@ class SchedulingQueue(PodNominator):
         self.unschedulable_q: Dict[str, QueuedPodInfo] = {}  # kubelint: guarded-by(_cond)
         self._unschedulable_recorder = m.unschedulable_recorder() if m else None
         self._metrics = metrics
+        # seconds pops have spent blocked waiting for pods (an empty
+        # active queue, the gather window), summed since start; counted
+        # only with the flight recorder armed -- the ``pop`` phase's
+        # ``wait_s`` is its growth over one pop_batch
+        self.pop_wait_s = 0.0               # kubelint: guarded-by(_cond)
         self.scheduling_cycle = 0           # reference: :120
         self.move_request_cycle = -1        # reference: :125
         self._stop = threading.Event()
@@ -215,9 +221,16 @@ class SchedulingQueue(PodNominator):
     def pop(self, timeout: Optional[float] = None) -> Optional[QueuedPodInfo]:
         """Blocks until a pod is available (reference: :378)."""
         with self._cond:
-            while len(self.active_q) == 0 and not self._closed:
-                if not self._cond.wait(timeout=timeout):
-                    return None
+            if len(self.active_q) == 0 and not self._closed:
+                armed = utrace.flight_recorder() is not None
+                t_w = wallclock() if armed else 0.0
+                try:
+                    while len(self.active_q) == 0 and not self._closed:
+                        if not self._cond.wait(timeout=timeout):
+                            return None
+                finally:
+                    if armed:
+                        self.pop_wait_s += wallclock() - t_w
             if self._closed and len(self.active_q) == 0:
                 return None
             qp = self.active_q.pop()
@@ -251,11 +264,17 @@ class SchedulingQueue(PodNominator):
         if (timeout is None or timeout > 0) and len(out) < max_batch:
             gather = 0.02 if timeout is None else min(0.02, timeout)
             with self._cond:
-                # one cond wait instead of a 2 ms poll loop: wakes on the
-                # notify that completes the batch, or at the window's end
-                self._cond.wait_for(
-                    lambda: len(self.active_q) >= max_batch - len(out),
-                    timeout=gather)
+                if len(self.active_q) < max_batch - len(out):
+                    armed = utrace.flight_recorder() is not None
+                    t_w = wallclock() if armed else 0.0
+                    # one cond wait instead of a 2 ms poll loop: wakes on
+                    # the notify that completes the batch, or at the
+                    # window's end
+                    self._cond.wait_for(
+                        lambda: len(self.active_q) >= max_batch - len(out),
+                        timeout=gather)
+                    if armed:
+                        self.pop_wait_s += wallclock() - t_w
         with self._cond:
             # one clock read for the whole drained batch (SLO armed only)
             pop_t = self._clock() if uslo.tracker() is not None else 0.0

@@ -55,6 +55,14 @@ from .utils.decisions import DecisionLog, PodDecision
 from .utils.trace import Trace
 
 
+def _lap(acc: List[float], step: int) -> None:
+    """The commit loop's running split (_commit_group): charge the time
+    since the last stamp, ``acc[-1]``, to ``acc[step]`` and stamp anew."""
+    now = time.perf_counter()
+    acc[step] += now - acc[-1]
+    acc[-1] = now
+
+
 def _vocab_caps(table):
     """Tensor-width signature chained cycles compare to detect overflow
     (tensor shapes would change) — ONE definition shared with the
@@ -545,10 +553,10 @@ class Scheduler:
             # the depth-k pipelined executor (kubetpu/pipeline.py):
             # prepare(k+1) overlaps device(k) and commit/bind(k-1)
             return self._pipeline.drain(max_batch, timeout)
-        batch = self.queue.pop_batch(max_batch, timeout=timeout)
-        if not batch:
+        by_profile, pop = self._pop_grouped(max_batch, timeout)
+        if not by_profile:
             return []
-        return self._schedule_batch(batch)
+        return self._schedule_groups(by_profile, pop)
 
     def flush_pipeline(self) -> List[ScheduleOutcome]:
         """Commit every in-flight pipelined cycle, oldest first (used at
@@ -556,18 +564,46 @@ class Scheduler:
         now)."""
         return self._pipeline.flush()
 
-    def _schedule_batch(self, qpods: List[QueuedPodInfo]) -> List[ScheduleOutcome]:
-        start = utrace.wallclock()
-        # group by profile: one device program per framework config
-        outcomes: List[ScheduleOutcome] = []
+    def _pop_grouped(self, max_batch: int, timeout: Optional[float]):
+        """The cycle's ``pop`` phase: pop a batch, drop the pods that
+        need no scheduling, group the rest by profile (one device program
+        per framework config).  Returns (by_profile, the phase or None):
+        still open, it is handed to the Trace of the cycle it fed, which
+        closes it as the cycle's own first phase opens."""
+        pop = utrace.begin_pop()
+        waited0 = self.queue.pop_wait_s if pop is not None else 0.0
+        qpods = self.queue.pop_batch(max_batch, timeout=timeout)
+        by_profile = self._group_by_profile(qpods)
+        if pop is not None:
+            pop.args.update(
+                wait_s=round(self.queue.pop_wait_s - waited0, 6),
+                popped=len(qpods),
+                skipped=len(qpods) - sum(map(len, by_profile.values())))
+            if not by_profile:
+                pop.close()          # no cycle follows to close it
+        return by_profile, pop
+
+    def _group_by_profile(self, qpods: List[QueuedPodInfo]
+                          ) -> Dict[str, List[QueuedPodInfo]]:
         by_profile: Dict[str, List[QueuedPodInfo]] = {}
         for qp in qpods:
             if self._skip_pod_schedule(qp.pod):
                 continue
             by_profile.setdefault(qp.pod.spec.scheduler_name, []).append(qp)
+        return by_profile
+
+    def _schedule_batch(self, qpods: List[QueuedPodInfo]) -> List[ScheduleOutcome]:
+        return self._schedule_groups(self._group_by_profile(qpods))
+
+    def _schedule_groups(self, by_profile: Dict[str, List[QueuedPodInfo]],
+                         pop=None) -> List[ScheduleOutcome]:
+        start = utrace.wallclock()
+        outcomes: List[ScheduleOutcome] = []
         for name, group in by_profile.items():
             fwk = self.profiles[name]
-            outcomes.extend(self._schedule_group(fwk, group))
+            # the pop fed every group; its span rides the first's record
+            outcomes.extend(self._schedule_group(fwk, group, pop=pop))
+            pop = None
         if self.metrics:
             self.metrics.observe_cycle(len(outcomes),
                                        utrace.wallclock() - start)
@@ -583,9 +619,9 @@ class Scheduler:
             return True
         return False
 
-    def _schedule_group(self, fwk: Framework,
-                        qpods: List[QueuedPodInfo]) -> List[ScheduleOutcome]:
-        prep, outcomes = self._prepare_group(fwk, qpods)
+    def _schedule_group(self, fwk: Framework, qpods: List[QueuedPodInfo],
+                        pop=None) -> List[ScheduleOutcome]:
+        prep, outcomes = self._prepare_group(fwk, qpods, pop=pop)
         if prep is None:
             return outcomes
         if self.extenders:
@@ -596,7 +632,7 @@ class Scheduler:
                     prep.cycle_ctx, score_bias=prep.score_bias)
             finally:
                 prep.trace.finish()
-        with prep.trace.stage("dispatch"):
+        with prep.trace.phase("dispatch"):
             try:
                 res = self._dispatch_group(prep)
             except Exception as e:  # device/backend fault: recover, never
@@ -632,20 +668,25 @@ class Scheduler:
     def _prepare_group(self, fwk: Framework, qpods: List[QueuedPodInfo],
                        uncommitted: Optional[List[PreparedCycle]] = None,
                        relevance: Optional[Dict[str, Tuple[bool, bool]]]
-                       = None):
+                       = None, pop=None):
         """Host half of a cycle, up to (but excluding) the device dispatch:
         snapshot, PreFilter, tensorize-or-chain, host filter masks,
-        nominated overlay.  Returns (PreparedCycle | None, early outcomes).
+        nominated overlay -- the phases ``snapshot``, ``prefilter``,
+        ``tensorize``, ``host-masks`` of the cycle's partition
+        (utils/trace.py).  Returns (PreparedCycle | None, early outcomes).
         uncommitted: EVERY dispatched-but-uncommitted pipelined cycle (the
         depth-k executor's in-flight ring) whose device buffers must
-        survive this prepare (gates delta donation)."""
+        survive this prepare (gates delta donation).  pop: the ``pop``
+        phase that fed this cycle (_pop_grouped), for its Trace to close
+        and record."""
         # queue depths ride the cycle record; the read takes the queue's
         # condition lock, so it is GATED on the recorder being armed (the
         # disarmed hot path must take no new locks)
         depths = (self.queue.depths()
                   if utrace.flight_recorder() is not None else None)
-        trace = Trace("Scheduling", profile=fwk.profile_name,
-                      pods=len(qpods), queue_depths=depths)
+        trace = Trace(utrace.CYCLE_TRACE, profile=fwk.profile_name,
+                      pods=len(qpods), queue_depths=depths, pop=pop)
+        trace.phase("snapshot")
         # devstats cycle tick: every Nth cycle is a deep-timing cycle —
         # its device dispatches (delta scatter below, the auction in
         # _dispatch_group) are micro-fenced so per-program device time
@@ -674,6 +715,12 @@ class Scheduler:
         node_infos = self.snapshot.node_info_list
         n_nodes = len(node_infos)
         trace.step("Snapshotting scheduler cache and node infos done")
+        if trace.rec is not None:
+            # the moment the snapshot was taken: what a tie-set check on
+            # this cycle's own binds has to place the cluster at
+            trace.rec.meta["snapshot_t"] = round(utrace.wallclock(), 6)
+            trace.note(nodes=n_nodes)
+        trace.phase("prefilter", pods=len(qpods))
         if self.metrics:
             self.metrics.cache_size.set(n_nodes, "nodes")
             self.metrics.cache_size.set(self.cache.pod_count(), "pods")
@@ -715,6 +762,7 @@ class Scheduler:
         # ---- tensorize, or reuse the CHAINED cluster: the previous gang
         # cycle's materialized tensors already ARE this snapshot (no
         # unaccounted event landed), so skip the full rebuild entirely
+        trace.phase("tensorize")
         pinfos = [PodInfo(qp.pod) for qp in live]
         # nominated pods join the tensor world too (labels/terms for the
         # addNominatedPods topology overlay) — their vocab must be interned
@@ -784,6 +832,9 @@ class Scheduler:
                                     parent_id=trace.span_id,
                                     delta_rows=dstats.delta_rows)
                 rec.meta["delta_rows"] = dstats.delta_rows
+                # the (dirty-node, churned-pod) row buckets the scatter
+                # program was dispatched with: a new pair is a compile
+                rec.meta["delta_buckets"] = list(dstats.delta_buckets)
                 rec.meta["resync"] = dstats.resync
                 if dstats.resync:
                     rec.event("resync", parent_id=trace.span_id,
@@ -827,8 +878,9 @@ class Scheduler:
         spread_sels = [self.store.default_spread_selector(pi.pod)
                        for pi in pinfos]
         pb = PodBatchBuilder(builder.table)
-        batch = self._jax.tree.map(np.asarray,
-                                   pb.build(pinfos, spread_selectors=spread_sels))
+        with trace.stage("batch-build", pods=len(pinfos)):
+            batch = self._jax.tree.map(
+                np.asarray, pb.build(pinfos, spread_selectors=spread_sels))
         batch_dev = None
         if self._mesh is not None:
             # DOUBLE-BUFFERED transfer: start the sharded upload of this
@@ -837,19 +889,21 @@ class Scheduler:
             # host->device transfer is issued behind the running program
             # instead of serializing in front of k+1's dispatch (whether
             # the copy actually overlaps the program on the chip: not
-            # measured).  device_put is async; the span below measures
-            # issue time, and traceview shows it inside the prepare
-            # stage — i.e. UNDER the previous wave's device window
+            # measured).  device_put is async: the span below is the
+            # ISSUE of the upload (its arg says so), not the transfer,
+            # and traceview shows it inside the prepare stage — i.e.
+            # UNDER the previous wave's device window
             from .parallel import mesh as pmesh
             t_up = utrace.wallclock()
             batch_dev = pmesh.shard_batch(batch, self._mesh)
             if trace.rec is not None:
+                t_issued = utrace.wallclock()
                 nbytes = sum(np.asarray(x).nbytes
                              for x in self._jax.tree.leaves(batch))
-                trace.rec.record_span("batch-upload", t_up,
-                                      utrace.wallclock(),
+                trace.rec.record_span("batch-upload", t_up, t_issued,
                                       parent_id=trace.span_id,
                                       bytes=int(nbytes),
+                                      issue_s=round(t_issued - t_up, 6),
                                       double_buffered=True)
         B = batch.valid.shape[0]
         N = cluster.allocatable.shape[0]
@@ -858,6 +912,14 @@ class Scheduler:
             # tools/kubeaot --prune works in (buckets the recorder never
             # saw are dead ladder rungs, dropped from the artifact set)
             trace.rec.meta["pod_bucket"] = int(cluster.pod_valid.shape[0])
+            trace.note(delta_rows=trace.rec.meta.get("delta_rows", 0),
+                       delta_buckets=trace.rec.meta.get("delta_buckets", []),
+                       pod_bucket=trace.rec.meta["pod_bucket"])
+            # the batch in batch order: row i of the bind table is
+            # batch_pods[i]
+            trace.rec.meta["batch_pods"] = [qp.pod.metadata.name
+                                            for qp in live]
+        trace.phase("host-masks")
 
         # ---- host filter plugins -> mask fed into the device program.
         # ONE walk of the host plugins' relevance predicates per pod per
@@ -1019,6 +1081,7 @@ class Scheduler:
             # dispatch still reads the resident cluster — withhold
             # donation (see __init__._undispatched)
             self._undispatched.append(prep)
+        # host-masks stays open: the dispatch phase closes it as it opens
         return prep, outcomes
 
     def _dispatch_group(self, prep: PreparedCycle, extra_uncommitted: int = 0):
@@ -1363,17 +1426,19 @@ class Scheduler:
             self._last_commit_failed = True
             self._sync_flight_dropped()
             return recovered
-        with prep.trace.stage("commit"):
+        with prep.trace.phase("commit"):
             out = self._commit_group(prep, packed)
-        if self.config.mode == "gang":
-            # per-cycle auction rounds as cycle meta: bench aggregates the
-            # histogram across cycles and traceview shows a digest column,
-            # so the round-count reduction ROADMAP item 3 claims is
-            # directly observable per run, not just as a max
-            prep.trace.finish(auction_rounds=self.last_gang_rounds,
-                              kernel_backend=self._gang_backend(prep))
-        else:
-            prep.trace.finish()
+            # finish() inside the phase: handing the record over is the
+            # commit's tail, and the phase closes as the record lands
+            if self.config.mode == "gang":
+                # per-cycle auction rounds as cycle meta: bench aggregates
+                # the histogram across cycles and traceview shows a digest
+                # column, so the round-count reduction ROADMAP item 3
+                # claims is directly observable per run, not just as a max
+                prep.trace.finish(auction_rounds=self.last_gang_rounds,
+                                  kernel_backend=self._gang_backend(prep))
+            else:
+                prep.trace.finish()
         self._sync_flight_dropped()
         return out
 
@@ -1400,7 +1465,7 @@ class Scheduler:
         ``res.packed`` returns only after the program is done (21.6 ms,
         against 21.8 ms for np.asarray alone), and np.asarray of the
         already-computed 12 KB vector then costs 0.70 ms."""
-        with prep.trace.stage("packed-readback") as sp:
+        with prep.trace.phase("packed-readback", ann="readback") as sp:
             t_dev = utrace.wallclock()
             packed = np.asarray(res.packed)
             t_done = utrace.wallclock()
@@ -1416,6 +1481,12 @@ class Scheduler:
 
     def _commit_group(self, prep: PreparedCycle,
                       packed: np.ndarray) -> List[ScheduleOutcome]:
+        """Runs inside the cycle's ``commit`` phase.  Armed, the per-pod
+        loop's split lands on that phase's span as SUMS -- recheck_s,
+        reserve_s, assume_s, permit_s, submit_s (pool submit + pruning of
+        the in-flight list), records_s (decision audit, SLO prefix, the
+        cycle context's note), pods, loop_s, loop_cpu_s -- not as a span
+        a pod."""
         fwk, trace = prep.fwk, prep.trace
         live, states, pinfos = prep.live, prep.states, prep.pinfos
         node_infos, cycle_ctx = prep.node_infos, prep.cycle_ctx
@@ -1481,6 +1552,18 @@ class Scheduler:
             slo_host_dispatch = max(prep.readback_done_t - prep.dispatch_t0
                                     - prep.device_wait
                                     - prep.host_exempt_s, 0.0)
+        # the split of the loop below, armed only: seconds summed per
+        # step over the cycle's pods, each stamp closing one step and
+        # opening the next (so the sums cover the loop), five stamps a
+        # pod -- _commit takes four, the loop's tail the fifth.
+        # acc = [recheck, reserve, assume, permit, submit, records, last
+        # stamp]; disarmed it is None and no clock is read
+        acc = None
+        if flight is not None:
+            flight.alloc_binds(len(live))
+            loop_cpu0 = time.thread_time()
+            loop_t0 = time.perf_counter()
+            acc = [0.0, 0.0, 0.0, 0.0, 0.0, 0.0, loop_t0]
         for i, qp in enumerate(live):
             state = states[qp.pod.uid]
             if chosen[i] < 0:
@@ -1493,10 +1576,12 @@ class Scheduler:
             slo = (self._slo_prefix(qp, prep, slo_host_dispatch, flight,
                                     jr_seq)
                    if slo_trk is not None and qp.pop_timestamp else None)
+            if acc is not None and slo is not None:
+                _lap(acc, 5)
             outcome = self._commit(fwk, qp, state, node_name,
                                    n_feas[i], pinfo=pinfos[i],
                                    host_relevant=prep.host_relevant[qp.pod.uid],
-                                   flight=flight, slo=slo)
+                                   flight=flight, slo=slo, row=i, acc=acc)
             if outcome.node:
                 # preemption for pods failing later in this batch must see
                 # this placement (CycleContext.cluster_now overlay)
@@ -1513,6 +1598,16 @@ class Scheduler:
                                           "commit failed",
                                           n_feasible=n_feas[i])
             outcomes.append(outcome)
+            if acc is not None:
+                _lap(acc, 5)
+        if acc is not None:
+            trace.note(
+                recheck_s=round(acc[0], 6), reserve_s=round(acc[1], 6),
+                assume_s=round(acc[2], 6), permit_s=round(acc[3], 6),
+                submit_s=round(acc[4], 6), records_s=round(acc[5], 6),
+                pods=len(live) - len(deferred),
+                loop_s=round(time.perf_counter() - loop_t0, 6),
+                loop_cpu_s=round(time.thread_time() - loop_cpu0, 6))
         # ---- preemption WAVE: every preemption-eligible FitError of this
         # cycle is served by ONE batched what-if (preemption.preempt_wave)
         # instead of a per-pod candidates pass + what-if dispatch each.
@@ -2050,7 +2145,11 @@ class Scheduler:
                 node_name: str, n_feasible: int,
                 binder_override=None, pinfo: Optional[PodInfo] = None,
                 host_relevant: Optional[bool] = None,
-                flight=None, slo=None) -> ScheduleOutcome:
+                flight=None, slo=None, row: int = -1,
+                acc: Optional[List[float]] = None) -> ScheduleOutcome:
+        """flight / row: the cycle's CycleRecord and this pod's row of
+        its bind table (armed only).  acc: the commit loop's running
+        split (_commit_group), None disarmed."""
         pod = qp.pod
         if host_relevant is None:
             host_relevant = fwk.has_relevant_host_filters(pod)
@@ -2072,6 +2171,8 @@ class Scheduler:
                                       st.message() or
                                       "commit-time filter re-check failed",
                                       preemption_may_help=False)
+            if acc is not None:
+                _lap(acc, 0)
         # Reserve (reference: scheduler.go:586).  Commit-phase failures are
         # not FitErrors, so they never trigger preemption
         # (reference: scheduler.go:542 err type check).
@@ -2080,6 +2181,8 @@ class Scheduler:
             fwk.run_unreserve_plugins(state, pod, node_name)
             return self._fail(fwk, qp, state, node_name, st.message(),
                               preemption_may_help=False)
+        if acc is not None:
+            _lap(acc, 1)
 
         # assume (reference: scheduler.go:435,593).  A shallow clone with a
         # fresh spec is enough: the cache reads spec/containers/labels,
@@ -2096,6 +2199,8 @@ class Scheduler:
             fwk.run_unreserve_plugins(state, pod, node_name)
             return self._fail(fwk, qp, state, node_name, str(e),
                               preemption_may_help=False)
+        if acc is not None:
+            _lap(acc, 2)
 
         # Permit (reference: scheduler.go:608)
         st = fwk.run_permit_plugins(state, pod, node_name)
@@ -2104,19 +2209,24 @@ class Scheduler:
             fwk.run_unreserve_plugins(state, pod, node_name)
             return self._fail(fwk, qp, state, node_name, st.message(),
                               preemption_may_help=False)
+        if acc is not None:
+            _lap(acc, 3)
 
         # binding cycle (reference: scheduler.go:628 goroutine)
+        if flight is not None:
+            flight.stamp_bind(row, utrace.BIND_SUBMITTED)
         if self._async_binding:
             try:
                 fut = self._bind_pool.submit(self._bind_cycle, fwk, qp,
                                              state, assumed, node_name,
-                                             binder_override, flight, slo)
+                                             binder_override, flight, slo,
+                                             row)
             except RuntimeError:
                 # close() raced the serving loop and shut the pool down
                 # mid-cycle: bind synchronously so the placement still
                 # lands instead of panicking the cycle
                 err = self._bind_cycle(fwk, qp, state, assumed, node_name,
-                                       binder_override, flight, slo)
+                                       binder_override, flight, slo, row)
             else:
                 # prune completed futures so a long-running scheduler
                 # doesn't retain one CycleState + pod copy per pod
@@ -2126,28 +2236,32 @@ class Scheduler:
                 err = None
         else:
             err = self._bind_cycle(fwk, qp, state, assumed, node_name,
-                                   binder_override, flight, slo)
+                                   binder_override, flight, slo, row)
+        if acc is not None:
+            _lap(acc, 4)
         return ScheduleOutcome(pod=pod, node=node_name if err is None else "",
                                err=err, n_feasible=n_feasible)
 
     def _bind_cycle(self, fwk: Framework, qp: QueuedPodInfo, state: CycleState,
                     assumed: api.Pod, node_name: str,
                     binder_override=None, flight=None,
-                    slo=None) -> Optional[str]:
-        """reference: scheduler.go:628-687.  flight: the cycle's
-        CycleRecord — per-pod bind spans land on it from whichever thread
-        runs the bind (capped per record; None when disarmed).  slo: the
-        pod's cycle-side stage vector (_slo_prefix) — the bind completes
-        it with commit/bind/e2e and records the terminal pod (None when
-        the tracker is disarmed)."""
-        if flight is not None:
-            with flight.span("bind", pod=qp.pod.metadata.name,
-                             node=node_name):
-                return self._bind_cycle_inner(fwk, qp, state, assumed,
-                                              node_name, binder_override,
-                                              slo)
-        return self._bind_cycle_inner(fwk, qp, state, assumed, node_name,
-                                      binder_override, slo)
+                    slo=None, row: int = -1) -> Optional[str]:
+        """reference: scheduler.go:628-687.  flight, row: the cycle's
+        CycleRecord and this pod's row of its bind table — the bind's
+        start and end are stamped there from whichever thread runs it,
+        lock-free (None when disarmed).  slo: the pod's cycle-side stage
+        vector (_slo_prefix) — the bind completes it with
+        commit/bind/e2e and records the terminal pod (None when the
+        tracker is disarmed)."""
+        if flight is None:
+            return self._bind_cycle_inner(fwk, qp, state, assumed,
+                                          node_name, binder_override, slo)
+        flight.stamp_bind(row, utrace.BIND_STARTED)
+        try:
+            return self._bind_cycle_inner(fwk, qp, state, assumed,
+                                          node_name, binder_override, slo)
+        finally:
+            flight.stamp_bind(row, utrace.BIND_DONE)
 
     def _bound_node(self, pod: api.Pod):
         """The API's current view of a pod's binding: the node name,

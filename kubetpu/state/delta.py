@@ -137,6 +137,10 @@ class DeltaStats(NamedTuple):
     resync: bool
     reason: str                     # "" on pure delta cycles
     spans: Tuple[Tuple[str, float, float], ...]  # (name, t0, t1)
+    # the (dirty-node, churned-pod) row buckets _apply_cluster_delta was
+    # dispatched with (gather_delta's pow2 pads): the program compiles
+    # once per pair.  () when no scatter ran
+    delta_buckets: Tuple[int, ...] = ()
 
 
 class DeltaTensorizer:
@@ -482,12 +486,16 @@ class DeltaTensorizer:
         self.cycles_since_resync += 1
         spans = ((("delta-build", t0, t_build),) + term_span
                  + (("delta-apply", t_build, wallclock()),))
+        buckets = (int(delta.node_rows.shape[0]),
+                   int(delta.pod_rows.shape[0]))
         vspan, vstats = self._verify_tick(node_infos, names, pending)
         if vstats is not None:
             return self.cluster, vstats._replace(spans=spans
-                                                 + vstats.spans)
+                                                 + vstats.spans,
+                                                 delta_buckets=buckets)
         return self.cluster, DeltaStats(
-            len(node_rows) + len(pod_rows), False, "", spans + vspan)
+            len(node_rows) + len(pod_rows), False, "", spans + vspan,
+            buckets)
 
     # ------------------------------------------------------------- resync
 

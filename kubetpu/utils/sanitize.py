@@ -28,13 +28,14 @@ suites can scope it to single cases.
 
 from __future__ import annotations
 
+import collections
 import logging
 import os
 import re
 import threading
 import warnings
 from contextlib import contextmanager
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Deque, Dict, List, Optional, Set, Tuple
 
 ENV_FLAG = "KUBETPU_SANITIZE"
 
@@ -50,6 +51,58 @@ _COMPILE_RE = re.compile(
 _JIT_WRAPPER_RE = re.compile(r"jit\((.*)\)")
 _DONATION_RE = re.compile(r"[Dd]onated buffers? .*not usable|"
                           r"buffer donat\w+ .*mismatch")
+# one argument of a shape signature: "float32[8,16]", "int32[]"
+_ARG_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+MAX_COMPILE_RECORDS = 256
+MAX_SIGNATURES_PER_PROGRAM = 64
+
+Signature = List[Tuple[str, Tuple[int, ...]]]
+
+
+def parse_signature(shapes: str) -> Signature:
+    """The compile record's shape string as [(dtype, dims)...], one entry
+    per argument, in argument order."""
+    return [(dt, tuple(int(d) for d in dims.split(",") if d))
+            for dt, dims in _ARG_RE.findall(shapes)]
+
+
+def _diff(old: Signature, new: Signature) -> List[str]:
+    if len(old) != len(new):
+        return [f"args: {len(old)} -> {len(new)}"]
+    changes: List[Tuple[int, str]] = []      # (argument, what changed)
+    for i, ((dt0, d0), (dt1, d1)) in enumerate(zip(old, new)):
+        if dt0 != dt1:
+            changes.append((i, f"dtype: {dt0} -> {dt1}"))
+        if len(d0) != len(d1):
+            changes.append((i, f"rank: {len(d0)} -> {len(d1)}"))
+            continue
+        changes.extend((i, f"dim {k}: {a} -> {b}")
+                       for k, (a, b) in enumerate(zip(d0, d1)) if a != b)
+    # one bucket edge moves a run of arguments alike: say it once
+    out: List[str] = []
+    runs: List[List] = []                    # [first, last, what]
+    for i, what in changes:
+        for run in runs:
+            if run[2] == what and run[1] == i - 1:
+                run[1] = i
+                break
+        else:
+            runs.append([i, i, what])
+    for first, last, what in runs:
+        out.append(f"arg {first} {what}" if first == last
+                   else f"args {first}-{last} {what}")
+    return out
+
+
+def signature_differs(seen: List[Signature], new: Signature) -> List[str]:
+    """Where ``new`` differs from the NEAREST signature in ``seen`` (the
+    one with the fewest differences): argument positions and dimensions,
+    e.g. ``arg 7 dim 0: 2048 -> 4096``, a run of arguments that changed
+    alike as ``args 69-75 dim 0: 4096 -> 8192``.  Empty when nothing was seen
+    before, or when an identical signature was (a recompile)."""
+    if not seen:
+        return []
+    return min((_diff(old, new) for old in seen), key=len)
 
 
 class CompileWatchdog(logging.Handler):
@@ -73,6 +126,15 @@ class CompileWatchdog(logging.Handler):
         self._lock = threading.Lock()
         self.counts: Dict[Tuple[str, str], int] = {}
         self.donation_mismatches: List[str] = []
+        # per program, the signatures seen (parsed), newest last
+        self._signatures: Dict[str, List[Signature]] = {}   # kubelint: guarded-by(_lock)
+        # what, how long and why, one dict per compile or cache load:
+        # program, kind ("compiled" | "cache-load"), seconds, t
+        # (wallclock() at the record), differs (signature_differs).
+        # Bounded; appended when the compile ENDS, so ``seconds`` is in
+        self.records: Deque[Dict[str, object]] = collections.deque(
+            maxlen=MAX_COMPILE_RECORDS)
+        self._pending = threading.local()
 
     # logging.Handler interface ----------------------------------------
     def emit(self, record: logging.LogRecord) -> None:
@@ -86,19 +148,65 @@ class CompileWatchdog(logging.Handler):
             # matching, the per-program tests) key on the bare program
             wrapped = _JIT_WRAPPER_RE.fullmatch(m.group(1))
             key = (wrapped.group(1) if wrapped else m.group(1), m.group(2))
+            sig = parse_signature(key[1])
             with self._lock:
                 self.counts[key] = self.counts.get(key, 0) + 1
-            # feed the flight recorder: a compile landing under a cycle's
-            # open span (dispatch, audit, wave) is exactly the event the
-            # recorder exists to attribute — no-op when disarmed
-            from .trace import note_compile_event
-            note_compile_event(*key)
+                seen = self._signatures.setdefault(key[0], [])
+                differs = signature_differs(seen, sig)
+                if sig not in seen:
+                    seen.append(sig)
+                    del seen[:-MAX_SIGNATURES_PER_PROGRAM]
+            # jax logs this record when lowering is done and the backend
+            # compile (or the cache load) is about to start, on the
+            # thread that called the program; the duration events that
+            # say how long it took, and which of the two it was, arrive
+            # on the same thread when it ends (note_duration)
+            from .trace import wallclock
+            self._flush_pending()
+            self._pending.record = {
+                "program": key[0], "kind": "compiled", "seconds": 0.0,
+                "t": wallclock(), "differs": differs, "shapes": key[1]}
             return
         if _DONATION_RE.search(msg):
             with self._lock:
                 self.donation_mismatches.append(msg)
             logging.getLogger("kubetpu.sanitize").warning(
                 "donation mismatch: %s", msg)
+
+    def note_duration(self, event: str, duration: float) -> None:
+        """jax.monitoring duration events, fed by the process's
+        CompileTimer: a cache retrieval marks the pending record a
+        ``cache-load``; the backend-compile event (fired on both paths,
+        last) closes it with its seconds."""
+        rec = getattr(self._pending, "record", None)
+        if rec is None:
+            return
+        if event == _CACHE_RETRIEVAL_EV:
+            rec["kind"] = "cache-load"
+        elif event == _COMPILE_DURATION_EV:
+            rec["seconds"] = float(duration)
+            self._flush_pending()
+
+    def _flush_pending(self) -> None:
+        """Publish this thread's pending record: onto ``records`` and, as
+        the flight event ``xla-compile``, onto the cycle open on this
+        thread (a compile landing under tensorize, dispatch, the audit
+        or the wave is exactly what the recorder exists to attribute;
+        disarmed or outside a cycle that half is a no-op).  A record
+        whose end was never seen (no compile timer installed, or the
+        compile raised) goes out with seconds 0."""
+        rec = getattr(self._pending, "record", None)
+        if rec is None:
+            return
+        self._pending.record = None
+        shapes = rec.pop("shapes")
+        self.records.append(rec)
+        if _watchdogs and _watchdogs[-1] is not self:
+            return      # one event a compile, from the newest one armed
+        from .trace import note_compile_event
+        note_compile_event(rec["program"], shapes, kind=rec["kind"],
+                           seconds=round(rec["seconds"], 6), t=rec["t"],
+                           differs=list(rec["differs"]))
 
     # warnings interface (jax emits donation mismatches via warnings.warn,
     # not logging — see enable_sanitizer's showwarning hook) -------------
@@ -133,6 +241,8 @@ class CompileWatchdog(logging.Handler):
         with self._lock:
             self.counts.clear()
             self.donation_mismatches.clear()
+            self._signatures.clear()
+        self.records.clear()
 
 
 class _SanitizerState:
@@ -155,6 +265,8 @@ _state_lock = threading.Lock()
 # hold _state_lock.
 _logger_armed: Set[int] = set()   # id()s of handlers _arm_pxla_logger attached
 _logger_prev: Optional[Tuple[int, bool]] = None
+# the armed watchdogs, for the compile timer to feed (note_duration)
+_watchdogs: List["CompileWatchdog"] = []
 
 
 def _arm_pxla_logger(handler: logging.Handler) -> None:
@@ -171,12 +283,18 @@ def _arm_pxla_logger(handler: logging.Handler) -> None:
             logger.propagate = False
     _logger_armed.add(id(handler))
     logger.addHandler(handler)
+    if isinstance(handler, CompileWatchdog):
+        _watchdogs.append(handler)
+        # its records take their kind and seconds from the timer's events
+        install_compile_timer()
 
 
 def _disarm_pxla_logger(handler: logging.Handler) -> None:
     global _logger_prev
     logger = logging.getLogger(_PXLA_LOGGER)
     logger.removeHandler(handler)
+    if handler in _watchdogs:
+        _watchdogs.remove(handler)
     # only handlers WE armed count toward the restore — an uninstall of a
     # shared watchdog handed out while the sanitizer was active (never
     # armed here) must not release someone else's arming
@@ -347,6 +465,12 @@ class CompileTimer:
                 self.backend_s += duration
             elif event == _CACHE_RETRIEVAL_EV:
                 self.cache_load_s += duration
+            else:
+                return
+        # the watchdog's record of this compile learns its kind and its
+        # seconds from the same two events
+        for wd in list(_watchdogs):
+            wd.note_duration(event, duration)
 
     def on_event(self, event: str, **kw) -> None:
         with self._lock:

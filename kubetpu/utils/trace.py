@@ -9,11 +9,13 @@ predicates done", "Prioritizing done", logged when the cycle exceeds
 On top of the reference's threshold log, this module is the structured
 observability layer: every ``Trace`` carries a span id, parent linkage and
 thread tag, and — when the flight recorder is ARMED — the full span tree
-of each scheduling cycle (prepare/tensorize steps, dispatch,
-packed-readback with device-wait attribution, commit, preemption wave,
-per-pod binds, recompile events fed by the sanitize watchdog, and the
-queue depths at cycle start) lands in a lock-guarded ring buffer of the
-last N cycles (``KUBETPU_FLIGHT_N``, default 64).  The ring serializes to
+of each scheduling cycle (the eight PHASES that partition the serving
+thread's cycle: pop, snapshot, prefilter, tensorize, host-masks,
+dispatch, packed-readback with device-wait attribution, commit; the
+utiltrace steps; preemption wave; the per-pod BIND TABLE; recompile
+events fed by the sanitize watchdog, and the queue depths at cycle
+start) lands in a lock-guarded ring buffer of the last N cycles
+(``KUBETPU_FLIGHT_N``, default 64).  The ring serializes to
 the Chrome ``traceEvents`` JSON format (one pid per component, one tid
 per thread, ``ph: "X"`` spans) loadable in Perfetto/chrome://tracing,
 alongside the existing ``jax.profiler`` XPlane capture.
@@ -31,6 +33,7 @@ the hot path is byte-identical to the pre-recorder behavior.
 
 from __future__ import annotations
 
+import array
 import collections
 import contextlib
 import logging
@@ -68,23 +71,31 @@ DEFAULT_FLIGHT_N = 64
 DEFAULT_FLIGHT_SPANS = 512
 
 # SURVEY §5: keep jax.profiler traces alongside the host-side step spans.
-# When a capture is active (capture_device_trace below, or
-# KUBETPU_PROFILE_DIR at import), every Trace phase also opens a
-# jax.profiler.TraceAnnotation so device ops group under the cycle phase
-# names in the TensorBoard/XProf timeline.
+# While a capture is active (capture_device_trace below), every PHASE of
+# the serving thread's cycle opens ONE jax.profiler.TraceAnnotation named
+# "Scheduling:<phase>" for exactly its own extent -- the phase that is
+# OPEN, never two at once -- so device-idle gaps can be charged to what
+# the host was doing, and every cycle drops a CLOCK_ANNOTATION carrying
+# wallclock(), which puts any flight-recorder stamp of any thread on the
+# profiler's timeline (offset = event start - wallclock_s).
 _PROFILE_ACTIVE = False
+CYCLE_TRACE = "Scheduling"
+CLOCK_ANNOTATION = "kubetpu.clock"
 
 
 @contextlib.contextmanager
-def capture_device_trace(log_dir: str):
+def capture_device_trace(log_dir: str, profiler_options=None):
     """Capture a jax.profiler trace (XPlane/TensorBoard format) for the
     enclosed serving activity — the TPU analog of the reference's pprof
-    endpoints (DebuggingConfiguration.EnableProfiling, SURVEY §5).  Host
-    Trace phases appear as TraceAnnotations inside the capture."""
+    endpoints (DebuggingConfiguration.EnableProfiling, SURVEY §5).  The
+    cycle's phases appear as TraceAnnotations inside the capture.
+    profiler_options: a ``jax.profiler.ProfileOptions`` handed to
+    ``start_trace`` (e.g. the Python tracer off for a serving-rate
+    capture)."""
     global _PROFILE_ACTIVE
     import jax
     os.makedirs(log_dir, exist_ok=True)
-    jax.profiler.start_trace(log_dir)
+    jax.profiler.start_trace(log_dir, profiler_options=profiler_options)
     _PROFILE_ACTIVE = True
     try:
         yield log_dir
@@ -99,6 +110,16 @@ def capture_device_trace(log_dir: str):
         ds = _devstats.devstats()
         if ds is not None:
             ds.ingest_xplane(log_dir)
+
+
+def _emit_clock(cycle: int) -> None:
+    """One short host event whose metadata is this process's wallclock()
+    at (within a microsecond of) the event's own start on the profiler's
+    clock."""
+    import jax
+    with jax.profiler.TraceAnnotation(CLOCK_ANNOTATION,
+                                      wallclock_s=wallclock(), cycle=cycle):
+        pass
 
 
 # --------------------------------------------------------------------- spans
@@ -127,6 +148,11 @@ class FlightSpan:
                 "t0": round(self.t0, 6),
                 "t1": round(self.t1 if self.t1 is not None else self.t0, 6),
                 "args": dict(self.args)}
+
+
+# columns of a CycleRecord's bind table
+BIND_SUBMITTED, BIND_STARTED, BIND_DONE = 0, 1, 2
+BIND_STAMPS = 3
 
 
 class _NullSpan:
@@ -160,10 +186,16 @@ def _span_stack() -> list:
 
 
 class CycleRecord:
-    """The span tree of ONE scheduling cycle.  Spans may be appended from
-    multiple threads (serving loop + binder pool), so the lists are
-    lock-guarded; the per-record span cap keeps a 4k-pod commit loop from
-    ballooning the record (drops are counted, never silent)."""
+    """The span tree of ONE scheduling cycle, plus its BIND TABLE.
+    Spans come from the serving thread and are few (the cycle's
+    structure); the lists are lock-guarded and capped all the same
+    (drops are counted, never silent).  What happens once per POD on
+    other threads -- the binds -- does not go through spans: it lands in
+    a preallocated table of three stamps a pod (submitted, started,
+    done, on wallclock()), written by row index from whichever thread
+    runs the bind.  No lock, no allocation: each cell has one writer,
+    and a reader that races a bind in flight sees a 0.0 (row not yet
+    complete), never a torn value."""
 
     def __init__(self, seq: int, label: str,
                  queue_depths: Optional[Dict[str, int]] = None,
@@ -182,6 +214,54 @@ class CycleRecord:
         self._next_id = 1                    # kubelint: guarded-by(_lock)
         self.span_drops = 0                  # kubelint: guarded-by(_lock)
         self.event_drops = 0                 # kubelint: guarded-by(_lock)
+        # bind table (alloc_binds): 3 doubles a pod, row = batch index
+        self._bind_t: Optional[array.array] = None
+        self._bind_thread: List[Optional[str]] = []
+
+    # -- bind table ---------------------------------------------------------
+
+    def alloc_binds(self, pods: int) -> None:
+        """Size the bind table for a batch of ``pods`` (the commit loop
+        calls this once, before the first submit)."""
+        self._bind_t = array.array("d", bytes(8 * BIND_STAMPS * pods))
+        self._bind_thread = [None] * pods
+
+    def stamp_bind(self, row: int, which: int) -> None:
+        """Stamp wallclock() into column ``which`` (BIND_SUBMITTED /
+        BIND_STARTED / BIND_DONE) of the pod's row; BIND_STARTED also
+        names the thread that runs the bind."""
+        tbl = self._bind_t
+        if tbl is None or not 0 <= row < len(self._bind_thread):
+            return
+        tbl[BIND_STAMPS * row + which] = wallclock()
+        if which == BIND_STARTED:
+            self._bind_thread[row] = threading.current_thread().name
+
+    def bind_rows(self) -> List[Tuple[float, float, float, Optional[str]]]:
+        """(submitted, started, done, thread) per pod of the batch, in
+        batch order; zeros where the pod was never submitted (it did not
+        place) or the stamp has not landed yet."""
+        tbl = self._bind_t
+        if tbl is None:
+            return []
+        return [(tbl[BIND_STAMPS * i], tbl[BIND_STAMPS * i + 1],
+                 tbl[BIND_STAMPS * i + 2], th)
+                for i, th in enumerate(self._bind_thread)]
+
+    def bind_spans(self) -> List[FlightSpan]:
+        """The COMPLETE rows of the bind table as ``bind`` spans (started
+        -> done, on the thread that ran them, ``queued_s`` = started -
+        submitted) for the span exports; ids continue past the recorded
+        spans'."""
+        with self._lock:
+            next_id = self._next_id
+        out = []
+        for i, (sub, start, done, th) in enumerate(self.bind_rows()):
+            if done > 0.0 and start > 0.0:
+                out.append(FlightSpan(
+                    next_id + i, 1, "bind", th or "", start, done,
+                    args={"row": i, "queued_s": round(start - sub, 6)}))
+        return out
 
     # -- recording ----------------------------------------------------------
 
@@ -270,7 +350,12 @@ class CycleRecord:
                 "queue_depths": dict(self.queue_depths),
                 "meta": dict(self.meta),
                 "span_drops": drops, "event_drops": ev_drops,
-                "spans": spans, "events": events}
+                "spans": spans, "events": events,
+                # one row a pod of the batch, batch order (meta
+                # batch_pods names them): [submitted, started, done,
+                # thread]
+                "binds": [[round(a, 6), round(b, 6), round(c, 6), th]
+                          for a, b, c, th in self.bind_rows()]}
 
 
 class FlightRecorder:
@@ -350,7 +435,7 @@ class FlightRecorder:
         t_base = recs[0].t0 if recs else 0.0
         spans = []
         for rec in recs:
-            for s in rec.spans():
+            for s in rec.spans() + rec.bind_spans():
                 if s.t1 is None:
                     continue
                 spans.append({
@@ -466,7 +551,7 @@ class FlightRecorder:
                                "ts": us(rec.t0),
                                "args": {k: int(v) for k, v
                                         in rec.queue_depths.items()}})
-            for s in rec.spans():
+            for s in rec.spans() + rec.bind_spans():
                 if s.t1 is None:
                     continue   # open span: excluded like to_pipeline_doc
                 comp = self._component_of(s.thread)
@@ -562,12 +647,116 @@ def note_instant(name: str, **args) -> None:
     rec.event(name, parent_id=parent.span_id if parent else 0, **args)
 
 
-def note_compile_event(program: str, shapes: str) -> None:
-    """Sanitize-watchdog hook: record an XLA (re)compile as an instant
-    event on the cycle currently open on this thread (compiles triggered
-    by a cycle's dispatch happen under its dispatch span).  Disarmed or
-    outside a cycle this is a no-op."""
-    note_instant("xla-compile", program=program, shapes=shapes[:512])
+def note_compile_event(program: str, shapes: str, **what) -> None:
+    """Sanitize-watchdog hook: record an XLA compile or cache load as the
+    instant event ``xla-compile`` on the cycle currently open on this
+    thread (it happens under the phase that called the program).  what:
+    ``kind``, ``seconds``, ``t``, ``differs`` (utils/sanitize.py).
+    Disarmed or outside a cycle this is a no-op."""
+    note_instant("xla-compile", program=program, shapes=shapes[:512],
+                 **what)
+
+
+# -------------------------------------------------------------------- phases
+#
+# The serving thread's cycle is ONE flat partition into phases -- pop,
+# snapshot, prefilter, tensorize, host-masks, dispatch, packed-readback,
+# commit -- each a child span of the cycle's root with its wall extent
+# and ``cpu_s`` (the thread's CPU seconds over it: wall - cpu is time the
+# thread was blocked on the GIL, a lock or the device), and, while a
+# profiler capture is active, ONE "Scheduling:<phase>" TraceAnnotation of
+# exactly that extent.  Opening a phase closes whichever phase this
+# thread still has open, so two are never open at once (the trace
+# reduction sums idle time per annotation name; nesting would count a
+# gap twice).
+
+
+class _Phase:
+    """An open phase.  As a context manager it yields the FlightSpan (or
+    None: recorder disarmed, or past the span cap) so the caller can
+    attach args before the exit closes it."""
+
+    __slots__ = ("name", "rec", "span", "ann", "t0", "t1", "cpu0", "cpu_s",
+                 "args", "closed")
+
+    def __init__(self, name: str, ann: str, rec: Optional[CycleRecord],
+                 parent_id: int, args: Dict[str, Any]):
+        self.name, self.rec, self.args = name, rec, args
+        self.span = self.ann = self.cpu0 = None
+        self.t0 = self.t1 = self.cpu_s = 0.0
+        self.closed = False
+        if _flight is not None or rec is not None:
+            self.cpu0 = time.thread_time()
+            self.t0 = wallclock()
+        if rec is not None:
+            self.span = rec.begin_span(name, parent_id=parent_id,
+                                       t0=self.t0, **args)
+            # nested spans and instants (a compile under tensorize, a
+            # fence under dispatch) parent under the open phase
+            _span_stack().append((rec, self.span))
+        if _PROFILE_ACTIVE:
+            import jax
+            self.ann = jax.profiler.TraceAnnotation(f"{CYCLE_TRACE}:{ann}")
+            self.ann.__enter__()
+
+    def __enter__(self):
+        return self.span
+
+    def __exit__(self, *exc):
+        self.close()
+        return False
+
+    def close(self) -> None:
+        if self.closed:
+            return
+        self.closed = True
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+            self.ann = None
+        if self.cpu0 is not None:
+            self.cpu_s = time.thread_time() - self.cpu0
+            self.t1 = wallclock()
+        if self.rec is not None:
+            try:
+                _span_stack().remove((self.rec, self.span))
+            except ValueError:
+                pass        # closed from another thread: its stack, not ours
+            if self.span is not None:
+                self.span.args["cpu_s"] = round(self.cpu_s, 6)
+                self.span.t1 = self.t1
+        if getattr(_tls, "phase", None) is self:
+            _tls.phase = None
+
+
+def _open_phase(name: str, ann: str, rec: Optional[CycleRecord],
+                parent_id: int, args: Dict[str, Any]) -> _Phase:
+    """Close the phase this thread has open, open the next."""
+    cur = getattr(_tls, "phase", None)
+    if cur is not None:
+        cur.close()
+    _tls.phase = ph = _Phase(name, ann, rec, parent_id, args)
+    return ph
+
+
+def begin_pop():
+    """The ``pop`` phase -- the previous cycle's teardown (its outcomes
+    and per-pod states freed), the serving loop, the queue pop, the
+    per-pod skip check, grouping by profile -- which runs BEFORE the
+    cycle's Trace exists.  ``Trace.finish()`` opens it as it closes a
+    cycle's last phase, so the partition has no hole between two cycles;
+    here the caller picks that one up (``teardown_s``: how long ago it
+    opened) or, on a thread that has just started, opens one.  Returns
+    None when neither the recorder nor a capture is on (no clock read, no
+    allocation); otherwise the caller hands the phase to the ``Trace`` of
+    the cycle the pop fed (``pop=``), which closes and records it."""
+    if _flight is None and not _PROFILE_ACTIVE:
+        return None
+    cur = getattr(_tls, "phase", None)
+    if cur is not None and cur.name == "pop" and cur.rec is None:
+        if cur.cpu0 is not None:
+            cur.args["teardown_s"] = round(wallclock() - cur.t0, 6)
+        return cur
+    return _open_phase("pop", "pop", None, 0, {})
 
 
 # --------------------------------------------------------------------- Trace
@@ -577,22 +766,25 @@ class Trace:
     """The per-cycle step trace (reference: utiltrace.Trace) — now also
     the flight recorder's cycle handle: when the recorder is armed at
     construction, the Trace owns a CycleRecord, carries a span id, parent
-    linkage and thread tag, and every ``step()`` interval becomes a child
-    span.  Disarmed, nothing beyond the original step list is touched."""
+    linkage and thread tag, every ``step()`` interval becomes a child
+    span under its upstream message, and every ``phase()`` a child span
+    of the cycle's partition.  Disarmed, nothing beyond the original step
+    list is touched."""
 
     def __init__(self, name: str, parent: Optional["Trace"] = None,
-                 queue_depths: Optional[Dict[str, int]] = None, **fields):
+                 queue_depths: Optional[Dict[str, int]] = None,
+                 pop: Optional[_Phase] = None, **fields):
         self.name = name
         self.fields = fields
         self.start = wallclock()
         self.steps: List[Tuple[float, str]] = []
         self.thread = threading.current_thread().name
-        self._ann = None
-        self._closed = False
         # flight recorder linkage (no lock taken when disarmed: _flight is
         # read once; None short-circuits everything below)
         fr = _flight
         self._fr = fr
+        if pop is not None:
+            pop.close()
         self.rec: Optional[CycleRecord] = None
         self._root: Optional[FlightSpan] = None
         self.span_id = 0
@@ -604,21 +796,15 @@ class Trace:
                                              parent_id=self.parent_id)
             if self._root is not None:
                 self.span_id = self._root.span_id
+            if pop is not None and pop.cpu0 is not None:  # armed when opened
+                # the pop that fed this cycle, stamped before the record
+                # existed
+                self.rec.record_span("pop", pop.t0, pop.t1,
+                                     parent_id=self.span_id,
+                                     cpu_s=round(pop.cpu_s, 6), **pop.args)
         self._last_mark = self.start
         if _PROFILE_ACTIVE:
-            self._open_annotation("begin")
-
-    def _close_annotation(self) -> None:
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
-
-    def _open_annotation(self, label: str) -> None:
-        import jax
-        self._close_annotation()
-        if _PROFILE_ACTIVE:
-            self._ann = jax.profiler.TraceAnnotation(f"{self.name}:{label}")
-            self._ann.__enter__()
+            _emit_clock(self.rec.seq if self.rec is not None else 0)
 
     def step(self, msg: str) -> None:
         now = wallclock()
@@ -628,13 +814,30 @@ class Trace:
             self.rec.record_span(msg, self._last_mark, now,
                                  parent_id=self.span_id)
         self._last_mark = now
-        if self._ann is not None or _PROFILE_ACTIVE:
-            self._open_annotation(msg)
+
+    def phase(self, name: str, ann: Optional[str] = None, **args):
+        """Open the next phase of the cycle's partition (see "phases"
+        above), closing the one this thread had open.  Use as a context
+        manager, or call it bare and let the next ``phase()`` or
+        ``finish()`` close it.  ann: the annotation's
+        name where it differs from the span's.  Neither recorder nor
+        capture on: the shared no-op, zero allocation, zero locks."""
+        if self.rec is None and not _PROFILE_ACTIVE:
+            return _NULL_SPAN
+        return _open_phase(name, ann or name, self.rec, self.span_id, args)
+
+    @staticmethod
+    def note(**args) -> None:
+        """Attach args to the span of the phase this thread has open (a
+        no-op with the recorder disarmed)."""
+        cur = getattr(_tls, "phase", None)
+        if cur is not None and cur.span is not None:
+            cur.span.args.update(args)
 
     def stage(self, name: str, **args):
-        """Scoped child span for a cycle stage (dispatch, commit,
-        preemption wave...).  Returns a no-op context when disarmed —
-        zero allocation, zero locks."""
+        """Scoped child span INSIDE a phase (preemption wave, decision
+        audit...): no annotation.  Returns a no-op context when disarmed
+        — zero allocation, zero locks."""
         if self.rec is None:
             return _NULL_SPAN
         return self.rec.span(name, parent_id=self.span_id, **args)
@@ -646,19 +849,25 @@ class Trace:
         away)."""
         rec, fr = self.rec, self._fr
         self.rec = None
-        if rec is None or fr is None:
-            return
-        if meta:
-            rec.meta.update(meta)
-        CycleRecord.end_span(self._root)
-        rec.t1 = wallclock()
-        fr.commit_cycle(rec)
+        if rec is not None and fr is not None:
+            if meta:
+                rec.meta.update(meta)
+            CycleRecord.end_span(self._root)
+            rec.t1 = wallclock()
+            fr.commit_cycle(rec)
+        # the phase still open is this cycle's last (commit, when the
+        # cycle ran to its end): closed here, it takes in the hand-over
+        # of the record too, and the thread's next pop phase opens at
+        # once -- what follows (the cycle's teardown, the serving loop)
+        # is on the way to the next pop
+        cur = getattr(_tls, "phase", None)
+        if cur is not None and cur.name != "pop" \
+                and (cur.rec is rec or rec is None):
+            cur.close()
+            begin_pop()
 
     def __del__(self):
-        # last-resort close so an early-return cycle can never leak an
-        # entered TraceAnnotation into the rest of the capture
-        self._close_annotation()
-        # ...and a cycle that unwound on an exception still commits its
+        # a cycle that unwound on an exception still commits its
         # record: the crashing cycle is exactly the one the flight
         # recorder exists to capture (CPython refcounting runs this as
         # the serving loop's except-and-continue drops the cycle state)
@@ -672,7 +881,6 @@ class Trace:
         return wallclock() - self.start
 
     def log_if_long(self, threshold: float = SLOW_CYCLE_THRESHOLD) -> Optional[str]:
-        self._close_annotation()
         total = self.total()
         if total < threshold:
             return None

@@ -1,0 +1,400 @@
+"""The readers of the program's span tree (perfbench/lib/spans.py and the
+thirteen metric files of PR 26): each on cycle records whose answers are
+worked out by hand, the clock offset and ``readback_wake`` on a synthetic
+capture and on the small one recorded on a TPU v5e, and all of them --
+with the seven older metrics that need no device plane -- through a whole
+traced run of the toy cells."""
+
+import json
+import os
+import statistics
+from types import SimpleNamespace
+
+import pytest
+
+import perfbench_toy
+from perfbench.lib import drive, spans, spec
+
+REPO = perfbench_toy.REPO
+TESTDATA = os.path.join(REPO, "perfbench", "testdata")
+CELLS = ["sp-basic-5000.saturated", "sp-antiaffinity-5000.saturated"]
+NEW = {
+    "pop_ms_per_cycle.sat": ("queue", "program_span"),
+    "queue_empty_wait_ms_per_cycle.sat": ("queue", "program_span"),
+    "snapshot_ms_per_cycle.sat": ("prepare", "program_span"),
+    "term_refresh_ms_per_cycle.sat": ("prepare", "program_span"),
+    "commit_plugins_ms_per_cycle.sat": ("commit and bind", "program_span"),
+    "commit_assume_ms_per_cycle.sat": ("commit and bind", "program_span"),
+    "commit_submit_ms_per_cycle.sat": ("commit and bind", "program_span"),
+    "commit_blocked_pct.sat": ("commit and bind", "program_span"),
+    "bind_queue_wait_p95_ms.sat": ("commit and bind", "program_span"),
+    "bind_exec_p95_ms.sat": ("commit and bind", "program_span"),
+    "bind_done_lag_p95_ms.sat": ("commit and bind", "program_span"),
+    "readback_wake_ms_per_cycle.sat": ("readback", "device_trace"),
+    "window_compile_stall_ms.sat": ("compile", "program_counter"),
+}
+OLD = ["generator_late_p95_ms.sat", "prepare_ms_per_cycle.sat",
+       "auction_device_ms_per_cycle.sat", "auction_roofline",
+       "readback_wait_ms_per_cycle.sat", "commit_ms_per_cycle.sat",
+       "window_compiles.sat"]
+
+
+def _span(name, t0, t1, **args):
+    return {"id": 0, "parent": 1, "name": name, "thread": "serving",
+            "t0": t0, "t1": t1, "args": args}
+
+
+def _cycle(t, terms_ms=0.0, compile_s=None, binds=()):
+    """One cycle starting at t (seconds): pop 40 ms of which 10 waiting,
+    snapshot 100, tensorize 200 (holding a delta-build and, with
+    terms_ms, a delta-terms), readback 50 ending at t + 0.45, commit 500
+    of which the thread ran 300."""
+    sp = [_span("pop", t - 0.04, t, wait_s=0.01, cpu_s=0.03),
+          _span("snapshot", t, t + 0.1, cpu_s=0.1),
+          _span("tensorize", t + 0.1, t + 0.3, cpu_s=0.2),
+          _span("delta-build", t + 0.1, t + 0.25),
+          _span("dispatch", t + 0.3, t + 0.4, cpu_s=0.1),
+          _span("packed-readback", t + 0.4, t + 0.45, device_wait_s=0.04,
+                cpu_s=0.001),
+          _span("commit", t + 0.45, t + 0.95, cpu_s=0.3, recheck_s=0.01,
+                reserve_s=0.02, assume_s=0.1, permit_s=0.03,
+                submit_s=0.2, records_s=0.05, pods=4, loop_s=0.42)]
+    if terms_ms:
+        sp.append(_span("delta-terms", t + 0.12, t + 0.12 + terms_ms / 1e3))
+    events = []
+    if compile_s is not None:
+        events.append({"name": "xla-compile", "ts": t + 0.31, "parent": 5,
+                       "thread": "serving",
+                       "args": {"program": "_apply_cluster_delta",
+                                "kind": "cache-load",
+                                "seconds": compile_s, "differs":
+                                ["arg 54 dim 0: 1024 -> 2048"]}})
+    return {"seq": 1, "t0": t, "t1": t + 0.95, "spans": sp,
+            "events": events, "meta": {},
+            "binds": [list(b) for b in binds]}
+
+
+def _ctx(cycles, platform="cpu", root=REPO):
+    return SimpleNamespace(cycles=cycles, device={"platform": platform},
+                           cell=SimpleNamespace(root=root))
+
+
+# binds of the first cycle (readback ends at 0.45): waits 0.1 / 0.2 / 0.3 /
+# 0.4 s in the pool, runs 10 / 20 / 30 / 40 ms; one row never submitted
+# (the pod did not place), one still running when the record was read
+BINDS = [(0.5, 0.6, 0.61, "binder_0"), (0.5, 0.7, 0.72, "binder_1"),
+         (0.5, 0.8, 0.83, "binder_0"), (0.5, 0.9, 0.94, "binder_2"),
+         (0.0, 0.0, 0.0, None), (0.5, 0.95, 0.0, "binder_1")]
+TWO = [_cycle(0.0, terms_ms=150.0, compile_s=0.45, binds=BINDS),
+       _cycle(1.0)]
+WANT = {
+    "pop_ms_per_cycle.sat": 30.0,
+    "queue_empty_wait_ms_per_cycle.sat": 10.0,
+    "snapshot_ms_per_cycle.sat": 100.0,
+    "term_refresh_ms_per_cycle.sat": 75.0,       # 150 and 0
+    "commit_plugins_ms_per_cycle.sat": 60.0,
+    "commit_assume_ms_per_cycle.sat": 100.0,
+    "commit_submit_ms_per_cycle.sat": 200.0,
+    "commit_blocked_pct.sat": 40.0,
+    "bind_queue_wait_p95_ms.sat": 400.0,
+    "bind_exec_p95_ms.sat": 40.0,
+    "bind_done_lag_p95_ms.sat": 490.0,           # 0.94 - 0.45
+    "readback_wake_ms_per_cycle.sat": None,      # off the chip
+    "window_compile_stall_ms.sat": 450.0,
+}
+
+
+def _reader(name):
+    return spec.cell(CELLS[0], REPO).readers()[name]
+
+
+@pytest.mark.parametrize("name", sorted(NEW))
+def test_a_reader_on_cycles_worked_out_by_hand(name):
+    got = _reader(name)(_ctx(TWO))
+    if WANT[name] is None:
+        assert got is None
+    else:
+        assert got == pytest.approx(WANT[name], rel=1e-9)
+
+
+@pytest.mark.parametrize("name", sorted(NEW))
+def test_a_reader_finds_nothing_in_a_program_without_the_spans(name):
+    """The parent of PR 26 records the root, the steps, dispatch,
+    packed-readback and commit, with no args but device_wait_s and no
+    bind table: every new reader returns None there, and none raises."""
+    old = {"seq": 1, "t0": 0.0, "t1": 1.0, "meta": {}, "events": [
+        {"name": "xla-compile", "ts": 0.3, "parent": 0, "thread": "t",
+         "args": {"program": "p", "shapes": "(...)"}}],
+        "spans": [_span("dispatch", 0.3, 0.4),
+                  _span("packed-readback", 0.4, 0.45, device_wait_s=0.04),
+                  _span("commit", 0.45, 0.95),
+                  _span("bind", 0.5, 0.6, pod="p", node="n")]}
+    assert _reader(name)(_ctx([old], platform="tpu",
+                              root="/nonexistent")) is None
+    assert _reader(name)(_ctx([])) is None
+
+
+def test_benchmark_json_names_the_new_metrics_and_touches_no_old_entry():
+    bench = spec.load_benchmark(REPO)
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    assert [m["name"] for m in bench["per_layer"]][:7] == OLD
+    assert [m["name"] for m in bench["per_layer"]][7:] == list(NEW)
+    for name, (layer, source) in NEW.items():
+        m = by_name[name]
+        assert (m["layer"], m["source"], m["moves"], m["workloads"]) == (
+            layer, source, "pods_bound_per_s", CELLS)
+        assert m["better"] == "lower"
+        assert m["unit"] == ("%" if name.endswith("_pct.sat") else "ms")
+    for cell in CELLS:
+        assert set(spec.cell(cell, REPO).readers()) == set(OLD) | set(NEW)
+
+
+# ------------------------------------------------------ the shared clock
+
+# host: kubetpu.clock events at 1.000 / 2.000 / 3.0001 s on the profiler's
+#   clock saying wallclock_s = 101.000 / 102.000 / 103.000: offsets -100,
+#   -100, -99.9999 -> median -100, spread 100 us
+# device 0: two executions of jit__schedule_gang, [1.30,1.40) whose last
+#   op ends at 1.38, and [2.30,2.45) whose last op ends at 2.42; one of
+#   another program between them
+# cycles (wallclock): dispatch from 101.25 / 102.25, readback ending at
+#   101.385 / 102.43 -> on the profiler's clock 1.385 / 2.43: the serving
+#   thread is back 5 ms / 10 ms after the device's last operation
+CLOCKED = """
+planes {
+  name: "/device:TPU:0"
+  lines { name: "XLA Ops" timestamp_ns: 0
+    events { metadata_id: 1 offset_ps: 1300000000000 duration_ps: 50000000000 }
+    events { metadata_id: 1 offset_ps: 1360000000000 duration_ps: 20000000000 }
+    events { metadata_id: 1 offset_ps: 1800000000000 duration_ps: 10000000000 }
+    events { metadata_id: 1 offset_ps: 2300000000000 duration_ps: 120000000000 }
+  }
+  lines { name: "XLA Modules" timestamp_ns: 0
+    events { metadata_id: 3 offset_ps: 1300000000000 duration_ps: 100000000000 }
+    events { metadata_id: 4 offset_ps: 1800000000000 duration_ps: 10000000000 }
+    events { metadata_id: 3 offset_ps: 2300000000000 duration_ps: 150000000000 }
+  }
+  event_metadata { key: 1 value { id: 1 name: "fusion.1" } }
+  event_metadata { key: 3 value { id: 3 name: "jit__schedule_gang(123)" } }
+  event_metadata { key: 4 value { id: 4 name: "jit__apply_cluster_delta(9)" } }
+}
+planes {
+  name: "/host:CPU"
+  lines { name: "kubetpu-scheduler" timestamp_ns: 0
+    events { metadata_id: 1 offset_ps: 1000000000000 duration_ps: 1000000
+      stats { metadata_id: 1 double_value: 101.0 }
+      stats { metadata_id: 2 int64_value: 7 } }
+    events { metadata_id: 1 offset_ps: 2000000000000 duration_ps: 1000000
+      stats { metadata_id: 1 double_value: 102.0 }
+      stats { metadata_id: 2 int64_value: 8 } }
+    events { metadata_id: 1 offset_ps: 3000100000000 duration_ps: 1000000
+      stats { metadata_id: 1 double_value: 103.0 }
+      stats { metadata_id: 2 int64_value: 9 } }
+    events { metadata_id: 2 offset_ps: 1250000000000 duration_ps: 100000000000 }
+  }
+  event_metadata { key: 1 value { id: 1 name: "kubetpu.clock" } }
+  event_metadata { key: 2 value { id: 2 name: "Scheduling:dispatch" } }
+  stat_metadata { key: 1 value { id: 1 name: "wallclock_s" } }
+  stat_metadata { key: 2 value { id: 2 name: "cycle" } }
+}
+"""
+
+
+def _clocked_cycles():
+    out = []
+    for t, back in ((101.0, 101.385), (102.0, 102.43)):
+        out.append({"seq": 1, "t0": t, "t1": t + 0.9, "meta": {},
+                    "events": [], "spans": [
+                        _span("dispatch", t + 0.25, t + 0.3),
+                        _span("packed-readback", t + 0.3, back,
+                              device_wait_s=0.08)]})
+    return out
+
+
+def test_the_clock_offset_and_the_wake_up_on_a_synthetic_capture():
+    from jax.profiler import ProfileData
+    pd = ProfileData.from_text_proto(CLOCKED)
+    clock = spans.clock_offset(pd)
+    assert clock["n"] == 3
+    assert clock["offset"] == pytest.approx(-100.0, abs=1e-9)
+    assert clock["spread_s"] == pytest.approx(1e-4, rel=1e-6)
+    from perfbench.lib import xplane
+    assert spans.device_ends(xplane.planes(pd)) == [
+        (pytest.approx(1.30), pytest.approx(1.38)),
+        (pytest.approx(2.30), pytest.approx(2.42))]
+    assert spans.readback_wake_ms(_clocked_cycles(), pd) == pytest.approx(
+        7.5, rel=1e-6)
+    # a cycle outside the capture, and a capture without clock events
+    far = _clocked_cycles()[:1]
+    far[0]["spans"] = [_span("dispatch", 50.0, 50.1),
+                       _span("packed-readback", 50.1, 50.2)]
+    assert spans.readback_wake_ms(far, pd) is None
+    bare = ProfileData.from_text_proto(
+        CLOCKED.replace("kubetpu.clock", "something.else"))
+    assert spans.clock_offset(bare) is None
+    assert spans.readback_wake_ms(_clocked_cycles(), bare) is None
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    """The capture recorded on a TPU v5e with
+    perfbench/tools/record_clock_trace.py: four cycles of the program's
+    own tracing around a small ``schedule_gang`` program."""
+    from perfbench.lib import xplane
+    with open(os.path.join(TESTDATA, "v5e_clock.cycles.json")) as f:
+        cycles = json.load(f)
+    with open(os.path.join(TESTDATA, "v5e_clock.expected.json")) as f:
+        want = json.load(f)
+    return xplane.load(os.path.join(TESTDATA, "v5e_clock.xplane.pb")), \
+        cycles, want
+
+
+def test_the_recorded_capture_shares_one_clock_with_its_cycles(recorded):
+    pd, cycles, want = recorded
+    clock = spans.clock_offset(pd)
+    assert clock["n"] == len(cycles) == 4
+    assert clock["offset"] == pytest.approx(want["clock"]["offset"],
+                                            abs=1e-9)
+    # the events agree on the offset to well under the wake-up they are
+    # there to measure
+    assert 0.0 <= clock["spread_s"] < 100e-6
+    wake = spans.readback_wake_ms(cycles, pd)
+    assert wake == pytest.approx(want["readback_wake_ms"], rel=1e-6)
+    # the serving thread cannot be back before the device is done, and on
+    # an idle host it is back within a few milliseconds
+    assert 0.0 < wake < 20.0
+
+
+def test_the_recorded_captures_idle_gaps_carry_the_open_phases(recorded):
+    from perfbench.lib import xplane
+    pd, cycles, want = recorded
+    got = xplane.summarize(pd)
+    gaps = dict(got["idle_gaps"])
+    assert gaps.keys() == dict(want["idle_gaps"]).keys()
+    named = {k for k in gaps if k != xplane.IDLE_LABEL}
+    assert named <= {"Scheduling:pop", "Scheduling:snapshot",
+                     "Scheduling:tensorize", "Scheduling:dispatch",
+                     "Scheduling:readback", "Scheduling:commit"}
+    assert {"Scheduling:commit", "Scheduling:tensorize"} <= named
+    # the annotations are the phases' own extents: per cycle each phase's
+    # annotation lasts what its span lasted, to the clock's error
+    tree = xplane.planes(pd)
+    commits = [e for e in xplane.host_phases(tree, "Scheduling:commit")]
+    assert len(commits) == len(cycles)
+    off = spans.clock_offset(pd)["offset"]
+    for (_, s, e), c in zip(commits, cycles):
+        sp = next(x for x in c["spans"] if x["name"] == "commit")
+        assert s == pytest.approx(sp["t0"] + off, abs=2e-4)
+        assert e == pytest.approx(sp["t1"] + off, abs=2e-4)
+
+
+# ---------------------------------------------- a whole traced run, toy
+
+
+@pytest.fixture(scope="module")
+def toy_traced(tmp_path_factory):
+    """The toy anti-affinity cell with every metric of the real cells
+    listed for it too (an edit to the COPY's BENCHMARK.json), run traced
+    through the whole of drive.run_cell on the CPU."""
+    root = perfbench_toy.make_root(str(tmp_path_factory.mktemp("toyspans")))
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    for m in bench["per_layer"]:
+        if m["name"] in NEW or m["name"] in OLD:
+            m["workloads"].append("toy-anti-96.closed")
+    with open(path, "w") as f:
+        json.dump(bench, f)
+    cell = spec.cell("toy-anti-96.closed", root)
+    said = []
+    kept = {}
+
+    def keep(**kw):          # what run_cell hands the readers as ctx
+        kept.update(kw)
+        return SimpleNamespace(**kw)
+    from kubetpu.utils import sanitize
+    armed = list(sanitize._watchdogs)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(drive, "SimpleNamespace", keep)
+        try:
+            res = drive.run_cell(cell, seed=2 ** 31 + 26, seconds=3.0,
+                                 trace=True, require_tpu=False,
+                                 out=said.append)
+        finally:
+            # a run is a process's whole life and never takes its compile
+            # watchdog off; a test process lives on
+            for wd in list(sanitize._watchdogs):
+                if wd not in armed:
+                    sanitize.uninstall_compile_watchdog(wd)
+    return res, kept, "\n".join(said)
+
+
+def test_a_traced_toy_run_fills_the_old_and_the_new_metrics(toy_traced):
+    res, ctx, said = toy_traced
+    assert res["correct"] is True, said
+    got = res["metrics"]
+    # everything that needs no device plane, old and new, has a number
+    off_chip = {"auction_device_ms_per_cycle.sat", "auction_roofline",
+                "readback_wake_ms_per_cycle.sat"}
+    assert set(got) == (set(OLD) | set(NEW) | {"toy_cycles"}) - off_chip
+    for name in ("prepare_ms_per_cycle.sat", "commit_ms_per_cycle.sat",
+                 "readback_wait_ms_per_cycle.sat", "pop_ms_per_cycle.sat",
+                 "snapshot_ms_per_cycle.sat",
+                 "commit_assume_ms_per_cycle.sat",
+                 "commit_submit_ms_per_cycle.sat",
+                 "bind_exec_p95_ms.sat", "bind_done_lag_p95_ms.sat"):
+        assert got[name]["value"] > 0, name
+    assert got["queue_empty_wait_ms_per_cycle.sat"]["value"] >= 0
+    assert 0 <= got["commit_blocked_pct.sat"]["value"] < 100
+    # every pod anti-affine: the term refresh runs every delta cycle
+    assert got["term_refresh_ms_per_cycle.sat"]["value"] > 0
+    # the split stays inside the stage it splits
+    commit = got["commit_ms_per_cycle.sat"]["value"]
+    parts = sum(got[n]["value"] for n in (
+        "commit_plugins_ms_per_cycle.sat", "commit_assume_ms_per_cycle.sat",
+        "commit_submit_ms_per_cycle.sat"))
+    assert 0 < parts <= commit * 1.001
+    assert got["snapshot_ms_per_cycle.sat"]["value"] \
+        < got["prepare_ms_per_cycle.sat"]["value"]
+
+
+def test_every_in_window_cycle_of_the_toy_run_keeps_its_structure(
+        toy_traced):
+    res, ctx, said = toy_traced
+    cycles = ctx["cycles"]
+    assert len(cycles) >= 3
+    shares = []
+    for c, nxt in zip(cycles, cycles[1:]):
+        assert c["span_drops"] == 0 and len(c["spans"]) <= 32
+        ph = {s["name"]: s for s in c["spans"] if s["name"] in spans.PHASES}
+        assert set(ph) == set(spans.PHASES)
+        covered = sum(s["t1"] - s["t0"] for s in ph.values())
+        nxt_pop = next(s for s in nxt["spans"] if s["name"] == "pop")
+        shares.append(covered / (nxt_pop["t0"] - ph["pop"]["t0"]))
+        a = ph["commit"]["args"]
+        assert (a["recheck_s"] + a["reserve_s"] + a["assume_s"]
+                + a["permit_s"] + a["submit_s"] + a["records_s"]
+                ) >= 0.9 * a["loop_s"]
+    # a toy cycle lasts milliseconds with 16 binder threads and the
+    # client after the interpreter: one hand-over between two phases is
+    # a tenth of it (tests/test_trace_phases.py holds a quiet cycle to
+    # 98%, a run on the chip holds the real size to it)
+    assert statistics.median(shares) >= 0.8
+
+
+def test_the_toy_runs_bind_tables_hold_every_bind_the_client_saw(
+        toy_traced):
+    res, ctx, said = toy_traced
+    cl = ctx["client"]
+    rows = {}
+    for c in ctx["cycles"]:
+        assert len(c["binds"]) == len(c["meta"]["batch_pods"])
+        for name, row in zip(c["meta"]["batch_pods"], c["binds"]):
+            if row[0] > 0:
+                assert row[0] <= row[1] <= row[2], (name, row)
+                rows[name] = row
+    placed = {name for c in ctx["cycles"]
+              for name in c["meta"]["batch_pods"]}
+    seen = placed & set(cl.bound_t)
+    assert len(seen) >= 16
+    assert seen <= set(rows)

@@ -99,8 +99,13 @@ def test_span_tree_linkage_and_threads(flight):
         ids = {s.span_id for s in spans}
         assert all(s.parent_id in ids for s in spans if s.parent_id)
         assert all(s.thread for s in spans)
-        # bind spans ride the cycle record too (sync binding: same thread)
-        assert sum(1 for s in spans if s.name == "bind") == 3
+        # binds ride the cycle record too, as rows of its bind table
+        # (sync binding: same thread), and render as spans in the exports
+        rows = [r for r in rec.bind_rows() if r[0] > 0.0]
+        assert len(rows) == 3
+        assert all(sub <= start <= done and th == root[0].thread
+                   for sub, start, done, th in rows)
+        assert sum(1 for s in rec.bind_spans() if s.name == "bind") == 3
     finally:
         sched.close()
 
