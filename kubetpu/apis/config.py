@@ -131,9 +131,10 @@ class KubeSchedulerConfiguration:
     # server must not cost a placement the cycle already won.  Each retry
     # first checks whether the bind landed server-side (bind is not
     # idempotent; a lost response must not re-POST into a Conflict).
-    # Retries run on whichever thread ran bind: the binder pool under
-    # async binding (the default), the serving loop under sync binding —
-    # where each failing pod can stall it for the summed backoff.
+    # Retries sleep on a binder pool thread under async binding (the
+    # default; never on the binder lane, which hands such a bind to the
+    # pool), on the serving loop under sync binding — where each failing
+    # pod can stall it for the summed backoff.
     bind_retries: int = 2
     mesh_shape: Optional[tuple] = None
     # Cycle chaining (gang mode): reuse the auction's materialized cluster

@@ -237,6 +237,8 @@ class RestClusterStore(ClusterStore):
     model — the scheduler's assume/ForgetPod protocol bridges the gap,
     cache.go:338)."""
 
+    in_process = False      # every write is an HTTP round trip
+
     def __init__(self, base_url: str):
         super().__init__()
         self.base_url = base_url.rstrip("/")

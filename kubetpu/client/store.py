@@ -41,6 +41,12 @@ class NotFound(Exception):
 
 
 class ClusterStore:
+    # a write returns once it is applied and its watch events delivered,
+    # in the caller's thread: nothing here waits on a network.  The
+    # scheduler's bind hand-over reads this (Scheduler._commit); a
+    # subclass whose writes leave the process says False
+    in_process = True
+
     def __init__(self):
         self._lock = threading.RLock()
         self._objs: Dict[str, Dict[str, object]] = {k: {} for k in KINDS}  # kubelint: guarded-by(_lock)

@@ -48,17 +48,25 @@ def _timed_point(point: str):
     818 each wrap their run in metrics.ObserveExtensionPoint).  Only the
     per-pod-per-cycle points are instrumented — the per-(pod, node)
     Filter loop is deliberately unsampled (see utils/metrics.py note).
-    Without a metrics registry the wrapper is one attribute read."""
+    Without a metrics registry the wrapper is one attribute read.
+    sink: a caller that runs many pods in a row (the scheduler's binder
+    lane) passes a list; the observation lands there as a ``(seconds,
+    point, status)`` row for ONE ``Histogram.observe_many`` once the run
+    is over, and the histogram's lock is not taken here."""
     def deco(fn):
         @functools.wraps(fn)
-        def wrapper(self, *args, **kwargs):
+        def wrapper(self, *args, sink=None, **kwargs):
             m = self.metrics
             if m is None:
                 return fn(self, *args, **kwargs)
             t0 = time.time()
             result = fn(self, *args, **kwargs)
-            m.framework_extension_point_duration.observe(
-                time.time() - t0, point, _status_label(result))
+            if sink is None:
+                m.framework_extension_point_duration.observe(
+                    time.time() - t0, point, _status_label(result))
+            else:
+                sink.append((time.time() - t0, point,
+                             _status_label(result)))
             return result
         return wrapper
     return deco
@@ -149,6 +157,15 @@ class Framework:
             if ka is not None and isinstance(inst, TensorPlugin):
                 out.append((name, ka(table)))
         return tuple(out)
+
+    def binds_in_process(self) -> bool:
+        """Whether a bind through this profile's Bind plugins stays in
+        this process: every one of them writes through a client that
+        says so of itself (``ClusterStore.in_process``).  A plugin that
+        names no client is not known to, and counts as remote."""
+        return bool(self.bind_plugins) and all(
+            getattr(getattr(p, "client", None), "in_process", False)
+            for p in self.bind_plugins)
 
     def queue_sort_less(self, a, b) -> bool:
         # reference: framework.go:358 QueueSortFunc (exactly one plugin)

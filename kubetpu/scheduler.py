@@ -26,12 +26,13 @@ import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
 from .api import types as api
 from .apis.config import (KubeSchedulerConfiguration, KubeSchedulerProfile)
+from .bindlane import BindFold, BindJob, BindLane
 from .client.store import ClusterStore
 from .framework import interface as fw
 from .framework.interface import Code, CycleState, Status
@@ -61,6 +62,13 @@ def _lap(acc: List[float], step: int) -> None:
     now = time.perf_counter()
     acc[step] += now - acc[-1]
     acc[-1] = now
+
+
+class _LadderOwed(NamedTuple):
+    """A binding cycle stopped where its retry ladder owes a sleep
+    (Scheduler._bind_cycle_inner): what the thread that resumes it needs."""
+    status: Status          # the rejected first Bind
+    bind_start: float
 
 
 def _vocab_caps(table):
@@ -366,9 +374,18 @@ class Scheduler:
         self._chain_ledger_key = None
         # (pod-axis bucket, compile-or-load seconds) per prewarmed program
         self.prewarm_report: List[Tuple[int, float]] = []
+        # binding (reference: scheduler.go:628): the commit loop hands a
+        # cycle's binds over as ONE job to ONE binder lane, which applies
+        # them in batch order; only a bind that would BLOCK the lane (a
+        # Permit wait, an extender's or a remote client's HTTP bind, a
+        # retry ladder's sleep, an armed chaos bind fault: _commit,
+        # _bind_cycle) goes to the pool, where it holds up nothing else
+        self._bind_lane = BindLane(self._run_bind_job)
         self._bind_pool = ThreadPoolExecutor(max_workers=16,
                                              thread_name_prefix="binder")
-        self._inflight_binds: List = []
+        # hand-overs not yet known to be applied, pruned once a hand-over
+        self._bind_jobs_lock = threading.Lock()
+        self._bind_jobs: List[BindJob] = []  # kubelint: guarded-by(_bind_jobs_lock)
         self._stop = threading.Event()
         self._serve_thread: Optional[threading.Thread] = None
         self._closed = False
@@ -1481,12 +1498,20 @@ class Scheduler:
 
     def _commit_group(self, prep: PreparedCycle,
                       packed: np.ndarray) -> List[ScheduleOutcome]:
-        """Runs inside the cycle's ``commit`` phase.  Armed, the per-pod
-        loop's split lands on that phase's span as SUMS -- recheck_s,
-        reserve_s, assume_s, permit_s, submit_s (pool submit + pruning of
-        the in-flight list), records_s (decision audit, SLO prefix, the
-        cycle context's note), pods, loop_s, loop_cpu_s -- not as a span
-        a pod."""
+        """Serving thread only (_finish_group for the serial loop, the
+        pipelined executor's drain for a ring of cycles), inside the
+        cycle's ``commit`` phase.  The loop assumes the cycle's pods one
+        by one (_commit) and collects their binds; as it ends they go to
+        the binder lane as ONE job (_hand_over), never a hand-over a pod.
+        One job at the loop's end, not chunks as the loop runs: the lane
+        then works under the next cycle's pop, prepare and readback
+        instead of taking the interpreter from this loop.  Armed, the
+        per-pod loop's split lands on that phase's span as SUMS --
+        recheck_s, reserve_s, assume_s, permit_s, submit_s (the stamp and
+        the append a pod, plus the hand-over), records_s (decision audit,
+        SLO prefix, the cycle context's note), pods, loop_s, loop_cpu_s --
+        not as a span a pod; _hand_over adds bind_jobs and
+        binds_pooled."""
         fwk, trace = prep.fwk, prep.trace
         live, states, pinfos = prep.live, prep.states, prep.pinfos
         node_infos, cycle_ctx = prep.node_infos, prep.cycle_ctx
@@ -1564,6 +1589,8 @@ class Scheduler:
             loop_cpu0 = time.thread_time()
             loop_t0 = time.perf_counter()
             acc = [0.0, 0.0, 0.0, 0.0, 0.0, 0.0, loop_t0]
+        job = (self._new_bind_job(fwk, flight) if self._async_binding
+               else None)
         for i, qp in enumerate(live):
             state = states[qp.pod.uid]
             if chosen[i] < 0:
@@ -1581,7 +1608,8 @@ class Scheduler:
             outcome = self._commit(fwk, qp, state, node_name,
                                    n_feas[i], pinfo=pinfos[i],
                                    host_relevant=prep.host_relevant[qp.pod.uid],
-                                   flight=flight, slo=slo, row=i, acc=acc)
+                                   flight=flight, slo=slo, row=i, acc=acc,
+                                   job=job)
             if outcome.node:
                 # preemption for pods failing later in this batch must see
                 # this placement (CycleContext.cluster_now overlay)
@@ -1600,7 +1628,10 @@ class Scheduler:
             outcomes.append(outcome)
             if acc is not None:
                 _lap(acc, 5)
+        if job is not None:
+            self._hand_over(job)
         if acc is not None:
+            _lap(acc, 4)
             trace.note(
                 recheck_s=round(acc[0], 6), reserve_s=round(acc[1], 6),
                 assume_s=round(acc[2], 6), permit_s=round(acc[3], 6),
@@ -2146,10 +2177,17 @@ class Scheduler:
                 binder_override=None, pinfo: Optional[PodInfo] = None,
                 host_relevant: Optional[bool] = None,
                 flight=None, slo=None, row: int = -1,
-                acc: Optional[List[float]] = None) -> ScheduleOutcome:
-        """flight / row: the cycle's CycleRecord and this pod's row of
-        its bind table (armed only).  acc: the commit loop's running
-        split (_commit_group), None disarmed."""
+                acc: Optional[List[float]] = None,
+                job: Optional[BindJob] = None) -> ScheduleOutcome:
+        """Serving thread only: Reserve, assume and Permit for one pod,
+        in scan order, then its bind is handed on.  flight / row: the
+        cycle's CycleRecord and this pod's row of its bind table (armed
+        only).  acc: the commit loop's running split (_commit_group),
+        None disarmed.  job: the cycle's hand-over, which _commit_group
+        gives to the binder lane once its loop ends; a caller that
+        commits one pod (the extender path) passes none and the pod is
+        handed over here, as a job of one.  With ``async_binding=False``
+        the bind runs here, before this returns."""
         pod = qp.pod
         if host_relevant is None:
             host_relevant = fwk.has_relevant_host_filters(pod)
@@ -2215,53 +2253,173 @@ class Scheduler:
         # binding cycle (reference: scheduler.go:628 goroutine)
         if flight is not None:
             flight.stamp_bind(row, utrace.BIND_SUBMITTED)
-        if self._async_binding:
-            try:
-                fut = self._bind_pool.submit(self._bind_cycle, fwk, qp,
-                                             state, assumed, node_name,
-                                             binder_override, flight, slo,
-                                             row)
-            except RuntimeError:
-                # close() raced the serving loop and shut the pool down
-                # mid-cycle: bind synchronously so the placement still
-                # lands instead of panicking the cycle
-                err = self._bind_cycle(fwk, qp, state, assumed, node_name,
-                                       binder_override, flight, slo, row)
-            else:
-                # prune completed futures so a long-running scheduler
-                # doesn't retain one CycleState + pod copy per pod
-                self._inflight_binds = [f for f in self._inflight_binds
-                                        if not f.done()]
-                self._inflight_binds.append(fut)
-                err = None
-        else:
+        err = None
+        if not self._async_binding:
             err = self._bind_cycle(fwk, qp, state, assumed, node_name,
                                    binder_override, flight, slo, row)
+        else:
+            own = job is None
+            if own:
+                job = self._new_bind_job(fwk, flight)
+            if (job.lane_ok and binder_override is None
+                    and st.code != Code.WAIT):
+                job.entries.append((fwk, qp, state, assumed, node_name,
+                                    slo, row))
+            else:
+                # this bind would block the lane (it waits on Permit or
+                # on a network): it takes a pool thread of its own
+                args = (fwk, qp, state, assumed, node_name,
+                        binder_override, flight, slo, row)
+                try:
+                    job.pooled.append(
+                        self._bind_pool.submit(self._bind_cycle, *args))
+                except RuntimeError:
+                    # close() raced the serving loop and shut the pool
+                    # down mid-cycle: bind synchronously so the placement
+                    # still lands instead of panicking the cycle
+                    err = self._bind_cycle(*args)
+            if own:
+                self._hand_over(job)
         if acc is not None:
             _lap(acc, 4)
         return ScheduleOutcome(pod=pod, node=node_name if err is None else "",
                                err=err, n_feasible=n_feasible)
 
+    def _new_bind_job(self, fwk: Framework, flight=None) -> BindJob:
+        """An empty hand-over for binds through ``fwk``.  Whether they may
+        ride the lane at all is read off what they will meet: a Bind
+        client outside this process, or a chaos bind fault that can still
+        fire, blocks, so those binds keep the pool and its parallelism."""
+        return BindJob(flight, lane_ok=fwk.binds_in_process()
+                       and not uchaos.armed("bind"))
+
+    def _hand_over(self, job: BindJob) -> None:
+        """The serving thread gives ``job`` to the binder lane, once the
+        pods it holds are assumed.  Counts the hand-over on the open
+        phase's span (``commit``: args ``bind_jobs``, ``binds_pooled``),
+        which the lane keeps counting into when it sends a bind of the
+        job to the pool after all (_bind_cycle)."""
+        span = job.span = Trace.open_span()
+        if span is not None:
+            a = span.args
+            a["bind_jobs"] = a.get("bind_jobs", 0) + bool(job.entries)
+            a["binds_pooled"] = a.get("binds_pooled", 0) + len(job.pooled)
+        if not job.entries:
+            job.applied()
+            if not job.pooled:
+                return
+        elif not self._bind_lane.submit(job):
+            # close() raced the serving loop: apply the job here, so the
+            # placements still land
+            try:
+                self._run_bind_job(job)
+            finally:
+                job.applied()
+        with self._bind_jobs_lock:
+            self._bind_jobs = [j for j in self._bind_jobs if not j.done()]
+            self._bind_jobs.append(job)
+
+    def _run_bind_job(self, job: BindJob) -> None:
+        """The binder lane's work (thread ``binder-lane``; the serving
+        thread only when close() has raced it): the binding cycle of each
+        pod of ``job`` in batch order, then ONE settling of what they owe
+        the cache's and the histograms' locks.  A bind that raises is
+        logged and kept for ``wait_for_inflight_binds``; the rest of the
+        job still binds."""
+        fold = BindFold(job)
+        try:
+            for fwk, qp, state, assumed, node_name, slo, row in job.entries:
+                try:
+                    self._bind_cycle(fwk, qp, state, assumed, node_name,
+                                     None, job.flight, slo, row, fold)
+                except Exception as e:
+                    import logging
+                    logging.getLogger("kubetpu").exception(
+                        "binding cycle of %s/%s raised", qp.pod.namespace,
+                        qp.pod.metadata.name)
+                    job.error = job.error or e
+        finally:
+            self._settle_bind_fold(fold)
+
+    def _settle_bind_fold(self, fold: BindFold) -> None:
+        """FinishBinding and the bind metrics for everything ``fold``
+        collected: each histogram's lock once, whatever the number of
+        pods (the cache's once a pod, but in one uncontended run: the
+        cache's source is under the AOT index's digest, left as it is)."""
+        for assumed in fold.finished:
+            self.cache.finish_binding(assumed)
+        m = self.metrics
+        if m is not None:
+            m.framework_extension_point_duration.observe_many(fold.points)
+            m.binding_duration.observe_many(fold.bind_s)
+            m.pods_scheduled(fold.scheduled)
+
     def _bind_cycle(self, fwk: Framework, qp: QueuedPodInfo, state: CycleState,
                     assumed: api.Pod, node_name: str,
                     binder_override=None, flight=None,
-                    slo=None, row: int = -1) -> Optional[str]:
-        """reference: scheduler.go:628-687.  flight, row: the cycle's
-        CycleRecord and this pod's row of its bind table — the bind's
-        start and end are stamped there from whichever thread runs it,
-        lock-free (None when disarmed).  slo: the pod's cycle-side stage
-        vector (_slo_prefix) — the bind completes it with
-        commit/bind/e2e and records the terminal pod (None when the
-        tracker is disarmed)."""
-        if flight is None:
-            return self._bind_cycle_inner(fwk, qp, state, assumed,
-                                          node_name, binder_override, slo)
-        flight.stamp_bind(row, utrace.BIND_STARTED)
+                    slo=None, row: int = -1,
+                    fold: Optional[BindFold] = None) -> Optional[str]:
+        """reference: scheduler.go:628-687.  Called from three threads:
+        the binder lane for every pod of a job (_run_bind_job; ``fold``
+        is the job's), a pool thread for a bind that would block the
+        lane (_commit), the serving thread itself with
+        ``async_binding=False`` or when close() has raced the cycle.
+        flight, row: the cycle's CycleRecord and this pod's row of its
+        bind table — the bind's start and end are stamped there from
+        whichever thread runs it, lock-free (None when disarmed).  slo:
+        the pod's cycle-side stage vector (_slo_prefix) — the bind
+        completes it with commit/bind/e2e and records the terminal pod
+        (None when the tracker is disarmed).  The lane never sleeps: a
+        bind whose retry ladder owes a sleep leaves it here for the
+        pool (_bind_resume), which also stamps its end."""
+        if flight is not None:
+            flight.stamp_bind(row, utrace.BIND_STARTED)
+        on_lane = fold is not None
+        if not on_lane:
+            fold = BindFold()
+        moved = False
+        try:
+            out = self._bind_cycle_inner(fwk, qp, state, assumed, node_name,
+                                         binder_override, slo, fold)
+            if not isinstance(out, _LadderOwed):
+                return out
+            moved = True
+            args = (fwk, qp, state, assumed, node_name, flight, slo, row,
+                    out)
+            try:
+                fold.job.pooled.append(
+                    self._bind_pool.submit(self._bind_resume, *args))
+            except RuntimeError:        # the pool is shut down: see _commit
+                return self._bind_resume(*args)
+            span = fold.job.span
+            if span is not None:
+                # counted from the lane: a hand-over of the same phase
+                # that races this on the serving thread (the extender
+                # path alone) may lose one count, never a bind
+                span.args["binds_pooled"] = \
+                    span.args.get("binds_pooled", 0) + 1
+            return None
+        finally:
+            if not on_lane:
+                self._settle_bind_fold(fold)
+            if flight is not None and not moved:
+                flight.stamp_bind(row, utrace.BIND_DONE)
+
+    def _bind_resume(self, fwk: Framework, qp: QueuedPodInfo,
+                     state: CycleState, assumed: api.Pod, node_name: str,
+                     flight, slo, row: int,
+                     owed: "_LadderOwed") -> Optional[str]:
+        """A pool thread finishes a binding cycle the lane began: the
+        retry ladder with its sleeps, then success or failure as ever."""
+        fold = BindFold()
         try:
             return self._bind_cycle_inner(fwk, qp, state, assumed,
-                                          node_name, binder_override, slo)
+                                          node_name, None, slo, fold,
+                                          owed=owed)
         finally:
-            flight.stamp_bind(row, utrace.BIND_DONE)
+            self._settle_bind_fold(fold)
+            if flight is not None:
+                flight.stamp_bind(row, utrace.BIND_DONE)
 
     def _bound_node(self, pod: api.Pod):
         """The API's current view of a pod's binding: the node name,
@@ -2277,88 +2435,62 @@ class Scheduler:
 
     def _bind_cycle_inner(self, fwk: Framework, qp: QueuedPodInfo,
                           state: CycleState, assumed: api.Pod,
-                          node_name: str, binder_override=None,
-                          slo=None) -> Optional[str]:
+                          node_name: str, binder_override, slo,
+                          fold: BindFold,
+                          owed: Optional["_LadderOwed"] = None):
+        """One pod's binding cycle, the same on every thread.  What it
+        owes the cache's and the histograms' locks goes to ``fold`` for
+        the caller to settle.  Returns None (bound), the failure's
+        message, or -- only on the lane (``fold.job`` set), which never
+        sleeps -- a ``_LadderOwed``: the first Bind was rejected and the
+        retry ladder owes a sleep; handed back as ``owed``, the cycle
+        resumes there."""
         pod = qp.pod
-        st = fwk.wait_on_permit(pod)
-        if not st.is_success():
+
+        def failed(st: Status, default: str) -> str:
             self._forget(assumed)
             fwk.run_unreserve_plugins(state, pod, node_name)
             self._record_failure(fwk, qp, st.message())
-            return st.message() or "permit rejected"
-        st = fwk.run_pre_bind_plugins(state, pod, node_name)
-        if not st.is_success():
-            self._forget(assumed)
-            fwk.run_unreserve_plugins(state, pod, node_name)
-            self._record_failure(fwk, qp, st.message())
-            return st.message() or "prebind failed"
-        bind_start = utrace.wallclock()
-        if binder_override is not None:
-            # extender binding (reference: scheduler.go:457 extendersBinding)
-            try:
-                binder_override(pod, node_name)
-                st = Status.success()
-            except Exception as e:
-                st = Status.error(f"extender bind failed: {e}")
+            return st.message() or default
+
+        if owed is not None:
+            st, bind_start = owed
         else:
-            st = fwk.run_bind_plugins(state, pod, node_name)
-            # transient-bind retry ladder: a bind transport ERROR (socket
-            # hiccup, injected chaos "bind" fault) retries in place on
-            # the thread that ran bind (the binder pool under async
-            # binding, the serving loop otherwise), sleeping the pod
-            # backoff ladder between attempts (pod_initial_backoff_seconds
-            # doubling, capped) — the cycle already won this placement; a
-            # once-flaky API server must not cost it.  Each attempt is
-            # gated on the API's CURRENT state, never on error-message
-            # classification: bind is NOT idempotent (BindingREST rejects
-            # any re-bind, even to the same node), so a bind that LANDED
-            # with a lost response resolves to success without a re-POST,
-            # and a pod that is gone or bound elsewhere stops the ladder
-            # immediately — deterministic failures never sleep it.  Only
-            # DefaultBinder's exception path ("binding rejected: ...")
-            # enters at all; config errors fail as before.
-            retries = max(int(getattr(self.config, "bind_retries", 0)), 0)
-            delay = min(self.config.pod_initial_backoff_seconds,
-                        self.config.pod_max_backoff_seconds)
-            attempt = 0
-            while (not st.is_success() and attempt < retries
-                   and st.message().startswith("binding rejected:")):
-                bound = self._bound_node(pod)
-                if bound == node_name:
-                    # applied-but-response-lost: already bound right
+            st = fwk.wait_on_permit(pod)
+            if not st.is_success():
+                return failed(st, "permit rejected")
+            st = fwk.run_pre_bind_plugins(state, pod, node_name,
+                                          sink=fold.points)
+            if not st.is_success():
+                return failed(st, "prebind failed")
+            bind_start = utrace.wallclock()
+            if binder_override is not None:
+                # extender binding (reference: scheduler.go:457
+                # extendersBinding)
+                try:
+                    binder_override(pod, node_name)
                     st = Status.success()
-                    attempt += 1     # counts as a recovered attempt
-                    break
-                if bound != "":
-                    # gone (None) or bound elsewhere: permanent — the
-                    # normal failure path handles it, no sleeps owed
-                    break
-                attempt += 1
-                time.sleep(delay)
-                delay = min(delay * 2,
-                            self.config.pod_max_backoff_seconds)
-                st = fwk.run_bind_plugins(state, pod, node_name)
-            if attempt and st.is_success():
-                if self.metrics is not None:
-                    self.metrics.recoveries.inc("bind-retry")
-                if self.recorder:
-                    self.recorder.event(
-                        pod, "Normal", "BindRetried",
-                        f"bind succeeded after {attempt} retr"
-                        f"{'y' if attempt == 1 else 'ies'}")
+                except Exception as e:
+                    st = Status.error(f"extender bind failed: {e}")
+            else:
+                st = fwk.run_bind_plugins(state, pod, node_name,
+                                          sink=fold.points)
+        if binder_override is None and not st.is_success():
+            after = self._bind_retry_ladder(fwk, state, pod, node_name, st,
+                                            fold)
+            if after is None:
+                return _LadderOwed(st, bind_start)
+            st = after
         if not st.is_success():
-            self._forget(assumed)
-            fwk.run_unreserve_plugins(state, pod, node_name)
-            self._record_failure(fwk, qp, st.message())
-            return st.message() or "bind failed"
-        self.cache.finish_binding(assumed)
-        fwk.run_post_bind_plugins(state, pod, node_name)
+            return failed(st, "bind failed")
+        fold.finished.append(assumed)
+        fwk.run_post_bind_plugins(state, pod, node_name, sink=fold.points)
         if self.metrics:
             now = utrace.wallclock()
-            self.metrics.binding_duration.observe(now - bind_start)
-            self.metrics.pod_scheduled(
-                qp.attempts, now - qp.initial_attempt_timestamp,
-                now - qp.timestamp)
+            fold.bind_s.append((now - bind_start,))
+            fold.scheduled.append((qp.attempts,
+                                   now - qp.initial_attempt_timestamp,
+                                   now - qp.timestamp))
         if slo is not None:
             trk = uslo.tracker()
             if trk is not None:
@@ -2370,6 +2502,61 @@ class Scheduler:
                                 f"{pod.namespace}/{pod.metadata.name} to "
                                 f"{node_name}")
         return None
+
+    def _bind_retry_ladder(self, fwk: Framework, state: CycleState,
+                           pod: api.Pod, node_name: str, st: Status,
+                           fold: BindFold) -> Optional[Status]:
+        """Transient-bind retry ladder: a bind transport ERROR (socket
+        hiccup, injected chaos "bind" fault) retries in place, sleeping
+        the pod backoff ladder between attempts
+        (pod_initial_backoff_seconds doubling, capped) — the cycle
+        already won this placement; a once-flaky API server must not
+        cost it.  Each attempt is gated on the API's CURRENT state, never
+        on error-message classification: bind is NOT idempotent
+        (BindingREST rejects any re-bind, even to the same node), so a
+        bind that LANDED with a lost response resolves to success without
+        a re-POST, and a pod that is gone or bound elsewhere stops the
+        ladder immediately — deterministic failures never sleep it.  Only
+        DefaultBinder's exception path ("binding rejected: ...") enters
+        at all; config errors fail as before.  Sleeps on the thread that
+        ran the bind (a pool thread under async binding, the serving loop
+        otherwise) but never on the binder lane: for the lane's fold
+        (``fold.job`` set) it returns None where the first sleep is owed,
+        having changed nothing, and the caller moves the bind to the
+        pool."""
+        retries = max(int(getattr(self.config, "bind_retries", 0)), 0)
+        delay = min(self.config.pod_initial_backoff_seconds,
+                    self.config.pod_max_backoff_seconds)
+        attempt = 0
+        while (not st.is_success() and attempt < retries
+               and st.message().startswith("binding rejected:")):
+            bound = self._bound_node(pod)
+            if bound == node_name:
+                # applied-but-response-lost: already bound right
+                st = Status.success()
+                attempt += 1     # counts as a recovered attempt
+                break
+            if bound != "":
+                # gone (None) or bound elsewhere: permanent — the
+                # normal failure path handles it, no sleeps owed
+                break
+            if fold.job is not None:
+                return None
+            attempt += 1
+            time.sleep(delay)
+            delay = min(delay * 2,
+                        self.config.pod_max_backoff_seconds)
+            st = fwk.run_bind_plugins(state, pod, node_name,
+                                      sink=fold.points)
+        if attempt and st.is_success():
+            if self.metrics is not None:
+                self.metrics.recoveries.inc("bind-retry")
+            if self.recorder:
+                self.recorder.event(
+                    pod, "Normal", "BindRetried",
+                    f"bind succeeded after {attempt} retr"
+                    f"{'y' if attempt == 1 else 'ies'}")
+        return st
 
     def _forget(self, assumed: api.Pod) -> None:
         # a rolled-back placement invalidates the chained cluster (it may
@@ -2897,18 +3084,27 @@ class Scheduler:
         return t
 
     def wait_for_inflight_binds(self, timeout: float = 10.0) -> None:
+        """Return once every bind handed over so far has run, on the lane
+        or on the pool; raises TimeoutError past ``timeout``, and what a
+        bind raised."""
         deadline = time.time() + timeout
-        for fut in list(self._inflight_binds):
-            fut.result(timeout=max(0.0, deadline - time.time()))
-        self._inflight_binds = [f for f in self._inflight_binds if not f.done()]
+        with self._bind_jobs_lock:
+            jobs = list(self._bind_jobs)
+        for job in jobs:
+            job.result(timeout=max(0.0, deadline - time.time()))
+        with self._bind_jobs_lock:
+            self._bind_jobs = [j for j in self._bind_jobs if not j.done()]
 
     def close(self) -> None:
         """Idempotent shutdown: stop the serving loop and JOIN it before
         flushing, so the pipeline flush cannot race a cycle in flight —
         if the loop outlives the join bound (a cold cycle can be paying a
         multi-second compile), the in-flight cycle is left to that loop
-        and NOT flushed here.  Then close the queue (wakes blocked pops,
-        joins flushers), the cache (joins cleanup), and the bind pool."""
+        and NOT flushed here (its binds it then applies itself:
+        _hand_over).  Then drain the binder lane (the jobs queued on it
+        are applied, in order, before this returns), close the queue
+        (wakes blocked pops, joins flushers), the cache (joins cleanup),
+        and the bind pool (binds blocked there finish on their own)."""
         if self._closed:
             return
         self._closed = True
@@ -2925,6 +3121,7 @@ class Scheduler:
                 self.flush_pipeline()
             except Exception:
                 pass
+        self._bind_lane.close()
         self.queue.close()
         self.cache.close()
         self._bind_pool.shutdown(wait=False)

@@ -24,6 +24,9 @@ Injection points threaded through the stack:
                  (pickle fails; the seam must degrade to the trace path)
   ``bind``       plugins/intree.DefaultBinder.bind — transient bind
                  transport error (the binder retry ladder's test feed)
+                 or a stall (a slow API server); while the point can
+                 still fire, binds keep off the scheduler's one binder
+                 lane (``armed``)
   ``extender``   extender.HTTPExtender._send — transient webhook error
   ``rest``       client/rest.RestClusterStore._req — transient API-server
                  transport error
@@ -70,7 +73,7 @@ POINTS: Dict[str, Tuple[str, ...]] = {
     "dispatch": ("error", "stall"),
     "delta": ("drop", "corrupt"),
     "aot-load": ("corrupt",),
-    "bind": ("error",),
+    "bind": ("error", "stall"),
     "extender": ("error",),
     "rest": ("error",),
     "watch": ("error",),
@@ -158,6 +161,14 @@ class ChaosRegistry:
         from .trace import note_instant
         note_instant("chaos", point=point, mode=mode)
         return mode, delay
+
+    def armed(self, point: str) -> bool:
+        """True while the point has a rule that can still fire (draws
+        nothing, counts nothing)."""
+        with self._lock:
+            rule = self._rules.get(point)
+            return rule is not None and (rule.n is None
+                                         or rule.fired < rule.n)
 
     def counts(self) -> Dict[str, int]:
         """Monotonic per-point fire counts (the
@@ -248,6 +259,14 @@ def maybe_arm_from_env() -> Optional[ChaosRegistry]:
 
 
 # ------------------------------------------------------------ site helpers
+
+
+def armed(point: str) -> bool:
+    """Whether ``point`` may still fire: what a caller asks before it
+    commits a thread that must not sleep to the seam (the scheduler's
+    binder lane).  Disarmed: one attribute read."""
+    reg = _active
+    return reg is not None and reg.armed(point)
 
 
 def action(point: str) -> Optional[str]:

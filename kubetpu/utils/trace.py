@@ -834,6 +834,14 @@ class Trace:
         if cur is not None and cur.span is not None:
             cur.span.args.update(args)
 
+    @staticmethod
+    def open_span() -> Optional[FlightSpan]:
+        """The span of the phase this thread has open, for a caller that
+        counts into its args or hands it to another thread to count
+        into (None with the recorder disarmed)."""
+        cur = getattr(_tls, "phase", None)
+        return cur.span if cur is not None else None
+
     def stage(self, name: str, **args):
         """Scoped child span INSIDE a phase (preemption wave, decision
         audit...): no annotation.  Returns a no-op context when disarmed

@@ -59,7 +59,7 @@ def _drain(sched):
 
 @pytest.fixture
 def cycles(flight):
-    """Three cycles of 32 pods through the async 16-thread binder pool."""
+    """Three cycles of 32 pods, their binds through the binder lane."""
     store, sched = _world()
     try:
         outs = _drain(sched)
@@ -197,7 +197,7 @@ def test_the_bind_table_has_one_complete_row_per_bound_pod(cycles):
             binders.add(thread)
             # row i is batch_pods[i]: that pod is bound in the store
             assert store.get_pod("default", name).spec.node_name
-    assert len(binders) > 1          # the pool, not one thread
+    assert binders == {"binder-lane"}    # one lane, not the pool
     json.dumps(recs)
 
 
