@@ -14,6 +14,16 @@ printed beside its limit.
 
 Both limits are 0: they are exact comparisons.
 
+What the residents and the sample of (b) carry: they are records of the
+measured template under the roles "resident" and "sample", so their
+names differ (``resident-<i>``, ``sample-<i>``).  A template that states
+its labels and selectors literally gives every role the same labels and
+terms, so a sample pod's terms count the residents exactly as a measured
+pod's terms count the window's residents.  A ``features`` template
+labels a pod ``group=<role>``, which is what its ``aff`` and ``spread``
+selectors select: under it a sample pod's term of those two kinds counts
+no resident (the two cells that exist use neither).
+
 Why (b) is a cycle of its own and not the window's binds: the client
 knows the cluster a window's cycle started from only to within a cycle
 (its deletes race the scheduler's snapshot), and with as many residents

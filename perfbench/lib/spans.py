@@ -28,6 +28,9 @@ from .readers import AUCTION_PROGRAM
 PHASES = ("pop", "snapshot", "prefilter", "tensorize", "host-masks",
           "dispatch", "packed-readback", "commit")
 READBACK = "packed-readback"
+# the thread name the program's one binder lane stamps on its rows
+# (kubetpu/bindlane.py, PR 27); a pooled bind carries ``binder_<n>``
+LANE_THREAD = "binder-lane"
 CLOCK_EVENT = "kubetpu.clock"
 # how far before its own dispatch a device event may appear to lie
 SKEW_SLACK_S = 0.005
@@ -115,7 +118,21 @@ def _p95_ms(values: List[float]) -> Optional[float]:
 
 
 def bind_queue_wait_p95_ms(ctx) -> Optional[float]:
-    """How long a bind waits in the pool: started - submitted."""
+    """started - submitted, 95th percentile over the pods.
+
+    What it MEANS changed with the program in PR 27, the arithmetic did
+    not.  Before, a pod's bind was submitted to a 16-thread pool from
+    inside the commit loop and this was its wait in the pool's queue.
+    Since PR 27 a row's ``submitted`` is still stamped in the commit
+    loop, but the cycle's binds are ONE job handed to one binder lane as
+    the loop ends, and ``started`` is stamped when the lane reaches the
+    row: after the rest of the commit loop, the hand-over's wait for the
+    lane's previous job, and every earlier row of its own job.  So it
+    reads the pod's POSITION IN A SERIAL JOB plus the rest of the loop
+    (p95 ~ 0.95 x the job's length + what is left of the loop; 765-814
+    ms, where the pool read 383-642; my chip runs, PR 27), not
+    contention for a pool.  ``lane_busy_ms_per_cycle`` reads the job's
+    length itself."""
     return _p95_ms([start - sub for _, sub, start, _ in _bind_rows(ctx)])
 
 
@@ -126,13 +143,41 @@ def bind_exec_p95_ms(ctx) -> Optional[float]:
 
 def bind_done_lag_p95_ms(ctx) -> Optional[float]:
     """How long after its cycle's readback a bind lands: done - the end
-    of the cycle's packed-readback span."""
+    of the cycle's packed-readback span, 95th percentile over the pods.
+
+    Since PR 27 the binds of a cycle run as one serial job AFTER the
+    commit loop, under the next cycle's pop / prepare / readback, so this
+    is the whole commit loop + the hand-over + the pod's position in the
+    job (819-913 ms against 647-890 with the pool; my chip runs, PR 27):
+    it is what a pod's bind latency owes to everything after the device
+    answered, and it no longer shrinks when a pool thread is free."""
     lags = []
     for c, _, _, done in _bind_rows(ctx):
         rb = named(c, READBACK)
         if rb:
             lags.append(done - max(s["t1"] for s in rb))
     return _p95_ms(lags)
+
+
+def lane_busy_ms_per_cycle(ctx, thread: str = LANE_THREAD
+                           ) -> Optional[float]:
+    """Mean ms a cycle between the lane's first ``started`` and its last
+    ``done`` over the cycle record's rows whose thread is ``thread``:
+    the wall extent of the cycle's one bind job on the binder lane (PR
+    27).  It is the lane's busy time a cycle as seen from outside: a
+    moment in which the lane thread waits for the interpreter or a lock
+    counts as busy.  Held against the cycle's period it says whether the
+    lane can be the pace-setter: a hand-over waits until the job before
+    it is applied.  Over the cycles that have such a row, complete rows
+    only; None where no row names the thread (a program before PR 27)."""
+    per = []
+    for c in ctx.cycles:
+        rows = [(row[1], row[2]) for row in c.get("binds", ())
+                if len(row) > 3 and row[3] == thread
+                and row[1] > 0.0 and row[2] > 0.0]
+        if rows:
+            per.append(max(d for _, d in rows) - min(s for s, _ in rows))
+    return _mean_ms(per)
 
 
 # --------------------------------------------------------------- compile

@@ -7,8 +7,22 @@ its per-layer metric readers are files found by NAME:
   perfbench/traffic/<traffic>.json
   perfbench/metrics/<metric name>.py     (one ``read(ctx)`` each)
   perfbench/reference/<reference>.py     (named by the configuration)
+  perfbench/controls/<control>.py        (named by the configuration:
+                                          ``REFERENCE_KW``, what the
+                                          reference's ``auction_schedule``
+                                          is called with, and
+                                          ``program_control()``, the
+                                          context manager that patches the
+                                          program; ``tools/control.py``)
 
 so a later PR adds a cell by adding files and one entry, and edits none.
+What a configuration file may state about its pods and nodes is in
+``lib/world.py``'s docstring; ``tests/perfbench/perfbench_toy.py`` adds
+three toy configurations this way (``toy-mixed-96`` is the worked
+example of literal templates, an init-pod list and node labels), and
+``perfbench/tools/later_pr_tree.py`` builds a copy of the benchmark with
+a row added so at its real size, in which the benchmark's own tests run:
+a test that holds only for today's rows or entries fails there.
 """
 
 from __future__ import annotations
@@ -19,6 +33,7 @@ import os
 from typing import Any, Callable, Dict
 
 from . import traffic as _traffic
+from . import world as _world
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -62,6 +77,17 @@ class Cell:
             raise SpecError(f"workload {name}: no config {entry['config']!r}")
         self.config_file = os.path.join(root, cfg_entry["file"])
         self.config = load_json(self.config_file)
+        try:
+            _world.validate(self.config)
+        except ValueError as e:
+            raise SpecError(f"{self.config_file}: {e}") from e
+        self.control_file = os.path.join(
+            root, "perfbench", "controls",
+            str(self.config.get("control")) + ".py")
+        if not os.path.exists(self.control_file):
+            raise SpecError(
+                f"config {self.config.get('name')}: control "
+                f"{self.config.get('control')!r}: no {self.control_file}")
         self.traffic_file = os.path.join(
             root, "perfbench", "traffic", entry["traffic"] + ".json")
         if not os.path.exists(self.traffic_file):
@@ -93,6 +119,17 @@ class Cell:
         if not os.path.exists(path):
             raise SpecError(f"config {self.config['name']}: no {path}")
         return _load_module(path, "perfbench_reference_" + ref)
+
+    def control(self):
+        """The configuration's control module: ``REFERENCE_KW`` and
+        ``program_control()``."""
+        name = self.config["control"]
+        mod = _load_module(self.control_file, "perfbench_control_"
+                           + name.replace(".", "_").replace("-", "_"))
+        for attr in ("REFERENCE_KW", "program_control"):
+            if not hasattr(mod, attr):
+                raise SpecError(f"{self.control_file}: no {attr}")
+        return mod
 
 
 def load_benchmark(root: str = ROOT) -> Dict[str, Any]:

@@ -40,8 +40,11 @@ file), the client's event log as tuples.  It decides ``correct``:
       DefaultPodTopologySpread 100 (no service/controller selects the
       pod, so every count is 0), TaintToleration 100 (no taint).
 
-    A template feature outside that list raises: a configuration that
-    needs more brings a reference file of its own.
+    A record that holds anything outside that list (a preferred term, a
+    spread constraint, a node-affinity term, a required term on a
+    selector of several labels) raises, whether it is the incoming pod's
+    or an existing pod's, and whatever the template called it: a
+    configuration that needs more brings a reference file of its own.
 
 ``auction_schedule`` is that auction written plainly, with this
 reference's own scores: in float64 it places the check's resident pods;
@@ -61,7 +64,12 @@ MAX_NODE_SCORE = 100
 HOSTNAME = "kubernetes.io/hostname"
 # weight * normalized score of the seven plugins that do not vary here
 CONSTANT_SCORE = 10000 * MAX_NODE_SCORE + MAX_NODE_SCORE + MAX_NODE_SCORE
-SUPPORTED_FEATURES = ("anti", "aff")
+# what a record (lib/world.py PodRec) may hold besides its required terms,
+# none of which this reference models
+UNMODELLED = (("anti_preferred", "a preferred anti-affinity term"),
+              ("aff_preferred", "a preferred affinity term"),
+              ("spread", "a topology spread constraint"),
+              ("node_affinity_in", "a node-affinity term"))
 
 
 def bf16(x):
@@ -113,6 +121,7 @@ class Cluster:
     # -- state ----------------------------------------------------------
 
     def _bump(self, pod, r: int, d: int) -> None:
+        _check_record(pod)
         self.req_cpu[r] += d * pod.cpu_milli
         self.req_mem[r] += d * pod.mem_bytes
         self.count[r] += d
@@ -171,7 +180,7 @@ class Cluster:
 
     def terms_ok(self, pod, row: Optional[int] = None):
         """InterPodAffinity's filter, per node or for one row."""
-        _check_features(pod)
+        _check_record(pod)
         ok = np.ones(len(self.names), bool) if row is None else True
         zeros = np.zeros(len(self.names), np.int64)
         for topo, sel in pod.anti_required:
@@ -238,12 +247,21 @@ class Cluster:
         return np.flatnonzero(s == s.max())
 
 
-def _check_features(pod) -> None:
-    extra = [f for f in pod.features if f not in SUPPORTED_FEATURES]
-    if extra:
-        raise NotImplementedError(
-            f"reference default_plugins does not model {extra} "
-            f"(pod {pod.name})")
+def _check_record(pod) -> None:
+    """Refuse what this reference does not model, by what the record
+    HOLDS: ignoring a term would pass a placement upstream forbids, or
+    score a node upstream scores otherwise."""
+    for attr, what in UNMODELLED:
+        if getattr(pod, attr, ()):
+            raise NotImplementedError(
+                f"reference default_plugins does not model {what} "
+                f"(pod {pod.name}: {attr} {getattr(pod, attr)})")
+    for _, sel in tuple(pod.anti_required) + tuple(pod.aff_required):
+        if len(tuple(sel)) != 1:
+            raise NotImplementedError(
+                f"reference default_plugins does not model a required term "
+                f"whose selector {dict(sel)} is not a one-label match "
+                f"(pod {pod.name})")
 
 
 def auction_schedule(cluster: Cluster, pods: Sequence[Any], rng,

@@ -13,15 +13,13 @@ Per seed it prints the misses of check (b) for
   program              the program as it stands (must be 0)
   program:<control>    the program with the control patched into it
 
-Controls (a configuration names the one that must fail it, ``control``):
+A control is a file found by name, ``perfbench/controls/<control>.py``
+(``lib/spec.py``): ``REFERENCE_KW`` is what the reference's
+``auction_schedule`` is called with, ``program_control()`` the context
+manager that patches the program.  The two that exist:
 
-  bf16-scores  the summed plugin scores held in bfloat16, the nearest
-               precision below the float32 the program sums them in: the
-               step that would halve the bytes of every [pods, nodes]
-               score plane
-  blind-batch  the batch's own pods left out of the required-term
-               filter (the auction's ``intra_batch_topology`` switched
-               off): the exchange between the pods of one batch left out
+  bf16-scores  the summed plugin scores held in bfloat16
+  blind-batch  the batch's own pods left out of the required-term filter
 
 PERF.md records the readings the limit (0) was set from.
 """
@@ -29,7 +27,6 @@ PERF.md records the readings the limit (0) was set from.
 from __future__ import annotations
 
 import argparse
-import contextlib
 import json
 import os
 import sys
@@ -38,40 +35,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
-
-REFERENCE_CONTROLS = {"bf16-scores": {"lowprec": True},
-                      "blind-batch": {"blind_batch": True}}
-
-
-@contextlib.contextmanager
-def program_control(name: str):
-    """The program with the named control patched in, for the block."""
-    import jax
-    import jax.numpy as jnp
-    from kubetpu.models import gang
-    if name == "bf16-scores":
-        attr, real = "run_scores", gang.run_scores
-
-        def patched(*a, **kw):
-            total, per_plugin = real(*a, **kw)
-            return (total.astype(jnp.bfloat16).astype(jnp.float32),
-                    per_plugin)
-    elif name == "blind-batch":
-        attr, real = "run_auction", gang.run_auction
-
-        def patched(*a, **kw):
-            kw["intra_batch_topology"] = False
-            return real(*a, **kw)
-    else:
-        raise ValueError(f"no control {name!r}; known: "
-                         f"{sorted(REFERENCE_CONTROLS)}")
-    setattr(gang, attr, patched)
-    jax.clear_caches()        # the auction is traced anew, patched
-    try:
-        yield
-    finally:
-        setattr(gang, attr, real)
-        jax.clear_caches()
 
 
 def reference_misses(cell, seed: int, nodes, init, **control) -> int:
@@ -96,6 +59,7 @@ def main(argv=None) -> int:
     from perfbench.lib import check, spec, world
     cell = spec.cell(args.workload, ROOT)
     control = cell.config["control"]
+    control_mod = cell.control()
     nodes = world.node_records(cell.config)
     for seed in [int(s) for s in args.seeds.split(",")]:
         init = world.init_records(cell.config, seed)
@@ -103,10 +67,10 @@ def main(argv=None) -> int:
                "batch": int(cell.config["scheduler"]["batch_size"]),
                "reference": reference_misses(cell, seed, nodes, init),
                "reference:" + control: reference_misses(
-                   cell, seed, nodes, init, **REFERENCE_CONTROLS[control])}
+                   cell, seed, nodes, init, **control_mod.REFERENCE_KW)}
         if args.program:
             row["program"] = len(check.gang_check(cell, seed, nodes, init))
-            with program_control(control):
+            with control_mod.program_control():
                 row["program:" + control] = len(
                     check.gang_check(cell, seed, nodes, init))
         print("CONTROL " + json.dumps(row), flush=True)

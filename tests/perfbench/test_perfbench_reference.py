@@ -251,3 +251,74 @@ def test_features_outside_the_reference_raise(nodes):
     p = pod("spready", "measured", 0)
     with pytest.raises(NotImplementedError):
         ref.Cluster(nodes).feasible(p)
+
+
+# what a record may HOLD that this reference does not model, whatever the
+# template called it (literal terms here; "spready" above is the shorthand)
+_T = {"topology_key": world.HOSTNAME, "match_labels": {"color": "red"}}
+UNMODELLED = {
+    "a preferred affinity term": {"pod_affinity": [dict(_T, weight=1)]},
+    "a preferred anti-affinity term": {
+        "pod_anti_affinity": [dict(_T, weight=1)]},
+    "a topology spread constraint": {"topology_spread": [dict(
+        _T, max_skew=5, when_unsatisfiable="ScheduleAnyway")]},
+    "a node-affinity term": {"node_affinity_in": {
+        "key": world.ZONE, "values": ["zone-0"]}},
+    "a required term on two labels": {"pod_anti_affinity": [{
+        "topology_key": world.HOSTNAME, "required": True,
+        "match_labels": {"color": "red", "app": "x"}}]},
+}
+
+
+@pytest.mark.parametrize("existing", [False, True],
+                         ids=["incoming", "existing"])
+@pytest.mark.parametrize("what", sorted(UNMODELLED))
+def test_a_record_that_holds_what_the_reference_does_not_model_raises(
+        nodes, what, existing):
+    config = dict(CONFIG, templates=dict(CONFIG["templates"], odd=dict(
+        {"cpu_milli": 100, "memory_bytes": 1 << 20,
+         "labels": {"color": "red"}}, **UNMODELLED[what])))
+    odd = world.pod_record(config, "odd", "measured", 0)
+    plain = pod("plain", "measured", 1)
+    cluster = ref.Cluster(nodes)
+    with pytest.raises(NotImplementedError) as e:
+        if existing:
+            cluster.add(odd, "node-0")      # the cluster already holds it
+        else:
+            cluster.feasible(odd)
+    assert odd.name in str(e.value)
+    # ...and through the entries the check calls
+    if existing:
+        with pytest.raises(NotImplementedError):
+            ref.replay(nodes, [(odd, "node-0")], {plain.name: plain},
+                       [("bind", plain.name, "node-1", 0.0)],
+                       {plain.name: "node-1"})
+    else:
+        with pytest.raises(NotImplementedError):
+            ref.auction_schedule(cluster, [plain, odd],
+                                 np.random.default_rng(0))
+        with pytest.raises(NotImplementedError):
+            ref.gang_misses(ref.Cluster(nodes), [odd], {odd.name: "node-0"})
+
+
+@pytest.mark.parametrize("name", ["sp-basic-5000", "sp-antiaffinity-5000"])
+def test_the_reference_accepts_every_record_of_the_two_rows(name):
+    import os
+    from perfbench.lib import spec
+    config = spec.load_json(os.path.join(spec.ROOT, "perfbench", "configs",
+                                         name + ".json"))
+    cluster = ref.Cluster(world.node_records(config))
+    init = world.init_records(config, seed=3)
+    for rec, node in init:
+        cluster.add(rec, node)              # existing
+    for role in ("measured", "resident", "sample"):
+        rec = world.measured_record(config, role, 17)
+        assert cluster.feasible(rec).any()  # incoming
+    # a literal required term on one label is what it models, too
+    green = world.pod_record({"templates": {"g": {
+        "cpu_milli": 100, "memory_bytes": 1 << 20,
+        "labels": {"color": "green"}, "pod_anti_affinity": [{
+            "topology_key": world.HOSTNAME, "required": True,
+            "match_labels": {"color": "green"}}]}}}, "g", "measured", 0)
+    cluster.add(green, "node-0")
+    assert not cluster.feasible(green)[0] and cluster.feasible(green)[1]
