@@ -847,7 +847,8 @@ class Scheduler:
                 for name, st0, st1 in dstats.spans:
                     rec.record_span(name, st0, st1,
                                     parent_id=trace.span_id,
-                                    delta_rows=dstats.delta_rows)
+                                    delta_rows=dstats.delta_rows,
+                                    **dstats.span_args.get(name, {}))
                 rec.meta["delta_rows"] = dstats.delta_rows
                 # the (dirty-node, churned-pod) row buckets the scatter
                 # program was dispatched with: a new pair is a compile
@@ -929,6 +930,11 @@ class Scheduler:
             # tools/kubeaot --prune works in (buckets the recorder never
             # saw are dead ladder rungs, dropped from the artifact set)
             trace.rec.meta["pod_bucket"] = int(cluster.pod_valid.shape[0])
+            # the existing-term rows (Et filter, Es score) the auction's
+            # match and contractions run over, padding included
+            trace.rec.meta["term_buckets"] = [
+                int(cluster.filter_terms.valid.shape[0]),
+                int(cluster.score_terms.valid.shape[0])]
             trace.note(delta_rows=trace.rec.meta.get("delta_rows", 0),
                        delta_buckets=trace.rec.meta.get("delta_buckets", []),
                        pod_bucket=trace.rec.meta["pod_bucket"])

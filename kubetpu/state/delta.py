@@ -60,7 +60,8 @@ from __future__ import annotations
 import os
 import pickle
 import time
-from typing import Dict, List, NamedTuple, Optional, Tuple
+from types import MappingProxyType
+from typing import Dict, List, Mapping, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -141,6 +142,10 @@ class DeltaStats(NamedTuple):
     # dispatched with (gather_delta's pow2 pads): the program compiles
     # once per pair.  () when no scatter ran
     delta_buckets: Tuple[int, ...] = ()
+    # span name -> the args it is recorded with besides delta_rows: what
+    # the term refresh rebuilt ("delta-terms": filter_rows, score_rows,
+    # their buckets Et / Es, pods_walked, owners_changed)
+    span_args: Mapping[str, Mapping[str, int]] = MappingProxyType({})
 
 
 class DeltaTensorizer:
@@ -179,6 +184,9 @@ class DeltaTensorizer:
         self.node_gen: Dict[str, int] = {}
         self.node_pods: Dict[str, List[str]] = {}   # name -> uid list
         self.node_terms: Dict[str, bool] = {}    # name -> owns term pods
+        # uid -> node of every term owner at the last term rebuild, for
+        # the refresh's ``owners_changed``
+        self.term_owners: Dict[str, str] = {}
         self.pod_row: Dict[str, int] = {}        # uid -> row
         self.free_rows: List[int] = []           # kept sorted, pop lowest
         self.next_pod_row = 0
@@ -457,9 +465,10 @@ class DeltaTensorizer:
         a["image_spread"] = image_nodes / max(float(len(node_infos)), 1.0)
 
         term_span = ()
+        span_args: Dict[str, Dict[str, int]] = {}
         if terms_dirty:
             t_terms = wallclock()
-            self._refresh_terms(node_infos)
+            span_args["delta-terms"] = self._refresh_terms(node_infos)
             term_span = (("delta-terms", t_terms, wallclock()),)
 
         pod_rows = sorted(touched_pods)
@@ -474,17 +483,20 @@ class DeltaTensorizer:
             return self.cluster, DeltaStats(
                 len(node_rows) + len(pod_rows), True, "pod-axis-growth",
                 (("delta-build", t0, t_build),) + term_span
-                + (("resync", t_build, wallclock()),))
+                + (("resync", t_build, wallclock()),), span_args=span_args)
         delta = gather_delta(self.host, node_rows, pod_rows)
         t_build = wallclock()
+        upload_span: list = []
         self.cluster = self._apply(delta, donate=donate,
-                                   replace_terms=terms_dirty)
+                                   replace_terms=terms_dirty,
+                                   spans=upload_span)
         if terms_dirty:
             # wholesale term replacement can change the term-table
             # shapes — the only delta-path event that moves residency
             self._register_residency()
         self.cycles_since_resync += 1
         spans = ((("delta-build", t0, t_build),) + term_span
+                 + tuple(upload_span)
                  + (("delta-apply", t_build, wallclock()),))
         buckets = (int(delta.node_rows.shape[0]),
                    int(delta.pod_rows.shape[0]))
@@ -492,10 +504,11 @@ class DeltaTensorizer:
         if vstats is not None:
             return self.cluster, vstats._replace(spans=spans
                                                  + vstats.spans,
-                                                 delta_buckets=buckets)
+                                                 delta_buckets=buckets,
+                                                 span_args=span_args)
         return self.cluster, DeltaStats(
             len(node_rows) + len(pod_rows), False, "", spans + vspan,
-            buckets)
+            buckets, span_args)
 
     # ------------------------------------------------------------- resync
 
@@ -525,6 +538,11 @@ class DeltaTensorizer:
         self.node_terms = {ni.node_name: any(pod_has_terms(pi, hw)
                                              for pi in ni.pods)
                            for ni in node_infos}
+        # the pods _refresh_terms would walk as owners (it does not ask
+        # the hard weight)
+        self.term_owners = {pi.pod.uid: ni.node_name
+                            for ni in node_infos for pi in ni.pods
+                            if pod_has_terms(pi)}
         self.pod_row = dict(a["_pod_rows"])
         self.next_pod_row = len(self.pod_row)
         self.free_rows = []
@@ -577,7 +595,7 @@ class DeltaTensorizer:
             "delta-resident", self.profile or "default", self.cluster,
             len(self.node_names), meta={"resyncs": self.resync_count})
 
-    def _refresh_terms(self, node_infos) -> None:
+    def _refresh_terms(self, node_infos) -> Dict[str, int]:
         """Term-only rebuild: walk the term OWNERS (a small subset of the
         existing pods), recompile the flattened ExistingTerms against the
         persistent table, and stage them in the mirror for wholesale
@@ -585,23 +603,42 @@ class DeltaTensorizer:
         order as build(), so row content matches a rebuild exactly (term
         pod_idx points at the stable delta rows).  This demotes
         "topology-term structural change" from a full-resync trigger to a
-        bounded partial rebuild."""
-        hw = self.hard_pod_affinity_weight
+        bounded partial rebuild.  Returns what it cost, as the
+        ``delta-terms`` span's args: the pods walked, the term rows
+        recompiled, the buckets they are padded to, and
+        ``owners_changed``: the owners added, removed or moved since the
+        tables were last built (0: a rebuild of tables that had not
+        changed).  The owners are noted on the walk the rebuild makes
+        anyway, and compared once a refresh."""
         filter_owners, score_owners = [], []
+        owners: Dict[str, str] = {}
+        walked = 0
         for ni in node_infos:
+            walked += len(ni.pods)
             for pi in ni.pods:
-                row = self.pod_row[pi.pod.uid]
+                uid = pi.pod.uid
+                row = self.pod_row[uid]
                 if pi.required_anti_affinity_terms:
                     filter_owners.append((pi, row))
+                    owners[uid] = ni.node_name
                 if (pi.preferred_affinity_terms
                         or pi.preferred_anti_affinity_terms
                         or pi.required_affinity_terms):
                     score_owners.append((pi, row))
+                    owners[uid] = ni.node_name
+        was, self.term_owners = self.term_owners, owners
+        changed = 0 if owners == was else (
+            len(owners.items() - was.items())             # added or moved
+            + len(was.keys() - owners.keys()))            # removed
         a = self.host.arrays
         a["filter_terms"] = self.builder._build_terms(filter_owners,
                                                       kind="filter")
         a["score_terms"] = self.builder._build_terms(score_owners,
                                                      kind="score")
+        ft, st = a["filter_terms"].valid, a["score_terms"].valid
+        return {"pods_walked": walked, "owners_changed": changed,
+                "filter_rows": int(ft.sum()), "score_rows": int(st.sum()),
+                "Et": int(ft.shape[0]), "Es": int(st.shape[0])}
 
     def _device_terms(self):
         """The mirror's term tensors as device (mesh: replicated) arrays —
@@ -621,7 +658,9 @@ class DeltaTensorizer:
         return ft, st
 
     def _apply(self, delta: ClusterDelta, donate: bool,
-               replace_terms: bool = False):
+               replace_terms: bool = False, spans: Optional[list] = None):
+        """``spans``: where given, the ``delta-terms-upload`` span of a
+        wholesale term replacement is appended to it."""
         from ..models import programs
         from ..utils import chaos
         cluster = self.cluster
@@ -630,8 +669,11 @@ class DeltaTensorizer:
             # program passes terms through untouched, and a donated
             # pass-through of the OLD terms would invalidate buffers the
             # new cluster no longer uses anyway
+            t_up = wallclock()
             ft, st = self._device_terms()
             cluster = cluster._replace(filter_terms=ft, score_terms=st)
+            if spans is not None:
+                spans.append(("delta-terms-upload", t_up, wallclock()))
         if ujournal.journal() is not None:
             # journal capture: the exact scatter tables (and wholesale
             # term replacement) this cycle applies — pickled eagerly, the
