@@ -44,6 +44,9 @@ Term-carrying pod churn is NOT a resync trigger: the flattened
 ``ExistingTerms`` rebuild from the term OWNERS alone (``_refresh_terms``,
 the ``delta-terms`` span) and replace wholesale — they are small, and a
 1-in-5-pods-with-anti-affinity drain would otherwise resync every cycle.
+They rebuild only when a dirty node's ordered OWNERS changed
+(``node_owners``): plain pods coming and going beside long-lived owners
+keep the resident tables, buffers and all.
 
 Bit-exactness contract (tested by tests/test_delta.py): after any
 sequence of deltas, the resident tensors match a from-scratch ``build()``
@@ -144,8 +147,25 @@ class DeltaStats(NamedTuple):
     delta_buckets: Tuple[int, ...] = ()
     # span name -> the args it is recorded with besides delta_rows: what
     # the term refresh rebuilt ("delta-terms": filter_rows, score_rows,
-    # their buckets Et / Es, pods_walked, owners_changed)
+    # their buckets Et / Es, pods_walked, owners_changed) and whether the
+    # build kept the term tables beside a dirty owner ("delta-build":
+    # terms_kept)
     span_args: Mapping[str, Mapping[str, int]] = MappingProxyType({})
+
+
+class TermOwner(NamedTuple):
+    """Everything one existing pod gives the two term tables: its stable
+    delta row (``pod_idx``) and the parsed term lists its rows compile
+    from, under PodInfo's names (``_build_terms`` reads an owner through
+    them).  Compared by VALUE with the lists themselves held, so an
+    unchanged PodInfo compares by identity and a pod replaced in place
+    under its uid by the content of its terms."""
+    uid: str
+    row: int
+    required_anti_affinity_terms: list
+    preferred_affinity_terms: list
+    preferred_anti_affinity_terms: list
+    required_affinity_terms: list
 
 
 class DeltaTensorizer:
@@ -183,10 +203,10 @@ class DeltaTensorizer:
         self.node_names: List[str] = []          # row order
         self.node_gen: Dict[str, int] = {}
         self.node_pods: Dict[str, List[str]] = {}   # name -> uid list
-        self.node_terms: Dict[str, bool] = {}    # name -> owns term pods
-        # uid -> node of every term owner at the last term rebuild, for
-        # the refresh's ``owners_changed``
-        self.term_owners: Dict[str, str] = {}
+        # name -> the node's term owners in pod order, as the resident
+        # term tables were last built from them: what ``terms_dirty`` and
+        # ``owners_changed`` compare, and what a rebuild compiles
+        self.node_owners: Dict[str, Tuple[TermOwner, ...]] = {}
         self.pod_row: Dict[str, int] = {}        # uid -> row
         self.free_rows: List[int] = []           # kept sorted, pop lowest
         self.next_pod_row = 0
@@ -364,14 +384,6 @@ class DeltaTensorizer:
         if len(dirty) > self.max_delta_frac * max(len(names), 1):
             return self._resync(node_infos, names, "delta-too-large", t0,
                                 pending)
-        # term-carrying pod churn does NOT force a full resync: the
-        # flattened ExistingTerms rebuild from the term OWNERS alone (a
-        # small subset) and replace wholesale — see _refresh_terms
-        hw = self.hard_pod_affinity_weight
-        terms_dirty = any(
-            self.node_terms.get(ni.node_name)
-            or any(pod_has_terms(pi, hw) for pi in ni.pods)
-            for _, ni in dirty)
         # intern BEFORE the width check so new strings from dirty nodes
         # count against the caps the resident tensors were sized with
         self.builder._intern_node_strings([ni for _, ni in dirty])
@@ -443,6 +455,16 @@ class DeltaTensorizer:
                     effect == api.TAINT_EFFECT_PREFER_NO_SCHEDULE)
         image_nodes = a["_image_nodes"]
         node_rows = []
+        # term-carrying pod churn does NOT force a full resync: the
+        # flattened ExistingTerms rebuild from the term OWNERS alone and
+        # replace wholesale, and only when a dirty node's owners are not
+        # the ones the tables were built from (_refresh_terms says why
+        # that is the whole condition).  The anti-entropy verifier cannot
+        # catch a table kept wrongly: it compares the device with the
+        # mirror, and both would be stale together — tests/test_delta.py
+        # holds the kept tables to a fresh build()
+        owners_were: Dict[str, Tuple[TermOwner, ...]] = {}
+        owner_seen = False
         for i, ni in dirty:
             old_imgs = set(np.nonzero(a["images"][i])[0].tolist())
             fill_node_row(a, i, ni, t)
@@ -456,19 +478,28 @@ class DeltaTensorizer:
                 fill_pod_row(a, row, pi, i, t)
                 touched_pods.add(row)
             self.node_pods[ni.node_name] = [pi.pod.uid for pi in ni.pods]
-            self.node_terms[ni.node_name] = any(pod_has_terms(pi, hw)
-                                                for pi in ni.pods)
+            owners = self._owners_of(ni)
+            was = self.node_owners[ni.node_name]
+            if owners != was:
+                owners_were[ni.node_name] = was
+                self.node_owners[ni.node_name] = owners
+            elif owners:
+                owner_seen = True
             self.node_gen[ni.node_name] = ni.generation
             node_rows.append(i)
         # images that no node carries anymore read 0 in a fresh build
         a["image_size"][image_nodes <= 0] = 0.0
         a["image_spread"] = image_nodes / max(float(len(node_infos)), 1.0)
 
+        terms_dirty = bool(owners_were)
         term_span = ()
-        span_args: Dict[str, Dict[str, int]] = {}
+        span_args: Dict[str, Dict[str, int]] = {"delta-build": {
+            "terms_kept": int(owner_seen and not terms_dirty)}}
         if terms_dirty:
             t_terms = wallclock()
-            span_args["delta-terms"] = self._refresh_terms(node_infos)
+            span_args["delta-terms"] = dict(
+                self._refresh_terms(owners_were),
+                pods_walked=sum(len(ni.pods) for _, ni in dirty))
             term_span = (("delta-terms", t_terms, wallclock()),)
 
         pod_rows = sorted(touched_pods)
@@ -534,18 +565,11 @@ class DeltaTensorizer:
         self.node_gen = {ni.node_name: ni.generation for ni in node_infos}
         self.node_pods = {ni.node_name: [pi.pod.uid for pi in ni.pods]
                           for ni in node_infos}
-        hw = self.hard_pod_affinity_weight
-        self.node_terms = {ni.node_name: any(pod_has_terms(pi, hw)
-                                             for pi in ni.pods)
-                           for ni in node_infos}
-        # the pods _refresh_terms would walk as owners (it does not ask
-        # the hard weight)
-        self.term_owners = {pi.pod.uid: ni.node_name
-                            for ni in node_infos for pi in ni.pods
-                            if pod_has_terms(pi)}
         self.pod_row = dict(a["_pod_rows"])
         self.next_pod_row = len(self.pod_row)
         self.free_rows = []
+        self.node_owners = {ni.node_name: self._owners_of(ni)
+                            for ni in node_infos}
         self.caps = self.signature()
         self.cycles_since_resync = 0
         # a resync re-uploads the mirror wholesale, so device == mirror
@@ -595,48 +619,79 @@ class DeltaTensorizer:
             "delta-resident", self.profile or "default", self.cluster,
             len(self.node_names), meta={"resyncs": self.resync_count})
 
-    def _refresh_terms(self, node_infos) -> Dict[str, int]:
-        """Term-only rebuild: walk the term OWNERS (a small subset of the
-        existing pods), recompile the flattened ExistingTerms against the
-        persistent table, and stage them in the mirror for wholesale
-        replacement — the owner collection follows the same node-walk
-        order as build(), so row content matches a rebuild exactly (term
-        pod_idx points at the stable delta rows).  This demotes
-        "topology-term structural change" from a full-resync trigger to a
-        bounded partial rebuild.  Returns what it cost, as the
-        ``delta-terms`` span's args: the pods walked, the term rows
-        recompiled, the buckets they are padded to, and
-        ``owners_changed``: the owners added, removed or moved since the
-        tables were last built (0: a rebuild of tables that had not
-        changed).  The owners are noted on the walk the rebuild makes
-        anyway, and compared once a refresh."""
+    def _owners_of(self, ni) -> Tuple[TermOwner, ...]:
+        """The node's term owners in pod order: the pods that give
+        ``_build_terms`` a row (required affinity owns one only at a
+        nonzero hard weight — ``pod_has_terms``)."""
+        hw, row = self.hard_pod_affinity_weight, self.pod_row
+        return tuple([
+            TermOwner(pi.pod.uid, row[pi.pod.uid],
+                      pi.required_anti_affinity_terms,
+                      pi.preferred_affinity_terms,
+                      pi.preferred_anti_affinity_terms,
+                      pi.required_affinity_terms)
+            for pi in ni.pods if pod_has_terms(pi, hw)])
+
+    def _refresh_terms(self, owners_were) -> Dict[str, int]:
+        """Term-only rebuild: recompile the flattened ExistingTerms from
+        the term OWNERS (a small subset of the existing pods, noted a node
+        in ``node_owners``) against the persistent table, and stage them
+        in the mirror for wholesale replacement — the owners are taken in
+        the same node-walk order as build(), so row content matches a
+        rebuild exactly (term pod_idx points at the stable delta rows).
+        This demotes "topology-term structural change" from a full-resync
+        trigger to a bounded partial rebuild.  ``owners_were``: the dirty
+        nodes whose owners changed, each with its owners as the tables
+        still have them (refresh() has noted the new ones).  Returns what
+        the rebuild did, as args of the ``delta-terms`` span (refresh()
+        adds ``pods_walked``, the dirty nodes' pods it read the owners
+        from): the term rows recompiled, the buckets they are padded to,
+        and ``owners_changed``: the owners whose node, place among their
+        node's owners, row or terms are not what the tables were built
+        from, plus the owners gone (never 0).
+
+        Why refresh() may KEEP the tables while no dirty node's owners
+        changed: both tables are a function of the ordered owner list
+        and of nothing else that moves between resyncs.  A row reads its
+        selector as kv / key ids (``SelectorCompiler.compile(
+        intern_new=True)`` interns what it meets, so no id is ever "not
+        yet known", and ids are append-only), its namespaces and topology
+        key as ids interned WITH the owner (``_intern_node_strings`` on
+        the owner's dirty node), ``pod_idx`` as the owner's delta row
+        (stable while the uid stays on its node; a move frees and
+        re-assigns it, and is a changed owner on both nodes) and its
+        weight (the term's own, or the tensorizer's constant hard
+        weight).  The unique-selector index, ``Et`` / ``U`` / ``Q`` follow
+        from the ordered list; the widths are vocab CAPS, never lengths,
+        and a cap crossing is a resync.  Nothing reads the owner's node
+        (the match looks ``pod_node[pod_idx]`` up on the device), another
+        pod, or a node's labels.  Clean nodes cannot have changed owners
+        (any pod churn bumps the node's generation), so the dirty nodes'
+        owners are all there is to compare, and to note."""
         filter_owners, score_owners = [], []
-        owners: Dict[str, str] = {}
-        walked = 0
-        for ni in node_infos:
-            walked += len(ni.pods)
-            for pi in ni.pods:
-                uid = pi.pod.uid
-                row = self.pod_row[uid]
-                if pi.required_anti_affinity_terms:
-                    filter_owners.append((pi, row))
-                    owners[uid] = ni.node_name
-                if (pi.preferred_affinity_terms
-                        or pi.preferred_anti_affinity_terms
-                        or pi.required_affinity_terms):
-                    score_owners.append((pi, row))
-                    owners[uid] = ni.node_name
-        was, self.term_owners = self.term_owners, owners
-        changed = 0 if owners == was else (
-            len(owners.items() - was.items())             # added or moved
-            + len(was.keys() - owners.keys()))            # removed
+        for name in self.node_names:
+            for o in self.node_owners[name]:
+                if o.required_anti_affinity_terms:
+                    filter_owners.append((o, o.row))
+                if (o.preferred_affinity_terms
+                        or o.preferred_anti_affinity_terms
+                        or o.required_affinity_terms):
+                    score_owners.append((o, o.row))
+
+        def placed(nodes):
+            return {o.uid: (name, k, o) for name, owners in nodes
+                    for k, o in enumerate(owners)}
+        old = placed(owners_were.items())
+        new = placed((name, self.node_owners[name]) for name in owners_were)
+        changed = (sum(1 for uid, at in new.items() if old.get(uid) != at)
+                   + len(old.keys() - new.keys()))
         a = self.host.arrays
         a["filter_terms"] = self.builder._build_terms(filter_owners,
                                                       kind="filter")
         a["score_terms"] = self.builder._build_terms(score_owners,
                                                      kind="score")
         ft, st = a["filter_terms"].valid, a["score_terms"].valid
-        return {"pods_walked": walked, "owners_changed": changed,
+        return {"owners_changed": changed,
                 "filter_rows": int(ft.sum()), "score_rows": int(st.sum()),
                 "Et": int(ft.shape[0]), "Es": int(st.shape[0])}
 

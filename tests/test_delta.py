@@ -183,6 +183,359 @@ def test_randomized_churn_stays_golden():
 
 
 # ---------------------------------------------------------------------------
+# the existing-term tables are KEPT while no dirty node's owners changed:
+# the golden over every way an owner can change
+
+
+COLORS = ("green", "blue", "red", "yellow")
+
+
+def owner_pod(name, node="", kind="green", tier="a"):
+    """A pod that owns existing-term rows, upstream's Mixed row's four
+    templates by colour: ``green`` required hostname anti-affinity,
+    ``blue`` required zone affinity, ``red`` preferred hostname affinity
+    (weight 1), ``yellow`` preferred hostname anti-affinity (weight 1);
+    each selects its own colour.  ``both``: a green pod that also carries
+    blue's required affinity."""
+    color = "green" if kind == "both" else kind
+    p = hollow.make_pod(name)
+    p.metadata.labels = {"color": color, "tier": tier}
+    match = {"color": color}
+    if kind in ("green", "both"):
+        hollow.with_anti_affinity(p, match=match)
+    if kind in ("blue", "both"):
+        hollow.with_affinity(p, match=match)
+    if kind in ("red", "yellow"):
+        term = api.WeightedPodAffinityTerm(
+            weight=1, pod_affinity_term=api.PodAffinityTerm(
+                label_selector=api.LabelSelector(match_labels=match),
+                topology_key=api.LABEL_HOSTNAME))
+        p.spec.affinity = api.Affinity()
+        if kind == "red":
+            p.spec.affinity.pod_affinity = api.PodAffinity()
+            p.spec.affinity.pod_affinity \
+                .preferred_during_scheduling_ignored_during_execution \
+                .append(term)
+        else:
+            p.spec.affinity.pod_anti_affinity = api.PodAntiAffinity()
+            p.spec.affinity.pod_anti_affinity \
+                .preferred_during_scheduling_ignored_during_execution \
+                .append(term)
+    p.spec.node_name = node
+    return p
+
+
+def plain_pod(name, node, tier="b"):
+    p = hollow.make_pod(name)
+    p.metadata.labels = {"tier": tier}
+    p.spec.node_name = node
+    return p
+
+
+class OwnerWorld:
+    """Upstream's Mixed row in small: EVERY node holds a term owner (its
+    colour by node index) and a plain pod, the last node two owners; every
+    label value the steps use is in the vocab from the first build, so no
+    step crosses a cap."""
+
+    def __init__(self, n_nodes=6, hard_pod_affinity_weight=1):
+        self.cache = SchedulerCache()
+        self.nodes = hollow.make_nodes(n_nodes, zones=3)
+        self.owners, self.plains = [], []
+        for i, n in enumerate(self.nodes):
+            n.metadata.labels["rack"] = f"rack-{i % 2}"
+            self.cache.add_node(n)
+            self.add(owner_pod(f"own-{i}", n.name, COLORS[i % 4]))
+            self.add(plain_pod(f"plain-{i}", n.name,
+                               tier="ab"[i % 2]))
+        self.add(owner_pod("own-second", self.nodes[-1].name, "red"))
+        self.dt = DeltaTensorizer(
+            hard_pod_affinity_weight=hard_pod_affinity_weight)
+        self.seq = 0
+        _, st = self.dt.refresh(snapshot_of(self.cache))
+        assert st.resync
+
+    def add(self, p):
+        self.cache.add_pod(p)
+        (self.owners if p.spec.affinity is not None
+         else self.plains).append(p)
+        return p
+
+    def remove(self, p):
+        self.cache.remove_pod(p)
+        (self.owners if p.spec.affinity is not None
+         else self.plains).remove(p)
+
+    def move(self, p, node):
+        """The same uid on another node (or taken off its node and put
+        back: last in its pod order)."""
+        self.remove(p)
+        q = copy.copy(p)
+        q.spec = copy.copy(p.spec)
+        q.spec.node_name = node
+        return self.add(q)
+
+    def update(self, old, new):
+        self.cache.update_pod(old, new)
+        pods = self.owners if old.spec.affinity is not None else self.plains
+        pods.remove(old)
+        (self.owners if new.spec.affinity is not None
+         else self.plains).append(new)
+        return new
+
+    def relabel_node(self, i):
+        """The node takes the other rack: a label change inside the
+        vocab (not the zone: the node tree orders by it), no pod churn."""
+        old = self.nodes[i]
+        new = copy.deepcopy(old)
+        new.metadata.labels["rack"] = (
+            "rack-1" if old.metadata.labels["rack"] == "rack-0"
+            else "rack-0")
+        new.spec.unschedulable = not old.spec.unschedulable
+        self.cache.update_node(old, new)
+        self.nodes[i] = new
+
+    def fresh_name(self, prefix):
+        self.seq += 1
+        return f"{prefix}-{self.seq}"
+
+    def refresh(self, expect=None):
+        """One refresh, held to a from-scratch build() leaf for leaf; and
+        to what the step expects of the term tables: ``kept`` (a dirty
+        node held an owner, nothing of the term refresh ran), ``rebuilt``,
+        or ``untouched`` (no dirty node held an owner)."""
+        infos = snapshot_of(self.cache)
+        _, st = self.dt.refresh(infos)
+        assert_matches_fresh(self.dt, infos)
+        names = [n for n, _, _ in st.spans]
+        if "delta-build" in names:
+            kept = st.span_args["delta-build"]["terms_kept"]
+            rebuilt = "delta-terms" in names
+            assert ("delta-terms-upload" in names) == (
+                rebuilt and not st.resync)
+            assert set(st.span_args) == (
+                {"delta-build", "delta-terms"} if rebuilt
+                else {"delta-build"})
+            assert not (kept and rebuilt)
+            if rebuilt:
+                # a rebuild always has a changed owner to show for itself
+                assert st.span_args["delta-terms"]["owners_changed"] >= 1
+            got = "rebuilt" if rebuilt else "kept" if kept else "untouched"
+        else:
+            assert st.span_args == {}
+            got = "resync" if st.resync else "noop"
+        if expect is not None:
+            assert got == expect, (got, expect, st.reason, names)
+        return st, got
+
+
+def _plain_arrives(w):
+    w.add(plain_pod("late-plain", w.nodes[0].name))
+    yield "kept"
+
+
+def _plain_leaves(w):
+    w.remove(w.plains[2])
+    yield "kept"
+
+
+def _owner_arrives_and_leaves(w):
+    p = w.add(owner_pod("late-owner", w.nodes[1].name, "yellow"))
+    yield "rebuilt"
+    w.add(plain_pod("after", w.nodes[1].name))
+    yield "kept"
+    w.remove(p)
+    yield "rebuilt"
+    w.add(plain_pod("after-2", w.nodes[1].name))
+    yield "kept"
+
+
+def _owner_moves_up(w):
+    w.move(w.owners[1], w.nodes[4].name)
+    yield "rebuilt"
+    w.add(plain_pod("after", w.nodes[4].name))
+    w.add(plain_pod("after-2", w.nodes[1].name))
+    yield "kept"
+
+
+def _owner_moves_to_a_lower_node(w):
+    w.move(w.owners[4], w.nodes[0].name)
+    yield "rebuilt"
+    w.add(plain_pod("after", w.nodes[0].name))
+    yield "kept"
+
+
+def _two_owners_swap_order(w):
+    last = w.nodes[-1].name
+    first = next(p for p in w.owners if p.spec.node_name == last)
+    w.move(first, last)                # same node, same row: now walked last
+    yield "rebuilt"
+    w.add(plain_pod("after", last))
+    yield "kept"
+
+
+def _owner_replaced_in_place_with_other_terms(w):
+    old = w.owners[0]                                  # green
+    new = owner_pod(old.metadata.name, old.spec.node_name, "yellow")
+    new.metadata.uid = old.uid
+    new.metadata.labels = dict(old.metadata.labels)
+    assert new.uid == old.uid
+    w.update(old, new)
+    yield "rebuilt"
+    w.add(plain_pod("after", old.spec.node_name))
+    yield "kept"
+
+
+def _owner_updated_in_place_with_equal_terms(w):
+    """Re-parsed term lists that read the same compile the same rows."""
+    old = w.owners[0]
+    new = copy.deepcopy(old)
+    new.metadata.labels["tier"] = "b"
+    w.update(old, new)
+    yield "kept"
+
+
+def _owner_node_relabelled(w):
+    w.relabel_node(2)
+    yield "kept"
+
+
+def _kept_cycle_grows_the_pod_axis(w):
+    pp0 = w.dt.host.arrays["pod_node"].shape[0]
+    free = pp0 - len(w.dt.pod_row)
+    for k in range(free + 1):
+        w.add(plain_pod(f"fill-{k}", w.nodes[k % len(w.nodes)].name))
+    st, got = w.refresh()
+    assert st.resync and st.reason == "pod-axis-growth" and got == "kept"
+    assert [n for n, _, _ in st.spans] == ["delta-build", "resync"]
+    assert w.dt.host.arrays["pod_node"].shape[0] > pp0
+    w.add(plain_pod("after", w.nodes[0].name))
+    yield "kept"
+
+
+def _kept_straight_after_a_resync(w):
+    w.cache.add_node(hollow.make_node("late-node", zone="zone-0"))
+    st, _ = w.refresh("resync")
+    assert st.reason == "node-set"
+    w.add(plain_pod("after", w.nodes[0].name))
+    yield "kept"
+    w.dt.cycles_since_resync = w.dt.resync_interval
+    w.add(plain_pod("after-2", w.nodes[1].name))
+    st, _ = w.refresh("resync")
+    assert st.reason == "anti-entropy"
+    w.add(plain_pod("after-3", w.nodes[1].name))
+    yield "kept"
+    w.move(w.owners[0], w.nodes[3].name)
+    yield "rebuilt"
+
+
+def _plain_pod_on_a_node_without_owner(w):
+    w.remove(w.owners[0])
+    yield "rebuilt"
+    w.add(plain_pod("after", w.nodes[0].name))
+    yield "untouched"
+
+
+OWNER_CHANGES = [
+    _plain_arrives, _plain_leaves, _owner_arrives_and_leaves,
+    _owner_moves_up, _owner_moves_to_a_lower_node, _two_owners_swap_order,
+    _owner_replaced_in_place_with_other_terms,
+    _owner_updated_in_place_with_equal_terms, _owner_node_relabelled,
+    _kept_cycle_grows_the_pod_axis, _kept_straight_after_a_resync,
+    _plain_pod_on_a_node_without_owner]
+
+
+@pytest.mark.parametrize("steps", OWNER_CHANGES,
+                         ids=[f.__name__.strip("_") for f in OWNER_CHANGES])
+def test_term_tables_kept_or_rebuilt_stay_golden(steps):
+    """Every way an owner can change, each refresh of the sequence held to
+    a fresh build(): the only fault keeping can introduce is a table kept
+    that should have been rebuilt, and this is what catches it."""
+    w = OwnerWorld()
+    for expect in steps(w):
+        w.refresh(expect)
+
+
+@pytest.mark.parametrize("hw", [1, 0])
+def test_required_affinity_owns_a_row_only_at_a_hard_weight(hw):
+    """``hard_pod_affinity_weight`` 0: required affinity compiles to no
+    score row, so a pod with nothing else is no owner (the dirty-node
+    check and the rebuild's walk ask ``pod_has_terms(pi, hw)`` alike),
+    while a pod that also carries anti-affinity still is one."""
+    w = OwnerWorld(hard_pod_affinity_weight=hw)
+    node = w.nodes[2].name                       # its own owner: red
+    blue = w.add(owner_pod("late-blue", node, "blue"))
+    st, _ = w.refresh("rebuilt" if hw else "kept")
+    rows = int(np.asarray(w.dt.cluster.score_terms.valid).sum())
+    assert rows == (6 if hw else 3)      # blue x 3, red x 2, yellow
+    w.add(plain_pod("after", node))
+    w.refresh("kept")
+    w.remove(blue)
+    w.refresh("rebuilt" if hw else "kept")
+    w.add(owner_pod("late-both", node, "both"))
+    w.refresh("rebuilt")
+    w.add(plain_pod("after-2", node))
+    w.refresh("kept")
+
+
+@pytest.mark.parametrize("hw", [1, 0])
+def test_randomized_owner_churn_stays_golden(hw):
+    """200 steps mixing every case above on a world of owners: plain and
+    owner arrivals and departures, moves, reorders, in-place replacements
+    with other and with equal terms, node relabels, pod-axis growth and
+    forced anti-entropy resyncs — the resident tables match a rebuild
+    after EVERY refresh, kept or rebuilt."""
+    rng = random.Random(30 + hw)
+    w = OwnerWorld(n_nodes=8, hard_pod_affinity_weight=hw)
+    kinds = COLORS + ("both",)
+    seen = {"kept": 0, "rebuilt": 0, "untouched": 0, "resync": 0,
+            "noop": 0}
+    for step in range(200 if hw else 60):
+        for _ in range(rng.choice([1, 1, 2, 3])):
+            op = rng.choice(["plain", "plain", "plain-leaves",
+                             "plain-leaves", "owner", "owner-leaves",
+                             "move", "reorder", "replace", "update",
+                             "relabel", "anti-entropy"])
+            node = rng.choice(w.nodes).name
+            if op == "plain":
+                w.add(plain_pod(w.fresh_name("p"), node,
+                                tier=rng.choice("ab")))
+            elif op == "plain-leaves" and w.plains:
+                w.remove(rng.choice(w.plains))
+            elif op == "owner":
+                w.add(owner_pod(w.fresh_name("o"), node, rng.choice(kinds),
+                                tier=rng.choice("ab")))
+            elif op == "owner-leaves" and w.owners:
+                w.remove(rng.choice(w.owners))
+            elif op == "move" and w.owners:
+                w.move(rng.choice(w.owners), node)
+            elif op == "reorder" and w.owners:
+                p = rng.choice(w.owners)
+                w.move(p, p.spec.node_name)
+            elif op == "replace" and w.owners:
+                old = rng.choice(w.owners)
+                new = owner_pod(old.metadata.name, old.spec.node_name,
+                                rng.choice(kinds))
+                new.metadata.uid = old.uid
+                w.update(old, new)
+            elif op == "update" and (w.owners or w.plains):
+                old = rng.choice(w.owners + w.plains)
+                new = copy.deepcopy(old)
+                new.metadata.labels["tier"] = rng.choice("ab")
+                w.update(old, new)
+            elif op == "relabel":
+                w.relabel_node(rng.randrange(len(w.nodes)))
+            elif op == "anti-entropy" and rng.random() < 0.3:
+                w.dt.cycles_since_resync = w.dt.resync_interval
+        st, got = w.refresh()
+        seen[got] += 1
+        if st.resync:
+            assert st.reason in ("pod-axis-growth", "anti-entropy"), \
+                st.reason
+    assert seen["kept"] >= 10 and seen["rebuilt"] >= 10, seen
+
+
+# ---------------------------------------------------------------------------
 # fallback triggers
 
 

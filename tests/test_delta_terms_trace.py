@@ -1,8 +1,16 @@
 """What the term refresh says about itself (PR 29): the ``delta-terms``
 span's args (rows rebuilt, their buckets, pods walked, owners changed),
 the ``delta-terms-upload`` span around the wholesale replacement on the
-device, and the ``(Et, Es)`` the auction ran with on the cycle's meta."""
+device, and the ``(Et, Es)`` the auction ran with on the cycle's meta.
+Since PR 30 the refresh runs only when a dirty node's owners changed: a
+kept cycle carries neither span and says ``terms_kept`` 1 on its
+``delta-build``; the journal, the replay rig and a pipelined drain see
+the kept tables as they saw a cycle without owners."""
 
+import copy
+import pickle
+
+import jax
 import pytest
 
 from kubetpu.api import types as api
@@ -13,7 +21,10 @@ from kubetpu.harness import hollow
 from kubetpu.scheduler import Scheduler
 from kubetpu.state.cache import SchedulerCache, Snapshot
 from kubetpu.state.delta import DeltaTensorizer
+from kubetpu.utils import journal as ujournal
 from kubetpu.utils import trace as utrace
+from kubetpu.utils.journal import read_records
+from tools.kubereplay import replay_journal
 
 ARGS = {"filter_rows", "score_rows", "Et", "Es", "pods_walked",
         "owners_changed"}
@@ -67,23 +78,35 @@ def test_a_refresh_says_what_it_rebuilt_and_how_many_owners_changed():
         at = {n: (t0, t1) for n, t0, t1 in st.spans}
         assert at["delta-terms"][1] <= at["delta-terms-upload"][0] \
             <= at["delta-terms-upload"][1] <= at["delta-apply"][1]
-        assert set(st.span_args) == {"delta-terms"}
+        assert set(st.span_args) == {"delta-build", "delta-terms"}
+        assert st.span_args["delta-build"] == {"terms_kept": 0}
         assert set(st.span_args["delta-terms"]) == ARGS
         return st.span_args["delta-terms"]
 
-    # a PLAIN pod lands on a node that holds a term owner: the tables are
-    # rebuilt whole, and no owner had changed
+    # a PLAIN pod lands on a node that holds a term owner: no owner
+    # changed, so the tables are kept and nothing of the refresh runs
     plain = hollow.make_pod("plain-0")
     plain.spec.node_name = nodes[0].name
     cache.add_pod(plain)
-    assert refresh() == {"filter_rows": 6, "score_rows": 3, "Et": 8, "Es": 4,
-                         "pods_walked": 10, "owners_changed": 0}
+    kept_terms = (dt.cluster.filter_terms, dt.cluster.score_terms)
+    cluster, st = dt.refresh(_snapshot(cache), donate=False)
+    assert not st.resync, st.reason
+    assert [n for n, _, _ in st.spans] == ["delta-build", "delta-apply"]
+    assert st.span_args == {"delta-build": {"terms_kept": 1}}
+    # undonated (an in-flight pipelined cycle still reads them), the kept
+    # tables are the buffers the last build uploaded, still readable
+    for was, now in zip(jax.tree.leaves(kept_terms),
+                        jax.tree.leaves((cluster.filter_terms,
+                                         cluster.score_terms))):
+        assert not was.is_deleted()
+        assert (was == now).all()
     # an owner arrives; an owner moves (one uid: counted once); one leaves
     extra = _owner("green-extra", nodes[1].name)
     cache.add_pod(extra)
     got = refresh()
-    assert (got["filter_rows"], got["pods_walked"],
-            got["owners_changed"]) == (7, 11, 1)
+    # walked: the dirty node's pods (its green, its red, the new one)
+    assert got == {"filter_rows": 7, "score_rows": 3, "Et": 8, "Es": 4,
+                   "pods_walked": 3, "owners_changed": 1}
     cache.remove_pod(extra)
     extra.spec.node_name = nodes[2].name
     cache.add_pod(extra)
@@ -106,56 +129,185 @@ def flight():
         utrace.disarm_flight_recorder()
 
 
-def test_the_cycle_record_carries_the_refreshs_args_and_the_term_buckets(
-        flight):
-    """Upstream's Mixed row in small: every node holds a term owner, so
-    every cycle's dirty nodes mark the terms dirty.  Plain traffic (a
-    batch binds, an older pod leaves: the departure is what takes the
-    cycle off the chain and onto the delta path, as the benchmark's
-    client does) reads ``owners_changed`` 0; the cycle after a labelled
-    owner bound reads 1."""
+def _mixed_world(n_nodes=12):
+    """Upstream's Mixed row in small: every node holds a green owner
+    (required hostname anti-affinity) and a red one (preferred
+    affinity), so every cycle's dirty nodes hold owners."""
     store = ClusterStore()
-    nodes = hollow.make_nodes(12, zones=1)
+    nodes = hollow.make_nodes(n_nodes, zones=1)
     for i, n in enumerate(nodes):
         store.add(n)
         store.add(_owner(f"green-{i}", n.name))
         store.add(_owner(f"red-{i}", n.name, preferred=True))
-    sched = Scheduler(store, config=KubeSchedulerConfiguration(
-        profiles=[KubeSchedulerProfile()], batch_size=8, mode="gang"),
+    return store, nodes
+
+
+def _mixed_scheduler(store, **kw):
+    return Scheduler(store, config=KubeSchedulerConfiguration(
+        profiles=[KubeSchedulerProfile()], batch_size=8, mode="gang", **kw),
         async_binding=False)
 
-    def cycle(pods, leaves=None):
-        before = len(flight.cycles())
-        if leaves is not None:
-            store.delete(store.get_pod("default", leaves))
-        for p in pods:
-            store.add(p)
-        while sched.schedule_pending(timeout=0.0):
-            pass
-        return [c.to_dict() for c in flight.cycles()][before:]
+
+def _drive(store, sched, pods, leaves=None):
+    """One batch through the scheduler, an older pod leaving first: the
+    departure is what takes the cycle off the chain and onto the delta
+    path, as the benchmark's client does."""
+    if leaves is not None:
+        store.delete(store.get_pod("default", leaves))
+    for p in pods:
+        store.add(p)
+    while sched.schedule_pending(timeout=0.0):
+        pass
+
+
+def _kept_rebuilt_kept(store, sched):
+    """Plain traffic (kept), a labelled owner binds (its own cycle still
+    kept: it is pending; the 38 bound pods outgrow the 32-row pod axis, so
+    the kept tables ride a re-upload), the cycle after it (rebuilt), plain
+    again (kept)."""
+    _drive(store, sched, hollow.make_pods(8, prefix="warm-"))   # the resync
+    _drive(store, sched, hollow.make_pods(8, prefix="plain-"),
+           leaves="warm-0")
+    _drive(store, sched, [_owner("red-new", preferred=True)],
+           leaves="warm-1")
+    _drive(store, sched, hollow.make_pods(8, prefix="later-"),
+           leaves="warm-2")
+    _drive(store, sched, hollow.make_pods(8, prefix="last-"),
+           leaves="warm-3")
+
+
+def test_the_cycle_record_carries_the_refreshs_args_and_the_term_buckets(
+        flight):
+    """Plain traffic on the small mixed world carries NO ``delta-terms``
+    span (``terms_kept`` 1 on ``delta-build``, the buckets of the kept
+    tables on the meta); the cycle after a labelled owner bound carries
+    one, with ``owners_changed`` 1."""
+    store, _ = _mixed_world()
+    sched = _mixed_scheduler(store)
     try:
-        cycle(hollow.make_pods(8, prefix="warm-"))          # the resync
-        plain = cycle(hollow.make_pods(8, prefix="plain-"), leaves="warm-0")
-        cycle([_owner("red-new", preferred=True)], leaves="warm-1")  # binds
-        after = cycle(hollow.make_pods(8, prefix="later-"), leaves="warm-2")
+        _kept_rebuilt_kept(store, sched)
     finally:
         sched.close()
+    records = [c.to_dict() for c in flight.cycles()]
+    assert len(records) == 5
+    assert [r["meta"]["resync"] for r in records] == [
+        True, False, True, False, False]
+    assert [e["args"]["reason"] for e in records[2]["events"]
+            if e["name"] == "resync"] == ["pod-axis-growth"]
 
-    def refresh_of(records):
-        assert len(records) == 1 and records[0]["meta"]["resync"] is False
-        spans = {s["name"]: s for s in records[0]["spans"]}
-        assert {"delta-terms", "delta-terms-upload"} <= set(spans)
-        # beside the tensorizer's other spans in the tree, inside the phase
-        for name in ("delta-terms", "delta-terms-upload"):
-            assert spans[name]["parent"] == spans["delta-build"]["parent"]
-            assert spans["tensorize"]["t0"] <= spans[name]["t0"] \
-                <= spans[name]["t1"] <= spans["tensorize"]["t1"]
-        args = spans["delta-terms"]["args"]
-        assert ARGS <= set(args)
-        assert records[0]["meta"]["term_buckets"] == [args["Et"], args["Es"]]
-        return args
-    first, second = refresh_of(plain), refresh_of(after)
-    assert (first["filter_rows"], first["score_rows"], first["Et"],
-            first["Es"], first["owners_changed"]) == (12, 12, 16, 16, 0)
-    assert first["pods_walked"] == 24 + 8 - 1
-    assert (second["score_rows"], second["owners_changed"]) == (13, 1)
+    def spans_of(record):
+        return {s["name"]: s for s in record["spans"]}
+    for record in (records[1], records[2], records[4]):
+        spans = spans_of(record)
+        assert not {"delta-terms", "delta-terms-upload"} & set(spans)
+        assert spans["delta-build"]["args"]["terms_kept"] == 1
+    assert records[1]["meta"]["term_buckets"] == [16, 16]
+    spans = spans_of(records[3])
+    assert spans["delta-build"]["args"]["terms_kept"] == 0
+    # beside the tensorizer's other spans in the tree, inside the phase
+    for name in ("delta-terms", "delta-terms-upload"):
+        assert spans[name]["parent"] == spans["delta-build"]["parent"]
+        assert spans["tensorize"]["t0"] <= spans[name]["t0"] \
+            <= spans[name]["t1"] <= spans["tensorize"]["t1"]
+    args = spans["delta-terms"]["args"]
+    assert ARGS <= set(args)
+    assert records[3]["meta"]["term_buckets"] == [args["Et"], args["Es"]]
+    assert (args["filter_rows"], args["score_rows"], args["Et"], args["Es"],
+            args["owners_changed"]) == (12, 13, 16, 16, 1)
+    # the pods of the dirty nodes (the owner's and the departure's), not
+    # the cluster's 38
+    assert 3 <= args["pods_walked"] <= 8
+    assert records[4]["meta"]["term_buckets"] == [16, 16]
+
+
+def test_the_journal_captures_no_terms_on_a_kept_cycle_and_replays_it(
+        tmp_path):
+    """A kept cycle journals ``("delta", (delta, None))`` as a cycle
+    without owners does, a rebuilt one both tables; kubereplay carries the
+    resident tables over the kept records and bit-matches the
+    kept-rebuilt-kept window."""
+    d = str(tmp_path / "journal")
+    ujournal.disarm_journal()
+    ujournal.arm_journal(d)
+    store, _ = _mixed_world()
+    sched = _mixed_scheduler(store)
+    try:
+        _kept_rebuilt_kept(store, sched)
+    finally:
+        sched.close()
+        ujournal.disarm_journal()
+    recs = [rec for _s, rec, _k in read_records(d)]
+    assert [r["input"] for r in recs] == ["resync", "delta", "resync",
+                                          "delta", "delta"]
+    kept, rebuilt, last = (pickle.loads(recs[i]["input_payload"])[1]
+                           for i in (1, 3, 4))
+    assert kept is None and last is None
+    ft, st = rebuilt
+    assert (int(ft.valid.sum()), int(st.valid.sum())) == (12, 13)
+    rep = replay_journal(d)
+    assert rep["replayed"] == rep["matched"] == 5 and rep["skipped"] == []
+    assert rep["bit_match"] is True and rep["first_divergence"] is None
+
+
+def test_a_pipelined_drain_over_owner_nodes_places_as_the_serial_one():
+    """Depth 2, every node an owner's: a node update lands while a cycle
+    is dispatched and uncommitted, so the next prepare's refresh KEEPS the
+    tables with ``donate=False`` (the in-flight cycle's commit-side device
+    work still reads them).  Label-only green pods in the stream are
+    placed by the kept ``filter_terms`` alone; the drain places every pod
+    where the serial drain does."""
+    def drain(**kw):
+        store, nodes = _mixed_world(n_nodes=8)
+        # half the nodes hold no green owner: where a green label may go
+        for i in range(0, 8, 2):
+            store.delete(store.get_pod("default", f"green-{i}"))
+        sched = _mixed_scheduler(store, **kw)
+        seen = []
+        orig = DeltaTensorizer.refresh
+
+        def spy(self, node_infos, pending=(), donate=True):
+            cluster, st = orig(self, node_infos, pending=pending,
+                               donate=donate)
+            kept = st.span_args.get("delta-build", {}).get("terms_kept")
+            seen.append((donate, kept))
+            return cluster, st
+        DeltaTensorizer.refresh = spy
+        try:
+            pods = hollow.make_pods(40, prefix="pd-", cpu_milli=100)
+            for i, p in enumerate(pods):
+                if i % 5 == 0:
+                    p.metadata.labels = {"color": "green"}
+                store.add(p)
+            out, flips = [], 0
+            for _ in range(40):
+                got = sched.schedule_pending(timeout=0.0)
+                out.extend(got)
+                if not got and not len(sched.queue):
+                    break
+                # a node update inside the vocab: no placement reads it,
+                # the chain breaks and the node (an owner's) is dirty
+                n = copy.deepcopy(nodes[flips % 8])
+                n.metadata.labels["flap"] = "on"
+                n.spec.unschedulable = False
+                store.update(n)
+                nodes[flips % 8] = n
+                flips += 1
+            out.extend(sched.flush_pipeline())
+        finally:
+            DeltaTensorizer.refresh = orig
+            sched.close()
+        placed = {o.pod.metadata.name: o.node for o in out}
+        assert len(out) == len(placed) == 40 and all(placed.values())
+        return placed, seen, store
+
+    serial, seen, _ = drain(chain_cycles=True)
+    assert (True, 1) in seen and not any(d is False for d, _ in seen)
+    piped, seen, store = drain(chain_cycles=True, pipeline_cycles=True,
+                               pipeline_depth=2)
+    assert (False, 1) in seen, seen
+    assert piped == serial
+    # the kept filter table was live: no green label on a green owner's node
+    for name, node in piped.items():
+        pod = store.get_pod("default", name)
+        if pod.metadata.labels.get("color") == "green":
+            assert int(node.rsplit("-", 1)[1]) % 2 == 0, (name, node)
