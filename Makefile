@@ -3,7 +3,7 @@
 # lint gate via tests/test_kubelint.py).  `make help` lists everything.
 
 .PHONY: help lint lock-graph test sanitize-test race-test flight-test \
-	delta-test census census-test aot aot-test pallas-test chaos-test \
+	delta-test census census-test aot aot-test chaos-test \
 	slo-test pipeline-test journal-test replay-test devstats-test \
 	mesh-test exact exact-test close close-test load-test load-soak \
 	trend trace bench
@@ -42,11 +42,6 @@ help:
 	@echo "                      with bit-identical placements, capture->serve"
 	@echo "                      signature hits, env-drift fallback, index"
 	@echo "                      gate, persistent-cache config coverage"
-	@echo "  make pallas-test    Pallas megakernel differential suite:"
-	@echo "                      lax-vs-pallas-interpret bit-match oracle"
-	@echo "                      (randomized churned clusters + goldens +"
-	@echo "                      compile-once watchdog); reasoned skip when"
-	@echo "                      pallas is unavailable"
 	@echo "  make chaos-test     chaos harness + self-healing runtime suite:"
 	@echo "                      seeded fault injection (dispatch, delta"
 	@echo "                      scatter, aot load, bind/extender/watch"
@@ -87,10 +82,10 @@ help:
 	@echo "                      windowed rounds, serving path incl. the"
 	@echo "                      double-buffered batch upload)"
 	@echo "  make exact          re-prove the exact-reduction invariant over"
-	@echo "                      every mesh/Pallas root and rewrite the"
+	@echo "                      every mesh root and rewrite the"
 	@echo "                      committed EXACT_MANIFEST.json (tools/"
 	@echo "                      kubeexact --write); run after an INTENTIONAL"
-	@echo "                      collective/VMEM surface change"
+	@echo "                      collective surface change"
 	@echo "  make exact-test     exactness prover suite: every prover rule"
 	@echo "                      fires on a bad snippet, clean snippet empty,"
 	@echo "                      manifest byte-idempotence + drift gate,"
@@ -184,15 +179,6 @@ aot-test:
 	JAX_PLATFORMS=cpu python -m pytest \
 		tests/test_aot.py tests/test_compilation.py -q -p no:cacheprovider
 
-# Pallas megakernel (kubetpu/ops/pallas_kernels.py): the fused
-# filter->score->propose auction round vs the lax oracle, interpret=True
-# on CPU; `make bench` adds the backend_compare case with the round
-# histogram.  Environments without jax.experimental.pallas skip with a
-# reason, never fail.
-pallas-test:
-	JAX_PLATFORMS=cpu python -m pytest \
-		tests/test_pallas_gang.py -q -m 'not slow' -p no:cacheprovider
-
 # pod-axis mesh scale-out (kubetpu/parallel/shardmap.py): the explicit
 # shard_map auction/scan vs the single-device oracle on the 8-virtual-CPU
 # mesh — the previously env-gated (2,4)/(4,2) shapes, ungated
@@ -253,11 +239,11 @@ devstats-test:
 	JAX_PLATFORMS=cpu python -m pytest \
 		tests/test_devstats.py -q -p no:cacheprovider
 
-# jaxpr-level exactness prover + collective/VMEM census (tools/
-# kubeexact): abstract interpretation of every exact-marked mesh/Pallas
-# root proves each cross-shard/cross-tile reduction is float max/min or
-# an integer-valued sum bounded below 2**24, enumerates the collective
-# surface, and budgets the Pallas kernel's VMEM; --write rewrites the
+# jaxpr-level exactness prover + collective census (tools/
+# kubeexact): abstract interpretation of every exact-marked mesh
+# root proves each cross-shard reduction is float max/min or
+# an integer-valued sum bounded below 2**24 and enumerates the
+# collective surface; --write rewrites the
 # committed EXACT_MANIFEST.json (byte-identical when the surface is
 # unchanged).  `make lint` / ci_lint.sh fail on drift.
 exact:

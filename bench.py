@@ -203,7 +203,7 @@ def _latency_block(trk):
 
 def _rounds_hist(cycle_rounds):
     """Per-cycle auction round HISTOGRAM {rounds: cycles} — the shape of
-    the round distribution, not just its max, so a megakernel/windowing
+    the round distribution, not just its max, so a windowing
     change that shifts the tail is visible in the committed JSON."""
     hist = {}
     for r in cycle_rounds:
@@ -213,7 +213,7 @@ def _rounds_hist(cycle_rounds):
 
 def run_mode(mode, n_nodes, n_pods, existing_per_node, repeats,
              mesh_shape=None, batch_cap=None, chain=None, ipa_heavy=False,
-             pipeline=False, kernel_backend="lax", pipeline_depth=None):
+             pipeline=False, pipeline_depth=None):
     """One full e2e measurement: fresh store + scheduler per attempt; the
     first attempt pays XLA compiles (bounded by the persistent cache),
     later attempts reuse the in-process jit cache.  Pod counts above
@@ -262,8 +262,7 @@ def run_mode(mode, n_nodes, n_pods, existing_per_node, repeats,
             profiles=[KubeSchedulerProfile()],
             batch_size=min(n_pods, batch_cap), mode=mode,
             mesh_shape=mesh_shape, chain_cycles=chain,
-            pipeline_cycles=pipeline, kernel_backend=kernel_backend,
-            pipeline_depth=pipeline_depth)
+            pipeline_cycles=pipeline, pipeline_depth=pipeline_depth)
         sched = Scheduler(store, config=cfg, async_binding=False)
         for p in pending:
             store.add(p)
@@ -323,7 +322,6 @@ def run_mode(mode, n_nodes, n_pods, existing_per_node, repeats,
         if mode == "gang":
             stats["auction_rounds_max"] = max(cycle_rounds, default=0)
             stats["auction_rounds_hist"] = _rounds_hist(cycle_rounds)
-            stats["kernel_backend"] = kernel_backend
             # analytic matmul-FLOP lower bound (kubetpu/utils/flops.py):
             # achieved TFLOP/s over MEASURED device time when devstats is
             # armed (deep-timing fences, kubetpu/utils/devstats.py) —
@@ -522,13 +520,6 @@ def northstar_gate(detail, path="NORTHSTAR.json"):
         failures.append(
             "warm_restart: restart-mode placements diverged (cold / "
             "cache-warm / aot-artifact must be bit-identical)")
-    # same contract for the kernel backends: the lax path is the Pallas
-    # megakernel's bit-match oracle — divergence is a correctness failure
-    # on every jax backend, perf floors or not
-    if detail.get("backend_compare", {}).get("placements_match") is False:
-        failures.append(
-            "backend_compare: pallas placements diverged from the lax "
-            "oracle (bit-identity contract, ops/pallas_kernels.py)")
     # ...and for the pipeline depths: depth-1 is the synchronous oracle
     # the depth-k executor must reproduce bit-for-bit
     if detail.get("pipeline_depth", {}).get("placements_match") is False:
@@ -1433,88 +1424,6 @@ def rescore_case(n_pods=51200, n_nodes=10240, chunk=4096):
     return out
 
 
-def backend_compare_case(n_nodes=512, n_pods=2048, existing_per_node=2,
-                         batch_cap=1024):
-    """kernel_backend comparison (ROADMAP item 3): the SAME deterministic
-    TERM-FREE world — the Pallas megakernel's supported surface, where
-    needs_topo routes intra_batch_topology=False — drained once per
-    backend.  Placements must be BIT-IDENTICAL (the lax path is the
-    oracle); under BENCH_GATE a mismatch fails the run like
-    warm_restart's placements_match, with no recorded floor needed.  On
-    CPU the pallas path runs interpret=True so its seconds carry no perf
-    claim (parity only); the JSON schema carries kernel_backend + the
-    per-cycle round histogram either way, so a TPU run can gate
-    device_wait_s / round-count wins without schema churn."""
-    from kubetpu.apis.config import (KubeSchedulerConfiguration,
-                                     KubeSchedulerProfile)
-    from kubetpu.client.store import ClusterStore
-    from kubetpu.harness import hollow
-    from kubetpu.scheduler import Scheduler
-    from kubetpu.utils import pallas_backend as PB
-
-    def run(backend):
-        dev = _devstats()
-        if dev is not None:
-            dev.clear()
-        store = ClusterStore()
-        for i, n in enumerate(hollow.make_nodes(n_nodes, zones=8)):
-            store.add(n)
-            for p in hollow.make_pods(existing_per_node, prefix=f"ex-{i}-",
-                                      group_labels=16):
-                p.spec.node_name = n.name
-                store.add(p)
-        # group_labels=0: no controller spread selectors, no topology
-        # terms — the batch shape the megakernel serves
-        pending = hollow.make_pods(n_pods, prefix="pend-", group_labels=0)
-        cfg = KubeSchedulerConfiguration(
-            profiles=[KubeSchedulerProfile()],
-            batch_size=min(n_pods, batch_cap), mode="gang",
-            kernel_backend=backend)
-        sched = Scheduler(store, config=cfg, async_binding=False)
-        for p in pending:
-            store.add(p)
-        sched.device_wait_s = 0.0
-        sched.device_flops = 0.0
-        placements = {}
-        rounds = []
-        t0 = time.time()
-        while True:
-            out = sched.schedule_pending(timeout=0.2)
-            if not out:
-                break
-            rounds.append(sched.last_gang_rounds)
-            for o in out:
-                placements[o.pod.metadata.name] = o.node
-        dt = time.time() - t0
-        stats = {"kernel_backend": backend,
-                 "e2e_s": round(dt, 3),
-                 "device_wait_s": round(sched.device_wait_s, 3),
-                 "placed": sum(1 for v in placements.values() if v),
-                 "auction_rounds_max": max(rounds, default=0),
-                 "auction_rounds_hist": _rounds_hist(rounds)}
-        # measured per-backend device time + achieved FLOP/s: the
-        # number a TPU run gates the Mosaic win on (device_wait_s is
-        # the readback block; the fenced measurement survives overlap)
-        measured = _measured_device_s(dev, "run_auction", len(rounds))
-        if measured > 0:
-            stats["device_time_s"] = round(measured, 3)
-            _achieved(stats, sched.device_flops, measured)
-        if dev is not None:
-            stats["device"] = dev.summary()
-        sched.close()
-        return placements, stats
-
-    PB.reset_fallbacks()
-    p_lax, s_lax = run("lax")
-    p_pal, s_pal = run("pallas")
-    s_pal["fallbacks"] = PB.fallback_counts()
-    return {"nodes": n_nodes, "pods": n_pods,
-            "interpret_mode": PB.interpret_mode(),
-            "lax": s_lax, "pallas": s_pal,
-            "journal_armed": _journal_armed(),
-            "placements_match": bool(p_lax) and p_lax == p_pal}
-
-
 def multichip_scale_case(mesh_shape, n_nodes=512, n_pods=2048,
                          existing_per_node=1, batch_cap=512):
     """Pod-axis mesh scale-out (ROADMAP item 1): the SAME deterministic
@@ -1818,9 +1727,6 @@ def main() -> None:
         run_case("preemption", preemption_case)
     if enabled("BENCH_NODE_FLAP"):
         run_case("node_flap", node_flap_case)
-    if enabled("BENCH_BACKENDS"):
-        run_case("backend_compare", lambda: backend_compare_case(
-            n_nodes=min(n_nodes, 512), n_pods=min(n_pods, 2048)))
     if enabled("BENCH_REPLAY"):
         run_case("replay_fidelity", replay_fidelity_case)
     if enabled("BENCH_SUSTAINED"):

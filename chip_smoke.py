@@ -16,9 +16,6 @@ TPU.  Phases:
   warm        the same shapes drained twice, synchronously: the second
               drain must not compile anything
   sequential  the config-default mode, 256 pods on the same cluster
-  pallas      kernel_backend="pallas" on the term-free batch it supports
-              (2,048 pods x 512 nodes): Mosaic-compiled, placements equal
-              to lax on the same device, no fallback, no demotion
   sync-probe  what block_until_ready and a small readback cost here
   mesh        (>= 4 devices) the gang drain under mesh_shape (1, 4) and
               (2, 2): placements equal the single-device run, resident
@@ -51,7 +48,6 @@ BACKLOG = 4096
 BATCH = 1024
 WARM_BACKLOG = 2048
 SEQ_PODS = 256
-PALLAS_NODES, PALLAS_PODS = 512, 2048
 MESH_BACKLOG = 2048     # cut from 4,096: three drains share one 4-chip call
 BIG_BATCH = 8192
 
@@ -196,8 +192,6 @@ class Sizes:
         self.batch = 64 if rehearse else BATCH
         self.warm = 128 if rehearse else WARM_BACKLOG
         self.seq = 32 if rehearse else SEQ_PODS
-        self.pallas = (64, 128) if rehearse else (PALLAS_NODES, PALLAS_PODS)
-        self.pallas_batch = 64 if rehearse else BATCH
         self.mesh = 128 if rehearse else MESH_BACKLOG
 
 
@@ -408,40 +402,6 @@ def phase_sequential(sz, seed):
     return info
 
 
-def phase_pallas(sz, seed):
-    from kubetpu.client.store import ClusterStore
-    from kubetpu.harness import hollow
-    from kubetpu.utils import pallas_backend as PB
-    n_nodes, n_pods = sz.pallas
-    check(sz.rehearse or PB.interpret_mode() is False,
-          "interpret_mode() is True on the chip")
-    PB.reset_fallbacks()
-    info = {"nodes": n_nodes, "pods": n_pods,
-            "interpret_mode": PB.interpret_mode()}
-    placements = {}
-    for backend in ("lax", "pallas"):
-        store = ClusterStore()
-        for i, n in enumerate(hollow.make_nodes(n_nodes, zones=8)):
-            store.add(n)
-            for p in hollow.make_pods(2, prefix=f"ex-{i}-", group_labels=16):
-                p.spec.node_name = n.name
-                store.add(p)
-        # group_labels=0: no topology terms, no controller spread selectors
-        # — the batch the megakernel serves
-        pending = hollow.make_pods(n_pods, prefix="pend-", group_labels=0)
-        sched = make_sched(store, "gang", sz.pallas_batch, seed,
-                           async_binding=False, kernel_backend=backend)
-        t0 = time.time()
-        placements[backend], info[f"{backend}_bound"] = sync_drain(
-            store, pending, sched, f"pallas phase ({backend})")
-        info[f"{backend}_smoke_s"] = round(time.time() - t0, 2)
-    check(PB.fallback_counts() == {}, f"fallbacks: {PB.fallback_counts()}")
-    check(PB.demotion() is None, f"demoted: {PB.demotion()}")
-    check(placements["lax"] == placements["pallas"],
-          "pallas placements differ from lax on the same device")
-    return info
-
-
 def phase_sync_probe(sz, seed):
     """Does block_until_ready on the packed result return only after the
     program is done, and what does the small readback cost?"""
@@ -543,7 +503,7 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0,
                     help="scheduler tie-break seed (the worlds themselves "
                          "are deterministic)")
-    ap.add_argument("--phases", default="gang,warm,sequential,pallas,"
+    ap.add_argument("--phases", default="gang,warm,sequential,"
                     "sync-probe,mesh",
                     help="comma-separated subset; a pass needs the default")
     ap.add_argument("--big-batch", action="store_true",
@@ -586,7 +546,6 @@ def main() -> int:
     phases = {"gang": lambda: phase_gang(sz, args.seed)[0],
               "warm": lambda: phase_warm(sz, args.seed, watchdog),
               "sequential": lambda: phase_sequential(sz, args.seed),
-              "pallas": lambda: phase_pallas(sz, args.seed),
               "sync-probe": lambda: phase_sync_probe(sz, args.seed),
               "mesh": lambda: phase_mesh(sz, args.seed)}
     wanted = args.phases.split(",")

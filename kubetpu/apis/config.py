@@ -102,25 +102,13 @@ class KubeSchedulerConfiguration:
     # exact capacity/hostPort semantics, topology scored against the
     # snapshot rather than intra-batch placements.
     mode: str = "sequential"
-    # Device kernel backend for the gang auction's round loop:
-    # "lax"    — the reference path: XLA-fused but stage-separate filter /
-    #            score / propose programs (also the bit-match oracle).
-    # "pallas" — the fused filter→score→propose megakernel
-    #            (kubetpu/ops/pallas_kernels.py): per auction round the
-    #            [B, N_tile] mask/score blocks stay in VMEM and only
-    #            [B]-sized proposals return to HBM.  Engages only for the
-    #            supported surface (term-free batches, default score
-    #            family — utils/pallas_backend.unsupported_reason);
-    #            anything else falls back to lax with a recorded reason,
-    #            and placements are bit-identical either way.
-    kernel_backend: str = "lax"
     # Deadline-guarded dispatch (the self-healing runtime): a cycle whose
     # device dispatch errors — or whose dispatch-to-readback wall time
     # exceeds this deadline — is DISCARDED before anything commits: the
-    # backend is demoted one rung (pallas -> lax, AOT artifacts ->
-    # trace) with a recorded reason, the device residents are
-    # invalidated (next cycle resyncs from the host mirror), and the
-    # cycle's pods are requeued through the backoff queue — never lost,
+    # AOT runtime, if armed, is disarmed (AOT artifacts -> trace) with
+    # a recorded reason, the device residents are invalidated (next
+    # cycle resyncs from the host mirror), and the cycle's pods are
+    # requeued through the backoff queue — never lost,
     # never double-bound.  0 (default) disables the deadline; dispatch
     # ERRORS are always recovered.  Env override: KUBETPU_DISPATCH_DEADLINE.
     dispatch_deadline_seconds: float = 0.0

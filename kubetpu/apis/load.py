@@ -61,7 +61,12 @@ def load_config(doc: Dict[str, Any]) -> KubeSchedulerConfiguration:
     cfg.extenders = list(doc.get("extenders", []) or [])
     cfg.batch_size = doc.get("batchSize", 256)  # TPU extension
     cfg.mode = doc.get("mode", "sequential")    # TPU extension
-    cfg.kernel_backend = doc.get("kernelBackend", "lax")  # TPU extension
+    # the auction has one kernel path; documents written for the removed
+    # second backend are refused, not silently served by the first
+    if doc.get("kernelBackend", "lax") != "lax":
+        raise ConfigError(
+            f"kernelBackend {doc['kernelBackend']!r}: the Pallas kernel "
+            "backend was removed; drop the field (or set 'lax')")
     # TPU extension: depth-k pipelined executor (kubetpu/pipeline.py)
     cfg.pipeline_cycles = bool(doc.get("pipelineCycles", False))
     cfg.pipeline_depth = int(doc.get("pipelineDepth", 2))
@@ -124,8 +129,6 @@ def validate(cfg: KubeSchedulerConfiguration,
         errs.append("podInitialBackoffSeconds must be > 0")
     if cfg.mode not in ("sequential", "gang"):
         errs.append("mode must be 'sequential' or 'gang'")
-    if cfg.kernel_backend not in ("lax", "pallas"):
-        errs.append("kernelBackend must be 'lax' or 'pallas'")
     if int(getattr(cfg, "pipeline_depth", 2) or 0) < 1:
         errs.append("pipelineDepth must be >= 1")
     if cfg.pod_max_backoff_seconds < cfg.pod_initial_backoff_seconds:

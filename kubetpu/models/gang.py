@@ -317,8 +317,7 @@ def _materialize_assigned(cluster, batch, chosen, requested, nz, ports_used,
 
 def run_auction(cluster, batch, cfg: ProgramConfig, rng,
                 host_ok=None, intra_batch_topology: bool = True,
-                score_bias=None,
-                kernel_backend: Optional[str] = None) -> GangResult:
+                score_bias=None) -> GangResult:
     """The serving-loop gang entry: ONE device dispatch, ONE small readback.
 
     Round 3 ran a two-phase host-orchestrated residual auction here (full
@@ -333,8 +332,7 @@ def run_auction(cluster, batch, cfg: ProgramConfig, rng,
     measured."""
     return schedule_gang(cluster, batch, cfg, rng, host_ok=host_ok,
                          intra_batch_topology=intra_batch_topology,
-                         score_bias=score_bias,
-                         kernel_backend=kernel_backend)
+                         score_bias=score_bias)
 
 
 def schedule_gang(cluster, batch, cfg: ProgramConfig, rng,
@@ -343,8 +341,7 @@ def schedule_gang(cluster, batch, cfg: ProgramConfig, rng,
                   intra_batch_topology: bool = True,
                   tie_index: Optional[jnp.ndarray] = None,
                   residual_window: int = 512,
-                  score_bias: Optional[jnp.ndarray] = None,
-                  kernel_backend: Optional[str] = None) -> GangResult:
+                  score_bias: Optional[jnp.ndarray] = None) -> GangResult:
     """Python entry for the jitted auction.  The indirection is a REQUIRED
     workaround for this runtime's jit dispatch: calling the jit object
     directly from multiple call sites with different static-arg
@@ -357,21 +354,6 @@ def schedule_gang(cluster, batch, cfg: ProgramConfig, rng,
     # normalize it out of the static key
     if cfg.percentage_of_nodes_to_score != 100:
         cfg = cfg._replace(percentage_of_nodes_to_score=100)
-    # kernel backend selection: "pallas" engages the fused
-    # filter->score->propose megakernel (ops/pallas_kernels.py) for the
-    # supported surface; any unsupported (cfg, routing) combination falls
-    # back to the lax path and records why (utils/pallas_backend) — the
-    # lax path doubles as the bit-match oracle either way
-    backend = kernel_backend or "lax"
-    if backend == "pallas":
-        from ..utils import pallas_backend as PB
-        # batch passed too: a host-side (numpy) batch carrying soft
-        # spread constraints falls back here — the kernel's constant
-        # PodTopologySpread path only matches term-free batches
-        reason = PB.unsupported_reason(cfg, intra_batch_topology, batch)
-        if reason is not None:
-            PB.note_fallback(reason)
-            backend = "lax"
     # AOT seam (utils/aot.py): armed, a signature hit runs the
     # deserialized build-time executable instead of tracing/compiling;
     # disarmed this is the plain jit call through the same Python frame
@@ -382,10 +364,10 @@ def schedule_gang(cluster, batch, cfg: ProgramConfig, rng,
         dict(host_ok=host_ok, max_rounds=max_rounds,
              intra_batch_topology=intra_batch_topology,
              tie_index=tie_index, residual_window=residual_window,
-             score_bias=score_bias, kernel_backend=backend),
+             score_bias=score_bias),
         static_argnums=(2,),
         static_argnames=("max_rounds", "intra_batch_topology",
-                         "residual_window", "kernel_backend"))
+                         "residual_window"))
 
 
 # What a profiler trace calls the served auction: the lowered module is
@@ -399,22 +381,20 @@ AUCTION_PROGRAM = "schedule_gang"
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "max_rounds",
                                     "intra_batch_topology",
-                                    "residual_window", "kernel_backend"))
+                                    "residual_window"))
 def _schedule_gang(cluster, batch, cfg: ProgramConfig, rng,
                    host_ok: Optional[jnp.ndarray] = None,
                    max_rounds: Optional[int] = None,
                    intra_batch_topology: bool = True,
                    tie_index: Optional[jnp.ndarray] = None,
                    residual_window: int = 512,
-                   score_bias: Optional[jnp.ndarray] = None,
-                   kernel_backend: str = "lax") -> GangResult:
+                   score_bias: Optional[jnp.ndarray] = None) -> GangResult:
     return _gang_program(cluster, batch, cfg, rng, host_ok=host_ok,
                          max_rounds=max_rounds,
                          intra_batch_topology=intra_batch_topology,
                          tie_index=tie_index,
                          residual_window=residual_window,
-                         score_bias=score_bias,
-                         kernel_backend=kernel_backend)
+                         score_bias=score_bias)
 
 
 def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
@@ -423,8 +403,7 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
                   intra_batch_topology: bool = True,
                   tie_index: Optional[jnp.ndarray] = None,
                   residual_window: int = 512,
-                  score_bias: Optional[jnp.ndarray] = None,
-                  kernel_backend: str = "lax") -> GangResult:
+                  score_bias: Optional[jnp.ndarray] = None) -> GangResult:
     """The auction program body, jit-free: `_schedule_gang` above is its
     single-device jit root, and the shard_map mesh path
     (parallel/shardmap.py) traces the SAME body per device for its
@@ -509,29 +488,6 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
     pod_idx = (jnp.arange(B, dtype=jnp.int32) if tie_index is None
                else jnp.asarray(tie_index, jnp.int32))
     tie_keys = jax.vmap(lambda i: jax.random.fold_in(rng, i))(pod_idx)
-
-    # ---- Pallas megakernel backend (ops/pallas_kernels.py) ----
-    # selectHost's categorical(key, logits) decomposes into
-    # argmax(where(tie, gumbel(key), -2**62)) EXACTLY in f32, so the
-    # per-pod gumbel rows are drawn once from the same fold_in keys and
-    # the kernel's cross-tile argmax replays the lax tie-break bit-for-bit
-    use_pallas = kernel_backend == "pallas"
-    pallas_interpret = False
-    bundle = None
-    if use_pallas:
-        if intra:
-            raise ValueError(
-                "kernel_backend='pallas' requires intra_batch_topology="
-                "False (schedule_gang's wrapper routes this; see "
-                "utils/pallas_backend.unsupported_reason)")
-        from ..ops import pallas_kernels as PK
-        from ..utils.pallas_backend import interpret_mode
-        pallas_interpret = interpret_mode()
-        gumbel = jax.vmap(
-            lambda k: jax.random.gumbel(k, (N,), jnp.float32))(tie_keys)
-        bundle = PK.kernel_layout(
-            PK.build_bundle(cluster, batch, cfg, static_ok, ports_ok0,
-                            score_pre, score_bias, gumbel))
 
     P = batch.ports_hot.shape[1]
     carry0 = dict(
@@ -772,30 +728,11 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
                            feas=feas, aff_unres=aff_unres,
                            boot_live=boot_live)
 
-    def pallas_round(c, sb, windowed: bool = False):
-        """round_step with the propose half fused into the Pallas
-        megakernel: feasibility, score combine and the tie-broken argmax
-        stay in VMEM per node tile; only the [W]-sized (prop, active,
-        best) come back to HBM.  Bit-identical to round_step by the
-        oracle contract (ops/pallas_kernels.py).  Round 0 stays on
-        round_step because its [B, N] feasibility IS a GangResult
-        diagnostic output (feas0/unres capture)."""
-        from ..ops import pallas_kernels as PK
-        rows = sb["rows"]
-        rsafe = jnp.clip(rows, 0, B - 1)
-        unassigned = (jnp.take(c["assigned"], rsafe) < 0) & sb["valid"]
-        prop, active, best = PK.propose(
-            sb["bundle"], cfg, unassigned, c["req"], c["nz"],
-            c["ports_used"], n_nodes=N, interpret=pallas_interpret)
-        return _round_tail(c, sb, prop, active, best, unassigned,
-                           windowed=windowed)
-
     def _round_tail(c, sb, prop, active, best, unassigned,
                     windowed: bool, capture_first: bool = False,
                     feas=None, aff_unres=None, boot_live=None):
-        """The shared admit/commit half of a round: segmented-reduce
-        admission over the proposed nodes + carry update.  O(W) / O(W, R)
-        work — kept at lax level for both backends."""
+        """The admit/commit half of a round: segmented-reduce admission
+        over the proposed nodes + carry update.  O(W) / O(W, R) work."""
         rows = sb["rows"]
         rsafe = jnp.clip(rows, 0, B - 1)
         sbatch = sb["batch"]
@@ -855,33 +792,16 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         return new
 
     fsb = full_sub()
-    if use_pallas:
-        fsb["bundle"] = bundle
     use_window = bool(residual_window) and residual_window < B  # kubelint: ignore[host-sync/cast] trace-time constant: residual_window is a static int (jit static_argnames on _schedule_gang)
 
     if not use_window:
         def cond(c):
             return c["progress"] & (c["rounds"] < max_rounds)
 
-        if use_pallas:
-            # hybrid: round 0 on the lax path (it must materialize the
-            # [B, N] feasibility anyway for the feas0/unres diagnostics),
-            # every later round fused in the megakernel.  Identical round
-            # sequencing: the peeled round runs iff cond(carry0) held.
-            if max_rounds < 1:
-                out = carry0
-            else:
-                out = round_step(carry0, fsb, capture_first=True)
+        def body(c):
+            return round_step(c, fsb, capture_first=True)
 
-                def bodyp(c):
-                    return pallas_round(c, fsb)
-
-                out = jax.lax.while_loop(cond, bodyp, out)
-        else:
-            def body(c):
-                return round_step(c, fsb, capture_first=True)
-
-            out = jax.lax.while_loop(cond, body, carry0)
+        out = jax.lax.while_loop(cond, body, carry0)
     elif max_rounds < 1:
         out = carry0
     else:
@@ -905,11 +825,6 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
             pool = (c["assigned"] < 0) & batch.valid & ~c["retired"]
             rows = jnp.nonzero(pool, size=residual_window,
                                fill_value=B)[0].astype(jnp.int32)
-            if use_pallas:
-                from ..ops import pallas_kernels as PK
-                sb = gather_sub(rows)
-                sb["bundle"] = PK.gather_bundle(bundle, rows, B)
-                return pallas_round(c, sb, windowed=True)
             return round_step(c, gather_sub(rows), capture_first=False,
                               windowed=True)
 

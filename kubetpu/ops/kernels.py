@@ -44,10 +44,10 @@ def _idiv(a, b):
 # ---------------------------------------------------------------------------
 # blessed exact cross-axis reductions
 #
-# The only sanctioned ways to reduce across shard_map mesh axes or Pallas
-# grid tiles (kubelint exact/raw-collective-reduce + exact/raw-tie-argmax
-# route every call site here; tools/kubeexact proves the discipline on the
-# traced jaxprs).  The contract:
+# The only sanctioned ways to reduce across shard_map mesh axes (kubelint
+# exact/raw-collective-reduce + exact/raw-tie-argmax route every call
+# site here; tools/kubeexact proves the discipline on the traced jaxprs).
+# The contract:
 #
 #   * float max/min are exactly associative — any tile order, same bits;
 #   * float sums must be integer-valued with |value| < 2**24 (callers are
@@ -58,9 +58,8 @@ def _idiv(a, b):
 #     lowest-index) by STRICT improvement so the winner equals the
 #     replicated jnp.argmax bit-for-bit.
 #
-# Sentinels (neg) ride in from the caller: the lax twin uses
-# jnp.float32(-2**62) while the Pallas kernel uses the python float — the
-# weak-type difference is part of each program's committed lowering.
+# The sentinel (neg) rides in from the caller as jnp.float32(-2**62): its
+# strong type is part of the program's committed lowering.
 
 
 def exact_psum(x, axis_name):
@@ -79,8 +78,7 @@ def exact_pmin(x, axis_name):
     return jax.lax.pmin(x, axis_name)
 
 
-def gumbel_tiebreak_argmax(total, f, gumbel, col_offset, neg,
-                           keepdims: bool = False):
+def gumbel_tiebreak_argmax(total, f, gumbel, col_offset, neg):
     """Per-tile propose half of the selectHost decomposition.
 
     Masks infeasible columns to ``neg``, takes the tile max, then breaks
@@ -89,17 +87,13 @@ def gumbel_tiebreak_argmax(total, f, gumbel, col_offset, neg,
     reservoir draw).  Returns (tile_best, tile_h, tile_arg) with
     tile_arg offset into global column space by ``col_offset``;
     jnp.argmax keeps the lowest index on exact gumbel ties, which is the
-    first-index contract the cross-axis fold preserves.  ``keepdims``
-    returns [B, 1] columns (the Pallas kernel's layout: Mosaic has no
-    1-D vectors) instead of [B]."""
+    first-index contract the cross-axis fold preserves."""
     masked = jnp.where(f, total, neg)
     tile_best = jnp.max(masked, axis=1, keepdims=True)
     h = jnp.where((masked == tile_best) & f, gumbel, neg)
     tile_h = jnp.max(h, axis=1, keepdims=True)
     tile_arg = (jnp.argmax(h, axis=1, keepdims=True).astype(jnp.int32)
                 + col_offset)
-    if keepdims:
-        return tile_best, tile_h, tile_arg
     return tile_best[:, 0], tile_h[:, 0], tile_arg[:, 0]
 
 
@@ -662,10 +656,11 @@ def interpod_score_raw(cluster, batch,
                        pre: InterpodScorePre | None = None,
                        active_keys=None):
     """The assignment-dependent RAW half of interpod_score -> (raw [B, N],
-    any_counts [B, 1]).  Split out so gang mode's Pallas backend can
-    precompute it once per auction (under intra_batch_topology=False the
-    pod axis is frozen, so raw is round-invariant) and fuse only the
-    feasibility-dependent normalization into the megakernel."""
+    any_counts [B, 1]).  Split out so the tiled mesh auction
+    (parallel/shardmap.py build_bundle) can precompute it once per
+    auction (under intra_batch_topology=False the pod axis is frozen, so
+    raw is round-invariant) and recompute only the feasibility-dependent
+    normalization per round."""
     B = batch.req.shape[0]
     N = cluster.allocatable.shape[0]
     if pre is None:

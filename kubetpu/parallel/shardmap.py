@@ -14,21 +14,21 @@ wrong.
 
 Two surfaces, chosen statically per dispatch (``gang_surface``):
 
-* ``tiled`` — the scale path, term-free batches (the same supported
-  surface as the Pallas megakernel, whose decomposition this reuses —
-  ops/pallas_kernels.py build_bundle provides the round-invariant
-  [S, B, N] planes).  Each device owns a [B/mp, N/mn] tile of the
-  filter/score plane; per auction round it
+* ``tiled`` — the scale path, term-free batches (``gang_surface``
+  states the surface; ``build_bundle`` below provides the
+  round-invariant [S, B, N] planes).  Each device owns a [B/mp, N/mn]
+  tile of the filter/score plane; per auction round it
 
     1. recomputes feasibility + the weighted score combine on its tile
        (per-pod normalization statistics via ``lax.pmax/pmin/psum`` over
        the "nodes" axis — every reduction is a float max/min or an
-       integer-valued-f32 sum, exact in any order below 2**24: the
-       Pallas oracle's exactness discipline),
+       integer-valued-f32 sum, exact in any order below 2**24),
     2. proposes GATHER-FREE: the selectHost categorical decomposes into
-       ``argmax(where(tie, gumbel, -2**62))`` (the PR 8 pillar), and the
-       cross-shard argmax resolves without any cross-shard gather — a
-       strict-improvement (best, gumbel) pmax pair plus a pmin over
+       ``argmax(where(tie, gumbel, -2**62))`` EXACTLY in f32 (the
+       auction's logits are 0 / -2**62, so gumbel + logits is that
+       where), and the cross-shard argmax resolves without any
+       cross-shard gather — a strict-improvement (best, gumbel) pmax
+       pair plus a pmin over
        qualifying GLOBAL node indices reproduces jnp.argmax's
        first-index tie-break bit-for-bit,
     3. resolves contention collectively: per-pod winners
@@ -83,7 +83,6 @@ from jax.sharding import PartitionSpec as P
 from ..models import gang, programs, sequential
 from ..models.gang import GangResult, admission_mask, admission_sums
 from ..ops import kernels as K
-from ..ops import pallas_kernels as PK
 from ..state.tensors import CH_CPU, CH_MEM, CH_PODS, N_FIXED_CHANNELS
 
 AXIS_PODS = "pods"
@@ -126,12 +125,95 @@ def _rep_spec(tree):
     return jax.tree.map(lambda _: P(), tree)
 
 
+# score plugins whose raw matrix is round-invariant under
+# intra_batch_topology=False and enters the tiled auction as a plane
+_PLANE_OF = {
+    "ImageLocality": "raw:ImageLocality",
+    "NodeAffinity": "raw:NodeAffinity",
+    "NodePreferAvoidPods": "raw:NodePreferAvoidPods",
+    "TaintToleration": "raw:TaintToleration",
+    "InterPodAffinity": "ipa_raw",
+    "DefaultPodTopologySpread": "dps_raw",
+}
+
+# the score family the tiled auction combines: the planes above plus the
+# scorers it recomputes per round from the evolving requested/nonzero
+# carries; anything else dispatches on "replicated"
+SUPPORTED_SCORES = frozenset(_PLANE_OF) | frozenset({
+    "NodeResourcesBalancedAllocation",
+    "NodeResourcesLeastAllocated",
+    "NodeResourcesMostAllocated",
+    # the no-soft-constraints constant path (MaxNodeScore on every
+    # feasible node): exactly what a term-free batch evaluates to
+    "PodTopologySpread",
+})
+
+
+def plane_order(cfg, has_bias: bool) -> Tuple[str, ...]:
+    """Static plane layout of the stacked [S, B, N] input: score raws in
+    cfg.scores order, then the optional host score bias, then the
+    selectHost gumbel matrix (always last)."""
+    names = []
+    for name, _ in cfg.scores:
+        key = _PLANE_OF.get(name)
+        if key is not None and key not in names:
+            names.append(key)
+    if has_bias:
+        names.append("bias")
+    names.append("gumbel")
+    return tuple(names)
+
+
+def build_bundle(cluster, batch, cfg, static_ok, ports_ok0, score_pre,
+                 score_bias, gumbel) -> Dict[str, jnp.ndarray]:
+    """Precompute the tiled auction's round-invariant inputs, once per
+    auction (traced inside _shardmap_gang).  All [B, N] planes here are
+    assignment-independent under intra_batch_topology=False: the pod axis
+    is frozen during the loop, so interpod/default-spread raws are
+    round-invariant even though their single-device twins recompute per
+    round."""
+    B = batch.req.shape[0]
+    planes: Dict[str, jnp.ndarray] = {}
+    ipa_any = jnp.zeros((B,), bool)
+    for name, _ in cfg.scores:
+        if name == "InterPodAffinity" and "ipa_raw" not in planes:
+            raw, any_counts = K.interpod_score_raw(
+                cluster, batch, pre=score_pre.get("interpod_score"),
+                active_keys=cfg.active_keys)
+            planes["ipa_raw"] = raw
+            ipa_any = any_counts[:, 0]
+        elif name == "DefaultPodTopologySpread" and "dps_raw" not in planes:
+            planes["dps_raw"] = K.default_spread_score(
+                cluster, batch, match_ns=score_pre.get("default_spread"))
+        elif name in _PLANE_OF and _PLANE_OF[name] not in planes:
+            planes[_PLANE_OF[name]] = score_pre["raw:" + name]
+    if score_bias is not None:
+        planes["bias"] = score_bias
+    planes["gumbel"] = gumbel
+    order = plane_order(cfg, score_bias is not None)
+    stack = jnp.stack([planes[k].astype(jnp.float32) for k in order])
+    zone = cluster.zone_hot
+    if zone.shape[1] == 0:
+        zone = jnp.zeros((zone.shape[0], 1), jnp.float32)
+    return dict(
+        planes=stack,                         # [S, B, N] f32
+        mask=static_ok & ports_ok0,           # [B, N] bool
+        ipa_any=ipa_any,                      # [B] bool
+        skip=batch.spread_skip,               # [B] bool
+        breq=batch.req,                       # [B, R] f32
+        bnz=batch.nonzero_req,                # [B, 2] f32
+        bports=batch.ports_hot,               # [B, P] f32
+        alloc=cluster.allocatable,            # [N, R] f32 (node side)
+        zone=zone,                            # [N, Z] f32 (node side)
+    )
+
+
 def gang_surface(cfg, intra_batch_topology: bool, batch, mesh,
                  n_nodes: int, n_pods: int) -> str:
     """The static surface this (cfg, routing, batch, mesh) dispatches
-    on.  "tiled" mirrors the Pallas supported surface
-    (utils/pallas_backend.unsupported_reason): intra_batch_topology off,
-    every score plugin in the plane family, no soft spread constraints
+    on.  "tiled" needs: intra_batch_topology off (the pod axis is frozen
+    during the loop, so every score raw is a round-invariant plane),
+    every score plugin in SUPPORTED_SCORES, no soft spread constraints
     in the batch (host-side numpy inspection — a device-array batch
     skips the check and its caller carries the term-free contract, which
     the scheduler's needs_topo gate does: soft-spread batches route
@@ -141,7 +223,7 @@ def gang_surface(cfg, intra_batch_topology: bool, batch, mesh,
     if intra_batch_topology:
         return "replicated"
     for name, _ in cfg.scores:
-        if name not in PK.SUPPORTED_SCORES:
+        if name not in SUPPORTED_SCORES:
             return "replicated"
     sv = getattr(getattr(batch, "spread_soft", None), "valid", None)
     if isinstance(sv, np.ndarray) and bool(sv.any()):
@@ -196,7 +278,7 @@ def _gang_replicated(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
         return gang._gang_program(
             cl, b, cfg, r, max_rounds=max_rounds,
             intra_batch_topology=intra_batch_topology,
-            residual_window=residual_window, kernel_backend="lax", **dk)
+            residual_window=residual_window, **dk)
 
     out_struct = jax.eval_shape(body, cluster, batch, rng, dyn)
     return jax.shard_map(
@@ -209,11 +291,10 @@ def _gang_replicated(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
 
 def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
                 max_rounds, residual_window):
-    """The gather-free tiled auction: Pallas-decomposition planes,
+    """The gather-free tiled auction: round-invariant planes,
     node-axis collective stats, pods-axis all_gather resolution,
-    replicated admission.  Bit-match oracle: models/gang.py's lax path
-    at intra_batch_topology=False (the same contract — and largely the
-    same math — as ops/pallas_kernels.propose)."""
+    replicated admission.  Bit-match oracle: models/gang.py's
+    single-device auction at intra_batch_topology=False."""
     from ..models.batch import densify_for
     from ..models.programs import run_filters, static_raw_scores
 
@@ -243,15 +324,15 @@ def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
     tie_keys = jax.vmap(lambda i: jax.random.fold_in(rng, i))(pod_idx)
     gumbel = jax.vmap(
         lambda k: jax.random.gumbel(k, (N,), jnp.float32))(tie_keys)
-    bundle = PK.build_bundle(cluster, batch, cfg, static_ok, ports_ok0,
-                             score_pre, score_bias, gumbel)
+    bundle = build_bundle(cluster, batch, cfg, static_ok, ports_ok0,
+                          score_pre, score_bias, gumbel)
 
     mp = mesh.shape[AXIS_PODS]
     mn = mesh.shape[AXIS_NODES]
     Bl, Nl = B // mp, N // mn
     Z = bundle["zone"].shape[1]
     plane = {name: i
-             for i, name in enumerate(PK.plane_order(
+             for i, name in enumerate(plane_order(
                  cfg, score_bias is not None))}
     scores_static = tuple((n, float(w)) for n, w in cfg.scores)  # kubelint: ignore[host-sync/cast] trace-time constant: weights are static ints from cfg.scores (jit static arg)
 
@@ -273,9 +354,10 @@ def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
         has_zone = jnp.any(zone_t > 0, axis=1)   # [Nl]
 
         def feas_tile(c, live):
-            """ops/pallas_kernels._make_kernel feas_tile, on the shard's
-            tile: identical f32/bool op sequence (the oracle contract's
-            'VPU recompute' half)."""
+            """The round's feasibility on the shard's tile: the static
+            mask AND'd with the fit verdict against committed usage and
+            the hostPort conflict against registered ports — the same
+            f32/bool op sequence as gang.py's feasibility()."""
             f = mask_t & live[:, None]
             if use_fit:
                 used_t = lax.dynamic_slice_in_dim(c["req"], no, Nl)
@@ -315,11 +397,10 @@ def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
             return req_cpu, req_mem, alloc_cpu, alloc_mem
 
         def stats_for(f):
-            """Phase-0 twin: per-pod normalization statistics, tile
-            reduce + "nodes"-axis collective.  Float max/min are exactly
+            """Per-pod normalization statistics, tile reduce +
+            "nodes"-axis collective.  Float max/min are exactly
             associative; the DPS zone sums are integer-valued f32, exact
-            under psum below 2**24 — the Pallas cross-tile argument,
-            verbatim."""
+            under psum below 2**24."""
             st = {}
             st["act"] = K.exact_pmax(
                 jnp.max(f.astype(jnp.float32), axis=1), AXIS_NODES)
@@ -354,9 +435,8 @@ def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
             return st
 
         def combine(c, f, st):
-            """Phase-1 twin: the weighted score combine on the tile,
-            same formula helpers, same accumulation order as
-            run_scores/the Pallas kernel."""
+            """The weighted score combine on the tile: same formula
+            helpers, same accumulation order as run_scores."""
             total = jnp.zeros((Bl, Nl), jnp.float32)
             for name, weight in scores_static:
                 if name == "NodeResourcesBalancedAllocation":
@@ -407,7 +487,7 @@ def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
                     max_node = jnp.maximum(st["max_dps"], 0.0)
                     f_score = jnp.where(
                         (max_node > 0)[:, None],
-                        MAX_NODE_SCORE * (max_node[:, None] - raw)  # kubelint: ignore[numeric/score-div] reference computes fScore in float64 (default_pod_topology_spread.go:126); mirrors the lax/Pallas twin exactly
+                        MAX_NODE_SCORE * (max_node[:, None] - raw)  # kubelint: ignore[numeric/score-div] reference computes fScore in float64 (default_pod_topology_spread.go:126); mirrors the single-device twin exactly
                         / jnp.maximum(max_node, 1.0)[:, None],
                         MAX_NODE_SCORE)
                     cz = st["czone"]
@@ -416,7 +496,7 @@ def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
                                   preferred_element_type=jnp.float32)
                     zone_score = jnp.where(
                         (max_zone > 0)[:, None],
-                        MAX_NODE_SCORE * (max_zone[:, None] - nzc)  # kubelint: ignore[numeric/score-div] reference computes zoneScore in float64 (default_pod_topology_spread.go:142); mirrors the lax/Pallas twin exactly
+                        MAX_NODE_SCORE * (max_zone[:, None] - nzc)  # kubelint: ignore[numeric/score-div] reference computes zoneScore in float64 (default_pod_topology_spread.go:142); mirrors the single-device twin exactly
                         / jnp.maximum(max_zone, 1.0)[:, None],
                         MAX_NODE_SCORE)
                     with_zone = (f_score * (1.0 - K.ZONE_WEIGHTING)
@@ -446,8 +526,7 @@ def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
             # gather-free cross-shard argmax, first-index tie-break:
             # per-tile gumbel decomposition then MIN global index among
             # exact (score, gumbel) ties — the earliest index IS
-            # jnp.argmax's choice (blessed ops/kernels.py pair; the
-            # Pallas kernel folds the same tuple across grid tiles)
+            # jnp.argmax's choice (blessed ops/kernels.py pair)
             tile_best, tile_h, tile_arg = K.gumbel_tiebreak_argmax(
                 total, f, gum_t, no, _NEG)
             best, gidx = K.crossaxis_first_index_argmax(

@@ -449,8 +449,7 @@ class PipelinedExecutor:
             outs = s._commit_group(prep, packed)
             failed = s._last_commit_failed
             if s.config.mode == "gang":
-                prep.trace.finish(auction_rounds=s.last_gang_rounds,
-                                  kernel_backend=s._gang_backend(prep))
+                prep.trace.finish(auction_rounds=s.last_gang_rounds)
             else:
                 prep.trace.finish()
         dt = utrace.wallclock() - t0

@@ -1161,8 +1161,7 @@ class Scheduler:
                     cluster, batch, cfg, self._next_rng(),
                     host_ok=host_ok_dev,
                     intra_batch_topology=needs_topo,
-                    score_bias=prep.score_bias,
-                    kernel_backend=self.config.kernel_backend)
+                    score_bias=prep.score_bias)
             # the auction already produced per-pod verdict rows; share them
             # lazily so preemption can skip its candidates pass without the
             # scheduler paying a multi-MB transfer it may never need
@@ -1325,10 +1324,7 @@ class Scheduler:
         blew its deadline (kind: "dispatch-error" / "dispatch-deadline").
         Three moves, in order:
 
-        1. DEMOTE the backend one rung with the reason recorded: a
-           pallas-backed profile drops to the lax oracle path
-           (utils/pallas_backend.demote — process-wide, every later
-           cycle routes lax), and an armed AOT runtime disarms
+        1. DEMOTE: an armed AOT runtime disarms with the reason recorded
            (AOT -> trace; the persistent-cache/trace ladder still
            serves).  The demotion is an incident INSTANT on the cycle's
            flight record, visible in /debug/flightz and traceview.
@@ -1339,7 +1335,7 @@ class Scheduler:
         3. REQUEUE the cycle's pods through the backoff queue.  Recovery
            runs strictly BEFORE the commit loop, so nothing was
            reserved, assumed or bound: pods are never lost and never
-           double-bound — they simply retry against the demoted backend.
+           double-bound — they simply retry on the traced program.
 
         Never raises: the serving loop must survive any fault this
         handles."""
@@ -1347,23 +1343,11 @@ class Scheduler:
         logging.getLogger("kubetpu").warning(
             "cycle recovery (%s): %s; %d pods requeued", kind, reason,
             len(prep.live))
-        # demote ONE rung per fault, outermost first (the ladder the
-        # docstring and README describe): a pallas-backed profile drops
-        # to lax; only a fault that recurs on the lax path disarms AOT.
-        # Demoting everything at once would throw away both fast paths —
-        # and the evidence of which layer actually faulted — on the
-        # first blip.
         demoted = []
-        if self.config.kernel_backend == "pallas":
-            from .utils import pallas_backend as PB
-            if PB.demotion() is None:
-                PB.demote("%s: %s" % (kind, reason[:200]))
-                demoted.append("pallas->lax")
-        if not demoted:
-            from .utils import aot as _aot
-            if _aot.active_runtime() is not None:
-                _aot.disarm(reason="%s: %s" % (kind, reason[:200]))
-                demoted.append("aot->trace")
+        from .utils import aot as _aot
+        if _aot.active_runtime() is not None:
+            _aot.disarm(reason="%s: %s" % (kind, reason[:200]))
+            demoted.append("aot->trace")
         with self._chain_lock:
             self._chain = None
             self._chain_seq += 1
@@ -1458,21 +1442,11 @@ class Scheduler:
                 # the histogram across cycles and traceview shows a digest
                 # column, so the round-count reduction ROADMAP item 3
                 # claims is directly observable per run, not just as a max
-                prep.trace.finish(auction_rounds=self.last_gang_rounds,
-                                  kernel_backend=self._gang_backend(prep))
+                prep.trace.finish(auction_rounds=self.last_gang_rounds)
             else:
                 prep.trace.finish()
         self._sync_flight_dropped()
         return out
-
-    def _gang_backend(self, prep: PreparedCycle) -> str:
-        """The kernel backend this cycle actually traced (pallas falls
-        back per cycle on unsupported routing, e.g. topology batches)."""
-        if self._mesh is not None or self.config.kernel_backend != "pallas":
-            return "lax"
-        from .utils import pallas_backend as PB
-        return PB.effective_backend(prep.cfg, prep.needs_topo, "pallas",
-                                    batch=prep.batch)
 
     def _readback_group(self, prep: PreparedCycle, res) -> np.ndarray:
         """ONE device->host readback per cycle: the packed [3B+1] i32 view
@@ -1533,8 +1507,7 @@ class Scheduler:
             from .utils.flops import gang_cycle_flops
             cyc_flops = gang_cycle_flops(
                 prep.cluster, prep.batch, prep.cfg, self.last_gang_rounds,
-                intra_batch_topology=prep.needs_topo,
-                kernel_backend=self._gang_backend(prep))
+                intra_batch_topology=prep.needs_topo)
             self.device_flops += cyc_flops
             if prep.devstats_fenced:
                 # pair the cycle's analytic FLOP count with ITS OWN
@@ -1822,8 +1795,6 @@ class Scheduler:
         mode = self.config.mode
         fwk, live = prep.fwk, prep.live
         kind, payload = prep.journal_input or ("unknown", None)
-        kernel_backend = (self._gang_backend(prep) if mode == "gang"
-                          else "lax")
         hard_w = float(fwk.hard_pod_affinity_weight)
         placements: Dict[str, str] = {}
         blocking: Dict[str, int] = {}
@@ -1863,7 +1834,6 @@ class Scheduler:
             "needs_topo": bool(prep.needs_topo),
             "rng_counter": int(prep.journal_rng),
             "start_index": int(prep.journal_start),
-            "kernel_backend": kernel_backend,
             "hard_pod_affinity_weight": hard_w,
             "mesh": self._mesh is not None,
             "vocab_sig": _vocab_caps(prep.builder.table),
@@ -1873,8 +1843,7 @@ class Scheduler:
             "node_names": ([ni.node_name for ni in prep.node_infos]
                            if kind == "resync" else None),
             "config_digest": ujournal.config_digest(
-                mode, fwk.profile_name, prep.cfg, hard_w,
-                self.config.kernel_backend),
+                mode, fwk.profile_name, prep.cfg, hard_w),
             # ---- outputs ----
             "packed": np.asarray(packed),
             "rounds": (self.last_gang_rounds if mode == "gang" else 0),
@@ -2864,16 +2833,6 @@ class Scheduler:
                     from .models.gang import run_auction
                     res = run_auction(cluster, batch, cfg, rng,
                                       score_bias=warm_bias)
-                    if self.config.kernel_backend == "pallas":
-                        # term-free serving batches route
-                        # intra_batch_topology=False + pallas — a DISTINCT
-                        # compiled program; warm it or the first term-free
-                        # cycle pays the megakernel compile stall
-                        res_p = run_auction(cluster, batch, cfg, rng,
-                                            score_bias=warm_bias,
-                                            intra_batch_topology=False,
-                                            kernel_backend="pallas")
-                        np.asarray(res_p.packed)
             elif self._mesh is not None:
                 from .parallel import mesh as pmesh
                 res = pmesh.sharded_schedule_sequential(
@@ -3017,12 +2976,6 @@ class Scheduler:
         res = run_auction(cluster, batch, cfg, rng,
                           score_bias=warm_bias)
         np.asarray(res.packed)
-        if self.config.kernel_backend == "pallas":
-            res_p = run_auction(cluster, batch, cfg, rng,
-                                score_bias=warm_bias,
-                                intra_batch_topology=False,
-                                kernel_backend="pallas")
-            np.asarray(res_p.packed)
         if self.decisions.enabled:
             # audit program per pod-axis bucket, like the auction (a
             # drain's failures can land in any grown bucket)

@@ -2,14 +2,14 @@
 
 The reference scheduler survives etcd hiccups, API-server disconnects and
 crashed binders by design (informer resync, backoff queues, idempotent
-commits — SURVEY L0-L4).  The TPU-native reproduction grew three state
+commits — SURVEY L0-L4).  The TPU-native reproduction grew two state
 surfaces the reference never had — device-resident cluster tensors
-(state/delta.py), serialized AOT executables (utils/aot.py) and a Pallas
-kernel backend (ops/pallas_kernels.py) — each of which can silently
-corrupt, hang or diverge.  This module makes those faults first-class:
-every failure mode the recovery machinery claims to survive has a NAMED
-injection point here, armed deterministically so tests/test_chaos.py can
-assert the recovery invariants (serving thread alive, no lost pods, no
+(state/delta.py) and serialized AOT executables (utils/aot.py) — each
+of which can silently corrupt, hang or diverge.  This module makes
+those faults first-class: every failure mode the recovery machinery
+claims to survive has a NAMED injection point here, armed
+deterministically so tests/test_chaos.py can assert the recovery
+invariants (serving thread alive, no lost pods, no
 double binds, mirror/device bit-consistency) scenario by scenario.
 
 Injection points threaded through the stack:

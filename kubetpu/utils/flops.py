@@ -51,17 +51,9 @@ def device_peaks() -> Optional[DevicePeaks]:
 
 def gang_cycle_flops(cluster, batch, cfg, rounds: int,
                      residual_window: int = 512,
-                     intra_batch_topology: bool = True,
-                     kernel_backend: str = "lax") -> float:
+                     intra_batch_topology: bool = True) -> float:
     """Matmul FLOPs of one gang-auction cycle (schedule_gang) given the
-    executed round count (GangResult.rounds / packed[3B]).
-
-    kernel_backend="pallas": rounds 1+ run the fused megakernel
-    (ops/pallas_kernels.py), whose per-round matmul work collapses to the
-    fit/resource elementwise sweep plus the small zone contraction — the
-    interpod/default-spread raw matrices are precomputed ONCE (inside
-    round 0's accounting) instead of recontracted per round, which is
-    exactly the HBM/FLOP reduction the backend exists for."""
+    executed round count (GangResult.rounds / packed[3B])."""
     N = int(cluster.allocatable.shape[0])
     B = int(batch.valid.shape[0])
     R = int(cluster.allocatable.shape[1])
@@ -102,22 +94,8 @@ def gang_cycle_flops(cluster, batch, cfg, rounds: int,
         f += 2.0 * W * N * R
         return f
 
-    def pallas_round_flops(W: int) -> float:
-        # fused megakernel round: fit + resource scorers sweep, zone
-        # contraction, ports conflict dot; score raws are plane READS
-        Z = int(getattr(cluster, "zone_hot").shape[1] or 1)
-        Pp = int(batch.ports_hot.shape[1])
-        f = 2.0 * W * N * R + 2.0 * W * N * Z
-        if "NodePorts" in filters:
-            f += 2.0 * W * Pp * N
-        return f
-
     W_resid = min(residual_window or B, B)
     r = max(int(rounds), 0)
     if r == 0:
         return 0.0
-    if kernel_backend == "pallas":
-        # round 0 stays on the lax path (feas0 capture) and carries the
-        # once-per-auction raw precompute in its own accounting
-        return round_flops(B) + (r - 1) * pallas_round_flops(W_resid)
     return round_flops(B) + (r - 1) * round_flops(W_resid)

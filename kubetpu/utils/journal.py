@@ -13,9 +13,8 @@ self-contained record to a bounded, size-capped on-disk journal —
            or the chain-materialize pad buckets), the pod batch with its
            interned vocab slice, the RNG fold counter, the
            ``ProgramConfig`` + profile/config digest, the recorded
-           host-plugin mask (``host_ok``) and host score bias, the
-           effective ``kernel_backend``, ``pipeline_depth`` and
-           ``ring_slot``
+           host-plugin mask (``host_ok``) and host score bias,
+           ``pipeline_depth`` and ``ring_slot``
   OUTPUTS  the packed placement vector (chosen / n_feasible /
            unresolvable / rounds), per-pod placements by name, and a
            per-plugin verdict summary folded from the decision audit
@@ -26,7 +25,7 @@ self-contained record to a bounded, size-capped on-disk journal —
 
 — and ``tools/kubereplay`` re-executes any journaled window offline,
 bit-matching replayed placements against the recorded ones (the same
-oracle discipline as the Pallas and AOT gates: a divergence is a
+oracle discipline as the AOT gate: a divergence is a
 correctness failure, attributed to the first divergent cycle), or
 re-runs the window under a modified profile (``--counterfactual``) to
 turn every recorded trace into an eval set — the gating substrate for
@@ -356,8 +355,8 @@ def read_records(directory: str) -> Iterator[Tuple[int, Optional[Dict],
         yield seq, rec, None
 
 
-def config_digest(mode: str, profile: str, cfg, hard_weight: float,
-                  kernel_backend: str) -> str:
+def config_digest(mode: str, profile: str, cfg,
+                  hard_weight: float) -> str:
     """Stable digest of the profile/program configuration a record was
     produced under.  kubereplay surfaces the distinct digests of a
     window (``config_digests`` in its report): a window spanning more
@@ -367,8 +366,7 @@ def config_digest(mode: str, profile: str, cfg, hard_weight: float,
     text = repr((RECORD_VERSION, mode, profile, tuple(cfg.filters),
                  tuple(cfg.scores), cfg.hostname_topokey,
                  tuple(cfg.plugin_args), cfg.percentage_of_nodes_to_score,
-                 tuple(cfg.active_topo_keys), float(hard_weight),
-                 kernel_backend))
+                 tuple(cfg.active_topo_keys), float(hard_weight)))
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 

@@ -16,21 +16,19 @@ from kubetpu.client.store import ClusterStore
 from kubetpu.harness import hollow
 from kubetpu.scheduler import Scheduler
 from kubetpu.utils import chaos
-from kubetpu.utils import pallas_backend as PB
 from kubetpu.utils.metrics import SchedulerMetrics
 
 
 @pytest.fixture(autouse=True)
 def _disarm():
-    """Chaos and the pallas/aot demotion latches are process-global;
-    every test starts and ends disarmed."""
+    """Chaos and the aot demotion latch are process-global; every test
+    starts and ends disarmed."""
     from kubetpu.utils import aot
     chaos.disarm()
-    PB.reset_demotion()
     aot.reset_demotion()
     yield
     chaos.disarm()
-    PB.reset_demotion()
+    aot.disarm()
     aot.reset_demotion()
 
 
@@ -216,27 +214,35 @@ def test_deadline_exempts_first_compile():
         sched.close()
 
 
-def test_dispatch_error_demotes_pallas_backend():
-    """A pallas-backed profile that takes a dispatch fault demotes to the
-    lax oracle path with a recorded reason; later cycles serve lax and
-    still place."""
-    store = ClusterStore()
+@pytest.mark.parametrize("armed", [True, False])
+def test_dispatch_error_ladder_is_aot_to_trace_then_requeue(armed, tmp_path):
+    """The recovery ladder's one demotion: a dispatch fault with an AOT
+    runtime armed disarms it (AOT -> trace) with the reason recorded;
+    with nothing armed there is nothing to demote.  Either way the pods
+    requeue and every one binds exactly once."""
+    from kubetpu.utils import aot
+    store = CountingStore()
     for n in hollow.make_nodes(3):
         store.add(n)
-    sched = _sched(store, batch_size=4, kernel_backend="pallas")
+    sched = _sched(store, batch_size=4)
     try:
+        if armed:
+            # an empty store: every dispatch misses and traces, which is
+            # all the ladder needs to find a runtime to disarm
+            aot.arm(aot.serve_runtime(str(tmp_path)))
         chaos.arm(chaos.ChaosRegistry(seed=3).arm_point(
             "dispatch", "error", n=1))
         for p in hollow.make_pods(4, prefix="p-", group_labels=0):
             store.add(p)
         outs = _drain(sched)
         assert len(_placed(outs)) == 4
-        assert PB.demotion() is not None
-        assert PB.demotion().startswith("dispatch-error")
-        assert sched.recovery_log[0]["demoted"] == ["pallas->lax"]
-        # the demotion is the single authority: pallas refuses to engage
-        assert PB.unsupported_reason(
-            None, False).startswith("demoted:")
+        assert sorted(store.bind_calls) == [f"p-{i}" for i in range(4)]
+        first = sched.recovery_log[0]
+        assert first["kind"] == "dispatch-error"
+        assert first["demoted"] == (["aot->trace"] if armed else [])
+        assert aot.active_runtime() is None
+        assert (aot.demotion_reason() or "").startswith(
+            "dispatch-error") == armed
     finally:
         sched.close()
 

@@ -193,3 +193,17 @@ def test_validation_extender_rules():
         cfgload.load_config({"extenders": [
             {"urlPrefix": "http://x", "bindVerb": "bind"},
             {"urlPrefix": "http://y", "bindVerb": "bind"}]})
+
+
+def test_kernel_backend_field_is_refused_unless_lax():
+    """The auction has one kernel path: a document asking for the removed
+    backend (or any other) is an error that names it, "lax" or no field
+    loads, and the configuration object has no such field to set."""
+    with pytest.raises(cfgload.ConfigError, match="Pallas kernel backend"):
+        cfgload.load_config({"mode": "gang", "kernelBackend": "pallas"})
+    with pytest.raises(cfgload.ConfigError, match="'mosaic'"):
+        cfgload.load_config({"mode": "gang", "kernelBackend": "mosaic"})
+    for doc in ({"mode": "gang"}, {"mode": "gang", "kernelBackend": "lax"}):
+        cfg = cfgload.load_config(doc)
+        assert cfg.mode == "gang"
+        assert not hasattr(cfg, "kernel_backend")

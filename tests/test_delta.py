@@ -857,14 +857,13 @@ def test_depth4_chaos_dispatch_error_mid_drain():
     a seeded KUBETPU_CHAOS dispatch error fired mid-way through a
     depth-4 pipelined drain — with multiple cycles dispatched but
     uncommitted — must recover like the 2-deep chain did: every pod
-    still binds EXACTLY once, the backend demotes one rung
-    (pallas -> lax), and the recovery is auditable."""
+    still binds EXACTLY once, and the recovery is auditable (no AOT
+    runtime is armed here, so the ladder has nothing to demote)."""
     from kubetpu.apis.config import (KubeSchedulerConfiguration,
                                      KubeSchedulerProfile)
     from kubetpu.client.store import ClusterStore
     from kubetpu.scheduler import Scheduler
     from kubetpu.utils import chaos
-    from kubetpu.utils import pallas_backend as PB
 
     class CountingStore(ClusterStore):
         def __init__(self):
@@ -876,14 +875,12 @@ def test_depth4_chaos_dispatch_error_mid_drain():
             super().bind(pod, node_name)
 
     chaos.disarm()
-    PB.reset_demotion()
     store = CountingStore()
     for n in hollow.make_nodes(8, zones=4):
         store.add(n)
     cfg = KubeSchedulerConfiguration(
         profiles=[KubeSchedulerProfile()], batch_size=4, mode="gang",
         chain_cycles=True, pipeline_cycles=True, pipeline_depth=4,
-        kernel_backend="pallas",
         pod_initial_backoff_seconds=0.01, pod_max_backoff_seconds=0.05)
     sched = Scheduler(store, config=cfg, async_binding=False)
     try:
@@ -919,11 +916,9 @@ def test_depth4_chaos_dispatch_error_mid_drain():
         assert len(store.bind_calls) == len(set(store.bind_calls)) == 48
         assert any(e["kind"] == "dispatch-error"
                    for e in sched.recovery_log)
-        assert sched.recovery_log[0]["demoted"] == ["pallas->lax"]
-        assert PB.demotion() is not None
+        assert sched.recovery_log[0]["demoted"] == []
     finally:
         chaos.disarm()
-        PB.reset_demotion()
         sched.close()
 
 

@@ -6,8 +6,12 @@ with the sequential replay when uncontended (reference serial semantics,
 pkg/scheduler/scheduler.go:509)."""
 from typing import Dict, List
 
+import inspect
+
 import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from kubetpu.api import types as api
 from kubetpu.framework.types import NodeInfo, PodInfo
@@ -455,3 +459,83 @@ def test_windowed_retire_rounds_do_not_starve_feasible_pods():
     # the scenario genuinely exceeds the old shared budget of B rounds —
     # otherwise this test would pass on the buggy code too
     assert int(g.rounds) > 32
+
+
+def test_categorical_gumbel_decomposition():
+    """The identity the tiled mesh auction's selection rests on:
+    categorical(key, 0/-2**62 logits) == argmax(where(tie, gumbel(key),
+    -2**62)) BIT-EXACTLY, so gumbel rows drawn once from the same
+    fold_in keys replay the single-device tie-break."""
+    B, N = 64, 300
+    rng = jax.random.PRNGKey(7)
+    keys = jax.vmap(lambda i: jax.random.fold_in(rng, i))(
+        jnp.arange(B, dtype=jnp.int32))
+    neg = jnp.float32(-2**62)
+    rs = np.random.RandomState(0)
+    scores = jnp.asarray(rs.randint(0, 5, size=(B, N)).astype(np.float32))
+    feas = jnp.asarray(rs.rand(B, N) < 0.7)
+    masked = jnp.where(feas, scores, neg)
+    ties = (masked == jnp.max(masked, axis=1)[:, None]) & feas
+    logits = jnp.where(ties, 0.0, neg)
+    choice = jax.vmap(jax.random.categorical)(keys, logits)
+    gum = jax.vmap(lambda k: jax.random.gumbel(k, (N,), jnp.float32))(keys)
+    mine = jnp.argmax(jnp.where(ties, gum, neg), axis=1)
+    np.testing.assert_array_equal(np.asarray(choice), np.asarray(mine))
+
+
+def test_auction_signatures_hold_no_kernel_backend():
+    """One auction program: no entry of the auction, the jitted root
+    included, takes a backend, so a caller's (or a benchmark control's)
+    **kw pass-through cannot revive one."""
+    for fn in (gang.run_auction, gang.schedule_gang, gang._gang_program,
+               gang._schedule_gang):
+        assert "kernel_backend" not in inspect.signature(fn).parameters
+    nodes = [mknode(name=f"n{i}") for i in range(3)]
+    cluster, batch, cfg, _ = build(nodes, {}, [mkpod(name="p0")])
+    with pytest.raises(TypeError, match="kernel_backend"):
+        gang.run_auction(cluster, batch, cfg, jax.random.PRNGKey(0),
+                         kernel_backend="lax")
+
+
+def test_cycle_meta_records_rounds_and_no_backend():
+    """Flight-recorder cycle meta carries auction_rounds (traceview and
+    bench aggregate the round histogram) and no kernel_backend."""
+    from kubetpu.apis.config import (KubeSchedulerConfiguration,
+                                     KubeSchedulerProfile)
+    from kubetpu.client.store import ClusterStore
+    from kubetpu.harness import hollow
+    from kubetpu.scheduler import Scheduler
+    from kubetpu.utils import trace as utrace
+    from tools.traceview import auction_summary
+
+    fr = utrace.arm_flight_recorder()
+    fr.clear()
+    try:
+        store = ClusterStore()
+        for n in hollow.make_nodes(8, zones=2):
+            store.add(n)
+        cfg = KubeSchedulerConfiguration(
+            profiles=[KubeSchedulerProfile()], batch_size=8, mode="gang",
+            prewarm=False)
+        sched = Scheduler(store, config=cfg, async_binding=False)
+        for p in hollow.make_pods(16, prefix="m-", group_labels=0):
+            store.add(p)
+        for _ in range(6):
+            if not sched.schedule_pending(timeout=0.0):
+                break
+        sched.close()
+        doc = fr.to_pipeline_doc(workload="test")
+        metas = [c["meta"] for c in doc["cycle_meta"]
+                 if c.get("meta", {}).get("auction_rounds") is not None]
+        assert metas, "no gang cycle recorded auction_rounds meta"
+        assert all("kernel_backend" not in m for m in metas), metas
+        line = auction_summary(doc)
+        assert line.startswith("auction rounds:") and "backend" not in line
+    finally:
+        utrace.disarm_flight_recorder()
+
+
+def test_bench_rounds_hist():
+    import bench
+    assert bench._rounds_hist([1, 4, 4, 2, 4]) == {"1": 1, "2": 1, "4": 3}
+    assert bench._rounds_hist([]) == {}

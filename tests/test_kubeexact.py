@@ -16,7 +16,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from tools.kubeexact import vmem  # noqa: E402
 from tools.kubeexact.driver import (ExactResult, ProofResult,  # noqa: E402
                                     prove_callable, prove_entry, run_exact)
 from tools.kubeexact.manifest import (build_manifest,  # noqa: E402
@@ -123,15 +122,6 @@ def test_raw_tie_argmax_fires_and_gumbel_is_clean():
     assert "exact/raw-tie-argmax" not in rule_ids(findings)
 
 
-def test_vmem_over_budget():
-    over = vmem.budget([{"name": "huge", "kind": "scratch",
-                         "shape": [4096, 4096], "dtype": "float32"}])
-    assert not over["fits"]
-    ok = vmem.budget([{"name": "tile", "kind": "in",
-                       "shape": [128, 128], "dtype": "float32"}])
-    assert ok["fits"] and ok["buffers"][0]["copies"] == 2
-
-
 def test_clean_snippet_is_empty():
     mesh = _mesh()
 
@@ -164,7 +154,7 @@ def _tiny_result():
         surface={"n8_b8": [{"op": "psum", "kind": "sum", "axes": ["pods"],
                             "dtype": "float32", "shape": [8],
                             "bytes": 32}]},
-        vmem=None, facts=(("zone_hot", "onehot_rows"),))
+        facts=(("zone_hot", "onehot_rows"),))
     return ExactResult(results=[pr],
                        headroom={"floor": 4.0, "min_margin": 1024.0,
                                  "dominating": "prog:variant",
@@ -218,15 +208,6 @@ def test_check_manifest_pure_json(tmp_path):
     bad["programs"]["prog:variant"]["proofs"][0]["status"] = "violation"
     assert any("not exact/exempt" in f
                for f in check_manifest(bad, census_path=census))
-    # VMEM totals re-derive from the committed buffer rows
-    vm = json.loads(json.dumps(doc))
-    vm["programs"]["prog:variant"]["vmem"] = {
-        "buffers": [{"name": "x", "kind": "in", "shape": [8, 8],
-                     "dtype": "float32", "copies": 2, "bytes": 512}],
-        "total_bytes": 999, "capacity_bytes": 16 * 1024 * 1024,
-        "utilization": 0.0, "fits": True}
-    assert any("re-derived" in f for f in check_manifest(vm,
-                                                         census_path=census))
     # env drift fails
     env = json.loads(json.dumps(doc))
     env["northstar_env"] = dict(env["northstar_env"], B=1.0)
@@ -247,12 +228,19 @@ def test_check_census_join_flags_unlicensed_programs(tmp_path):
 # exemptions: audited, stale ones flagged
 
 
-def test_stale_exemption_fires():
-    # the pallas entry builds no device mesh, so it proves under the
-    # test session's virtual 8-device CPU topology
+def test_stale_exemption_fires(monkeypatch):
+    # the shard_map entries build a (1, 1) mesh over the whole backend
+    # (the gate's environment has one CPU device); the test session has
+    # eight virtual ones, so hand the builder the first
+    from kubetpu.parallel import mesh as pmesh
     from tools.kubecensus.registry import ENTRIES
+    make_mesh = pmesh.make_mesh
+    monkeypatch.setattr(
+        pmesh, "make_mesh",
+        lambda shape=None, devices=None: make_mesh(
+            shape, devices=jax.devices()[:1]))
     entry = next(e for e in ENTRIES
-                 if e.exact and e.key == "_schedule_gang:pallas")
+                 if e.exact and e.key == "_shardmap_gang:tiled")
     stale = dataclasses.replace(
         entry, exact_exempt=entry.exact_exempt
         + (("exact/raw-collective-reduce", "obsolete"),))

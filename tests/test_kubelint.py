@@ -287,56 +287,6 @@ def f(x):
     assert any(f.rule == "recompile/shape-branch" for f in res.findings)
 
 
-def test_pallas_dynamic_grid_fires(tmp_path):
-    """len(...) of a host container and floor division of a shape-derived
-    value both poison pallas grid/block dims: per-size Mosaic recompiles,
-    and the floor-div silently drops the remainder tile."""
-    src = """
-import jax
-from jax.experimental import pallas as pl
-
-def kernel(x_ref, o_ref):
-    o_ref[...] = x_ref[...]
-
-def run(x, items):
-    grid = (len(items),)
-    return pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[pl.BlockSpec((x.shape[0] // 8, 128),
-                               lambda i: (i, 0))],
-        out_shape=x)(x)
-"""
-    res = lint_snippet(tmp_path, src, rules=["recompile"])
-    hits = [f for f in res.findings
-            if f.rule == "recompile/pallas-dynamic-grid"]
-    assert len(hits) >= 2, [str(f) for f in res.findings]
-
-
-def test_pallas_bucketed_grid_quiet(tmp_path):
-    """Ceil division over aval shapes (pl.cdiv or -(-a // b)) and
-    pow2_bucket-wrapped sizes are the blessed forms — quiet."""
-    src = """
-import jax
-from jax.experimental import pallas as pl
-from kubetpu.utils.intern import pow2_bucket
-
-def kernel(x_ref, o_ref):
-    o_ref[...] = x_ref[...]
-
-def run(x, items):
-    nt = -(-x.shape[0] // 128)
-    grid = (pl.cdiv(x.shape[1], 128), nt, pow2_bucket(len(items)))
-    return pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[pl.BlockSpec((128, 128), lambda i, j, k: (i, j))],
-        out_shape=x)(x)
-"""
-    res = lint_snippet(tmp_path, src, rules=["recompile"])
-    assert not [f for f in res.findings
-                if f.rule == "recompile/pallas-dynamic-grid"], (
-        [str(f) for f in res.findings])
-
-
 # ---------------------------------------------------------------------------
 # numeric family
 
@@ -444,52 +394,6 @@ def helper(k, v):
     kinds = [f.message for f in res.findings
              if f.rule == "purity/global-mutate"]
     assert len(kinds) >= 2  # global stmt + container mutation
-
-
-def test_purity_pallas_host_callback_fires(tmp_path):
-    """Host callbacks inside a pallas kernel body: both detection modes —
-    the function passed to pallas_call, and the *_ref naming convention
-    (the builder-pattern kernel pallas_call can't see directly)."""
-    src = """
-import jax
-from jax.experimental import pallas as pl
-
-def kernel(x_ref, o_ref):
-    jax.debug.callback(print, x_ref[0])
-    o_ref[...] = x_ref[...]
-
-def builder_kernel(a_ref, b_ref, o_ref):
-    jax.pure_callback(lambda v: v, a_ref[...], a_ref[...])
-    o_ref[...] = a_ref[...] + b_ref[...]
-
-@jax.jit
-def run(x):
-    return pl.pallas_call(kernel, out_shape=x)(x)
-"""
-    res = lint_snippet(tmp_path, src, rules=["purity"])
-    hits = [f for f in res.findings
-            if f.rule == "purity/pallas-host-callback"]
-    assert len(hits) >= 2, [str(f) for f in res.findings]
-
-
-def test_purity_pallas_debug_print_quiet(tmp_path):
-    """pl.debug_print is the sanctioned in-kernel print — quiet."""
-    src = """
-import jax
-from jax.experimental import pallas as pl
-
-def kernel(x_ref, o_ref):
-    pl.debug_print("x = {}", x_ref[0])
-    o_ref[...] = x_ref[...]
-
-@jax.jit
-def run(x):
-    return pl.pallas_call(kernel, out_shape=x)(x)
-"""
-    res = lint_snippet(tmp_path, src, rules=["purity"])
-    assert not [f for f in res.findings
-                if f.rule == "purity/pallas-host-callback"], (
-        [str(f) for f in res.findings])
 
 
 def test_purity_quiet_without_jit(tmp_path):
@@ -1146,6 +1050,18 @@ def pick(scores):
 def test_exact_family_registered():
     from tools.kubelint import RULE_FAMILIES
     assert "exact" in RULE_FAMILIES
+
+
+def test_exact_rule_modules_exist():
+    """The blessed-helper home and every cross-axis selection module the
+    exact family names must be files of this tree: a rule keyed on a
+    module that was deleted or renamed guards nothing."""
+    import importlib.util
+
+    from tools.kubelint import rules_exact
+    for name in (rules_exact._BLESSED_MODULE,
+                 *rules_exact._SELECTION_MODULES):
+        assert importlib.util.find_spec(name) is not None, name
 
 
 # ---------------------------------------------------------------------------

@@ -16,7 +16,10 @@ Two lowerings exist (parallel/mesh.py ``partitioner=``):
   runs and passes, so it is asserted like the rest (ROADMAP C2 decides
   whether the old lowering stays at all).
 """
+import random
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -26,6 +29,9 @@ from kubetpu.models import programs
 from kubetpu.models.gang import schedule_gang
 from kubetpu.models.sequential import schedule_sequential
 from kubetpu.parallel import mesh as pmesh
+from kubetpu.parallel import shardmap
+from tests.test_gang import build
+from tests.test_tensors import mknode, mkpod
 
 cpu_devices = jax.devices("cpu")
 pytestmark = pytest.mark.skipif(len(cpu_devices) < 8,
@@ -115,8 +121,7 @@ def test_sharded_sequential_pod_axis_2d_shard_map():
 
 def _term_free_world(n_nodes=32, n_pods=16):
     """A term-free world (no pod topology terms, no controller spread
-    selectors): the tiled shard_map surface — the same supported
-    surface as the Pallas megakernel."""
+    selectors): the tiled shard_map surface."""
     from kubetpu.framework.types import NodeInfo, PodInfo
     from kubetpu.harness import hollow
     from kubetpu.models.batch import PodBatchBuilder
@@ -150,8 +155,6 @@ def test_sharded_gang_tiled_term_free():
     collectives and pods-axis all_gather resolution — and must be
     bit-identical to the lax oracle, both monolithic and through the
     windowed-residual (masked window) rounds."""
-    from kubetpu.parallel import shardmap
-
     cluster, batch, cfg, rng = _term_free_world()
     mesh = pmesh.make_mesh((2, 4), devices=cpu_devices[:8])
     assert shardmap.gang_surface(cfg, False, batch, mesh, 32,
@@ -169,6 +172,218 @@ def test_sharded_gang_tiled_term_free():
                                        intra_batch_topology=False,
                                        residual_window=4)
     _assert_gang_equal(refw, resw)
+
+
+# --------------------------------------------------------------------------
+# the tiled auction against the single-device auction, differentially:
+# build_bundle's cases (full default score family, hostPorts, taints,
+# existing pods' preferred affinity in cluster.score_terms, a host score
+# bias plane) on worlds the one plain world above does not reach
+
+FULL_FILTERS = ("NodeUnschedulable", "NodeResourcesFit", "NodeName",
+                "NodePorts", "NodeAffinity", "TaintToleration",
+                "PodTopologySpread", "InterPodAffinity")
+
+
+def churned_world(seed, n_nodes, n_pods):
+    """Randomized churned world: heterogeneous capacities, zones, taints,
+    unschedulable nodes, hostPort pods, tolerations, preferred NODE
+    affinity, and existing pods carrying preferred POD affinity — the
+    latter lands in cluster.score_terms, so the InterPodAffinity raw
+    plane is genuinely nonzero (IPA coverage withOUT batch terms, which
+    is exactly the tiled surface)."""
+    r = random.Random(seed)
+    nodes = []
+    for i in range(n_nodes):
+        labels = {"disk": r.choice(["ssd", "hdd"])}
+        if r.random() < 0.8:
+            labels[api.LABEL_ZONE] = "z%d" % r.randrange(3)
+        taints = []
+        if r.random() < 0.2:
+            taints.append(api.Taint(
+                key="dedicated", value="gpu",
+                effect=r.choice(["NoSchedule", "PreferNoSchedule"])))
+        nodes.append(mknode(name=f"n{i}", labels=labels,
+                            cpu=r.choice(["2", "4", "8"]),
+                            mem=r.choice(["4Gi", "16Gi"]),
+                            pods=str(r.choice([4, 8, 110])),
+                            taints=taints,
+                            unschedulable=r.random() < 0.05))
+    existing = {}
+    for i in range(n_nodes):
+        eps = []
+        for j in range(r.randrange(0, 4)):
+            p = mkpod(name=f"e{i}_{j}",
+                      labels={"app": r.choice(["a", "b", "c"])},
+                      cpu=r.choice(["100m", "500m"]), mem="128Mi")
+            if r.random() < 0.3:
+                p.spec.affinity = api.Affinity(pod_affinity=api.PodAffinity(
+                    preferred_during_scheduling_ignored_during_execution=[
+                        api.WeightedPodAffinityTerm(
+                            weight=r.choice([10, 50]),
+                            pod_affinity_term=api.PodAffinityTerm(
+                                label_selector=api.LabelSelector(
+                                    match_labels={
+                                        "app": r.choice(["a", "b"])}),
+                                topology_key=api.LABEL_ZONE))]))
+            eps.append(p)
+        existing[f"n{i}"] = eps
+    pending = []
+    for i in range(n_pods):
+        kw = {}
+        if r.random() < 0.25:
+            kw["tolerations"] = [api.Toleration(key="dedicated",
+                                                operator="Exists")]
+        p = mkpod(name=f"p{i}", labels={"app": r.choice(["a", "b", "c"])},
+                  cpu=r.choice(["100m", "500m", "1"]),
+                  mem=r.choice(["64Mi", "512Mi"]), **kw)
+        if r.random() < 0.2:
+            p.spec.containers[0].ports = [api.ContainerPort(
+                container_port=8080, host_port=r.choice([8080, 9090]))]
+        if r.random() < 0.15:
+            p.spec.affinity = api.Affinity(node_affinity=api.NodeAffinity(
+                preferred_during_scheduling_ignored_during_execution=[
+                    api.PreferredSchedulingTerm(
+                        weight=r.choice([10, 100]),
+                        preference=api.NodeSelectorTerm(match_expressions=[
+                            api.NodeSelectorRequirement(
+                                key="disk", operator="In",
+                                values=["ssd"])]))]))
+        pending.append(p)
+    return build(nodes, existing, pending, filters=FULL_FILTERS,
+                 scores=programs.DEFAULT_SCORE_PLUGINS)
+
+
+def _tiled_vs_single(cluster, batch, cfg, rng, **kw):
+    """(single-device auction, tiled shard_map auction) of one call on
+    the (2, 4) mesh (both axes are pow2-bucketed from 8 up, so they
+    divide); asserts the dispatch really took the tiled surface."""
+    mesh = pmesh.make_mesh((2, 4), devices=cpu_devices[:8])
+    assert shardmap.gang_surface(
+        cfg, False, batch, mesh, int(cluster.allocatable.shape[0]),
+        int(batch.valid.shape[0])) == "tiled"
+    ref = schedule_gang(cluster, batch, cfg, rng,
+                        intra_batch_topology=False, **kw)
+    res = shardmap.schedule_gang_mesh(cluster, batch, cfg, rng, mesh,
+                                      intra_batch_topology=False, **kw)
+    return ref, res
+
+
+def test_tiled_differential_contended_full_scores():
+    """Contended auction (16 pods, 4 nodes) under the complete default
+    score family: every GangResult field bit-matches."""
+    nodes = [mknode(name=f"n{i}", cpu="2", pods="6") for i in range(4)]
+    pending = [mkpod(name=f"p{i}", cpu="500m") for i in range(16)]
+    cluster, batch, cfg, _ = build(nodes, {}, pending, filters=FULL_FILTERS,
+                                   scores=programs.DEFAULT_SCORE_PLUGINS)
+    ref, res = _tiled_vs_single(cluster, batch, cfg, jax.random.PRNGKey(5))
+    _assert_gang_equal(ref, res)
+    assert int(ref.rounds) >= 2, "contention must force multiple rounds"
+
+
+@pytest.mark.parametrize("seed,n_nodes,n_pods,rw", [
+    (0, 3, 24, 4),      # deep windowed residual rounds
+    (1, 150, 12, 0),    # wide node axis, monolithic loop
+    (2, 9, 17, 512),    # window wider than batch == full-width rounds
+    # three seeds of the former slow sweep, at its smaller sizes
+    (3, 9, 5, 512),
+    (4, 3, 40, 64),
+    (5, 40, 40, 4),
+])
+def test_tiled_differential_randomized_property(seed, n_nodes, n_pods, rw):
+    """Randomized churned clusters (ports/taints/zones/IPA score terms):
+    the tiled and the single-device GangResults are bit-identical,
+    across the windowed and monolithic round schedules."""
+    cluster, batch, cfg, _ = churned_world(seed, n_nodes, n_pods)
+    ref, res = _tiled_vs_single(cluster, batch, cfg,
+                                jax.random.PRNGKey(seed),
+                                residual_window=rw)
+    _assert_gang_equal(ref, res)
+
+
+def test_tiled_zero_feasible_pods_edge():
+    """Every node unschedulable: the auction terminates after round 0
+    with nothing placed, identically on both paths."""
+    nodes = [mknode(name=f"n{i}", unschedulable=True) for i in range(4)]
+    pending = [mkpod(name=f"p{i}") for i in range(8)]
+    cluster, batch, cfg, _ = build(nodes, {}, pending, filters=FULL_FILTERS,
+                                   scores=programs.DEFAULT_SCORE_PLUGINS)
+    ref, res = _tiled_vs_single(cluster, batch, cfg, jax.random.PRNGKey(1))
+    _assert_gang_equal(ref, res)
+    assert np.all(np.asarray(ref.chosen) == -1)
+
+
+def test_tiled_score_bias_plane():
+    """Host Score-plugin bias rides the tiled auction as a plane, applied
+    after the plugin combine exactly like the single-device round."""
+    nodes = [mknode(name=f"n{i}") for i in range(5)]
+    pending = [mkpod(name=f"p{i}") for i in range(6)]
+    cluster, batch, cfg, _ = build(nodes, {}, pending, filters=FULL_FILTERS,
+                                   scores=programs.DEFAULT_SCORE_PLUGINS)
+    B, N = batch.valid.shape[0], cluster.allocatable.shape[0]
+    bias = np.zeros((B, N), np.float32)
+    bias[:, :5] = np.random.RandomState(3).rand(5)[None, :] * 7
+    ref, res = _tiled_vs_single(cluster, batch, cfg, jax.random.PRNGKey(2),
+                                score_bias=jnp.asarray(bias))
+    _assert_gang_equal(ref, res)
+
+
+def _surface(cfg, intra, batch, shape=(2, 4), n_nodes=32, n_pods=16):
+    mesh = pmesh.make_mesh(shape, devices=cpu_devices[:shape[0] * shape[1]])
+    return shardmap.gang_surface(cfg, intra, batch, mesh, n_nodes, n_pods)
+
+
+def test_topology_batch_takes_the_replicated_surface():
+    """A batch carrying required anti-affinity routes
+    intra_batch_topology=True: the tiled auction freezes the pod axis,
+    so the dispatch must take the replicated single-device body."""
+    cluster, batch, cfg, _ = _term_free_world()
+    assert _surface(cfg, False, batch) == "tiled"
+    assert _surface(cfg, True, batch) == "replicated"
+
+
+def test_soft_spread_batch_takes_the_replicated_surface():
+    """The one content-dependent hole in the cfg-level gate: a batch
+    whose pods carry ScheduleAnyway spread constraints must leave the
+    tiled surface even under intra_batch_topology=False (its constant
+    PodTopologySpread path would silently diverge from the real soft
+    scoring)."""
+    nodes = [mknode(name=f"n{i}", labels={api.LABEL_ZONE: f"z{i % 2}",
+                                          api.LABEL_HOSTNAME: f"n{i}"})
+             for i in range(4)]
+    pending = [mkpod(name=f"p{i}", labels={"app": "a"}) for i in range(8)]
+    for p in pending:
+        p.spec.topology_spread_constraints = [api.TopologySpreadConstraint(
+            max_skew=1, topology_key=api.LABEL_ZONE,
+            when_unsatisfiable="ScheduleAnyway",
+            label_selector=api.LabelSelector(match_labels={"app": "a"}))]
+    cluster, batch, cfg, _ = build(nodes, {}, pending, filters=FULL_FILTERS,
+                                   scores=programs.DEFAULT_SCORE_PLUGINS)
+    n_nodes = int(cluster.allocatable.shape[0])
+    n_pods = int(batch.valid.shape[0])
+    assert _surface(cfg, False, batch, shape=(1, 1), n_nodes=n_nodes,
+                    n_pods=n_pods) == "replicated"
+    plain = batch._replace(spread_soft=batch.spread_soft._replace(
+        valid=np.zeros_like(batch.spread_soft.valid)))
+    assert _surface(cfg, False, plain, shape=(1, 1), n_nodes=n_nodes,
+                    n_pods=n_pods) == "tiled"
+
+
+def test_unsupported_score_plugin_takes_the_replicated_surface():
+    cluster, batch, cfg, _ = _term_free_world()
+    odd = cfg._replace(scores=(("RequestedToCapacityRatio", 1),))
+    assert _surface(odd, False, batch) == "replicated"
+    assert _surface(cfg._replace(scores=programs.DEFAULT_SCORE_PLUGINS),
+                    False, batch) == "tiled"
+
+
+@pytest.mark.parametrize("n_nodes,n_pods", [(30, 16), (32, 15)])
+def test_non_dividing_axis_takes_the_replicated_surface(n_nodes, n_pods):
+    """shard_map does not pad: either sharded axis failing to divide the
+    mesh leaves the tiled surface."""
+    cluster, batch, cfg, _ = _term_free_world()
+    assert _surface(cfg, False, batch, n_nodes=n_nodes,
+                    n_pods=n_pods) == "replicated"
 
 
 def test_sharded_gang_matches_single_device_gspmd_legacy():

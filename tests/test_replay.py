@@ -190,6 +190,55 @@ def test_corrupt_record_skips_with_reason_until_anchor(churned, tmp_path):
     assert rep["replayed"] + len(rep["skipped"]) == rep["records"]
 
 
+# ------------------------------------- records from before the one backend
+
+
+def _rewrite(d, seq, **fields):
+    path = os.path.join(d, record_filename(seq))
+    with open(path, "rb") as f:
+        rec = decode_record(f.read())
+    rec.update(fields)
+    with open(path, "wb") as f:
+        f.write(encode_record(rec))
+
+
+def test_record_written_by_the_removed_backend_is_refused(churned,
+                                                          tmp_path):
+    """A record that says kernel_backend "pallas" was produced by a
+    program that no longer exists: it is skipped with that reason, its
+    lineage breaks until the next anchor, and nothing else diverges."""
+    recs = churned["records"]
+    anchors = [r["seq"] for r in recs if r["input"] == "resync"]
+    seq = anchors[0] + 1
+    hi = anchors[1] + 2
+    d = str(tmp_path / "old-backend")
+    shutil.copytree(churned["dir"], d)
+    _rewrite(d, seq, kernel_backend="pallas")
+    rep = replay_journal(d, window=(anchors[0], hi))
+    reasons = {s["seq"]: s["reason"] for s in rep["skipped"]}
+    assert "removed Pallas kernel backend" in reasons[seq]
+    assert all("broken-lineage" in r for q, r in reasons.items()
+               if q != seq)
+    assert rep["replayed"] == rep["matched"] >= 3   # both anchors + tail
+    assert rep["bit_match"] is True
+
+
+def test_records_marked_lax_or_unmarked_replay(churned, tmp_path):
+    """Journals written before the field went carry kernel_backend
+    "lax"; new ones carry nothing.  Both replay bit-exact."""
+    recs = churned["records"]
+    assert all("kernel_backend" not in r for r in recs)
+    lo = recs[0]["seq"]
+    d = str(tmp_path / "marked-lax")
+    shutil.copytree(churned["dir"], d)
+    for r in recs[:8:2]:
+        _rewrite(d, r["seq"], kernel_backend="lax")
+    rep = replay_journal(d, window=(lo, lo + 7))
+    assert rep["skipped"] == []
+    assert rep["replayed"] == rep["matched"] == 8
+    assert rep["bit_match"] is True
+
+
 # -------------------------------------------------------- counterfactual
 
 

@@ -286,7 +286,7 @@ def test_chaos_recoveries_land_in_firing_window(tel):
 
         sched.recovery_log.append(
             {"kind": "dispatch-error", "cycle": 99,
-             "demoted": ["pallas->lax"]})
+             "demoted": ["aot->trace"]})
         w2 = tel.force_roll(sched)
         assert w2["recoveries"] == 1 and w2["demotions"] == 1
 

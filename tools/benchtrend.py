@@ -361,7 +361,7 @@ def northstar_check(rounds: List[Dict[str, Any]]
     # the trend check gates committed HISTORY, where placements_match
     # booleans may predate the oracle cases — only gate numeric drift
     detail = {k: v for k, v in latest["detail"].items()
-              if k not in ("warm_restart", "backend_compare")}
+              if k != "warm_restart"}
     detail["warm_restart"] = {
         k: v for k, v in (latest["detail"].get("warm_restart") or {}).items()
         if k != "placements_match"}

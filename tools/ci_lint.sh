@@ -86,19 +86,9 @@ python -m tools.kubeclose --json
 # Exactness manifest gate, pure-JSON half (tools/kubeexact --check, no
 # jax): the committed EXACT_MANIFEST.json must pin the northstar
 # environment and constants, keep every proof exact/exempt with margin
-# above the 4x floor, re-derive its VMEM totals from the committed
-# buffer rows, and name only programs COMPILE_MANIFEST.json licenses.
+# above the 4x floor, and name only programs COMPILE_MANIFEST.json
+# licenses.
 python -m tools.kubeexact --check --json
-# Pallas megakernel bit-match oracle (ops/pallas_kernels.py): the
-# interpret-mode differential suite on CPU — lax vs pallas GangResults
-# must be bit-identical on randomized churned clusters, the committed
-# golden worlds, and the fallback routings.  Also covers the two new
-# kubelint pallas checks (recompile/pallas-dynamic-grid,
-# purity/pallas-host-callback) via tests/test_kubelint.py above.
-# Environments without jax.experimental.pallas degrade to a REASONED
-# pytest skip (the suite's module-level skipif), never a failure.
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
-	tests/test_pallas_gang.py -q -m 'not slow' -p no:cacheprovider
 # Pod-axis mesh scale-out (kubetpu/parallel/shardmap.py): the explicit
 # shard_map auction/scan vs the single-device oracle on the 8-virtual-CPU
 # mesh — sharded-vs-unsharded bit-identity at the previously env-gated
@@ -158,17 +148,17 @@ JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
 	tests/test_devstats.py -q -m 'not slow' -p no:cacheprovider
 # Exactness prover gate, full half (tools/kubeexact): re-traces every
-# exact-marked mesh/Pallas root, re-proves each cross-shard/cross-tile
+# exact-marked mesh root, re-proves each cross-shard
 # reduction exact (float max/min or int-valued sum < 2**24 via the
 # integer-valuedness + interval lattice), re-enumerates the collective
-# surface and the Pallas VMEM budget, and fails on any unsuppressed
+# surface, and fails on any unsuppressed
 # exact/* finding, a stale exemption, or DRIFT against the committed
 # EXACT_MANIFEST.json in either direction.  Regenerate after an
 # intentional change: make exact (python -m tools.kubeexact --write).
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m tools.kubeexact --json
 # Exactness prover suite: every prover rule fires on a seeded bad
 # snippet (non-integer f32 psum, out-of-range sum, shard_map row-
-# gather, raw tie-argmax, VMEM over budget), clean snippets stay empty,
+# gather, raw tie-argmax), clean snippets stay empty,
 # manifest regeneration is byte-identical, the drift gate sees both
 # directions, and exemption staleness is audited.
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \

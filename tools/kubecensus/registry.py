@@ -191,8 +191,8 @@ class Entry:
     # exact=True opts the entry into the jaxpr-level exact-reduction
     # proof: every cross-shard/cross-tile float reduction must be proved
     # max/min or an integer-valued sum bounded below 2**24 at the
-    # north-star shapes.  The shard_map/Pallas family (the roots with
-    # collectives or grid-accumulator folds) must all be exact=True.
+    # north-star shapes.  The shard_map family (the roots with
+    # collectives) must all be exact=True.
     exact: bool = False
     # (input-path substring, fact name): seeds the abstract interpreter
     # with invariants the builders guarantee but tracing cannot see —
@@ -202,14 +202,10 @@ class Entry:
     # (rule, reason) exemptions for exactness findings, mirroring
     # ``exempt``: reasonless or stale entries are themselves findings.
     exact_exempt: Tuple[Tuple[str, str], ...] = ()
-    # symbol name per pallas grid axis ("" = literal grid size): lets the
-    # prover generalize a grid-axis fold count from the probe rung to the
-    # north-star environment (e.g. ("", "WB", "NT")).
-    exact_grid_syms: Tuple[str, ...] = ()
     # ---- closure prover metadata (tools/kubeclose) ---------------------
     # The (axis, value) assignment this entry covers in the program's
     # enumerated reachable-signature set: one pair per MULTI-VALUED
-    # closure axis (enumerated statics as canonical reprs — "'lax'",
+    # closure axis (enumerated statics as canonical reprs — "'tiled'",
     # "True" — and optional-dynamic presence axes as "absent"/"present").
     # kubeclose joins CLOSURE_MANIFEST combos against these, so a combo
     # no entry matches is close/uncaptured-signature and an entry whose
@@ -349,43 +345,21 @@ def _schedule_gang_bias(w):
 
 def _schedule_gang_notopo(w):
     from kubetpu.models import gang
-    # the term-free DEFAULT-BACKEND serving form: a batch with no
-    # topology terms routes intra_batch_topology=False (scheduler's
-    # needs_topo gate) while kernel_backend stays "lax" — a DISTINCT
-    # static combination from the plain entry (intra=True) that the
-    # closure prover found reachable-but-uncovered: the first term-free
-    # cycle of a default-config deployment compiled cold on the serving
-    # path
+    # the term-free serving form: a batch with no topology terms routes
+    # intra_batch_topology=False (scheduler's needs_topo gate) — a
+    # DISTINCT static combination from the plain entry (intra=True) that
+    # the closure prover found reachable-but-uncovered: the first
+    # term-free cycle of a default-config deployment compiled cold on
+    # the serving path
     return (gang._schedule_gang, (w.cluster, w.batch, w.cfg, w.rng),
-            {"intra_batch_topology": False, "kernel_backend": "lax"})
+            {"intra_batch_topology": False})
 
 
 def _schedule_gang_notopo_hostok(w):
     from kubetpu.models import gang
-    # host-filter cycles over a term-free batch on the lax backend
+    # host-filter cycles over a term-free batch
     return (gang._schedule_gang, (w.cluster, w.batch, w.cfg, w.rng),
-            {"host_ok": w.host_ok(), "intra_batch_topology": False,
-             "kernel_backend": "lax"})
-
-
-def _schedule_gang_pallas(w):
-    from kubetpu.models import gang
-    # the fused-megakernel serving call form: a TERM-FREE batch routes
-    # intra_batch_topology=False + kernel_backend="pallas" (scheduler's
-    # needs_topo gate); on CPU the pallas_call lowers under interpret=True
-    # — a DIFFERENT program (and AOT key) from a Mosaic lowering, which is
-    # exactly why the backend is a static arg
-    return (gang._schedule_gang, (w.cluster, w.batch, w.cfg, w.rng),
-            {"intra_batch_topology": False, "kernel_backend": "pallas"})
-
-
-def _schedule_gang_pallas_hostok(w):
-    from kubetpu.models import gang
-    # host-filter cycles (volume pods are term-free, so they still route
-    # to the megakernel) pass host_ok as KEYWORD like the serving seam
-    return (gang._schedule_gang, (w.cluster, w.batch, w.cfg, w.rng),
-            {"host_ok": w.host_ok(), "intra_batch_topology": False,
-             "kernel_backend": "pallas"})
+            {"host_ok": w.host_ok(), "intra_batch_topology": False})
 
 
 def _shardmap_mesh(w):
@@ -616,53 +590,29 @@ ENTRIES: List[Entry] = [
           _schedule_gang, meshable=True, static_argnums=(2,),
           closure_statics=(("host_ok", "absent"),
                            ("intra_batch_topology", "True"),
-                           ("kernel_backend", "'lax'"),
                            ("score_bias", "absent"))),
     Entry("_schedule_gang", "kubetpu.models.gang:_schedule_gang",
           _schedule_gang_hostok, tag="hostok", static_argnums=(2,),
           closure_statics=(("host_ok", "present"),
                            ("intra_batch_topology", "True"),
-                           ("kernel_backend", "'lax'"),
                            ("score_bias", "absent"))),
     Entry("_schedule_gang", "kubetpu.models.gang:_schedule_gang",
           _schedule_gang_bias, tag="bias", static_argnums=(2,),
           closure_statics=(("host_ok", "present"),
                            ("intra_batch_topology", "True"),
-                           ("kernel_backend", "'lax'"),
                            ("score_bias", "present"))),
     Entry("_schedule_gang", "kubetpu.models.gang:_schedule_gang",
           _schedule_gang_notopo, tag="notopo", static_argnums=(2,),
-          static_argnames=("intra_batch_topology", "kernel_backend"),
+          static_argnames=("intra_batch_topology",),
           closure_statics=(("host_ok", "absent"),
                            ("intra_batch_topology", "False"),
-                           ("kernel_backend", "'lax'"),
                            ("score_bias", "absent"))),
     Entry("_schedule_gang", "kubetpu.models.gang:_schedule_gang",
           _schedule_gang_notopo_hostok, tag="notopo_hostok",
           static_argnums=(2,),
-          static_argnames=("intra_batch_topology", "kernel_backend"),
+          static_argnames=("intra_batch_topology",),
           closure_statics=(("host_ok", "present"),
                            ("intra_batch_topology", "False"),
-                           ("kernel_backend", "'lax'"),
-                           ("score_bias", "absent"))),
-    Entry("_schedule_gang", "kubetpu.models.gang:_schedule_gang",
-          _schedule_gang_pallas, tag="pallas", static_argnums=(2,),
-          static_argnames=("intra_batch_topology", "kernel_backend"),
-          exact=True, exact_facts=(("zone_hot", "onehot_rows"),),
-          exact_grid_syms=("", "WB", "NT"),
-          closure_statics=(("host_ok", "absent"),
-                           ("intra_batch_topology", "False"),
-                           ("kernel_backend", "'pallas'"),
-                           ("score_bias", "absent"))),
-    Entry("_schedule_gang", "kubetpu.models.gang:_schedule_gang",
-          _schedule_gang_pallas_hostok, tag="pallas_hostok",
-          static_argnums=(2,),
-          static_argnames=("intra_batch_topology", "kernel_backend"),
-          exact=True, exact_facts=(("zone_hot", "onehot_rows"),),
-          exact_grid_syms=("", "WB", "NT"),
-          closure_statics=(("host_ok", "present"),
-                           ("intra_batch_topology", "False"),
-                           ("kernel_backend", "'pallas'"),
                            ("score_bias", "absent"))),
     Entry("_schedule_sequential",
           "kubetpu.models.sequential:_schedule_sequential",

@@ -3,8 +3,8 @@
 The fourth static-analysis layer (after kubelint, kubecensus and
 kubeexact): an abstract interpretation over the HOST Python that tracks
 the provenance of every value reaching a dispatch seam (the
-``aot.dispatch``-seamed serving programs, raw ``jit`` roots,
-``pallas_call`` grids) in a shape-determining or static-arg position,
+``aot.dispatch``-seamed serving programs, raw ``jit`` roots) in a
+shape-determining or static-arg position,
 with a lattice over {const, bool, config-constant, registry-enumerated,
 mesh-key, pad-capacity, pow2-bucketed, unbounded} propagated through
 calls, returns, dataclass fields, and the scheduler's

@@ -30,9 +30,6 @@ CONFIG_CLASSES = ("ProgramConfig", "KubeSchedulerConfiguration",
 # enumeration sound.  Value: a tuple of canonical reprs
 # (registry-enumerated), or None to pin the field symbolic explicitly.
 CONFIG_FIELD_DOMAINS: Dict[Tuple[str, str], Optional[Tuple[str, ...]]] = {
-    # the kernel backend knob: apis/config.py restricts it to the lax
-    # oracle and the fused Pallas megakernel
-    ("KubeSchedulerConfiguration", "kernel_backend"): ("'lax'", "'pallas'"),
     ("KubeSchedulerConfiguration", "mode"): ("'gang'", "'sequential'"),
     # read on the seam path only to normalize the static out of the
     # program key (gang) or via the _seq_cfg replica (sequential)
@@ -81,35 +78,6 @@ EXTRA_ROOTS = (
 # no finding is itself a close/stale-exemption finding.
 EXEMPTIONS: Tuple[Tuple[str, str, str], ...] = (
     # ---- branch correlations the flow-insensitive join cannot see ----
-    # schedule_gang forces backend="lax" BEFORE the seam whenever
-    # unsupported_reason(cfg, intra_batch_topology, batch) is non-None,
-    # and intra_batch_topology=True is unconditionally unsupported
-    # (utils/pallas_backend.py) — so the pallas x topology cross never
-    # reaches the jit; topology batches serve on the lax auction.
-    ("close/uncaptured-signature",
-     "_schedule_gang host_ok=absent intra_batch_topology=True "
-     "kernel_backend='pallas' score_bias=absent",
-     "statically excluded before the seam: unsupported_reason returns "
-     "'intra-batch-topology' and run_auction falls back to the lax "
-     "auction (the covered intra=True rows)"),
-    ("close/uncaptured-signature",
-     "_schedule_gang host_ok=present intra_batch_topology=True "
-     "kernel_backend='pallas' score_bias=absent",
-     "statically excluded before the seam: unsupported_reason returns "
-     "'intra-batch-topology' and run_auction falls back to the lax "
-     "auction (the covered intra=True rows)"),
-    ("close/uncaptured-signature",
-     "_schedule_gang host_ok=absent intra_batch_topology=True "
-     "kernel_backend='pallas' score_bias=present",
-     "statically excluded before the seam: unsupported_reason returns "
-     "'intra-batch-topology' and run_auction falls back to the lax "
-     "auction (the covered intra=True rows)"),
-    ("close/uncaptured-signature",
-     "_schedule_gang host_ok=present intra_batch_topology=True "
-     "kernel_backend='pallas' score_bias=present",
-     "statically excluded before the seam: unsupported_reason returns "
-     "'intra-batch-topology' and run_auction falls back to the lax "
-     "auction (the covered intra=True rows)"),
     # _shardmap_gang: gang_surface returns "replicated" whenever
     # intra_batch_topology=True, so the topology x tiled cross is
     # unreachable (parallel/shardmap.py gang_surface).
@@ -137,39 +105,28 @@ EXEMPTIONS: Tuple[Tuple[str, str, str], ...] = (
     # The bias-variant census row covers the common host-score profile
     # (host_ok AND score_bias from the same framework runner).  The rarer
     # crosses (a Score plugin without a Filter plugin, bias on the
-    # term-free/megakernel routes) fall back at the seam to the traced
+    # term-free route) fall back at the seam to the traced
     # jit dispatch: ONE bounded compile per (program, bucket), warmed by
     # Scheduler.prewarm's score_bias=warm_bias pass when the profile
     # declares host score plugins, and fenced by the BENCH_GATE watchdog
     # + the per-(program, shape) recompile watchdog.
     ("close/uncaptured-signature",
      "_schedule_gang host_ok=absent intra_batch_topology=True "
-     "kernel_backend='lax' score_bias=present",
+     "score_bias=present",
      "score-plugin-without-filter-plugin profile: traced-jit fallback at "
      "the seam, prewarmed by the score_bias=warm_bias prewarm variant"),
     ("close/uncaptured-signature",
      "_schedule_gang host_ok=absent intra_batch_topology=False "
-     "kernel_backend='lax' score_bias=present",
+     "score_bias=present",
      "score-plugin-without-filter-plugin profile on a term-free batch: "
      "traced-jit fallback at the seam, prewarmed by the "
      "score_bias=warm_bias prewarm variant"),
     ("close/uncaptured-signature",
      "_schedule_gang host_ok=present intra_batch_topology=False "
-     "kernel_backend='lax' score_bias=present",
-     "host filter+score profile on a term-free lax batch: traced-jit "
+     "score_bias=present",
+     "host filter+score profile on a term-free batch: traced-jit "
      "fallback at the seam, prewarmed by the score_bias=warm_bias "
      "prewarm variant"),
-    ("close/uncaptured-signature",
-     "_schedule_gang host_ok=absent intra_batch_topology=False "
-     "kernel_backend='pallas' score_bias=present",
-     "host score bias on the megakernel route: traced-jit fallback at "
-     "the seam (the megakernel's lax oracle serves the bias variant); "
-     "BENCH_GATE watchdog fences the compile"),
-    ("close/uncaptured-signature",
-     "_schedule_gang host_ok=present intra_batch_topology=False "
-     "kernel_backend='pallas' score_bias=present",
-     "host filter+score bias on the megakernel route: traced-jit "
-     "fallback at the seam; BENCH_GATE watchdog fences the compile"),
     ("close/uncaptured-signature",
      "_schedule_sequential host_ok=absent score_bias=present",
      "score-plugin-without-filter-plugin profile: traced-jit fallback at "

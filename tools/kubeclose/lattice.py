@@ -27,8 +27,8 @@ class, an optional explicit value set when the class is enumerable, and a
 The join is label-max with value-set union; ``unbounded`` absorbs.  A
 join of two enumerable labels stays enumerable (const ⊔ bool and
 const ⊔ registry-enumerated are registry-enumerated), which is what lets
-``kernel_backend or "lax"`` or a helper returning one of two literals
-enumerate instead of widening.
+``x or "default"`` or a helper returning one of two literals enumerate
+instead of widening.
 
 No jax imports anywhere in this package: the full prover runs in the
 no-jax CI gate.
@@ -96,7 +96,7 @@ def unbounded(why: str) -> Prov:
 def canon(v) -> str:
     """Canonical repr used for value sets, closure axes, and the
     registry's ``closure_statics`` metadata — plain ``repr`` so True /
-    512 / 'lax' / None all round-trip through JSON as strings."""
+    512 / 'tiled' / None all round-trip through JSON as strings."""
     return repr(v)
 
 

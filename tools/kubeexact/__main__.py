@@ -2,7 +2,7 @@
 
 --write      re-prove the registry and regenerate EXACT_MANIFEST.json
 --check      pure-JSON CI gate: re-validate the committed manifest
-             without jax (margins, proof statuses, VMEM re-derivation,
+             without jax (margins, proof statuses,
              environment pin, COMPILE_MANIFEST key join) — safe in
              ci_lint.sh before any jax import
 (default)    full gate: re-prove everything, fail on any unsuppressed

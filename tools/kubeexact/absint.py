@@ -1,11 +1,10 @@
 """The exactness lattice: an abstract interpreter over jaxprs.
 
-Proves the exact-reduction invariant for every cross-shard collective and
-cross-tile Pallas accumulator in a traced program: a float reduction is
-exact iff it is a max/min (exactly associative in IEEE754) or a sum of
-integer-valued terms whose value-range bound stays below 2**24 (f32
-integers are exact up to that magnitude, so any association order yields
-the same bits).
+Proves the exact-reduction invariant for every cross-shard collective
+in a traced program: a float reduction is exact iff it is a max/min
+(exactly associative in IEEE754) or a sum of integer-valued terms whose
+value-range bound stays below 2**24 (f32 integers are exact up to that
+magnitude, so any association order yields the same bits).
 
 Each jaxpr variable carries an ``AbsVal``:
 
@@ -19,9 +18,9 @@ Each jaxpr variable carries an ``AbsVal``:
                bearing component: a plain interval bounds the DPS zone
                count by N*P (hopeless), while "each pod lands on exactly
                one node" gives row sums <= P via the one-hot dot rule
-  lastsum_global  True when the bound was derived OUTSIDE the shard_map /
-               Pallas body, i.e. it bounds the GLOBAL row sum; summing a
-               value across disjoint shards/tiles is then bounded by the
+  lastsum_global  True when the bound was derived OUTSIDE the shard_map
+               body, i.e. it bounds the GLOBAL row sum; summing a
+               value across disjoint shards is then bounded by the
                single global bound instead of shards x local
   random       PRNG taint (threefry/random_bits and everything computed
                from them) — the gumbel-decomposition witness for the
@@ -33,11 +32,10 @@ Each jaxpr variable carries an ``AbsVal``:
                score planes), so a static plane index recovers the
                plane's own facts — the gumbel plane stays distinguishable
                from the integer count planes it is stacked with
-  sharded      dim -> mesh-axis (from shard_map in_names) or grid-axis
-               (from Pallas BlockSpec index maps) tiling marks
+  sharded      dim -> mesh-axis (from shard_map in_names) tiling marks
   tile_total   "summing this value over all tiles of axis k is <= Expr":
                produced when a dot contracts a tiled dim using a global
-               lastsum, consumed by psum / grid-fold bounds
+               lastsum, consumed by psum bounds
 
 Unknown primitives default to TOP (sound; precision recovers at the next
 comparison, which is bool-valued regardless of its inputs).  While-loop
@@ -93,9 +91,6 @@ class AbsVal:
     parts_axis: int = 0
     sharded: Optional[Dict[object, int]] = None   # key -> dim
     tile_total: Optional[Dict[object, Tuple[Expr, bool]]] = None
-    pid_deps: frozenset = frozenset()       # grid axes (linear deps only)
-    pin: Optional[Tuple[int, int]] = None   # value==1 <=> program_id(g)==c
-    origin: Optional[tuple] = None          # ("get", ref_id)
 
     # ---- helpers ------------------------------------------------------
     @property
@@ -113,8 +108,7 @@ class AbsVal:
         """Interval/int/random survive; positional structure does not."""
         base = dataclasses.replace(
             self, lastsum=None, lastsum_global=False, iota_dim=None,
-            varies=None, parts=None, sharded=None, tile_total=None,
-            pid_deps=frozenset(), pin=None, origin=None)
+            varies=None, parts=None, sharded=None, tile_total=None)
         return dataclasses.replace(base, **kw) if kw else base
 
 
@@ -138,7 +132,7 @@ def _join(a: AbsVal, b: AbsVal, shape=None) -> AbsVal:
         if _is_zero(q) and not _is_zero(p):
             out = p.replace(
                 shape=tuple(shape) if shape is not None else p.shape,
-                lo=p.lo.emin(ZERO), parts=None, origin=None)
+                lo=p.lo.emin(ZERO), parts=None)
             if not p.nonneg:
                 out.lastsum, out.lastsum_global = None, False
             return out
@@ -170,8 +164,7 @@ def _join(a: AbsVal, b: AbsVal, shape=None) -> AbsVal:
         iota_dim=a.iota_dim if a.iota_dim == b.iota_dim else None,
         varies=(a.varies | b.varies
                 if a.varies is not None and b.varies is not None else None),
-        sharded=sharded, tile_total=tt,
-        pin=a.pin if a.pin == b.pin else None)
+        sharded=sharded, tile_total=tt)
 
 
 def _bool01(shape) -> AbsVal:
@@ -180,10 +173,10 @@ def _bool01(shape) -> AbsVal:
 
 @dataclasses.dataclass
 class Reduction:
-    """One cross-shard collective or cross-tile accumulator fold."""
-    op: str                    # psum | pmax | ... | grid_fold
-    kind: str                  # sum | max | min | gather | store | ...
-    axes: Tuple[str, ...]      # mesh axis names ("grid" for Pallas folds)
+    """One cross-shard collective."""
+    op: str                    # psum | pmax | ...
+    kind: str                  # sum | max | min | gather | ...
+    axes: Tuple[str, ...]      # mesh axis names
     dtype: str
     shape: Tuple[int, ...]     # operand shape at the probe rung
     int_dtype: bool
@@ -193,37 +186,22 @@ class Reduction:
     note: str = ""
 
 
-@dataclasses.dataclass
-class _RefCell:
-    val: Optional[AbsVal] = None
-    acc_int: bool = True
-
-
 class Interp:
     """One abstract interpretation of a closed jaxpr.
 
     ``sizes``: dim-size -> tuple of candidate symbol names (bounds.
-    sym_table).  ``grid_syms``: Pallas grid axis -> Expr for its step
-    count (the caller knows the kernel's grid layout).  Findings that
-    need the north-star environment (sum bounds) are NOT emitted here —
+    sym_table).  Findings that need the north-star environment (sum
+    bounds) are NOT emitted here —
     reductions are recorded with symbolic bounds and judged by the
     driver, where entry exemptions apply."""
 
     def __init__(self, sizes: Dict[int, Tuple[str, ...]],
-                 grid_syms: Optional[Dict[int, Expr]] = None,
                  program: str = ""):
         self.sizes = dict(sizes or {})
-        self.grid_syms = dict(grid_syms or {})
         self.program = program
         self.reductions: List[Reduction] = []
         self.findings: List[Finding] = []
         self.in_shardmap = 0
-        self.in_kernel = 0
-        self.grid: Tuple[int, ...] = ()
-        self._pinned: List[frozenset] = []
-        self._defs: Dict[object, object] = {}   # Var -> eqn
-        self._env_all: Dict[object, AbsVal] = {}  # Var -> last written
-        self._refs: Dict[object, _RefCell] = {}  # Var(ref) -> cell
 
     # ---- symbols ------------------------------------------------------
     def size_expr(self, n: int) -> Expr:
@@ -233,11 +211,8 @@ class Interp:
     def mesh_sym(self, axis: str) -> Expr:
         return Expr.sym("MESH:%s" % axis)
 
-    def grid_expr(self, g: int, size: int) -> Expr:
-        return self.grid_syms.get(g, Expr.const(size))
-
     def _outside_body(self) -> bool:
-        return self.in_shardmap == 0 and self.in_kernel == 0
+        return self.in_shardmap == 0
 
     def _finding(self, rule: str, message: str) -> None:
         self.findings.append(Finding(rule=rule, program=self.program,
@@ -254,14 +229,10 @@ class Interp:
                invals: List[AbsVal]) -> List[AbsVal]:
         env: Dict[object, AbsVal] = {}
 
-        def write(var, val):
-            env[var] = val
-            self._env_all[var] = val
-
         for var, v in zip(jaxpr.constvars, consts):
-            write(var, v)
+            env[var] = v
         for var, v in zip(jaxpr.invars, invals):
-            write(var, v if v is not None else _top(var.aval))
+            env[var] = v if v is not None else _top(var.aval)
 
         def read(atom) -> AbsVal:
             if hasattr(atom, "val"):          # core.Literal
@@ -279,8 +250,7 @@ class Interp:
             for var, v in zip(eqn.outvars, outs):
                 if type(var).__name__ == "DropVar":
                     continue
-                write(var, v)
-                self._defs[var] = eqn
+                env[var] = v
         return [read(v) for v in jaxpr.outvars]
 
     # ---- literals / defaults ------------------------------------------
@@ -390,18 +360,12 @@ def _t_eq(interp, eqn, ins):
         last = len(shape) - 1
         # x[:, None] == iota  (either side): rows along the last axis hold
         # at most one True -> one-hot row, lastsum == 1.  Global iff
-        # derived outside a shard_map/Pallas body (a local iota only
+        # derived outside a shard_map body (a local iota only
         # enumerates the local tile).
         for p, q in ((a, b), (b, a)):
             if p.iota_dim == last and not q.varies_on(last):
                 v.lastsum = ONE
                 v.lastsum_global = interp._outside_body()
-    # program_id pin: eq(program_id(g), const c) -> value 1 <=> pid==c
-    for p, q in ((a, b), (b, a)):
-        if len(p.pid_deps) == 1 and q.varies == frozenset() \
-                and q.lo == q.hi and q.lo._const() is not None \
-                and p.origin == ("pid",):
-            v.pin = (next(iter(p.pid_deps)), int(q.lo._const()))
     return [v]
 
 
@@ -424,7 +388,7 @@ def _t_broadcast(interp, eqn, ins):
     shape = _shape(eqn)
     bdims = tuple(eqn.params["broadcast_dimensions"])
     out = a.replace(shape=shape, parts=None, sharded=None,
-                    tile_total=None, origin=None)
+                    tile_total=None)
     # varies: only images of (possibly-varying) operand dims vary
     src_varies = (a.varies if a.varies is not None
                   else frozenset(range(len(a.shape))))
@@ -453,7 +417,7 @@ def _t_broadcast(interp, eqn, ins):
 def _t_convert(interp, eqn, ins):
     (a,) = ins
     kind = _kind(eqn)
-    out = a.replace(shape=_shape(eqn), kind=kind, origin=None)
+    out = a.replace(shape=_shape(eqn), kind=kind)
     name = eqn.outvars[0].aval.dtype.name
     if kind == "int":
         out.int_valued = True
@@ -466,7 +430,6 @@ def _t_convert(interp, eqn, ins):
                               and hi_c <= 256.0)
         else:
             out.int_valued = a.int_valued
-    out.pin = a.pin            # bool -> int32 branch selector keeps pin
     return [out]
 
 
@@ -488,7 +451,7 @@ def _t_transpose(interp, eqn, ins):
     (a,) = ins
     perm = tuple(eqn.params["permutation"])
     shape = _shape(eqn)
-    out = a.replace(shape=shape, parts=None, origin=None)
+    out = a.replace(shape=shape, parts=None)
     inv = {old: new for new, old in enumerate(perm)}
     out.iota_dim = inv.get(a.iota_dim) if a.iota_dim is not None else None
     out.varies = (frozenset(inv[d] for d in a.varies)
@@ -509,7 +472,7 @@ def _t_squeeze(interp, eqn, ins):
     shape = _shape(eqn)
     keep = [d for d in range(len(a.shape)) if d not in dims]
     remap = {old: new for new, old in enumerate(keep)}
-    out = a.replace(shape=shape, parts=None, origin=None)
+    out = a.replace(shape=shape, parts=None)
     out.iota_dim = remap.get(a.iota_dim) if a.iota_dim is not None else None
     out.varies = (frozenset(remap[d] for d in a.varies if d in remap)
                   if a.varies is not None else None)
@@ -576,7 +539,7 @@ def _t_slice(interp, eqn, ins):
         if full_elsewhere:
             hit = _part_lookup(a, ax, starts[ax], limits[ax])
     base = hit if hit is not None else a
-    out = base.replace(shape=shape, parts=None, origin=None)
+    out = base.replace(shape=shape, parts=None)
     out.iota_dim = None        # offsets shift iota values
     out.varies = None
     if not base.nonneg and len(shape) > 0 \
@@ -590,8 +553,7 @@ def _t_slice(interp, eqn, ins):
 def _t_dynslice(interp, eqn, ins):
     a = ins[0]
     shape = _shape(eqn)
-    out = a.replace(shape=shape, parts=None, iota_dim=None, varies=None,
-                    origin=None)
+    out = a.replace(shape=shape, parts=None, iota_dim=None, varies=None)
     if not a.nonneg and len(shape) > 0 and shape[-1] != a.shape[-1]:
         out.lastsum, out.lastsum_global = None, False
     return [out]
@@ -652,15 +614,7 @@ def _t_pad(interp, eqn, ins):
             out.lastsum, out.lastsum_global = j.lastsum, j.lastsum_global
         return out
 
-    out = padded(a, _shape(eqn))
-    if a.parts is not None and tuple(
-            eqn.params["padding_config"][a.parts_axis]) == (0, 0, 0):
-        # untouched along the stacking axis: every slice of the result is
-        # the pad of that slice, so the per-slice facts survive (the
-        # Pallas bundle pads its stacked [S, B, N] planes on the node axis)
-        out.parts = tuple((p0, p1, padded(v)) for p0, p1, v in a.parts)
-        out.parts_axis = a.parts_axis
-    return [out]
+    return [padded(a, _shape(eqn))]
 
 
 # ---- arithmetic -------------------------------------------------------
@@ -686,11 +640,6 @@ def _t_addsub(interp, eqn, ins):
         if a.lastsum is not None or b.lastsum is not None:
             out.lastsum = la + lb
             out.lastsum_global = a.lastsum_global and b.lastsum_global
-    # linear-in-program_id tracking for disjoint-slice detection
-    if _const_like(b) and a.pid_deps:
-        out.pid_deps = a.pid_deps
-    elif _const_like(a) and b.pid_deps:
-        out.pid_deps = b.pid_deps
     out.sharded = a.sharded if a.sharded else b.sharded
     return [out]
 
@@ -712,10 +661,6 @@ def _t_mul(interp, eqn, ins):
                 out.lastsum = p.lastsum * q.hi
                 out.lastsum_global = p.lastsum_global
                 break
-    if _const_like(b) and a.pid_deps:
-        out.pid_deps = a.pid_deps
-    elif _const_like(a) and b.pid_deps:
-        out.pid_deps = b.pid_deps
     out.sharded = a.sharded if a.sharded else b.sharded
     return [out]
 
@@ -782,7 +727,7 @@ def _t_select(interp, eqn, ins):
         out = _join(out, c, shape=_shape(eqn))
     # value taint comes from the selected branches; a random predicate
     # choosing between non-random values does not make them gumbel
-    out = out.replace(shape=_shape(eqn), origin=None)
+    out = out.replace(shape=_shape(eqn))
     return [out]
 
 
@@ -814,7 +759,7 @@ def _t_ipow(interp, eqn, ins):
       "device_put")
 def _t_copy(interp, eqn, ins):
     a = ins[0]
-    return [a.replace(shape=_shape(eqn), origin=None)]
+    return [a.replace(shape=_shape(eqn))]
 
 
 @_reg("exp", "log", "log1p", "expm1", "tanh", "logistic", "rsqrt",
@@ -860,16 +805,10 @@ def _t_reduce_sum(interp, eqn, ins):
                         and axes == (len(a.shape) - 1,):
                     tt[key] = (a.lastsum, True)
                 else:
-                    tt[key] = (hi * _axis_fan(interp, key), False)
+                    tt[key] = (hi * interp.mesh_sym(key), False)
         if tt:
             out.tile_total = tt
     return [out]
-
-
-def _axis_fan(interp, key) -> Expr:
-    if isinstance(key, tuple) and key and key[0] == "grid":
-        return interp.grid_expr(key[1], 0)
-    return interp.mesh_sym(key)
 
 
 @_reg("reduce_max", "reduce_min", "cummax", "cummin", "argsort")
@@ -958,8 +897,7 @@ def _t_dot(interp, eqn, ins):
     out = AbsVal(_shape(eqn), _kind(eqn), int_valued, lo, hi,
                  random=_taint(ins))
     # the exact-count refinement (2D matmul contracting A's last axis,
-    # against B's first — or B's last, the transposed-rhs form the Pallas
-    # kernel's [channel, node] tables use):
+    # against B's first — or B's last, the transposed-rhs form):
     # out[s, z] = sum_p A[s, p] * B[p, z]       (or B[z, p])
     #   per-element   <= rowsum(A) * max(B)        (one-hot dot rule)
     #   per-row sum   <= rowsum(A) * rowsum(B)     (counts stay counts;
@@ -1049,8 +987,6 @@ def _stabilize(prev: AbsVal, out: AbsVal) -> AbsVal:
         sharded=prev.sharded if prev.sharded == out.sharded else None,
         tile_total=(prev.tile_total
                     if prev.tile_total == out.tile_total else None),
-        pid_deps=prev.pid_deps & out.pid_deps,
-        pin=prev.pin if prev.pin == out.pin else None,
     )
 
 
@@ -1066,10 +1002,10 @@ def _t_while(interp, eqn, ins):
     # fixpoint widening: seed the carries with their initial facts and
     # stabilize against the body until nothing degrades further.  This
     # is what lets the round loop carry the score-plane bundle (gumbel
-    # taint, per-plane decomposition, one-hot row sums) into the Pallas
-    # call inside the body without collapsing it to TOP.
+    # taint, per-plane decomposition, one-hot row sums) into the body
+    # without collapsing it to TOP.
     carry_vars = body.jaxpr.invars[bn:]
-    w = [v.replace(shape=tuple(var.aval.shape), origin=None)
+    w = [v.replace(shape=tuple(var.aval.shape))
          for v, var in zip(carry, carry_vars)]
     w += [_top(var.aval) for var in carry_vars[len(w):]]
     # fixpoint-search passes are muted: reductions/findings are recorded
@@ -1119,21 +1055,8 @@ def _t_cond(interp, eqn, ins):
     index, ops = ins[0], ins[1:]
     branches = eqn.params["branches"]
     outs_per = []
-    for bi, br in enumerate(branches):
-        pinned = frozenset()
-        if index.pin is not None and len(branches) == 2 and bi == 1:
-            pinned = frozenset((index.pin[0],))
-        # refs crossing into the branch (pl.when bodies) are the SAME
-        # cells: alias them so block-operand facts survive the boundary
-        # and branch writes land in the outer accumulator state
-        for atom, bvar in zip(eqn.invars[1:], br.jaxpr.invars):
-            if not hasattr(atom, "val") and atom in interp._refs:
-                interp._refs[bvar] = interp._refs[atom]
-        interp._pinned.append(pinned)
-        try:
-            outs_per.append(interp.run(br, list(ops)))
-        finally:
-            interp._pinned.pop()
+    for br in branches:
+        outs_per.append(interp.run(br, list(ops)))
     joined = []
     for i, o in enumerate(eqn.outvars):
         vals = [outs[i] for outs in outs_per if i < len(outs)]
@@ -1160,7 +1083,7 @@ def _t_shard_map(interp, eqn, ins):
                 continue
             for ax in (axes if isinstance(axes, tuple) else (axes,)):
                 sharded[ax] = dim
-        body_ins.append(v.replace(sharded=sharded or None, origin=None))
+        body_ins.append(v.replace(sharded=sharded or None))
     interp.in_shardmap += 1
     try:
         outs = interp._frame(body, [], body_ins)
@@ -1242,250 +1165,3 @@ def _t_all_gather(interp, eqn, ins):
 @_reg("axis_index")
 def _t_axis_index(interp, eqn, ins):
     return [AbsVal((), "int", True, ZERO, TOP)]
-
-
-# ---- Pallas -----------------------------------------------------------
-
-@_reg("program_id")
-def _t_program_id(interp, eqn, ins):
-    g = eqn.params["axis"]
-    size = interp.grid[g] if g < len(interp.grid) else 0
-    v = AbsVal((), "int", True, ZERO, Expr.const(max(size - 1, 0)))
-    v.pid_deps = frozenset((g,))
-    v.origin = ("pid",)
-    return [v]
-
-
-def _index_tree_vars(eqn, skip: int):
-    """Dynamic index operands of a get/swap (after ref [+ value])."""
-    return list(eqn.invars[skip:])
-
-
-def _static_scalar_starts(eqn, skip: int, interp=None):
-    """Best-effort NDIndexer decode: returns (axis0_static_index or None).
-    Static ints are baked into the tree; a scalar index lowered as a
-    dynamic leaf resolves through its atom when it is a Literal or a var
-    the interpreter knows to be a constant (lo == hi).  Used only to
-    recover a stacked plane by index — failure degrades to the joined
-    value, never to unsoundness."""
-    try:
-        import jax
-        idx = jax.tree_util.tree_unflatten(
-            eqn.params["tree"], _index_tree_vars(eqn, skip))
-        indexer = idx[0] if isinstance(idx, (list, tuple)) else idx
-        indices = getattr(indexer, "indices", None)
-        if not indices:
-            return None
-        first = indices[0]
-        if isinstance(first, int):
-            return first
-        start = getattr(first, "start", None)
-        size = getattr(first, "size", None)
-        if isinstance(start, int) and size == 1:
-            return start
-        if hasattr(first, "val"):            # jaxpr Literal leaf
-            return int(first.val)
-        if interp is not None and hasattr(first, "aval") \
-                and not getattr(first.aval, "shape", (1,)):
-            av = interp._abs_of_atom(first)
-            if av is not None:
-                lo, hi = av.lo._const(), av.hi._const()
-                if lo is not None and lo == hi and float(lo).is_integer():
-                    return int(lo)
-        return None
-    except Exception:
-        return None
-
-
-@_reg("get")
-def _t_get(interp, eqn, ins):
-    ref = eqn.invars[0]
-    cell = interp._refs.get(ref)
-    stored = cell.val if cell is not None and cell.val is not None \
-        else _top(eqn.outvars[0].aval)
-    shape = _shape(eqn)
-    axis0 = _static_scalar_starts(eqn, skip=1, interp=interp)
-    if axis0 is not None and stored.parts is not None \
-            and stored.parts_axis == 0:
-        part = _part_lookup(stored, 0, axis0, axis0 + 1)
-        if part is not None:
-            stored = part.replace(sharded=stored.sharded)
-    out = stored.replace(shape=shape, parts=None, origin=("get", ref))
-    if len(shape) != len(stored.shape):
-        # rank change via scalar indexing: remap trailing-dim facts by
-        # keeping them only when the last axis is untouched
-        drop = len(stored.shape) - len(shape)
-        if stored.sharded:
-            out.sharded = {k: d - drop for k, d in stored.sharded.items()
-                           if d - drop >= 0} or None
-    return [out]
-
-
-def _grid_multiplier(interp, g: int, size: int, pinned: frozenset,
-                     idx_deps: frozenset, covered: frozenset):
-    if g in covered or g in pinned or g in idx_deps:
-        return ONE
-    return interp.grid_expr(g, size)
-
-
-@_reg("swap")
-def _t_swap(interp, eqn, ins):
-    ref = eqn.invars[0]
-    value = ins[1]
-    cell = interp._refs.setdefault(ref, _RefCell())
-    old = cell.val
-    # classify the stored value against the cell: the three accumulator
-    # shapes the kernels use are  ref <- ref + v  (sum fold),
-    # ref <- max/min(ref, v)  (exact fold)  and  ref <- where(upd, v, ref)
-    # (conditional store); anything else is a plain store
-    deqn = interp._defs.get(eqn.invars[1])
-    acc, inc = None, None
-    if deqn is not None and deqn.primitive.name in ("add", "max", "min"):
-        srcs = [interp._defs.get(a) for a in deqn.invars]
-        del srcs
-        get_side = None
-        for i, a in enumerate(deqn.invars):
-            d = interp._defs.get(a)
-            if d is not None and d.primitive.name == "get" \
-                    and d.invars[0] is ref:
-                get_side = i
-        if get_side is not None:
-            acc = "sum" if deqn.primitive.name == "add" else "max"
-            other = deqn.invars[1 - get_side]
-            inc = ins[1]  # fallback
-            # re-read the increment's absval from the defining frame
-            # by construction it is one of the swap value's inputs —
-            # conservative fallback keeps the full value's bounds
-            inc = interp._abs_of_atom(other, fallback=ins[1])
-    if acc == "sum" and value.kind == "float":
-        pinned = frozenset().union(*interp._pinned) if interp._pinned \
-            else frozenset()
-        idx_deps = frozenset()
-        for a in _index_tree_vars(eqn, skip=2):
-            av = interp._abs_of_atom(a, fallback=None)
-            if av is not None:
-                idx_deps = idx_deps | av.pid_deps
-        covered = frozenset()
-        base_hi = inc.hi
-        note = []
-        for key in (inc.tile_total or {}):
-            if isinstance(key, tuple) and key and key[0] == "grid":
-                base_hi = inc.tile_total[key][0]
-                covered = covered | frozenset((key[1],))
-                note.append("disjoint-tile total over grid axis %d"
-                            % key[1])
-        total = base_hi
-        for g, size in enumerate(interp.grid):
-            total = total * _grid_multiplier(interp, g, size, pinned,
-                                             idx_deps, covered)
-        lo = ZERO if inc.nonneg else total.neg()
-        interp.reductions.append(Reduction(
-            op="grid_fold", kind="sum", axes=("grid",),
-            dtype=eqn.invars[1].aval.dtype.name,
-            shape=tuple(eqn.invars[1].aval.shape),
-            int_dtype=False, int_valued=inc.int_valued,
-            lo=lo, hi=total, note="; ".join(note)))
-        stored = AbsVal(value.shape, value.kind,
-                        inc.int_valued and (old is None or old.int_valued),
-                        lo, total)
-    elif acc == "sum":
-        stored = value.drop_structure()
-        interp.reductions.append(Reduction(
-            op="grid_fold", kind="sum", axes=("grid",),
-            dtype=eqn.invars[1].aval.dtype.name,
-            shape=tuple(eqn.invars[1].aval.shape),
-            int_dtype=True, int_valued=True, lo=BOT, hi=TOP))
-    elif acc == "max":
-        interp.reductions.append(Reduction(
-            op="grid_fold", kind="max", axes=("grid",),
-            dtype=eqn.invars[1].aval.dtype.name,
-            shape=tuple(eqn.invars[1].aval.shape),
-            int_dtype=_dtype_kind(eqn.invars[1].aval.dtype) != "float",
-            int_valued=value.int_valued, lo=value.lo, hi=value.hi))
-        stored = value.drop_structure()
-    else:
-        stored = value.replace(origin=None)
-    cell.val = stored if old is None else _join(old, stored,
-                                                shape=old.shape)
-    # swap returns the OLD value
-    prev = old if old is not None else _top(eqn.outvars[0].aval)
-    return [prev.replace(shape=_shape(eqn), origin=None)]
-
-
-@_reg("addupdate")
-def _t_addupdate(interp, eqn, ins):
-    ref = eqn.invars[0]
-    value = ins[1]
-    cell = interp._refs.setdefault(ref, _RefCell())
-    interp.reductions.append(Reduction(
-        op="grid_fold", kind="sum", axes=("grid",),
-        dtype=eqn.invars[1].aval.dtype.name,
-        shape=tuple(eqn.invars[1].aval.shape),
-        int_dtype=_dtype_kind(eqn.invars[1].aval.dtype) != "float",
-        int_valued=value.int_valued, lo=BOT, hi=TOP,
-        note="addupdate accumulator (unmodeled fold bound)"))
-    cell.val = (value.drop_structure() if cell.val is None
-                else _join(cell.val, value, shape=cell.val.shape))
-    return []
-
-
-@_reg("pallas_call")
-def _t_pallas_call(interp, eqn, ins):
-    gm = eqn.params["grid_mapping"]
-    body = eqn.params["jaxpr"]           # kernel jaxpr (refs as invars)
-    if not hasattr(body, "consts"):      # the kernel body is a plain Jaxpr
-        from jax.extend.core import ClosedJaxpr
-        body = ClosedJaxpr(body, ())
-    grid = tuple(int(g) for g in gm.grid)
-    block_ins: List[Optional[AbsVal]] = []
-    mappings = list(gm.block_mappings)
-    n_in = gm.num_inputs
-    for i, bm in enumerate(mappings[:n_in]):
-        v = ins[i] if i < len(ins) else None
-        if v is None:
-            block_ins.append(None)
-            continue
-        sharded = dict(v.sharded or {})
-        idx_j = bm.index_map_jaxpr.jaxpr
-        if not idx_j.eqns:     # identity tiling: outvars are grid invars
-            for dim, ov in enumerate(idx_j.outvars):
-                for g, iv in enumerate(idx_j.invars):
-                    if ov is iv:
-                        sharded[("grid", g)] = dim
-        block_ins.append(v.replace(
-            shape=tuple(bm.block_shape), sharded=sharded or None,
-            origin=None))
-    prev_grid, prev_refs = interp.grid, interp._refs
-    interp.grid, interp._refs = grid, {}
-    interp.in_kernel += 1
-    try:
-        invars = body.jaxpr.invars
-        frame_ins = []
-        for i, var in enumerate(invars):
-            if i < len(block_ins) and block_ins[i] is not None:
-                v = block_ins[i]
-                # the ref's cell starts as the block operand's facts
-                interp._refs[var] = _RefCell(val=v)
-                frame_ins.append(v)
-            else:
-                interp._refs[var] = _RefCell()
-                frame_ins.append(_top(var.aval) if hasattr(var, "aval")
-                                 else None)
-        interp._frame(body.jaxpr,
-                      [interp._literal_val_abs(c) for c in body.consts],
-                      frame_ins)
-    finally:
-        interp.in_kernel -= 1
-        interp.grid, interp._refs = prev_grid, prev_refs
-    return [_top(o.aval) for o in eqn.outvars]
-
-
-# absval lookup for an atom from the most recent frame write
-def _abs_of_atom(self, atom, fallback=None):
-    if hasattr(atom, "val"):
-        return self._literal(atom)
-    got = self._env_all.get(atom)
-    return got if got is not None else fallback
-
-
-Interp._abs_of_atom = _abs_of_atom

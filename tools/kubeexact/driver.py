@@ -5,15 +5,13 @@ For every registry entry with ``exact=True`` this module
   1. traces the program at its largest ladder rung (the probe rung) and
      runs the exactness lattice (absint.Interp) over the jaxpr, seeding
      input facts the builders guarantee (entry.exact_facts);
-  2. judges every recorded cross-shard/cross-tile reduction against the
+  2. judges every recorded cross-shard reduction against the
      committed north-star environment: float max/min and integer-dtype
      sums are exact by construction; float sums must be integer-valued
      with a finite symbolic bound that evaluates below 2**24;
   3. walks the collective surface at every ladder rung (operand bytes
      per rung — the DCN cost attribution kubecensus joins);
-  4. computes the static VMEM budget for Pallas entries from the
-     kernel's own buffer table evaluated at the north-star layout;
-  5. applies the entry's audited (rule, reason) exemptions, flagging
+  4. applies the entry's audited (rule, reason) exemptions, flagging
      stale ones exactly like kubecensus.
 
 ``prove_callable`` is the public seam the bad-snippet tests drive,
@@ -28,9 +26,9 @@ from typing import Dict, List, Optional, Tuple
 from tools.kubecensus.registry import ENTRIES, Entry, Rung, build_world
 from tools.kubecensus.rules import Finding
 
-from . import northstar, surface, vmem
+from . import northstar, surface
 from .absint import AbsVal, Interp, Reduction
-from .bounds import INT_EXACT_LIMIT, ONE, ZERO, Expr, sym_table
+from .bounds import INT_EXACT_LIMIT, ONE, ZERO, sym_table
 
 
 # ---------------------------------------------------------------- facts
@@ -137,7 +135,6 @@ class ProofResult:
     findings: List[Finding]          # unsuppressed
     suppressed: List[Finding]
     surface: Dict[str, List[dict]]   # rung name -> collective rows
-    vmem: Optional[dict] = None
     facts: Tuple[Tuple[str, str], ...] = ()
 
     @property
@@ -149,7 +146,6 @@ def prove_callable(program: str, fn, args: tuple, kwargs: dict = None,
                    static_argnames: Tuple[str, ...] = (),
                    static_argnums: Tuple[int, ...] = (),
                    facts: Tuple[Tuple[str, str], ...] = (),
-                   grid_syms: Tuple[str, ...] = (),
                    sizes: Optional[Dict[str, int]] = None,
                    env: Optional[Dict[str, float]] = None,
                    ) -> Tuple[List[dict], List[Finding]]:
@@ -163,10 +159,8 @@ def prove_callable(program: str, fn, args: tuple, kwargs: dict = None,
                             static_argnums)
     closed = jax.make_jaxpr(call)(*flat)
     invals = _input_absvals(flat, closed.jaxpr.invars, tuple(facts))
-    gs = {i: Expr.sym(name)
-          for i, name in enumerate(grid_syms) if name}
     interp = Interp(sym_table({k: int(v) for k, v in (sizes or {}).items()}),
-                    grid_syms=gs, program=program)
+                    program=program)
     interp.run(closed, invals)
     env = dict(northstar.NORTHSTAR_ENV if env is None else env)
     proofs: List[dict] = []
@@ -188,31 +182,6 @@ def _entry_sizes(w) -> Dict[str, int]:
             "Z": int(w.cluster.zone_hot.shape[-1])}
 
 
-def _entry_vmem(entry: Entry, w) -> Optional[dict]:
-    """North-star VMEM budget for a Pallas entry, from the kernel's own
-    buffer table evaluated at the committed deployment layout."""
-    if not entry.exact_grid_syms:
-        return None
-    from kubetpu.ops.pallas_kernels import _layout, kernel_buffers
-
-    ns = northstar.NORTHSTAR_ENV
-    W, N = int(ns["B"]), int(ns["N"])
-    # has_bias=True is the worst case (one more score plane resident);
-    # the ports vocabulary is workload- not scale-bound, so the probe
-    # world's bucket is the committed parameter (recorded in the row)
-    ports = int(w.cluster.ports.shape[1])
-    L = _layout(w.cfg, True, W=W, N=N, R=int(ns["R"]),
-                P=ports, Z=int(ns["Z"]))
-    WB = -(-W // L.TB)
-    bufs = kernel_buffers(L, WB)
-    out = vmem.budget(list(bufs))
-    out["params"] = {"W": W, "N": N, "R": int(ns["R"]),
-                     "Z": int(ns["Z"]), "ports": ports,
-                     "TB": L.TB, "TN": L.TN, "WB": WB, "NT": L.NT,
-                     "n_stats": L.n_stats, "planes": len(L.planes)}
-    return out
-
-
 def prove_entry(entry: Entry) -> ProofResult:
     """Prove one registry entry at its largest ladder rung, census the
     collective surface at every rung, and apply its audited exemptions."""
@@ -226,7 +195,6 @@ def prove_entry(entry: Entry) -> ProofResult:
         static_argnames=entry.static_argnames,
         static_argnums=entry.static_argnums,
         facts=entry.exact_facts,
-        grid_syms=entry.exact_grid_syms,
         sizes=_entry_sizes(w))
 
     surf: Dict[str, List[dict]] = {}
@@ -262,8 +230,7 @@ def prove_entry(entry: Entry) -> ProofResult:
                 "entry (reason was: %s)" % (rule, reason)))
     return ProofResult(program=entry.key, proofs=proofs,
                        findings=findings, suppressed=suppressed,
-                       surface=surf, vmem=_entry_vmem(entry, w),
-                       facts=entry.exact_facts)
+                       surface=surf, facts=entry.exact_facts)
 
 
 # ---------------------------------------------------------------- headroom
@@ -327,11 +294,5 @@ def run_exact(entries: Optional[List[Entry]] = None) -> ExactResult:
     for r in results:
         findings.extend(r.findings)
         suppressed.extend(r.suppressed)
-        if r.vmem is not None and not r.vmem["fits"]:
-            findings.append(Finding(
-                "exact/vmem-over-budget", r.program,
-                "static VMEM budget %d bytes exceeds the %d-byte v5e "
-                "capacity at the north-star layout" % (
-                    r.vmem["total_bytes"], r.vmem["capacity_bytes"])))
     return ExactResult(results=results, headroom=hr, findings=findings,
                        suppressed=suppressed)

@@ -3,8 +3,8 @@ re-validation the no-jax CI gate runs.
 
 The committed manifest is the version-controlled exactness surface —
 every proved reduction with its symbolic bound and north-star margin,
-the collective surface (operand bytes per ladder rung), the static VMEM
-budget, and the committed environment the bounds were evaluated under.
+the collective surface (operand bytes per ladder rung), and the
+committed environment the bounds were evaluated under.
 Two consumers:
 
 * CI (``python -m tools.kubeexact``): re-proves the registry and fails
@@ -13,10 +13,10 @@ Two consumers:
   trace reproduces (dead entry).  Mirrors COMPILE_MANIFEST.json.
 * CI without jax (``python -m tools.kubeexact --check``): re-validates
   the committed file alone — margins above the floor, every proof
-  exact/exempt, VMEM totals re-derived from the committed buffer rows,
-  the environment byte-equal to tools/kubeexact/northstar.py, and every
-  program key present in COMPILE_MANIFEST.json (the exactness surface
-  cannot name a program the compile census does not license).
+  exact/exempt, the environment byte-equal to
+  tools/kubeexact/northstar.py, and every program key present in
+  COMPILE_MANIFEST.json (the exactness surface cannot name a program
+  the compile census does not license).
 """
 
 from __future__ import annotations
@@ -25,7 +25,7 @@ import json
 import os
 from typing import Dict, List, Optional
 
-from . import northstar, vmem
+from . import northstar
 
 MANIFEST_PATH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "EXACT_MANIFEST.json")
@@ -45,13 +45,11 @@ def build_manifest(res) -> dict:
                 {(f.rule, f.reason or "") for f in r.suppressed})],
             "proofs": r.proofs,
             "surface": r.surface,
-            "vmem": r.vmem,
         }
     return {
         "_comment": _COMMENT,
         "int_exact_limit": northstar.INT_EXACT_LIMIT,
         "margin_floor": northstar.MARGIN_FLOOR,
-        "vmem_capacity_bytes": northstar.VMEM_CAPACITY_BYTES,
         "northstar_env": dict(northstar.NORTHSTAR_ENV),
         "headroom": res.headroom,
         "programs": programs,
@@ -81,7 +79,7 @@ def diff_manifest(current: dict,
                   committed: Optional[dict]) -> Dict[str, list]:
     """Two-directional drift over program keys plus watched-content
     changes: added (proved, not committed), removed (committed, not
-    reproduced), changed (same program, different proofs/surface/vmem/
+    reproduced), changed (same program, different proofs/surface/
     facts/exemptions — or the committed environment itself moved)."""
     if committed is None:
         return {"added": sorted(current.get("programs", {})),
@@ -91,11 +89,11 @@ def diff_manifest(current: dict,
     added = sorted(set(cur) - set(com))
     removed = sorted(set(com) - set(cur))
     changed = []
-    for key in ("int_exact_limit", "margin_floor", "vmem_capacity_bytes",
-                "northstar_env", "headroom"):
+    for key in ("int_exact_limit", "margin_floor", "northstar_env",
+                "headroom"):
         if current.get(key) != committed.get(key):
             changed.append("<%s>" % key)
-    watched = ("facts", "exemptions", "proofs", "surface", "vmem")
+    watched = ("facts", "exemptions", "proofs", "surface")
     for k in sorted(set(cur) & set(com)):
         for w in watched:
             if cur[k].get(w) != com[k].get(w):
@@ -123,10 +121,6 @@ def check_manifest(doc: Optional[dict],
     if doc.get("margin_floor") != northstar.MARGIN_FLOOR:
         fails.append("margin_floor %r != northstar.MARGIN_FLOOR %r"
                      % (doc.get("margin_floor"), northstar.MARGIN_FLOOR))
-    if doc.get("vmem_capacity_bytes") != northstar.VMEM_CAPACITY_BYTES:
-        fails.append("vmem_capacity_bytes %r != northstar constant %r"
-                     % (doc.get("vmem_capacity_bytes"),
-                        northstar.VMEM_CAPACITY_BYTES))
     if doc.get("northstar_env") != northstar.NORTHSTAR_ENV:
         fails.append("northstar_env drifted from tools/kubeexact/"
                      "northstar.py — regenerate with --write")
@@ -146,19 +140,6 @@ def check_manifest(doc: Optional[dict],
             if m is not None and m < northstar.MARGIN_FLOOR:
                 fails.append("%s: margin %.4gx below the %gx floor"
                              % (key, m, northstar.MARGIN_FLOOR))
-        vm = prog.get("vmem")
-        if vm is not None:
-            re_vm = vmem.budget(vm.get("buffers", []),
-                                doc.get("vmem_capacity_bytes",
-                                        northstar.VMEM_CAPACITY_BYTES))
-            if re_vm["total_bytes"] != vm.get("total_bytes"):
-                fails.append("%s: committed VMEM total %r != %d re-derived "
-                             "from the committed buffer rows"
-                             % (key, vm.get("total_bytes"),
-                                re_vm["total_bytes"]))
-            if not vm.get("fits"):
-                fails.append("%s: committed VMEM budget does not fit "
-                             "capacity" % key)
     fails.extend(_check_census_join(doc, census_path))
     return fails
 

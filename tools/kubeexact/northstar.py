@@ -24,9 +24,6 @@ INT_EXACT_LIMIT = float(2 ** 24)
 # for per-shard padding before the invariant is threatened.
 MARGIN_FLOOR = 4.0
 
-# v5e per-core VMEM (see /opt/skills/guides; ~16 MiB usable)
-VMEM_CAPACITY_BYTES = 16 * 1024 * 1024
-
 # dimension symbols: probe-rung dim sizes are mapped to these names by
 # the driver (bounds.sym_table) and bounds re-evaluate here.
 #   B  pending-pod batch bucket      (rescore chunk 4096)
@@ -37,8 +34,6 @@ VMEM_CAPACITY_BYTES = 16 * 1024 * 1024
 #   MESH:pods / MESH:nodes           largest per-axis mesh fan any
 #                                    profile uses (v5e-8 pod-axis 8x;
 #                                    (2,4)/(4,2) node-axis up to 4)
-#   WB / NT                          Pallas grid steps at north-star:
-#                                    ceil(B/128) and ceil(N/128)
 NORTHSTAR_ENV = {
     "B": 4096.0,
     "N": 16384.0,
@@ -47,6 +42,4 @@ NORTHSTAR_ENV = {
     "Z": 64.0,
     "MESH:pods": 8.0,
     "MESH:nodes": 4.0,
-    "WB": 32.0,
-    "NT": 128.0,
 }

@@ -24,8 +24,8 @@ _ITEMSIZE = {"bool": 1, "int8": 1, "uint8": 1, "bfloat16": 2,
 
 def _sub_jaxprs(params: dict):
     """Every jaxpr reachable from an eqn's params — ClosedJaxpr (pjit,
-    scan, cond branches) AND plain Jaxpr (shard_map bodies, pallas
-    kernels store their body unclosed)."""
+    scan, cond branches) AND plain Jaxpr (shard_map bodies store theirs
+    unclosed)."""
     for v in params.values():
         items = v if isinstance(v, (list, tuple)) else [v]
         for u in items:

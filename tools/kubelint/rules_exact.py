@@ -1,7 +1,7 @@
 """exact/* — exact-reduction discipline rules.
 
-The bit-match contract survives multi-chip and multi-tile execution only
-because every cross-shard/cross-tile reduction is drawn from a blessed
+The bit-match contract survives multi-chip execution only
+because every cross-shard reduction is drawn from a blessed
 set (ops/kernels.py): float max/min (exactly associative), integer-valued
 f32 sums proven below 2**24 (tools/kubeexact), and the gumbel-decomposed
 tie-broken argmax.  tools/kubeexact proves the *traced* programs obey the
@@ -16,8 +16,8 @@ Rules:
                                 reductions through exact_psum/exact_pmax/
                                 exact_pmin so every collective site names
                                 its exactness contract.
-  exact/raw-tie-argmax          jnp.argmax/argmin in a shard_map or
-                                Pallas kernel module outside the blessed
+  exact/raw-tie-argmax          jnp.argmax/argmin in a shard_map
+                                module outside the blessed
                                 helpers — tie-broken selections must use
                                 gumbel_tiebreak_argmax /
                                 crossaxis_first_index_argmax (ties replay
@@ -42,10 +42,8 @@ _RAW_COLLECTIVES = {
 }
 
 # modules whose argmax sites feed cross-axis selections (the shard_map
-# auction and the Pallas megakernel): a raw argmax here is a tie-break
-# hazard, not a local utility
-_SELECTION_MODULES = ("kubetpu.parallel.shardmap",
-                     "kubetpu.ops.pallas_kernels")
+# auction): a raw argmax here is a tie-break hazard, not a local utility
+_SELECTION_MODULES = ("kubetpu.parallel.shardmap",)
 
 _ARGMAX = {"jax.numpy.argmax", "numpy.argmax", "jax.numpy.argmin",
            "numpy.argmin"}

@@ -18,20 +18,6 @@ Rules:
                         module-level name (aug-assign, .append/.update/
                         .add/.extend/[...]=) from inside a kernel-module
                         function — hidden state across traces.
-  purity/pallas-host-callback  a host callback (jax.pure_callback /
-                        jax.debug.callback / jax.debug.print /
-                        io_callback / host_callback.*) inside a Pallas
-                        KERNEL BODY — a kernel body executes on the
-                        core's compute units with no host round-trip;
-                        Mosaic either rejects the lowering or silently
-                        degrades to interpret-only code.  Use
-                        pl.debug_print inside kernels.  Kernel bodies
-                        are detected as (a) the function passed to
-                        pl.pallas_call (plus functions nested inside
-                        it), and (b) any function taking >= 2
-                        ``*_ref``-suffixed parameters (the pallas Ref
-                        naming convention) in a module that imports
-                        pallas.
 """
 
 from __future__ import annotations
@@ -43,53 +29,6 @@ from .core import Finding, SourceModule
 
 _MUTATORS = {"append", "extend", "add", "update", "insert", "setdefault",
              "pop", "remove", "clear", "__setitem__"}
-
-_HOST_CALLBACKS = {
-    "jax.pure_callback", "jax.debug.callback", "jax.debug.print",
-    "jax.experimental.io_callback", "io_callback",
-    "jax.experimental.host_callback.call", "host_callback.call",
-    "jax.experimental.host_callback.id_tap", "host_callback.id_tap",
-}
-
-
-def _imports_pallas(mi) -> bool:
-    if any("pallas" in (dotted or "")
-           for dotted in mi.import_aliases.values()):
-        return True
-    return any("pallas" in (base or "") or "pallas" in (orig or "")
-               for base, orig in mi.from_imports.values())
-
-
-def _kernel_bodies(cg, mi, module: SourceModule):
-    """FunctionDefs that are pallas kernel bodies: passed (by name) as the
-    first argument to a pallas_call in this module, nested inside one of
-    those, or following the ``*_ref`` parameter naming convention."""
-    bodies = []
-    by_name = {}
-    for node in ast.walk(module.tree):
-        if isinstance(node, ast.FunctionDef):
-            by_name.setdefault(node.name, node)
-    for node in ast.walk(module.tree):
-        if (isinstance(node, ast.Call) and node.args
-                and (cg.resolve_dotted(mi, node.func) or ""
-                     ).split(".")[-1] == "pallas_call"):
-            first = node.args[0]
-            if isinstance(first, ast.Name) and first.id in by_name:
-                bodies.append(by_name[first.id])
-    if _imports_pallas(mi):
-        for node in ast.walk(module.tree):
-            if isinstance(node, ast.FunctionDef):
-                params = [a.arg for a in node.args.posonlyargs
-                          + node.args.args]
-                if sum(1 for p in params if p.endswith("_ref")) >= 2:
-                    bodies.append(node)
-    # nested defs inside a kernel body are part of it (pl.when closures)
-    seen = []
-    for b in bodies:
-        if all(b is not s for s in seen):
-            seen.append(b)
-    return seen
-
 
 def _env_access(cg, mi, node: ast.AST) -> bool:
     if isinstance(node, ast.Attribute):
@@ -166,22 +105,6 @@ def check(module: SourceModule, ctx) -> List[Finding]:
                         node.col_offset + 1,
                         "module-level container `%s` written by subscript "
                         "inside a kernel-module function" % t.value.id))
-    # ---- host callbacks inside pallas kernel bodies --------------------
-    for body in _kernel_bodies(cg, mi, module):
-        for node in ast.walk(body):
-            if not isinstance(node, ast.Call):
-                continue
-            dotted = cg.resolve_dotted(mi, node.func) or ""
-            if (dotted in _HOST_CALLBACKS
-                    or dotted.split(".", 1)[-1] in _HOST_CALLBACKS):
-                out.append(Finding(
-                    "purity/pallas-host-callback", module.path,
-                    node.lineno, node.col_offset + 1,
-                    "host callback `%s` inside pallas kernel body `%s` — "
-                    "kernel bodies run on-core with no host round trip; "
-                    "use pl.debug_print, or hoist the callback out of "
-                    "the kernel" % (dotted, body.name)))
-
     # deduplicate env-access findings that landed twice on one site
     seen = set()
     deduped = []

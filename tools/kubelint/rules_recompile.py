@@ -35,14 +35,6 @@ Rules:
                               — a shape-dependent Python branch whose
                               bound is itself dynamic splits the compile
                               cache unboundedly.
-  recompile/pallas-dynamic-grid  a pl.pallas_call grid / BlockSpec block
-                              dimension fed by len(...) (a host container
-                              size — unbucketed, recompiles per call) or
-                              by FLOOR division of a shape-derived value
-                              (silently drops the remainder tail AND
-                              recompiles per size).  Derive grid dims
-                              from pow2-bucketed aval shapes with ceil
-                              division (pl.cdiv / the -(-a // b) idiom).
 """
 
 from __future__ import annotations
@@ -154,28 +146,6 @@ def check(module: SourceModule, ctx) -> List[Finding]:
                     "hoist to a decorator or module level"
                     % (" on a fresh lambda" if fresh_lambda else "")))
 
-        # ---- pallas grid/block dimension hygiene -----------------------
-        if dotted and dotted.split(".")[-1] == "pallas_call":
-            enc_fn = module.enclosing_function(node)
-            enc_fi = (cg.info_for(module, enc_fn)
-                      if enc_fn is not None else None)
-            grids = []
-            for kw in node.keywords:
-                if kw.arg == "grid":
-                    grids.append(kw.value)
-                elif kw.arg == "grid_spec" and isinstance(kw.value, ast.Call):
-                    grids += [kw2.value for kw2 in kw.value.keywords
-                              if kw2.arg == "grid"]
-            for g in grids:
-                _pallas_dim_findings(ctx, cg, mi, module, enc_fi, g,
-                                     "grid", out)
-            for sub in ast.walk(node):
-                if (isinstance(sub, ast.Call) and sub.args
-                        and (cg.resolve_dotted(mi, sub.func) or ""
-                             ).split(".")[-1] == "BlockSpec"):
-                    _pallas_dim_findings(ctx, cg, mi, module, enc_fi,
-                                         sub.args[0], "block", out)
-
         # ---- static-arg hygiene at call sites --------------------------
         callee = None
         enc = module.enclosing_function(node)
@@ -283,74 +253,6 @@ def check(module: SourceModule, ctx) -> List[Finding]:
                     "bucket the bound (pow2_bucket) or lift the branch out "
                     "of the trace" % fi.name))
     return out
-
-
-def _resolve_dim_exprs(ctx, mi, fi, expr: ast.AST):
-    """Interprocedural replacement for the old one-level local-name
-    lookup: a bare-name grid/block dimension resolves to EVERY defining
-    expression the provenance engine can reach (assignments in the scope
-    chain, parameter bindings at call sites, module constants) — so
-    `grid=grid` still gets inspected, and so does a dim laundered
-    through a helper parameter two frames up."""
-    if not isinstance(expr, ast.Name):
-        return [expr]
-    resolved = [e for _dmi, _dfi, e in _engine(ctx).resolve_name_exprs(
-        mi, fi, expr.id)]
-    return resolved or [expr]
-
-
-def _pallas_dim_findings(ctx, cg, mi, module: SourceModule, fi,
-                         expr: ast.AST, what: str,
-                         out: List[Finding]) -> None:
-    """Flag unbucketed-dynamic pallas grid/block dimensions: len(...) of a
-    host container, or floor division of a shape-derived value outside
-    the ceil-division idiom.  pow2_bucket(...)/cdiv(...) subtrees are
-    blessed.  Plain .shape reads pass — aval shapes are already bucketed
-    upstream by the tensorizer's pow2 contract."""
-    exprs = _resolve_dim_exprs(ctx, mi, fi, expr)
-    comps = []
-    for e in exprs:
-        comps += list(e.elts) if isinstance(e, ast.Tuple) else [e]
-    resolved_comps = []
-    for comp in comps:
-        resolved_comps += _resolve_dim_exprs(ctx, mi, fi, comp)
-    for c in resolved_comps:
-        blessed = set()
-        for nd in ast.walk(c):
-            if isinstance(nd, ast.Call):
-                last = (cg.resolve_dotted(mi, nd.func) or "").split(".")[-1]
-                if last in ("pow2_bucket", "cdiv"):
-                    for sub in ast.walk(nd):
-                        blessed.add(id(sub))
-        for nd in ast.walk(c):
-            if id(nd) in blessed:
-                continue
-            if (isinstance(nd, ast.Call)
-                    and cg.resolve_dotted(mi, nd.func) == "len"):
-                out.append(Finding(
-                    "recompile/pallas-dynamic-grid", module.path,
-                    nd.lineno, nd.col_offset + 1,
-                    "len(...) feeds a pallas %s dimension — a host "
-                    "container size is unbucketed, so every new size "
-                    "compiles a fresh Mosaic kernel; derive the dim from "
-                    "a pow2-bucketed aval shape" % what))
-            if isinstance(nd, ast.BinOp) and isinstance(nd.op,
-                                                        ast.FloorDiv):
-                if not _contains_shape_or_len(cg, mi, nd):
-                    continue
-                par = module.parent(nd)
-                if (isinstance(nd.left, ast.UnaryOp)
-                        and isinstance(nd.left.op, ast.USub)
-                        and isinstance(par, ast.UnaryOp)
-                        and isinstance(par.op, ast.USub)):
-                    continue  # -(-a // b): the ceil-division idiom
-                out.append(Finding(
-                    "recompile/pallas-dynamic-grid", module.path,
-                    nd.lineno, nd.col_offset + 1,
-                    "floor division on a shape-derived pallas %s "
-                    "dimension silently drops the remainder tile AND "
-                    "recompiles per size — use ceil division (pl.cdiv "
-                    "or -(-a // b)) over a pow2-bucketed dim" % what))
 
 
 class _ModuleScope:

@@ -6,8 +6,8 @@ This tool re-executes any journaled window through the SAME device
 programs (models/gang.run_auction / models/sequential
 .schedule_sequential) and **bit-matches** the replayed packed placement
 vector against the recorded one — the same oracle discipline as the
-Pallas and AOT gates: a divergence is a correctness failure, attributed
-to the FIRST divergent cycle with a per-pod decision diff.
+AOT gate: a divergence is a correctness failure, attributed to the
+FIRST divergent cycle with a per-pod decision diff.
 
 Replay reconstructs the scheduler's two device lineages exactly as the
 serving loop maintained them:
@@ -28,7 +28,7 @@ lineage: every subsequent non-anchor record skips with
 window degrades, it never aborts.
 
 ``--counterfactual`` re-runs the window under a modified profile (score
-weights, ``kernelBackend``, ``pipelineDepth``) and reports per-cycle
+weights, ``pipelineDepth``) and reports per-cycle
 placement divergence plus utilization/spread deltas — every recorded
 production trace becomes an eval set (ROADMAP item 3's learned-scorer
 substrate).  Counterfactual placements PROPAGATE through the chain
@@ -68,14 +68,13 @@ def _load_payload(rec: Dict[str, Any]):
 
 def _apply_counterfactual(rec: Dict[str, Any],
                           counterfactual: Optional[Dict[str, Any]]):
-    """(cfg, kernel_backend) for this record's dispatch, with any
-    counterfactual profile overrides applied.  ``pipeline_depth`` is
-    accepted and deliberately ignored at dispatch — the executor depth
-    never reaches a device program (the zero-divergence contract)."""
+    """cfg for this record's dispatch, with any counterfactual profile
+    overrides applied.  ``pipeline_depth`` is accepted and deliberately
+    ignored at dispatch — the executor depth never reaches a device
+    program (the zero-divergence contract)."""
     cfg = rec["cfg"]
-    backend = rec["kernel_backend"]
     if not counterfactual:
-        return cfg, backend
+        return cfg
     weights = counterfactual.get("score_weights")
     if weights:
         unknown = set(weights) - {name for name, _w in cfg.scores}
@@ -86,17 +85,21 @@ def _apply_counterfactual(rec: Dict[str, Any],
                 % (sorted(unknown), [n for n, _ in cfg.scores]))
         cfg = cfg._replace(scores=tuple(
             (name, int(weights.get(name, w))) for name, w in cfg.scores))
-    if counterfactual.get("kernel_backend"):
-        backend = counterfactual["kernel_backend"]
-    return cfg, backend
+    return cfg
 
 
-def _dispatch(rec: Dict[str, Any], cluster, cfg, kernel_backend):
+def _dispatch(rec: Dict[str, Any], cluster, cfg):
     """Re-execute one journaled cycle's device program; returns the
     result object (``.packed`` is the oracle surface)."""
     import jax
     import jax.numpy as jnp
 
+    # records written before the auction had one kernel path carry the
+    # backend that ran them; only the surviving one can be re-executed
+    if rec.get("kernel_backend", "lax") != "lax":
+        raise ReplayError(
+            f"record {rec['seq']} was written by the removed Pallas "
+            "kernel backend: the program that produced it is gone")
     batch = rec["batch"]
     rng = jax.random.PRNGKey(int(rec["rng_counter"]))
     host_ok = rec.get("host_ok")
@@ -107,7 +110,7 @@ def _dispatch(rec: Dict[str, Any], cluster, cfg, kernel_backend):
         from kubetpu.models.gang import run_auction
         return run_auction(cluster, batch, cfg, rng, host_ok=host_ok,
                            intra_batch_topology=bool(rec["needs_topo"]),
-                           score_bias=bias, kernel_backend=kernel_backend)
+                           score_bias=bias)
     from kubetpu.models.sequential import schedule_sequential
     return schedule_sequential(
         cluster, batch, cfg, rng,
@@ -360,8 +363,8 @@ def replay_journal(directory: str,
                     continue
                 cluster = _materialize_chain(rec, line.prev[1],
                                              line.prev[2], line.prev[3])
-            cfg, backend = _apply_counterfactual(rec, cf_overrides)
-            res = _dispatch(rec, cluster, cfg, backend)
+            cfg = _apply_counterfactual(rec, cf_overrides)
+            res = _dispatch(rec, cluster, cfg)
             packed = np.asarray(res.packed)
         except ReplayError as e:
             skip(seq, str(e), reported)

@@ -3,7 +3,6 @@
   python -m tools.kubereplay <journal-dir>                  bit-match oracle
   python -m tools.kubereplay <dir> --window 10:60           seq window
   python -m tools.kubereplay <dir> --counterfactual scoreWeight:NodeResourcesBalancedAllocation=5
-  python -m tools.kubereplay <dir> --counterfactual kernelBackend=pallas
   python -m tools.kubereplay <dir> --counterfactual pipelineDepth=4
   ... --json                                                machine-readable
 
@@ -21,8 +20,8 @@ from . import replay_journal
 
 
 def parse_counterfactual(clauses):
-    """scoreWeight:<Plugin>=<int> | kernelBackend=<lax|pallas> |
-    pipelineDepth=<int> -> the replay_journal counterfactual dict."""
+    """scoreWeight:<Plugin>=<int> | pipelineDepth=<int> -> the
+    replay_journal counterfactual dict."""
     if not clauses:
         return None
     out = {"score_weights": {}}
@@ -32,17 +31,12 @@ def parse_counterfactual(clauses):
             raise SystemExit(f"--counterfactual {raw!r}: want key=value")
         if key.startswith("scoreWeight:"):
             out["score_weights"][key[len("scoreWeight:"):]] = int(val)
-        elif key == "kernelBackend":
-            if val not in ("lax", "pallas"):
-                raise SystemExit("--counterfactual kernelBackend must be "
-                                 "lax or pallas")
-            out["kernel_backend"] = val
         elif key == "pipelineDepth":
             out["pipeline_depth"] = int(val)
         else:
             raise SystemExit(f"--counterfactual {raw!r}: unknown key "
                              f"{key!r} (scoreWeight:<Plugin>, "
-                             "kernelBackend, pipelineDepth)")
+                             "pipelineDepth)")
     if not out["score_weights"]:
         out.pop("score_weights")
     return out
@@ -68,8 +62,7 @@ def main(argv=None) -> int:
     ap.add_argument("--counterfactual", action="append", default=[],
                     metavar="K=V",
                     help="re-run under a modified profile; repeatable "
-                         "(scoreWeight:<Plugin>=N, kernelBackend=lax|"
-                         "pallas, pipelineDepth=N)")
+                         "(scoreWeight:<Plugin>=N, pipelineDepth=N)")
     ap.add_argument("--keep-going", action="store_true",
                     help="keep replaying past a bit-match divergence "
                          "(bounded; default stops at the first)")

@@ -148,10 +148,8 @@ def slo_summary(doc) -> str:
 
 def auction_summary(doc) -> str:
     """One-line auction digest under the stage table: the per-cycle round
-    HISTOGRAM (rounds -> cycles) plus the kernel-backend split, read from
-    cycle meta (Scheduler records auction_rounds/kernel_backend on every
-    gang cycle).  Makes the round-count reduction ROADMAP item 3 claims
-    directly visible in `make trace` output."""
+    HISTOGRAM (rounds -> cycles), read from cycle meta (Scheduler
+    records auction_rounds on every gang cycle)."""
     metas = []
     if isinstance(doc.get("cycle_meta"), list):        # pipeline doc
         metas = [c.get("meta", {}) for c in doc["cycle_meta"]]
@@ -164,15 +162,8 @@ def auction_summary(doc) -> str:
     hist: Dict[int, int] = {}
     for r in rounds:
         hist[r] = hist.get(r, 0) + 1
-    backends: Dict[str, int] = {}
-    for m in metas:
-        kb = m.get("kernel_backend")
-        if kb:
-            backends[kb] = backends.get(kb, 0) + 1
     h = " ".join(f"{r}r:{n}" for r, n in sorted(hist.items()))
-    b = " ".join(f"{k}:{n}" for k, n in sorted(backends.items()))
-    return (f"auction rounds: {h} (max {max(rounds)}"
-            + (f"; backend {b}" if b else "") + ")")
+    return f"auction rounds: {h} (max {max(rounds)})"
 
 
 def journal_summary(doc) -> str:
