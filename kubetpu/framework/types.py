@@ -242,7 +242,7 @@ class NodeInfo:
 
     __slots__ = ("node", "pods", "pods_with_affinity", "pods_with_required_anti_affinity",
                  "used_ports", "requested", "non_zero_requested", "allocatable",
-                 "image_states", "generation")
+                 "image_states", "generation", "node_generation")
 
     def __init__(self, node: Optional[api.Node] = None):
         self.node: Optional[api.Node] = None
@@ -257,6 +257,15 @@ class NodeInfo:
         self.allocatable = Resource()
         self.image_states: Dict[str, int] = {}  # image name -> size bytes
         self.generation = next_generation()
+        # the generation at which ``node`` (and what set_node derives from
+        # it: allocatable, image_states) was last written: a pod's coming
+        # or going moves ``generation`` and leaves this alone, so a reader
+        # that kept what it derived from the Node (state/delta.py's
+        # mirror rows) knows when to derive it again.  Drawn from the
+        # process-wide counter, not counted per NodeInfo: a node deleted
+        # and added again under its name between two snapshots is a new
+        # NodeInfo, and must not read like the old one
+        self.node_generation = 0
         if node is not None:
             self.set_node(node)
 
@@ -272,7 +281,7 @@ class NodeInfo:
         for img in node.status.images:
             for name in img.names:
                 self.image_states[name] = img.size_bytes
-        self.generation = next_generation()
+        self.generation = self.node_generation = next_generation()
 
     def add_pod(self, pod: api.Pod, pinfo: Optional[PodInfo] = None) -> None:
         # reference: types.go:456 (AddPod).  pinfo: optional pre-parsed
@@ -331,4 +340,5 @@ class NodeInfo:
         ni.allocatable = self.allocatable.clone()
         ni.image_states = dict(self.image_states)
         ni.generation = self.generation
+        ni.node_generation = self.node_generation
         return ni

@@ -261,7 +261,8 @@ class SchedulerCache:
             if item is None:
                 raise ValueError(f"node {node.name} is not found")
             item.info.node = None
-            item.info.generation = next_generation()
+            item.info.generation = item.info.node_generation = (
+                next_generation())
             if not item.info.pods:
                 self._remove_from_list(item)
                 del self.nodes[node.name]

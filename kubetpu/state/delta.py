@@ -48,6 +48,16 @@ They rebuild only when a dirty node's ordered OWNERS changed
 (``node_owners``): plain pods coming and going beside long-lived owners
 keep the resident tables, buffers and all.
 
+A dirty node is one whose ``generation`` moved, which a pod bound to it
+or deleted from it does.  Its mirror rows are refilled only where what
+they are filled FROM changed: the node's label / taint / image rows when
+its Node object was set again (``NodeInfo.node_generation``), a pod's row
+when the PodInfo on the node is not the object the row was filled from
+(``pod_src``); what a pod's coming or going does move — requested, the
+pod count, host ports — is rewritten for every dirty node.  The
+``ClusterDelta`` still carries every row of every dirty node, so the
+scatter's row counts and buckets do not depend on what was skipped.
+
 Bit-exactness contract (tested by tests/test_delta.py): after any
 sequence of deltas, the resident tensors match a from-scratch ``build()``
 of the same NodeInfos against the same InternTable byte-for-byte, up to
@@ -73,8 +83,9 @@ from ..utils import journal as ujournal
 from ..utils.intern import pow2_bucket
 from ..utils.trace import wallclock
 from .tensors import (ClusterDelta, HostClusterArrays, SnapshotBuilder,
-                      clear_pod_row, fill_node_row, fill_pod_row,
-                      gather_delta, pod_has_terms, vocab_signature)
+                      clear_pod_row, fill_node_static, fill_node_usage,
+                      fill_pod_row, gather_delta, pod_has_terms,
+                      vocab_signature)
 
 RESYNC_INTERVAL_ENV = "KUBETPU_RESYNC_INTERVAL"
 MAX_FRAC_ENV = "KUBETPU_DELTA_MAX_FRAC"
@@ -208,6 +219,18 @@ class DeltaTensorizer:
         # ``owners_changed`` compare, and what a rebuild compiles
         self.node_owners: Dict[str, Tuple[TermOwner, ...]] = {}
         self.pod_row: Dict[str, int] = {}        # uid -> row
+        # what each mirror row was last filled FROM, so that refresh()
+        # refills a row only when that changed.  name -> the
+        # ``NodeInfo.node_generation`` the node's static rows
+        # (``fill_node_static``) were filled at; uid -> (the PodInfo the
+        # pod's row was filled from, its node's row, its TermOwner or
+        # None).  Held by OBJECT, not by id(): a PodInfo that is still
+        # noted cannot have been collected and its address reused.
+        # Noted at every fill and re-noted whole by every _resync (which
+        # moves pod rows and intern ids)
+        self.node_src: Dict[str, int] = {}
+        self.pod_src: Dict[str, Tuple[object, int,
+                                      Optional[TermOwner]]] = {}
         self.free_rows: List[int] = []           # kept sorted, pop lowest
         self.next_pod_row = 0
         self.caps = None                         # vocab signature
@@ -351,7 +374,8 @@ class DeltaTensorizer:
         t0 = wallclock()
         if pending:
             self.builder.intern_pending(pending)
-        names = [ni.node_name for ni in node_infos]
+        names = [ni.node.metadata.name if ni.node is not None else ""
+                 for ni in node_infos]
         if self.cluster is None:
             return self._resync(node_infos, names, "initial", t0, pending)
         if names != self.node_names:
@@ -366,8 +390,9 @@ class DeltaTensorizer:
         if self.cycles_since_resync >= self.resync_interval:
             return self._resync(node_infos, names, "anti-entropy", t0,
                                 pending)
+        node_gen = self.node_gen
         dirty = [(i, ni) for i, ni in enumerate(node_infos)
-                 if ni.generation != self.node_gen.get(ni.node_name)]
+                 if ni.generation != node_gen.get(names[i])]
         if not dirty:
             self.cycles_since_resync += 1
             if ujournal.journal() is not None:
@@ -384,23 +409,26 @@ class DeltaTensorizer:
         if len(dirty) > self.max_delta_frac * max(len(names), 1):
             return self._resync(node_infos, names, "delta-too-large", t0,
                                 pending)
-        # intern BEFORE the width check so new strings from dirty nodes
-        # count against the caps the resident tensors were sized with
-        self.builder._intern_node_strings([ni for _, ni in dirty])
-        if self.signature() != self.caps:
-            return self._resync(node_infos, names, "vocab-growth", t0,
-                                pending)
+
+        # A mirror row is refilled only when what it is filled FROM
+        # changed.  The node's static rows are filled from the Node
+        # object: ``NodeInfo.node_generation`` moves when it is set
+        # again (an in-place edit re-set with update_node(n, n)
+        # included) and not when a pod comes or goes.  A pod's row is
+        # filled from its PodInfo and its node's row: every pod event
+        # puts a NEW PodInfo on the node (``SchedulerCache.update_pod``
+        # is a remove and an add) and the snapshot's ``clone()`` keeps
+        # the objects, so the PodInfo noted in ``pod_src`` still being
+        # the one on that node means the row is what a refill would
+        # write.  Strings are interned with the part that reads them, so
+        # the width checks below see exactly the strings that are new.
+        # Any _resync() from here on finds state half updated and
+        # re-derives ALL of it, markers included.
         a = self.host.arrays
-        MLn = a["_kv_ids"].shape[1]
-        MLp = a["_pod_kv_ids"].shape[1]
-        for _, ni in dirty:
-            if len(ni.node.metadata.labels) + 1 > MLn:
-                return self._resync(node_infos, names, "label-capacity",
-                                    t0, pending)
-            for pi in ni.pods:
-                if len(pi.pod.metadata.labels) > MLp:
-                    return self._resync(node_infos, names,
-                                        "label-capacity", t0, pending)
+        b = self.builder
+        pod_row, pod_src = self.pod_row, self.pod_src
+        node_pods, node_src = self.node_pods, self.node_src
+        node_owners = self.node_owners
 
         # ---- pod-row churn: free EVERY departed row across all dirty
         # nodes BEFORE scanning for additions — a same-uid pod moving
@@ -408,53 +436,30 @@ class DeltaTensorizer:
         # skipped by the add scan (stale mapping still present) and then
         # popped by the later free, leaving the refill with no row
         touched_pods: set = set()
-        adds: List[Tuple[object, int]] = []    # (PodInfo, node row)
-        for _, ni in dirty:
-            old = self.node_pods.get(ni.node_name, [])
-            new_set = {pi.pod.uid for pi in ni.pods}
-            for uid in old:
-                if uid not in new_set:
-                    row = self.pod_row.pop(uid)
-                    clear_pod_row(a, row)
-                    touched_pods.add(row)
-                    self.free_rows.append(row)
+        walk = []                    # (node row, NodeInfo, its pods' uids)
         for i, ni in dirty:
-            for pi in ni.pods:
-                if pi.pod.uid not in self.pod_row:
-                    adds.append((pi, i))
+            uids = [pi.pod.metadata.uid for pi in ni.pods]
+            walk.append((i, ni, uids))
+            old = node_pods.get(names[i], ())
+            if old != uids:
+                new_set = set(uids)
+                for uid in old:
+                    if uid not in new_set:
+                        row = pod_row.pop(uid)
+                        del pod_src[uid]
+                        clear_pod_row(a, row)
+                        touched_pods.add(row)
+                        self.free_rows.append(row)
         self.free_rows.sort()
-        PP = a["pod_node"].shape[0]
-        need = len(adds) - len(self.free_rows)
-        grown = False
-        if need > 0 and self.next_pod_row + need > PP:
-            self._grow_pod_axis(self.next_pod_row + need)
-            grown = True
-            PP = a["pod_node"].shape[0]
-        for pi, n_idx in adds:
-            row = (self.free_rows.pop(0) if self.free_rows
-                   else self.next_pod_row)
-            if row == self.next_pod_row:
-                self.next_pod_row += 1
-            self.pod_row[pi.pod.uid] = row
 
-        # ---- refill the mirror rows (node + every pod on a dirty node —
-        # covers in-place pod updates without per-pod generations)
-        t = self.builder.table
-        # a dirty node can have interned a NEW taint inside the cap: the
-        # [T] vocab-metadata rows for fresh ids must land too (build()
-        # fills them from the vocab; ids are append-only, so only the
-        # tail can be stale)
-        from ..api import types as api
-        for ti in range(len(t.taint)):
-            if not a["taint_is_hard"][ti] and not a["taint_is_prefer"][ti]:
-                _, _, effect = t.taint.key(ti)
-                a["taint_is_hard"][ti] = effect in (
-                    api.TAINT_EFFECT_NO_SCHEDULE,
-                    api.TAINT_EFFECT_NO_EXECUTE)
-                a["taint_is_prefer"][ti] = (
-                    effect == api.TAINT_EFFECT_PREFER_NO_SCHEDULE)
-        image_nodes = a["_image_nodes"]
-        node_rows = []
+        # ---- ONE walk of each dirty node's pods: rows for the pods that
+        # are new (lowest free row first, in walk order), the pods whose
+        # row must be (re)filled, the node's uid list and its term owners
+        MLn = a["_kv_ids"].shape[1]
+        MLp = a["_pod_kv_ids"].shape[1]
+        free, n_free, k_free = self.free_rows, len(self.free_rows), 0
+        reset_nodes: List[Tuple[int, object]] = []
+        fills: List[Tuple[str, int, object, int, object]] = []
         # term-carrying pod churn does NOT force a full resync: the
         # flattened ExistingTerms rebuild from the term OWNERS alone and
         # replace wholesale, and only when a dirty node's owners are not
@@ -465,47 +470,120 @@ class DeltaTensorizer:
         # holds the kept tables to a fresh build()
         owners_were: Dict[str, Tuple[TermOwner, ...]] = {}
         owner_seen = False
-        for i, ni in dirty:
-            old_imgs = set(np.nonzero(a["images"][i])[0].tolist())
-            fill_node_row(a, i, ni, t)
-            new_imgs = set(np.nonzero(a["images"][i])[0].tolist())
-            for ii in old_imgs - new_imgs:
-                image_nodes[ii] -= 1
-            for ii in new_imgs - old_imgs:
-                image_nodes[ii] += 1
-            for pi in ni.pods:
-                row = self.pod_row[pi.pod.uid]
-                fill_pod_row(a, row, pi, i, t)
+        pods_seen = 0
+        for i, ni, uids in walk:
+            name = names[i]
+            if ni.node_generation != node_src.get(name):
+                if len(ni.node.metadata.labels) + 1 > MLn:
+                    return self._resync(node_infos, names,
+                                        "label-capacity", t0, pending)
+                b.intern_node(ni)
+                reset_nodes.append((i, ni))
+            b.intern_node_usage(ni)
+            owners = []
+            for uid, pi in zip(uids, ni.pods):
+                row = pod_row.get(uid)
+                src = pod_src.get(uid)
+                if src is not None and src[0] is pi and src[1] == i:
+                    owner = src[2]
+                else:
+                    if len(pi.pod.metadata.labels) > MLp:
+                        return self._resync(node_infos, names,
+                                            "label-capacity", t0, pending)
+                    b.intern_pod(pi)
+                    if row is None:
+                        if k_free < n_free:
+                            row = free[k_free]
+                            k_free += 1
+                        else:
+                            row = self.next_pod_row
+                            self.next_pod_row += 1
+                        pod_row[uid] = row
+                    owner = self._owner(uid, row, pi)
+                    fills.append((uid, row, pi, i, owner))
                 touched_pods.add(row)
-            self.node_pods[ni.node_name] = [pi.pod.uid for pi in ni.pods]
-            owners = self._owners_of(ni)
-            was = self.node_owners[ni.node_name]
+                if owner is not None:
+                    owners.append(owner)
+            pods_seen += len(uids)
+            node_pods[name] = uids
+            owners = tuple(owners)
+            was = node_owners[name]
             if owners != was:
-                owners_were[ni.node_name] = was
-                self.node_owners[ni.node_name] = owners
+                owners_were[name] = was
+                node_owners[name] = owners
             elif owners:
                 owner_seen = True
-            self.node_gen[ni.node_name] = ni.generation
+        del free[:k_free]
+        # AFTER the interning, BEFORE any fill: new strings from what is
+        # about to be filled count against the caps the resident tensors
+        # were sized with
+        if self.signature() != self.caps:
+            return self._resync(node_infos, names, "vocab-growth", t0,
+                                pending)
+        grown = self.next_pod_row > a["pod_node"].shape[0]
+        if grown:
+            self._grow_pod_axis(self.next_pod_row)
+
+        # ---- refill the mirror rows whose source changed
+        t = b.table
+        if reset_nodes:
+            # a Node set again can have interned a NEW taint inside the
+            # cap: the [T] vocab-metadata rows for fresh ids must land
+            # too (build() fills them from the vocab; ids are
+            # append-only, so only the tail can be stale)
+            from ..api import types as api
+            for ti in range(len(t.taint)):
+                if (not a["taint_is_hard"][ti]
+                        and not a["taint_is_prefer"][ti]):
+                    _, _, effect = t.taint.key(ti)
+                    a["taint_is_hard"][ti] = effect in (
+                        api.TAINT_EFFECT_NO_SCHEDULE,
+                        api.TAINT_EFFECT_NO_EXECUTE)
+                    a["taint_is_prefer"][ti] = (
+                        effect == api.TAINT_EFFECT_PREFER_NO_SCHEDULE)
+            image_nodes = a["_image_nodes"]
+            for i, ni in reset_nodes:
+                old_imgs = set(np.nonzero(a["images"][i])[0].tolist())
+                fill_node_static(a, i, ni, t)
+                new_imgs = set(np.nonzero(a["images"][i])[0].tolist())
+                for ii in old_imgs - new_imgs:
+                    image_nodes[ii] -= 1
+                for ii in new_imgs - old_imgs:
+                    image_nodes[ii] += 1
+                node_src[names[i]] = ni.node_generation
+            # images that no node carries anymore read 0 in a fresh build
+            a["image_size"][image_nodes <= 0] = 0.0
+            a["image_spread"] = image_nodes / max(float(len(node_infos)),
+                                                  1.0)
+        for uid, row, pi, i, owner in fills:
+            fill_pod_row(a, row, pi, i, t)
+            pod_src[uid] = (pi, i, owner)
+        node_rows = []
+        for i, ni in dirty:
+            fill_node_usage(a, i, ni, t)
+            node_gen[names[i]] = ni.generation
             node_rows.append(i)
-        # images that no node carries anymore read 0 in a fresh build
-        a["image_size"][image_nodes <= 0] = 0.0
-        a["image_spread"] = image_nodes / max(float(len(node_infos)), 1.0)
 
         terms_dirty = bool(owners_were)
         term_span = ()
         span_args: Dict[str, Dict[str, int]] = {"delta-build": {
-            "terms_kept": int(owner_seen and not terms_dirty)}}
+            "terms_kept": int(owner_seen and not terms_dirty),
+            "node_rows_dirty": len(dirty),
+            "node_rows_refilled": len(reset_nodes),
+            "pod_rows_seen": pods_seen,
+            "pod_rows_refilled": len(fills)}}
         if terms_dirty:
             t_terms = wallclock()
             span_args["delta-terms"] = dict(
-                self._refresh_terms(owners_were),
-                pods_walked=sum(len(ni.pods) for _, ni in dirty))
+                self._refresh_terms(owners_were), pods_walked=pods_seen)
             term_span = (("delta-terms", t_terms, wallclock()),)
 
         pod_rows = sorted(touched_pods)
         if grown:
             # the pod axis changed shape: scatter can't grow a buffer, so
-            # re-upload the (already-updated) mirror — no build() walk
+            # re-upload the (already-updated) mirror — no build() walk.
+            # The markers stand as the fills above noted them: padding
+            # moves no row and this table's ids stay
             self.cycles_since_resync = 0
             self.resync_count += 1
             t_build = wallclock()
@@ -562,14 +640,30 @@ class DeltaTensorizer:
         a = host.arrays
         self.host = host
         self.node_names = list(names)
-        self.node_gen = {ni.node_name: ni.generation for ni in node_infos}
-        self.node_pods = {ni.node_name: [pi.pod.uid for pi in ni.pods]
-                          for ni in node_infos}
         self.pod_row = dict(a["_pod_rows"])
         self.next_pod_row = len(self.pod_row)
         self.free_rows = []
-        self.node_owners = {ni.node_name: self._owners_of(ni)
-                            for ni in node_infos}
+        # every marker is noted again, for every reason this is called
+        # with: the rebuild packed the pod rows anew (an owner carries
+        # its row) and, compacting, moved the intern ids
+        self.node_gen, self.node_src = {}, {}
+        self.node_pods, self.node_owners, self.pod_src = {}, {}, {}
+        for i, (name, ni) in enumerate(zip(names, node_infos)):
+            uids, owners = [], []
+            for pi in ni.pods:
+                uid = pi.pod.metadata.uid
+                uids.append(uid)
+                row = self.pod_row.get(uid)
+                if row is None:
+                    continue         # build() gives a node-less info no row
+                owner = self._owner(uid, row, pi)
+                self.pod_src[uid] = (pi, i, owner)
+                if owner is not None:
+                    owners.append(owner)
+            self.node_gen[name] = ni.generation
+            self.node_src[name] = ni.node_generation
+            self.node_pods[name] = uids
+            self.node_owners[name] = tuple(owners)
         self.caps = self.signature()
         self.cycles_since_resync = 0
         # a resync re-uploads the mirror wholesale, so device == mirror
@@ -619,18 +713,17 @@ class DeltaTensorizer:
             "delta-resident", self.profile or "default", self.cluster,
             len(self.node_names), meta={"resyncs": self.resync_count})
 
-    def _owners_of(self, ni) -> Tuple[TermOwner, ...]:
-        """The node's term owners in pod order: the pods that give
-        ``_build_terms`` a row (required affinity owns one only at a
-        nonzero hard weight — ``pod_has_terms``)."""
-        hw, row = self.hard_pod_affinity_weight, self.pod_row
-        return tuple([
-            TermOwner(pi.pod.uid, row[pi.pod.uid],
-                      pi.required_anti_affinity_terms,
-                      pi.preferred_affinity_terms,
-                      pi.preferred_anti_affinity_terms,
-                      pi.required_affinity_terms)
-            for pi in ni.pods if pod_has_terms(pi, hw)])
+    def _owner(self, uid: str, row: int, pi) -> Optional[TermOwner]:
+        """What the pod gives ``_build_terms``, or None for a pod that
+        gives it no row (required affinity owns one only at a nonzero
+        hard weight — ``pod_has_terms``).  A node's owners are these in
+        pod order."""
+        if not pod_has_terms(pi, self.hard_pod_affinity_weight):
+            return None
+        return TermOwner(uid, row, pi.required_anti_affinity_terms,
+                         pi.preferred_affinity_terms,
+                         pi.preferred_anti_affinity_terms,
+                         pi.required_affinity_terms)
 
     def _refresh_terms(self, owners_were) -> Dict[str, int]:
         """Term-only rebuild: recompile the flattened ExistingTerms from
@@ -656,8 +749,8 @@ class DeltaTensorizer:
         selector as kv / key ids (``SelectorCompiler.compile(
         intern_new=True)`` interns what it meets, so no id is ever "not
         yet known", and ids are append-only), its namespaces and topology
-        key as ids interned WITH the owner (``_intern_node_strings`` on
-        the owner's dirty node), ``pod_idx`` as the owner's delta row
+        key as ids interned WITH the owner (``intern_pod`` as its row
+        is filled), ``pod_idx`` as the owner's delta row
         (stable while the uid stays on its node; a move frees and
         re-assigns it, and is a changed owner on both nodes) and its
         weight (the term's own, or the tensorizer's constant hard

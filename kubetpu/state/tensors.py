@@ -192,42 +192,61 @@ class SnapshotBuilder:
 
     def _intern_node_strings(self, nodes: List[NodeInfo]) -> None:
         """First pass: make sure vocab contains everything in the cluster so
-        bucket caps are final before array allocation."""
-        t = self.table
+        bucket caps are final before array allocation.  The three parts are
+        the three things a mirror row is filled FROM (the fills below take
+        the same split), so the delta path interns a part only when it
+        fills from it: what it kept has its ids already."""
         for ni in nodes:
-            node = ni.node
-            if node is None:
+            if ni.node is None:
                 continue
-            for k, v in node.metadata.labels.items():
-                t.kv.intern((k, v)); t.key.intern(k)
-            t.kv.intern((FIELD_PREFIX + "metadata.name", node.name))
-            t.key.intern(FIELD_PREFIX + "metadata.name")
-            for taint in node.spec.taints:
-                t.taint.intern((taint.key, taint.value, taint.effect))
-            for name in ni.image_states:
-                t.image.intern(_norm_image(name))
-            for r in ni.allocatable.scalar_resources:
-                t.rname.intern(r)
-            zk = zone_key(node)
-            if zk:
-                t.zone.intern(zk)
-            for kind, uid in _avoid_entries(node):
-                t.avoid.intern((kind, uid))
-            for triple in ni.used_ports:
-                for pid in _port_ids_node(triple):
-                    t.port.intern(pid)
+            self.intern_node(ni)
+            self.intern_node_usage(ni)
             for pi in ni.pods:
-                p = pi.pod
-                t.ns.intern(p.namespace)
-                for k, v in p.metadata.labels.items():
-                    t.kv.intern((k, v)); t.key.intern(k)
-                for term in (pi.required_anti_affinity_terms
-                             + [w.term for w in pi.preferred_affinity_terms]
-                             + [w.term for w in pi.preferred_anti_affinity_terms]
-                             + pi.required_affinity_terms):
-                    t.topokey.intern(term.topology_key)
-                    for ns in term.namespaces:
-                        t.ns.intern(ns)
+                self.intern_pod(pi)
+
+    def intern_node(self, ni: NodeInfo) -> None:
+        """The strings ``fill_node_static`` reads: the Node object's, and
+        what ``NodeInfo.set_node`` derives from it."""
+        t = self.table
+        node = ni.node
+        for k, v in node.metadata.labels.items():
+            t.kv.intern((k, v)); t.key.intern(k)
+        t.kv.intern((FIELD_PREFIX + "metadata.name", node.name))
+        t.key.intern(FIELD_PREFIX + "metadata.name")
+        for taint in node.spec.taints:
+            t.taint.intern((taint.key, taint.value, taint.effect))
+        for name in ni.image_states:
+            t.image.intern(_norm_image(name))
+        for r in ni.allocatable.scalar_resources:
+            t.rname.intern(r)
+        zk = zone_key(node)
+        if zk:
+            t.zone.intern(zk)
+        for kind, uid in _avoid_entries(node):
+            t.avoid.intern((kind, uid))
+
+    def intern_node_usage(self, ni: NodeInfo) -> None:
+        """The strings ``fill_node_usage`` reads: the host ports in use."""
+        t = self.table
+        for triple in ni.used_ports:
+            for pid in _port_ids_node(triple):
+                t.port.intern(pid)
+
+    def intern_pod(self, pi: PodInfo) -> None:
+        """The strings ``fill_pod_row`` reads, and those an existing pod's
+        terms compile from (``_build_terms``)."""
+        t = self.table
+        p = pi.pod
+        t.ns.intern(p.namespace)
+        for k, v in p.metadata.labels.items():
+            t.kv.intern((k, v)); t.key.intern(k)
+        for term in (pi.required_anti_affinity_terms
+                     + [w.term for w in pi.preferred_affinity_terms]
+                     + [w.term for w in pi.preferred_anti_affinity_terms]
+                     + pi.required_affinity_terms):
+            t.topokey.intern(term.topology_key)
+            for ns in term.namespaces:
+                t.ns.intern(ns)
 
     def intern_pending(self, pods: List[PodInfo]) -> None:
         """Pre-intern the strings of *pending* pods so vocab capacities are
@@ -322,7 +341,8 @@ class SnapshotBuilder:
             node = ni.node
             if node is None:
                 continue
-            fill_node_row(d, n_idx, ni, t)
+            fill_node_static(d, n_idx, ni, t)
+            fill_node_usage(d, n_idx, ni, t)
             for ii in np.nonzero(d["images"][n_idx])[0]:
                 image_nodes[ii] += 1
 
@@ -401,10 +421,18 @@ class SnapshotBuilder:
 # so delta-maintained tensors never drift from a rebuild.
 
 
-def fill_node_row(d: dict, n_idx: int, ni: NodeInfo, t: InternTable) -> None:
-    """(Re)fill every node-axis array row for one NodeInfo.  Clears the row
-    first so refilling a previously-populated row (the delta path) leaves
-    no stale label/taint/port bits behind."""
+def fill_node_static(d: dict, n_idx: int, ni: NodeInfo,
+                     t: InternTable) -> None:
+    """The node-axis rows that are a function of the Node object and of
+    what ``NodeInfo.set_node`` derives from it (``allocatable``,
+    ``image_states``): nothing a pod's coming or going moves, so the
+    delta path refills them only for a node whose ``node_generation``
+    moved.  ``topo_pair`` also reads the topology-key LIST, which no
+    marker covers and none has to: ``vocab_signature`` carries the
+    list's length, so a key interned since the row was filled is a
+    resync before any row is read.  Clears each row first so refilling a
+    previously-populated one leaves no stale label/taint/image bits
+    behind."""
     node = ni.node
     R = d["allocatable"].shape[1]
     d["node_valid"][n_idx] = True
@@ -414,16 +442,10 @@ def fill_node_row(d: dict, n_idx: int, ni: NodeInfo, t: InternTable) -> None:
     d["num"][n_idx] = np.inf
     d["topo_pair"][n_idx] = -1
     d["taints"][n_idx] = False
-    d["ports"][n_idx] = False
     d["images"][n_idx] = False
     d["avoid_hot"][n_idx] = False
     d["zone_hot"][n_idx] = 0.0
     d["allocatable"][n_idx] = resource_to_channels(ni.allocatable, t, R)
-    req = resource_to_channels(ni.requested, t, R)
-    req[CH_PODS] = len(ni.pods)
-    d["requested"][n_idx] = req
-    d["nonzero_requested"][n_idx, 0] = ni.non_zero_requested.milli_cpu
-    d["nonzero_requested"][n_idx, 1] = ni.non_zero_requested.memory / MIB
     labels = dict(node.metadata.labels)
     labels[FIELD_PREFIX + "metadata.name"] = node.name
     for li, (k, v) in enumerate(labels.items()):
@@ -441,9 +463,6 @@ def fill_node_row(d: dict, n_idx: int, ni: NodeInfo, t: InternTable) -> None:
     for taint in node.spec.taints:
         d["taints"][n_idx, t.taint.get((taint.key, taint.value,
                                         taint.effect))] = True
-    for triple in ni.used_ports:
-        for pid in _port_ids_node(triple):
-            d["ports"][n_idx, t.port.get(pid)] = True
     for name, size in ni.image_states.items():
         ii = t.image.get(_norm_image(name))
         d["images"][n_idx, ii] = True
@@ -453,6 +472,21 @@ def fill_node_row(d: dict, n_idx: int, ni: NodeInfo, t: InternTable) -> None:
     zk = zone_key(node)
     if zk:
         d["zone_hot"][n_idx, t.zone.get(zk)] = 1.0
+
+
+def fill_node_usage(d: dict, n_idx: int, ni: NodeInfo,
+                    t: InternTable) -> None:
+    """The node-axis rows a pod's coming or going moves: what the node's
+    pods request (with their count) and the host ports they hold."""
+    req = resource_to_channels(ni.requested, t, d["requested"].shape[1])
+    req[CH_PODS] = len(ni.pods)
+    d["requested"][n_idx] = req
+    d["nonzero_requested"][n_idx, 0] = ni.non_zero_requested.milli_cpu
+    d["nonzero_requested"][n_idx, 1] = ni.non_zero_requested.memory / MIB
+    d["ports"][n_idx] = False
+    for triple in ni.used_ports:
+        for pid in _port_ids_node(triple):
+            d["ports"][n_idx, t.port.get(pid)] = True
 
 
 def fill_pod_row(d: dict, row: int, pi: PodInfo, n_idx: int,

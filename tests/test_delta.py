@@ -456,6 +456,307 @@ def test_term_tables_kept_or_rebuilt_stay_golden(steps):
         w.refresh(expect)
 
 
+# ---------------------------------------------------------------------------
+# a mirror row is refilled only when what it is filled FROM changed: the
+# golden over every way a row's source can change beside rows that are kept
+
+
+def refilled(st):
+    """(node rows, pod rows) a delta build rewrote from their objects."""
+    b = st.span_args["delta-build"]
+    return b["node_rows_refilled"], b["pod_rows_refilled"]
+
+
+def _set_again(w, i, edit):
+    """Edit node i's Node object IN PLACE and hand the same object back,
+    as an informer that reuses its objects does: no new identity to see,
+    only ``NodeInfo.set_node`` having run."""
+    n = w.nodes[i]
+    edit(n)
+    w.cache.update_node(n, n)
+    w.add(plain_pod(w.fresh_name("arrives"), n.name))
+
+
+def _node_relabelled_in_place_while_a_pod_arrives(w):
+    _set_again(w, 2, lambda n: n.metadata.labels.__setitem__(
+        "rack", "rack-1" if n.metadata.labels["rack"] == "rack-0"
+        else "rack-0"))
+    yield 1, 1
+
+
+def _node_tainted_in_place_while_a_pod_arrives(w):
+    _set_again(w, 1, lambda n: n.spec.taints.append(api.Taint(
+        key="dedicated", value="batch",
+        effect=api.TAINT_EFFECT_NO_SCHEDULE)))
+    yield 1, 1
+    _set_again(w, 1, lambda n: n.spec.taints.clear())
+    yield 1, 1
+
+
+def _node_cordoned_in_place_while_a_pod_arrives(w):
+    _set_again(w, 3, lambda n: setattr(n.spec, "unschedulable", True))
+    yield 1, 1
+    # and beside it a node that is dirty for a pod's sake alone keeps
+    # its static rows
+    w.add(plain_pod("elsewhere", w.nodes[4].name))
+    yield 0, 1
+
+
+def _node_images_change(w):
+    def pulls(*images):
+        return lambda n: setattr(n.status, "images", [
+            api.ContainerImage(names=[name], size_bytes=size)
+            for name, size in images])
+    _set_again(w, 0, pulls(("registry/a:1", 10 << 20),
+                           ("registry/b:1", 20 << 20)))
+    yield 1, 1
+    _set_again(w, 5, pulls(("registry/a:1", 10 << 20)))
+    yield 1, 1
+    # node 0 drops b (no node carries it any more: its size reads 0 in a
+    # fresh build) and pulls c
+    _set_again(w, 0, pulls(("registry/a:1", 10 << 20),
+                           ("registry/c:1", 30 << 20)))
+    yield 1, 1
+    w.add(plain_pod("elsewhere", w.nodes[0].name))
+    yield 0, 1
+
+
+def _resident_relabelled_beside_an_arrival(w):
+    old = next(p for p in w.plains
+               if p.spec.node_name == w.nodes[2].name)
+    new = copy.deepcopy(old)
+    new.metadata.labels["tier"] = (
+        "a" if old.metadata.labels["tier"] == "b" else "b")
+    w.update(old, new)
+    w.add(plain_pod("arrives", w.nodes[2].name))
+    yield 0, 2
+
+
+def _resident_terminating_beside_an_arrival(w):
+    old = next(p for p in w.plains
+               if p.spec.node_name == w.nodes[3].name)
+    new = copy.deepcopy(old)
+    new.metadata.deletion_timestamp = 1234.5
+    w.update(old, new)
+    w.add(plain_pod("arrives", w.nodes[3].name))
+    yield 0, 2
+    back = copy.deepcopy(new)
+    back.metadata.deletion_timestamp = None
+    w.update(new, back)
+    yield 0, 1
+
+
+def _resident_untouched_over_twenty_cycles_of_churn(w):
+    node = w.nodes[1].name
+    residents = {p.uid for p in w.owners + w.plains
+                 if p.spec.node_name == node}
+    noted = {uid: w.dt.pod_src[uid] for uid in residents}
+    last = None
+    for k in range(20):
+        if last is not None:
+            w.remove(last)
+        last = w.add(plain_pod(f"churn-{k}", node, tier="ab"[k % 2]))
+        yield 0, 1
+        assert all(w.dt.pod_src[uid] is noted[uid] for uid in residents)
+
+
+ROW_SOURCES = [
+    _node_relabelled_in_place_while_a_pod_arrives,
+    _node_tainted_in_place_while_a_pod_arrives,
+    _node_cordoned_in_place_while_a_pod_arrives, _node_images_change,
+    _resident_relabelled_beside_an_arrival,
+    _resident_terminating_beside_an_arrival,
+    _resident_untouched_over_twenty_cycles_of_churn]
+NODE_SOURCES, POD_SOURCES = ROW_SOURCES[:4], ROW_SOURCES[4:6]
+
+
+def _ids(fs):
+    return [f.__name__.strip("_") for f in fs]
+
+
+def run_row_sources(steps):
+    w = OwnerWorld()
+    for expect in steps(w):
+        st, _ = w.refresh()                # held to a fresh build()
+        # (the pod axis may grow under the arrivals: no build() walk)
+        assert st.reason in ("", "pod-axis-growth"), st.reason
+        assert refilled(st) == expect
+
+
+@pytest.mark.parametrize("steps", ROW_SOURCES, ids=_ids(ROW_SOURCES))
+def test_a_mirror_row_is_refilled_when_its_source_changed(steps):
+    """Each refresh held to a fresh build() AND to how many rows it
+    rewrote: the rows whose Node / PodInfo changed, and no other."""
+    run_row_sources(steps)
+
+
+@pytest.mark.parametrize("steps", NODE_SOURCES, ids=_ids(NODE_SOURCES))
+def test_the_golden_fails_when_the_node_marker_is_ignored(steps,
+                                                          monkeypatch):
+    """The mutation: a Node set again leaves ``node_generation`` where
+    it was (what object identity alone would see of an in-place edit
+    handed back with update_node(n, n)).  The golden must not pass."""
+    from kubetpu.framework.types import NodeInfo
+    orig = NodeInfo.set_node
+
+    def set_node(self, node):
+        first = self.node_generation
+        orig(self, node)
+        self.node_generation = first or self.node_generation
+    monkeypatch.setattr(NodeInfo, "set_node", set_node)
+    with pytest.raises(AssertionError):
+        run_row_sources(steps)
+
+
+@pytest.mark.parametrize("steps", POD_SOURCES, ids=_ids(POD_SOURCES))
+def test_the_golden_fails_when_the_pod_marker_cannot_see_the_change(
+        steps, monkeypatch):
+    """The mutation: a pod updated under its uid keeps its PodInfo
+    OBJECT, rewritten in place (which no writer does: update_pod is a
+    remove and an add, and add_pod makes a new one).  The marker is that
+    object, so the row is kept and the golden must not pass."""
+    from kubetpu.framework.types import NodeInfo, PodInfo
+    orig, seen = NodeInfo.add_pod, {}
+
+    def add_pod(self, pod, pinfo=None):
+        fresh = PodInfo(pod)
+        pi = seen.setdefault(pod.uid, fresh)
+        for slot in PodInfo.__slots__:
+            setattr(pi, slot, getattr(fresh, slot))
+        orig(self, pod, pi)
+    monkeypatch.setattr(NodeInfo, "add_pod", add_pod)
+    with pytest.raises(AssertionError):
+        run_row_sources(steps)
+
+
+def _scrambled(w):
+    """Rows out of walk order and a freed row, so that a rebuild MOVES
+    rows (it packs them in node-walk order)."""
+    w.remove(next(p for p in w.plains
+                  if p.spec.node_name == w.nodes[0].name))
+    w.add(plain_pod("scramble-a", w.nodes[4].name))
+    w.add(plain_pod("scramble-b", w.nodes[2].name))
+    w.remove(next(p for p in w.plains
+                  if p.spec.node_name == w.nodes[1].name))
+    w.refresh("kept")
+
+
+def _by_vocab_growth(w):
+    kv_cap, k = w.dt.builder.table.kv.cap, 0
+    while w.dt.builder.table.kv.cap == kv_cap:
+        k += 1
+        p = plain_pod(f"grow-{k}", w.nodes[k % len(w.nodes)].name)
+        p.metadata.labels["uniq"] = f"v{k}"
+        w.add(p)
+        st, _ = w.refresh()
+    return st
+
+
+def _by_anti_entropy(w):
+    w.dt.cycles_since_resync = w.dt.resync_interval
+    w.add(plain_pod("tick", w.nodes[3].name))
+    return w.refresh()[0]
+
+
+def _by_node_set(w):
+    n = hollow.make_node("late-node", zone="zone-0")
+    n.metadata.labels["rack"] = "rack-0"
+    w.cache.add_node(n)
+    return w.refresh()[0]
+
+
+def _by_delta_too_large(w):
+    w.dt.max_delta_frac = 0.0
+    w.add(plain_pod("one-too-many", w.nodes[3].name))
+    st = w.refresh()[0]
+    w.dt.max_delta_frac = 1.0
+    return st
+
+
+def _by_label_capacity(w):
+    p = plain_pod("many-labels", w.nodes[3].name)
+    width = w.dt.host.arrays["_pod_kv_ids"].shape[1]
+    for k in range(width):
+        p.metadata.labels[f"extra-{k}"] = "x"
+    w.add(p)
+    return w.refresh()[0]
+
+
+def _by_pod_axis_growth(w):
+    free = w.dt.host.arrays["pod_node"].shape[0] - len(w.dt.pod_row)
+    for k in range(free + 1):
+        w.add(plain_pod(f"fill-{k}", w.nodes[k % len(w.nodes)].name))
+    return w.refresh()[0]
+
+
+RESYNCS = {"vocab-growth": _by_vocab_growth,
+           "anti-entropy": _by_anti_entropy, "node-set": _by_node_set,
+           "delta-too-large": _by_delta_too_large,
+           "label-capacity": _by_label_capacity,
+           "pod-axis-growth": _by_pod_axis_growth}
+
+
+def assert_markers_noted(dt, infos):
+    """Every marker names what the mirror row was filled from, as the
+    resident state stands NOW: the PodInfo on the node, the node's row,
+    the owner's delta row; the Node's ``node_generation``."""
+    assert set(dt.pod_src) == set(dt.pod_row)
+    for i, ni in enumerate(infos):
+        assert dt.node_src[ni.node_name] == ni.node_generation
+        for pi in ni.pods:
+            src, i_was, owner = dt.pod_src[pi.pod.uid]
+            assert src is pi and i_was == i
+            assert owner is None or owner.row == dt.pod_row[pi.pod.uid]
+
+
+def run_resync_then_churn(reason, noted=assert_markers_noted):
+    w = OwnerWorld()
+    _scrambled(w)
+    st = RESYNCS[reason](w)
+    assert st.resync and st.reason == reason
+    noted(w.dt, snapshot_of(w.cache))
+    # what the markers guard, straight after: a resident replaced in
+    # place, an owner whose terms change (the tables rebuild from the
+    # noted owners' rows), a Node set again
+    _set_again(w, 2, lambda n: setattr(n.spec, "unschedulable", True))
+    old = w.owners[0]
+    new = owner_pod(old.metadata.name, old.spec.node_name, "yellow")
+    new.metadata.uid = old.uid
+    w.update(old, new)
+    w.refresh("rebuilt")
+    noted(w.dt, snapshot_of(w.cache))
+    w.add(plain_pod("after", w.nodes[1].name))
+    w.refresh("kept")
+
+
+@pytest.mark.parametrize("reason", list(RESYNCS))
+def test_no_marker_survives_a_resync(reason):
+    run_resync_then_churn(reason)
+
+
+@pytest.mark.parametrize("reason", [r for r in RESYNCS
+                                    if r != "pod-axis-growth"])
+def test_the_golden_fails_when_a_resync_keeps_the_markers(reason,
+                                                          monkeypatch):
+    """The mutation: _resync() leaves ``pod_src`` / ``node_src`` as they
+    were.  A rebuild packs the pod rows anew, so a kept owner would hand
+    the term tables the row it had BEFORE.  (pod-axis growth does not
+    pass through _resync: it moves no row and no id, and the markers the
+    same refresh noted stand.)"""
+    orig = DeltaTensorizer._resync
+
+    def keeps(self, *a, **kw):
+        pod_src, node_src = self.pod_src, self.node_src
+        out = orig(self, *a, **kw)
+        if pod_src:                       # not the initial build
+            self.pod_src, self.node_src = pod_src, node_src
+        return out
+    monkeypatch.setattr(DeltaTensorizer, "_resync", keeps)
+    with pytest.raises(AssertionError):
+        # the golden itself, not the look at the markers
+        run_resync_then_churn(reason, noted=lambda dt, infos: None)
+
+
 @pytest.mark.parametrize("hw", [1, 0])
 def test_required_affinity_owns_a_row_only_at_a_hard_weight(hw):
     """``hard_pod_affinity_weight`` 0: required affinity compiles to no

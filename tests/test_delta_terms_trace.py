@@ -79,7 +79,7 @@ def test_a_refresh_says_what_it_rebuilt_and_how_many_owners_changed():
         assert at["delta-terms"][1] <= at["delta-terms-upload"][0] \
             <= at["delta-terms-upload"][1] <= at["delta-apply"][1]
         assert set(st.span_args) == {"delta-build", "delta-terms"}
-        assert st.span_args["delta-build"] == {"terms_kept": 0}
+        assert st.span_args["delta-build"]["terms_kept"] == 0
         assert set(st.span_args["delta-terms"]) == ARGS
         return st.span_args["delta-terms"]
 
@@ -92,7 +92,8 @@ def test_a_refresh_says_what_it_rebuilt_and_how_many_owners_changed():
     cluster, st = dt.refresh(_snapshot(cache), donate=False)
     assert not st.resync, st.reason
     assert [n for n, _, _ in st.spans] == ["delta-build", "delta-apply"]
-    assert st.span_args == {"delta-build": {"terms_kept": 1}}
+    assert set(st.span_args) == {"delta-build"}
+    assert st.span_args["delta-build"]["terms_kept"] == 1
     # undonated (an in-flight pipelined cycle still reads them), the kept
     # tables are the buffers the last build uploaded, still readable
     for was, now in zip(jax.tree.leaves(kept_terms),
@@ -117,6 +118,72 @@ def test_a_refresh_says_what_it_rebuilt_and_how_many_owners_changed():
     # nothing dirty: no refresh, nothing to say
     _, st = dt.refresh(_snapshot(cache))
     assert st.delta_rows == 0 and st.span_args == {}
+
+
+def churn_on_three_residents(refresh_of):
+    """Plain churn on four nodes of three residents each, then a Node
+    relabelled in place and set again: what each refresh says, through
+    ``refresh_of(cache)`` -> DeltaStats."""
+    cache = SchedulerCache()
+    nodes = hollow.make_nodes(4, zones=2)
+    residents = {}
+    for i, n in enumerate(nodes):
+        n.metadata.labels["rack"] = f"rack-{i % 2}"
+        cache.add_node(n)
+        for k in range(3):
+            p = hollow.make_pod(f"resident-{i}-{k}")
+            p.spec.node_name = n.name
+            cache.add_pod(p)
+            residents[i, k] = p
+    refresh = refresh_of(cache)
+    assert refresh().resync
+
+    def arrive(name, i):
+        p = hollow.make_pod(name)
+        p.spec.node_name = nodes[i].name
+        cache.add_pod(p)
+        return p
+    first = arrive("arrival-0", 0)
+    arrive("arrival-1", 1)
+    yield refresh()
+    cache.remove_pod(first)
+    cache.remove_pod(residents[2, 1])
+    arrive("arrival-2", 3)
+    yield refresh()
+    nodes[1].metadata.labels["rack"] = "rack-0"
+    cache.update_node(nodes[1], nodes[1])
+    arrive("arrival-3", 1)
+    yield refresh()
+
+
+# (delta_rows, delta_buckets) of the three refreshes as the PARENT's
+# code (c4ffb52, which refilled every row of a dirty node) gives them
+# for this event sequence (read by running it): dirty nodes + every pod
+# row on them + the rows of the departed (one of the two freed rows is
+# the next arrival's).  What goes to the device does not depend on what
+# the host skipped
+PARENT_ROWS = [(2 + 8, (8, 8)), (3 + 9 + 2 - 1, (8, 16)), (1 + 5, (8, 8))]
+
+
+def test_a_delta_build_says_how_many_mirror_rows_it_refilled():
+    """``pod_rows_refilled`` is the arrivals, not the residents seen;
+    ``node_rows_refilled`` counts Nodes set again; the scatter carries
+    the parent's rows all the same."""
+    dt = DeltaTensorizer()
+    stats = list(churn_on_three_residents(
+        lambda cache: lambda: dt.refresh(_snapshot(cache))[1]))
+    assert all(not st.resync for st in stats)
+    assert [(st.delta_rows, st.delta_buckets) for st in stats] \
+        == PARENT_ROWS
+    said = [{k: v for k, v in st.span_args["delta-build"].items()
+             if k != "terms_kept"} for st in stats]
+    assert said == [
+        {"node_rows_dirty": 2, "node_rows_refilled": 0,
+         "pod_rows_seen": 8, "pod_rows_refilled": 2},
+        {"node_rows_dirty": 3, "node_rows_refilled": 0,
+         "pod_rows_seen": 9, "pod_rows_refilled": 1},
+        {"node_rows_dirty": 1, "node_rows_refilled": 1,
+         "pod_rows_seen": 5, "pod_rows_refilled": 1}]
 
 
 @pytest.fixture
