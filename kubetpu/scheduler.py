@@ -388,6 +388,9 @@ class Scheduler:
         self._bind_jobs: List[BindJob] = []  # kubelint: guarded-by(_bind_jobs_lock)
         self._stop = threading.Event()
         self._serve_thread: Optional[threading.Thread] = None
+        # the longest pass of the serving loop so far (seconds): what
+        # close() takes a cycle in flight to need (see close)
+        self._longest_pass_s = 0.0
         self._closed = False
         self._add_all_event_handlers()
         # reference: scheduler.go:548 — preemption runs unless disabled
@@ -896,9 +899,13 @@ class Scheduler:
         spread_sels = [self.store.default_spread_selector(pi.pod)
                        for pi in pinfos]
         pb = PodBatchBuilder(builder.table)
-        with trace.stage("batch-build", pods=len(pinfos)):
+        with trace.stage("batch-build", pods=len(pinfos)) as build_span:
             batch = self._jax.tree.map(
                 np.asarray, pb.build(pinfos, spread_selectors=spread_sels))
+            # valid DoNotSchedule constraint rows the builder compiled
+            spread_rows = int(batch.spread.valid.sum())
+            if build_span is not None:
+                build_span.args["spread_rows"] = spread_rows
         batch_dev = None
         if self._mesh is not None:
             # DOUBLE-BUFFERED transfer: start the sharded upload of this
@@ -935,6 +942,13 @@ class Scheduler:
             trace.rec.meta["term_buckets"] = [
                 int(cluster.filter_terms.valid.shape[0]),
                 int(cluster.score_terms.valid.shape[0])]
+            # the batch's hard spread constraints: valid rows, and the
+            # constraint (C) and unique-selector (Us) buckets the
+            # auction's recount runs over, padding included
+            trace.rec.meta["spread_constraints"] = spread_rows
+            trace.rec.meta["spread_buckets"] = [
+                int(batch.spread.valid.shape[1]),
+                int(batch.spread.sel.sel_valid.shape[0])]
             trace.note(delta_rows=trace.rec.meta.get("delta_rows", 0),
                        delta_buckets=trace.rec.meta.get("delta_buckets", []),
                        pod_bucket=trace.rec.meta["pod_bucket"])
@@ -1089,6 +1103,8 @@ class Scheduler:
                       # DefaultPodTopologySpread even without explicit
                       # terms — they need intra-batch placements too
                       or any(s is not None for s in spread_sels))
+        if trace.rec is not None:
+            trace.rec.meta["needs_topo"] = int(needs_topo)
         prep = PreparedCycle(
             fwk=fwk, trace=trace, chain_seq0=chain_seq0,
             node_infos=node_infos, states=states, live=live, pinfos=pinfos,
@@ -3024,8 +3040,11 @@ class Scheduler:
 
         def loop():
             while not self._stop.is_set():
+                t_pass = time.monotonic()
                 try:
                     self.schedule_pending(timeout=0.2)
+                    self._longest_pass_s = max(
+                        self._longest_pass_s, time.monotonic() - t_pass)
                 except Exception:  # the serving loop must never die
                     # (reference: wait.UntilWithContext keeps scheduleOne
                     # running; per-pod errors go through
@@ -3054,16 +3073,27 @@ class Scheduler:
         with self._bind_jobs_lock:
             self._bind_jobs = [j for j in self._bind_jobs if not j.done()]
 
+    # close()'s bound on joining the serving loop, seconds
+    CLOSE_JOIN_FLOOR_S = 2.0
+    CLOSE_JOIN_CAP_S = 30.0
+
     def close(self) -> None:
         """Idempotent shutdown: stop the serving loop and JOIN it before
-        flushing, so the pipeline flush cannot race a cycle in flight —
-        if the loop outlives the join bound (a cold cycle can be paying a
-        multi-second compile), the in-flight cycle is left to that loop
-        and NOT flushed here (its binds it then applies itself:
-        _hand_over).  Then drain the binder lane (the jobs queued on it
-        are applied, in order, before this returns), close the queue
-        (wakes blocked pops, joins flushers), the cache (joins cleanup),
-        and the bind pool (binds blocked there finish on their own)."""
+        flushing, so the pipeline flush cannot race a cycle in flight.
+        The join is bounded by twice the longest pass the loop has made
+        (CLOSE_JOIN_FLOOR_S at the least, CLOSE_JOIN_CAP_S at the most): a
+        deployment whose cycles take seconds on the device (a batch under
+        a hard spread constraint runs hundreds of auction rounds) is
+        given the time its cycle in flight needs, so its binds land
+        BEFORE close() returns and not on a caller that has stopped
+        watching.  If the loop outlives the bound all the same (a cold
+        cycle can be paying a multi-second compile), the in-flight cycle
+        is left to that loop and NOT flushed here (its binds it then
+        applies itself: _hand_over).  Then drain the binder lane (the
+        jobs queued on it are applied, in order, before this returns),
+        close the queue (wakes blocked pops, joins flushers), the cache
+        (joins cleanup), and the bind pool (binds blocked there finish on
+        their own)."""
         if self._closed:
             return
         self._closed = True
@@ -3072,7 +3102,9 @@ class Scheduler:
         serve_loop_live = False
         if (t is not None and t is not threading.current_thread()
                 and t.is_alive()):
-            t.join(timeout=2.0)
+            t.join(timeout=min(max(self.CLOSE_JOIN_FLOOR_S,
+                                   2.0 * self._longest_pass_s),
+                               self.CLOSE_JOIN_CAP_S))
             serve_loop_live = t.is_alive()
         self._serve_thread = None
         if not serve_loop_live:

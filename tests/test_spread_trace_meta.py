@@ -1,0 +1,71 @@
+"""What a gang cycle says about its batch's hard spread constraints
+(PR 33): on the cycle's meta ``spread_constraints`` (valid DoNotSchedule
+rows), ``spread_buckets`` ([C, Us]: the constraint and unique-selector
+buckets the auction's recount ran over) and ``needs_topo``; on the
+``batch-build`` span ``spread_rows``.  A plain batch says 0 / 0."""
+
+import pytest
+
+from kubetpu.api import types as api
+from kubetpu.apis.config import (KubeSchedulerConfiguration,
+                                 KubeSchedulerProfile)
+from kubetpu.client.store import ClusterStore
+from kubetpu.harness import hollow
+from kubetpu.scheduler import Scheduler
+from kubetpu.utils import trace as utrace
+
+
+def _cycle_of(pods):
+    """The record of the one gang cycle that places ``pods`` on twelve
+    nodes in three zones."""
+    store = ClusterStore()
+    for node in hollow.make_nodes(12, zones=3):
+        store.add(node)
+    utrace.disarm_flight_recorder()
+    flight = utrace.arm_flight_recorder(capacity=8, max_spans_per_cycle=64)
+    sched = Scheduler(store, config=KubeSchedulerConfiguration(
+        profiles=[KubeSchedulerProfile()], batch_size=8, mode="gang"),
+        async_binding=False)
+    try:
+        for p in pods:
+            store.add(p)
+        while sched.schedule_pending(timeout=0.2):
+            pass
+        records = [c.to_dict() for c in flight.cycles()]
+    finally:
+        sched.close()
+        utrace.disarm_flight_recorder()
+    assert all(store.get_pod("default", p.metadata.name).spec.node_name
+               for p in pods)
+    assert len(records) == 1
+    return records[0]
+
+
+def _blue(i, **spread):
+    p = hollow.make_pod(f"blue-{i}", labels={"color": "blue"})
+    return hollow.with_spread(p, api.LABEL_ZONE, **spread) if spread else p
+
+
+@pytest.mark.parametrize("what,pods,want", [
+    ("every pod one hard zone constraint",
+     [_blue(i, max_skew=5) for i in range(6)], (6, 1, 1)),
+    ("a soft constraint is no hard row, and still needs the rounds",
+     [_blue(i, max_skew=5, when="ScheduleAnyway") for i in range(6)],
+     (0, 0, 1)),
+    ("a plain batch", [hollow.make_pod(f"plain-{i}") for i in range(6)],
+     (0, 0, 0))])
+def test_a_gang_cycle_records_its_spread_constraints(what, pods, want):
+    rows, selectors, needs_topo = want
+    rec = _cycle_of(pods)
+    meta = rec["meta"]
+    assert meta["spread_constraints"] == rows
+    assert meta["needs_topo"] == needs_topo
+    c, us = meta["spread_buckets"]
+    # buckets, padding included: one constraint a pod, one selector shared
+    assert c == 1 and us >= max(selectors, 1)
+    build = [s for s in rec["spans"] if s["name"] == "batch-build"]
+    assert len(build) == 1 and build[0]["args"]["spread_rows"] == rows
+    assert build[0]["args"]["pods"] == len(pods)
+    # six pods under one zone constraint over three zones: two rounds at
+    # the least, one pod a zone a round
+    assert meta["auction_rounds"] >= (2 if rows else 1)
