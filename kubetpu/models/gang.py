@@ -48,14 +48,29 @@ rounds < r the way the reference's serial loop sees previously bound pods
 Admitted pods' own required anti-affinity terms are spliced into
 filter_terms so they repel later-round pods (the existing-pods direction).
 Within a round, a conservative same-topology-pair deferral keeps admission
-order safe: a pod with required topology terms is deferred to the next
-round if any earlier-index pod was admitted this round into a topo pair one
-of its term keys maps its proposal to (and any pod is deferred from a pair
-an earlier-admitted anti-affinity-active pod landed in); the next round then
-re-checks it against exact committed counts.  Deferral never blocks the
-first admitted pod, so progress is preserved.  Score staleness within a
-single round (not across rounds) is the remaining gap vs the sequential
-replay mode.
+order safe.  A pod with a required anti-affinity term is deferred to the
+next round if any earlier-index pod matching that term was admitted this
+round into the topo pair the term's key maps its proposal to (and any pod is
+deferred from a pair an earlier-admitted pod whose anti-affinity term
+selects it landed in).  A pod with a hard spread constraint has a BUDGET:
+the filter that made its proposal feasible this round computed
+slack = maxSkew - (matchNum + selfMatch - minMatch) >= 0 there
+(K.spread_filter, return_slack), and the pod is deferred only once MORE
+THAN slack earlier-index pods matching the constraint's selector were
+admitted this round into that pair.  With e <= slack such admissions,
+matchNum + e + selfMatch - min' <= maxSkew holds at the pod's turn in pod
+order for every min' >= minMatch, and the minimum only rises inside a
+round (admissions add matching pods, nothing leaves, and the registered
+pairs do not depend on the carry): that is PodTopologySpread's filter as
+the serial loop evaluates it, with the round-start minimum standing in for
+the true one.  A constraint with no room left (skew at maxSkew) defers
+behind the first such pod by the same arithmetic, not by a switch.  The
+earlier pods counted are the capacity-admitted ones, those this deferral
+then holds back included: a superset of the admitted, so the count errs
+high.  The next round re-checks every deferred pod against exact committed
+counts.  Deferral never blocks the first admitted pod, so progress is
+preserved.  Score staleness within a single round (not across rounds) is
+the remaining gap vs the sequential replay mode.
 """
 
 from __future__ import annotations
@@ -606,10 +621,13 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         sbatch = sb["batch"]
         aff_unres = None
         boot_live = None
+        slack = None
         if use_sph:
-            feas = feas & K.spread_filter(cl, sbatch, sb["affinity_ok"],
-                                          match_ns=sb["sph_match"],
-                                          active_keys=cfg.active_keys)
+            ok, slack = K.spread_filter(cl, sbatch, sb["affinity_ok"],
+                                        match_ns=sb["sph_match"],
+                                        active_keys=cfg.active_keys,
+                                        return_slack=True)
+            feas = feas & ok
         if use_ipa:
             ok, aff_unres, boot_live = K.interpod_filter(
                 cl, sbatch, pre=sb["ipa_pre"], return_no_matches=True,
@@ -622,15 +640,17 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
                 "bp,np->bn", sbatch.ports_hot, c["ports_used"],
                 preferred_element_type=jnp.float32) > 0.5
             feas = feas & sb["ports_ok0"] & ~batch_conf
-        return feas, aff_unres, boot_live
+        return feas, aff_unres, boot_live, slack
 
     def _rules_for(terms, mu, uidx, k, pair_ok, order, is_start, admit_cap,
-                   anti: bool):
+                   anti: bool, room=0):
         """Selector-precise same-pair deferral for one term set x one key.
-        rule A: pod j defers iff an earlier-admitted pod in its landing pair
-        matches one of j's key-k term selectors.  rule B (anti only): pod j
-        defers iff it matches a key-k anti term of an earlier-admitted pod
-        in the same pair."""
+        rule A: pod j defers iff MORE THAN room earlier-admitted pods in its
+        landing pair match one of j's key-k term selectors (room 0, the
+        anti-affinity terms': any one of them; the spread constraints pass
+        room [W, T], the filter's own slack at j's proposal).  rule B (anti
+        only): pod j defers iff it matches a key-k anti term of an
+        earlier-admitted pod in the same pair."""
         W = admit_cap.shape[0]
         key_terms = _key_terms_mask(terms, k)  # [W, T]
         adm = _f(admit_cap & pair_ok)[:, None]
@@ -638,7 +658,7 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         e_a = mu.T * adm                               # [W, U]
         pref_a = jnp.zeros_like(e_a).at[order].set(
             _seg_prefix(e_a[order], is_start))
-        hits = jnp.take_along_axis(pref_a, uidx, axis=1) > 0  # [W, T]
+        hits = jnp.take_along_axis(pref_a, uidx, axis=1) > room  # [W, T]
         defer = jnp.any(hits & key_terms, axis=1) & pair_ok
         if anti:
             # events B: admitted pods registering their key-k selectors
@@ -650,15 +670,19 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
             defer = defer | (jnp.any((pref_b > 0) & mu.T, axis=1) & pair_ok)
         return defer
 
-    def topology_deferral(sb, admit_cap, prop, boot_live):
+    def topology_deferral(sb, admit_cap, prop, boot_live, slack):
         """Selector-precise intra-round serialization: see module
         docstring.  One stable sort by landing pair per topology key; the
         per-pair exclusive prefix sums run in unique-selector space
         (O(W x U) per key), so deferral only triggers on genuinely
-        interacting pods — not on mere pair co-occupancy."""
+        interacting pods — not on mere pair co-occupancy.  slack is
+        K.spread_filter's [W, C, N] of this round (None without the spread
+        filter); each pod's row at its proposal is its constraints' room."""
         W = prop.shape[0]
         prop_safe = jnp.clip(prop, 0, N - 1)
         is_prop = prop < N
+        room = None if slack is None else jnp.take_along_axis(
+            slack, prop_safe[:, None, None], axis=2)[:, :, 0]  # [W, C]
         defer = jnp.zeros((W,), bool)
         TK = cluster.topo_pair.shape[1]
         deferral_keys = (range(TK) if not cfg.active_topo_keys else
@@ -680,7 +704,7 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
                 defer = defer | _rules_for(sb["batch"].spread, sb["mu_sph"],
                                            sb["sph_uidx"], k,
                                            pair_ok, order, is_start,
-                                           admit_cap, anti=False)
+                                           admit_cap, anti=False, room=room)
         if use_ipa:
             # bootstrap rule: a pod whose required-affinity terms match
             # nothing THIS round is admitted only via the self-match
@@ -704,7 +728,7 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         sbatch = sb["batch"]
         unassigned = (jnp.take(c["assigned"], rsafe) < 0) & sb["valid"]
         cl = cluster_at(c)
-        feas, aff_unres, boot_live = feasibility(c, cl, sb)
+        feas, aff_unres, boot_live, slack = feasibility(c, cl, sb)
         feas = feas & unassigned[:, None]
 
         # scores against committed usage + placements so later rounds see
@@ -726,11 +750,11 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         return _round_tail(c, sb, prop, active, best, unassigned,
                            windowed=windowed, capture_first=capture_first,
                            feas=feas, aff_unres=aff_unres,
-                           boot_live=boot_live)
+                           boot_live=boot_live, slack=slack)
 
     def _round_tail(c, sb, prop, active, best, unassigned,
                     windowed: bool, capture_first: bool = False,
-                    feas=None, aff_unres=None, boot_live=None):
+                    feas=None, aff_unres=None, boot_live=None, slack=None):
         """The admit/commit half of a round: segmented-reduce admission
         over the proposed nodes + carry update.  O(W) / O(W, R) work."""
         rows = sb["rows"]
@@ -746,7 +770,8 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         if intra:
             # intra-round topology serialization (conservative; deferred
             # pods re-check against exact committed counts next round)
-            admit = admit & ~topology_deferral(sb, admit, prop, boot_live)
+            admit = admit & ~topology_deferral(sb, admit, prop, boot_live,
+                                               slack)
 
         # ---- commit ----
         add_req, add_nz, add_ports = admission_sums(

@@ -379,14 +379,22 @@ def _spread_state(cluster, batch, constraints, affinity_ok, count_mask_nodes,
 
 
 def spread_filter(cluster, batch, affinity_ok, match_ns=None,
-                  active_keys=None) -> jnp.ndarray:
+                  active_keys=None, return_slack: bool = False):
     """PodTopologySpread hard constraints
     (reference: podtopologyspread/filtering.go:200-283 calPreFilterState/Filter).
 
     Node-space formulation: pair aggregates are constant across a pair's
     member nodes, so "min over registered pairs" == "min over nodes of
     registered pairs" and no explicit pair axis is needed — everything is
-    same-pair matmuls on the MXU (see _samepair_pods_to_nodes)."""
+    same-pair matmuls on the MXU (see _samepair_pods_to_nodes).
+
+    return_slack=True also returns slack [B, C, N] f32 = maxSkew - skew from
+    the same intermediates: how many MORE matching pods node n's pair can
+    take before constraint c of pod b fails there (>= 0 wherever c passes;
+    the same value on every node of a pair).  +inf where the filter
+    tolerates the pod whatever lands (no valid constraint, or the empty
+    preFilterState).  The gang auction's intra-round deferral budgets
+    against it (models/gang.py)."""
     cons = batch.spread
     B, C = cons.topo_key.shape
     N = cluster.allocatable.shape[0]
@@ -420,7 +428,12 @@ def spread_filter(cluster, batch, affinity_ok, match_ns=None,
     ok = jnp.all(c_ok | ~cons.valid[:, :, None], axis=1)
     has_any = jnp.any(cons.valid, axis=1)
     # empty preFilterState (no eligible nodes anywhere) tolerates every pod
-    return jnp.where(has_any[:, None] & any_eligible[:, None], ok, True)
+    ok = jnp.where(has_any[:, None] & any_eligible[:, None], ok, True)
+    if not return_slack:
+        return ok
+    slack = jnp.where((has_any & any_eligible)[:, None, None],
+                      cons.max_skew[:, :, None] - skew, jnp.inf)
+    return ok, slack
 
 
 def spread_soft_score(cluster, batch, feasible, affinity_ok,

@@ -251,6 +251,216 @@ def test_intra_batch_hard_spread_skew_respected():
     assert abs(zone_counts[0] - zone_counts[1]) <= 1, zone_counts
 
 
+# ---- the spread deferral's budget (PR 34): a round admits a topology pair's
+# whole room, the filter's own slack maxSkew - skew, and never more ----
+
+def _zone_nodes(per_zone, zones=3):
+    return [mknode(name=f"n{i}", labels={
+        api.LABEL_HOSTNAME: f"n{i}", api.LABEL_ZONE: f"z{i % zones}"})
+        for i in range(per_zone * zones)]
+
+
+def _spread_pod(name, color, max_skew=None, match=None, host_skew=None):
+    from kubetpu.harness import hollow
+    pod = mkpod(name=name, labels={"color": color})
+    if max_skew is not None:
+        hollow.with_spread(pod, api.LABEL_ZONE, max_skew=max_skew,
+                           match=match)
+    if host_skew is not None:
+        hollow.with_spread(pod, api.LABEL_HOSTNAME, max_skew=host_skew,
+                           match=match)
+    return pod
+
+
+def _serial_spread_ok(nodes, placed, pod, node):
+    """PodTopologySpread's Filter (filtering.go:200-283) for `pod` at
+    `node` against `placed` [(pod, node)], counted afresh in plain Python:
+    what the serial loop evaluates at this pod's turn.  Every node here is
+    eligible (no node affinity), so every value of a key is registered."""
+    for c in pod.spec.topology_spread_constraints:
+        sel = c.label_selector.match_labels
+        count = {n.metadata.labels[c.topology_key]: 0 for n in nodes}
+        for q, at in placed:
+            if all(q.metadata.labels.get(k) == v for k, v in sel.items()):
+                count[at.metadata.labels[c.topology_key]] += 1
+        self_match = all(pod.metadata.labels.get(k) == v
+                         for k, v in sel.items())
+        here = count[node.metadata.labels[c.topology_key]]
+        if here + self_match - min(count.values()) > c.max_skew:
+            return False
+    return True
+
+
+def _spread_stops(nodes, existing, pending, window=0):
+    """The same batch stopped after 1, 2, ... admitting rounds (max_rounds
+    is static: each stop is a program of its own, and a prefix of the next
+    by determinism), until every pod is placed or a stop places nothing
+    more.  At EVERY stop the round's admissions, taken in pod order after
+    everything admitted before, must each pass the serial filter on exact
+    counts: what serial admission implies after every admission.  Returns
+    the placements a stop, [R][B] of node rows."""
+    cluster, batch, cfg, _ = build(nodes, existing, pending,
+                                   filters=TOPO_FILTERS)
+    B = len(pending)
+    placed = [(q, n) for n in nodes for q in existing.get(n.name, [])]
+    stops, prev = [], np.full(B, -1)
+    for r in range(1, B + 2):
+        g = gang.schedule_gang(cluster, batch, cfg, jax.random.PRNGKey(0),
+                               max_rounds=r, residual_window=window)
+        chosen = np.asarray(g.chosen)[:B]
+        held = prev >= 0
+        np.testing.assert_array_equal(chosen[held], prev[held])
+        new = np.flatnonzero((chosen >= 0) & ~held)
+        if not len(new):
+            break
+        for j in new:
+            assert _serial_spread_ok(nodes, placed, pending[j],
+                                     nodes[chosen[j]]), (r, j, chosen)
+            placed.append((pending[j], nodes[chosen[j]]))
+        assert_no_capacity_violation(cluster, batch, np.asarray(g.chosen))
+        stops.append(chosen)
+        prev = chosen
+        if (chosen >= 0).all():
+            break
+    return stops
+
+
+def _zones_of(chosen, rows=None, zones=3):
+    c = chosen if rows is None else chosen[rows]
+    return np.bincount(c[c >= 0] % zones, minlength=zones)
+
+
+def _budget_whole_room():
+    # 30 self-matching pods, maxSkew 5, empty cluster: five a zone a round
+    # (twice as many nodes as pods, so the emptiest-node tie set keeps
+    # spanning every zone, as in a cluster of thousands)
+    pending = [_spread_pod(f"p{i:02d}", "blue", 5) for i in range(30)]
+    stops = _spread_stops(_zone_nodes(20), {}, pending)
+    # placed within three rounds, where one a zone a round needs ten
+    assert len(stops) <= 3 and (stops[-1] >= 0).all()
+    for chosen in stops:
+        z = _zones_of(chosen)
+        assert z.max() - z.min() <= 5, z
+    # round one: more than one a zone, and no more than the room the
+    # filter saw from the empty cluster (slack 4: five a zone)
+    first = _zones_of(stops[0])
+    assert first.sum() > 3 and first.max() <= 5, first
+
+
+def _budget_no_room():
+    # maxSkew 1 on balanced zones: slack 0, one pod a zone a round as ever
+    pending = [_spread_pod(f"p{i}", "blue", 1) for i in range(9)]
+    stops = _spread_stops(_zone_nodes(4), {}, pending)
+    assert (stops[-1] >= 0).all() and len(stops) >= 9 // 3
+    placed = [int((c >= 0).sum()) for c in stops]
+    assert all(b - a <= 3 for a, b in zip([0] + placed, placed)), placed
+    z = _zones_of(stops[-1])
+    assert z.max() - z.min() <= 1, z
+
+
+def _budget_zone_ahead():
+    # zone z0 starts 8 ahead, maxSkew 5: it is infeasible until BOTH others
+    # hold 4 (8 + 1 - 4 = 5), whatever room the others' pairs have
+    nodes = _zone_nodes(4)
+    existing = {"n0": [_spread_pod(f"e{i}", "blue") for i in range(8)]}
+    pending = [_spread_pod(f"p{i:02d}", "blue", 5) for i in range(18)]
+    stops = _spread_stops(nodes, existing, pending)
+    assert (stops[-1] >= 0).all()
+    before = np.zeros(3, int)
+    for chosen in stops:
+        z = _zones_of(chosen)
+        if z[0] > before[0]:
+            assert min(before[1], before[2]) >= 4, (before, z)
+        before = z
+    assert _zones_of(stops[0])[0] == 0 and _zones_of(stops[-1])[0] > 0
+
+
+def _budget_two_selectors():
+    # red and blue interleaved, each selecting its own colour: a red
+    # admission uses up no blue room, so round one admits up to 5 a zone of
+    # EACH (one shared budget would stop at 15)
+    pending = [_spread_pod(f"p{i:02d}", ("red", "blue")[i % 2], 5)
+               for i in range(30)]
+    stops = _spread_stops(_zone_nodes(20), {}, pending)
+    assert (stops[-1] >= 0).all()
+    first = stops[0]
+    for rows in (np.arange(0, 30, 2), np.arange(1, 30, 2)):
+        assert _zones_of(first, rows).max() <= 5
+        for chosen in stops:
+            z = _zones_of(chosen, rows)
+            assert z.max() - z.min() <= 5, z
+    assert (first >= 0).sum() > 15
+
+
+def _budget_plain_pod_uses_room():
+    # six plain blue pods ahead in pod order: no constraint of their own,
+    # they land anywhere at once, and each counts against the room of the
+    # constrained pods behind it in its zone (maxSkew 1: room 0)
+    pending = ([_spread_pod(f"a{i}", "blue") for i in range(6)]
+               + [_spread_pod(f"b{i}", "blue", 1) for i in range(6)])
+    stops = _spread_stops(_zone_nodes(4), {}, pending)
+    assert (stops[0][:6] >= 0).all() and (stops[-1] >= 0).all()
+    plain = _zones_of(stops[0], np.arange(6))
+    mine = _zones_of(stops[0], np.arange(6, 12))
+    # a constrained pod got in at round one only where no plain pod landed
+    assert not (mine[plain > 0]).any(), (plain, mine)
+
+
+def _budget_not_self_matching():
+    # red watchers spread over BLUE pods (self_match 0, maxSkew 1): room 1
+    # on the empty cluster, so one blue pod ahead in a zone still lets a
+    # watcher in and two do not; watchers never use each other's room
+    pending = ([_spread_pod(f"a{i}", "blue") for i in range(4)]
+               + [_spread_pod(f"w{i}", "red", 1, match={"color": "blue"})
+                  for i in range(9)])
+    stops = _spread_stops(_zone_nodes(4), {}, pending)
+    assert (stops[-1] >= 0).all()
+    blue = _zones_of(stops[0], np.arange(4))
+    watchers = _zones_of(stops[0], np.arange(4, 13))
+    assert not (watchers[blue > 1]).any(), (blue, watchers)
+    # no blue pod at all: nothing to count, all nine in one round
+    alone = _spread_stops(_zone_nodes(4), {}, pending[4:])
+    assert len(alone) == 1 and (alone[0] >= 0).all()
+
+
+def _budget_two_keys():
+    # zone maxSkew 5 AND hostname maxSkew 1 on six nodes: the hostname
+    # constraint's room (0 on balanced nodes) is the tighter and wins
+    pending = [_spread_pod(f"p{i:02d}", "blue", 5, host_skew=1)
+               for i in range(12)]
+    stops = _spread_stops(_zone_nodes(2), {}, pending)
+    assert (stops[-1] >= 0).all() and len(stops) >= 2
+    for chosen in stops:
+        per_node = np.bincount(chosen[chosen >= 0], minlength=6)
+        assert per_node.max() - per_node.min() <= 1, per_node
+        z = _zones_of(chosen)
+        assert z.max() - z.min() <= 5, z
+    assert (stops[0] >= 0).sum() <= 6
+
+
+def _budget_windowed():
+    # the windowed residual loop traces the same body: same safety at every
+    # stop, the same pods placed, the zones as even
+    pending = [_spread_pod(f"p{i:02d}", "blue", 5) for i in range(30)]
+    full = _spread_stops(_zone_nodes(20), {}, pending)
+    win = _spread_stops(_zone_nodes(20), {}, pending, window=8)
+    assert (full[-1] >= 0).all() and (win[-1] >= 0).all()
+    # round one is full-width in both
+    np.testing.assert_array_equal(full[0], win[0])
+    for chosen in win:
+        z = _zones_of(chosen)
+        assert z.max() - z.min() <= 5, z
+
+
+@pytest.mark.parametrize("case", [
+    _budget_whole_room, _budget_no_room, _budget_zone_ahead,
+    _budget_two_selectors, _budget_plain_pod_uses_room,
+    _budget_not_self_matching, _budget_two_keys, _budget_windowed],
+    ids=lambda f: f.__name__.lstrip("_"))
+def test_spread_deferral_budget(case):
+    case()
+
+
 def test_required_affinity_enabled_by_batch_pod():
     # Pod 1 requires zone co-location with app=x; nothing in the cluster
     # matches until pod 0 (app=x) is admitted.  The serial loop schedules

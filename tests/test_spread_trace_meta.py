@@ -66,6 +66,21 @@ def test_a_gang_cycle_records_its_spread_constraints(what, pods, want):
     build = [s for s in rec["spans"] if s["name"] == "batch-build"]
     assert len(build) == 1 and build[0]["args"]["spread_rows"] == rows
     assert build[0]["args"]["pods"] == len(pods)
-    # six pods under one zone constraint over three zones: two rounds at
-    # the least, one pod a zone a round
-    assert meta["auction_rounds"] >= (2 if rows else 1)
+    # the unwindowed loop (six pods, a window of 512) ends on one round
+    # that admits nothing, so a batch the rounds re-evaluate says two at
+    # the least however many a round admits
+    assert meta["auction_rounds"] >= (2 if needs_topo else 1)
+
+
+@pytest.mark.parametrize("what,max_skew,admitting", [
+    # since PR 34 a round admits a zone's whole room, the filter's own
+    # slack: from empty zones maxSkew 5 leaves room for five a zone
+    ("six pods fit the room of one round", 5, (1, 1)),
+    # maxSkew 1 leaves room for one a zone a round: two rounds if the six
+    # proposals fall two a zone, more if they pile up
+    ("no room: one pod a zone a round as ever", 1, (2, 6))])
+def test_a_round_admits_a_zones_whole_room(what, max_skew, admitting):
+    meta = _cycle_of([_blue(i, max_skew=max_skew) for i in range(6)])["meta"]
+    lo, hi = admitting
+    # + the one closing round that admits nothing
+    assert lo + 1 <= meta["auction_rounds"] <= hi + 1
