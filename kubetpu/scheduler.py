@@ -739,7 +739,7 @@ class Scheduler:
             # the moment the snapshot was taken: what a tie-set check on
             # this cycle's own binds has to place the cluster at
             trace.rec.meta["snapshot_t"] = round(utrace.wallclock(), 6)
-            trace.note(nodes=n_nodes)
+            trace.note(nodes=n_nodes, pods_copied=self.snapshot.pods_copied)
         trace.phase("prefilter", pods=len(qpods))
         if self.metrics:
             self.metrics.cache_size.set(n_nodes, "nodes")
@@ -803,6 +803,8 @@ class Scheduler:
         if use_chain:
             cluster = chain["cluster"]
             chain_pod_uids = chain["pod_uids"]
+            cycle_pod_rows = {uid: i for i, uid in enumerate(chain_pod_uids)
+                              if uid}
             if ujournal.journal() is not None:
                 # journal provenance: this cycle's cluster is the
                 # previous committed cycle's auction, materialized at
@@ -874,6 +876,9 @@ class Scheduler:
                 # and diverge from the span-based traceview digest
                 self.delta_rows.append(dstats.delta_rows)
                 self.delta_cycle_count += 1
+            # this cycle's existing-pod rows by uid: a copy, the next
+            # refresh moves the tensorizer's own
+            cycle_pod_rows = dict(delta.pod_row)
             chain_pod_uids = delta.pod_uid_list()
             # journal capture seam (state/delta.py): the exact resync
             # snapshot / delta tables / zero-dirty marker this refresh
@@ -937,6 +942,10 @@ class Scheduler:
             # tools/kubeaot --prune works in (buckets the recorder never
             # saw are dead ladder rungs, dropped from the artifact set)
             trace.rec.meta["pod_bucket"] = int(cluster.pod_valid.shape[0])
+            # rows of that axis in use, and the bytes of the cluster the
+            # cycle dispatches on (from shapes)
+            trace.rec.meta["pod_rows_live"] = len(cycle_pod_rows)
+            trace.rec.meta["cluster_device_bytes"] = cluster.nbytes
             # the existing-term rows (Et filter, Es score) the auction's
             # match and contractions run over, padding included
             trace.rec.meta["term_buckets"] = [
@@ -1088,8 +1097,7 @@ class Scheduler:
         # existing-pod tensor rows by uid (chained clusters' row order
         # diverges from node_infos build order; preemption victim masking
         # needs the true mapping)
-        cycle_ctx.pod_rows = {uid: i for i, uid in enumerate(chain_pod_uids)
-                              if uid}
+        cycle_ctx.pod_rows = cycle_pod_rows
         trace.step("Tensorizing snapshot and pod batch done")
 
         from .framework.types import pod_with_affinity
@@ -2954,6 +2962,10 @@ class Scheduler:
                 # pruned bucket: the recorder's bucket-hit data says no
                 # serving cycle ever reached it
                 break
+            if not self._rung_fits(cluster, pow2_bucket(p_next)):
+                # a pod axis the device cannot hold cannot be served
+                # either: the ladder ends at the last rung that fits
+                break
             t0 = time.time()
             _lsp = (fr_rec.span("prewarm", mode="ladder")
                     if fr_rec is not None else contextlib.nullcontext())
@@ -2975,6 +2987,23 @@ class Scheduler:
                     "prewarm-ladder", fwk.profile_name, cluster,
                     int(cluster.allocatable.shape[0]),
                     meta={"bucket": int(cluster.pod_valid.shape[0])})
+
+    def _rung_fits(self, cluster, bucket: int) -> bool:
+        """Can the device hold the ladder's next rung beside what is
+        resident now?  The rung's cluster, taken as the current one's
+        bytes scaled by the pod axis (from shapes: pod_kv [P, L] is
+        nearly all of a large cluster), and as much again for what runs
+        on it (the delta scatter's densified rows, the auction's [B, P]
+        matches).  At 150,000 bound pods the rung after
+        262,144 rows is 8.9 GB and the one after that 17.8 GB of a
+        16 GB chip.  A backend that reports no limit (the CPU) is held to
+        none."""
+        stats = self._jax.devices()[0].memory_stats() or {}
+        limit = stats.get("bytes_limit")
+        if not limit:
+            return True
+        grown = cluster.nbytes * bucket / int(cluster.pod_valid.shape[0])
+        return stats.get("bytes_in_use", 0) + 2 * grown <= limit
 
     def _prewarm_ladder_step(self, fwk, cluster, batch, cfg, rng, res,
                              warm_bias, p_next, e_next):

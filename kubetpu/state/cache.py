@@ -55,6 +55,9 @@ class Snapshot:
         self.node_info_list: List[NodeInfo] = []
         self.have_pods_with_affinity_list: List[NodeInfo] = []
         self.generation = 0
+        # pod-list entries the last update_snapshot copied into the
+        # clones it made (the ``snapshot`` span's ``pods_copied``)
+        self.pods_copied = 0
 
     def num_nodes(self) -> int:
         return len(self.node_info_list)
@@ -288,6 +291,7 @@ class SchedulerCache:
         with self._lock:
             balanced_gen = snapshot.generation
             update_all = False
+            pods_copied = 0
             item = self.head
             while item is not None:
                 info = item.info
@@ -301,7 +305,9 @@ class SchedulerCache:
                             info.pods_with_affinity):
                         update_all = True
                     snapshot.node_info_map[info.node_name] = info.clone()
+                    pods_copied += len(info.pods)
                 item = item.next
+            snapshot.pods_copied = pods_copied
             if self.head is not None:
                 snapshot.generation = self.head.info.generation
             # removed nodes may still be in the snapshot map — compare

@@ -571,7 +571,10 @@ class DeltaTensorizer:
             "node_rows_dirty": len(dirty),
             "node_rows_refilled": len(reset_nodes),
             "pod_rows_seen": pods_seen,
-            "pod_rows_refilled": len(fills)}}
+            "pod_rows_refilled": len(fills),
+            # the host's visits; today also the pod rows sent
+            # (``pod_rows_seen``): every pod of a dirty node is both
+            "pods_walked": pods_seen}}
         if terms_dirty:
             t_terms = wallclock()
             span_args["delta-terms"] = dict(

@@ -123,6 +123,11 @@ class ClusterTensors(NamedTuple):
     def n_nodes_cap(self) -> int:
         return self.allocatable.shape[0]
 
+    @property
+    def nbytes(self) -> int:
+        """Bytes of every leaf, from shapes and dtypes: no transfer."""
+        return sum(int(x.nbytes) for x in jax.tree.leaves(self))
+
 
 class HostClusterArrays(NamedTuple):
     """Numpy twin of ClusterTensors (what the builder maintains).
