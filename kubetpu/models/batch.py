@@ -163,6 +163,19 @@ def densify_for(cluster, batch: "PodBatch") -> "PodBatch":
     return batch._replace(kv_hot=kv_hot, key_hot=key_hot)
 
 
+def live_term_sets(batch: "PodBatch") -> List[str]:
+    """Names of the batch's term sets with a row the topology kernels must
+    match against the existing pods — the host's (numpy) reading of the
+    predicates ops/kernels.py gates each set's pod-axis products behind
+    (_if_live).  The serving cycle records it as meta term_sets_live."""
+    sets = [(name, getattr(batch, name).valid.any())
+            for name in ("ra", "raa", "pref", "spread", "spread_soft")]
+    sel = batch.spread_selector
+    sets.append(("default_spread",
+                 (sel.sel_valid[sel.index] & ~batch.spread_skip).any()))
+    return [name for name, live in sets if live]
+
+
 def gather_batch_rows(batch: "PodBatch", rows: np.ndarray) -> "PodBatch":
     """Select pod rows (numpy; -1 entries are padding -> valid False).
     The residual-auction host loop uses this to re-run only the CONTENDED

@@ -466,12 +466,12 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
     # [B*Tp, L] x [N, L] selector match per round before this.
     from .programs import static_raw_scores
     score_pre = dict(static_raw_scores(ext, batch, cfg))
-    # hoist every assignment-independent match matrix out of the round
-    # loop: only the segment/gather work that depends on the carry's
-    # assignments runs per round.  The score pres are needed regardless of
-    # intra_batch_topology: windowed sub-rounds row-gather ONLY these
-    # matrices (the SelectorSets stay full-size), so a score kernel falling
-    # back to selector matching against a width-W batch would crash.
+    # hoist every assignment-independent selector match out of the round
+    # loop, one row a UNIQUE selector ([U, P]; all False, unmatched, for a
+    # term set with no valid row — ops/kernels.py _if_live): only the
+    # expansion to the round's pods and the segment/gather work that
+    # depends on the carry's assignments run per round, and those only
+    # for a live set.
     if "InterPodAffinity" in score_names:
         score_pre["interpod_score"] = K.interpod_score_pre(ext, batch)
     if "PodTopologySpread" in score_names:
@@ -535,11 +535,16 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
                        "valid", "self_match", "max_skew")
 
     def _gather_terms(t, rsafe):
-        """Row-gather the dense [B, ...] companion arrays of a
-        PodTerms/SpreadConstraints set.  The SelectorSet stays full-size:
-        every in-round kernel consumes the precomputed match matrices
-        (sph_match / ipa_pre / score_pre), never the selectors."""
-        return t._replace(**{f: jnp.take(getattr(t, f), rsafe, axis=0)
+        """Row-gather a PodTerms/SpreadConstraints set: its dense [B, ...]
+        companion arrays, and its SelectorSet's slot index (the unique
+        compiled selectors are shared), so the in-round kernels expand
+        the hoisted unique-selector matches (sph_match / ipa_pre /
+        score_pre, [U, P]) over the window's own rows."""
+        T = t.valid.shape[1]
+        index = jnp.take(jnp.asarray(t.sel.index).reshape(B, T), rsafe,
+                         axis=0).reshape(-1)
+        return t._replace(sel=t.sel._replace(index=index),
+                          **{f: jnp.take(getattr(t, f), rsafe, axis=0)
                              for f in TERM_ROW_FIELDS if f in t._fields})
 
     def full_sub():
@@ -566,19 +571,18 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         def g(x):
             return jnp.take(x, rsafe, axis=0)
 
-        def g_pre(v):
-            if isinstance(v, K.InterpodPre):
-                return K.InterpodPre(m_ra=g(v.m_ra), m_raa=g(v.m_raa),
-                                     em=v.em[:, rsafe])
-            if isinstance(v, K.InterpodScorePre):
-                return K.InterpodScorePre(m_pref=g(v.m_pref),
-                                          em=v.em[:, rsafe])
-            return g(v)
+        def g_em(pre):
+            # the hoisted matches are per UNIQUE selector ([U, P]) and
+            # shared; the existing-terms match has a column a pod
+            return pre._replace(em=pre.em[:, rsafe])
 
         sub_batch = batch._replace(
             req=g(batch.req), nonzero_req=g(batch.nonzero_req),
             ports_hot=g(batch.ports_hot),
             ports_asnode_hot=g(batch.ports_asnode_hot),
+            ns_hot=g(batch.ns_hot),
+            spread_selector=batch.spread_selector._replace(
+                index=g(jnp.asarray(batch.spread_selector.index))),
             spread_skip=g(batch.spread_skip),
             valid=g(batch.valid) & wvalid,
             ra=_gather_terms(batch.ra, rsafe),
@@ -589,12 +593,14 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         sb = dict(rows=rows, valid=sub_batch.valid, batch=sub_batch,
                   static_ok=g(static_ok), ports_ok0=g(ports_ok0),
                   affinity_ok=g(affinity_ok), tie_keys=g(tie_keys),
-                  score_pre={k: g_pre(v) for k, v in score_pre.items()},
+                  score_pre={k: g_em(v) if k == "interpod_score"
+                             else g(v) if k.startswith("raw:") else v
+                             for k, v in score_pre.items()},
                   score_bias=None if score_bias is None
                   else g(score_bias))
         if intra:
-            sb["sph_match"] = g(sph_match) if use_sph else None
-            sb["ipa_pre"] = g_pre(ipa_pre) if use_ipa else None
+            sb["sph_match"] = sph_match
+            sb["ipa_pre"] = g_em(ipa_pre) if use_ipa else None
         if use_ipa:
             sb["ra_boot"] = g(ra_boot)
             sb["mu_raa"] = mu_raa[:, rsafe]

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..state.tensors import CH_CPU, CH_EPH, CH_MEM, CH_PODS, N_FIXED_CHANNELS
-from .selectors import match_selectors
+from .selectors import match_selectors, match_selectors_unique
 
 MAX_NODE_SCORE = 100.0  # reference: framework/v1alpha1/interface.go:85
 
@@ -113,6 +113,41 @@ def crossaxis_first_index_argmax(tile_best, tile_h, tile_arg, axis_name,
 
 # ---------------------------------------------------------------------------
 # shared aggregation helpers
+
+
+def _if_live(live, live_fn, dead_fn):
+    """The runtime gate of one of a batch's term sets (required affinity,
+    required anti-affinity, preferred terms, hard and soft spread
+    constraints, the controller selectors).
+
+    Everything a topology kernel does along the existing-pod axis for one
+    set — the [., P] selector match and its [., P] x [P, N] contractions —
+    is live_fn; dead_fn is what that code returns when no row of the set
+    is valid, built from shapes alone.  ``live`` is a device bool scalar
+    read from the BATCH's own arrays, never the cluster's, so one compiled
+    program does the work each batch needs and a vmap over clusters
+    (preemption's candidates) leaves the cond a cond.  Both branches must
+    return the same node-shaped ([., N]) or row-shaped avals."""
+    return jax.lax.cond(live, live_fn, dead_fn)
+
+
+def _pod_axis_match(cluster, sel) -> jnp.ndarray:
+    """[U, P] match of a SelectorSet's UNIQUE selectors against the
+    existing pods — the assignment-independent part of every topology
+    kernel's pod-axis work."""
+    return match_selectors_unique(sel, cluster.pod_kv, cluster.pod_key)
+
+
+def _pod_axis_pre(cluster, sel, live) -> jnp.ndarray:
+    """_pod_axis_match as gang mode hoists it out of its rounds, behind
+    the set's gate: all False, unmatched, for a dead set (the kernels never
+    read it).  Rows expand to the batch's slots ([B, T, P], and meet the
+    namespaces) only inside the kernel's own gate, so nothing
+    [B, T, P]-sized outlives a round, or exists at all for a dead set."""
+    U = sel.sel_valid.shape[0]
+    P = cluster.pod_valid.shape[0]
+    return _if_live(live, lambda: _pod_axis_match(cluster, sel),
+                    lambda: jnp.zeros((U, P), bool))
 
 
 def per_node_counts(match_sp: jnp.ndarray, pod_node: jnp.ndarray, n_nodes: int) -> jnp.ndarray:
@@ -324,15 +359,23 @@ class SpreadState(NamedTuple):
     any_eligible: jnp.ndarray  # [B]
 
 
-def spread_match_ns(cluster, batch, constraints) -> jnp.ndarray:
+def _spread_match_ns(cluster, batch, constraints, pre=None) -> jnp.ndarray:
     """[B, C, P] constraint-selector x namespace match against the pod axis
-    — the assignment-independent part of _spread_state, precomputable once
-    for gang mode's per-round re-evaluation."""
+    — the assignment-independent part of _spread_state.  pre: the
+    constraints' unique-selector match [Us, P] (spread_match_ns)."""
     B, C = constraints.topo_key.shape
-    m = match_selectors(constraints.sel, cluster.pod_kv, cluster.pod_key)
+    if pre is None:
+        pre = _pod_axis_match(cluster, constraints.sel)
+    m = jnp.take(pre, constraints.sel.index, axis=0)  # [B*C, P]
     ns_ok = jnp.einsum("bn,pn->bp", batch.ns_hot, cluster.pod_ns_hot,
                        preferred_element_type=jnp.float32) > 0.5
     return m.reshape(B, C, -1) & ns_ok[:, None, :]
+
+
+def spread_match_ns(cluster, batch, constraints) -> jnp.ndarray:
+    """The hoisted pre of a constraint set ([Us, P], _pod_axis_pre)."""
+    return _pod_axis_pre(cluster, constraints.sel,
+                         jnp.any(constraints.valid))
 
 
 def _spread_state(cluster, batch, constraints, affinity_ok, count_mask_nodes,
@@ -351,7 +394,7 @@ def _spread_state(cluster, batch, constraints, affinity_ok, count_mask_nodes,
     # matching existing pods: same namespace, selector, non-terminating
     # (reference: podtopologyspread/common.go:87 countPodsMatchSelector)
     if match_ns is None:
-        match_ns = spread_match_ns(cluster, batch, constraints)
+        match_ns = _spread_match_ns(cluster, batch, constraints)
     countable = cluster.pod_valid & ~cluster.pod_terminating
     m = match_ns & countable[None, None, :]
     node_counts = per_node_counts(m.reshape(B * C, -1), cluster.pod_node,
@@ -398,42 +441,53 @@ def spread_filter(cluster, batch, affinity_ok, match_ns=None,
     cons = batch.spread
     B, C = cons.topo_key.shape
     N = cluster.allocatable.shape[0]
-    if match_ns is None:
-        match_ns = spread_match_ns(cluster, batch, cons)
-    countable = cluster.pod_valid & ~cluster.pod_terminating
-    m = (match_ns & countable[None, None, :]).reshape(B * C, -1)
-    keys = jnp.where(cons.topo_known, cons.topo_key, -1).reshape(-1)
-    # matching-pod count of each node's pair, per constraint  [B*C, N]
-    cnt = _samepair_pods_to_nodes(cluster, m, keys, cluster.pod_node,
-                                  cluster.pod_valid,
-                                  active_keys=active_keys)
-    node_pair = node_topo_pairs(cluster, cons.topo_key.reshape(-1))
-    has_key = ((node_pair >= 0).reshape(B, C, N)
-               & cons.topo_known.reshape(B, C)[:, :, None])
-    all_keys = jnp.all(has_key | ~cons.valid[:, :, None], axis=1)  # [B, N]
-    eligible = affinity_ok & cluster.node_valid[None, :] & all_keys
-    any_eligible = jnp.any(eligible, axis=1)
-    # a pair is registered iff some eligible node carries it
-    elig_bc = jnp.broadcast_to(eligible[:, None, :], (B, C, N)).reshape(B * C, N)
-    registered = _samepair_nodes(cluster, elig_bc, keys,
-                                 active_keys=active_keys) > 0.5  # [B*C, N]
-    big = jnp.float32(2**31)
-    min_match = jnp.min(jnp.where(registered, cnt, big),
-                        axis=1).reshape(B, C)
-    # unregistered pair => matchNum 0 (reference Filter: nil *tpCount)
-    match_num = jnp.where(registered, cnt, 0.0).reshape(B, C, N)
-    self_m = _f(cons.self_match)[:, :, None]
-    skew = match_num + self_m - min_match[:, :, None]
-    c_ok = has_key & (skew <= cons.max_skew[:, :, None])
-    ok = jnp.all(c_ok | ~cons.valid[:, :, None], axis=1)
-    has_any = jnp.any(cons.valid, axis=1)
-    # empty preFilterState (no eligible nodes anywhere) tolerates every pod
-    ok = jnp.where(has_any[:, None] & any_eligible[:, None], ok, True)
-    if not return_slack:
-        return ok
-    slack = jnp.where((has_any & any_eligible)[:, None, None],
-                      cons.max_skew[:, :, None] - skew, jnp.inf)
-    return ok, slack
+
+    def live():
+        m_ns = _spread_match_ns(cluster, batch, cons, pre=match_ns)
+        countable = cluster.pod_valid & ~cluster.pod_terminating
+        m = (m_ns & countable[None, None, :]).reshape(B * C, -1)
+        keys = jnp.where(cons.topo_known, cons.topo_key, -1).reshape(-1)
+        # matching-pod count of each node's pair, per constraint  [B*C, N]
+        cnt = _samepair_pods_to_nodes(cluster, m, keys, cluster.pod_node,
+                                      cluster.pod_valid,
+                                      active_keys=active_keys)
+        node_pair = node_topo_pairs(cluster, cons.topo_key.reshape(-1))
+        has_key = ((node_pair >= 0).reshape(B, C, N)
+                   & cons.topo_known.reshape(B, C)[:, :, None])
+        all_keys = jnp.all(has_key | ~cons.valid[:, :, None], axis=1)  # [B, N]
+        eligible = affinity_ok & cluster.node_valid[None, :] & all_keys
+        any_eligible = jnp.any(eligible, axis=1)
+        # a pair is registered iff some eligible node carries it
+        elig_bc = jnp.broadcast_to(eligible[:, None, :],
+                                   (B, C, N)).reshape(B * C, N)
+        registered = _samepair_nodes(cluster, elig_bc, keys,
+                                     active_keys=active_keys) > 0.5  # [B*C, N]
+        big = jnp.float32(2**31)
+        min_match = jnp.min(jnp.where(registered, cnt, big),
+                            axis=1).reshape(B, C)
+        # unregistered pair => matchNum 0 (reference Filter: nil *tpCount)
+        match_num = jnp.where(registered, cnt, 0.0).reshape(B, C, N)
+        self_m = _f(cons.self_match)[:, :, None]
+        skew = match_num + self_m - min_match[:, :, None]
+        c_ok = has_key & (skew <= cons.max_skew[:, :, None])
+        ok = jnp.all(c_ok | ~cons.valid[:, :, None], axis=1)
+        has_any = jnp.any(cons.valid, axis=1)
+        # empty preFilterState (no eligible nodes anywhere) tolerates every pod
+        ok = jnp.where(has_any[:, None] & any_eligible[:, None], ok, True)
+        if not return_slack:
+            return ok
+        slack = jnp.where((has_any & any_eligible)[:, None, None],
+                          cons.max_skew[:, :, None] - skew, jnp.inf)
+        return ok, slack
+
+    def dead():
+        # no valid constraint on any pod: has_any is False on every row
+        ok = jnp.ones((B, N), bool)
+        if not return_slack:
+            return ok
+        return ok, jnp.full((B, C, N), jnp.inf, jnp.float32)
+
+    return _if_live(jnp.any(cons.valid), live, dead)
 
 
 def spread_soft_score(cluster, batch, feasible, affinity_ok,
@@ -444,98 +498,102 @@ def spread_soft_score(cluster, batch, feasible, affinity_ok,
     cons = batch.spread_soft
     B, C = cons.topo_key.shape
     N = cluster.allocatable.shape[0]
-    count_nodes = affinity_ok & cluster.node_valid[None, :]
-    if match_ns is None:
-        match_ns = spread_match_ns(cluster, batch, cons)
-    countable = cluster.pod_valid & ~cluster.pod_terminating
-    m = match_ns & countable[None, None, :]          # [B, C, P]
-    keys = jnp.where(cons.topo_known, cons.topo_key, -1).reshape(-1)
-    node_pair = node_topo_pairs(cluster, cons.topo_key.reshape(-1))
-    has_key = ((node_pair >= 0).reshape(B, C, N)
-               & cons.topo_known.reshape(B, C)[:, :, None])
-    is_host = (cons.topo_key == hostname_topokey) & cons.topo_known
-    valid = cons.valid
 
-    # per-node match counts (hostname constraints read these directly)
-    node_counts = per_node_counts(m.reshape(B * C, -1), cluster.pod_node,
-                                  N).reshape(B, C, N)
-    # pair sums count only pods on PreScore-eligible nodes
-    # (reference: scoring.go:139-165 counts over filtered+affinity nodes)
-    cm_pods = jnp.take_along_axis(
-        count_nodes, jnp.clip(cluster.pod_node, 0, None)[None, :], axis=1)
-    cm_pods = cm_pods & (cluster.pod_node >= 0)[None, :]     # [B, P]
-    m_counted = (m & cm_pods[:, None, :]).reshape(B * C, -1)
-    cnt_pair = _samepair_pods_to_nodes(cluster, m_counted, keys,
-                                       cluster.pod_node, cluster.pod_valid,
-                                       active_keys=active_keys)
+    def live():
+        count_nodes = affinity_ok & cluster.node_valid[None, :]
+        m_ns = _spread_match_ns(cluster, batch, cons, pre=match_ns)
+        countable = cluster.pod_valid & ~cluster.pod_terminating
+        m = m_ns & countable[None, None, :]          # [B, C, P]
+        keys = jnp.where(cons.topo_known, cons.topo_key, -1).reshape(-1)
+        node_pair = node_topo_pairs(cluster, cons.topo_key.reshape(-1))
+        has_key = ((node_pair >= 0).reshape(B, C, N)
+                   & cons.topo_known.reshape(B, C)[:, :, None])
+        is_host = (cons.topo_key == hostname_topokey) & cons.topo_known
+        valid = cons.valid
 
-    # eligibility / registration from *filtered* nodes only
-    all_keys = jnp.all(has_key | ~valid[:, :, None], axis=1)  # [B, N]
-    ignored = feasible & ~all_keys
-    scored = feasible & all_keys
-    eligible = feasible & cluster.node_valid[None, :] & all_keys
-    elig_bc = jnp.broadcast_to(eligible[:, None, :], (B, C, N)).reshape(B * C, N)
-    members = _samepair_nodes(cluster, elig_bc, keys,
-                              active_keys=active_keys)      # [B*C, N]
-    registered = members > 0.5
+        # per-node match counts (hostname constraints read these directly)
+        node_counts = per_node_counts(m.reshape(B * C, -1), cluster.pod_node,
+                                      N).reshape(B, C, N)
+        # pair sums count only pods on PreScore-eligible nodes
+        # (reference: scoring.go:139-165 counts over filtered+affinity nodes)
+        cm_pods = jnp.take_along_axis(
+            count_nodes, jnp.clip(cluster.pod_node, 0, None)[None, :], axis=1)
+        cm_pods = cm_pods & (cluster.pod_node >= 0)[None, :]     # [B, P]
+        m_counted = (m & cm_pods[:, None, :]).reshape(B * C, -1)
+        cnt_pair = _samepair_pods_to_nodes(cluster, m_counted, keys,
+                                           cluster.pod_node, cluster.pod_valid,
+                                           active_keys=active_keys)
 
-    # distinct registered-pair count: each pair contributes
-    # sum-over-its-eligible-members of 1/members == exactly 1
-    inv = jnp.where(registered & elig_bc, 1.0 / jnp.maximum(members, 1.0),
-                    0.0)
-    topo_size = jnp.round(jnp.sum(inv, axis=1)).reshape(B, C)
-    n_scored = jnp.sum(_f(scored), axis=1)  # [B]
-    size = jnp.where(is_host, n_scored[:, None], topo_size)
-    weight = jnp.log(size + 2.0)  # reference: scoring.go:286
+        # eligibility / registration from *filtered* nodes only
+        all_keys = jnp.all(has_key | ~valid[:, :, None], axis=1)  # [B, N]
+        ignored = feasible & ~all_keys
+        scored = feasible & all_keys
+        eligible = feasible & cluster.node_valid[None, :] & all_keys
+        elig_bc = jnp.broadcast_to(eligible[:, None, :], (B, C, N)).reshape(B * C, N)
+        members = _samepair_nodes(cluster, elig_bc, keys,
+                                  active_keys=active_keys)      # [B*C, N]
+        registered = members > 0.5
 
-    pair_cnt = jnp.where(registered, cnt_pair, 0.0).reshape(B, C, N)
-    cnt = jnp.where(is_host[:, :, None], node_counts, pair_cnt)
-    # adjustForMaxSkew (scoring.go:294)
-    ms = cons.max_skew[:, :, None]
-    cnt = jnp.where(cnt < ms, ms - 1.0, cnt)
-    contrib = jnp.where((valid & cons.topo_known)[:, :, None] & has_key,
-                        cnt * weight[:, :, None], 0.0)
-    raw = jnp.floor(jnp.sum(contrib, axis=1))  # int64(score)
-    raw = jnp.where(ignored, 0.0, raw)
+        # distinct registered-pair count: each pair contributes
+        # sum-over-its-eligible-members of 1/members == exactly 1
+        inv = jnp.where(registered & elig_bc, 1.0 / jnp.maximum(members, 1.0),
+                        0.0)
+        topo_size = jnp.round(jnp.sum(inv, axis=1)).reshape(B, C)
+        n_scored = jnp.sum(_f(scored), axis=1)  # [B]
+        size = jnp.where(is_host, n_scored[:, None], topo_size)
+        weight = jnp.log(size + 2.0)  # reference: scoring.go:286
 
-    # NormalizeScore (scoring.go:210-257): min/max over non-ignored filtered
-    sel = scored
-    big = jnp.float32(2**62)
-    min_s = jnp.min(jnp.where(sel, raw, big), axis=1, keepdims=True)
-    max_s = jnp.max(jnp.where(sel, raw, -big), axis=1, keepdims=True)
-    max_s = jnp.maximum(max_s, 0.0)
-    norm = jnp.where(max_s > 0,
-                     _idiv(MAX_NODE_SCORE * (max_s + jnp.minimum(min_s, big)
-                                             - raw), jnp.maximum(max_s, 1.0)),
-                     MAX_NODE_SCORE)
-    out = jnp.where(ignored, 0.0, norm)
-    # no soft constraints => every filtered node scores MaxNodeScore (the
-    # reference's maxScore==0 branch)
-    has_any = jnp.any(valid, axis=1, keepdims=True)
-    out = jnp.where(has_any, out, MAX_NODE_SCORE)
-    return jnp.where(feasible, out, 0.0)
+        pair_cnt = jnp.where(registered, cnt_pair, 0.0).reshape(B, C, N)
+        cnt = jnp.where(is_host[:, :, None], node_counts, pair_cnt)
+        # adjustForMaxSkew (scoring.go:294)
+        ms = cons.max_skew[:, :, None]
+        cnt = jnp.where(cnt < ms, ms - 1.0, cnt)
+        contrib = jnp.where((valid & cons.topo_known)[:, :, None] & has_key,
+                            cnt * weight[:, :, None], 0.0)
+        raw = jnp.floor(jnp.sum(contrib, axis=1))  # int64(score)
+        raw = jnp.where(ignored, 0.0, raw)
+
+        # NormalizeScore (scoring.go:210-257): min/max over non-ignored filtered
+        sel = scored
+        big = jnp.float32(2**62)
+        min_s = jnp.min(jnp.where(sel, raw, big), axis=1, keepdims=True)
+        max_s = jnp.max(jnp.where(sel, raw, -big), axis=1, keepdims=True)
+        max_s = jnp.maximum(max_s, 0.0)
+        norm = jnp.where(max_s > 0,
+                         _idiv(MAX_NODE_SCORE * (max_s + jnp.minimum(min_s, big)
+                                                 - raw), jnp.maximum(max_s, 1.0)),
+                         MAX_NODE_SCORE)
+        out = jnp.where(ignored, 0.0, norm)
+        # no soft constraints => every filtered node scores MaxNodeScore (the
+        # reference's maxScore==0 branch)
+        has_any = jnp.any(valid, axis=1, keepdims=True)
+        out = jnp.where(has_any, out, MAX_NODE_SCORE)
+        return jnp.where(feasible, out, 0.0)
+
+    def dead():
+        # no valid constraint on any pod: has_any is False on every row
+        return jnp.where(feasible, jnp.float32(MAX_NODE_SCORE),
+                         jnp.float32(0.0))
+
+    return _if_live(jnp.any(cons.valid), live, dead)
 
 
 # ---------------------------------------------------------------------------
 # InterPodAffinity
 
 
-def _pod_term_matches_static(cluster, terms, B: int) -> jnp.ndarray:
-    """Selector x namespace match of pod-side terms against the pod axis —
-    the assignment-independent part of _pod_term_matches -> [B, T, P]."""
-    m = match_selectors(terms.sel, cluster.pod_kv, cluster.pod_key)  # [B*T, P]
+def _pod_term_matches(cluster, terms, B: int, pre=None) -> jnp.ndarray:
+    """Match pod-side affinity terms against existing pods -> [B, T, P]:
+    selector x namespace x pod_valid.  pre: the terms' unique-selector
+    match [U, P] (_pod_axis_pre), the assignment-independent part gang
+    mode hoists out of its rounds."""
+    if pre is None:
+        pre = _pod_axis_match(cluster, terms.sel)
     T = terms.valid.shape[1]
-    m = m.reshape(B, T, -1)
+    m = jnp.take(pre, terms.sel.index, axis=0).reshape(B, T, -1)
     ns_ok = jnp.einsum("btn,pn->btp", terms.ns_hot, cluster.pod_ns_hot,
                        preferred_element_type=jnp.float32) > 0.5
-    return m & ns_ok
-
-
-def _pod_term_matches(cluster, terms, B: int, pre=None) -> jnp.ndarray:
-    """Match pod-side affinity terms against existing pods -> [B, T, P]."""
-    if pre is None:
-        pre = _pod_term_matches_static(cluster, terms, B)
-    return pre & cluster.pod_valid[None, None, :]
+    return m & ns_ok & cluster.pod_valid[None, None, :]
 
 
 def existing_terms_match(terms, batch) -> jnp.ndarray:
@@ -547,19 +605,33 @@ def existing_terms_match(terms, batch) -> jnp.ndarray:
     return em & ens & terms.valid[:, None]
 
 
+def _owner_pairs(cluster, terms):
+    """(e_pair [E], owner_ok [E]) of existing pods' terms: the (key, value)
+    pair each term's owner pins on its node, -1 for an invalid term or an
+    owner that is gone or unplaced.  Gathers E rows, never the pod axis."""
+    owner = jnp.clip(terms.pod_idx, 0, None)
+    owner_node = jnp.take(cluster.pod_node, owner)
+    owner_ok = jnp.take(cluster.pod_valid, owner) & (owner_node >= 0)
+    owner_tp = jnp.take(cluster.topo_pair, jnp.clip(owner_node, 0, None),
+                        axis=0)  # [E, TK]
+    e_pair = jnp.take_along_axis(owner_tp, terms.topo_key[:, None],
+                                 axis=1)[:, 0]
+    return jnp.where(terms.valid & owner_ok, e_pair, -1), owner_ok
+
+
 class InterpodPre(NamedTuple):
     """Assignment-independent matches for interpod_filter, precomputable
     once for gang mode's per-round re-evaluation."""
-    m_ra: jnp.ndarray   # [B, Tr, P]
-    m_raa: jnp.ndarray  # [B, Ta, P]
+    m_ra: jnp.ndarray   # [Ur, P] unique-selector match (_pod_axis_pre)
+    m_raa: jnp.ndarray  # [Ua, P]
     em: jnp.ndarray     # [Et, B]
 
 
 def interpod_filter_pre(cluster, batch) -> InterpodPre:
-    B = batch.req.shape[0]
     return InterpodPre(
-        m_ra=_pod_term_matches_static(cluster, batch.ra, B),
-        m_raa=_pod_term_matches_static(cluster, batch.raa, B),
+        m_ra=_pod_axis_pre(cluster, batch.ra.sel, jnp.any(batch.ra.valid)),
+        m_raa=_pod_axis_pre(cluster, batch.raa.sel,
+                            jnp.any(batch.raa.valid)),
         em=existing_terms_match(cluster.filter_terms, batch))
 
 
@@ -575,41 +647,55 @@ def interpod_filter(cluster, batch,
     bootstrap branch (filtering.go:356) is what admits them."""
     B = batch.req.shape[0]
     N = cluster.allocatable.shape[0]
-    if pre is None:
-        pre = interpod_filter_pre(cluster, batch)
+    m_ra, m_raa, em = (None, None, None) if pre is None else pre
+    if em is None:
+        em = existing_terms_match(cluster.filter_terms, batch)  # [Et, B]
 
     # --- incoming required affinity (filtering.go:342 satisfyPodAffinity)
     ra = batch.ra
     Tr = ra.valid.shape[1]
-    m = _pod_term_matches(cluster, ra, B, pre=pre.m_ra)  # [B, T, P]
-    match_all = jnp.all(m | ~ra.valid[:, :, None], axis=1)  # [B, P]
-    has_ra = jnp.any(ra.valid, axis=1)  # [B]
     keys_r = jnp.where(ra.topo_known, ra.topo_key, -1).reshape(-1)
-    contrib = jnp.broadcast_to(match_all[:, None, :], m.shape).reshape(B * Tr, -1)
-    cnt = _samepair_pods_to_nodes(cluster, contrib, keys_r,
-                                  cluster.pod_node, cluster.pod_valid,
-                                  active_keys=active_keys)
+
+    def ra_live():
+        m = _pod_term_matches(cluster, ra, B, pre=m_ra)  # [B, T, P]
+        match_all = jnp.all(m | ~ra.valid[:, :, None], axis=1)  # [B, P]
+        contrib = jnp.broadcast_to(match_all[:, None, :],
+                                   m.shape).reshape(B * Tr, -1)
+        cnt = _samepair_pods_to_nodes(cluster, contrib, keys_r,
+                                      cluster.pod_node, cluster.pod_valid,
+                                      active_keys=active_keys)
+        # "matches anywhere" counts matching pods on key-carrying nodes over
+        # VALID terms only (the reference's topologyToMatchedAffinityTerms
+        # map has entries only for (term, key-bearing-node) pods).
+        pod_tp = jnp.take(cluster.topo_pair,
+                          jnp.clip(cluster.pod_node, 0, None),
+                          axis=0)  # [P, TK]
+        pod_keyed = (jnp.take(pod_tp.T, jnp.clip(keys_r, 0, None),
+                              axis=0) >= 0) \
+            & (keys_r >= 0)[:, None] \
+            & (cluster.pod_node >= 0)[None, :] & cluster.pod_valid[None, :]
+        # bool -> f32 cast, not where(mask, 1.0, 0.0): two Python-float
+        # branches COMMIT to the default float dtype, so the count silently
+        # becomes f64 wherever x64 is enabled (census/f64-promotion)
+        tot = jnp.sum((pod_keyed & contrib
+                       & ra.valid.reshape(-1)[:, None]).astype(jnp.float32),
+                      axis=1)  # [B*Tr]
+        return (cnt > 0.5).reshape(B, Tr, N), tot
+
+    def ra_dead():
+        # no valid term: term_ok is masked out of aff_ok on every row, and
+        # tot counts over valid terms only
+        return (jnp.zeros((B, Tr, N), bool),
+                jnp.zeros((B * Tr,), jnp.float32))
+
+    matched, tot = _if_live(jnp.any(ra.valid), ra_live, ra_dead)
+    has_ra = jnp.any(ra.valid, axis=1)  # [B]
     node_pair = node_topo_pairs(cluster, ra.topo_key.reshape(-1))  # [B*T, N]
     node_has_key = (node_pair >= 0).reshape(B, Tr, N) & ra.topo_known[:, :, None]
-    cnt = cnt.reshape(B, Tr, N)
-    term_ok = node_has_key & (cnt > 0.5)
+    term_ok = node_has_key & matched
     aff_ok = jnp.all(term_ok | ~ra.valid[:, :, None], axis=1)
     # bootstrap: no matches anywhere + pod matches its own terms
     # (filtering.go:356-366); node must still carry every topology key.
-    # "matches anywhere" counts matching pods on key-carrying nodes over
-    # VALID terms only (the reference's topologyToMatchedAffinityTerms map
-    # has entries only for (term, key-bearing-node) pods).
-    pod_tp = jnp.take(cluster.topo_pair, jnp.clip(cluster.pod_node, 0, None),
-                      axis=0)  # [P, TK]
-    pod_keyed = (jnp.take(pod_tp.T, jnp.clip(keys_r, 0, None), axis=0) >= 0) \
-        & (keys_r >= 0)[:, None] \
-        & (cluster.pod_node >= 0)[None, :] & cluster.pod_valid[None, :]
-    # bool -> f32 cast, not where(mask, 1.0, 0.0): two Python-float
-    # branches COMMIT to the default float dtype, so the count silently
-    # becomes f64 wherever x64 is enabled (census/f64-promotion)
-    tot = jnp.sum((pod_keyed & contrib
-                   & ra.valid.reshape(-1)[:, None]).astype(jnp.float32),
-                  axis=1)  # [B*Tr]
     no_matches = jnp.sum(tot.reshape(B, Tr), axis=1) < 0.5
     self_all = jnp.all(ra.self_match | ~ra.valid, axis=1) & has_ra
     all_keys = jnp.all(node_has_key | ~ra.valid[:, :, None], axis=1)
@@ -619,28 +705,28 @@ def interpod_filter(cluster, batch,
     # --- incoming required anti-affinity (filtering.go:329 satisfyPodAntiAffinity)
     raa = batch.raa
     Ta = raa.valid.shape[1]
-    ma = _pod_term_matches(cluster, raa, B, pre=pre.m_raa).reshape(B * Ta, -1)
     keys_a = jnp.where(raa.topo_known, raa.topo_key, -1).reshape(-1)
-    cnt_a = _samepair_pods_to_nodes(cluster, ma, keys_a,
-                                    cluster.pod_node, cluster.pod_valid,
-                                    active_keys=active_keys)
+
+    def raa_live():
+        ma = _pod_term_matches(cluster, raa, B,
+                               pre=m_raa).reshape(B * Ta, -1)
+        cnt_a = _samepair_pods_to_nodes(cluster, ma, keys_a,
+                                        cluster.pod_node, cluster.pod_valid,
+                                        active_keys=active_keys)
+        return (cnt_a > 0.5).reshape(B, Ta, N)
+
+    matched_a = _if_live(jnp.any(raa.valid), raa_live,
+                         lambda: jnp.zeros((B, Ta, N), bool))
     np_a = node_topo_pairs(cluster, raa.topo_key.reshape(-1))
     has_key_a = (np_a >= 0).reshape(B, Ta, N) & raa.topo_known[:, :, None]
-    cnt_a = cnt_a.reshape(B, Ta, N)
-    anti_fail = jnp.any(has_key_a & (cnt_a > 0.5) & raa.valid[:, :, None], axis=1)
+    anti_fail = jnp.any(has_key_a & matched_a & raa.valid[:, :, None], axis=1)
 
     # --- existing pods' required anti-affinity
     # (filtering.go:314 satisfyExistingPodsAntiAffinity): each term's owner
     # pins one (key, value) pair; a node fails iff it shares that pair and
     # the incoming pod matches the term — an [Et, B] x [Et, N] contraction
     ft = cluster.filter_terms
-    em = pre.em  # [Et, B]
-    e_pair = jnp.take_along_axis(pod_tp[jnp.clip(ft.pod_idx, 0, None)],
-                                 ft.topo_key[:, None], axis=1)[:, 0]  # [Et]
-    owner_ok = (jnp.take(cluster.pod_valid, jnp.clip(ft.pod_idx, 0, None))
-                & (jnp.take(cluster.pod_node,
-                            jnp.clip(ft.pod_idx, 0, None)) >= 0))
-    e_pair = jnp.where(ft.valid & owner_ok, e_pair, -1)
+    e_pair, _ = _owner_pairs(cluster, ft)  # [Et]
     node_pairs_e = jnp.take(cluster.topo_pair.T, ft.topo_key, axis=0)  # [Et, N]
     sp_rows = (node_pairs_e == e_pair[:, None]) & (e_pair >= 0)[:, None]
     exist_fail = jnp.einsum("eb,en->bn", em.astype(jnp.bfloat16),
@@ -654,14 +740,14 @@ def interpod_filter(cluster, batch,
 
 
 class InterpodScorePre(NamedTuple):
-    m_pref: jnp.ndarray  # [B, Tp, P]
+    m_pref: jnp.ndarray  # [Up, P] unique-selector match
     em: jnp.ndarray      # [Es, B]
 
 
 def interpod_score_pre(cluster, batch) -> InterpodScorePre:
-    B = batch.req.shape[0]
     return InterpodScorePre(
-        m_pref=_pod_term_matches_static(cluster, batch.pref, B),
+        m_pref=_pod_axis_pre(cluster, batch.pref.sel,
+                             jnp.any(batch.pref.valid)),
         em=existing_terms_match(cluster.score_terms, batch))
 
 
@@ -676,31 +762,32 @@ def interpod_score_raw(cluster, batch,
     normalization per round."""
     B = batch.req.shape[0]
     N = cluster.allocatable.shape[0]
-    if pre is None:
-        pre = interpod_score_pre(cluster, batch)
+    m_pref, em_s = (None, None) if pre is None else pre
+    if em_s is None:
+        em_s = existing_terms_match(cluster.score_terms, batch)  # [Es, B]
 
     # incoming pod's preferred terms vs existing pods
     pt = batch.pref
     T = pt.valid.shape[1]
-    m = _pod_term_matches(cluster, pt, B, pre=pre.m_pref)  # [B, T, P]
-    data = (_f(m) * pt.weight[:, :, None] * _f(pt.valid)[:, :, None])
-    keys_p = jnp.where(pt.topo_known, pt.topo_key, -1).reshape(-1)
-    raw1 = _samepair_pods_to_nodes(cluster, data.reshape(B * T, -1), keys_p,
-                                   cluster.pod_node, cluster.pod_valid,
-                                   active_keys=active_keys)
-    raw1 = jnp.sum(raw1.reshape(B, T, N), axis=1)  # [B, N]
+
+    def pref_live():
+        m = _pod_term_matches(cluster, pt, B, pre=m_pref)  # [B, T, P]
+        data = (_f(m) * pt.weight[:, :, None] * _f(pt.valid)[:, :, None])
+        keys_p = jnp.where(pt.topo_known, pt.topo_key, -1).reshape(-1)
+        raw1 = _samepair_pods_to_nodes(cluster, data.reshape(B * T, -1),
+                                       keys_p, cluster.pod_node,
+                                       cluster.pod_valid,
+                                       active_keys=active_keys)
+        return jnp.sum(raw1.reshape(B, T, N), axis=1)  # [B, N]
+
+    raw1 = _if_live(jnp.any(pt.valid), pref_live,
+                    lambda: jnp.zeros((B, N), jnp.float32))
 
     # existing pods' terms vs incoming pod: each term pins its owner-node's
     # (key, value) pair; nodes sharing it receive the term weight
     st = cluster.score_terms
-    owner_ok = (jnp.take(cluster.pod_valid, jnp.clip(st.pod_idx, 0, None))
-                & (jnp.take(cluster.pod_node,
-                            jnp.clip(st.pod_idx, 0, None)) >= 0))
-    em = _f(pre.em & owner_ok[:, None]) * st.weight[:, None]  # [Es, B]
-    pod_topo = jnp.take(cluster.topo_pair, jnp.clip(cluster.pod_node, 0, None), axis=0)
-    e_pair = jnp.take_along_axis(pod_topo[jnp.clip(st.pod_idx, 0, None)],
-                                 st.topo_key[:, None], axis=1)[:, 0]
-    e_pair = jnp.where(st.valid & owner_ok, e_pair, -1)
+    e_pair, owner_ok = _owner_pairs(cluster, st)
+    em = _f(em_s & owner_ok[:, None]) * st.weight[:, None]  # [Es, B]
     node_pairs_e = jnp.take(cluster.topo_pair.T, st.topo_key, axis=0)  # [Es, N]
     sp_rows = (node_pairs_e == e_pair[:, None]) & (e_pair >= 0)[:, None]
     raw2 = jnp.einsum("eb,en->bn", em.astype(jnp.bfloat16),
@@ -863,26 +950,50 @@ def prefer_avoid_pods_score(cluster, batch) -> jnp.ndarray:
     return jnp.where(avoided, 0.0, MAX_NODE_SCORE)
 
 
-def default_spread_match_ns(cluster, batch) -> jnp.ndarray:
+def _default_spread_live(batch) -> jnp.ndarray:
+    """Device bool: some pod of the batch can score a nonzero
+    DefaultPodTopologySpread count — it has a controller selector (a nil
+    selector matches nothing, selectors.py sel_valid) and does not skip
+    the plugin for explicit constraints of its own."""
+    sel = batch.spread_selector
+    return jnp.any(jnp.take(sel.sel_valid, sel.index) & ~batch.spread_skip)
+
+
+def _default_spread_match_ns(cluster, batch, pre=None) -> jnp.ndarray:
     """[B, P] DefaultPodTopologySpread selector x namespace match —
-    assignment-independent."""
-    m = match_selectors(batch.spread_selector, cluster.pod_kv, cluster.pod_key)
+    assignment-independent.  pre: the controller selectors' unique match
+    [U, P] (default_spread_match_ns)."""
+    if pre is None:
+        pre = _pod_axis_match(cluster, batch.spread_selector)
+    m = jnp.take(pre, batch.spread_selector.index, axis=0)
     ns_ok = jnp.einsum("bn,pn->bp", batch.ns_hot, cluster.pod_ns_hot,
                        preferred_element_type=jnp.float32) > 0.5
     return m & ns_ok
+
+
+def default_spread_match_ns(cluster, batch) -> jnp.ndarray:
+    """The hoisted pre of the controller selectors ([U, P],
+    _pod_axis_pre)."""
+    return _pod_axis_pre(cluster, batch.spread_selector,
+                         _default_spread_live(batch))
 
 
 def default_spread_score(cluster, batch, match_ns=None) -> jnp.ndarray:
     """DefaultPodTopologySpread raw score: count of same-namespace,
     non-terminating pods on the node matched by the combined controller
     selector (reference: default_pod_topology_spread.go:74-97, 200-215)."""
+    B = batch.spread_skip.shape[0]
     N = cluster.allocatable.shape[0]
-    if match_ns is None:
-        match_ns = default_spread_match_ns(cluster, batch)
-    countable = cluster.pod_valid & ~cluster.pod_terminating
-    m = match_ns & countable[None, :]
-    counts = per_node_counts(m, cluster.pod_node, N)
-    return jnp.where(batch.spread_skip[:, None], 0.0, counts)
+
+    def live():
+        m_ns = _default_spread_match_ns(cluster, batch, pre=match_ns)
+        countable = cluster.pod_valid & ~cluster.pod_terminating
+        counts = per_node_counts(m_ns & countable[None, :],
+                                 cluster.pod_node, N)
+        return jnp.where(batch.spread_skip[:, None], 0.0, counts)
+
+    return _if_live(_default_spread_live(batch), live,
+                    lambda: jnp.zeros((B, N), jnp.float32))
 
 
 ZONE_WEIGHTING = 2.0 / 3.0  # reference: default_pod_topology_spread.go:44

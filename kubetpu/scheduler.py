@@ -39,7 +39,7 @@ from .framework.interface import Code, CycleState, Status
 from .framework.runtime import Framework
 from .framework.types import NodeInfo, PodInfo, QueuedPodInfo
 from .models import programs
-from .models.batch import PodBatchBuilder
+from .models.batch import PodBatchBuilder, live_term_sets
 from .models.sequential import schedule_sequential
 from .plugins.intree import new_in_tree_registry
 from .schedqueue.queue import SchedulingQueue
@@ -909,8 +909,10 @@ class Scheduler:
                 np.asarray, pb.build(pinfos, spread_selectors=spread_sels))
             # valid DoNotSchedule constraint rows the builder compiled
             spread_rows = int(batch.spread.valid.sum())
+            term_sets_live = live_term_sets(batch)
             if build_span is not None:
                 build_span.args["spread_rows"] = spread_rows
+                build_span.args["term_sets_live"] = term_sets_live
         batch_dev = None
         if self._mesh is not None:
             # DOUBLE-BUFFERED transfer: start the sharded upload of this
@@ -955,6 +957,9 @@ class Scheduler:
             # constraint (C) and unique-selector (Us) buckets the
             # auction's recount runs over, padding included
             trace.rec.meta["spread_constraints"] = spread_rows
+            # the term sets whose existing-pod products this batch's
+            # auction runs (ops/kernels.py _if_live); the rest are gated off
+            trace.rec.meta["term_sets_live"] = term_sets_live
             trace.rec.meta["spread_buckets"] = [
                 int(batch.spread.valid.shape[1]),
                 int(batch.spread.sel.sel_valid.shape[0])]

@@ -749,3 +749,241 @@ def test_bench_rounds_hist():
     import bench
     assert bench._rounds_hist([1, 4, 4, 2, 4]) == {"1": 1, "2": 1, "4": 3}
     assert bench._rounds_hist([]) == {}
+
+
+# ---- PR 37: the term-set gates change no result.  Four batches through
+# the whole auction with the default plugins; the goldens were recorded
+# from the parent commit (08081cc), whose kernels run every set's
+# existing-pod products whatever the batch holds ----
+
+GATE_BATCHES = ("term-free", "spread-only", "anti-affinity-only",
+                "mixed-rows")
+
+
+def _gate_batch(kind):
+    """Twelve 1-cpu nodes in three zones with two residents each (some
+    with anti-affinity and preferred terms of their own), sixteen 300m
+    pods: two fit a node, so the auction takes several rounds."""
+    from kubetpu.harness import hollow
+    nodes = [mknode(name=f"n{i}", cpu="1", labels={
+        api.LABEL_HOSTNAME: f"n{i}", api.LABEL_ZONE: f"z{i % 3}"})
+        for i in range(12)]
+    existing = {}
+    for i, n in enumerate(nodes):
+        a = mkpod(name=f"e{i}a", labels={"app": "web", "color": "blue"})
+        b = mkpod(name=f"e{i}b", labels={"app": "db", "color": "red"})
+        if i % 4 == 0:
+            hollow.with_anti_affinity(a, match={"app": "cache"})
+        if i % 3 == 0:
+            b.spec.affinity = api.Affinity(pod_affinity=api.PodAffinity(
+                preferred_during_scheduling_ignored_during_execution=[
+                    api.WeightedPodAffinityTerm(
+                        weight=5, pod_affinity_term=api.PodAffinityTerm(
+                            label_selector=api.LabelSelector(
+                                match_labels={"color": "blue"}),
+                            topology_key=api.LABEL_ZONE))]))
+        existing[n.name] = [a, b]
+
+    def plain(i):
+        return mkpod(name=f"p{i:02d}", cpu="300m",
+                     labels={"app": "web", "color": "blue"})
+
+    def spread(i):
+        return hollow.with_spread(plain(i), api.LABEL_ZONE, max_skew=1,
+                                  match={"color": "blue"})
+
+    def anti(i):
+        p = plain(i)
+        p.metadata.labels["app"] = "cache"
+        return hollow.with_anti_affinity(p, api.LABEL_HOSTNAME,
+                                         match={"app": "cache"})
+
+    def soft(i):
+        return hollow.with_spread(plain(i), api.LABEL_HOSTNAME, max_skew=1,
+                                  when="ScheduleAnyway",
+                                  match={"app": "web"})
+
+    def affine(i):
+        return hollow.with_affinity(plain(i), api.LABEL_ZONE,
+                                    match={"app": "db"})
+
+    def prefers(i):
+        p = plain(i)
+        p.spec.affinity = api.Affinity(pod_anti_affinity=api.PodAntiAffinity(
+            preferred_during_scheduling_ignored_during_execution=[
+                api.WeightedPodAffinityTerm(
+                    weight=9, pod_affinity_term=api.PodAffinityTerm(
+                        label_selector=api.LabelSelector(
+                            match_labels={"app": "web"}),
+                        topology_key=api.LABEL_ZONE))]))
+        return p
+
+    make = {"term-free": [plain], "spread-only": [spread],
+            "anti-affinity-only": [anti],
+            "mixed-rows": [plain, spread, anti, soft, affine, prefers]}[kind]
+    pending = [make[i % len(make)](i) for i in range(16)]
+    return build(nodes, existing, pending,
+                 filters=programs.DEFAULT_FILTER_PLUGINS,
+                 scores=programs.DEFAULT_SCORE_PLUGINS)
+
+
+def _gate_result(kind, intra, window=512):
+    cluster, batch, cfg, _ = _gate_batch(kind)
+    g = gang.schedule_gang(cluster, batch, cfg, jax.random.PRNGKey(37),
+                           intra_batch_topology=intra,
+                           residual_window=window)
+    rows = np.packbits(np.asarray(g.unresolvable), axis=1)
+    return dict(chosen=np.asarray(g.chosen).tolist(),
+                score=np.asarray(g.score).tolist(),
+                rounds=int(g.rounds),
+                unres=[bytes(r).hex() for r in rows],
+                feas0=int(np.asarray(g.feasible0).sum()))
+
+
+GATE_GOLDENS = {('anti-affinity-only', False): {'chosen': [9, 9, 2, 3, 5, 6, 3, 7, 5, 11, 1,
+                                            11, 6, 2, 10, 1],
+                                 'feas0': 144,
+                                 'rounds': 4,
+                                 'score': [1000625.0, 1000625.0, 1000525.0,
+                                           1000625.0, 1000525.0, 1000625.0,
+                                           1000625.0, 1000525.0, 1000525.0,
+                                           1000525.0, 1000525.0, 1000525.0,
+                                           1000625.0, 1000480.0, 1000525.0,
+                                           1000525.0],
+                                 'unres': ['0000', '0000', '0000', '0000',
+                                           '0000', '0000', '0000', '0000',
+                                           '0000', '0000', '0000', '0000',
+                                           '0000', '0000', '0000', '0000']},
+ ('anti-affinity-only', True): {'chosen': [9, 10, 2, 3, 5, 6, -1, 7, -1, 11,
+                                           1, -1, -1, -1, -1, -1],
+                                'feas0': 144,
+                                'rounds': 3,
+                                'score': [1000625.0, 1000525.0, 1000525.0,
+                                          1000625.0, 1000525.0, 1000625.0,
+                                          0.0, 1000525.0, 0.0, 1000525.0,
+                                          1000525.0, 0.0, 0.0, 0.0, 0.0,
+                                          0.0],
+                                'unres': ['0000', '0000', '0000', '0000',
+                                          '0000', '0000', '0000', '0000',
+                                          '0000', '0000', '0000', '0000',
+                                          '0000', '0000', '0000', '0000']},
+ ('mixed-rows', False): {'chosen': [9, 9, 2, 3, 5, 6, 3, 0, 5, 8, 4, 11, 6, 0,
+                                    10, 1],
+                         'feas0': 183,
+                         'rounds': 3,
+                         'score': [1000625.0, 1000525.0, 1000525.0, 1000525.0,
+                                   1000525.0, 1000580.0, 1000625.0, 1000525.0,
+                                   1000525.0, 1000425.0, 1000525.0, 1000525.0,
+                                   1000625.0, 1000525.0, 1000525.0,
+                                   1000425.0],
+                         'unres': ['0000', '0000', '0000', '0000', '0000',
+                                   '0000', '0000', '0000', '0000', '0000',
+                                   '0000', '0000', '0000', '0000', '0000',
+                                   '0000']},
+ ('mixed-rows', True): {'chosen': [9, 10, 9, 3, 0, 6, 3, 4, 5, 0, 4, 11, 6, 2,
+                                   7, 1],
+                        'feas0': 183,
+                        'rounds': 5,
+                        'score': [1000625.0, 1000425.0, 1000580.0, 1000525.0,
+                                  1000625.0, 1000580.0, 1000625.0, 1000425.0,
+                                  1000525.0, 1000525.0, 1000525.0, 1000565.0,
+                                  1000625.0, 1000425.0, 1000525.0,
+                                  1000425.0],
+                        'unres': ['0000', '0000', '0000', '0000', '0000',
+                                  '0000', '0000', '0000', '0000', '0000',
+                                  '0000', '0000', '0000', '0000', '0000',
+                                  '0000']},
+ ('spread-only', False): {'chosen': [9, 9, 8, 3, 5, 6, 3, 0, 5, 8, 4, 11, 6,
+                                     0, 10, 1],
+                          'feas0': 192,
+                          'rounds': 4,
+                          'score': [1000525.0, 1000525.0, 1000425.0,
+                                    1000525.0, 1000425.0, 1000525.0,
+                                    1000525.0, 1000525.0, 1000425.0,
+                                    1000425.0, 1000425.0, 1000425.0,
+                                    1000525.0, 1000525.0, 1000425.0,
+                                    1000425.0],
+                          'unres': ['0000', '0000', '0000', '0000', '0000',
+                                    '0000', '0000', '0000', '0000', '0000',
+                                    '0000', '0000', '0000', '0000', '0000',
+                                    '0000']},
+ ('spread-only', True): {'chosen': [9, 10, 8, 3, 5, 4, 0, 7, 6, 11, 1, 2, 6,
+                                    5, 9, 1],
+                         'feas0': 192,
+                         'rounds': 12,
+                         'score': [1000525.0, 1000425.0, 1000425.0, 1000525.0,
+                                   1000425.0, 1000425.0, 1000525.0, 1000425.0,
+                                   1000525.0, 1000425.0, 1000425.0, 1000425.0,
+                                   1000480.0, 1000380.0, 1000480.0,
+                                   1000380.0],
+                         'unres': ['0000', '0000', '0000', '0000', '0000',
+                                   '0000', '0000', '0000', '0000', '0000',
+                                   '0000', '0000', '0000', '0000', '0000',
+                                   '0000']},
+ ('term-free', False): {'chosen': [9, 9, 8, 3, 5, 6, 3, 0, 5, 8, 4, 11, 6, 0,
+                                   10, 1],
+                        'feas0': 192,
+                        'rounds': 4,
+                        'score': [1000625.0, 1000625.0, 1000525.0, 1000625.0,
+                                  1000525.0, 1000625.0, 1000625.0, 1000625.0,
+                                  1000525.0, 1000525.0, 1000525.0, 1000525.0,
+                                  1000625.0, 1000625.0, 1000525.0,
+                                  1000525.0],
+                        'unres': ['0000', '0000', '0000', '0000', '0000',
+                                  '0000', '0000', '0000', '0000', '0000',
+                                  '0000', '0000', '0000', '0000', '0000',
+                                  '0000']},
+ ('term-free', True): {'chosen': [9, 9, 8, 3, 5, 6, 3, 0, 5, 8, 4, 11, 6, 0,
+                                  10, 1],
+                       'feas0': 192,
+                       'rounds': 4,
+                       'score': [1000625.0, 1000625.0, 1000525.0, 1000625.0,
+                                 1000525.0, 1000625.0, 1000625.0, 1000625.0,
+                                 1000525.0, 1000525.0, 1000525.0, 1000525.0,
+                                 1000625.0, 1000625.0, 1000525.0, 1000525.0],
+                       'unres': ['0000', '0000', '0000', '0000', '0000',
+                                 '0000', '0000', '0000', '0000', '0000',
+                                 '0000', '0000', '0000', '0000', '0000',
+                                 '0000']},
+ ('mixed-rows/window-4', False): {'chosen': [9, 9, 2, 3, 5, 6, 3, 0, 5, 8, 4,
+                                             11, 6, 0, 10, 1],
+                                  'feas0': 183,
+                                  'rounds': 3,
+                                  'score': [1000625.0, 1000525.0, 1000525.0,
+                                            1000525.0, 1000525.0, 1000580.0,
+                                            1000625.0, 1000525.0, 1000525.0,
+                                            1000425.0, 1000525.0, 1000525.0,
+                                            1000625.0, 1000525.0, 1000525.0,
+                                            1000425.0],
+                                  'unres': ['0000', '0000', '0000', '0000',
+                                            '0000', '0000', '0000', '0000',
+                                            '0000', '0000', '0000', '0000',
+                                            '0000', '0000', '0000', '0000']},
+ ('mixed-rows/window-4', True): {'chosen': [9, 10, 9, 3, 0, 6, 3, 5, 5, 8, 0,
+                                            7, 6, 4, 11, 1],
+                                 'feas0': 183,
+                                 'rounds': 5,
+                                 'score': [1000625.0, 1000425.0, 1000580.0,
+                                           1000525.0, 1000625.0, 1000580.0,
+                                           1000625.0, 1000425.0, 1000525.0,
+                                           1000425.0, 1000580.0, 1000541.0,
+                                           1000625.0, 1000425.0, 1000525.0,
+                                           1000425.0],
+                                 'unres': ['0000', '0000', '0000', '0000',
+                                           '0000', '0000', '0000', '0000',
+                                           '0000', '0000', '0000', '0000',
+                                           '0000', '0000', '0000', '0000']}}
+
+
+@pytest.mark.parametrize("intra", [True, False],
+                         ids=["intra-batch", "static"])
+@pytest.mark.parametrize("kind", GATE_BATCHES + ("mixed-rows/window-4",))
+def test_the_gates_leave_the_auctions_results_as_the_parent_had_them(
+        kind, intra):
+    # window-4: the residual rounds run over gathered rows of four pods,
+    # each round's gates reading the window's own rows
+    batch, _, window = kind.partition("/window-")
+    got = _gate_result(batch, intra, window=int(window or 512))
+    assert got == GATE_GOLDENS[kind, intra]
+    placed = [c for c in got["chosen"][:16] if c >= 0]
+    assert placed                      # the batch is not vacuous

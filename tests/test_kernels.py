@@ -1,6 +1,7 @@
 """Kernel golden tests: expectations derived from the reference plugins'
 documented algorithms and unit-test tables (values computed independently
 with integer arithmetic)."""
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -365,3 +366,312 @@ class TestSelect:
                         filters=FIT_ONLY, scores=LEAST)
         assert r.chosen[0] in (1, 2)
         assert r.scores[0, 1] == r.scores[0, 2] > r.scores[0, 0]
+
+
+# ---------------------------------------------------------------------------
+# the runtime gate of the six term sets (ops/kernels.py _if_live, PR 37):
+# everything a topology kernel does along the existing-pod axis for one set
+# runs only when the batch has a valid row in it, and what it returns
+# otherwise is what the live code computes for an all-invalid set.
+
+GATE_SETS = ("ra", "raa", "pref", "spread", "spread_soft", "default_spread")
+
+
+def _gate_world():
+    """Twelve nodes in three zones, 64 pod rows (40 resident pods, some with
+    anti-affinity and preferred terms of their own), and a batch of eight
+    pods that EACH carry a term of every set, with selectors the residents'
+    labels hit: masking a set's ``valid`` leaves real selectors behind the
+    invalid rows, as a padded slot never does."""
+    import random
+
+    import jax
+
+    from kubetpu.framework.types import NodeInfo, PodInfo
+    from kubetpu.harness import hollow
+    from kubetpu.models import programs
+    from kubetpu.models.batch import PodBatchBuilder
+    from kubetpu.state.tensors import SnapshotBuilder
+
+    rng = random.Random(37)
+    nodes = [mknode(f"n{i}", labels={api.LABEL_HOSTNAME: f"n{i}",
+                                     api.LABEL_ZONE: f"z{i % 3}"})
+             for i in range(12)]
+    infos = []
+    k = 0
+    for n in nodes:
+        ni = NodeInfo(n)
+        for _ in range(rng.randint(2, 5)):
+            k += 1
+            p = mkpod(f"e{k}", labels={"app": rng.choice(["web", "db"]),
+                                       "color": rng.choice(["blue", "red"])})
+            if k % 7 == 0:
+                hollow.with_anti_affinity(p, match={"app": "web"})
+            if k % 5 == 0:
+                p.spec.affinity = p.spec.affinity or api.Affinity()
+                p.spec.affinity.pod_affinity = api.PodAffinity(
+                    preferred_during_scheduling_ignored_during_execution=[
+                        api.WeightedPodAffinityTerm(
+                            weight=7, pod_affinity_term=api.PodAffinityTerm(
+                                label_selector=api.LabelSelector(
+                                    match_labels={"color": "blue"}),
+                                topology_key=api.LABEL_ZONE))])
+            p.spec.node_name = n.name
+            ni.add_pod(p)
+        infos.append(ni)
+    pending = []
+    for i in range(8):
+        p = mkpod(f"p{i}", labels={"app": "web", "color": "blue"})
+        hollow.with_affinity(p, api.LABEL_ZONE, match={"app": "db"})
+        hollow.with_anti_affinity(p, api.LABEL_HOSTNAME,
+                                  match={"color": "red"})
+        p.spec.affinity.pod_anti_affinity \
+            .preferred_during_scheduling_ignored_during_execution.append(
+                api.WeightedPodAffinityTerm(
+                    weight=3 + i, pod_affinity_term=api.PodAffinityTerm(
+                        label_selector=api.LabelSelector(
+                            match_labels={"app": "web"}),
+                        topology_key=api.LABEL_ZONE)))
+        hollow.with_spread(p, api.LABEL_ZONE, max_skew=2,
+                           match={"color": "blue"})
+        hollow.with_spread(p, api.LABEL_HOSTNAME, max_skew=1,
+                           when="ScheduleAnyway", match={"app": "web"})
+        pending.append(p)
+    sb = SnapshotBuilder()
+    pinfos = [PodInfo(p) for p in pending]
+    sb.intern_pending(pinfos)
+    cluster = sb.build(infos).to_device()
+    batch = jax.tree.map(np.asarray, PodBatchBuilder(sb.table).build(
+        pinfos, spread_selectors=[api.LabelSelector(
+            match_labels={"app": "web"})] * len(pending)))
+    cfg = programs.ProgramConfig(
+        hostname_topokey=sb.table.topokey.get(api.LABEL_HOSTNAME))
+    return cluster, batch, cfg
+
+
+@pytest.fixture(scope="module")
+def gate_world():
+    return _gate_world()
+
+
+def _mask_set(batch, name, rows):
+    """``batch`` with set ``name`` valid in ``rows`` only ("none", "one",
+    "all"); every other set stays valid on every pod."""
+    B = batch.valid.shape[0]
+    keep = {"none": np.zeros(B, bool), "one": np.arange(B) == 5,
+            "all": np.ones(B, bool)}[rows]
+    if name == "default_spread":
+        # a pod with explicit constraints skips the plugin; the world's
+        # pods all carry them, so the rows that count are un-skipped here
+        return batch._replace(spread_skip=~keep)
+    terms = getattr(batch, name)
+    return batch._replace(**{name: terms._replace(
+        valid=terms.valid & keep[:, None])})
+
+
+def _gate_outputs(name, intra):
+    """jit of the kernel whose pod-axis work set ``name`` gates, every
+    output; ``intra``: over the batch-extended pod axis with hoisted pres
+    (the round body's call), else on the plain cluster with none."""
+    import jax
+
+    from kubetpu.models import gang
+    from kubetpu.models.batch import densify_for
+    from kubetpu.ops import kernels as K
+
+    def run(cluster, batch, feasible):
+        batch = densify_for(cluster, batch)
+        cl = cluster
+        if intra:
+            B = batch.valid.shape[0]
+            cl = gang._extend_cluster(cluster, batch)
+            # three batch pods already admitted, as in a later round
+            placed = jnp.arange(B) < 3
+            cl = cl._replace(
+                pod_node=jnp.concatenate(
+                    [cluster.pod_node,
+                     jnp.where(placed, jnp.arange(B, dtype=jnp.int32), -1)]),
+                pod_valid=jnp.concatenate([cluster.pod_valid, placed]))
+        aff_ok = K.node_affinity_filter(cl, batch)
+        if name in ("ra", "raa"):
+            return K.interpod_filter(
+                cl, batch, return_no_matches=True,
+                pre=K.interpod_filter_pre(cl, batch) if intra else None)
+        if name == "pref":
+            pre = K.interpod_score_pre(cl, batch) if intra else None
+            return (K.interpod_score_raw(cl, batch, pre=pre),
+                    K.interpod_score(cl, batch, feasible, pre=pre))
+        if name == "spread":
+            return K.spread_filter(
+                cl, batch, aff_ok, return_slack=True,
+                match_ns=K.spread_match_ns(cl, batch, batch.spread)
+                if intra else None)
+        if name == "spread_soft":
+            return K.spread_soft_score(
+                cl, batch, feasible, aff_ok, 0,
+                match_ns=K.spread_match_ns(cl, batch, batch.spread_soft)
+                if intra else None)
+        return K.default_spread_score(
+            cl, batch,
+            match_ns=K.default_spread_match_ns(cl, batch) if intra else None)
+    return jax.jit(run)
+
+
+@pytest.fixture(scope="module")
+def gate_programs():
+    """(set, intra) -> (gated program, the same traced with the gate forced
+    live): one compile each serves the three row cases, because the
+    predicate is a runtime value."""
+    from kubetpu.ops import kernels as K
+    progs = {}
+
+    def get(name, intra):
+        if (name, intra) not in progs:
+            gated = _gate_outputs(name, intra)
+            forced = _gate_outputs(name, intra)
+
+            def forced_live(*a, _f=forced):
+                real = K._if_live
+                K._if_live = lambda live, live_fn, dead_fn: live_fn()
+                try:
+                    return _f(*a)       # traces on its first call
+                finally:
+                    K._if_live = real
+            progs[name, intra] = (gated, forced_live)
+        return progs[name, intra]
+    return get
+
+
+@pytest.mark.parametrize("intra", [True, False],
+                         ids=["intra-batch", "static"])
+@pytest.mark.parametrize("rows", ["none", "one", "all"])
+@pytest.mark.parametrize("name", GATE_SETS)
+def test_a_gated_kernel_equals_itself_forced_live(gate_world, gate_programs,
+                                                  name, rows, intra):
+    import jax
+    cluster, batch, _ = gate_world
+    masked = _mask_set(batch, name, rows)
+    B, N = batch.valid.shape[0], cluster.allocatable.shape[0]
+    feasible = jnp.asarray((np.arange(B * N).reshape(B, N) % 5) != 0)
+    gated, forced = gate_programs(name, intra)
+    got = jax.tree.leaves(gated(cluster, masked, feasible))
+    want = jax.tree.leaves(forced(cluster, masked, feasible))
+    assert len(got) == len(want) > 0
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert g.tobytes() == w.tobytes()
+    if rows == "all" and name in ("ra", "raa", "spread"):
+        # the world is not vacuous: the live set does fail some node
+        assert not np.asarray(got[0]).all()
+
+
+def _pod_axis_dots_outside_cond(jaxpr, P):
+    """(outside, inside): dot_generals with an operand or result dimension
+    of size P, split by whether a ``cond`` encloses them."""
+    outside, inside = [], []
+
+    def walk(jp, in_cond):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "dot_general":
+                shapes = [v.aval.shape for v in eqn.invars + eqn.outvars]
+                if any(P in s for s in shapes):
+                    (inside if in_cond else outside).append(shapes)
+            for v in eqn.params.values():
+                subs = v if isinstance(v, (list, tuple)) else [v]
+                for sub in subs:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, in_cond or eqn.primitive.name == "cond")
+    walk(jaxpr.jaxpr, False)
+    return outside, inside
+
+
+def _gated_calls(cluster, batch):
+    """name -> thunk of the five kernels and the five hoisted pres, with no
+    pre handed in (so the match itself is traced too)."""
+    from kubetpu.models.batch import densify_for
+    from kubetpu.ops import kernels as K
+    b = densify_for(cluster, batch)
+    B, N = b.valid.shape[0], cluster.allocatable.shape[0]
+    feas = jnp.ones((B, N), bool)
+    return {
+        "interpod_filter": lambda c: K.interpod_filter(c, b),
+        "interpod_score_raw": lambda c: K.interpod_score_raw(c, b),
+        "spread_filter": lambda c: K.spread_filter(c, b, feas),
+        "spread_soft_score":
+            lambda c: K.spread_soft_score(c, b, feas, feas, 0),
+        "default_spread_score": lambda c: K.default_spread_score(c, b),
+        "interpod_filter_pre": lambda c: K.interpod_filter_pre(c, b),
+        "interpod_score_pre": lambda c: K.interpod_score_pre(c, b),
+        "spread_match_ns(hard)":
+            lambda c: K.spread_match_ns(c, b, b.spread),
+        "spread_match_ns(soft)":
+            lambda c: K.spread_match_ns(c, b, b.spread_soft),
+        "default_spread_match_ns":
+            lambda c: K.default_spread_match_ns(c, b),
+    }
+
+
+@pytest.mark.parametrize("name", [
+    "interpod_filter", "interpod_score_raw", "spread_filter",
+    "spread_soft_score", "default_spread_score", "interpod_filter_pre",
+    "interpod_score_pre", "spread_match_ns(hard)", "spread_match_ns(soft)",
+    "default_spread_match_ns"])
+def test_no_pod_axis_product_sits_outside_a_gate(gate_world, name):
+    import jax
+    cluster, batch, _ = gate_world
+    P = cluster.pod_valid.shape[0]
+    # the pod axis must be told apart by its size alone
+    other = {d for x in jax.tree.leaves((cluster, batch))
+             for d in np.shape(x)} - {P}
+    assert P == 64 and P not in (cluster.allocatable.shape[0],
+                                 batch.valid.shape[0])
+    assert all(x.shape[0] == P for x in (cluster.pod_kv, cluster.pod_node))
+    assert P not in {cluster.kv.shape[1], cluster.keymask.shape[1],
+                     cluster.pod_ns_hot.shape[1]}, other
+    jaxpr = jax.make_jaxpr(_gated_calls(cluster, batch)[name])(cluster)
+    outside, inside = _pod_axis_dots_outside_cond(jaxpr, P)
+    assert outside == []
+    assert inside                 # and the gate does hold such products
+
+
+def test_the_gate_stays_a_cond_under_preemptions_candidate_vmap(gate_world):
+    """preemption._whatif_reprieve maps run_filters over candidates'
+    pod_valid; the batch is not mapped, so each gate's predicate is not,
+    and the cond is not lowered to a select over both branches."""
+    import jax
+
+    from kubetpu.models import programs
+    from kubetpu.models.batch import densify_for
+    cluster, batch, cfg = gate_world
+    b = densify_for(cluster, batch)
+    P = cluster.pod_valid.shape[0]
+
+    def one(pod_valid):
+        return programs.run_filters(
+            cluster._replace(pod_valid=pod_valid), b, cfg)[0]
+
+    def conds(jaxpr):
+        n = 0
+
+        def walk(jp):
+            nonlocal n
+            for eqn in jp.eqns:
+                n += eqn.primitive.name == "cond"
+                for v in eqn.params.values():
+                    for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            walk(sub)
+        walk(jaxpr.jaxpr)
+        return n
+
+    plain = jax.make_jaxpr(one)(cluster.pod_valid)
+    mapped = jax.make_jaxpr(jax.vmap(one))(
+        jnp.stack([cluster.pod_valid] * 3))
+    # required affinity, required anti-affinity, the hard spread filter
+    assert conds(plain) == 3
+    assert conds(mapped) == 3
+    outside, inside = _pod_axis_dots_outside_cond(mapped, P)
+    assert outside == [] and inside

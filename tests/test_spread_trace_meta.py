@@ -15,12 +15,13 @@ from kubetpu.scheduler import Scheduler
 from kubetpu.utils import trace as utrace
 
 
-def _cycle_of(pods):
+def _cycle_of(pods, others=()):
     """The record of the one gang cycle that places ``pods`` on twelve
-    nodes in three zones."""
+    nodes in three zones; ``others``: objects (Services, ...) the store
+    holds before the pods arrive."""
     store = ClusterStore()
-    for node in hollow.make_nodes(12, zones=3):
-        store.add(node)
+    for obj in list(hollow.make_nodes(12, zones=3)) + list(others):
+        store.add(obj)
     utrace.disarm_flight_recorder()
     flight = utrace.arm_flight_recorder(capacity=8, max_spans_per_cycle=64)
     sched = Scheduler(store, config=KubeSchedulerConfiguration(
