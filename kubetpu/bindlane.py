@@ -24,6 +24,8 @@ import queue
 import threading
 from typing import Callable, List, Optional
 
+from .utils import trace as utrace
+
 
 class BindJob:
     """One hand-over: the binds of one cycle (or of one singly committed
@@ -31,8 +33,8 @@ class BindJob:
     the pool instead.  Duck-types the part of ``Future`` that
     ``Scheduler.wait_for_inflight_binds`` uses."""
 
-    __slots__ = ("entries", "flight", "span", "lane_ok", "pooled",
-                 "error", "_applied")
+    __slots__ = ("entries", "flight", "span", "handed_t", "lane_ok",
+                 "pooled", "error", "_applied")
 
     def __init__(self, flight=None, lane_ok: bool = True):
         # (fwk, qp, state, assumed, node_name, slo, row) a pod, batch order
@@ -41,6 +43,9 @@ class BindJob:
         # (its ``binds_pooled`` arg), both None disarmed
         self.flight = flight
         self.span = None
+        # armed: wallclock() as the lane's queue took the job (``wake_s``
+        # of its ``bind-job`` span counts from here)
+        self.handed_t = 0.0
         # False: nothing of this hand-over may ride the lane (a remote
         # Bind client, an armed chaos bind fault)
         self.lane_ok = lane_ok
@@ -98,6 +103,8 @@ class BindLane:
                 self._thread = threading.Thread(
                     target=self._loop, daemon=True, name=self._name)
                 self._thread.start()
+            if job.flight is not None:
+                job.handed_t = utrace.wallclock()
             self._jobs.put(job)
         return True
 

@@ -876,28 +876,31 @@ class Scheduler:
                 # and diverge from the span-based traceview digest
                 self.delta_rows.append(dstats.delta_rows)
                 self.delta_cycle_count += 1
-            # this cycle's existing-pod rows by uid: a copy, the next
-            # refresh moves the tensorizer's own
-            cycle_pod_rows = dict(delta.pod_row)
-            chain_pod_uids = delta.pod_uid_list()
-            # journal capture seam (state/delta.py): the exact resync
-            # snapshot / delta tables / zero-dirty marker this refresh
-            # applied — None when the journal is disarmed
-            journal_input = delta.take_capture()
-            if journal_input is not None:
-                if (fwk.profile_name in self._journal_force_anchor
-                        and journal_input[0] != "resync"):
-                    # THIS profile's discarded cycle applied a
-                    # delta/resync capture that never journaled, so its
-                    # resident is ahead of the journal stream —
-                    # re-anchor from the mirror (bit-equal to the
-                    # resident after any successful refresh, the
-                    # anti-entropy verifier's invariant).  The capture
-                    # format is owned by ONE site: the tensorizer's own
-                    # resync seam
-                    delta._capture_resync()
-                    journal_input = delta.take_capture()
-                self._journal_force_anchor.discard(fwk.profile_name)
+            with trace.stage("row-maps") as maps_span:
+                # this cycle's existing-pod rows by uid: a copy, the next
+                # refresh moves the tensorizer's own
+                cycle_pod_rows = dict(delta.pod_row)
+                chain_pod_uids = delta.pod_uid_list()
+                if maps_span is not None:
+                    maps_span.args["pod_rows"] = len(cycle_pod_rows)
+                # journal capture seam (state/delta.py): the exact resync
+                # snapshot / delta tables / zero-dirty marker this refresh
+                # applied — None when the journal is disarmed
+                journal_input = delta.take_capture()
+                if journal_input is not None:
+                    if (fwk.profile_name in self._journal_force_anchor
+                            and journal_input[0] != "resync"):
+                        # THIS profile's discarded cycle applied a
+                        # delta/resync capture that never journaled, so
+                        # its resident is ahead of the journal stream —
+                        # re-anchor from the mirror (bit-equal to the
+                        # resident after any successful refresh, the
+                        # anti-entropy verifier's invariant).  The
+                        # capture format is owned by ONE site: the
+                        # tensorizer's own resync seam
+                        delta._capture_resync()
+                        journal_input = delta.take_capture()
+                    self._journal_force_anchor.discard(fwk.profile_name)
             with self._chain_lock:
                 self._chain = None
             self._drop_chain_residency()
@@ -1519,8 +1522,9 @@ class Scheduler:
         recheck_s, reserve_s, assume_s, permit_s, submit_s (the stamp and
         the append a pod, plus the hand-over), records_s (decision audit,
         SLO prefix, the cycle context's note), pods, loop_s, loop_cpu_s --
-        not as a span a pod; _hand_over adds bind_jobs and
-        binds_pooled."""
+        not as a span a pod; _hand_over adds bind_jobs, binds_pooled and
+        handover_wait_s.  loop_s is the loop's last stamp less its first,
+        so the six sums add up to it."""
         fwk, trace = prep.fwk, prep.trace
         live, states, pinfos = prep.live, prep.states, prep.pinfos
         node_infos, cycle_ctx = prep.node_infos, prep.cycle_ctx
@@ -1645,7 +1649,7 @@ class Scheduler:
                 assume_s=round(acc[2], 6), permit_s=round(acc[3], 6),
                 submit_s=round(acc[4], 6), records_s=round(acc[5], 6),
                 pods=len(live) - len(deferred),
-                loop_s=round(time.perf_counter() - loop_t0, 6),
+                loop_s=round(acc[-1] - loop_t0, 6),
                 loop_cpu_s=round(time.thread_time() - loop_cpu0, 6))
         # ---- preemption WAVE: every preemption-eligible FitError of this
         # cycle is served by ONE batched what-if (preemption.preempt_wave)
@@ -2302,7 +2306,9 @@ class Scheduler:
         pods it holds are assumed.  Counts the hand-over on the open
         phase's span (``commit``: args ``bind_jobs``, ``binds_pooled``),
         which the lane keeps counting into when it sends a bind of the
-        job to the pool after all (_bind_cycle)."""
+        job to the pool after all (_bind_cycle), and on the same span the
+        seconds the hand-over waited in ``BindLane.submit``
+        (``handover_wait_s``)."""
         span = job.span = Trace.open_span()
         if span is not None:
             a = span.args
@@ -2312,13 +2318,24 @@ class Scheduler:
             job.applied()
             if not job.pooled:
                 return
-        elif not self._bind_lane.submit(job):
-            # close() raced the serving loop: apply the job here, so the
-            # placements still land
-            try:
-                self._run_bind_job(job)
-            finally:
-                job.applied()
+        else:
+            if span is None:
+                handed = self._bind_lane.submit(job)
+            else:
+                # the wait for the job before this one (bindlane: the
+                # lane holds one at a time); ``submit_s`` contains it
+                t_wait = time.perf_counter()
+                handed = self._bind_lane.submit(job)
+                a["handover_wait_s"] = round(
+                    a.get("handover_wait_s", 0.0)
+                    + time.perf_counter() - t_wait, 6)
+            if not handed:
+                # close() raced the serving loop: apply the job here, so
+                # the placements still land
+                try:
+                    self._run_bind_job(job)
+                finally:
+                    job.applied()
         with self._bind_jobs_lock:
             self._bind_jobs = [j for j in self._bind_jobs if not j.done()]
             self._bind_jobs.append(job)
@@ -2329,8 +2346,16 @@ class Scheduler:
         pod of ``job`` in batch order, then ONE settling of what they owe
         the cache's and the histograms' locks.  A bind that raises is
         logged and kept for ``wait_for_inflight_binds``; the rest of the
-        job still binds."""
+        job still binds.  Armed, the job leaves ONE span ``bind-job`` on
+        its cycle's record, child of the ``commit`` span that handed it
+        over: args ``pods``, ``cpu_s`` (this thread's), ``settle_s``,
+        ``wake_s`` (hand-over to here), ``pooled`` (binds sent on to the
+        pool), ``gc_s`` / ``gc_full``."""
         fold = BindFold(job)
+        # armed: the job's own span on its cycle's record (utrace.JobSpan)
+        js = (utrace.JobSpan(job.flight, job.span, job.handed_t)
+              if job.flight is not None else None)
+        pooled0 = len(job.pooled)
         try:
             for fwk, qp, state, assumed, node_name, slo, row in job.entries:
                 try:
@@ -2343,7 +2368,12 @@ class Scheduler:
                         qp.pod.metadata.name)
                     job.error = job.error or e
         finally:
+            if js is not None:
+                js.settling()
             self._settle_bind_fold(fold)
+            if js is not None:
+                js.close(pods=len(job.entries),
+                         pooled=len(job.pooled) - pooled0)
 
     def _settle_bind_fold(self, fold: BindFold) -> None:
         """FinishBinding and the bind metrics for everything ``fold``

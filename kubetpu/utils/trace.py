@@ -36,8 +36,10 @@ from __future__ import annotations
 import array
 import collections
 import contextlib
+import gc
 import logging
 import os
+import re
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -296,11 +298,14 @@ class CycleRecord:
             span.t1 = t1
         return span
 
-    def event(self, name: str, parent_id: int = 0, **args) -> None:
+    def event(self, name: str, parent_id: int = 0,
+              ts: Optional[float] = None, **args) -> None:
         """Record an instant event (ph "i" in the Chrome export) — used
         for recompiles fed by the sanitize watchdog.  Capped like spans
-        (a recompile storm must not balloon the record); drops count."""
-        ev = {"name": name, "ts": wallclock(), "parent": parent_id,
+        (a recompile storm must not balloon the record); drops count.
+        ts: when it happened, where that is not now."""
+        ev = {"name": name, "ts": ts if ts is not None else wallclock(),
+              "parent": parent_id,
               "thread": threading.current_thread().name,
               "args": dict(args)}
         with self._lock:
@@ -377,6 +382,18 @@ class FlightRecorder:
         self._ring: collections.deque = collections.deque()  # kubelint: guarded-by(_lock)
         self._dropped = 0    # kubelint: guarded-by(_lock)
         self._seq = 0        # kubelint: guarded-by(_lock)
+        # interpreter accounting (note_interpreter): the last reading of
+        # every live thread's CPU clock and when it was taken
+        self._thread_cpu: Dict[threading.Thread, float] = {}  # kubelint: guarded-by(_lock)
+        self._thread_cpu_t = 0.0    # kubelint: guarded-by(_lock)
+        # running sums of the collector hook (_on_gc), which takes no
+        # lock: collections never overlap, so each has one writer at a
+        # time.  gc_other_s: pauses on threads with no phase or bind job
+        # open; the _seen twins are what the cycles before took
+        self.gc_other_s = 0.0
+        self.gc_collections = 0
+        self._gc_other_seen = 0.0   # kubelint: guarded-by(_lock)
+        self._gc_seen = 0           # kubelint: guarded-by(_lock)
 
     def begin_cycle(self, label: str,
                     queue_depths: Optional[Dict[str, int]] = None,
@@ -398,6 +415,44 @@ class FlightRecorder:
             while len(self._ring) > self.capacity:
                 self._ring.popleft()
                 self._dropped += 1
+
+    def note_interpreter(self, rec: CycleRecord) -> None:
+        """Who held the interpreter since the cycle before (Trace.finish
+        calls this on the serving thread): meta ``thread_cpu_s`` =
+        {thread name: CPU seconds} of every live Python thread above 0.1
+        ms, pool threads summed under their prefix (_fold_name), with
+        ``thread_cpu_window_s``, the wall seconds the two readings are
+        apart -- both absent on the first cycle after arming and where
+        the platform has no per-thread CPU clock; ``gc_other_s``, the
+        collector's pauses on threads with no phase or bind job open, and
+        ``gc_collections``, its passes on any thread, where not zero.  A
+        thread that ends between two readings takes its last slice with
+        it."""
+        now = time.perf_counter()
+        cpu = _read_thread_cpu()
+        with self._lock:
+            last, last_t = self._thread_cpu, self._thread_cpu_t
+            if cpu is not None:
+                self._thread_cpu, self._thread_cpu_t = cpu, now
+            other, n = self.gc_other_s, self.gc_collections
+            d_other, d_n = other - self._gc_other_seen, n - self._gc_seen
+            self._gc_other_seen, self._gc_seen = other, n
+        if d_other > 0.0:
+            rec.meta["gc_other_s"] = round(d_other, 6)
+        if d_n:
+            rec.meta["gc_collections"] = d_n
+        if cpu is None or not last_t:
+            return
+        by_name: Dict[str, float] = {}
+        for t, c in cpu.items():
+            # a thread new since the last reading spent all it has since
+            d = c - last.get(t, 0.0)
+            if d > 1e-4:
+                name = _fold_name(t.name)
+                by_name[name] = by_name.get(name, 0.0) + d
+        rec.meta["thread_cpu_s"] = {k: round(v, 6)
+                                    for k, v in by_name.items()}
+        rec.meta["thread_cpu_window_s"] = round(now - last_t, 6)
 
     def cycles(self) -> List[CycleRecord]:
         with self._lock:
@@ -576,6 +631,103 @@ class FlightRecorder:
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
+_POOL_THREAD = re.compile(r"^(.+)_\d+$")
+
+
+def _fold_name(name: str) -> str:
+    """``binder_3`` -> ``binder_pool``: the threads of a
+    ThreadPoolExecutor are one contender for the interpreter."""
+    m = _POOL_THREAD.match(name)
+    return f"{m.group(1)}_pool" if m else name
+
+
+def _read_thread_cpu() -> Optional[Dict[threading.Thread, float]]:
+    """The CPU seconds of every live Python thread so far, each from its
+    own CPU-time clock (two system calls a thread); None where the
+    platform has no such clock."""
+    clock_of = getattr(time, "pthread_getcpuclockid", None)
+    if clock_of is None:
+        return None
+    out = {}
+    for t in threading.enumerate():
+        try:
+            out[t] = time.clock_gettime(clock_of(t.ident))
+        except (OSError, TypeError, OverflowError):
+            pass            # it ended since enumerate() listed it
+    return out
+
+
+# ------------------------------------------------------- the collector hook
+#
+# While the recorder is armed ONE function sits in gc.callbacks.  It adds
+# every collection's pause (and, for generation 2, one count) to the
+# running sums of the thread the collection ran on; a phase or a bind job
+# reads them as it opens and as it closes, and what its thread gathered
+# in between becomes its args ``gc_s`` / ``gc_full``.  Collections run
+# under the interpreter lock and never overlap, so a slot a thread is
+# enough and the hook takes no lock.  It must not: a collection can start
+# between any two bytecodes, also inside a CycleRecord's lock, so a full
+# collection's ``gc`` event is only noted here and recorded when the
+# phase or the job closes (_flush_gc_events).
+
+
+class _GcSums:
+    __slots__ = ("seconds", "full", "t0", "events")
+
+    def __init__(self):
+        self.seconds, self.full, self.t0 = 0.0, 0, 0.0
+        self.events: List[Tuple[float, float, int]] = []
+
+
+def _gc_sums() -> _GcSums:
+    g = getattr(_tls, "gc", None)
+    if g is None:
+        g = _tls.gc = _GcSums()
+    return g
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    g = _gc_sums()
+    if phase == "start":
+        g.t0 = time.perf_counter()
+        return
+    pause = time.perf_counter() - g.t0
+    full = info["generation"] == 2
+    g.seconds += pause
+    g.full += full
+    fr = _flight
+    if fr is None:
+        return
+    fr.gc_collections += 1
+    if (getattr(_tls, "phase", None) is None
+            and getattr(_tls, "job", None) is None):
+        fr.gc_other_s += pause
+    elif full:
+        g.events.append((wallclock(), pause, info["collected"]))
+
+
+def _gc_args(g: _GcSums, s0: float, full0: int,
+             args: Dict[str, Any]) -> None:
+    """What the thread's collector sums gained since (s0, full0), as
+    ``gc_s`` and ``gc_full`` (absent when zero)."""
+    if g.seconds > s0:
+        args["gc_s"] = round(g.seconds - s0, 6)
+        if g.full > full0:
+            args["gc_full"] = g.full - full0
+
+
+def _flush_gc_events(g: _GcSums, rec: Optional[CycleRecord],
+                     parent_id: int) -> None:
+    """The full collections this thread noted since the last flush, as
+    ``gc`` events of ``rec`` (dropped where there is no cycle open)."""
+    if g.events:
+        evs, g.events = g.events, []
+        if rec is not None:
+            for ts, pause, collected in evs:
+                rec.event("gc", parent_id=parent_id, ts=ts, generation=2,
+                          seconds=round(pause, 6), collected=collected)
+
+
 # module arming state.  The reference is read WITHOUT a lock on the hot
 # path (Trace.__init__): rebinding a Python reference is atomic, a racing
 # reader sees either the old or the new recorder, and the disarmed fast
@@ -594,13 +746,15 @@ def arm_flight_recorder(capacity: Optional[int] = None,
                         max_spans_per_cycle: Optional[int] = None
                         ) -> FlightRecorder:
     """Idempotently arm the flight recorder (returns the existing one if
-    already armed)."""
+    already armed) and hook the collector (_on_gc), once."""
     global _flight
     with _flight_lock:
         if _flight is None:
             _flight = FlightRecorder(
                 capacity=capacity,
                 max_spans_per_cycle=max_spans_per_cycle)
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
         return _flight
 
 
@@ -608,6 +762,8 @@ def disarm_flight_recorder() -> None:
     global _flight
     with _flight_lock:
         _flight = None
+        if _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
 
 
 def maybe_arm_from_env() -> Optional[FlightRecorder]:
@@ -677,7 +833,7 @@ class _Phase:
     attach args before the exit closes it."""
 
     __slots__ = ("name", "rec", "span", "ann", "t0", "t1", "cpu0", "cpu_s",
-                 "args", "closed")
+                 "args", "closed", "gc0_s", "gc0_full")
 
     def __init__(self, name: str, ann: str, rec: Optional[CycleRecord],
                  parent_id: int, args: Dict[str, Any]):
@@ -686,6 +842,8 @@ class _Phase:
         self.t0 = self.t1 = self.cpu_s = 0.0
         self.closed = False
         if _flight is not None or rec is not None:
+            g = _gc_sums()
+            self.gc0_s, self.gc0_full = g.seconds, g.full
             self.cpu0 = time.thread_time()
             self.t0 = wallclock()
         if rec is not None:
@@ -713,9 +871,15 @@ class _Phase:
         if self.ann is not None:
             self.ann.__exit__(None, None, None)
             self.ann = None
+        g = None
         if self.cpu0 is not None:
             self.cpu_s = time.thread_time() - self.cpu0
             self.t1 = wallclock()
+            if getattr(_tls, "phase", None) is self:    # on its own thread
+                # a pop's args reach its span later (Trace.__init__)
+                g = _gc_sums()
+                _gc_args(g, self.gc0_s, self.gc0_full,
+                         self.args if self.span is None else self.span.args)
         if self.rec is not None:
             try:
                 _span_stack().remove((self.rec, self.span))
@@ -724,6 +888,9 @@ class _Phase:
             if self.span is not None:
                 self.span.args["cpu_s"] = round(self.cpu_s, 6)
                 self.span.t1 = self.t1
+        if g is not None:
+            _flush_gc_events(g, self.rec, self.span.span_id
+                             if self.span is not None else 0)
         if getattr(_tls, "phase", None) is self:
             _tls.phase = None
 
@@ -757,6 +924,73 @@ def begin_pop():
             cur.args["teardown_s"] = round(wallclock() - cur.t0, 6)
         return cur
     return _open_phase("pop", "pop", None, 0, {})
+
+
+# ------------------------------------------------------------------ bind job
+#
+# The binder lane works off the serving thread's partition, under the
+# next cycle's phases, so its job is no phase: ONE finished span
+# ``bind-job`` on the record of the cycle whose binds it applies, written
+# by the thread that ran it when it is done (the record is in the ring by
+# then, as it is when the bind table's stamps land).  In a capture the
+# job opens one TraceAnnotation too, "Binding:bind-job" -- NOT under
+# CYCLE_TRACE's prefix, which the capture's readers take as the serving
+# thread's partition.
+JOB_SPAN = "bind-job"
+JOB_TRACE = "Binding"
+
+
+class JobSpan:
+    """A bind job being run, armed (Scheduler._run_bind_job): its wall
+    extent, the running thread's CPU seconds over it (wall - cpu is time
+    the thread was blocked on the interpreter, a lock or a wake-up) and
+    the collector's pauses on that thread."""
+
+    __slots__ = ("rec", "parent_id", "ann", "t0", "cpu0", "gc0_s",
+                 "gc0_full", "args", "t_settle")
+
+    def __init__(self, rec: CycleRecord, parent: Optional[FlightSpan],
+                 handed_t: float = 0.0):
+        self.rec = rec
+        self.parent_id = parent.span_id if parent is not None else 0
+        self.ann = None
+        if _PROFILE_ACTIVE:
+            import jax
+            self.ann = jax.profiler.TraceAnnotation(
+                f"{JOB_TRACE}:{JOB_SPAN}")
+            self.ann.__enter__()
+        _tls.job = self
+        g = _gc_sums()
+        self.gc0_s, self.gc0_full = g.seconds, g.full
+        self.t_settle = 0.0
+        self.cpu0 = time.thread_time()
+        self.t0 = wallclock()
+        # handed_t: BindLane.submit's stamp as it queued the job; 0.0 for
+        # a job its own hand-over runs (close() raced the lane)
+        self.args = ({"wake_s": round(self.t0 - handed_t, 6)}
+                     if handed_t else {})
+
+    def settling(self) -> None:
+        """The binds are done; what follows is the fold's settling."""
+        self.t_settle = wallclock()
+
+    def close(self, **args) -> None:
+        t1 = wallclock()
+        a = self.args
+        a["cpu_s"] = round(time.thread_time() - self.cpu0, 6)
+        if self.t_settle:
+            a["settle_s"] = round(t1 - self.t_settle, 6)
+        a.update(args)
+        g = _gc_sums()
+        _gc_args(g, self.gc0_s, self.gc0_full, a)
+        _tls.job = None
+        span = self.rec.record_span(JOB_SPAN, self.t0, t1,
+                                    parent_id=self.parent_id, **a)
+        _flush_gc_events(g, self.rec,
+                         span.span_id if span is not None else 0)
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+            self.ann = None
 
 
 # --------------------------------------------------------------------- Trace
@@ -862,6 +1096,7 @@ class Trace:
                 rec.meta.update(meta)
             CycleRecord.end_span(self._root)
             rec.t1 = wallclock()
+            fr.note_interpreter(rec)
             fr.commit_cycle(rec)
         # the phase still open is this cycle's last (commit, when the
         # cycle ran to its end): closed here, it takes in the hand-over
