@@ -13,6 +13,15 @@ lease is fatal so a standby takes over); 2 bad flags/config; 3 ``--once``
 only: a cycle was recovered (the self-healing path ran — see
 ``Scheduler.recovery_log``) or the drain timed out with pods still
 retryable, so the summary line does not describe a clean drain.
+
+The serving process owns the collector's old generation between
+``Scheduler.run()`` and ``Scheduler.close()`` (kubetpu/utils/heap.py):
+what survives start-up and every cycle goes to CPython's permanent
+generation (``gc.freeze()``), so no automatic full collection walks the
+warm cache; ``close()`` calls ``gc.unfreeze()``.  That is process-wide
+state: a process that embeds a ``Scheduler`` beside a heap of its own
+shares it (README, "The serving process owns the collector's old
+generation").
 """
 
 from __future__ import annotations

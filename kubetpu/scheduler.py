@@ -48,6 +48,7 @@ from .state.delta import DeltaTensorizer
 from .state.tensors import SnapshotBuilder
 from .utils import chaos as uchaos
 from .utils import devstats as udevstats
+from .utils import heap as uheap
 from .utils import journal as ujournal
 from .utils import slo as uslo
 from .utils import telemetry as utelemetry
@@ -388,6 +389,9 @@ class Scheduler:
         self._bind_jobs: List[BindJob] = []  # kubelint: guarded-by(_bind_jobs_lock)
         self._stop = threading.Event()
         self._serve_thread: Optional[threading.Thread] = None
+        # the collector policy (utils/heap.py): run() starts it, close()
+        # stops it; a Scheduler that is never run() has none
+        self._heap: Optional[uheap.HeapPolicy] = None
         # the longest pass of the serving loop so far (seconds): what
         # close() takes a cycle in flight to need (see close)
         self._longest_pass_s = 0.0
@@ -562,6 +566,12 @@ class Scheduler:
         tel = utelemetry.ring()
         if tel is not None:
             tel.maybe_tick(self)
+        # between two cycles nothing is open on this thread (the caller
+        # has dropped the last cycle's outcomes): the one place the
+        # survivors are handed to the permanent generation
+        heap = self._heap
+        if heap is not None:
+            heap.boundary(self.cycle_count)
         max_batch = max_batch or self.config.batch_size
         if self.extenders:
             # extenders are a per-pod HTTP round trip; keep the reference's
@@ -1396,6 +1406,8 @@ class Scheduler:
         # leftover flush
         self.queue.move_all_to_active_or_backoff_queue("DispatchRecovery")
         self._deadline_grace = 2
+        if self._heap is not None:
+            self._heap.want_sweep()
         self._record_recovery(kind, reason=reason, pods=len(prep.live),
                               demoted=demoted)
         if prep.trace.rec is not None:
@@ -3077,6 +3089,7 @@ class Scheduler:
         """Start the serving loop (reference: scheduler.go:339 Run)."""
         self.queue.run()
         self.cache.run()
+        heap = uheap.HeapPolicy()
         import os
         if (getattr(self.config, "prewarm", True)
                 and os.environ.get("KUBETPU_PREWARM", "1") != "0"):
@@ -3093,6 +3106,9 @@ class Scheduler:
                         exc_info=True)
                     self._record_recovery("prewarm-error", reason=repr(e),
                                           ladder_steps=ladder_steps)
+                if ladder_steps:
+                    # the ladder's programs are as permanent as the first
+                    heap.startup_handoff()
 
             # current shape blocks startup (it gates the first cycle);
             # the bucket ladder compiles in the background
@@ -3101,6 +3117,11 @@ class Scheduler:
             if steps:
                 threading.Thread(target=prewarm, args=(steps,), daemon=True,
                                  name="kubetpu-prewarm-ladder").start()
+        # from here to close() the scheduler owns the collector's old
+        # generation: the warm cache and the programs compiled so far are
+        # handed to the permanent one
+        heap.start()
+        self._heap = heap
 
         def loop():
             while not self._stop.is_set():
@@ -3157,7 +3178,9 @@ class Scheduler:
         jobs queued on it are applied, in order, before this returns),
         close the queue (wakes blocked pops, joins flushers), the cache
         (joins cleanup), and the bind pool (binds blocked there finish on
-        their own)."""
+        their own); last, a scheduler that was run() gives the
+        collector's permanent generation back (utils/heap.py:
+        gc.unfreeze(), unless another scheduler still serves)."""
         if self._closed:
             return
         self._closed = True
@@ -3176,7 +3199,11 @@ class Scheduler:
                 self.flush_pipeline()
             except Exception:
                 pass
-        self._bind_lane.close()
-        self.queue.close()
-        self.cache.close()
-        self._bind_pool.shutdown(wait=False)
+        try:
+            self._bind_lane.close()
+            self.queue.close()
+            self.cache.close()
+            self._bind_pool.shutdown(wait=False)
+        finally:
+            if self._heap is not None:
+                self._heap.stop()   # the heap goes back to the process

@@ -42,7 +42,7 @@ import os
 import re
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 LOG = logging.getLogger("kubetpu.trace")
 
@@ -394,6 +394,11 @@ class FlightRecorder:
         self.gc_collections = 0
         self._gc_other_seen = 0.0   # kubelint: guarded-by(_lock)
         self._gc_seen = 0           # kubelint: guarded-by(_lock)
+        # the heap policy's hand-offs (note_heap; utils/heap.py): None
+        # until a serving scheduler has said anything
+        self._heap_handoffs: Optional[int] = None   # kubelint: guarded-by(_lock)
+        self._heap_frozen = 0       # kubelint: guarded-by(_lock)
+        self._heap_swept = 0        # kubelint: guarded-by(_lock)
 
     def begin_cycle(self, label: str,
                     queue_depths: Optional[Dict[str, int]] = None,
@@ -416,6 +421,20 @@ class FlightRecorder:
                 self._ring.popleft()
                 self._dropped += 1
 
+    def note_heap(self, handoffs: int, frozen: Optional[int],
+                  swept: int = 0) -> None:
+        """The heap policy (utils/heap.py) handed the survivors to the
+        permanent generation ``handoffs`` times (0: at start-up, which
+        only says that a policy serves); frozen: what
+        ``gc.get_freeze_count()`` read then, where it was read; swept:
+        the unreachable objects a sweep found.  They reach the next
+        committed cycle's meta (note_interpreter)."""
+        with self._lock:
+            self._heap_handoffs = (self._heap_handoffs or 0) + handoffs
+            if frozen is not None:
+                self._heap_frozen = frozen
+            self._heap_swept += swept
+
     def note_interpreter(self, rec: CycleRecord) -> None:
         """Who held the interpreter since the cycle before (Trace.finish
         calls this on the serving thread): meta ``thread_cpu_s`` =
@@ -425,7 +444,11 @@ class FlightRecorder:
         apart -- both absent on the first cycle after arming and where
         the platform has no per-thread CPU clock; ``gc_other_s``, the
         collector's pauses on threads with no phase or bind job open, and
-        ``gc_collections``, its passes on any thread, where not zero.  A
+        ``gc_collections``, its passes on any thread, where not zero;
+        while a heap policy serves (note_heap) ``heap_handoffs``, its
+        hand-offs since the cycle before (0 or 1), ``heap_frozen``, the
+        permanent generation's size when it was last read, and
+        ``heap_sweep_collected``, what a sweep found, where not zero.  A
         thread that ends between two readings takes its last slice with
         it."""
         now = time.perf_counter()
@@ -437,6 +460,15 @@ class FlightRecorder:
             other, n = self.gc_other_s, self.gc_collections
             d_other, d_n = other - self._gc_other_seen, n - self._gc_seen
             self._gc_other_seen, self._gc_seen = other, n
+            handoffs, frozen = self._heap_handoffs, self._heap_frozen
+            swept, self._heap_swept = self._heap_swept, 0
+            if handoffs is not None:
+                self._heap_handoffs = 0
+        if handoffs is not None:
+            rec.meta["heap_handoffs"] = handoffs
+            rec.meta["heap_frozen"] = frozen
+            if swept:
+                rec.meta["heap_sweep_collected"] = swept
         if d_other > 0.0:
             rec.meta["gc_other_s"] = round(d_other, 6)
         if d_n:
@@ -668,7 +700,7 @@ def _read_thread_cpu() -> Optional[Dict[threading.Thread, float]]:
 # enough and the hook takes no lock.  It must not: a collection can start
 # between any two bytecodes, also inside a CycleRecord's lock, so a full
 # collection's ``gc`` event is only noted here and recorded when the
-# phase or the job closes (_flush_gc_events).
+# phase or the job closes (_record_gc_events).
 
 
 class _GcSums:
@@ -716,16 +748,19 @@ def _gc_args(g: _GcSums, s0: float, full0: int,
             args["gc_full"] = g.full - full0
 
 
-def _flush_gc_events(g: _GcSums, rec: Optional[CycleRecord],
-                     parent_id: int) -> None:
-    """The full collections this thread noted since the last flush, as
-    ``gc`` events of ``rec`` (dropped where there is no cycle open)."""
-    if g.events:
-        evs, g.events = g.events, []
-        if rec is not None:
-            for ts, pause, collected in evs:
-                rec.event("gc", parent_id=parent_id, ts=ts, generation=2,
-                          seconds=round(pause, 6), collected=collected)
+def _take_gc_events(g: _GcSums) -> List[Tuple[float, float, int]]:
+    """The full collections this thread noted since they were last
+    taken."""
+    evs, g.events = g.events, []
+    return evs
+
+
+def _record_gc_events(rec: CycleRecord, parent_id: int,
+                      evs: Sequence[Tuple[float, float, int]]) -> None:
+    """Noted full collections as ``gc`` events of ``rec``."""
+    for ts, pause, collected in evs:
+        rec.event("gc", parent_id=parent_id, ts=ts, generation=2,
+                  seconds=round(pause, 6), collected=collected)
 
 
 # module arming state.  The reference is read WITHOUT a lock on the hot
@@ -833,7 +868,7 @@ class _Phase:
     attach args before the exit closes it."""
 
     __slots__ = ("name", "rec", "span", "ann", "t0", "t1", "cpu0", "cpu_s",
-                 "args", "closed", "gc0_s", "gc0_full")
+                 "args", "closed", "gc0_s", "gc0_full", "gc_events")
 
     def __init__(self, name: str, ann: str, rec: Optional[CycleRecord],
                  parent_id: int, args: Dict[str, Any]):
@@ -841,6 +876,7 @@ class _Phase:
         self.span = self.ann = self.cpu0 = None
         self.t0 = self.t1 = self.cpu_s = 0.0
         self.closed = False
+        self.gc_events: Sequence[Tuple[float, float, int]] = ()
         if _flight is not None or rec is not None:
             g = _gc_sums()
             self.gc0_s, self.gc0_full = g.seconds, g.full
@@ -888,9 +924,14 @@ class _Phase:
             if self.span is not None:
                 self.span.args["cpu_s"] = round(self.cpu_s, 6)
                 self.span.t1 = self.t1
-        if g is not None:
-            _flush_gc_events(g, self.rec, self.span.span_id
-                             if self.span is not None else 0)
+        if g is not None and g.events:
+            # a pop closes before its cycle's record exists: the Trace
+            # that takes it in records them
+            self.gc_events = _take_gc_events(g)
+            if self.rec is not None:
+                _record_gc_events(self.rec, self.span.span_id
+                                  if self.span is not None else 0,
+                                  self.gc_events)
         if getattr(_tls, "phase", None) is self:
             _tls.phase = None
 
@@ -986,8 +1027,8 @@ class JobSpan:
         _tls.job = None
         span = self.rec.record_span(JOB_SPAN, self.t0, t1,
                                     parent_id=self.parent_id, **a)
-        _flush_gc_events(g, self.rec,
-                         span.span_id if span is not None else 0)
+        _record_gc_events(self.rec, span.span_id if span is not None else 0,
+                          _take_gc_events(g))
         if self.ann is not None:
             self.ann.__exit__(None, None, None)
             self.ann = None
@@ -1033,9 +1074,14 @@ class Trace:
             if pop is not None and pop.cpu0 is not None:  # armed when opened
                 # the pop that fed this cycle, stamped before the record
                 # existed
-                self.rec.record_span("pop", pop.t0, pop.t1,
-                                     parent_id=self.span_id,
-                                     cpu_s=round(pop.cpu_s, 6), **pop.args)
+                sp = self.rec.record_span(
+                    "pop", pop.t0, pop.t1, parent_id=self.span_id,
+                    cpu_s=round(pop.cpu_s, 6), **pop.args)
+                # full passes between two cycles (the heap policy's sweep
+                # runs there), noted before the record existed
+                _record_gc_events(self.rec,
+                                  sp.span_id if sp is not None else 0,
+                                  pop.gc_events)
         self._last_mark = self.start
         if _PROFILE_ACTIVE:
             _emit_clock(self.rec.seq if self.rec is not None else 0)
