@@ -176,6 +176,26 @@ def live_term_sets(batch: "PodBatch") -> List[str]:
     return [name for name, live in sets if live]
 
 
+def batch_score_sets(term_sets_live: Sequence[str],
+                     hard_pod_affinity_weight: float = 1.0) -> tuple:
+    """ProgramConfig.batch_score_sets of a batch, from live_term_sets: its
+    term sets that score other pods once their owner is bound.  Required
+    affinity scores only at a non-zero hardPodAffinityWeight (scoring.go
+    processExistingPod), which is also when a fresh build compiles such
+    rows (state/tensors.py)."""
+    return tuple(name for name in ("pref", "ra")
+                 if name in term_sets_live
+                 and (name != "ra" or hard_pod_affinity_weight))
+
+
+def score_rows_spliced(batch: "PodBatch", sets: Sequence[str]) -> int:
+    """Valid rows models/gang.py _splice_score_terms appends for `sets`
+    (host numpy; the topology key of a batch term always indexes the
+    cluster's key axis, which is sized from the same vocabulary)."""
+    terms = [getattr(batch, name) for name in sets]
+    return int(sum((t.valid & t.topo_known).sum() for t in terms))
+
+
 def gather_batch_rows(batch: "PodBatch", rows: np.ndarray) -> "PodBatch":
     """Select pod rows (numpy; -1 entries are padding -> valid False).
     The residual-auction host loop uses this to re-run only the CONTENDED

@@ -46,7 +46,9 @@ placements exactly — a pod admitted in round r sees every pod admitted in
 rounds < r the way the reference's serial loop sees previously bound pods
 (interpodaffinity/filtering.go:314, podtopologyspread/filtering.go:200).
 Admitted pods' own required anti-affinity terms are spliced into
-filter_terms so they repel later-round pods (the existing-pods direction).
+filter_terms so they repel later-round pods (the existing-pods direction),
+and their preferred and required-affinity terms into score_terms, so they
+score later-round pods the way bound pods' terms do (_extend_cluster).
 Within a round, a conservative same-topology-pair deferral keeps admission
 order safe.  A pod with a required anti-affinity term is deferred to the
 next round if any earlier-index pod matching that term was admitted this
@@ -111,6 +113,12 @@ class GangResult(NamedTuple):
                             # per-cycle view in ONE device->host readback
                             # (each readback is a host sync; the serving
                             # loop makes exactly one per cycle)
+    capacity_deferred: jnp.ndarray  # i32 proposals, summed over the rounds,
+                            # that found their node full at their turn
+                            # (admission_mask refused them; topology
+                            # deferrals are not counted).  Diagnostics: not
+                            # in packed, read back only by an armed flight
+                            # recorder, after packed
 
 
 def _segment_base(values: jnp.ndarray, is_start: jnp.ndarray) -> jnp.ndarray:
@@ -123,12 +131,71 @@ def _segment_base(values: jnp.ndarray, is_start: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.cummax(marked, axis=0)
 
 
-def _extend_cluster(cluster, batch):
+def _term_rows(terms, weight, owner0: int, n_topo_keys: int):
+    """A batch term set ([B, T] companions over a flat [B*T] selector set)
+    as ExistingTerms rows owned by pod-axis rows owner0 + j.  A term whose
+    topology key exists nowhere in the cluster can never produce a pair, so
+    it never counts for or against anything — its row is invalid."""
+    B, T = terms.valid.shape
+    return ExistingTerms(
+        sel=terms.sel,
+        ns_hot=terms.ns_hot.reshape(B * T, -1),
+        topo_key=terms.topo_key.reshape(-1),
+        pod_idx=owner0 + jnp.repeat(jnp.arange(B, dtype=jnp.int32), T),
+        weight=weight.reshape(-1),
+        valid=(terms.valid & terms.topo_known
+               & (terms.topo_key < n_topo_keys)).reshape(-1))
+
+
+def _append_terms(table: ExistingTerms, *more: ExistingTerms) -> ExistingTerms:
+    """table's rows followed by each of more's, in order."""
+    sel = table.sel
+    for m in more:
+        sel = concat_selector_sets(sel, m.sel)
+    return ExistingTerms(
+        sel=sel,
+        **{f: jnp.concatenate([getattr(table, f)]
+                              + [getattr(m, f) for m in more])
+           for f in ExistingTerms._fields if f != "sel"})
+
+
+def _splice_score_terms(cluster, batch, owner0: int,
+                        sets=("pref", "ra"), hard_pod_affinity_weight=1.0):
+    """score_terms with the batch pods' own score-side terms appended,
+    owner rows owner0 + j: what a FRESH build puts there for a bound pod
+    (state/tensors.py SnapshotBuilder: preferred affinity and
+    anti-affinity at their signed weights, required affinity at
+    hardPodAffinityWeight; scoring.go processExistingPod), in the order of
+    `sets`.  A row counts once its owner is valid on the pod axis at a
+    node (ops/kernels.py _owner_pairs)."""
+    TK = cluster.topo_pair.shape[1]
+    rows = []
+    for name in sets:
+        t = getattr(batch, name)
+        w = (t.weight if name == "pref"
+             else jnp.full_like(t.weight, hard_pod_affinity_weight))
+        rows.append(_term_rows(t, w * _f(t.valid), owner0, TK))
+    return _append_terms(cluster.score_terms, *rows)
+
+
+def _extend_cluster(cluster, batch, score_sets=(),
+                    hard_pod_affinity_weight=1.0):
     """Append the batch's pods to the existing-pod axis (pod_node/pod_valid
-    are patched per round from the carry) and splice the batch pods' required
-    anti-affinity terms into filter_terms with owner rows P+j, so admitted
-    batch pods repel later pods exactly like bound existing pods
-    (interpodaffinity/filtering.go:166 getExistingAntiAffinityCounts)."""
+    are patched per round from the carry) and splice their terms into the
+    existing pods' term tables with owner rows P+j, so a batch pod admitted
+    in round r acts on the pods of later rounds exactly like a bound
+    existing pod:
+
+    - filter_terms gets every pod's required anti-affinity terms, so
+      admitted batch pods repel (interpodaffinity/filtering.go:166
+      getExistingAntiAffinityCounts);
+    - score_terms gets the term sets named in score_sets
+      (_splice_score_terms: "pref", "ra"), so admitted batch pods' own
+      preferred and required-affinity terms score the nodes they landed
+      on for the pods they select (scoring.go processExistingPod).
+      score_sets is STATIC (ProgramConfig.batch_score_sets, which the
+      scheduler reads off the batch): a batch without a score-side term
+      names none and appends no row."""
     B = batch.req.shape[0]
     P = cluster.pod_valid.shape[0]
     raa = batch.raa
@@ -149,7 +216,7 @@ def _extend_cluster(cluster, batch):
         weight=jnp.concatenate([ft.weight, jnp.ones((B * Ta,), jnp.float32)]),
         valid=jnp.concatenate([ft.valid, valid]),
     )
-    return cluster._replace(
+    ext = cluster._replace(
         pod_kv=jnp.concatenate([cluster.pod_kv, batch.kv_hot]),
         pod_key=jnp.concatenate([cluster.pod_key, batch.key_hot]),
         pod_ns_hot=jnp.concatenate([cluster.pod_ns_hot, batch.ns_hot]),
@@ -161,6 +228,10 @@ def _extend_cluster(cluster, batch):
             [cluster.pod_terminating, jnp.zeros((B,), bool)]),
         filter_terms=ext_terms,
     )
+    if score_sets:
+        ext = ext._replace(score_terms=_splice_score_terms(
+            cluster, batch, P, score_sets, hard_pod_affinity_weight))
+    return ext
 
 
 def _seg_prefix(e_sorted: jnp.ndarray, is_start: jnp.ndarray) -> jnp.ndarray:
@@ -272,35 +343,13 @@ def _materialize_assigned(cluster, batch, chosen, requested, nz, ports_used,
         ports=cluster.ports | (ports_used > 0.5),
     )
     if extend_score_terms:
-        # a FRESH rebuild would put the newly-bound pods' preferred terms
-        # (signed weights) and required-affinity terms (hardPodAffinityWeight)
-        # into score_terms (state/tensors.py:334); chained clusters must
-        # match or scoring silently diverges from a rebuild
-        P0 = cluster.pod_valid.shape[0]
-        TK = cluster.topo_pair.shape[1]
-        st = cluster.score_terms
-
-        def term_rows(t, w):
-            bb, tt = t.valid.shape
-            return (t.sel, t.ns_hot.reshape(bb * tt, -1),
-                    t.topo_key.reshape(-1),
-                    P0 + jnp.repeat(jnp.arange(bb, dtype=jnp.int32), tt),
-                    w.reshape(-1),
-                    (t.valid & t.topo_known & (t.topo_key < TK)).reshape(-1))
-
-        pr = term_rows(batch.pref, batch.pref.weight * _f(batch.pref.valid))
-        ra = term_rows(batch.ra,
-                       jnp.full_like(batch.ra.weight,
-                                     hard_pod_affinity_weight)
-                       * _f(batch.ra.valid))
-        ext = ext._replace(score_terms=ExistingTerms(
-            sel=concat_selector_sets(concat_selector_sets(st.sel, pr[0]),
-                                     ra[0]),
-            ns_hot=jnp.concatenate([st.ns_hot, pr[1], ra[1]]),
-            topo_key=jnp.concatenate([st.topo_key, pr[2], ra[2]]),
-            pod_idx=jnp.concatenate([st.pod_idx, pr[3], ra[3]]),
-            weight=jnp.concatenate([st.weight, pr[4], ra[4]]),
-            valid=jnp.concatenate([st.valid, pr[5], ra[5]])))
+        # a FRESH rebuild would put the newly-bound pods' score-side terms
+        # into score_terms; chained clusters must match or scoring silently
+        # diverges from a rebuild.  The same splice the auction makes for
+        # its own later rounds (_extend_cluster, _splice_score_terms)
+        ext = ext._replace(score_terms=_splice_score_terms(
+            cluster, batch, cluster.pod_valid.shape[0],
+            hard_pod_affinity_weight=hard_pod_affinity_weight))
     P = ext.pod_valid.shape[0]
     if pad_pods_to > P:
         n = pad_pods_to - P
@@ -458,7 +507,9 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
     ports_ok0 = (K.node_ports_filter(cluster, batch) if use_ports
                  else jnp.ones((B, N), bool))
 
-    ext = _extend_cluster(cluster, batch) if intra else cluster
+    ext = (_extend_cluster(cluster, batch, cfg.batch_score_sets,
+                           cfg.hard_pod_affinity_weight)
+           if intra else cluster)
     score_names = set(n for n, _ in cfg.scores)
     # assignment-independent raw scores: computed ONCE; only their
     # normalization (a [B, N] reduce over the evolving feasible mask)
@@ -523,6 +574,10 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         # Admission rounds are intrinsically <= B (each assigns >= 1 pod),
         # so the budget keeps its original meaning.
         admits=jnp.int32(0),
+        # proposals that found their node full at their turn (a hostPort
+        # an earlier proposer registered counts as full), summed over the
+        # rounds: diagnostics, GangResult.capacity_deferred
+        cap_deferred=jnp.int32(0),
         progress=jnp.bool_(True),
         # windowed-residual bookkeeping: pods proven infeasible in a round
         # with no admission leave the selection pool until an admission
@@ -773,6 +828,7 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         admit = admission_mask(prop, active, sbatch.req, sbatch.ports_hot,
                                sbatch.ports_asnode_hot, cluster.allocatable,
                                c["req"], use_ports, N)
+        cap_deferred = jnp.sum(active & ~admit, dtype=jnp.int32)
         if intra:
             # intra-round topology serialization (conservative; deferred
             # pods re-check against exact committed counts next round)
@@ -803,6 +859,7 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         admitted_any = jnp.any(admit)
         new["rounds"] = c["rounds"] + 1
         new["admits"] = c["admits"] + admitted_any.astype(jnp.int32)
+        new["cap_deferred"] = c["cap_deferred"] + cap_deferred
         if windowed:
             # retirement: a pod with NO feasible node in a no-admission
             # round leaves the window-selection pool; any admission
@@ -876,4 +933,5 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
                       nz=out["nz"], ports_used=out["ports_used"],
                       feasible0=out["feas0"], unresolvable=unresolvable,
                       n_feasible=n_feas,
-                      all_unresolvable=all_unres, packed=packed)
+                      all_unresolvable=all_unres, packed=packed,
+                      capacity_deferred=out["cap_deferred"])

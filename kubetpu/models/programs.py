@@ -82,6 +82,19 @@ class ProgramConfig(NamedTuple):
     # that work proportionally.  () = unknown -> all keys (always safe);
     # a non-empty tuple MUST be a superset of the batch's term keys.
     active_topo_keys: Tuple[int, ...] = ()
+    # the BATCH's own term sets that score OTHER pods once their owner is
+    # bound: "pref" (preferred affinity and anti-affinity, signed weights),
+    # "ra" (required affinity, at hard_pod_affinity_weight).  The gang
+    # auction splices the named sets into score_terms, so a pod admitted in
+    # round r scores the pods of later rounds as a bound pod would
+    # (models/gang.py _extend_cluster).  Read off the batch by the
+    # scheduler like active_topo_keys; () = the batch carries none and the
+    # auction appends no row.  Naming a set the batch has no valid row of
+    # is safe (its rows are invalid), naming too few under-counts.
+    batch_score_sets: Tuple[str, ...] = ()
+    # InterPodAffinityArgs.hardPodAffinityWeight, for the "ra" rows above
+    # (existing pods' rows carry it in cluster.score_terms already)
+    hard_pod_affinity_weight: float = 1.0
 
     @property
     def active_keys(self):

@@ -555,6 +555,8 @@ def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
             admitted_any = jnp.any(admit)
             new["rounds"] = c["rounds"] + 1
             new["admits"] = c["admits"] + admitted_any.astype(jnp.int32)
+            new["cap_deferred"] = c["cap_deferred"] + jnp.sum(
+                active & ~admit, dtype=jnp.int32)
             if windowed:
                 new_retire = (~active) & live_g & ~c["retired"]
                 new["retired"] = jnp.where(
@@ -572,6 +574,7 @@ def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
             win_score=jnp.zeros((B,), jnp.float32),
             feas0=jnp.zeros((Bl, Nl), bool),
             rounds=jnp.int32(0), admits=jnp.int32(0),
+            cap_deferred=jnp.int32(0),
             progress=jnp.bool_(True),
             retired=jnp.zeros((B,), bool))
 
@@ -617,16 +620,16 @@ def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
         all_unres = lax.all_gather(au_l, AXIS_PODS, tiled=True)
         return (out["assigned"], out["win_score"], out["rounds"],
                 out["req"], out["nz"], out["ports_used"], f0, n_feas,
-                all_unres)
+                all_unres, out["cap_deferred"])
 
     tile2 = P(AXIS_PODS, AXIS_NODES)
     (assigned, win_score, rounds, req, nz, ports_used, feas0, n_feas,
-     all_unres) = jax.shard_map(
+     all_unres, cap_deferred) = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, AXIS_PODS, AXIS_NODES), tile2, tile2,
                   P(), P(), P(), P(), P(), P(), P(), P(), P(), P(),
                   P(), P()),
-        out_specs=(P(), P(), P(), P(), P(), P(), tile2, P(), P()),
+        out_specs=(P(), P(), P(), P(), P(), P(), tile2, P(), P(), P()),
         check_vma=False)(
         bundle["planes"], bundle["mask"], static_unres,
         bundle["breq"], bundle["bnz"], bundle["bports"],
@@ -641,7 +644,7 @@ def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
                       requested=req, nz=nz, ports_used=ports_used,
                       feasible0=feas0, unresolvable=static_unres,
                       n_feasible=n_feas, all_unresolvable=all_unres,
-                      packed=packed)
+                      packed=packed, capacity_deferred=cap_deferred)
 
 
 # --------------------------------------------------------------------------

@@ -39,7 +39,8 @@ from .framework.interface import Code, CycleState, Status
 from .framework.runtime import Framework
 from .framework.types import NodeInfo, PodInfo, QueuedPodInfo
 from .models import programs
-from .models.batch import PodBatchBuilder, live_term_sets
+from .models.batch import (PodBatchBuilder, batch_score_sets,
+                           live_term_sets, score_rows_spliced)
 from .models.sequential import schedule_sequential
 from .plugins.intree import new_in_tree_registry
 from .schedqueue.queue import SchedulingQueue
@@ -1106,7 +1107,12 @@ class Scheduler:
                 if self.config.percentage_of_nodes_to_score > 0 else 0),
             # restrict the same-pair matmuls to the keys THIS batch's terms
             # actually use (superset contract, see ProgramConfig)
-            active_topo_keys=batch_topo_keys)
+            active_topo_keys=batch_topo_keys,
+            # the batch's score-side term sets with a valid row: the
+            # auction splices them into score_terms for its later rounds
+            batch_score_sets=batch_score_sets(
+                term_sets_live, fwk.hard_pod_affinity_weight),
+            hard_pod_affinity_weight=float(fwk.hard_pod_affinity_weight))
         from .preemption import CycleContext
         cycle_ctx = CycleContext(
             builder=builder, cluster=cluster, cfg=cfg,
@@ -1131,6 +1137,11 @@ class Scheduler:
                       or any(s is not None for s in spread_sels))
         if trace.rec is not None:
             trace.rec.meta["needs_topo"] = int(needs_topo)
+            # valid batch rows the auction splices into score_terms
+            # (models/gang.py _extend_cluster; none without needs_topo)
+            trace.rec.meta["score_terms_spliced"] = (
+                score_rows_spliced(batch, cfg.batch_score_sets)
+                if needs_topo else 0)
         prep = PreparedCycle(
             fwk=fwk, trace=trace, chain_seq0=chain_seq0,
             node_infos=node_infos, states=states, live=live, pinfos=pinfos,
@@ -1517,6 +1528,13 @@ class Scheduler:
                 # per-span device-wait attribution: the readback is the
                 # cycle's only observable device sync
                 sp.args["device_wait_s"] = round(wait, 6)
+            if prep.trace.rec is not None and hasattr(
+                    res, "capacity_deferred"):
+                # armed only: the auction's own count (GangResult), a
+                # second, 4-byte copy that waits for nothing, the program
+                # being done
+                prep.trace.rec.meta["capacity_deferred"] = int(
+                    res.capacity_deferred)
         self.device_wait_s += wait
         return packed
 
@@ -2869,7 +2887,8 @@ class Scheduler:
             hostname_topokey=max(builder.table.topokey.get(api.LABEL_HOSTNAME), 0),
             plugin_args=fwk.tensor_plugin_args(builder.table),
             active_topo_keys=self._batch_topo_keys(builder.table,
-                                                   protos[:1]))
+                                                   protos[:1]),
+            hard_pod_affinity_weight=float(fwk.hard_pod_affinity_weight))
         rng = self._jax.random.PRNGKey(0)
         # profiles with host score plugins serve with a [B, N] bias array;
         # warming the bias=None variant alone would leave the serving
