@@ -299,9 +299,8 @@ class RestClusterStore(ClusterStore):
                 self._objs[kind].pop(self._key(old), None)
             else:
                 self._objs[kind][self._key(new)] = new
-            subs = list(self._subs[kind])
-        for h in subs:
-            h(event, old, new)
+            subs = self._subscribers(kind)
+        self._deliver(subs, [(event, old, new)])
 
     def _list_all(self) -> Optional[int]:
         """Initial/recovery LIST of every kind (reflector.go ListAndWatch).

@@ -13,6 +13,20 @@ synchronously in-process — the integration-test shape of the reference
 the parity harness runs without a real control plane.  The `bind` method is
 the pods/<name>/binding subresource (reference:
 defaultbinder/default_binder.go:56, pkg/registry/core/pod BindingREST).
+
+A write is a TRANSACTION of one or more events (``bind_many``,
+``add_many``: many; every other write: one), applied under one hold of
+the lock and delivered after it, in this order: first, whole, to each
+subscriber that takes a transaction's events as one list
+(``subscribe(kind, handler, batched=True)``: the scheduler's own cache
+and queue), then event by event, in store order, to each subscriber that
+takes them one at a time (``handler(event, old, new)``: a client, a REST
+mirror).  So every subscriber sees a transaction's events in store
+order, and nobody learns of a bind event by event before the scheduler
+has confirmed all of the transaction's binds in its cache: a per-event
+subscriber that reacts to a bind (deletes the pod) can no longer get
+ahead of the scheduler's own handling of it, as it could within one
+fan-out while the order was the order of subscription.
 """
 
 from __future__ import annotations
@@ -25,6 +39,9 @@ from ..api import types as api
 
 Handler = Callable[[str, Optional[object], Optional[object]], None]
 # handler(event, old, new) with event in {"add", "update", "delete"}
+BatchHandler = Callable[[List[Tuple[str, Optional[object],
+                                    Optional[object]]]], None]
+# handler([(event, old, new), ...]): one transaction's events, store order
 
 KINDS = ("Pod", "Node", "PersistentVolumeClaim", "PersistentVolume",
          "StorageClass", "CSINode", "Service", "ReplicaSet",
@@ -51,6 +68,7 @@ class ClusterStore:
         self._lock = threading.RLock()
         self._objs: Dict[str, Dict[str, object]] = {k: {} for k in KINDS}  # kubelint: guarded-by(_lock)
         self._subs: Dict[str, List[Handler]] = {k: [] for k in KINDS}  # kubelint: guarded-by(_lock)
+        self._batch_subs: Dict[str, List[BatchHandler]] = {k: [] for k in KINDS}  # kubelint: guarded-by(_lock)
         # PV binding assume-cache (reference: scheduler_binder assume cache)
         self._assumed_pv: Dict[str, str] = {}   # pv name -> pvc name  # kubelint: guarded-by(_lock)
 
@@ -65,25 +83,75 @@ class ClusterStore:
             "Event") \
             else m.name
 
-    def subscribe(self, kind: str, handler: Handler) -> None:
+    def subscribe(self, kind: str, handler, batched: bool = False) -> None:
+        """``handler(event, old, new)`` an event; with ``batched``,
+        ``handler(events)`` a transaction, before the per-event
+        subscribers hear of it (module docstring)."""
         with self._lock:
-            self._subs[kind].append(handler)
+            (self._batch_subs if batched else self._subs)[kind].append(
+                handler)
             # replay current state as adds (informer initial List)
             current = list(self._objs[kind].values())
+        if batched:
+            if current:
+                handler([("add", None, obj) for obj in current])
+            return
         for obj in current:
             handler("add", None, obj)
 
+    def _subscribers(self, kind: str):
+        """Who hears of a write to ``kind``, snapshotted under the
+        write's own hold of the lock."""
+        return list(self._batch_subs[kind]), list(self._subs[kind])
+
+    @staticmethod
+    def _deliver(subs, events) -> None:
+        """One transaction's events, after its lock is released: whole
+        to the list-taking subscribers first, then event-major to the
+        rest (module docstring)."""
+        batch_subs, each_subs = subs
+        if not events:              # every row of it was refused
+            return
+        for h in batch_subs:
+            h(events)
+        for ev in events:
+            for h in each_subs:
+                h(*ev)
+
     def add(self, obj) -> None:
-        kind = obj.kind
+        """A transaction of one."""
+        (err,) = self._add_transaction((obj,))
+        if err is not None:
+            raise err
+
+    def add_many(self, objs) -> List[Optional[Exception]]:
+        """``add`` each of ``objs`` (one kind) as ONE transaction: one
+        hold of the lock, one delivery.  One result an object: None, or
+        the Conflict ``add`` would have raised; an object that fails
+        does not stop the rest.  A store whose ``add`` is not the one
+        above (a remote client's) keeps its say, as in ``bind_many``."""
+        if type(self).add is not _ADD:
+            return _each(self.add, [(obj,) for obj in objs])
+        return self._add_transaction(objs) if objs else []
+
+    def _add_transaction(self, objs) -> List[Optional[Exception]]:
+        kind = objs[0].kind
+        results: List[Optional[Exception]] = []
+        events = []
         with self._lock:
-            k = self._key(obj)
-            if k in self._objs[kind]:
-                raise Conflict(f"{kind} {k} already exists")
-            obj.metadata.resource_version += 1
-            self._objs[kind][k] = obj
-            subs_snapshot = list(self._subs[kind])
-        for h in subs_snapshot:
-            h("add", None, obj)
+            held = self._objs[kind]
+            for obj in objs:
+                k = self._key(obj)
+                if k in held:
+                    results.append(Conflict(f"{kind} {k} already exists"))
+                    continue
+                obj.metadata.resource_version += 1
+                held[k] = obj
+                results.append(None)
+                events.append(("add", None, obj))
+            subs = self._subscribers(kind)
+        self._deliver(subs, events)
+        return results
 
     def update(self, obj) -> None:
         kind = obj.kind
@@ -94,9 +162,8 @@ class ClusterStore:
                 raise NotFound(f"{kind} {k} not found")
             obj.metadata.resource_version = old.metadata.resource_version + 1
             self._objs[kind][k] = obj
-            subs_snapshot = list(self._subs[kind])
-        for h in subs_snapshot:
-            h("update", old, obj)
+            subs = self._subscribers(kind)
+        self._deliver(subs, [("update", old, obj)])
 
     def delete(self, obj) -> None:
         kind = obj.kind
@@ -105,9 +172,8 @@ class ClusterStore:
             old = self._objs[kind].pop(k, None)
             if old is None:
                 raise NotFound(f"{kind} {k} not found")
-            subs_snapshot = list(self._subs[kind])
-        for h in subs_snapshot:
-            h("delete", old, None)
+            subs = self._subscribers(kind)
+        self._deliver(subs, [("delete", old, None)])
 
     def get(self, kind: str, key: str):
         with self._lock:
@@ -145,27 +211,55 @@ class ClusterStore:
     def bind(self, pod: api.Pod, node_name: str) -> None:
         """POST pods/<name>/binding (reference: default_binder.go:56).
         Fails if the pod is gone or already bound — the scheduler's
-        ForgetPod path handles that (scheduler.go:497)."""
+        ForgetPod path handles that (scheduler.go:497).  A transaction
+        of one."""
+        (err,) = self._bind_transaction(((pod, node_name),))
+        if err is not None:
+            raise err
+
+    def bind_many(self, pairs) -> List[Optional[Exception]]:
+        """``bind`` each ``(pod, node_name)`` of ``pairs`` as ONE
+        transaction: one hold of the lock applies them in order with
+        ``bind``'s own checks a pod, then one delivery of an ``update``
+        event a bound pod, in that order.  One result a pair: None, or
+        the NotFound / Conflict ``bind`` would have raised; a pair that
+        fails does not stop the rest.  A store whose ``bind`` is not
+        the one below (a subclass's: a remote client, a test's fault; one
+        patched over this class) keeps its say: that ``bind`` runs a
+        pair, each a transaction of its own."""
+        if type(self).bind is not _BIND:
+            return _each(self.bind, pairs)
+        return self._bind_transaction(pairs)
+
+    def _bind_transaction(self, pairs) -> List[Optional[Exception]]:
+        results: List[Optional[Exception]] = []
+        events = []
         with self._lock:
-            k = f"{pod.namespace}/{pod.metadata.name}"
-            current: Optional[api.Pod] = self._objs["Pod"].get(k)
-            if current is None:
-                raise NotFound(f"pod {k} not found")
-            if current.spec.node_name:
-                # reference: pkg/registry/core/pod BindingREST rejects any
-                # re-bind, even to the same node
-                raise Conflict(f"pod {k} is already assigned to node "
-                               f"{current.spec.node_name}")
-            if self.get("Node", node_name) is None:
-                raise NotFound(f"node {node_name} not found")
-            old = copy.copy(current)
-            old.spec = copy.copy(current.spec)
-            current.spec.node_name = node_name
-            current.status.phase = api.POD_PENDING
-            current.metadata.resource_version += 1
-            subs_snapshot = list(self._subs["Pod"])
-        for h in subs_snapshot:
-            h("update", old, current)
+            pods, nodes = self._objs["Pod"], self._objs["Node"]
+            for pod, node_name in pairs:
+                k = f"{pod.metadata.namespace}/{pod.metadata.name}"
+                current: Optional[api.Pod] = pods.get(k)
+                if current is None:
+                    results.append(NotFound(f"pod {k} not found"))
+                elif current.spec.node_name:
+                    # reference: pkg/registry/core/pod BindingREST rejects
+                    # any re-bind, even to the same node
+                    results.append(Conflict(
+                        f"pod {k} is already assigned to node "
+                        f"{current.spec.node_name}"))
+                elif node_name not in nodes:
+                    results.append(NotFound(f"node {node_name} not found"))
+                else:
+                    old = api.shallow_copy(current)
+                    old.spec = api.shallow_copy(current.spec)
+                    current.spec.node_name = node_name
+                    current.status.phase = api.POD_PENDING
+                    current.metadata.resource_version += 1
+                    results.append(None)
+                    events.append(("update", old, current))
+            subs = self._subscribers("Pod")
+        self._deliver(subs, events)
+        return results
 
     def update_pod_condition(self, pod: api.Pod, condition: api.PodCondition,
                              nominated_node_name: str = "") -> None:
@@ -183,9 +277,8 @@ class ClusterStore:
             if nominated_node_name:
                 current.status.nominated_node_name = nominated_node_name
             current.metadata.resource_version += 1
-            subs_snapshot = list(self._subs["Pod"])
-        for h in subs_snapshot:
-            h("update", old, current)
+            subs = self._subscribers("Pod")
+        self._deliver(subs, [("update", old, current)])
 
     # -- PV binding (SchedulerVolumeBinder surface) -------------------------
 
@@ -230,9 +323,8 @@ class ClusterStore:
                 pvc.metadata.annotations[
                     "volume.kubernetes.io/selected-node"] = node_name
             pvc.metadata.resource_version += 1
-            subs_snapshot = list(self._subs["PersistentVolumeClaim"])
-        for h in subs_snapshot:
-            h("update", old, pvc)
+            subs = self._subscribers("PersistentVolumeClaim")
+        self._deliver(subs, [("update", old, pvc)])
 
     # -- spread selectors (DefaultPodTopologySpread) ------------------------
 
@@ -266,3 +358,21 @@ class ClusterStore:
         if not reqs:
             return None
         return api.LabelSelector(match_expressions=reqs)
+
+
+# the single writes as written above: what the batch forms may stand in for
+_ADD, _BIND = ClusterStore.add, ClusterStore.bind
+
+
+def _each(write, rows) -> List[Optional[Exception]]:
+    """``write(*row)`` a row, each a transaction of its own; one result a
+    row: None, or what it raised."""
+    results: List[Optional[Exception]] = []
+    for row in rows:
+        try:
+            write(*row)
+        except Exception as e:  # noqa: BLE001 — a result, not a raise
+            results.append(e)
+        else:
+            results.append(None)
+    return results

@@ -17,7 +17,7 @@ from __future__ import annotations
 import threading
 import time
 from enum import IntEnum
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Set, Tuple
 
 from ..api import types as api
 
@@ -329,6 +329,11 @@ class WaitingPodsMap:
     def get(self, uid: str) -> Optional[WaitingPod]:
         with self._lock:
             return self._pods.get(uid)
+
+    def uids(self) -> Set[str]:
+        """The pods waiting now, in one locked read."""
+        with self._lock:
+            return set(self._pods)
 
     def iterate(self, fn: Callable[[WaitingPod], None]) -> None:
         with self._lock:

@@ -43,7 +43,9 @@ class Heap:
     update = add
 
     def delete(self, item: Any) -> bool:
-        k = self._key(item)
+        return self.delete_by_key(self._key(item))
+
+    def delete_by_key(self, k: str) -> bool:
         if k in self._items:
             del self._items[k]
             del self._live_seq[k]

@@ -82,6 +82,12 @@ class PodNominator:
         with self._lock:
             self._delete(pod)
 
+    def delete_nominated_pods(self, pods: List[api.Pod]) -> None:
+        """``delete_nominated_pod_if_exists`` for each, the lock once."""
+        with self._lock:
+            for pod in pods:
+                self._delete(pod)
+
     def _delete(self, pod: api.Pod) -> None:
         nn = self._nominated_pod_to_node.pop(pod.uid, None)
         if nn is None:
@@ -332,13 +338,25 @@ class SchedulingQueue(PodNominator):
         """reference: :443 Delete."""
         with self._cond:
             self.delete_nominated_pod_if_exists(pod)
-            k = _pod_key(pod)
-            qp = QueuedPodInfo(pod=pod)
-            if not self.active_q.delete(qp):
-                self.backoff_q.delete(qp)
-                if self.unschedulable_q.pop(k, None) is not None:
-                    if self._unschedulable_recorder:
-                        self._unschedulable_recorder.dec()
+            self._drop(_pod_key(pod))
+
+    def _drop(self, k: str) -> None:
+        if not self.active_q.delete_by_key(k):
+            self.backoff_q.delete_by_key(k)
+            if self.unschedulable_q.pop(k, None) is not None:
+                if self._unschedulable_recorder:
+                    self._unschedulable_recorder.dec()
+
+    def pods_bound(self, pods: List[api.Pod]) -> None:
+        """The watch confirmed the binds of ``pods``: ``delete`` and
+        ``assigned_pod_added`` for each, under ONE hold of the queue's
+        condition and one of the nominator's lock.  AssignedPodAdded's
+        move runs once: its targets do not depend on the bound pod."""
+        with self._cond:
+            self.delete_nominated_pods(pods)
+            for pod in pods:
+                self._drop(_pod_key(pod))
+            self._move_affinity_pods("AssignedPodAdded")
 
     # -- cluster-event moves ------------------------------------------------
 
@@ -351,11 +369,13 @@ class SchedulingQueue(PodNominator):
         """A bound pod may unblock pods with (anti-)affinity
         (reference: :480 AssignedPodAdded / getUnschedulablePodsWithMatchingAffinityTerm :716)."""
         with self._cond:
-            targets = [qp for qp in self.unschedulable_q.values()
-                       if pod_with_affinity(qp.pod)]
-            self._move_pods(targets, "AssignedPodAdded")
+            self._move_affinity_pods("AssignedPodAdded")
 
     assigned_pod_updated = assigned_pod_added
+
+    def _move_affinity_pods(self, event: str) -> None:
+        self._move_pods([qp for qp in self.unschedulable_q.values()
+                         if pod_with_affinity(qp.pod)], event)
 
     def _move_pods(self, pods: List[QueuedPodInfo], event: str) -> None:
         # reference: :512 movePodsToActiveOrBackoffQueue

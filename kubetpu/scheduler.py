@@ -22,6 +22,7 @@ recordSchedulingFailure exactly like the reference (scheduler.go:586-687).
 from __future__ import annotations
 
 import copy
+import itertools
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -432,13 +433,7 @@ class Scheduler:
                 was_assigned = bool(old.spec.node_name)
                 is_assigned = bool(new.spec.node_name)
                 if is_assigned and not was_assigned:
-                    # bind confirmed (possibly our own optimistic assume)
-                    foreign = not self.cache.is_assumed_pod(new)
-                    self._add_pod_to_cache(new)
-                    if foreign:
-                        self._mark_chain_dirty()   # a foreign writer bound it
-                    self.queue.delete(old)
-                    self.queue.assigned_pod_added(new)
+                    binds_confirmed([new])
                 elif is_assigned:
                     self._update_pod_in_cache(old, new)
                     self._mark_chain_dirty()
@@ -458,6 +453,31 @@ class Scheduler:
                     fwk = self.profiles.get(pod.spec.scheduler_name)
                     if fwk is not None:
                         fwk.reject_waiting_pod(pod.uid)
+
+        def binds_confirmed(pods: List[api.Pod]) -> None:
+            """The watch says ``pods`` are bound (possibly our own
+            optimistic assumes): the cache's lock once and the queue's
+            once, whatever their number."""
+            if self.cache.confirm_pods(pods):
+                self._mark_chain_dirty()   # a foreign writer bound one
+            self.queue.pods_bound(pods)
+
+        def on_pods(events) -> None:
+            """One transaction of the store (client/store.py): its bind
+            confirmations together, in runs, anything else one by one,
+            in store order."""
+            bound: List[api.Pod] = []
+            for event, old, new in events:
+                if (event == "update" and new.spec.node_name
+                        and not old.spec.node_name):
+                    bound.append(new)
+                    continue
+                if bound:
+                    binds_confirmed(bound)
+                    bound = []
+                on_pod(event, old, new)
+            if bound:
+                binds_confirmed(bound)
 
         def on_node(event: str, old, new) -> None:
             if event == "add":
@@ -481,7 +501,10 @@ class Scheduler:
                 self.queue.move_all_to_active_or_backoff_queue(f"{kind}{event.title()}")
             return handler
 
-        s.subscribe("Pod", on_pod)
+        # the scheduler takes a transaction whole, so it has confirmed
+        # every bind of one before any per-event subscriber hears of the
+        # first (client/store.py)
+        s.subscribe("Pod", on_pods, batched=True)
         s.subscribe("Node", on_node)
         for kind in ("PersistentVolume", "PersistentVolumeClaim",
                      "StorageClass", "Service", "CSINode"):
@@ -2386,32 +2409,125 @@ class Scheduler:
         js = (utrace.JobSpan(job.flight, job.span, job.handed_t)
               if job.flight is not None else None)
         pooled0 = len(job.pooled)
+        batched = 0
         try:
-            for fwk, qp, state, assumed, node_name, slo, row in job.entries:
+            for (fwk, binder, hooks_s), run in self._bind_runs(job.entries):
                 try:
+                    if binder is not None:
+                        batched += len(run)
+                        self._bind_batch(fwk, binder, hooks_s, run, fold)
+                        continue
+                    _, qp, state, assumed, node_name, slo, row = run[0]
                     self._bind_cycle(fwk, qp, state, assumed, node_name,
                                      None, job.flight, slo, row, fold)
                 except Exception as e:
                     import logging
                     logging.getLogger("kubetpu").exception(
-                        "binding cycle of %s/%s raised", qp.pod.namespace,
-                        qp.pod.metadata.name)
+                        "binding cycle of %s/%s%s raised",
+                        run[0][1].pod.namespace,
+                        run[0][1].pod.metadata.name,
+                        " and %d more" % (len(run) - 1)
+                        if len(run) > 1 else "")
                     job.error = job.error or e
         finally:
             if js is not None:
                 js.settling()
             self._settle_bind_fold(fold)
             if js is not None:
-                js.close(pods=len(job.entries),
+                js.close(pods=len(job.entries), batched=batched,
                          pooled=len(job.pooled) - pooled0)
+
+    @staticmethod
+    def _bind_runs(entries):
+        """A job's rows cut into maximal runs, in batch order: ``((fwk,
+        binder, hooks_s), rows)`` for rows whose binding cycle is their
+        Bind alone and whose profile binds a list in one call
+        (``_bind_batch`` runs them column-wise; ``hooks_s`` as
+        ``Framework.binds_bare`` gives it), ``((fwk, None, None),
+        [row])`` for any other row (``_bind_cycle``, as ever).  Read off
+        the rows, never a knob."""
+        for fwk, rows in itertools.groupby(entries, key=lambda e: e[0]):
+            rows = list(rows)
+            binder = fwk.batch_binder()
+            bare, hooks_s = (fwk.binds_bare([e[1].pod for e in rows])
+                             if binder is not None
+                             else ([False] * len(rows), None))
+            i = 0
+            for ok, flags in itertools.groupby(bare):
+                n = len(list(flags))
+                if ok:
+                    yield (fwk, binder, hooks_s), rows[i:i + n]
+                else:
+                    for e in rows[i:i + n]:
+                        yield (fwk, None, None), [e]
+                i += n
+
+    def _bind_batch(self, fwk: Framework, binder, hooks_s,
+                    rows: List[tuple], fold: BindFold) -> None:
+        """The binding cycle of ``rows`` (entries of the lane's job whose
+        PreBind, WaitOnPermit and PostBind do nothing) a step at a time
+        over all of them, where ``_bind_cycle`` runs all steps a pod:
+        ONE Bind call (one store transaction, whose events the cache and
+        the queue take as one: _add_all_event_handlers), one stamp of the
+        bind table a column, one write of the ``Scheduled`` Events.  What
+        a row owes the fold is what ``_bind_cycle_inner`` gives it.  A
+        row whose bind was rejected goes on where it would be a pod at a
+        time, after the rest: the retry ladder's gate, then the pool for
+        its sleeps, or the failure path."""
+        flight = fold.job.flight
+        done = [e[6] for e in rows]
+        if flight is not None:
+            flight.stamp_binds(done, utrace.BIND_STARTED)
+        rejected: List[tuple] = []
+        try:
+            bind_start = utrace.wallclock()
+            sts = fwk.run_bind_batch(binder, [e[1].pod for e in rows],
+                                     [e[4] for e in rows], hooks_s,
+                                     sink=fold.points)
+            bound = rows
+            if not all(st.is_success() for st in sts):
+                bound = [e for e, st in zip(rows, sts) if st.is_success()]
+                rejected = [(e, st) for e, st in zip(rows, sts)
+                            if not st.is_success()]
+                done = [e[6] for e in bound]    # theirs: _bind_cycle's
+            fold.finished.extend(e[3] for e in bound)
+            if self.metrics:
+                now = utrace.wallclock()
+                fold.bind_s.extend([(now - bind_start,)] * len(bound))
+                fold.scheduled.extend(
+                    (qp.attempts, now - qp.initial_attempt_timestamp,
+                     now - qp.timestamp) for _, qp, *_ in bound)
+            trk = uslo.tracker()
+            if trk is not None:
+                for _, qp, _, _, _, slo, _ in bound:
+                    if slo is not None:
+                        self._slo_observe_terminal(trk, slo, qp, "bound",
+                                                   bind_start=bind_start)
+            if self.recorder:
+                self.recorder.events([
+                    (qp.pod, "Normal", "Scheduled",
+                     f"Successfully assigned {qp.pod.namespace}/"
+                     f"{qp.pod.metadata.name} to {node_name}")
+                    for _, qp, _, _, node_name, _, _ in bound])
+        finally:
+            if flight is not None:
+                flight.stamp_binds(done, utrace.BIND_DONE)
+        raised = None
+        for (_, qp, state, assumed, node_name, slo, row), st in rejected:
+            try:
+                self._bind_cycle(fwk, qp, state, assumed, node_name, None,
+                                 flight, slo, row, fold,
+                                 owed=_LadderOwed(st, bind_start))
+            except Exception as e:      # the other rejected rows still go on
+                raised = raised or e
+        if raised is not None:
+            raise raised
 
     def _settle_bind_fold(self, fold: BindFold) -> None:
         """FinishBinding and the bind metrics for everything ``fold``
-        collected: each histogram's lock once, whatever the number of
-        pods (the cache's once a pod, but in one uncontended run: the
-        cache's source is under the AOT index's digest, left as it is)."""
-        for assumed in fold.finished:
-            self.cache.finish_binding(assumed)
+        collected: the cache's lock and each histogram's once, whatever
+        the number of pods."""
+        self.cache.finish_binding_many(fold.finished)
         m = self.metrics
         if m is not None:
             m.framework_extension_point_duration.observe_many(fold.points)
@@ -2422,10 +2538,13 @@ class Scheduler:
                     assumed: api.Pod, node_name: str,
                     binder_override=None, flight=None,
                     slo=None, row: int = -1,
-                    fold: Optional[BindFold] = None) -> Optional[str]:
+                    fold: Optional[BindFold] = None,
+                    owed: Optional["_LadderOwed"] = None) -> Optional[str]:
         """reference: scheduler.go:628-687.  Called from three threads:
-        the binder lane for every pod of a job (_run_bind_job; ``fold``
-        is the job's), a pool thread for a bind that would block the
+        the binder lane for a pod of a job that cannot ride a batch
+        (_run_bind_job; ``fold`` is the job's; ``owed``: its Bind ran in
+        a batch and was rejected, the cycle goes on from there), a pool
+        thread for a bind that would block the
         lane (_commit), the serving thread itself with
         ``async_binding=False`` or when close() has raced the cycle.
         flight, row: the cycle's CycleRecord and this pod's row of its
@@ -2444,7 +2563,8 @@ class Scheduler:
         moved = False
         try:
             out = self._bind_cycle_inner(fwk, qp, state, assumed, node_name,
-                                         binder_override, slo, fold)
+                                         binder_override, slo, fold,
+                                         owed=owed)
             if not isinstance(out, _LadderOwed):
                 return out
             moved = True

@@ -152,11 +152,19 @@ class SchedulerCache:
 
     def finish_binding(self, pod: api.Pod, now: Optional[float] = None) -> None:
         """reference: cache.go:359 FinishBinding — starts the expiry TTL."""
+        self.finish_binding_many((pod,), now)
+
+    def finish_binding_many(self, pods, now: Optional[float] = None) -> None:
+        """FinishBinding for each of ``pods`` under ONE hold of the lock
+        and one reading of the clock (the binder lane settles a job's
+        binds at once: Scheduler._settle_bind_fold)."""
         with self._lock:
-            st = self.pod_states.get(pod.uid)
-            if st is not None and self.assumed_pods.get(pod.uid):
-                st.binding_finished = True
-                st.deadline = (now if now is not None else self._clock()) + self._ttl
+            deadline = (now if now is not None else self._clock()) + self._ttl
+            for pod in pods:
+                st = self.pod_states.get(pod.uid)
+                if st is not None and self.assumed_pods.get(pod.uid):
+                    st.binding_finished = True
+                    st.deadline = deadline
 
     def forget_pod(self, pod: api.Pod) -> None:
         """reference: cache.go:383 ForgetPod."""
@@ -176,20 +184,40 @@ class SchedulerCache:
     def add_pod(self, pod: api.Pod) -> None:
         """Watch-confirmed pod (reference: cache.go:416 AddPod)."""
         with self._lock:
-            st = self.pod_states.get(pod.uid)
-            if st is not None and self.assumed_pods.get(pod.uid):
-                if st.pod.spec.node_name != pod.spec.node_name:
-                    # the pod was added to a different node than assumed
-                    self._remove_pod(st.pod)
-                    self._add_pod(pod)
-                self.assumed_pods.pop(pod.uid, None)
-                st.deadline = None
-                st.pod = pod
-            elif st is None:
+            self._confirm(pod)
+
+    def _confirm(self, pod: api.Pod) -> None:
+        st = self.pod_states.get(pod.uid)
+        if st is not None and self.assumed_pods.get(pod.uid):
+            if st.pod.spec.node_name != pod.spec.node_name:
+                # the pod was added to a different node than assumed
+                self._remove_pod(st.pod)
                 self._add_pod(pod)
-                self.pod_states[pod.uid] = _PodState(pod=pod)
-            else:
-                raise ValueError(f"pod {pod.uid} was already in added state")
+            self.assumed_pods.pop(pod.uid, None)
+            st.deadline = None
+            st.pod = pod
+        elif st is None:
+            self._add_pod(pod)
+            self.pod_states[pod.uid] = _PodState(pod=pod)
+        else:
+            raise ValueError(f"pod {pod.uid} was already in added state")
+
+    def confirm_pods(self, pods) -> int:
+        """The watch confirmed the binds of ``pods``: AddPod for each, in
+        order, under ONE hold of the lock.  Returns how many of them this
+        cache had NOT assumed (a foreign writer bound them).  A pod
+        already in added state is left as it is, as the scheduler's
+        watch handler has always left it (AddPod's error, ignored)."""
+        foreign = 0
+        with self._lock:
+            for pod in pods:
+                if not self.assumed_pods.get(pod.uid):
+                    foreign += 1
+                try:
+                    self._confirm(pod)
+                except ValueError:
+                    pass
+        return foreign
 
     def update_pod(self, old: api.Pod, new: api.Pod) -> None:
         """reference: cache.go:452 UpdatePod."""

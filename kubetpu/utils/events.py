@@ -14,7 +14,6 @@ objects into it; aggregation semantics match the reference's defaults
 
 from __future__ import annotations
 
-import copy
 import threading
 import time
 from dataclasses import dataclass, field
@@ -61,7 +60,14 @@ class EventRecorder:
         self.component = component
 
     def event(self, obj, type_: str, reason: str, message: str) -> None:
-        self._b._record(self.component, obj, type_, reason, message)
+        self._b._record(self.component, ((obj, type_, reason, message),))
+
+    def events(self, rows) -> None:
+        """``event(*row)`` for each ``(obj, type, reason, message)`` of
+        ``rows``, in order, as ONE write: the broadcaster's lock once,
+        and the new Events to the sink in one call where it takes many
+        (``add_many``)."""
+        self._b._record(self.component, rows)
 
 
 class EventBroadcaster:
@@ -102,55 +108,74 @@ class EventBroadcaster:
         with self._lock:
             self._watchers.append(fn)
 
-    def _record(self, component: str, obj, type_: str, reason: str,
-                message: str) -> None:
+    def _record(self, component: str, rows) -> None:
         now = self._clock()
-        meta = getattr(obj, "metadata", api.ObjectMeta())
-        key = (component, getattr(obj, "kind", ""), meta.namespace,
-               meta.name, type_, reason)
+        sink = self.sink
+        add_many = getattr(sink, "add_many", None)
+        out: List[Event] = []
+        fresh: List[Event] = []      # new Events the sink has not seen yet
+
+        def flush() -> None:
+            # one Event the sink refuses costs no other, as ever
+            if add_many is not None and fresh:
+                try:
+                    add_many(fresh)
+                except Exception:
+                    pass
+            elif sink is not None:
+                for ev in fresh:
+                    try:
+                        sink.add(ev)
+                    except Exception:
+                        pass
+            fresh.clear()
+
         with self._lock:
-            ev = self._cache.get(key)
-            if ev is not None:
-                self._cache.move_to_end(key)
-            if ev is not None and now - ev.last_timestamp <= self._window:
-                ev.count += 1
-                ev.last_timestamp = now
-                ev.message = message
-                # watchers and the sink get an immutable SNAPSHOT taken
-                # under the lock: the cached Event keeps mutating on
-                # aggregation, and handing out the live object would let
-                # concurrent recorders expose torn count/message reads
-                ev = copy.copy(ev)
-                if self.sink is not None:
-                    try:
-                        self.sink.update(ev)
-                    except Exception:
-                        pass
-            else:
-                self._seq += 1
-                ev = Event(
-                    metadata=api.ObjectMeta(
-                        name=f"{meta.name}.{self._seq:x}",
-                        namespace=meta.namespace or "default"),
-                    involved_kind=getattr(obj, "kind", ""),
-                    involved_namespace=meta.namespace,
-                    involved_name=meta.name,
-                    involved_uid=getattr(obj, "uid", meta.uid),
-                    type=type_, reason=reason, message=message,
-                    count=1, first_timestamp=now, last_timestamp=now)
-                self._cache[key] = ev
-                # LRU bound (events_cache.go maxLruCacheEntries): evicted
-                # keys simply start a fresh Event on their next repeat
-                while len(self._cache) > self._max:
-                    self._cache.popitem(last=False)
-                # same immutable-snapshot rule: the cached instance will
-                # mutate on future aggregations
-                ev = copy.copy(ev)
-                if self.sink is not None:
-                    try:
-                        self.sink.add(ev)
-                    except Exception:
-                        pass
+            for obj, type_, reason, message in rows:
+                meta = getattr(obj, "metadata", None) or api.ObjectMeta()
+                kind = getattr(obj, "kind", "")
+                key = (component, kind, meta.namespace, meta.name, type_,
+                       reason)
+                ev = self._cache.get(key)
+                if ev is not None:
+                    self._cache.move_to_end(key)
+                if ev is not None and now - ev.last_timestamp <= self._window:
+                    # an Event handed out (to the sink, to watchers) is
+                    # never written again: a repeat aggregates into a
+                    # COPY, which takes its place in the cache, so
+                    # nobody reads a torn count / message
+                    ev = self._cache[key] = api.shallow_copy(ev)
+                    ev.count += 1
+                    ev.last_timestamp = now
+                    ev.message = message
+                    if sink is not None:
+                        flush()     # the sink sees the rows in order
+                        try:
+                            sink.update(ev)
+                        except Exception:
+                            pass
+                else:
+                    self._seq += 1
+                    ev = Event(
+                        metadata=api.ObjectMeta(
+                            name=f"{meta.name}.{self._seq:x}",
+                            namespace=meta.namespace or "default"),
+                        involved_kind=kind,
+                        involved_namespace=meta.namespace,
+                        involved_name=meta.name,
+                        involved_uid=getattr(obj, "uid", meta.uid),
+                        type=type_, reason=reason, message=message,
+                        count=1, first_timestamp=now, last_timestamp=now)
+                    self._cache[key] = ev
+                    # LRU bound (events_cache.go maxLruCacheEntries):
+                    # evicted keys simply start a fresh Event on their
+                    # next repeat
+                    while len(self._cache) > self._max:
+                        self._cache.popitem(last=False)
+                    fresh.append(ev)
+                out.append(ev)
+            flush()
             watchers = list(self._watchers)
-        for fn in watchers:
-            fn(ev)
+        for ev in out:
+            for fn in watchers:
+                fn(ev)

@@ -16,6 +16,8 @@ from __future__ import annotations
 
 import threading
 import time
+from bisect import bisect_left
+from itertools import accumulate
 from typing import Dict, List, Optional, Tuple
 
 # default duration buckets (prometheus.DefBuckets)
@@ -69,40 +71,59 @@ class Gauge(Counter):
 
 
 class Histogram:
+    """Counts are kept A BUCKET (slot i: observations in (buckets[i-1],
+    buckets[i]], the last slot past the largest edge), so an observation
+    is one bisect and one increment; the exposition's cumulative ``le``
+    series are summed at scrape time."""
+
     def __init__(self, name: str, help_: str, label_names=(),
                  buckets=DEF_BUCKETS):
         self.name, self.help = name, help_
         self.label_names = tuple(label_names)
         self.buckets = tuple(buckets)
-        self._counts: Dict[Tuple, List[int]] = {}
-        self._sums: Dict[Tuple, float] = {}
+        self._counts: Dict[Tuple, List[int]] = {}  # kubelint: guarded-by(_lock)
+        self._sums: Dict[Tuple, float] = {}  # kubelint: guarded-by(_lock)
         self._lock = threading.Lock()
 
     def observe(self, value: float, *labels):
         with self._lock:
-            self._record(value, labels)
+            self._slots(labels)[bisect_left(self.buckets, value)] += 1
+            self._sums[labels] = self._sums.get(labels, 0.0) + value
 
     def observe_many(self, rows) -> None:
         """Each row ``(value, *labels)`` observed as ``observe`` would,
         all under ONE hold of the lock: the binder lane folds a whole
-        job's observations into one call (Scheduler._run_bind_job)."""
+        job's observations into one call (Scheduler._run_bind_job).  A
+        bisect a row, never a walk of the buckets."""
         with self._lock:
+            buckets = self.buckets
+            labels = counts = None
+            total = 0.0
             for row in rows:
-                self._record(row[0], row[1:])
+                if row[1:] != labels:
+                    # a run of rows with one label set keeps its slots
+                    # and its sum in locals; the sum adds up in row
+                    # order, as N observes would
+                    if labels is not None:
+                        self._sums[labels] = total
+                    labels = row[1:]
+                    counts = self._slots(labels)
+                    total = self._sums.get(labels, 0.0)
+                counts[bisect_left(buckets, row[0])] += 1
+                total += row[0]
+            if labels is not None:
+                self._sums[labels] = total
 
-    def _record(self, value: float, labels: Tuple) -> None:
-        counts = self._counts.setdefault(labels,
-                                         [0] * (len(self.buckets) + 1))
-        for i, b in enumerate(self.buckets):
-            if value <= b:
-                counts[i] += 1
-        counts[-1] += 1  # +Inf
-        self._sums[labels] = self._sums.get(labels, 0.0) + value
+    def _slots(self, labels: Tuple) -> List[int]:
+        counts = self._counts.get(labels)
+        if counts is None:
+            counts = self._counts[labels] = [0] * (len(self.buckets) + 1)
+        return counts
 
     def count(self, *labels) -> int:
         with self._lock:
             c = self._counts.get(labels)
-            return c[-1] if c else 0
+            return sum(c) if c else 0
 
     def sum(self, *labels) -> float:
         with self._lock:
@@ -111,8 +132,7 @@ class Histogram:
     def percentile(self, q: float, *labels) -> float:
         """Approximate quantile from bucket counts (upper bound)."""
         with self._lock:
-            c = self._counts.get(labels)
-            c = list(c) if c else None
+            c = list(accumulate(self._counts.get(labels, ())))
         if not c or c[-1] == 0:
             return 0.0
         target = q * c[-1]
@@ -126,7 +146,7 @@ class Histogram:
         out = [f"# HELP {self.name} {_escape_help(self.help)}",
                f"# TYPE {self.name} histogram"]
         with self._lock:
-            snapshot = sorted((k, list(v), self._sums[k])
+            snapshot = sorted((k, list(accumulate(v)), self._sums[k])
                               for k, v in self._counts.items())
         for labels, counts, total in snapshot:
             for i, b in enumerate(self.buckets):

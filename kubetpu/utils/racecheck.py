@@ -61,7 +61,7 @@ GUARDED: Dict[Tuple[str, str], Tuple[str, Tuple[str, ...]]] = {
         ("_cond", ("active_q", "backoff_q", "unschedulable_q",
                    "scheduling_cycle", "move_request_cycle", "_closed")),
     ("kubetpu.client.store", "ClusterStore"):
-        ("_lock", ("_objs", "_subs", "_assumed_pv")),
+        ("_lock", ("_objs", "_subs", "_batch_subs", "_assumed_pv")),
     ("kubetpu.utils.events", "EventBroadcaster"):
         ("_lock", ("_cache", "_seq", "_watchers")),
     ("kubetpu.utils.features", "FeatureGate"):
@@ -105,7 +105,10 @@ class _Registry:
         self.armed = False
         self.hold_ms = 200.0
         self.sample = 1
-        self._mu = threading.Lock()
+        # reentrant: a tracked container's finalizer (_untrack) runs
+        # wherever the collector fires, also on a thread that is inside
+        # one of the blocks below
+        self._mu = threading.RLock()
         self.violations: List[Violation] = []  # kubelint: guarded-by(_mu)
         # lock-order edges: (a, b) means a was held while b was acquired
         self.edges: Dict[Tuple[str, str], str] = {}  # kubelint: guarded-by(_mu)

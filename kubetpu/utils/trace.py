@@ -239,6 +239,23 @@ class CycleRecord:
         if which == BIND_STARTED:
             self._bind_thread[row] = threading.current_thread().name
 
+    def stamp_binds(self, rows, which: int) -> None:
+        """``stamp_bind(row, which)`` for each of ``rows`` with ONE
+        reading of the clock: the rows of a batch start and end
+        together (Scheduler._bind_batch)."""
+        tbl = self._bind_t
+        if tbl is None:
+            return
+        now = wallclock()
+        name = (threading.current_thread().name
+                if which == BIND_STARTED else None)
+        n = len(self._bind_thread)
+        for row in rows:
+            if 0 <= row < n:
+                tbl[BIND_STAMPS * row + which] = now
+                if name is not None:
+                    self._bind_thread[row] = name
+
     def bind_rows(self) -> List[Tuple[float, float, float, Optional[str]]]:
         """(submitted, started, done, thread) per pod of the batch, in
         batch order; zeros where the pod was never submitted (it did not

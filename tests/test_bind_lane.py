@@ -570,14 +570,45 @@ def test_binds_through_the_lane_leave_the_metrics_the_pool_leaves():
     assert set(counts) <= set(pool_text.splitlines())
 
 
-def test_observe_many_is_observe_many_times():
+BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
+
+
+@pytest.mark.parametrize("rows", [
+    [(0.003, "x"), (0.2, "y"), (7.0, "x"), (0.04, "x")],
+    [(0.005, "x"), (0.01, "x"), (10.0, "x"), (1.0, "y")],     # on an edge
+    [(0.0, "x"), (-1.0, "x"), (0.0049999, "y")],        # below the first
+    [(10.000001, "x"), (1e9, "y"), (float("inf"), "x")],    # above the last
+    [(0.1, "x"), (0.1, "y"), (0.2, "x"), (0.3, "y"), (0.1 + 0.2, "x")],
+    [(0.1,)] * 1024 + [(0.7,)] * 3,                           # no labels
+    [],
+], ids=["mixed", "on-an-edge", "below-the-first", "above-the-last",
+        "interleaved-labels", "a-job", "empty"])
+def test_observe_many_is_observe_many_times(rows):
     from kubetpu.utils.metrics import Histogram
-    one, many = Histogram("h", "", ("a",)), Histogram("h", "", ("a",))
-    rows = [(0.003, "x"), (0.2, "y"), (7.0, "x"), (0.04, "x")]
-    for v, a in rows:
-        one.observe(v, a)
+    labels = ("a",) if rows and len(rows[0]) > 1 else ()
+    one, many = (Histogram("h", "", labels, BUCKETS) for _ in range(2))
+    for row in rows:
+        one.observe(*row)
     many.observe_many(rows)
-    assert one.expose() == many.expose()
+    assert "\n".join(one.expose()) == "\n".join(many.expose())
+    for lab in {r[1:] for r in rows}:
+        assert one.count(*lab) == many.count(*lab) == sum(
+            1 for r in rows if r[1:] == lab)
+        assert one.sum(*lab) == many.sum(*lab)
+        for q in (0.5, 0.99):
+            assert one.percentile(q, *lab) == many.percentile(q, *lab)
+
+
+def test_the_exposition_is_cumulative_as_ever():
+    from kubetpu.utils.metrics import Histogram
+    h = Histogram("h", "help", (), (1, 2, 4))
+    h.observe_many([(0.5,), (1,), (3,), (9,)])
+    assert h.expose() == [
+        "# HELP h help", "# TYPE h histogram",
+        'h_bucket{le="1"} 2', 'h_bucket{le="2"} 2', 'h_bucket{le="4"} 3',
+        'h_bucket{le="+Inf"} 4', "h_sum 13.5", "h_count 4"]
+    assert h.count() == 4 and h.percentile(0.5) == 1
+    assert h.percentile(0.99) == 4      # past the last edge: clamped
 
 
 # ------------------------------------------------------------- the lane alone

@@ -264,17 +264,22 @@ def test_disarmed_the_hand_over_and_the_job_read_no_clock(monkeypatch):
                 ran_on.append(threading.current_thread().name)
         return call
     real_run, real_over = Scheduler._run_bind_job, Scheduler._hand_over
-    real_bind = Scheduler._bind_cycle
 
-    def bind_cycle(self, *a, **kw):
-        # a pod's binding cycle times itself for the bind metrics, armed
-        # or not: the poison is for what the job adds around it
-        depth, inside.depth = getattr(inside, "depth", 0), 0
-        try:
-            return real_bind(self, *a, **kw)
-        finally:
-            inside.depth = depth
-    monkeypatch.setattr(Scheduler, "_bind_cycle", bind_cycle)
+    def unpoisoned(real):
+        def call(self, *a, **kw):
+            # a binding cycle (a pod's, a batch's) times itself for the
+            # bind metrics, armed or not: the poison is for what the job
+            # adds around it
+            depth, inside.depth = getattr(inside, "depth", 0), 0
+            try:
+                return real(self, *a, **kw)
+            finally:
+                inside.depth = depth
+        return call
+    monkeypatch.setattr(Scheduler, "_bind_cycle",
+                        unpoisoned(Scheduler._bind_cycle))
+    monkeypatch.setattr(Scheduler, "_bind_batch",
+                        unpoisoned(Scheduler._bind_batch))
     monkeypatch.setattr(Scheduler, "_hand_over", poisoned(real_over))
     monkeypatch.setattr(Scheduler, "_run_bind_job", poisoned(real_run))
     store, sched = _world(pods=64)      # its lane binds the patched job

@@ -135,6 +135,99 @@ def test_stress_8_threads_50_iterations_clean():
             "host-only stress compiled a device program"
 
 
+def test_bind_transactions_against_adds_and_deletes_are_clean():
+    """PR 41's batch forms under the harness: ``bind_many`` transactions
+    delivered whole to a list-taking subscriber that confirms them in the
+    cache and the queue (``confirm_pods``, ``pods_bound``) and settles
+    them (``finish_binding_many``), the job's events in one
+    ``add_many``, while other threads add, delete and list pods and a
+    per-event subscriber deletes what it hears bound."""
+    from kubetpu.client.store import ClusterStore
+    from kubetpu.schedqueue.queue import SchedulingQueue
+    from kubetpu.state.cache import SchedulerCache
+    from kubetpu.utils.events import EventBroadcaster
+
+    with racechecked_relaxed_hold() as reg:
+        for it in range(10):
+            store, cache = ClusterStore(), SchedulerCache()
+            queue = SchedulingQueue()
+            recorder = EventBroadcaster(sink=store).new_recorder()
+            for j in range(4):
+                store.add(_node(f"n{j}"))
+                cache.add_node(_node(f"n{j}"))
+            confirmed, deleted = [], []
+
+            def on_pods(events):
+                bound = [new for ev, old, new in events
+                         if ev == "update" and new.spec.node_name
+                         and not old.spec.node_name]
+                for ev, old, new in events:
+                    if ev == "add" and not new.spec.node_name:
+                        queue.add(new)
+                    elif ev == "delete":
+                        queue.delete(old)
+                        if old.spec.node_name:
+                            cache.remove_pod(old)
+                if bound:
+                    confirmed.append(len(bound) - cache.confirm_pods(bound))
+                    queue.pods_bound(bound)
+
+            def on_pod(ev, old, new):
+                if (ev == "update" and new.spec.node_name
+                        and new.metadata.name.endswith("3")):
+                    store.delete(new)
+                    deleted.append(new.metadata.name)
+            store.subscribe("Pod", on_pods, batched=True)
+            store.subscribe("Pod", on_pod)
+            errors = []
+
+            def binder(base):
+                def run():
+                    for k in range(6):
+                        pods = [_pod(f"it{it}-b{base}-{k}-{i}")
+                                for i in range(5)]
+                        for p in pods:
+                            store.add(p)
+                        queue.pop_batch(8, timeout=0)
+                        assumed = []
+                        for i, p in enumerate(pods):
+                            a = api.shallow_copy(p)
+                            a.spec = api.shallow_copy(p.spec)
+                            a.spec.node_name = f"n{i % 4}"
+                            cache.assume_pod(a)
+                            assumed.append(a)
+                        got = store.bind_many(
+                            [(p, a.spec.node_name)
+                             for p, a in zip(pods, assumed)])
+                        assert got == [None] * 5, got
+                        cache.finish_binding_many(assumed, now=0.0)
+                        recorder.events([(p, "Normal", "Scheduled", "ok")
+                                         for p in pods])
+                return run
+
+            def churn(base):
+                def run():
+                    for k in range(OPS):
+                        p = _pod(f"it{it}-c{base}-{k}")
+                        store.add(p)
+                        store.list("Pod")
+                        store.delete(p)
+                return run
+
+            _hammer([binder(0), binder(1), churn(0), churn(1),
+                     lambda: [cache.pod_count() or len(queue)
+                              for _ in range(OPS)]], errors)
+            assert not errors, errors
+            vs = reg.snapshot()
+            assert not vs, "\n".join(str(v) for v in vs)
+            # every bind was one this cache had assumed, and confirmed
+            assert sum(confirmed) == 60 and not cache.assumed_pods
+            assert len(deleted) == 12 == 60 - cache.pod_count()
+            assert len(store.list("Event")) == 60 and len(queue) == 0
+            queue.close()
+            cache.close()
+
+
 def racechecked_relaxed_hold():
     """Stress iterations share one armed scope; CI boxes can stall a
     thread scheduler tick, so the hold threshold is generous — the
