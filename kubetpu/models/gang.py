@@ -119,6 +119,10 @@ class GangResult(NamedTuple):
                             # deferrals are not counted).  Diagnostics: not
                             # in packed, read back only by an armed flight
                             # recorder, after packed
+    soft_spread_skew: jnp.ndarray  # i32 K.spread_soft_skew after the last
+                            # round's admits; -1 for a batch without a
+                            # ScheduleAnyway constraint.  Diagnostics,
+                            # read back as capacity_deferred is
 
 
 def _segment_base(values: jnp.ndarray, is_start: jnp.ndarray) -> jnp.ndarray:
@@ -928,10 +932,14 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
     packed = jnp.concatenate([out["assigned"], n_feas,
                               all_unres.astype(jnp.int32),
                               out["rounds"].reshape(1)])
+    soft_skew = (K.spread_soft_skew(cluster_at(out), batch,
+                                    match_ns=score_pre["spread_soft"])
+                 if "PodTopologySpread" in score_names else jnp.int32(-1))
     return GangResult(chosen=out["assigned"], score=out["win_score"],
                       rounds=out["rounds"], requested=out["req"],
                       nz=out["nz"], ports_used=out["ports_used"],
                       feasible0=out["feas0"], unresolvable=unresolvable,
                       n_feasible=n_feas,
                       all_unresolvable=all_unres, packed=packed,
-                      capacity_deferred=out["cap_deferred"])
+                      capacity_deferred=out["cap_deferred"],
+                      soft_spread_skew=soft_skew)

@@ -524,14 +524,14 @@ def _sequential_program(cluster, batch, cfg: ProgramConfig, rng,
             topo_size = jnp.sum(_f(reg), axis=1)
             n_scored = jnp.sum(_f(scored))
             size = jnp.where(is_host, n_scored, topo_size)
-            weight = jnp.log(size + 2.0)
             pair_c = K.pair_gather(jnp.where(reg, cnt, 0.0), npair)  # [Cs, N]
             cval = jnp.where(is_host[:, None], ncnt, pair_c)
             ms = scons.max_skew[i][:, None]
             cval = jnp.where(cval < ms, ms - 1.0, cval)
-            contrib = jnp.where((valid & scons.topo_known[i])[:, None]
-                                & sst.has_key[i], cval * weight[:, None], 0.0)
-            raw = jnp.floor(jnp.sum(contrib, axis=0))
+            raw = K.log_weighted_floor(
+                cval, size,
+                (valid & scons.topo_known[i])[:, None] & sst.has_key[i],
+                N + 1)
             raw = jnp.where(ignored, 0.0, raw)
             min_s = jnp.min(jnp.where(scored, raw, big))
             max_s = jnp.maximum(jnp.max(jnp.where(scored, raw, neg)), 0.0)

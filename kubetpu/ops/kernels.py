@@ -13,10 +13,13 @@ state/tensors.py for the unit-scaling argument).
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..state.tensors import CH_CPU, CH_EPH, CH_MEM, CH_PODS, N_FIXED_CHANNELS
 from .selectors import match_selectors, match_selectors_unique
@@ -346,6 +349,80 @@ def node_affinity_filter(cluster, batch) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
+# PodTopologySpread's raw score: int64(sum of float64(count) * log(size + 2))
+#
+# The reference multiplies in float64 and truncates.  A float32 product
+# floors to another integer wherever cnt * log(size + 2) lies within the
+# float32 error of one: with the host's float32 log at 0.3% of the pairs
+# (cnt to 16,384, size to 8,192), for three zones first at 4,217 matching
+# pods; with a v5e's own log, good to five digits (log 5 reads 1.60946),
+# at 16% of them, for three zones first at 233 (PERF.md, section 6, PR 42;
+# tests/test_spread_soft_product.py has the enumeration).  So the product
+# is made in integers: log(k + 2) as the float64 the reference holds,
+# taken from the HOST's math.log at trace time and carried as a fixed-point
+# integer in 12-bit limbs, the count in two, schoolbook products in int32,
+# the floor a shift.  That is the floor of the EXACT product of the count
+# and the float64 weight, summed over a pod's constraints before the
+# floor as the reference sums before it truncates; the reference's own
+# float64 rounding of each product and of their sum (relative 2**-52)
+# could part from it only where the exact sum lies that close under an
+# integer, which no enumerated pair does.
+
+_LIMB_BITS = 12
+_LIMB_MASK = (1 << _LIMB_BITS) - 1
+_LOG_LIMBS = 5            # 60 bits: log(k + 2) * 2**53 < 2**57 up to k = 8.8e6
+_LOG_FRAC_BITS = 53       # log(k + 2) >= log 2 > 1/2: a float64 there is a
+                          # multiple of 2**-53
+_CNT_LIMBS = 2            # counts < 2**24 (per_node_counts' own bound)
+_MAX_SOFT_CONSTRAINTS = 32   # C * 2 * 2**24 stays inside int32
+
+
+@functools.lru_cache(maxsize=None)
+def _log_weight_limbs(n: int) -> np.ndarray:
+    """[n, _LOG_LIMBS] int32: math.log(k + 2) * 2**53 for k in 0..n-1,
+    exactly (a float64 is a dyadic rational), least limb first."""
+    out = np.zeros((n, _LOG_LIMBS), np.int32)
+    for k in range(n):
+        num, den = math.log(k + 2.0).as_integer_ratio()
+        fixed = (num << _LOG_FRAC_BITS) // den       # den divides 2**53
+        for i in range(_LOG_LIMBS):
+            out[k, i] = (fixed >> (_LIMB_BITS * i)) & _LIMB_MASK
+    return out
+
+
+def log_weighted_floor(cnt: jnp.ndarray, size: jnp.ndarray,
+                       counted: jnp.ndarray, n_sizes: int) -> jnp.ndarray:
+    """floor(sum over constraints c of cnt[..., c, n] * log(size[..., c] + 2))
+    over the ``counted`` entries, per node: [..., C, N] f32 whole-number
+    counts under 2**24, [..., C] f32 whole-number sizes in [0, n_sizes),
+    [..., C, N] bool -> [..., N] f32, a whole number that is exact while
+    the score stays under 2**24 (NormalizeScore's own f32 bound is lower:
+    100 x twice the score).  See the note above."""
+    if cnt.shape[-2] > _MAX_SOFT_CONSTRAINTS:
+        raise ValueError(f"{cnt.shape[-2]} soft constraints a pod: the limb "
+                         f"sums hold {_MAX_SOFT_CONSTRAINTS}")
+    table = jnp.asarray(_log_weight_limbs(n_sizes))
+    w = jnp.take(table, jnp.clip(size.astype(jnp.int32), 0, n_sizes - 1),
+                 axis=0)                                    # [..., C, 5]
+    ci = jnp.where(counted, cnt, 0.0).astype(jnp.int32)
+    c_limbs = (ci & _LIMB_MASK, ci >> _LIMB_BITS)
+    limbs = [jnp.zeros(cnt.shape[:-2] + cnt.shape[-1:], jnp.int32)
+             for _ in range(_LOG_LIMBS + _CNT_LIMBS)]
+    for j, c in enumerate(c_limbs):
+        for i in range(_LOG_LIMBS):
+            limbs[i + j] = limbs[i + j] + jnp.sum(
+                c * w[..., i][..., None], axis=-2)
+    for k in range(len(limbs) - 1):                  # carry, least first
+        limbs[k + 1] = limbs[k + 1] + (limbs[k] >> _LIMB_BITS)
+        limbs[k] = limbs[k] & _LIMB_MASK
+    whole, part = divmod(_LOG_FRAC_BITS, _LIMB_BITS)  # bits below the point
+    out = limbs[whole] >> part
+    for k in range(whole + 1, len(limbs)):
+        out = out + (limbs[k] << (_LIMB_BITS * (k - whole) - part))
+    return out.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
 # PodTopologySpread
 
 
@@ -541,16 +618,16 @@ def spread_soft_score(cluster, batch, feasible, affinity_ok,
         topo_size = jnp.round(jnp.sum(inv, axis=1)).reshape(B, C)
         n_scored = jnp.sum(_f(scored), axis=1)  # [B]
         size = jnp.where(is_host, n_scored[:, None], topo_size)
-        weight = jnp.log(size + 2.0)  # reference: scoring.go:286
 
         pair_cnt = jnp.where(registered, cnt_pair, 0.0).reshape(B, C, N)
         cnt = jnp.where(is_host[:, :, None], node_counts, pair_cnt)
         # adjustForMaxSkew (scoring.go:294)
         ms = cons.max_skew[:, :, None]
         cnt = jnp.where(cnt < ms, ms - 1.0, cnt)
-        contrib = jnp.where((valid & cons.topo_known)[:, :, None] & has_key,
-                            cnt * weight[:, :, None], 0.0)
-        raw = jnp.floor(jnp.sum(contrib, axis=1))  # int64(score)
+        # int64(sum of float64(cnt) * log(size + 2)) (scoring.go:286)
+        raw = log_weighted_floor(
+            cnt, size, (valid & cons.topo_known)[:, :, None] & has_key,
+            N + 1)
         raw = jnp.where(ignored, 0.0, raw)
 
         # NormalizeScore (scoring.go:210-257): min/max over non-ignored filtered
@@ -575,7 +652,45 @@ def spread_soft_score(cluster, batch, feasible, affinity_ok,
         return jnp.where(feasible, jnp.float32(MAX_NODE_SCORE),
                          jnp.float32(0.0))
 
-    return _if_live(jnp.any(cons.valid), live, dead)
+    # the profiler's op metadata names everything below (Perfetto, xprof)
+    with jax.named_scope("spread_soft_score"):
+        return _if_live(jnp.any(cons.valid), live, dead)
+
+
+def spread_soft_skew(cluster, batch, match_ns=None) -> jnp.ndarray:
+    """Diagnostics, not a score: for the batch's FIRST valid ScheduleAnyway
+    constraint, the most less the least count of the pods its selector
+    matches (its owner's namespace, terminating pods left out), over the
+    pairs of its topology key that a valid node carries; i32, -1 where the
+    batch has no such constraint.  ScheduleAnyway bounds no skew: this is
+    what a cycle left behind.  One row's counts by pair id (P + N + L
+    additions, no [., P] x [P, N] product), behind the set's gate.
+    match_ns: the hoisted spread_match_ns ([Us, P])."""
+    cons = batch.spread_soft
+    C = cons.topo_key.shape[1]
+    L = cluster.kv.shape[1]
+
+    def live():
+        row = jnp.argmax((cons.valid & cons.topo_known).reshape(-1))
+        pre = (_pod_axis_match(cluster, cons.sel) if match_ns is None
+               else match_ns)
+        ns_ok = jnp.einsum("n,pn->p", batch.ns_hot[row // C],
+                           cluster.pod_ns_hot,
+                           preferred_element_type=jnp.float32) > 0.5
+        m = (pre[jnp.asarray(cons.sel.index).reshape(-1)[row]] & ns_ok
+             & cluster.pod_valid & ~cluster.pod_terminating)      # [P]
+        key = cons.topo_key.reshape(-1)[row][None]
+        cnt = pair_scatter(m[None, :], pod_topo_pairs(cluster, key), L)[0]
+        node_pair = jnp.where(cluster.node_valid[None, :],
+                              node_topo_pairs(cluster, key), -1)
+        on = pair_scatter(jnp.ones_like(node_pair), node_pair, L)[0] > 0.5
+        big = jnp.float32(2**31)
+        skew = (jnp.max(jnp.where(on, cnt, -big))
+                - jnp.min(jnp.where(on, cnt, big)))
+        return jnp.where(jnp.any(on), skew, -1.0).astype(jnp.int32)
+
+    return _if_live(jnp.any(cons.valid & cons.topo_known), live,
+                    lambda: jnp.int32(-1))
 
 
 # ---------------------------------------------------------------------------

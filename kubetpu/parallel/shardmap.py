@@ -644,7 +644,10 @@ def _gang_tiled(cluster, batch, cfg, rng, mesh, host_ok, score_bias,
                       requested=req, nz=nz, ports_used=ports_used,
                       feasible0=feas0, unresolvable=static_unres,
                       n_feasible=n_feas, all_unresolvable=all_unres,
-                      packed=packed, capacity_deferred=cap_deferred)
+                      packed=packed, capacity_deferred=cap_deferred,
+                      # a batch with a soft constraint never tiles
+                      # (gang_surface): nothing to read
+                      soft_spread_skew=jnp.int32(-1))
 
 
 # --------------------------------------------------------------------------

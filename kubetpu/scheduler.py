@@ -944,8 +944,10 @@ class Scheduler:
         with trace.stage("batch-build", pods=len(pinfos)) as build_span:
             batch = self._jax.tree.map(
                 np.asarray, pb.build(pinfos, spread_selectors=spread_sels))
-            # valid DoNotSchedule constraint rows the builder compiled
+            # valid DoNotSchedule constraint rows the builder compiled,
+            # and the ScheduleAnyway ones beside them
             spread_rows = int(batch.spread.valid.sum())
+            soft_spread_rows = int(batch.spread_soft.valid.sum())
             term_sets_live = live_term_sets(batch)
             if build_span is not None:
                 build_span.args["spread_rows"] = spread_rows
@@ -994,6 +996,7 @@ class Scheduler:
             # constraint (C) and unique-selector (Us) buckets the
             # auction's recount runs over, padding included
             trace.rec.meta["spread_constraints"] = spread_rows
+            trace.rec.meta["spread_soft_constraints"] = soft_spread_rows
             # the term sets whose existing-pod products this batch's
             # auction runs (ops/kernels.py _if_live); the rest are gated off
             trace.rec.meta["term_sets_live"] = term_sets_live
@@ -1558,6 +1561,11 @@ class Scheduler:
                 # being done
                 prep.trace.rec.meta["capacity_deferred"] = int(
                     res.capacity_deferred)
+                if prep.trace.rec.meta.get("spread_soft_constraints"):
+                    # the same for K.spread_soft_skew, where the batch
+                    # carries a ScheduleAnyway constraint
+                    prep.trace.rec.meta["spread_soft_skew"] = int(
+                        res.soft_spread_skew)
         self.device_wait_s += wait
         return packed
 
