@@ -33,7 +33,9 @@ Invariants:
   registered ports is deferred to the next round;
 - progress: the first proposer of every proposed-to node always fits (the
   node was feasible for it this round), so each round either admits >=1 pod
-  or proves the remaining pods unschedulable — the loop terminates;
+  or proves the remaining pods unschedulable — the loop terminates (a
+  round that proposed past the strict spread verdict is followed by a
+  strict one before it proves anything: see below);
 - uncontended agreement: when every pod's argmax is distinct and capacity
   suffices, round 1 admits every pod at exactly the node the sequential
   replay picks under the same rng.
@@ -50,29 +52,85 @@ filter_terms so they repel later-round pods (the existing-pods direction),
 and their preferred and required-affinity terms into score_terms, so they
 score later-round pods the way bound pods' terms do (_extend_cluster).
 Within a round, a conservative same-topology-pair deferral keeps admission
-order safe.  A pod with a required anti-affinity term is deferred to the
-next round if any earlier-index pod matching that term was admitted this
-round into the topo pair the term's key maps its proposal to (and any pod is
-deferred from a pair an earlier-admitted pod whose anti-affinity term
-selects it landed in).  A pod with a hard spread constraint has a BUDGET:
-the filter that made its proposal feasible this round computed
-slack = maxSkew - (matchNum + selfMatch - minMatch) >= 0 there
-(K.spread_filter, return_slack), and the pod is deferred only once MORE
-THAN slack earlier-index pods matching the constraint's selector were
-admitted this round into that pair.  With e <= slack such admissions,
-matchNum + e + selfMatch - min' <= maxSkew holds at the pod's turn in pod
-order for every min' >= minMatch, and the minimum only rises inside a
-round (admissions add matching pods, nothing leaves, and the registered
-pairs do not depend on the carry): that is PodTopologySpread's filter as
-the serial loop evaluates it, with the round-start minimum standing in for
-the true one.  A constraint with no room left (skew at maxSkew) defers
-behind the first such pod by the same arithmetic, not by a switch.  The
-earlier pods counted are the capacity-admitted ones, those this deferral
-then holds back included: a superset of the admitted, so the count errs
-high.  The next round re-checks every deferred pod against exact committed
-counts.  Deferral never blocks the first admitted pod, so progress is
-preserved.  Score staleness within a single round (not across rounds) is
-the remaining gap vs the sequential replay mode.
+order safe for InterPodAffinity.  A pod with a required anti-affinity term
+is deferred to the next round if any earlier-index pod matching that term
+was admitted this round into the topo pair the term's key maps its proposal
+to (and any pod is deferred from a pair an earlier-admitted pod whose
+anti-affinity term selects it landed in); a pod admitted only by the
+self-match bootstrap defers behind any earlier admission.  The earlier pods
+these rules count are the capacity-admitted ones: a superset of the
+admitted, so they err high.  They never block the first admitted pod.
+
+A hard (DoNotSchedule) spread constraint is held IN POD ORDER inside the
+round, at the pod's turn and not at the round's start (spread_turns).  The
+filter of the round (K.spread_filter, return_slack) hands back, from the
+intermediates of its verdict, slack = maxSkew - (matchNum + selfMatch -
+minMatch) on every node and floor, the matching pods of every pair
+REGISTERED for the constraint (the pairs its minimum runs over).  One serial
+pass over the window's rows, which are ascending batch indices, so window
+order is the full round's order, then admits pod j iff for each of its
+valid constraints c, with p its proposal's pair under c's key,
+
+    matchNum0[p] + e + selfMatch - (minMatch0 + rise) <= maxSkew,
+    i.e.  e - rise <= slack at the proposal,
+
+e the pods admitted before j THIS round that c's selector matches and that
+landed in p, rise = min over c's registered pairs of (floor + this round's
+matching admits there) - minMatch0 >= 0.  That is PodTopologySpread's
+filter as the serial loop evaluates it at j's turn, exactly, because:
+- the pass runs LAST, on the pods capacity, rules A / B and the bootstrap
+  rule admit, and counts a pod only once this rule itself has admitted it:
+  a pod held back lifts nobody's minimum and uses up nobody's room;
+- the filter here counts EVERY node's pods into a registered pair
+  (spread_filter: cnt runs over all nodes, registered over the pod's
+  eligible ones), so an admitted pod counts for j wherever in the pair it
+  landed: no eligibility test on the landing node is needed, none is made;
+- the minimum runs over j's registered pairs only: an unregistered pair
+  carries _UNREGISTERED in floor, counts 0 pods at the proposal (the
+  reference's nil tpCount) and is in no minimum;
+- slack is +inf where the filter tolerates the pod (no valid constraint,
+  the empty preFilterState): such a pod is never held back;
+- nothing leaves inside a round and the registered pairs do not depend on
+  the carry, so the counts only rise.
+The round's admits are counted by UNIQUE selector, which does not read the
+namespace: a selector whose community (the pods it matches, the pods whose
+constraints use it) spans two namespaces would be over-counted, so for such
+a one (sph_mixed, read from the batch) e keeps the over-count and rise is 0:
+the round-start minimum, PR 34's rule, which errs to the safe side on both.
+No cell and no upstream row has such a selector.
+
+Proposals run over every pair that can OPEN inside the round: the verdict
+that masks the proposals loosens the skew test alone to skew <= maxSkew +
+r, r = (the window's unassigned pods) // (c's registered pairs), how far a
+round whose admits fall level over the pairs lifts the minimum (three zones
+and 512 pods: 170, wide open; a hostname constraint over 5,000 nodes: 0,
+the strict verdict to the letter; 0 too for an sph_mixed selector).  Without
+it a zone that stands at minimum + maxSkew as the round starts is proposed
+by nobody, the others rise 2 x maxSkew above it and stop, and the roles
+swap every round.  Correctness never rests on r: only the pass admits; r
+decides how many proposals a round wastes.  A pod the pass held back draws
+its tie-break from the next stream of its key (fold_in by the times it was
+held): its own stream is fixed for the cycle, so it would draw the same
+node while that stays in its tie set, and the pods a widened round leaves
+over are exactly those whose favourite lies in the full pair (read on the
+CPU at the spread cell's size: 8-10 rounds a cycle without, 4 with).  A pod
+never held back, any pod of a batch without a hard constraint, draws as the
+sequential replay does.  Round 0's capture (feasible0,
+n_feasible, the preemption gate) keeps the STRICT verdict.  PROGRESS is kept
+by construction: a round that proposed past the strict verdict and admitted
+nobody proves nothing, so the next one proposes on the strict verdict,
+today's round, whose first proposer always fits (e = 0, rise = 0, slack >=
+0); the loop ends, and the windowed loop retires a pod, only on a strict
+round that admits nothing.  Both halves sit behind the filter's own runtime
+gate (any valid hard constraint in the batch): a batch without one runs no
+pass, its widened verdict is its strict one, and its placements are the
+parent's bit for bit.
+
+Score staleness within a single round (not across rounds) is the remaining
+gap vs the sequential replay mode; since the proposals run over the widened
+verdict it covers the spread verdict too: NormalizeScore runs over the
+widened feasible set, and a pod may hold a node whose pair was full as its
+round started.
 """
 
 from __future__ import annotations
@@ -123,6 +181,15 @@ class GangResult(NamedTuple):
                             # round's admits; -1 for a batch without a
                             # ScheduleAnyway constraint.  Diagnostics,
                             # read back as capacity_deferred is
+    spread_late_admits: Optional[jnp.ndarray] = None  # i32 pods admitted,
+                            # summed over the rounds, that the round-start
+                            # rule would have held back: their node failed
+                            # the round-start skew test, or their pair's
+                            # round-start room was used up by their turn
+                            # (spread_turns).  None (no output at all) for
+                            # a program that does not re-evaluate
+                            # PodTopologySpread in its rounds.
+                            # Diagnostics, read back as capacity_deferred is
 
 
 def _segment_base(values: jnp.ndarray, is_start: jnp.ndarray) -> jnp.ndarray:
@@ -535,6 +602,10 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
     if "DefaultPodTopologySpread" in score_names:
         score_pre["default_spread"] = K.default_spread_match_ns(ext, batch)
     if intra:
+        # the topology keys the intra-round rules serialize on
+        TK = cluster.topo_pair.shape[1]
+        deferral_keys = (list(range(TK)) if not cfg.active_topo_keys else
+                         [k for k in cfg.active_topo_keys if 0 <= k < TK])
         sph_match = (K.spread_match_ns(ext, batch, batch.spread)
                      if use_sph else None)
         ipa_pre = K.interpod_filter_pre(ext, batch) if use_ipa else None
@@ -551,6 +622,26 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
                                         batch.key_hot)  # [Us, B]
         sph_uidx = jnp.asarray(batch.spread.sel.index).reshape(
             B, batch.spread.valid.shape[1])
+
+        def _sph_mixed():
+            # a unique selector whose community (the pods it matches and
+            # the pods whose valid constraints use it) spans namespaces:
+            # mu_sph does not read the namespace, so this round's admits
+            # counted by selector run high for such a one (spread_turns)
+            Us = mu_sph.shape[0]
+            NS = batch.ns_hot.shape[1]
+            nsid = jnp.sum(jnp.where(batch.ns_hot > 0.5,
+                                     jnp.arange(NS)[None, :], 0), axis=1)
+            uses = jnp.any((sph_uidx[None, :, :]
+                            == jnp.arange(Us)[:, None, None])
+                           & batch.spread.valid[None, :, :], axis=2)
+            member = (mu_sph | uses) & batch.valid[None, :]
+            lo = jnp.min(jnp.where(member, nsid[None, :], 2**30), axis=1)
+            hi = jnp.max(jnp.where(member, nsid[None, :], -1), axis=1)
+            return hi > lo
+        sph_mixed = K._if_live(
+            jnp.any(batch.spread.valid), _sph_mixed,
+            lambda: jnp.zeros((mu_sph.shape[0],), bool))
 
     # tie_index: each pod's selectHost RNG stream id (fold_in index).  The
     # residual auction passes the pods' ORIGINAL batch rows here so its
@@ -588,6 +679,14 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         # re-opens feasibility (see _round below)
         retired=jnp.zeros((B,), bool),
     )
+    if use_sph:
+        # the round proposes over the STRICT spread verdict (set by a
+        # widened round that admitted nobody, for the one round after it)
+        carry0["strict"] = jnp.bool_(False)
+        carry0["late"] = jnp.int32(0)   # GangResult.spread_late_admits
+        # times spread_turns held each pod back: such a pod draws its
+        # tie-break anew (round_step)
+        carry0["held"] = jnp.zeros((B,), jnp.int32)
 
     # ---- width-W views of every per-pod tensor the round math reads ----
     TERM_ROW_FIELDS = ("ns_hot", "topo_key", "topo_known", "weight",
@@ -621,6 +720,7 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         if use_sph:
             sb["mu_sph"] = mu_sph
             sb["sph_uidx"] = sph_uidx
+            sb["sph_mixed"] = sph_mixed
         return sb
 
     def gather_sub(rows):
@@ -667,6 +767,7 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         if use_sph:
             sb["mu_sph"] = mu_sph[:, rsafe]
             sb["sph_uidx"] = g(sph_uidx)
+            sb["sph_mixed"] = sph_mixed
         return sb
 
     def cluster_at(c):
@@ -681,18 +782,28 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
             cl = cl._replace(pod_node=pod_node, pod_valid=pod_valid)
         return cl
 
-    def feasibility(c, cl, sb):
+    def feasibility(c, cl, sb, n_open=None):
+        """The round's feasible mask.  With the spread filter in the
+        rounds it is the mask PROPOSALS run over: the filter's verdict
+        widened by what the round's n_open unassigned pods can lift a
+        constraint's minimum (K.spread_filter open_pods; 0 for a selector
+        spread_turns cannot count exactly), or the strict one where the
+        carry asks for it; sph_ok is the strict verdict either way."""
         feas = sb["static_ok"]
         sbatch = sb["batch"]
         aff_unres = None
         boot_live = None
-        slack = None
+        room = None
+        sph_ok = None
         if use_sph:
-            ok, slack = K.spread_filter(cl, sbatch, sb["affinity_ok"],
-                                        match_ns=sb["sph_match"],
-                                        active_keys=cfg.active_keys,
-                                        return_slack=True)
-            feas = feas & ok
+            open_pods = jnp.where(
+                jnp.take(sb["sph_mixed"], sb["sph_uidx"]), 0, n_open)
+            sph_ok, room = K.spread_filter(cl, sbatch, sb["affinity_ok"],
+                                           match_ns=sb["sph_match"],
+                                           active_keys=cfg.active_keys,
+                                           return_slack=True,
+                                           open_pods=open_pods)
+            feas = feas & jnp.where(c["strict"], sph_ok, room.ok_wide)
         if use_ipa:
             ok, aff_unres, boot_live = K.interpod_filter(
                 cl, sbatch, pre=sb["ipa_pre"], return_no_matches=True,
@@ -705,17 +816,14 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
                 "bp,np->bn", sbatch.ports_hot, c["ports_used"],
                 preferred_element_type=jnp.float32) > 0.5
             feas = feas & sb["ports_ok0"] & ~batch_conf
-        return feas, aff_unres, boot_live, slack
+        return feas, aff_unres, boot_live, room, sph_ok
 
-    def _rules_for(terms, mu, uidx, k, pair_ok, order, is_start, admit_cap,
-                   anti: bool, room=0):
-        """Selector-precise same-pair deferral for one term set x one key.
-        rule A: pod j defers iff MORE THAN room earlier-admitted pods in its
-        landing pair match one of j's key-k term selectors (room 0, the
-        anti-affinity terms': any one of them; the spread constraints pass
-        room [W, T], the filter's own slack at j's proposal).  rule B (anti
-        only): pod j defers iff it matches a key-k anti term of an
-        earlier-admitted pod in the same pair."""
+    def _rules_for(terms, mu, uidx, k, pair_ok, order, is_start, admit_cap):
+        """Selector-precise same-pair deferral for the required
+        anti-affinity terms x one key.  rule A: pod j defers iff an
+        earlier-admitted pod in its landing pair matches one of j's key-k
+        term selectors.  rule B: pod j defers iff it matches a key-k anti
+        term of an earlier-admitted pod in the same pair."""
         W = admit_cap.shape[0]
         key_terms = _key_terms_mask(terms, k)  # [W, T]
         adm = _f(admit_cap & pair_ok)[:, None]
@@ -723,35 +831,27 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         e_a = mu.T * adm                               # [W, U]
         pref_a = jnp.zeros_like(e_a).at[order].set(
             _seg_prefix(e_a[order], is_start))
-        hits = jnp.take_along_axis(pref_a, uidx, axis=1) > room  # [W, T]
+        hits = jnp.take_along_axis(pref_a, uidx, axis=1) > 0  # [W, T]
         defer = jnp.any(hits & key_terms, axis=1) & pair_ok
-        if anti:
-            # events B: admitted pods registering their key-k selectors
-            reg = jnp.zeros_like(e_a).at[
-                jnp.arange(W)[:, None], uidx].max(_f(key_terms))
-            e_b = reg * adm
-            pref_b = jnp.zeros_like(e_b).at[order].set(
-                _seg_prefix(e_b[order], is_start))
-            defer = defer | (jnp.any((pref_b > 0) & mu.T, axis=1) & pair_ok)
-        return defer
+        # events B: admitted pods registering their key-k selectors
+        reg = jnp.zeros_like(e_a).at[
+            jnp.arange(W)[:, None], uidx].max(_f(key_terms))
+        e_b = reg * adm
+        pref_b = jnp.zeros_like(e_b).at[order].set(
+            _seg_prefix(e_b[order], is_start))
+        return defer | (jnp.any((pref_b > 0) & mu.T, axis=1) & pair_ok)
 
-    def topology_deferral(sb, admit_cap, prop, boot_live, slack):
-        """Selector-precise intra-round serialization: see module
-        docstring.  One stable sort by landing pair per topology key; the
-        per-pair exclusive prefix sums run in unique-selector space
-        (O(W x U) per key), so deferral only triggers on genuinely
-        interacting pods — not on mere pair co-occupancy.  slack is
-        K.spread_filter's [W, C, N] of this round (None without the spread
-        filter); each pod's row at its proposal is its constraints' room."""
+    def topology_deferral(sb, admit_cap, prop, boot_live):
+        """Selector-precise intra-round serialization of InterPodAffinity:
+        see module docstring.  One stable sort by landing pair per
+        topology key; the per-pair exclusive prefix sums run in
+        unique-selector space (O(W x U) per key), so deferral only
+        triggers on genuinely interacting pods — not on mere pair
+        co-occupancy."""
         W = prop.shape[0]
         prop_safe = jnp.clip(prop, 0, N - 1)
         is_prop = prop < N
-        room = None if slack is None else jnp.take_along_axis(
-            slack, prop_safe[:, None, None], axis=2)[:, :, 0]  # [W, C]
         defer = jnp.zeros((W,), bool)
-        TK = cluster.topo_pair.shape[1]
-        deferral_keys = (range(TK) if not cfg.active_topo_keys else
-                         [k for k in cfg.active_topo_keys if 0 <= k < TK])
         for k in deferral_keys:
             pair_k = jnp.where(is_prop, cluster.topo_pair[prop_safe, k], -1)
             pair_ok = pair_k >= 0
@@ -760,29 +860,89 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
             spair = skey[order]
             is_start = jnp.concatenate(
                 [jnp.ones((1,), bool), spair[1:] != spair[:-1]])
-            if use_ipa:
-                defer = defer | _rules_for(sb["batch"].raa, sb["mu_raa"],
-                                           sb["raa_uidx"], k,
-                                           pair_ok, order, is_start,
-                                           admit_cap, anti=True)
-            if use_sph:
-                defer = defer | _rules_for(sb["batch"].spread, sb["mu_sph"],
-                                           sb["sph_uidx"], k,
-                                           pair_ok, order, is_start,
-                                           admit_cap, anti=False, room=room)
-        if use_ipa:
-            # bootstrap rule: a pod whose required-affinity terms match
-            # nothing THIS round is admitted only via the self-match
-            # bootstrap (filtering.go:356); any same-round admission could
-            # create a match and invalidate "no matches", so it defers
-            # behind any earlier admission.  Once matches exist the normal
-            # count path applies and co-admission is monotone-safe
-            # (placements only add matches), so no deferral.
-            earlier_any = jnp.cumsum(_f(admit_cap)) - _f(admit_cap)
-            live = (sb["ra_boot"] if boot_live is None
-                    else (sb["ra_boot"] & boot_live))
-            defer = defer | (live & (earlier_any > 0))
+            defer = defer | _rules_for(sb["batch"].raa, sb["mu_raa"],
+                                       sb["raa_uidx"], k,
+                                       pair_ok, order, is_start, admit_cap)
+        # bootstrap rule: a pod whose required-affinity terms match
+        # nothing THIS round is admitted only via the self-match
+        # bootstrap (filtering.go:356); any same-round admission could
+        # create a match and invalidate "no matches", so it defers
+        # behind any earlier admission.  Once matches exist the normal
+        # count path applies and co-admission is monotone-safe
+        # (placements only add matches), so no deferral.
+        earlier_any = jnp.cumsum(_f(admit_cap)) - _f(admit_cap)
+        live = (sb["ra_boot"] if boot_live is None
+                else (sb["ra_boot"] & boot_live))
+        defer = defer | (live & (earlier_any > 0))
         return defer
+
+    def spread_turns(sb, cand, prop, room):
+        """PodTopologySpread's hard filter at each pod's TURN (module
+        docstring): cand [W] are the pods every other rule admits, in pod
+        order; returns (admitted [W], late i32).  Pod j is admitted iff for
+        each of its valid constraints c, with e the pods admitted BEFORE it
+        in this round that c's selector matches in its proposal's pair and
+        rise how far they lifted c's minimum, e - rise <= the room the
+        filter saw at the round's start (slack at the proposal), which is
+        matchNum + e + selfMatch - (minMatch + rise) <= maxSkew on this
+        round's counts.  One serial pass over the pods that hold a valid
+        constraint or match a constraint's selector (nobody else can be
+        held back or use up room), its state the round's admits by
+        (unique selector, key) on every node of their pair, [Us, K, N];
+        no product.  late: the admitted whose round-start room was used
+        up, or negative, at their turn."""
+        sbatch = sb["batch"]
+        cons = sbatch.spread
+        W = prop.shape[0]
+
+        def live():
+            mu, uidx, mixed = sb["mu_sph"], sb["sph_uidx"], sb["sph_mixed"]
+            Us = mu.shape[0]
+            kk = jnp.asarray(deferral_keys, jnp.int32)                 # [K]
+            on_key = cons.topo_key[:, :, None] == kk[None, None, :]
+            kslot = jnp.argmax(on_key, axis=2)                     # [W, C]
+            counted = (cons.valid & cons.topo_known
+                       & jnp.any(on_key, axis=2))                  # [W, C]
+            prop_safe = jnp.clip(prop, 0, N - 1)
+            tpk = cluster.topo_pair[:, kk].T                       # [K, N]
+            pair_at = jnp.where((prop < N)[:, None],
+                                tpk[:, prop_safe].T, -1)           # [W, K]
+            room_at = jnp.take_along_axis(
+                room.slack, prop_safe[:, None, None], axis=2)[:, :, 0]
+            min0 = jnp.min(room.floor, axis=2)                     # [W, C]
+            exact = ~jnp.take(mixed, uidx)                         # [W, C]
+            turns = cand & (jnp.any(counted, axis=1) | jnp.any(mu, axis=0))
+            order = jnp.nonzero(turns, size=W, fill_value=0)[0]
+
+            def turn(i, st):
+                landed, admitted, late = st
+                j = order[i]
+                node = prop_safe[j]
+                rows = landed[uidx[j], kslot[j]]                   # [C, N]
+                floor = room.floor[j]                              # [C, N]
+                rise = jnp.min(floor + rows * _f(exact[j])[:, None],
+                               axis=1) - min0[j]
+                # an unregistered pair counts no pod (Filter: nil tpCount)
+                e = jnp.where(floor[:, node] < K._UNREGISTERED,
+                              rows[:, node], 0.0)
+                ok = ~jnp.any(counted[j] & (e - rise > room_at[j]))
+                was_late = ok & jnp.any(counted[j] & (e > room_at[j]))
+                same = ((tpk == pair_at[j][:, None])
+                        & (pair_at[j] >= 0)[:, None])              # [K, N]
+                landed = landed + (_f(mu[:, j] & ok)[:, None, None]
+                                   * _f(same)[None, :, :])
+                return (landed, admitted.at[j].set(ok),
+                        late + was_late.astype(jnp.int32))
+
+            st0 = (jnp.zeros((Us, len(deferral_keys), N), jnp.float32),
+                   cand & ~turns, jnp.int32(0))
+            _, admitted, late = jax.lax.fori_loop(
+                0, jnp.sum(turns, dtype=jnp.int32), turn, st0)
+            return admitted, late
+
+        with jax.named_scope("spread_turns"):
+            return K._if_live(jnp.any(cons.valid), live,
+                              lambda: (cand, jnp.int32(0)))
 
     def round_step(c, sb, capture_first: bool, windowed: bool = False):
         """One propose/admit round over sb's rows (width W <= B; the full
@@ -793,7 +953,9 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         sbatch = sb["batch"]
         unassigned = (jnp.take(c["assigned"], rsafe) < 0) & sb["valid"]
         cl = cluster_at(c)
-        feas, aff_unres, boot_live, slack = feasibility(c, cl, sb)
+        feas, aff_unres, boot_live, room, sph_ok = feasibility(
+            c, cl, sb,
+            jnp.sum(unassigned, dtype=jnp.int32) if use_sph else None)
         feas = feas & unassigned[:, None]
 
         # scores against committed usage + placements so later rounds see
@@ -809,17 +971,31 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         best = jnp.max(masked, axis=1)
         ties = (masked == best[:, None]) & feas
         logits = jnp.where(ties, 0.0, _NEG)
-        choice = jax.vmap(jax.random.categorical)(sb["tie_keys"], logits)
+        tie_keys = sb["tie_keys"]
+        if use_sph:
+            # a pod spread_turns held back proposed a node whose pair was
+            # full at its turn; its own stream would draw that node again
+            # while it stays in the tie set, and the pods a widened round
+            # leaves over would all be such (the ones whose favourite's
+            # pair is the full one).  It draws from the next stream
+            held = jnp.take(c["held"], rsafe)
+            tie_keys = jnp.where(
+                (held > 0).reshape((-1,) + (1,) * (tie_keys.ndim - 1)),
+                jax.vmap(jax.random.fold_in)(tie_keys, held), tie_keys)
+        choice = jax.vmap(jax.random.categorical)(tie_keys, logits)
         active = jnp.any(feas, axis=1)
         prop = jnp.where(active, choice.astype(jnp.int32), N)  # N = no-op seg
+        # round 0's capture (n_feasible, the preemption gate) is the
+        # filters' own verdict: the strict one
+        feas0 = feas if sph_ok is None else feas & sph_ok
         return _round_tail(c, sb, prop, active, best, unassigned,
                            windowed=windowed, capture_first=capture_first,
-                           feas=feas, aff_unres=aff_unres,
-                           boot_live=boot_live, slack=slack)
+                           feas=feas0, aff_unres=aff_unres,
+                           boot_live=boot_live, room=room)
 
     def _round_tail(c, sb, prop, active, best, unassigned,
                     windowed: bool, capture_first: bool = False,
-                    feas=None, aff_unres=None, boot_live=None, slack=None):
+                    feas=None, aff_unres=None, boot_live=None, room=None):
         """The admit/commit half of a round: segmented-reduce admission
         over the proposed nodes + carry update.  O(W) / O(W, R) work."""
         rows = sb["rows"]
@@ -833,11 +1009,15 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
                                sbatch.ports_asnode_hot, cluster.allocatable,
                                c["req"], use_ports, N)
         cap_deferred = jnp.sum(active & ~admit, dtype=jnp.int32)
-        if intra:
+        if use_ipa:
             # intra-round topology serialization (conservative; deferred
             # pods re-check against exact committed counts next round)
-            admit = admit & ~topology_deferral(sb, admit, prop, boot_live,
-                                               slack)
+            admit = admit & ~topology_deferral(sb, admit, prop, boot_live)
+        if use_sph:
+            # last, on what every other rule admits: a pod held back must
+            # lift nobody's minimum
+            cand = admit
+            admit, late = spread_turns(sb, cand, prop, room)
 
         # ---- commit ----
         add_req, add_nz, add_ports = admission_sums(
@@ -864,6 +1044,18 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         new["rounds"] = c["rounds"] + 1
         new["admits"] = c["admits"] + admitted_any.astype(jnp.int32)
         new["cap_deferred"] = c["cap_deferred"] + cap_deferred
+        # did this round propose past the strict spread verdict?  Such a
+        # round proves nothing by admitting nobody: the next one is strict
+        # (its first proposer always fits), and only a strict round's
+        # emptiness retires a pod or ends the loop
+        progressed = admitted_any
+        if use_sph:
+            widened = ~c["strict"] & jnp.any(room.widened & unassigned)
+            new["strict"] = widened & ~admitted_any
+            new["late"] = c["late"] + late
+            new["held"] = c["held"].at[rows].add(
+                (cand & ~admit).astype(jnp.int32), mode="drop")
+            progressed = admitted_any | widened
         if windowed:
             # retirement: a pod with NO feasible node in a no-admission
             # round leaves the window-selection pool; any admission
@@ -875,12 +1067,14 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
             # forever and burn max_rounds.
             new_retire = ((~active) & unassigned
                           & ~jnp.take(c["retired"], rsafe))
+            if use_sph:
+                new_retire = new_retire & ~widened
             new["retired"] = jnp.where(
                 admitted_any, jnp.zeros_like(c["retired"]),
                 c["retired"].at[rows].max(new_retire, mode="drop"))
-            new["progress"] = admitted_any | jnp.any(new_retire)
+            new["progress"] = progressed | jnp.any(new_retire)
         else:
-            new["progress"] = admitted_any
+            new["progress"] = progressed
         return new
 
     fsb = full_sub()
@@ -942,4 +1136,5 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
                       n_feasible=n_feas,
                       all_unresolvable=all_unres, packed=packed,
                       capacity_deferred=out["cap_deferred"],
-                      soft_spread_skew=soft_skew)
+                      soft_spread_skew=soft_skew,
+                      spread_late_admits=out.get("late"))

@@ -498,8 +498,29 @@ def _spread_state(cluster, batch, constraints, affinity_ok, count_mask_nodes,
                        any_eligible=any_eligible)
 
 
+class SpreadRoom(NamedTuple):
+    """What one evaluation of the hard spread filter hands the gang
+    auction's round beside its verdict (spread_filter, return_slack): all
+    from the filter's own intermediates, no second product."""
+    slack: jnp.ndarray     # [B, C, N] f32 maxSkew - skew; +inf where the
+                           # filter tolerates the pod whatever lands
+    floor: jnp.ndarray     # [B, C, N] f32 matching pods in node n's pair
+                           # where that pair is REGISTERED for (b, c),
+                           # _UNREGISTERED elsewhere and on a row the
+                           # filter does not check: its minimum over n is
+                           # minMatch, and an unregistered pair is in none
+    ok_wide: jnp.ndarray   # [B, N] bool the verdict with the skew test
+                           # loosened by what the round can lift the
+                           # minimum (open_pods); == ok where that is 0
+    widened: jnp.ndarray   # [B] bool ok_wide passes a node ok fails
+
+
+_UNREGISTERED = 2.0 ** 31   # above any count (per_node_counts: < 2**24)
+
+
 def spread_filter(cluster, batch, affinity_ok, match_ns=None,
-                  active_keys=None, return_slack: bool = False):
+                  active_keys=None, return_slack: bool = False,
+                  open_pods=None):
     """PodTopologySpread hard constraints
     (reference: podtopologyspread/filtering.go:200-283 calPreFilterState/Filter).
 
@@ -508,13 +529,23 @@ def spread_filter(cluster, batch, affinity_ok, match_ns=None,
     registered pairs" and no explicit pair axis is needed — everything is
     same-pair matmuls on the MXU (see _samepair_pods_to_nodes).
 
-    return_slack=True also returns slack [B, C, N] f32 = maxSkew - skew from
-    the same intermediates: how many MORE matching pods node n's pair can
-    take before constraint c of pod b fails there (>= 0 wherever c passes;
-    the same value on every node of a pair).  +inf where the filter
-    tolerates the pod whatever lands (no valid constraint, or the empty
-    preFilterState).  The gang auction's intra-round deferral budgets
-    against it (models/gang.py)."""
+    return_slack=True returns (ok, SpreadRoom), the room from the same
+    intermediates.  slack [B, C, N] = maxSkew - skew: how many MORE
+    matching pods node n's pair can take before constraint c of pod b
+    fails there against the minimum as it stands (>= 0 wherever c passes;
+    the same value on every node of a pair).  floor [B, C, N]: the
+    per-pair counts the minimum runs over, so a caller that adds pods to
+    pairs can move the minimum with them.  ok_wide: the verdict with
+    ``skew <= maxSkew + r`` for ``r = open_pods[b, c] // (registered
+    pairs of (b, c))``, every other test (the key, eligibility, the empty
+    preFilterState, cons.valid) as in ok: r is how far a round that
+    admits open_pods more matching pods LEVEL over the registered pairs
+    lifts the minimum, so a node ok_wide passes and ok fails is one whose
+    pair can open inside such a round.  open_pods [B, C] i32 (None: 0,
+    ok_wide == ok); three zones and 512 open pods give r = 170, a hostname
+    constraint over 5,000 nodes r = 0.  The gang auction proposes over
+    ok_wide and admits in pod order against floor and slack
+    (models/gang.py)."""
     cons = batch.spread
     B, C = cons.topo_key.shape
     N = cluster.allocatable.shape[0]
@@ -537,11 +568,12 @@ def spread_filter(cluster, batch, affinity_ok, match_ns=None,
         # a pair is registered iff some eligible node carries it
         elig_bc = jnp.broadcast_to(eligible[:, None, :],
                                    (B, C, N)).reshape(B * C, N)
-        registered = _samepair_nodes(cluster, elig_bc, keys,
-                                     active_keys=active_keys) > 0.5  # [B*C, N]
-        big = jnp.float32(2**31)
-        min_match = jnp.min(jnp.where(registered, cnt, big),
-                            axis=1).reshape(B, C)
+        # eligible nodes of each node's pair  [B*C, N]
+        pair_elig = _samepair_nodes(cluster, elig_bc, keys,
+                                    active_keys=active_keys)
+        registered = pair_elig > 0.5
+        floor = jnp.where(registered, cnt, jnp.float32(_UNREGISTERED))
+        min_match = jnp.min(floor, axis=1).reshape(B, C)
         # unregistered pair => matchNum 0 (reference Filter: nil *tpCount)
         match_num = jnp.where(registered, cnt, 0.0).reshape(B, C, N)
         self_m = _f(cons.self_match)[:, :, None]
@@ -553,16 +585,39 @@ def spread_filter(cluster, batch, affinity_ok, match_ns=None,
         ok = jnp.where(has_any[:, None] & any_eligible[:, None], ok, True)
         if not return_slack:
             return ok
-        slack = jnp.where((has_any & any_eligible)[:, None, None],
+        checked = has_any & any_eligible
+        slack = jnp.where(checked[:, None, None],
                           cons.max_skew[:, :, None] - skew, jnp.inf)
-        return ok, slack
+        ok_wide = ok
+        if open_pods is not None:
+            # registered pairs of (b, c): each eligible node is 1 / (the
+            # eligible nodes of its pair) of one; whole to well under 1/2
+            n_pairs = jnp.round(jnp.sum(
+                jnp.where(elig_bc & registered,
+                          1.0 / jnp.maximum(pair_elig, 1.0), 0.0),
+                axis=1)).astype(jnp.int32).reshape(B, C)
+            lift = _f(open_pods // jnp.maximum(n_pairs, 1))
+            c_wide = has_key & (skew <= (cons.max_skew + lift)[:, :, None])
+            ok_wide = jnp.where(
+                checked[:, None],
+                jnp.all(c_wide | ~cons.valid[:, :, None], axis=1), True)
+        # a row the filter does not check is in nobody's minimum, as in
+        # the dead branch
+        floor = jnp.where((checked[:, None] & cons.valid)[:, :, None],
+                          floor.reshape(B, C, N),
+                          jnp.float32(_UNREGISTERED))
+        return ok, SpreadRoom(slack=slack, floor=floor, ok_wide=ok_wide,
+                              widened=jnp.any(ok_wide & ~ok, axis=1))
 
     def dead():
         # no valid constraint on any pod: has_any is False on every row
         ok = jnp.ones((B, N), bool)
         if not return_slack:
             return ok
-        return ok, jnp.full((B, C, N), jnp.inf, jnp.float32)
+        return ok, SpreadRoom(
+            slack=jnp.full((B, C, N), jnp.inf, jnp.float32),
+            floor=jnp.full((B, C, N), _UNREGISTERED, jnp.float32),
+            ok_wide=ok, widened=jnp.zeros((B,), bool))
 
     return _if_live(jnp.any(cons.valid), live, dead)
 

@@ -1566,6 +1566,13 @@ class Scheduler:
                     # carries a ScheduleAnyway constraint
                     prep.trace.rec.meta["spread_soft_skew"] = int(
                         res.soft_spread_skew)
+                if (prep.trace.rec.meta.get("spread_constraints")
+                        and res.spread_late_admits is not None):
+                    # and for the admissions the round-start rule would
+                    # have held back, where the batch carries a
+                    # DoNotSchedule constraint
+                    prep.trace.rec.meta["spread_late_admits"] = int(
+                        res.spread_late_admits)
         self.device_wait_s += wait
         return packed
 

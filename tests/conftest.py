@@ -48,6 +48,20 @@ def _bound_live_executables():
     failed as a segfault — in whichever call needed it, which happened
     to be the persistent cache's executable serialize or deserialize."""
     yield
+    _drop_executables_past_budget()
+
+
+def _drop_executables_past_budget():
     if "jax" in sys.modules and _live_mappings() > _MAPPING_BUDGET:
         import jax
         jax.clear_caches()
+
+
+@pytest.fixture
+def bounded_executables():
+    """The same check after ONE test, for a parametrised test whose cases
+    compile thousands of programs between them inside one module
+    (tests/test_gang.py's test_spread_deferral_budget: a program a stop,
+    ~47,000 mappings over its fourteen cases, measured)."""
+    yield
+    _drop_executables_past_budget()
