@@ -133,10 +133,14 @@ class CycleContext:
         self._verdict_commits = 0
         self._cluster_cache = None   # (commits, overlaid cluster)
         self._lazy = None            # (feasible_dev, unresolvable_dev)
-        self.pod_rows = None         # uid -> existing-pod tensor row (set
+        self.pod_uids = None         # the cluster's existing-pod rows in
+                                     # row order, uid or None a row (set
                                      # by the scheduler; required when the
-                                     # cluster is CHAINED and rows no
-                                     # longer follow node_infos order)
+                                     # cluster is delta-resident or
+                                     # CHAINED and rows no longer follow
+                                     # node_infos order)
+        self.pod_rows = None         # uid -> existing-pod tensor row, from
+                                     # pod_uids when first asked for
         self._has_filter_terms = None  # lazy: any valid existing
                                        # anti-affinity term in the cluster
         # node row -> _NodeVictims (lazy, one host pass per cycle)
@@ -274,20 +278,18 @@ class CycleContext:
 
     def pod_row_map(self) -> Dict[str, int]:
         """pod uid -> existing-pod tensor row (cached for the cycle, like
-        victim_index which consumes it).  Chained clusters carry the
-        mapping explicitly (rows diverge from build order); otherwise it is
-        the build order of state/tensors.py SnapshotBuilder.build."""
-        if self.pod_rows is not None:
-            return self.pod_rows
-        if getattr(self, "_pod_row_cache", None) is None:
-            rows: Dict[str, int] = {}
-            row = 0
-            for ni in self.node_infos:
-                for pi in ni.pods:
-                    rows[pi.pod.uid] = row
-                    row += 1
-            self._pod_row_cache = rows
-        return self._pod_row_cache
+        victim_index which consumes it).  The scheduler's clusters carry
+        their rows explicitly (``pod_uids``: they diverge from build
+        order); otherwise it is the build order of state/tensors.py
+        SnapshotBuilder.build."""
+        if self.pod_rows is None:
+            if self.pod_uids is not None:
+                self.pod_rows = {uid: row for row, uid
+                                 in enumerate(self.pod_uids) if uid}
+            else:
+                self.pod_rows = {pi.pod.uid: row for row, pi in enumerate(
+                    pi for ni in self.node_infos for pi in ni.pods)}
+        return self.pod_rows
 
     def victim_index(self) -> Dict[int, _NodeVictims]:
         """node row -> priority-ordered victim arrays, built in ONE host

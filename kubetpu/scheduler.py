@@ -837,8 +837,6 @@ class Scheduler:
         if use_chain:
             cluster = chain["cluster"]
             chain_pod_uids = chain["pod_uids"]
-            cycle_pod_rows = {uid: i for i, uid in enumerate(chain_pod_uids)
-                              if uid}
             if ujournal.journal() is not None:
                 # journal provenance: this cycle's cluster is the
                 # previous committed cycle's auction, materialized at
@@ -876,9 +874,11 @@ class Scheduler:
                 [p.cluster for p in inflight if p is not None]
                 + [p.cluster for p in self._undispatched])
             # pending/nominated pods intern inside refresh (a compacting
-            # resync re-interns them into its fresh table)
+            # resync re-interns them into its fresh table); the delta's
+            # pod-row floor stands on the batch alone
             cluster, dstats = delta.refresh(
-                node_infos, pending=pinfos + nom_pinfos, donate=donate)
+                node_infos, pending=pinfos + nom_pinfos, donate=donate,
+                batch=len(pinfos))
             # AFTER refresh: a compacting resync swaps the builder
             builder = delta.builder
             rec = trace.rec
@@ -911,12 +911,11 @@ class Scheduler:
                 self.delta_rows.append(dstats.delta_rows)
                 self.delta_cycle_count += 1
             with trace.stage("row-maps") as maps_span:
-                # this cycle's existing-pod rows by uid: a copy, the next
-                # refresh moves the tensorizer's own
-                cycle_pod_rows = dict(delta.pod_row)
+                # this cycle's existing-pod rows in row order: a copy, the
+                # next refresh moves the tensorizer's own
                 chain_pod_uids = delta.pod_uid_list()
                 if maps_span is not None:
-                    maps_span.args["pod_rows"] = len(cycle_pod_rows)
+                    maps_span.args["pod_rows"] = len(delta.pod_row)
                 # journal capture seam (state/delta.py): the exact resync
                 # snapshot / delta tables / zero-dirty marker this refresh
                 # applied — None when the journal is disarmed
@@ -985,7 +984,9 @@ class Scheduler:
             trace.rec.meta["pod_bucket"] = int(cluster.pod_valid.shape[0])
             # rows of that axis in use, and the bytes of the cluster the
             # cycle dispatches on (from shapes)
-            trace.rec.meta["pod_rows_live"] = len(cycle_pod_rows)
+            trace.rec.meta["pod_rows_live"] = (
+                len(chain_pod_uids) - chain_pod_uids.count(None)
+                if use_chain else len(delta.pod_row))
             trace.rec.meta["cluster_device_bytes"] = cluster.nbytes
             # the existing-term rows (Et filter, Es score) the auction's
             # match and contractions run over, padding included
@@ -1144,10 +1145,11 @@ class Scheduler:
             builder=builder, cluster=cluster, cfg=cfg,
             node_infos=node_infos, batch=batch,
             row_of={qp.pod.uid: i for i, qp in enumerate(live)})
-        # existing-pod tensor rows by uid (chained clusters' row order
-        # diverges from node_infos build order; preemption victim masking
-        # needs the true mapping)
-        cycle_ctx.pod_rows = cycle_pod_rows
+        # the cluster's existing-pod rows in row order (delta-resident and
+        # chained clusters' rows diverge from node_infos build order;
+        # preemption victim masking needs the true mapping and derives it
+        # from this when first asked)
+        cycle_ctx.pod_uids = chain_pod_uids
         trace.step("Tensorizing snapshot and pod batch done")
 
         from .framework.types import pod_with_affinity

@@ -52,11 +52,19 @@ A dirty node is one whose ``generation`` moved, which a pod bound to it
 or deleted from it does.  Its mirror rows are refilled only where what
 they are filled FROM changed: the node's label / taint / image rows when
 its Node object was set again (``NodeInfo.node_generation``), a pod's row
-when the PodInfo on the node is not the object the row was filled from
-(``pod_src``); what a pod's coming or going does move — requested, the
-pod count, host ports — is rewritten for every dirty node.  The
-``ClusterDelta`` still carries every row of every dirty node, so the
-scatter's row counts and buckets do not depend on what was skipped.
+when the PodInfo on the node is not the object the row was filled from;
+what a pod's coming or going does move — requested, the pod count, host
+ports — is rewritten for every dirty node.  A refresh costs what CHANGED,
+not what the dirty nodes hold: a node's arrivals and departures are the
+difference of two sets of PodInfo objects (``node_pods``), so no Python
+statement runs for a pod that stayed, and the ``ClusterDelta`` carries
+every dirty node's row but only the pod rows that were refilled or
+cleared — a row that was not is byte for byte what the device holds.  A
+node's pods are walked one at a time only to re-read its ordered term
+owners, and only where an owner came or went.  So that the steady churn
+of a serving loop (a batch of arrivals, about as many departures) and its
+jitter land in ONE compiled scatter, the delta's pod rows are padded to
+at least four times the pending batch's pow2 bucket (``refresh``).
 
 Bit-exactness contract (tested by tests/test_delta.py): after any
 sequence of deltas, the resident tensors match a from-scratch ``build()``
@@ -148,19 +156,25 @@ def _wrapsum_dev(x):
 
 class DeltaStats(NamedTuple):
     """One refresh()'s outcome — the flight-recorder/bench feed."""
-    delta_rows: int                 # node rows + pod rows actually updated
+    delta_rows: int                 # dirty node rows + the pod rows that
+                                    # were refilled or cleared
     resync: bool
     reason: str                     # "" on pure delta cycles
     spans: Tuple[Tuple[str, float, float], ...]  # (name, t0, t1)
     # the (dirty-node, churned-pod) row buckets _apply_cluster_delta was
-    # dispatched with (gather_delta's pow2 pads): the program compiles
-    # once per pair.  () when no scatter ran
+    # dispatched with (gather_delta's pow2 pads, the pod rows' from
+    # refresh()'s floor up): the program compiles once per pair.  () when
+    # no scatter ran
     delta_buckets: Tuple[int, ...] = ()
     # span name -> the args it is recorded with besides delta_rows: what
     # the term refresh rebuilt ("delta-terms": filter_rows, score_rows,
-    # their buckets Et / Es, pods_walked, owners_changed) and whether the
-    # build kept the term tables beside a dirty owner ("delta-build":
-    # terms_kept)
+    # their buckets Et / Es, pods_walked, owners_changed) and what the
+    # build did ("delta-build": terms_kept, whether it kept the term
+    # tables beside a dirty owner; node_rows_dirty / node_rows_refilled;
+    # pod_rows_seen, the pod rows in the delta: refilled or cleared;
+    # pod_rows_refilled; pods_walked, the dirty nodes' pods the host
+    # visited one at a time: the arrivals, and every pod of a node whose
+    # owners it re-read)
     span_args: Mapping[str, Mapping[str, int]] = MappingProxyType({})
 
 
@@ -213,7 +227,11 @@ class DeltaTensorizer:
         self.host: Optional[HostClusterArrays] = None
         self.node_names: List[str] = []          # row order
         self.node_gen: Dict[str, int] = {}
-        self.node_pods: Dict[str, List[str]] = {}   # name -> uid list
+        # name -> the PodInfo OBJECTS the node's pod rows were filled
+        # from (the snapshot's clone() keeps the objects and every pod
+        # event puts a new one on its node): what came and went on a dirty
+        # node is the difference of two such sets
+        self.node_pods: Dict[str, set] = {}
         # name -> the node's term owners in pod order, as the resident
         # term tables were last built from them: what ``terms_dirty`` and
         # ``owners_changed`` compare, and what a rebuild compiles
@@ -226,12 +244,16 @@ class DeltaTensorizer:
         # pod's row was filled from, its node's row, its TermOwner or
         # None).  Held by OBJECT, not by id(): a PodInfo that is still
         # noted cannot have been collected and its address reused.
-        # Noted at every fill and re-noted whole by every _resync (which
-        # moves pod rows and intern ids)
+        # Noted as a row is assigned or kept for a refill and re-noted
+        # whole by every _resync (which moves pod rows and intern ids)
         self.node_src: Dict[str, int] = {}
         self.pod_src: Dict[str, Tuple[object, int,
                                       Optional[TermOwner]]] = {}
         self.free_rows: List[int] = []           # kept sorted, pop lowest
+        # row -> uid (None: free or padding), sized to the pod-axis
+        # capacity and kept up to date wherever a row is assigned or
+        # freed: ``pod_row`` the other way round
+        self.row_uids: List[Optional[str]] = []
         self.next_pod_row = 0
         self.caps = None                         # vocab signature
         self.cycles_since_resync = 0
@@ -287,14 +309,9 @@ class DeltaTensorizer:
 
     def pod_uid_list(self) -> List[Optional[str]]:
         """Row-ordered uid list sized to the pod-axis capacity (the
-        scheduler's chain_pod_uids / CycleContext.pod_rows feed)."""
-        if self.host is None:
-            return []
-        out: List[Optional[str]] = [None] * self.host.arrays[
-            "pod_node"].shape[0]
-        for uid, r in self.pod_row.items():
-            out[r] = uid
-        return out
+        scheduler's chain_pod_uids / CycleContext.pod_uids feed).  A
+        COPY: the next refresh moves the tensorizer's own."""
+        return list(self.row_uids)
 
     # ------------------------------------------------------- anti-entropy
 
@@ -363,14 +380,17 @@ class DeltaTensorizer:
 
     # ------------------------------------------------------------- refresh
 
-    def refresh(self, node_infos, pending=(), donate: bool = True):
+    def refresh(self, node_infos, pending=(), donate: bool = True,
+                batch: Optional[int] = None):
         """Bring the resident cluster up to date with the snapshot's
         NodeInfos.  Returns (cluster, DeltaStats).  pending: PodInfos of
         this cycle's pending (and nominated) pods — interned HERE so the
         vocab-growth check always sees them (and so a compacting resync
         re-interns them into its fresh table).  donate=False keeps the
         previous device buffers alive (an in-flight pipelined cycle still
-        reads them)."""
+        reads them).  batch: how many of ``pending`` are the cycle's batch
+        (all of them unless said: the scheduler hands the nominated pods
+        in behind it); the delta's pod-row floor stands on it alone."""
         t0 = wallclock()
         if pending:
             self.builder.intern_pending(pending)
@@ -418,60 +438,82 @@ class DeltaTensorizer:
         # filled from its PodInfo and its node's row: every pod event
         # puts a NEW PodInfo on the node (``SchedulerCache.update_pod``
         # is a remove and an add) and the snapshot's ``clone()`` keeps
-        # the objects, so the PodInfo noted in ``pod_src`` still being
-        # the one on that node means the row is what a refill would
-        # write.  Strings are interned with the part that reads them, so
-        # the width checks below see exactly the strings that are new.
-        # Any _resync() from here on finds state half updated and
-        # re-derives ALL of it, markers included.
+        # the objects, so a PodInfo that is still in the node's noted set
+        # (``node_pods``; node rows do not move between resyncs) means
+        # the row is what a refill would write.  Strings are interned
+        # with the part that reads them, so the width checks below see
+        # exactly the strings that are new.  Any _resync() from here on
+        # finds state half updated and re-derives ALL of it, markers
+        # included.
         a = self.host.arrays
         b = self.builder
         pod_row, pod_src = self.pod_row, self.pod_src
         node_pods, node_src = self.node_pods, self.node_src
-        node_owners = self.node_owners
+        node_owners, row_uids = self.node_owners, self.row_uids
 
-        # ---- pod-row churn: free EVERY departed row across all dirty
-        # nodes BEFORE scanning for additions — a same-uid pod moving
-        # from a higher- to a lower-indexed dirty node would otherwise be
-        # skipped by the add scan (stale mapping still present) and then
-        # popped by the later free, leaving the refill with no row
+        # ---- what came and went on each dirty node: the difference of
+        # two sets of PodInfo objects, so nothing runs a pod that stayed.
+        # EVERY departed row across all dirty nodes is freed BEFORE a row
+        # is assigned — a same-uid pod moving from a higher- to a
+        # lower-indexed dirty node would otherwise keep its stale mapping
+        # through its arrival and lose it to the later free, leaving the
+        # refill with no row.  A pod replaced on its node under its uid
+        # (update_pod's remove and add) keeps its row: it is refilled
         touched_pods: set = set()
-        walk = []                    # (node row, NodeInfo, its pods' uids)
+        walk = []       # (node row, NodeInfo, arrivals, an owner went)
         for i, ni in dirty:
-            uids = [pi.pod.metadata.uid for pi in ni.pods]
-            walk.append((i, ni, uids))
-            old = node_pods.get(names[i], ())
-            if old != uids:
-                new_set = set(uids)
-                for uid in old:
-                    if uid not in new_set:
-                        row = pod_row.pop(uid)
-                        del pod_src[uid]
-                        clear_pod_row(a, row)
-                        touched_pods.add(row)
-                        self.free_rows.append(row)
+            name = names[i]
+            pods = ni.pods
+            now = set(pods)
+            was = node_pods[name]
+            came = now - was
+            if len(came) > 1:
+                # rows are assigned in pod order, not in a set's
+                came = sorted(came, key=pods.index)
+            owner_went = False
+            went = was - now
+            if went:
+                stays = {pi.pod.metadata.uid for pi in came}
+                for pi in went:
+                    uid = pi.pod.metadata.uid
+                    if pod_src[uid][2] is not None:
+                        owner_went = True
+                    if uid in stays:
+                        continue
+                    row = pod_row.pop(uid)
+                    del pod_src[uid]
+                    clear_pod_row(a, row)
+                    row_uids[row] = None
+                    touched_pods.add(row)
+                    self.free_rows.append(row)
+            node_pods[name] = now
+            walk.append((i, ni, came, owner_went))
         self.free_rows.sort()
 
-        # ---- ONE walk of each dirty node's pods: rows for the pods that
-        # are new (lowest free row first, in walk order), the pods whose
-        # row must be (re)filled, the node's uid list and its term owners
+        # ---- the arrivals of each dirty node: a row for each that has
+        # none (lowest free row first, in walk order), the rows to
+        # (re)fill, and the node's term owners where one came or went
         MLn = a["_kv_ids"].shape[1]
         MLp = a["_pod_kv_ids"].shape[1]
         free, n_free, k_free = self.free_rows, len(self.free_rows), 0
         reset_nodes: List[Tuple[int, object]] = []
-        fills: List[Tuple[str, int, object, int, object]] = []
+        fills: List[Tuple[int, object, int]] = []   # (row, PodInfo, node row)
         # term-carrying pod churn does NOT force a full resync: the
         # flattened ExistingTerms rebuild from the term OWNERS alone and
         # replace wholesale, and only when a dirty node's owners are not
         # the ones the tables were built from (_refresh_terms says why
-        # that is the whole condition).  The anti-entropy verifier cannot
-        # catch a table kept wrongly: it compares the device with the
-        # mirror, and both would be stale together — tests/test_delta.py
-        # holds the kept tables to a fresh build()
+        # that is the whole condition).  A node's ordered owners can
+        # differ from the noted ones only where an owner came or went
+        # (a plain pod beside them moves no owner's row, terms or place
+        # among the owners), so only there are the node's pods walked.
+        # The anti-entropy verifier cannot catch a table kept wrongly: it
+        # compares the device with the mirror, and both would be stale
+        # together — tests/test_delta.py holds the kept tables to a fresh
+        # build()
         owners_were: Dict[str, Tuple[TermOwner, ...]] = {}
         owner_seen = False
-        pods_seen = 0
-        for i, ni, uids in walk:
+        pods_walked = 0
+        for i, ni, came, owner_went in walk:
             name = names[i]
             if ni.node_generation != node_src.get(name):
                 if len(ni.node.metadata.labels) + 1 > MLn:
@@ -480,39 +522,49 @@ class DeltaTensorizer:
                 b.intern_node(ni)
                 reset_nodes.append((i, ni))
             b.intern_node_usage(ni)
-            owners = []
-            for uid, pi in zip(uids, ni.pods):
+            owner_came = False
+            for pi in came:
+                if len(pi.pod.metadata.labels) > MLp:
+                    return self._resync(node_infos, names,
+                                        "label-capacity", t0, pending)
+                b.intern_pod(pi)
+                uid = pi.pod.metadata.uid
                 row = pod_row.get(uid)
-                src = pod_src.get(uid)
-                if src is not None and src[0] is pi and src[1] == i:
-                    owner = src[2]
-                else:
-                    if len(pi.pod.metadata.labels) > MLp:
-                        return self._resync(node_infos, names,
-                                            "label-capacity", t0, pending)
-                    b.intern_pod(pi)
-                    if row is None:
-                        if k_free < n_free:
-                            row = free[k_free]
-                            k_free += 1
-                        else:
-                            row = self.next_pod_row
-                            self.next_pod_row += 1
-                        pod_row[uid] = row
-                    owner = self._owner(uid, row, pi)
-                    fills.append((uid, row, pi, i, owner))
-                touched_pods.add(row)
+                if row is None:
+                    if k_free < n_free:
+                        row = free[k_free]
+                        k_free += 1
+                    else:
+                        row = self.next_pod_row
+                        self.next_pod_row += 1
+                    pod_row[uid] = row
+                    if row < len(row_uids):
+                        row_uids[row] = uid
+                    else:
+                        # past the capacity the list grows a row at a
+                        # time; _grow_pod_axis pads it to the new bucket
+                        row_uids.append(uid)
+                owner = self._owner(uid, row, pi)
                 if owner is not None:
-                    owners.append(owner)
-            pods_seen += len(uids)
-            node_pods[name] = uids
-            owners = tuple(owners)
+                    owner_came = True
+                pod_src[uid] = (pi, i, owner)
+                fills.append((row, pi, i))
+                touched_pods.add(row)
             was = node_owners[name]
-            if owners != was:
-                owners_were[name] = was
-                node_owners[name] = owners
-            elif owners:
-                owner_seen = True
+            if owner_came or owner_went:
+                owners = tuple(o for o in (pod_src[pi.pod.metadata.uid][2]
+                                           for pi in ni.pods)
+                               if o is not None)
+                pods_walked += len(ni.pods)
+                if owners != was:
+                    owners_were[name] = was
+                    node_owners[name] = owners
+                elif owners:
+                    owner_seen = True
+            else:
+                pods_walked += len(came)
+                if was:
+                    owner_seen = True
         del free[:k_free]
         # AFTER the interning, BEFORE any fill: new strings from what is
         # about to be filled count against the caps the resident tensors
@@ -555,9 +607,8 @@ class DeltaTensorizer:
             a["image_size"][image_nodes <= 0] = 0.0
             a["image_spread"] = image_nodes / max(float(len(node_infos)),
                                                   1.0)
-        for uid, row, pi, i, owner in fills:
+        for row, pi, i in fills:
             fill_pod_row(a, row, pi, i, t)
-            pod_src[uid] = (pi, i, owner)
         node_rows = []
         for i, ni in dirty:
             fill_node_usage(a, i, ni, t)
@@ -570,15 +621,13 @@ class DeltaTensorizer:
             "terms_kept": int(owner_seen and not terms_dirty),
             "node_rows_dirty": len(dirty),
             "node_rows_refilled": len(reset_nodes),
-            "pod_rows_seen": pods_seen,
+            "pod_rows_seen": len(touched_pods),
             "pod_rows_refilled": len(fills),
-            # the host's visits; today also the pod rows sent
-            # (``pod_rows_seen``): every pod of a dirty node is both
-            "pods_walked": pods_seen}}
+            "pods_walked": pods_walked}}
         if terms_dirty:
             t_terms = wallclock()
             span_args["delta-terms"] = dict(
-                self._refresh_terms(owners_were), pods_walked=pods_seen)
+                self._refresh_terms(owners_were), pods_walked=pods_walked)
             term_span = (("delta-terms", t_terms, wallclock()),)
 
         pod_rows = sorted(touched_pods)
@@ -596,7 +645,18 @@ class DeltaTensorizer:
                 len(node_rows) + len(pod_rows), True, "pod-axis-growth",
                 (("delta-build", t0, t_build),) + term_span
                 + (("resync", t_build, wallclock()),), span_args=span_args)
-        delta = gather_delta(self.host, node_rows, pod_rows)
+        # ONE size for the steady state's pod rows: a serving loop's
+        # refresh sees about the last batch's arrivals and as many
+        # departures, up to twice the batch's bucket and wandering across
+        # that pow2 edge; four times the batch's bucket (the batch
+        # builder's, models/batch.py; the nominated pods behind the batch
+        # are no part of it: one of them must not double the floor in the
+        # middle of a preemption wave) holds that count and its jitter in
+        # one bucket, and more rows take the next as before
+        if batch is None:
+            batch = len(pending)
+        delta = gather_delta(self.host, node_rows, pod_rows,
+                             pod_floor=4 * pow2_bucket(batch, 8))
         t_build = wallclock()
         upload_span: list = []
         self.cluster = self._apply(delta, donate=donate,
@@ -651,21 +711,22 @@ class DeltaTensorizer:
         # its row) and, compacting, moved the intern ids
         self.node_gen, self.node_src = {}, {}
         self.node_pods, self.node_owners, self.pod_src = {}, {}, {}
+        self.row_uids = [None] * a["pod_node"].shape[0]
         for i, (name, ni) in enumerate(zip(names, node_infos)):
-            uids, owners = [], []
+            owners = []
             for pi in ni.pods:
                 uid = pi.pod.metadata.uid
-                uids.append(uid)
                 row = self.pod_row.get(uid)
                 if row is None:
                     continue         # build() gives a node-less info no row
+                self.row_uids[row] = uid
                 owner = self._owner(uid, row, pi)
                 self.pod_src[uid] = (pi, i, owner)
                 if owner is not None:
                     owners.append(owner)
             self.node_gen[name] = ni.generation
             self.node_src[name] = ni.node_generation
-            self.node_pods[name] = uids
+            self.node_pods[name] = set(ni.pods)
             self.node_owners[name] = tuple(owners)
         self.caps = self.signature()
         self.cycles_since_resync = 0
@@ -692,6 +753,7 @@ class DeltaTensorizer:
             arr = a[field]
             pad = np.full((n,) + arr.shape[1:], fill, arr.dtype)
             a[field] = np.concatenate([arr, pad])
+        self.row_uids.extend([None] * (new_pp - len(self.row_uids)))
 
     def _upload(self) -> None:
         """Full host→device transfer of the mirror (resync / pod-axis

@@ -583,14 +583,16 @@ class ClusterDelta(NamedTuple):
 
 
 def gather_delta(host: HostClusterArrays, node_rows: List[int],
-                 pod_rows: List[int]) -> ClusterDelta:
+                 pod_rows: List[int], pod_floor: int = 8) -> ClusterDelta:
     """Slice the dirty rows out of the host mirror into pow2-bucketed
-    update tables (the host half of the delta pipeline)."""
+    update tables (the host half of the delta pipeline).  ``pod_floor``:
+    the least pod-row bucket, a power of two (the DeltaTensorizer keeps a
+    serving loop's steady churn in one bucket with it)."""
     a = host.arrays
     N = a["allocatable"].shape[0]
     PP = a["pod_node"].shape[0]
     Dn = pow2_bucket(len(node_rows), 8)
-    Dp = pow2_bucket(len(pod_rows), 8)
+    Dp = pow2_bucket(len(pod_rows), pod_floor)
     nr = np.full((Dn,), N, np.int32)
     nr[:len(node_rows)] = node_rows
     pr = np.full((Dp,), PP, np.int32)

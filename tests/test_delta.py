@@ -631,7 +631,10 @@ def test_the_golden_fails_when_the_pod_marker_cannot_see_the_change(
 
 def _scrambled(w):
     """Rows out of walk order and a freed row, so that a rebuild MOVES
-    rows (it packs them in node-walk order)."""
+    rows (it packs them in node-walk order), those of a node's two
+    owners among them."""
+    w.add(owner_pod("scramble-owner", w.nodes[2].name, "red"))
+    w.refresh("rebuilt")
     w.remove(next(p for p in w.plains
                   if p.spec.node_name == w.nodes[0].name))
     w.add(plain_pod("scramble-a", w.nodes[4].name))
@@ -716,10 +719,11 @@ def run_resync_then_churn(reason, noted=assert_markers_noted):
     assert st.resync and st.reason == reason
     noted(w.dt, snapshot_of(w.cache))
     # what the markers guard, straight after: a resident replaced in
-    # place, an owner whose terms change (the tables rebuild from the
-    # noted owners' rows), a Node set again
+    # place, an owner whose terms change on the node that holds a second
+    # owner (the node's owners are re-read: the one that stayed from its
+    # marker, with the row noted there), a Node set again
     _set_again(w, 2, lambda n: setattr(n.spec, "unschedulable", True))
-    old = w.owners[0]
+    old = next(p for p in w.owners if p.metadata.name == "own-2")
     new = owner_pod(old.metadata.name, old.spec.node_name, "yellow")
     new.metadata.uid = old.uid
     w.update(old, new)
@@ -1014,6 +1018,338 @@ def test_anti_entropy_resync_interval():
         _, st = dt.refresh(snapshot_of(cache))
         reasons.append(st.reason)
     assert "anti-entropy" in reasons
+
+
+# ---------------------------------------------------------------------------
+# a refresh costs what CHANGED (PR 44): at thirty pods a node it visits the
+# arrivals, sends the rows it refilled or cleared and no other, and walks a
+# node's pods only where an owner came or went
+
+
+class DenseWorld:
+    """Eight nodes of thirty plain pods (the ``sigscale-150k`` density),
+    one refresh behind it; ``refresh()`` holds the one it makes to a fresh
+    build() and to what it may visit and send."""
+
+    PER_NODE = 30
+
+    def __init__(self, monkeypatch):
+        from kubetpu.state import delta as delta_mod
+        self.cache, self.nodes, pods = build_cache(
+            n_nodes=8, pods_per_node=self.PER_NODE)
+        self.on = {n.name: [] for n in self.nodes}
+        for p in pods:
+            self.on[p.spec.node_name].append(p)
+        self.dt = DeltaTensorizer()
+        self.seq = 0
+        self.cleared, self.filled, self.sent = [], [], []
+        for name, into in (("clear_pod_row", self.cleared),
+                           ("fill_pod_row", self.filled)):
+            monkeypatch.setattr(delta_mod, name, self._spy(
+                getattr(delta_mod, name), into))
+        gather = delta_mod.gather_delta
+
+        def gathered(host, node_rows, pod_rows, **kw):
+            self.sent.append(list(pod_rows))
+            return gather(host, node_rows, pod_rows, **kw)
+        monkeypatch.setattr(delta_mod, "gather_delta", gathered)
+        assert self.refresh().reason == "initial"
+
+    @staticmethod
+    def _spy(fn, into):
+        def spied(d, row, *a):
+            into.append(row)
+            return fn(d, row, *a)
+        return spied
+
+    def arrive(self, i, pod=None):
+        self.seq += 1
+        p = pod or plain_pod(f"new-{self.seq}", self.nodes[i].name)
+        p.spec.node_name = self.nodes[i].name
+        self.cache.add_pod(p)
+        self.on[p.spec.node_name].append(p)
+        return p
+
+    def leave(self, i, k=0):
+        p = self.on[self.nodes[i].name].pop(k)
+        self.cache.remove_pod(p)
+        return p
+
+    def refresh(self):
+        del self.cleared[:], self.filled[:], self.sent[:]
+        infos = snapshot_of(self.cache)
+        dirty = [ni for ni in infos
+                 if ni.generation != self.dt.node_gen.get(ni.node_name)]
+        owned_before = {ni.node_name for ni in dirty
+                        if self.dt.node_owners.get(ni.node_name)}
+        _, st = self.dt.refresh(infos)
+        assert_matches_fresh(self.dt, infos)
+        uids = self.dt.pod_uid_list()
+        assert len(uids) == self.dt.host.arrays["pod_node"].shape[0]
+        assert {u: r for r, u in enumerate(uids) if u} == self.dt.pod_row
+        if "delta-build" not in st.span_args:
+            return st
+        said = st.span_args["delta-build"]
+        rows = set(self.cleared) | set(self.filled)
+        assert said["pod_rows_refilled"] == len(self.filled) \
+            == len(set(self.filled))
+        assert said["pod_rows_seen"] == len(rows)
+        assert st.delta_rows == len(dirty) + len(rows)
+        if not st.resync:       # growth re-uploads: nothing is gathered
+            assert self.sent == [sorted(rows)]
+        with_owners = sum(
+            len(ni.pods) for ni in dirty
+            if ni.node_name in owned_before
+            or self.dt.node_owners[ni.node_name])
+        assert said["pods_walked"] <= len(self.filled) + with_owners
+        return st
+
+
+def _one_in_one_out(w):
+    """The benchmark client's churn: on k nodes a pod arrives and the one
+    that arrived a refresh earlier leaves."""
+    last = []
+    for cycle in range(4):
+        for i, p in last:
+            w.on[w.nodes[i].name].remove(p)
+            w.cache.remove_pod(p)
+        last = [(i, w.arrive(i))
+                for i in range(cycle % 3, 8, 2 + cycle % 2)]
+        st = yield
+        assert st.span_args["delta-build"]["pods_walked"] == len(last)
+
+
+def _replaced_on_its_node_under_its_uid(w):
+    old = w.on[w.nodes[2].name][7]
+    new = copy.copy(old)
+    new.metadata = copy.copy(old.metadata)
+    new.metadata.labels = {"app": "group-1", "tier": "b"}
+    w.cache.update_pod(old, new)
+    w.on[w.nodes[2].name][7] = new
+    row = w.dt.pod_row[old.uid]
+    st = yield
+    # the row is refilled where it is; nothing is freed
+    assert w.dt.pod_row[new.uid] == row
+    assert (w.cleared, w.filled) == ([], [row]) and not w.dt.free_rows
+    assert st.span_args["delta-build"]["pods_walked"] == 1
+
+
+def _moved_between_two_dirty_nodes(w):
+    for src, dst in ((6, 1), (1, 5)):       # to a lower node, to a higher
+        p = w.leave(src, 3)
+        moved = copy.copy(p)
+        moved.spec = copy.copy(p.spec)
+        w.arrive(dst, moved)
+        w.arrive(src)                       # beside a plain arrival
+        st = yield
+        assert st.span_args["delta-build"]["pods_walked"] == 2
+        assert len(w.cleared) == 1 and len(w.filled) == 2
+
+
+def _an_owner_arrives_where_none_was_and_leaves(w):
+    owner = w.arrive(4, owner_pod("late-owner", kind="green"))
+    st = yield
+    assert "delta-terms" in st.span_args
+    # the owner's node is read for its owners: its thirty and the owner
+    assert st.span_args["delta-build"]["pods_walked"] == w.PER_NODE + 1
+    w.arrive(4)
+    w.arrive(5)
+    st = yield
+    # a plain pod beside the owner: the tables are kept, nothing is walked
+    said = st.span_args["delta-build"]
+    assert (said["terms_kept"], said["pods_walked"]) == (1, 2)
+    w.on[w.nodes[4].name].remove(owner)
+    w.cache.remove_pod(owner)
+    w.arrive(5)
+    st = yield
+    assert st.span_args["delta-terms"]["owners_changed"] == 1
+    assert st.span_args["delta-build"]["pods_walked"] == w.PER_NODE + 1 + 1
+    assert w.dt.node_owners[w.nodes[4].name] == ()
+    w.arrive(4)
+    st = yield
+    said = st.span_args["delta-build"]
+    assert (said["terms_kept"], said["pods_walked"]) == (0, 1)
+
+
+def _the_pod_axis_grows(w):
+    pp0 = w.dt.host.arrays["pod_node"].shape[0]
+    w.leave(0)
+    for k in range(pp0 - len(w.dt.pod_row) + 2):
+        w.arrive(k % 8)
+    st = yield
+    assert st.reason == "pod-axis-growth"
+    assert w.dt.host.arrays["pod_node"].shape[0] == 2 * pp0
+    w.leave(3)
+    w.arrive(6)
+    st = yield
+    assert not st.resync and len(w.filled) == 1
+
+
+def _a_resync_in_the_middle(w):
+    w.leave(1)
+    w.arrive(2)
+    yield
+    w.dt.cycles_since_resync = w.dt.resync_interval
+    w.leave(2, 5)
+    w.arrive(3)
+    st = yield
+    assert st.reason == "anti-entropy"
+    w.leave(3, 9)
+    w.arrive(1)
+    w.arrive(1)
+    st = yield
+    assert not st.resync
+    assert st.span_args["delta-build"]["pods_walked"] == 2
+
+
+CHANGES = [_one_in_one_out, _replaced_on_its_node_under_its_uid,
+           _moved_between_two_dirty_nodes,
+           _an_owner_arrives_where_none_was_and_leaves,
+           _the_pod_axis_grows, _a_resync_in_the_middle]
+
+
+@pytest.mark.parametrize("steps", CHANGES, ids=_ids(CHANGES))
+def test_a_refresh_visits_and_sends_what_changed(steps, monkeypatch):
+    """After EVERY refresh the resident tensors are a fresh build()'s,
+    ``pod_rows_seen`` is the rows refilled or cleared (and they are what
+    ``gather_delta`` was given), and ``pods_walked`` is at most the
+    arrivals and the pods of the dirty nodes that hold owners: never the
+    thirty of a node where only plain pods came and went."""
+    w = DenseWorld(monkeypatch)
+    gen = steps(w)
+    next(gen)
+    while True:
+        st = w.refresh()
+        try:
+            gen.send(st)
+        except StopIteration:
+            break
+
+
+@pytest.mark.parametrize("nominated", [0, 3],
+                         ids=["batch-alone", "nominated-pods-behind-it"])
+def test_steady_churn_at_a_batch_of_1024_dispatches_one_bucket_pair(
+        nominated):
+    """Forty refreshes of 900-1,150 arrivals and as many departures, the
+    1,024 pending pods handed in: after the first every scatter runs at
+    ONE (Dn, Dp).  The changed rows alone wander across 1,024 and 2,048;
+    the floor of four times the batch's bucket holds them in 4,096.  The
+    nominated pods the scheduler hands in behind a FULL batch (a
+    preemption wave under way) are interned and move no floor: 1,027
+    pending would bucket to 2,048 and send the same rows at 8,192."""
+    rng = random.Random(44)
+    cache = SchedulerCache()
+    nodes = hollow.make_nodes(1600, zones=3)
+    for n in nodes:
+        cache.add_node(n)
+    seq = 0
+
+    def arrivals(k):
+        nonlocal seq
+        out = []
+        for _ in range(k):
+            seq += 1
+            p = plain_pod(f"churn-{seq}", rng.choice(nodes).name)
+            cache.add_pod(p)
+            out.append(p)
+        return out
+    arrivals(2000)                      # residents that stay
+    last = arrivals(1024)
+    from kubetpu.framework.types import PodInfo
+    pending = [PodInfo(plain_pod(f"pending-{k}", "")) for k in range(1024)]
+    said = {}
+    if nominated:
+        said = {"batch": len(pending)}
+        pending = pending + [PodInfo(plain_pod(f"nominated-{k}", ""))
+                             for k in range(nominated)]
+    dt = DeltaTensorizer(resync_interval=1000)
+    dt.refresh(snapshot_of(cache), pending=pending, **said)
+    pairs, rows = [], []
+    for _ in range(40):
+        # the batch bound a cycle ago leaves, as the benchmark's client
+        # deletes the oldest-bound; the next one binds
+        for p in last:
+            cache.remove_pod(p)
+        last = arrivals(rng.randint(900, 1150))
+        _, st = dt.refresh(snapshot_of(cache), pending=pending, **said)
+        assert not st.resync, st.reason
+        pairs.append(st.delta_buckets)
+        rows.append(st.span_args["delta-build"]["pod_rows_seen"])
+    assert set(pairs[1:]) == {(2048, 4096)}, sorted(set(pairs))
+    # the case the floor is for: the counts do cross a pow2 edge
+    assert min(rows) <= 1024 < max(rows), (min(rows), max(rows))
+    assert_matches_fresh(dt, snapshot_of(cache))
+
+
+def test_the_scheduler_hands_refresh_the_batch_apart_from_the_nominated(
+        monkeypatch):
+    """``_prepare_group`` interns the nominated pods with the batch and
+    says how many of ``pending`` the batch is."""
+    from kubetpu.apis.config import (KubeSchedulerConfiguration,
+                                     KubeSchedulerProfile)
+    from kubetpu.client.store import ClusterStore
+    from kubetpu.scheduler import Scheduler
+    seen = []
+    orig = DeltaTensorizer.refresh
+
+    def noted(self, node_infos, pending=(), donate=True, batch=None):
+        seen.append((len(pending), batch))
+        return orig(self, node_infos, pending=pending, donate=donate,
+                    batch=batch)
+    monkeypatch.setattr(DeltaTensorizer, "refresh", noted)
+    store = ClusterStore()
+    nodes = hollow.make_nodes(8, zones=4)
+    for n in nodes:
+        store.add(n)
+    cfg = KubeSchedulerConfiguration(
+        profiles=[KubeSchedulerProfile()], batch_size=8, mode="gang",
+        chain_cycles=False)
+    sched = Scheduler(store, config=cfg, async_binding=False)
+    sched.queue.add_nominated_pod(plain_pod("waits-for-its-victims", ""),
+                                  nodes[0].name)
+    for p in hollow.make_pods(20, group_labels=4):
+        store.add(p)
+    assert len(drain(sched)) == 20
+    sched.close()
+    assert [batch for _, batch in seen] == [8, 8, 4]
+    assert [n for n, _ in seen] == [9, 9, 5]
+
+
+def test_the_cycles_row_maps_are_the_tensorizers_and_a_copy(monkeypatch):
+    """``pod_uid_list()`` and the row map a cycle derives from it when
+    first asked are ``pod_row`` the other way round after free-row reuse,
+    a pod-axis growth and a resync; the list a cycle took does not move
+    when the next refresh runs."""
+    from kubetpu.preemption import CycleContext
+    w = DenseWorld(monkeypatch)
+
+    def taken():
+        uids = w.dt.pod_uid_list()
+        ctx = CycleContext(w.dt.builder, w.dt.cluster, None,
+                           snapshot_of(w.cache))
+        ctx.pod_uids = uids
+        assert ctx.pod_rows is None           # nothing derived unasked
+        assert ctx.pod_row_map() == w.dt.pod_row
+        assert ctx.pod_row_map() is ctx.pod_rows
+        return uids, list(uids), ctx
+    kept = [taken()]
+    for steps in (_one_in_one_out, _the_pod_axis_grows,
+                  _a_resync_in_the_middle):
+        gen = steps(w)
+        next(gen)
+        while True:
+            st = w.refresh()
+            kept.append(taken())
+            try:
+                gen.send(st)
+            except StopIteration:
+                break
+    assert len({len(uids) for uids, _, _ in kept}) == 2       # it grew
+    for uids, as_taken, ctx in kept:
+        assert uids == as_taken
+        assert ctx.pod_rows == {u: r for r, u in enumerate(uids) if u}
+    # the last cycle's copy is not the tensorizer's own list
+    assert kept[-1][0] is not w.dt.row_uids
 
 
 # ---------------------------------------------------------------------------
@@ -1313,14 +1649,14 @@ def test_depth4_donation_withheld_while_ring_uncommitted():
     refreshes = []          # (donate, uncommitted-on-resident, ring len)
     orig_refresh = DeltaTensorizer.refresh
 
-    def spy(self, node_infos, pending=(), donate=True):
+    def spy(self, node_infos, pending=(), donate=True, **kw):
         on_resident = sum(
             1 for p in sched._pipeline.ring.preps()
             if p.cluster is self.cluster)
         refreshes.append((donate, on_resident,
                           len(sched._pipeline.ring)))
         return orig_refresh(self, node_infos, pending=pending,
-                            donate=donate)
+                            donate=donate, **kw)
 
     DeltaTensorizer.refresh = spy
     try:

@@ -5,7 +5,11 @@ pods a node are held to a fresh build (``test_delta.assert_matches_fresh``),
 and the four things the flight recorder says since PR 35 are counted by
 hand: the ``delta-build`` span's ``pods_walked``, the ``snapshot`` span's
 ``pods_copied``, and the cycle meta ``pod_rows_live`` and
-``cluster_device_bytes`` (``ClusterTensors.nbytes``)."""
+``cluster_device_bytes`` (``ClusterTensors.nbytes``).  Since PR 44 the
+refresh visits and sends what CHANGED: ``pods_walked`` is the arrivals
+(no pod here owns a term), ``pod_rows_seen`` the rows refilled or
+cleared, at 1 and at 30 pods a node alike; the snapshot still copies the
+dirty nodes whole."""
 
 import pytest
 
@@ -38,8 +42,9 @@ def test_arrivals_and_departures_stay_golden_and_say_what_they_walked(
     """Six refreshes: on k of the 8 nodes one pod arrives and (from the
     second refresh on) the one that arrived a refresh earlier leaves, as
     the benchmark's client does.  After every one the device tensors are
-    a fresh build's, the refresh walked the pods of the dirty nodes and
-    no others, and the snapshot copied as many."""
+    a fresh build's, the refresh walked the arrivals and no other pod,
+    sent the rows it refilled or cleared and no other, and the snapshot
+    copied the pods of the dirty nodes."""
     cache, nodes, _ = build_cache(n_nodes=N_NODES, pods_per_node=per_node)
     snap, dt = Snapshot(), DeltaTensorizer()
     cache.update_snapshot(snap)
@@ -53,6 +58,7 @@ def test_arrivals_and_departures_stay_golden_and_say_what_they_walked(
     for cycle in range(6):
         k = 1 + cycle % 3
         dirty = [nodes[(cycle + 2 * j) % N_NODES] for j in range(k)]
+        cleared = {dt.pod_row[p.uid] for p in last}
         for p in last:
             cache.remove_pod(p)
         gone = {p.spec.node_name for p in last}
@@ -74,8 +80,12 @@ def test_arrivals_and_departures_stay_golden_and_say_what_they_walked(
             assert dt.cluster.nbytes > bytes0
         args = st.span_args["delta-build"]
         assert args["node_rows_dirty"] == len(names)
-        assert args["pods_walked"] == on_dirty == args["pod_rows_seen"]
-        assert args["pod_rows_refilled"] == len(dirty)
+        assert args["pods_walked"] == args["pod_rows_refilled"] == len(dirty)
+        # an arrival takes the lowest free row: a row just cleared counts
+        # once
+        rows = cleared | {dt.pod_row[p.uid] for p in last}
+        assert args["pod_rows_seen"] == len(rows) <= len(dirty) + len(gone)
+        assert st.delta_rows == len(names) + len(rows)
     # an unchanged cache copies and walks nothing
     cache.update_snapshot(snap)
     assert snap.pods_copied == 0
@@ -134,11 +144,17 @@ def test_the_cycle_record_says_rows_live_bytes_walked_and_copied(
         if cycle == 0:
             assert copied == bound            # the first snapshot: all
             continue
-        # the nodes the last batch and the departure touched, whole
+        # the snapshot copied the nodes the last batch and the departure
+        # touched, whole; the build visited and sent the seven of the
+        # batch's eight that are still bound (the one that left never had
+        # a row: nothing is cleared)
         build = spans["delta-build"]["args"]
-        assert build["pods_walked"] == copied
         assert build["node_rows_dirty"] * per_node <= copied \
             <= build["node_rows_dirty"] * (per_node + 8)
+        assert build["pods_walked"] == build["pod_rows_refilled"] == 7
+        assert build["pod_rows_seen"] == 7
+        assert meta["delta_rows"] \
+            == build["node_rows_dirty"] + build["pod_rows_seen"]
     # bytes are the resident cluster's leaves, from shapes
     assert records[-1]["meta"]["cluster_device_bytes"] \
         == udevstats.pytree_nbytes(sched._delta["default-scheduler"].cluster)

@@ -105,7 +105,8 @@ def test_a_refresh_says_what_it_rebuilt_and_how_many_owners_changed():
     extra = _owner("green-extra", nodes[1].name)
     cache.add_pod(extra)
     got = refresh()
-    # walked: the dirty node's pods (its green, its red, the new one)
+    # walked: an owner came, so the dirty node's pods (its green, its
+    # red, the new one) are read for their owners
     assert got == {"filter_rows": 7, "score_rows": 3, "Et": 8, "Es": 4,
                    "pods_walked": 3, "owners_changed": 1}
     cache.remove_pod(extra)
@@ -156,34 +157,36 @@ def churn_on_three_residents(refresh_of):
     yield refresh()
 
 
-# (delta_rows, delta_buckets) of the three refreshes as the PARENT's
-# code (c4ffb52, which refilled every row of a dirty node) gives them
-# for this event sequence (read by running it): dirty nodes + every pod
-# row on them + the rows of the departed (one of the two freed rows is
-# the next arrival's).  What goes to the device does not depend on what
-# the host skipped
-PARENT_ROWS = [(2 + 8, (8, 8)), (3 + 9 + 2 - 1, (8, 16)), (1 + 5, (8, 8))]
+# (delta_rows, delta_buckets) of the three refreshes, by hand: dirty
+# nodes + the pod rows refilled or cleared (the two arrivals' rows; the
+# two departed rows, one of them the next arrival's; the other freed row
+# as the last arrival takes it).  Until PR 44 every pod row of a dirty
+# node went to the device too: 2 + 8, 3 + 9 + 2 - 1 and 1 + 5 rows in
+# buckets (8, 8), (8, 16), (8, 8).  The pod rows' bucket starts at four
+# times the pending batch's (none here: 4 x 8)
+CHANGED_ROWS = [(2 + 2, (8, 32)), (3 + 2, (8, 32)), (1 + 1, (8, 32))]
 
 
 def test_a_delta_build_says_how_many_mirror_rows_it_refilled():
-    """``pod_rows_refilled`` is the arrivals, not the residents seen;
-    ``node_rows_refilled`` counts Nodes set again; the scatter carries
-    the parent's rows all the same."""
+    """``pod_rows_refilled`` is the arrivals; ``pod_rows_seen`` the rows
+    in the delta, those and the rows cleared; ``pods_walked`` the pods
+    the host visited one at a time (no resident: no pod here owns a
+    term); ``node_rows_refilled`` counts Nodes set again."""
     dt = DeltaTensorizer()
     stats = list(churn_on_three_residents(
         lambda cache: lambda: dt.refresh(_snapshot(cache))[1]))
     assert all(not st.resync for st in stats)
     assert [(st.delta_rows, st.delta_buckets) for st in stats] \
-        == PARENT_ROWS
+        == CHANGED_ROWS
     said = [{k: v for k, v in st.span_args["delta-build"].items()
-             if k not in ("terms_kept", "pods_walked")} for st in stats]
+             if k != "terms_kept"} for st in stats]
     assert said == [
         {"node_rows_dirty": 2, "node_rows_refilled": 0,
-         "pod_rows_seen": 8, "pod_rows_refilled": 2},
+         "pod_rows_seen": 2, "pod_rows_refilled": 2, "pods_walked": 2},
         {"node_rows_dirty": 3, "node_rows_refilled": 0,
-         "pod_rows_seen": 9, "pod_rows_refilled": 1},
+         "pod_rows_seen": 2, "pod_rows_refilled": 1, "pods_walked": 1},
         {"node_rows_dirty": 1, "node_rows_refilled": 1,
-         "pod_rows_seen": 5, "pod_rows_refilled": 1}]
+         "pod_rows_seen": 1, "pod_rows_refilled": 1, "pods_walked": 1}]
 
 
 @pytest.fixture
@@ -281,9 +284,9 @@ def test_the_cycle_record_carries_the_refreshs_args_and_the_term_buckets(
     assert records[3]["meta"]["term_buckets"] == [args["Et"], args["Es"]]
     assert (args["filter_rows"], args["score_rows"], args["Et"], args["Es"],
             args["owners_changed"]) == (12, 13, 16, 16, 1)
-    # the pods of the dirty nodes (the owner's and the departure's), not
-    # the cluster's 38
-    assert 3 <= args["pods_walked"] <= 8
+    # the pods of the node the owner came to (its two and the owner: a
+    # batch of one), not the departure's node's and not the cluster's 38
+    assert args["pods_walked"] == 3
     assert records[4]["meta"]["term_buckets"] == [16, 16]
 
 
@@ -332,9 +335,9 @@ def test_a_pipelined_drain_over_owner_nodes_places_as_the_serial_one():
         seen = []
         orig = DeltaTensorizer.refresh
 
-        def spy(self, node_infos, pending=(), donate=True):
+        def spy(self, node_infos, pending=(), donate=True, **kw):
             cluster, st = orig(self, node_infos, pending=pending,
-                               donate=donate)
+                               donate=donate, **kw)
             kept = st.span_args.get("delta-build", {}).get("terms_kept")
             seen.append((donate, kept))
             return cluster, st
