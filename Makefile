@@ -4,9 +4,8 @@
 
 .PHONY: help lint lock-graph test sanitize-test race-test flight-test \
 	delta-test census census-test aot aot-test chaos-test \
-	slo-test pipeline-test journal-test replay-test devstats-test \
-	mesh-test exact exact-test close close-test load-test load-soak \
-	trend trace bench
+	pipeline-test journal-test replay-test \
+	mesh-test exact exact-test close close-test
 
 help:
 	@echo "kubetpu targets:"
@@ -27,7 +26,7 @@ help:
 	@echo "                      endpoints, disarmed no-op)"
 	@echo "  make delta-test     incremental tensorization suite (delta-vs-"
 	@echo "                      rebuild golden equivalence, resync fallbacks,"
-	@echo "                      scatter compile-once watchdog, bench gate)"
+	@echo "                      scatter compile-once watchdog)"
 	@echo "  make census         regenerate COMPILE_MANIFEST.json from the"
 	@echo "                      compile-surface census (tools/kubecensus);"
 	@echo "                      run after an INTENTIONAL surface change"
@@ -47,11 +46,6 @@ help:
 	@echo "                      scatter, aot load, bind/extender/watch"
 	@echo "                      transport), deadline demotion, anti-entropy"
 	@echo "                      verifier, disarmed-no-op poison test"
-	@echo "  make slo-test       per-pod latency SLO suite (utils/slo.py):"
-	@echo "                      sketch-vs-numpy quantile property, bounded"
-	@echo "                      memory, disarmed zero-lock poison, /debug/slo"
-	@echo "                      round trip, exemplar links, armed-vs-disarmed"
-	@echo "                      placement parity"
 	@echo "  make pipeline-test  depth-k pipelined executor suite"
 	@echo "                      (kubetpu/pipeline.py): depth-parity"
 	@echo "                      placement goldens, gather-window gating on"
@@ -68,13 +62,6 @@ help:
 	@echo "                      drain replays byte-identical, corrupt-"
 	@echo "                      record skip with reason, counterfactual"
 	@echo "                      score-weight/pipelineDepth divergence"
-	@echo "  make devstats-test  device-side observability suite"
-	@echo "                      (kubetpu/utils/devstats.py): sampled"
-	@echo "                      per-program device-time fences, roofline"
-	@echo "                      join vs COMPILE_MANIFEST.json, residency"
-	@echo "                      ledger + capacity-planner 10% sanity gate,"
-	@echo "                      /debug/devicez round trip, disarmed poison,"
-	@echo "                      armed-vs-disarmed placement parity"
 	@echo "  make mesh-test      pod-axis mesh scale-out suite (parallel/"
 	@echo "                      shardmap.py): (2,4)/(4,2)/(1,8) sharded-vs-"
 	@echo "                      unsharded bit-identity through the shard_map"
@@ -105,23 +92,6 @@ help:
 	@echo "                      --check under a jax import blocker, stale-"
 	@echo "                      exemption audit, serving-path dispatch-"
 	@echo "                      signature membership e2e"
-	@echo "  make load-test      sustained-load telemetry plane suite"
-	@echo "                      (utils/telemetry.py + harness streams +"
-	@echo "                      SustainedLoadRunner): window-delta-vs-numpy"
-	@echo "                      exactness, ring wrap/drop bounds, disarmed"
-	@echo "                      poison, parity golden, chaos-window"
-	@echo "                      attribution, /debug/loadz + /metrics"
-	@echo "  make load-soak      minutes-scale open-loop soak (slow-marked):"
-	@echo "                      steady-state span found, zero demotions"
-	@echo "  make trend          per-case bench trend table over saved"
-	@echo "                      BENCH_OUT runs (none is committed) with"
-	@echo "                      per-stage regression attribution"
-	@echo "                      (tools/benchtrend.py)"
-	@echo "  make trace          run the pipelined drain with the flight"
-	@echo "                      recorder armed, write PIPELINE_TRACE.json +"
-	@echo "                      .perfetto.json, print the text flame summary"
-	@echo "  make bench          end-to-end throughput benchmark (bench.py;"
-	@echo "                      BENCH_OUT=<path> writes the JSON atomically)"
 
 lint:
 	./tools/ci_lint.sh
@@ -194,14 +164,6 @@ chaos-test:
 	JAX_PLATFORMS=cpu python -m pytest \
 		tests/test_chaos.py -q -m 'not slow' -p no:cacheprovider
 
-# per-pod latency SLO layer (kubetpu/utils/slo.py): streaming quantile
-# sketch correctness, the disarmed-hot-path zero-lock contract, the
-# /debug/slo endpoint, exemplar->flight-recorder linkage, and the
-# golden parity proof that arming changes zero placements
-slo-test:
-	JAX_PLATFORMS=cpu python -m pytest \
-		tests/test_slo.py -q -p no:cacheprovider
-
 # depth-k pipelined executor (kubetpu/pipeline.py): depth-parity
 # placement goldens, the gather-window/free-slot gate, ring exemption
 # accounting, ring-slot flight tags, and the chaos-at-depth scatter
@@ -230,15 +192,6 @@ replay-test:
 	JAX_PLATFORMS=cpu python -m pytest \
 		tests/test_replay.py -q -m 'not slow' -p no:cacheprovider
 
-# device-side observability (kubetpu/utils/devstats.py): measured
-# per-program device time via sampled deep-timing fences, the roofline
-# join against the committed manifest cost rows, the HBM residency
-# ledger + the capacity planner's projection-vs-measured 10% gate, and
-# the house arming contract (disarmed poison, placement parity)
-devstats-test:
-	JAX_PLATFORMS=cpu python -m pytest \
-		tests/test_devstats.py -q -p no:cacheprovider
-
 # jaxpr-level exactness prover + collective census (tools/
 # kubeexact): abstract interpretation of every exact-marked mesh
 # root proves each cross-shard reduction is float max/min or
@@ -265,33 +218,3 @@ close:
 close-test:
 	JAX_PLATFORMS=cpu python -m pytest \
 		tests/test_kubeclose.py -q -p no:cacheprovider
-
-# sustained-load telemetry plane (kubetpu/utils/telemetry.py + the
-# open-loop harness streams in kubetpu/harness/hollow.py + perf.py
-# SustainedLoadRunner): window-delta merge exactness vs numpy, ring
-# wrap + drop counting, the disarmed zero-cost poison test, the
-# armed-vs-disarmed placement-parity golden, seeded chaos-storm
-# window attribution, /debug/loadz and the /metrics window series
-load-test:
-	JAX_PLATFORMS=cpu python -m pytest \
-		tests/test_telemetry.py -q -m 'not slow' -p no:cacheprovider
-
-# the minutes-scale sustained soak (excluded from tier-1 via the slow
-# marker): a live open-loop Poisson stream must reach a steady-state
-# span with zero recovery-ladder demotions and a bounded ring
-load-soak:
-	JAX_PLATFORMS=cpu python -m pytest \
-		tests/test_telemetry.py -q -m slow -p no:cacheprovider
-
-# bench trend table + regression attribution over the committed rounds
-trend:
-	python -m tools.benchtrend
-
-# pipelined-drain trace via the flight recorder + text flame summary
-# (PIPELINE_TRACE.json + PIPELINE_TRACE.perfetto.json for ui.perfetto.dev)
-trace:
-	python tools/trace_pipeline.py
-	python tools/traceview.py PIPELINE_TRACE.json
-
-bench:
-	python bench.py

@@ -9,10 +9,10 @@ cluster size upstream scheduler_perf's 5000-node rows use
 shape, 5,000 bound init pods).  It refuses to pass on anything but a
 TPU.  Phases:
 
-  gang        daemon + HTTP server, then a 4,096-pod backlog of bench.py's
+  gang        daemon + HTTP server, then a 4,096-pod backlog of the
               blended mix (1/3 soft zone spread, 1/5 hostname
               anti-affinity) at batch_size 1024; /healthz, /metrics and
-              /debug/devicez answered while it serves
+              /debug/flightz answered while it serves
   warm        the same shapes drained twice, synchronously: the second
               drain must not compile anything
   sequential  the config-default mode, 256 pods on the same cluster
@@ -242,9 +242,56 @@ def wait_drained(sched, store, names, timeout):
     return {n: store.get_pod("default", n).spec.node_name for n in names}
 
 
+# -------------------------------------------------------------------- world
+
+
+def build_world(n_nodes, n_pods, existing_per_node):
+    """The smoke's cluster and backlog: zoned hollow nodes with
+    ``existing_per_node`` bound pods each, and ``n_pods`` pending pods of
+    scheduler_perf's blended mix (1/3 soft zone spread, 1/5 hostname
+    anti-affinity on the app group)."""
+    from kubetpu.harness import hollow
+    return (hollow.restart_world(n_nodes, existing_per_node),
+            hollow.restart_wave(n_pods, prefix="pend-"))
+
+
+def explain(sched, pods):
+    """Attribute every pod of ``pods`` (the unbound ones) to its blocking
+    filter(s) against the final cluster state (the state in which the
+    last failures occurred)."""
+    import jax
+    import numpy as np
+
+    from kubetpu.api import types as api
+    from kubetpu.framework.types import PodInfo
+    from kubetpu.models import programs
+    from kubetpu.models.batch import PodBatchBuilder
+    from kubetpu.state.tensors import SnapshotBuilder
+
+    sched.cache.update_snapshot(sched.snapshot)
+    sb = SnapshotBuilder()
+    pinfos = [PodInfo(p) for p in pods]
+    sb.intern_pending(pinfos)
+    cluster = sb.build(sched.snapshot.node_info_list).to_device()
+    batch = jax.tree.map(np.asarray, PodBatchBuilder(sb.table).build(pinfos))
+    # attribute against the ACTIVE profile's filter list with the hostname
+    # topo key (not the zone key) so attribution matches what actually
+    # blocked scheduling
+    fwk = next(iter(sched.profiles.values()))
+    cfg = programs.ProgramConfig(
+        filters=fwk.tensor_filters, scores=fwk.tensor_scores,
+        hostname_topokey=max(sb.table.topokey.get(api.LABEL_HOSTNAME), 0),
+        plugin_args=fwk.tensor_plugin_args(sb.table))
+    no_feas, blocking = programs.explain_filters(cluster, batch, cfg)
+    blocking = np.asarray(blocking)[:, :len(pods)]
+    counts = {name: int(blocking[i].sum())
+              for i, name in enumerate(cfg.filters) if blocking[i].any()}
+    counts["_unschedulable"] = int(np.asarray(no_feas)[:len(pods)].sum())
+    return counts
+
+
 def verify_store(sched, store, before, pending, binds):
     """host_recheck + attribution of every unbound pod, from the store."""
-    import bench
     pods = store.list("Pod")
     nodes = store.list("Node")
     bad = host_recheck(nodes, pods, binds)
@@ -258,8 +305,7 @@ def verify_store(sched, store, before, pending, binds):
           f"{len(unbound)} pods unbound, host count predicts {want}")
     attributed = {}
     if unbound:
-        Out = collections.namedtuple("Out", "pod node")
-        attributed = bench.explain(sched, [Out(p, "") for p in unbound])
+        attributed = explain(sched, unbound)
         check(attributed.get("_unschedulable") == len(unbound),
               f"unattributed unbound pods: {attributed} vs {len(unbound)}")
     return len(pending) - len(unbound), attributed
@@ -277,9 +323,8 @@ def daemon_drain(sz, mode, n_pods, batch, seed, http=False):
     """The serving path the way ``python -m kubetpu`` wires it: store ->
     Scheduler -> SchedulerServer -> Scheduler.run() (prewarm included),
     then the backlog arrives."""
-    import bench
     from kubetpu.server import SchedulerServer
-    store, pending = bench.build_world(sz.nodes, n_pods, 1)
+    store, pending = build_world(sz.nodes, n_pods, 1)
     before = [p for p in store.list("Pod") if p.spec.node_name]
     binds = watch_binds(store)
     sched = make_sched(store, mode, batch, seed)
@@ -326,16 +371,10 @@ def http_checks(port, store, names):
                 if store.get_pod("default", n).spec.node_name)
     check(scheduled == bound,
           f"/metrics scheduled={scheduled}, store has {bound} bound")
-    status, body = http_get(port, "/debug/devicez")
-    check(status == 200, f"/debug/devicez: {status}")
-    doc = json.loads(body)
-    fenced = doc["programs"].get("run_auction", {}).get("count", 0)
-    check(fenced >= 1, "/debug/devicez: no fenced run_auction sample")
-    check(doc["ledger"]["total_bytes"] > 0,
-          "/debug/devicez: empty residency ledger")
-    return {"healthz": "ok", "metrics_scheduled": scheduled,
-            "devicez_fenced_auctions": fenced,
-            "devicez_ledger_bytes": doc["ledger"]["total_bytes"]}
+    status, body = http_get(port, "/debug/flightz")
+    check(status == 200 and "armed" in json.loads(body),
+          f"/debug/flightz: {status}")
+    return {"healthz": "ok", "metrics_scheduled": scheduled}
 
 
 def shard_devices(sched):
@@ -347,7 +386,7 @@ def shard_devices(sched):
 
 
 def sync_drain(store, pending, sched, what):
-    """Synchronous drain (bench.py's loop): the caller's thread runs every
+    """Synchronous drain: the caller's thread runs every
     cycle, so the batches — and the shapes — are the same in every run.
     Returns ({pod name: node or ""}, pods bound), host-rechecked."""
     before = [p for p in store.list("Pod") if p.spec.node_name]
@@ -379,12 +418,11 @@ def phase_gang(sz, seed):
 def phase_warm(sz, seed, watchdog):
     """Two identical synchronous drains; the second must find every
     program in the process's jit caches."""
-    import bench
     info = {"nodes": sz.nodes, "backlog": sz.warm, "batch": sz.batch}
     results = []
     for label in ("first", "second"):
         c0 = watchdog.compile_count()
-        store, pending = bench.build_world(sz.nodes, sz.warm, 1)
+        store, pending = build_world(sz.nodes, sz.warm, 1)
         sched = make_sched(store, "gang", sz.batch, seed,
                            async_binding=False)
         placed, info[f"{label}_bound"] = sync_drain(store, pending, sched,
@@ -455,7 +493,6 @@ def phase_mesh(sz, seed):
     """The gang drain through Scheduler under mesh_shape — the shard_map
     programs — against the same drain on one device.  Synchronous drains:
     the batches must be identical for the placements to be comparable."""
-    import bench
     import jax
     n = jax.device_count()
     if n < 4:
@@ -464,7 +501,7 @@ def phase_mesh(sz, seed):
     ref = None
     for shape in (None, (1, 4), (2, 2)):
         label = "single_device" if shape is None else "%dx%d" % shape
-        store, pending = bench.build_world(sz.nodes, sz.mesh, 1)
+        store, pending = build_world(sz.nodes, sz.mesh, 1)
         sched = make_sched(store, "gang", sz.batch, seed,
                            async_binding=False, mesh_shape=shape)
         t0 = time.time()
@@ -486,8 +523,7 @@ def phase_mesh(sz, seed):
 def phase_big_batch(sz, seed):
     """One 8,192-pod gang cycle on the 5,000-node cluster (not gating:
     the pre-PR-1 notes say it died with a device error)."""
-    import bench
-    store, pending = bench.build_world(sz.nodes, BIG_BATCH, 1)
+    store, pending = build_world(sz.nodes, BIG_BATCH, 1)
     sched = make_sched(store, "gang", BIG_BATCH, seed, async_binding=False)
     t0 = time.time()
     _, bound = sync_drain(store, pending, sched, "big batch")
@@ -530,7 +566,6 @@ def main() -> int:
         print("chip_smoke: no TPU — refusing to run", file=sys.stderr)
         return 2
 
-    from kubetpu.utils import devstats as udevstats
     from kubetpu.utils.compilation import enable_persistent_cache
     from kubetpu.utils.sanitize import (install_compile_timer,
                                         install_compile_watchdog)
@@ -540,7 +575,6 @@ def main() -> int:
           f"{os.environ.get('JAX_COMPILATION_CACHE_DIR')})", flush=True)
     timer = install_compile_timer()
     watchdog = install_compile_watchdog()
-    udevstats.arm_devstats()
 
     sz = Sizes(args.rehearse)
     phases = {"gang": lambda: phase_gang(sz, args.seed)[0],

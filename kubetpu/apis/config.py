@@ -130,7 +130,7 @@ class KubeSchedulerConfiguration:
     # (SURVEY §7 delta updates).  Default ON as of round 4: a randomized
     # chain-vs-fresh-rebuild equivalence test under event churn
     # (tests/test_chain.py) proves placements identical, and the measured
-    # multi-cycle drain (bench.py chain_drain) shows ~7% e2e at 4096x1000
+    # multi-cycle drain (round 4, CPU) showed ~7% e2e at 4096x1000
     # — growing with cluster size, since the saved SnapshotBuilder.build
     # scales with nodes+pods while the chain update is O(batch).  Any
     # store event the chain cannot account for still forces a full
@@ -146,7 +146,7 @@ class KubeSchedulerConfiguration:
     # that many chained cycles in a BACKGROUND thread after startup (gang
     # mode; see Scheduler._prewarm_ladder).  Without it, each new bucket
     # a drain grows into stalls serving for its compile.  Measured warm
-    # restart (bench.py warm_restart_case, 1024-pod wave x 1000 nodes):
+    # restart (round 5, CPU; 1024-pod wave x 1000 nodes):
     # first cycle 0.36 s.
     prewarm_ladder: int = 2
     # Pipelined drain (gang + chain_cycles only): schedule_pending
@@ -173,8 +173,8 @@ class KubeSchedulerConfiguration:
     # synchronous (every cycle commits before the next pops), 2 = the
     # historical double-buffered chain (the default), higher depths park
     # more dispatched-but-uncommitted cycles between schedule_pending
-    # calls.  Placements are bit-identical at every depth (the bench
-    # pipeline_depth case's gated contract).  Env override:
+    # calls.  Placements are bit-identical at every depth
+    # (tests/test_pipeline.py).  Env override:
     # KUBETPU_PIPELINE_DEPTH (an operator can re-depth a live fleet).
     pipeline_depth: int = 2
 

@@ -37,7 +37,7 @@ class BindJob:
                  "pooled", "error", "_applied")
 
     def __init__(self, flight=None, lane_ok: bool = True):
-        # (fwk, qp, state, assumed, node_name, slo, row) a pod, batch order
+        # (fwk, qp, state, assumed, node_name, row) a pod, batch order
         self.entries: List[tuple] = []
         # the cycle's CycleRecord (bind table) and the commit phase's span
         # (its ``binds_pooled`` arg), both None disarmed

@@ -191,9 +191,9 @@ class QueuedPodInfo:
     """Queue bookkeeping for a pending pod.
     reference: types.go:43 (QueuedPodInfo)."""
     pod: api.Pod
-    # wallclock (utils/trace.py), not time.time: these stamps anchor the
-    # SLO layer's queue_wait/backoff/e2e durations against scheduler-side
-    # wallclock stamps — the whole domain must share the monotonic clock
+    # wallclock (utils/trace.py), not time.time: the scheduler measures
+    # these stamps against its own wallclock stamps (the e2e and
+    # pod-scheduling histograms) — the whole domain shares one clock
     timestamp: float = field(default_factory=wallclock)
     attempts: int = 0
     initial_attempt_timestamp: float = field(default_factory=wallclock)
@@ -201,23 +201,12 @@ class QueuedPodInfo:
     # scheduler.go:515 podSchedulingCycle := SchedulingQueue.SchedulingCycle()
     # is read at pop time, not at failure time)
     scheduling_cycle: int = 0
-    # when the pod was popped into its current cycle — stamped by the
-    # queue ONLY while the SLO tracker (utils/slo.py) is armed; 0.0 means
-    # "never stamped" and the SLO layer skips the pod
-    pop_timestamp: float = 0.0
-    # the SLO layer already recorded an "unresolvable" vector for this
-    # pod: requeued pods retry, and re-recording every failing cycle
-    # would multi-count the pod in the sketches (a later successful bind
-    # still records its own "bound" vector)
-    slo_unres_observed: bool = False
 
     def deep_copy(self) -> "QueuedPodInfo":
         return QueuedPodInfo(pod=self.pod, timestamp=self.timestamp,
                              attempts=self.attempts,
                              initial_attempt_timestamp=self.initial_attempt_timestamp,
-                             scheduling_cycle=self.scheduling_cycle,
-                             pop_timestamp=self.pop_timestamp,
-                             slo_unres_observed=self.slo_unres_observed)
+                             scheduling_cycle=self.scheduling_cycle)
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,7 @@ import statistics
 import sys
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Dict, List, Optional
 
 from ..api import types as api
 from ..apis.config import KubeSchedulerConfiguration, KubeSchedulerProfile
@@ -28,16 +28,6 @@ from ..scheduler import Scheduler
 from ..utils import trace as _utrace
 from ..utils.metrics import SchedulerMetrics
 from . import hollow
-
-
-def host_share(device_wait_s: float, elapsed_s: float) -> float:
-    """ONE definition of the serial-exposure number every reporting
-    surface shares (bench.py's run_mode / pv_heavy cases and the perf
-    harness's SchedulerStats below — it used to be computed inline in
-    each): the fraction of wall time NOT spent blocked on the per-cycle
-    packed readback, i.e. the host-side share of the drain the depth-k
-    pipelined executor (kubetpu/pipeline.py) exists to hide."""
-    return round(1.0 - device_wait_s / max(elapsed_s, 1e-9), 3)
 
 
 @dataclass
@@ -262,102 +252,6 @@ def _stats(samples: List[float]) -> Dict[str, float]:
             "Perc99": round(perc(0.99), 2)}
 
 
-class SustainedLoadRunner:
-    """OPEN-LOOP sustained-load driver: fires a seeded arrival/departure
-    event stream (hollow.poisson_stream / burst_stream / diurnal_stream)
-    at its WALL DEADLINES against a live serving scheduler — each event
-    fires when its timestamp says, REGARDLESS of scheduler backpressure.
-    That is the coordinated-omission defense: a closed-loop driver that
-    waits for the scheduler before offering the next pod silently
-    excludes exactly the requests a slow scheduler would have made wait,
-    so its latency numbers flatter every stall.  Here the OFFERED rate
-    is fixed by the stream and the COMPLETED rate is measured
-    separately; the gap between them (plus ``behind_max_s``, how far the
-    injector itself fell behind its deadlines) is reported, never
-    hidden.
-
-    The latency verdict comes from the windowed telemetry ring
-    (utils/telemetry.py, armed by the caller): per-window e2e p99 with
-    warmup excluded by the steady-state slope test — not a
-    run-cumulative quantile that averages warmup compiles into the
-    steady number."""
-
-    def __init__(self, store: ClusterStore, sched: Scheduler,
-                 events: List[Dict[str, Any]], duration_s: float,
-                 settle_s: float = 30.0):
-        self.store = store
-        self.sched = sched
-        self.events = events
-        self.duration_s = float(duration_s)
-        self.settle_s = float(settle_s)
-
-    def run(self) -> Dict[str, Any]:
-        from ..utils import telemetry as _telemetry
-        offered = deletes = completed_deleted = 0
-        behind_max = 0.0
-        added: List[tuple] = []
-        t0 = time.time()
-        for e in self.events:
-            deadline = t0 + e["t"]
-            now = time.time()
-            if deadline > now:
-                time.sleep(deadline - now)
-            else:
-                behind_max = max(behind_max, now - deadline)
-            pod = e["pod"]
-            if e["kind"] == "add":
-                self.store.add(pod)
-                offered += 1
-                added.append((pod.namespace, pod.metadata.name))
-            else:
-                cur = self.store.get_pod(pod.namespace, pod.metadata.name)
-                if cur is not None:
-                    # a pod bound before its departure still COMPLETED —
-                    # the churn deletes it from the cluster, not from
-                    # the ledger
-                    if cur.spec.node_name:
-                        completed_deleted += 1
-                    self.store.delete(cur)
-                    deletes += 1
-
-        def bound_now() -> int:
-            n = 0
-            for ns, name in added:
-                p = self.store.get_pod(ns, name)
-                if p is not None and p.spec.node_name:
-                    n += 1
-            return n
-
-        # settle: the tail of the stream drains CLOSED-loop (arrivals
-        # have stopped; this phase is excluded from the offered-rate
-        # denominator and, via the slope test, from steady-state windows)
-        settle_deadline = time.time() + self.settle_s
-        completed = completed_deleted + bound_now()
-        while completed < offered and time.time() < settle_deadline:
-            time.sleep(0.2)
-            completed = completed_deleted + bound_now()
-        self.sched.wait_for_inflight_binds()
-        completed = completed_deleted + bound_now()
-        out: Dict[str, Any] = {
-            "duration_s": round(self.duration_s, 3),
-            "offered": offered,
-            "offered_rate": round(offered / max(self.duration_s, 1e-9), 2),
-            "completed": completed,
-            "completed_rate": round(
-                completed / max(self.duration_s, 1e-9), 2),
-            "completed_frac": round(completed / max(offered, 1), 4),
-            "deletes": deletes,
-            "behind_max_s": round(behind_max, 3),
-        }
-        tel = _telemetry.ring()
-        if tel is not None:
-            # close the tail window so the last arrivals land in a
-            # recorded window, then read the steady-state verdict
-            tel.force_roll(self.sched)
-            out["load"] = tel.digest()
-        return out
-
-
 def run_workload(w: Workload, verbose: bool = False) -> List[DataItem]:
     """reference: scheduler_perf_test.go:117 perfScheduling."""
     store = ClusterStore()
@@ -414,14 +308,13 @@ def run_workload(w: Workload, verbose: bool = False) -> List[DataItem]:
         items = [
             DataItem(data=_stats(coll.samples), unit="pods/s",
                      labels={"Name": w.name, "Metric": "SchedulingThroughput"}),
-            # measured-phase scheduler internals (same split bench.py
-            # reports for its direct-drive cases): committed cycles, wall
+            # measured-phase scheduler internals: committed cycles, wall
             # time spent blocked on the per-cycle packed readback, and the
-            # host share of the measured phase
+            # share of the measured phase NOT spent there
             DataItem(data={"Cycles": float(sched.cycle_count - cycles0),
                            "DeviceWaitS": round(device_wait, 3),
-                           "HostShare": host_share(device_wait,
-                                                   elapsed),
+                           "HostShare": round(
+                               1.0 - device_wait / max(elapsed, 1e-9), 3),
                            # incremental-tensorization health (state/delta)
                            # over the MEASURED phase only, like Cycles:
                            # rows the scatter path updated per delta cycle

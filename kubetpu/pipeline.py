@@ -1,24 +1,23 @@
 """Depth-k pipelined serving executor: overlap prepare(k+1) / device(k) /
 commit(k-1).
 
-The serving loop's serial pop -> prepare -> dispatch -> readback ->
-commit -> bind chain kept ``host_share`` at 0.5-0.8 across bench cases
-("It's the Critical Path!" is the framing; PR 10's ``stage_shares`` name
-exactly which stage is exposed).  The old ``Scheduler._schedule_pipelined``
+The serving loop's serial pop -> prepare -> dispatch -> readback -> commit
+-> bind chain kept the host's share of the drain at 0.5-0.8 ("It's the
+Critical Path!" is the framing). The old ``Scheduler._schedule_pipelined``
 hid SOME of it with a hand-rolled 2-deep chain around a single
-``_inflight_cycle`` tuple; this module generalizes that chain into a
-bounded ring of dispatched-but-uncommitted ``PreparedCycle``s so that, at
-depth k, the host can be tensorizing cycle k+1 while cycle k executes on
-device and cycle k-1's commit/bind loop drains — the depth is the lever
-that turns measured stage shares into recovered throughput.
+``_inflight_cycle`` tuple; this module generalizes that chain into a bounded
+ring of dispatched-but-uncommitted ``PreparedCycle``s so that, at depth k,
+the host can be tensorizing cycle k+1 while cycle k executes on device and
+cycle k-1's commit/bind loop drains — the depth is the lever that turns
+measured stage shares into recovered throughput.
 
 ``pipelineDepth`` (apis/config.py, env ``KUBETPU_PIPELINE_DEPTH``) is the
 maximum number of cycles in flight at once: depth 1 is the fully
 synchronous drain (ring capacity 0 — every cycle commits before the next
 pops), depth 2 reproduces the old double-buffered chain exactly, depth k
 parks up to k-1 dispatched cycles between ``schedule_pending`` calls.
-Placements are BIT-IDENTICAL across depths (the parity contract the bench
-``pipeline_depth`` case and tests/test_pipeline.py assert): every cycle
+Placements are BIT-IDENTICAL across depths (the parity contract
+tests/test_pipeline.py asserts): every cycle
 dispatches against either the previous cycle's speculative chained
 cluster or the committed cache — never a state that can diverge from the
 synchronous drain's.
@@ -35,9 +34,7 @@ ring of them":
   cycle, not just the single ``_inflight_cycle``: commit loops and
   readbacks of OTHER cycles land inside a younger cycle's
   dispatch->readback window and are folded into its ``host_exempt_s``,
-  so host work at depth can never demote a healthy device.  The SLO
-  layer subtracts the same exemptions from the per-pod ``dispatch``
-  stage so overlapped host work is not double-counted across slots.
+  so host work at depth can never demote a healthy device.
 * CHAIN-BREAK RECOVERY BY SCATTER — when cycle j's readback recovers
   (dispatch error / deadline) or its commit fails, every YOUNGER
   in-flight cycle was dispatched against placements that never
@@ -124,13 +121,6 @@ class InflightRing:
         with self._lock:
             return [p for p, _ in self._slots]
 
-    def results(self) -> List[object]:
-        """Device results of every in-flight slot (the devstats deep
-        fence pre-drains them UNTIMED so a sampled cycle's measurement
-        never includes older cycles' queued-ahead device work)."""
-        with self._lock:
-            return [r for _, r in self._slots]
-
     def park(self, now: float) -> None:
         """Stamp caller think time's start on every in-flight cycle —
         wall clock between ``schedule_pending`` calls is host time and
@@ -174,7 +164,7 @@ class PipelinedExecutor:
         self.depth = max(int(depth), 1)
         self.ring = InflightRing(self.depth - 1)
         # discarded-and-re-prepared cycle count (the scatter-recovery
-        # telemetry tests and bench read; serving thread only)
+        # tests read it; serving thread only)
         self.reruns = 0
 
     # ----------------------------------------------------------- introspection
@@ -183,11 +173,6 @@ class PipelinedExecutor:
         """Every dispatched-but-uncommitted PreparedCycle — the donation
         withholding set ``_prepare_group`` consults."""
         return self.ring.preps()
-
-    def inflight_results(self) -> List[object]:
-        """Every in-flight slot's device result (see
-        InflightRing.results)."""
-        return self.ring.results()
 
     def pop_timeout(self, timeout: Optional[float]) -> Optional[float]:
         """Gate the queue's 20 ms burst-gather window on FREE pipeline

@@ -29,7 +29,6 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 from ..api import types as api
 from ..framework.types import QueuedPodInfo, pod_with_affinity
-from ..utils import slo as uslo
 from ..utils import trace as utrace
 from ..utils.trace import wallclock
 from .heap import Heap
@@ -126,9 +125,9 @@ class SchedulingQueue(PodNominator):
                  pod_initial_backoff: float = DEFAULT_POD_INITIAL_BACKOFF,
                  pod_max_backoff: float = DEFAULT_POD_MAX_BACKOFF,
                  # wallclock, not time.time: every queue stamp is one
-                 # end of an SLO/backoff DURATION (queue_wait, backoff,
-                 # cycle_wait, e2e) whose other end is a scheduler-side
-                 # wallclock stamp — an NTP step must not corrupt them.
+                 # end of a DURATION (backoff, e2e) whose other end is a
+                 # scheduler-side wallclock stamp — an NTP step must not
+                 # corrupt them.
                  # Tests can still inject a fake clock.
                  clock: Callable[[], float] = wallclock,
                  metrics=None):
@@ -243,10 +242,6 @@ class SchedulingQueue(PodNominator):
             qp.attempts += 1
             self.scheduling_cycle += 1
             qp.scheduling_cycle = self.scheduling_cycle
-            if uslo.tracker() is not None:
-                # SLO queue_wait boundary; disarmed this is one module
-                # attribute read — no clock call, no lock
-                qp.pop_timestamp = self._clock()
             return qp
 
     def pop_batch(self, max_batch: int,
@@ -282,15 +277,11 @@ class SchedulingQueue(PodNominator):
                     if armed:
                         self.pop_wait_s += wallclock() - t_w
         with self._cond:
-            # one clock read for the whole drained batch (SLO armed only)
-            pop_t = self._clock() if uslo.tracker() is not None else 0.0
             while len(out) < max_batch and len(self.active_q) > 0:
                 qp = self.active_q.pop()
                 qp.attempts += 1
                 self.scheduling_cycle += 1
                 qp.scheduling_cycle = self.scheduling_cycle
-                if pop_t:
-                    qp.pop_timestamp = pop_t
                 out.append(qp)
         return out
 

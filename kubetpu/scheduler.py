@@ -49,11 +49,8 @@ from .state.cache import SchedulerCache, Snapshot
 from .state.delta import DeltaTensorizer
 from .state.tensors import SnapshotBuilder
 from .utils import chaos as uchaos
-from .utils import devstats as udevstats
 from .utils import heap as uheap
 from .utils import journal as ujournal
-from .utils import slo as uslo
-from .utils import telemetry as utelemetry
 from .utils import trace as utrace
 from .utils.decisions import DecisionLog, PodDecision
 from .utils.trace import Trace
@@ -137,12 +134,6 @@ class PreparedCycle:
     # device hang still counts — it blocks the READBACK, which runs
     # after pickup)
     parked_t: float = 0.0
-    # packed-readback completion time + the readback's device wait — the
-    # SLO layer's commit-stage anchor and per-pod device share (stamped
-    # unconditionally in _readback_group: two float stores, no clock call
-    # beyond the one the wait measurement already makes)
-    readback_done_t: float = 0.0
-    device_wait: float = 0.0
     # cycle-journal capture (utils/journal.py, armed only): the cycle's
     # cluster-input provenance — ("resync"|"delta"|"noop", payload) from
     # the DeltaTensorizer seam or ("chain", pads) for chained cycles —
@@ -152,15 +143,6 @@ class PreparedCycle:
     journal_rng: int = 0
     journal_start: int = 0
     ring_slot: int = 0
-    # devstats deep-timing marker (utils/devstats.py): True when this
-    # cycle's dispatch was micro-fenced — the commit side then pairs
-    # the cycle's analytic FLOP count with the measured device seconds.
-    # The fence's own seconds ride along explicitly: at sampling
-    # intervals below the pipeline depth, newer samples land before
-    # this cycle's commit runs, so "the program's last sample" would be
-    # the wrong one
-    devstats_fenced: bool = False
-    devstats_fence_s: float = 0.0
     # DOUBLE-BUFFERED batch transfer (mesh serving): the sharded device
     # copy of `batch`, upload STARTED at prepare time so the host->device
     # transfer of wave k+1 overlaps wave k's auction on the device
@@ -189,26 +171,11 @@ class Scheduler:
         # KUBETPU_CHAOS: arm the fault-injection registry (utils/chaos.py);
         # disarmed (the default) every injection site is one attribute read
         uchaos.maybe_arm_from_env()
-        # KUBETPU_SLO: arm the per-pod latency SLO tracker (utils/slo.py);
-        # disarmed (the default) every seam is one attribute read and the
-        # hot path takes zero new locks (tests/test_slo.py poison test)
-        uslo.maybe_arm_from_env()
         # KUBETPU_JOURNAL=<dir>: arm the durable cycle journal
         # (utils/journal.py) — every committed cycle appends one
         # self-contained replayable record; disarmed, every seam is one
         # attribute read (tests/test_journal.py poison test)
         ujournal.maybe_arm_from_env()
-        # KUBETPU_DEVSTATS: arm device-side observability
-        # (utils/devstats.py) — sampled per-program device-time fences,
-        # the HBM residency ledger, roofline attribution; disarmed,
-        # every seam is one attribute read and placements are
-        # bit-identical armed vs disarmed (tests/test_devstats.py)
-        udevstats.maybe_arm_from_env()
-        # KUBETPU_TELEMETRY: arm the windowed sustained-load telemetry
-        # ring (utils/telemetry.py) — the serving loop rolls one window
-        # record per KUBETPU_TELEMETRY_WINDOW seconds; disarmed, the
-        # tick seam is one attribute read (tests/test_telemetry.py)
-        utelemetry.maybe_arm_from_env()
         import jax
         self.store = store
         self.config = config or KubeSchedulerConfiguration(
@@ -287,8 +254,6 @@ class Scheduler:
         self.cycle_count = 0
         # auction round count of the most recent gang cycle (diagnostics)
         self.last_gang_rounds = 0
-        # cumulative analytic device FLOPs (utils/flops.py; gang mode only)
-        self.device_flops = 0.0
         self._async_binding = async_binding
         # per-pod decision audit (utils/decisions.py): bounded, on by
         # default, disabled with KUBETPU_AUDIT=0 — disabled, no commit
@@ -314,7 +279,7 @@ class Scheduler:
         # thread only (appended in _prepare_group, removed at dispatch
         # or discard)
         self._undispatched: List[PreparedCycle] = []
-        # delta telemetry for bench/perf: updated-row counts of recent
+        # delta counts for harness/perf.py: updated-row counts of recent
         # delta cycles (bounded ring) + monotonic tallies so windowed
         # readers survive ring eviction (serving thread only)
         from collections import deque
@@ -370,11 +335,6 @@ class Scheduler:
         # only): a failed commit invalidates the speculative chain and
         # every in-flight cycle dispatched against it
         self._last_commit_failed = False
-        # devstats chain-ledger memo (serving thread only): the chain
-        # registration re-runs only when (profile, pads, n_nodes)
-        # change — re-walking identical shapes every chained cycle
-        # would tax the armed serving thread for nothing
-        self._chain_ledger_key = None
         # (pod-axis bucket, compile-or-load seconds) per prewarmed program
         self.prewarm_report: List[Tuple[int, float]] = []
         # binding (reference: scheduler.go:628): the commit loop hands a
@@ -518,17 +478,6 @@ class Scheduler:
         with self._chain_lock:
             self._chain_seq += 1
 
-    def _drop_chain_residency(self) -> None:
-        """Residency-ledger seam (utils/devstats.py): the speculative
-        chain was discarded, so its materialized cluster is no longer
-        device-resident — the capacity planner must stop counting it.
-        Disarmed: one attribute read.  Called OUTSIDE _chain_lock (the
-        devstats lock never nests with it)."""
-        ds = udevstats.devstats()
-        if ds is not None:
-            ds.drop_group("chain")
-            self._chain_ledger_key = None
-
     def _chain_enabled(self, fwk) -> bool:
         # mesh profiles chain too (PR 14): materialize_assigned is a
         # concat/pad/scatter program — the kernel class the partitioner
@@ -584,12 +533,6 @@ class Scheduler:
         """Run ONE batched scheduling cycle: pop up to batch_size pods and
         schedule them.  Returns outcomes (the test/introspection surface).
         The serving loop (run/serve_forever) just calls this repeatedly."""
-        # telemetry tick seam: disarmed this is ONE attribute read (the
-        # house contract); armed, the deadline check is one float
-        # compare and a roll happens once per window, not per cycle
-        tel = utelemetry.ring()
-        if tel is not None:
-            tel.maybe_tick(self)
         # between two cycles nothing is open on this thread (the caller
         # has dropped the last cycle's outcomes): the one place the
         # survivors are handed to the permanent generation
@@ -741,25 +684,6 @@ class Scheduler:
         trace = Trace(utrace.CYCLE_TRACE, profile=fwk.profile_name,
                       pods=len(qpods), queue_depths=depths, pop=pop)
         trace.phase("snapshot")
-        # devstats cycle tick: every Nth cycle is a deep-timing cycle —
-        # its device dispatches (delta scatter below, the auction in
-        # _dispatch_group) are micro-fenced so per-program device time
-        # is measured even under depth-k overlap.  Disarmed: one read
-        ds = udevstats.devstats()
-        if ds is not None and ds.begin_cycle():
-            # pre-drain queued-ahead device work UNTIMED: at depth > 2
-            # older in-flight cycles are still executing, and the device
-            # runs programs in order — without this the fence would
-            # charge their remaining seconds to THIS cycle's programs.
-            # Completion is observed by READBACK (np.asarray) of the
-            # tiny packed vector, the same sync the serving path waits
-            # on (SYNC_PROBE in _readback_group: block_until_ready would
-            # do as well); re-reading it later is safe
-            for res_old in self._pipeline.inflight_results():
-                try:
-                    np.asarray(res_old.packed)
-                except Exception:
-                    pass   # its own readback path recovers the fault
         # capture the event sequence BEFORE snapshotting: a chain is only
         # reusable if no event has landed since the state it embeds
         with self._chain_lock:
@@ -852,7 +776,7 @@ class Scheduler:
             if delta is None:
                 delta = DeltaTensorizer(
                     hard_pod_affinity_weight=fwk.hard_pod_affinity_weight,
-                    mesh=self._mesh, profile=fwk.profile_name)
+                    mesh=self._mesh)
                 self._delta[fwk.profile_name] = delta
             # in-place buffer donation is only safe when NO
             # dispatched-but-uncommitted pipelined cycle still reads the
@@ -936,7 +860,6 @@ class Scheduler:
                     self._journal_force_anchor.discard(fwk.profile_name)
             with self._chain_lock:
                 self._chain = None
-            self._drop_chain_residency()
         spread_sels = [self.store.default_spread_selector(pi.pod)
                        for pi in pinfos]
         pb = PodBatchBuilder(builder.table)
@@ -1215,9 +1138,8 @@ class Scheduler:
         # an injected error models the device dying under the program; an
         # injected stall models a hung device — both recovered by
         # _recover_cycle via the guarded call sites / readback.
-        # wallclock (utils/trace.py): the deadline and the SLO dispatch
-        # stage are durations-by-subtraction — an NTP step must not
-        # corrupt them
+        # wallclock (utils/trace.py): the deadline is a
+        # duration-by-subtraction — an NTP step must not corrupt it
         prep.dispatch_t0 = utrace.wallclock()
         if self._dispatch_deadline > 0:
             # idempotent singleton; first call installs the
@@ -1266,36 +1188,6 @@ class Scheduler:
                     host_ok=host_ok_dev,
                     start_index=start,
                     score_bias=prep.score_bias)
-        # devstats deep-timing micro-fence (utils/devstats.py): on the
-        # sampled cycles, block until the dispatched program completes
-        # and record the wall seconds as MEASURED per-program device
-        # time — the only number that stays honest under depth-k
-        # overlap, where device_wait_s (the readback block) reads near
-        # zero.  The fence serializes work the pipeline would have
-        # hidden, so it runs on 1/N cycles and its cumulative cost is
-        # recorded (fence_wait_s).  Disarmed: one attribute read.
-        ds = udevstats.devstats()
-        if ds is not None and ds.deep_active():
-            program = ("run_auction" if self.config.mode == "gang"
-                       else "schedule_sequential")
-            with utrace.flight_span("device-fence", program=program) as sp:
-                # fence = a readback of the tiny packed vector, the
-                # same completion signal the serving path waits on (see
-                # SYNC_PROBE in _readback_group; ROADMAP C6 decides
-                # whether block_until_ready replaces it).  The readback's
-                # own cost is part of the recorded fence overhead, and
-                # re-reading packed in _readback_group is safe
-                # (transfers are non-destructive)
-                t_f = time.perf_counter()
-                np.asarray(res.packed)
-                dt_f = time.perf_counter() - t_f
-                if sp is not None:
-                    sp.args["device_time_s"] = round(dt_f, 6)
-            prep.devstats_fenced = True
-            prep.devstats_fence_s = dt_f
-            ds.record_program(
-                program, dt_f, source="fence",
-                in_bytes=udevstats.pytree_nbytes((cluster, batch)))
         if ujournal.journal() is not None:
             # journal provenance: the RNG fold counter this dispatch
             # consumed (_next_rng bumped it inside the call above) and
@@ -1361,30 +1253,9 @@ class Scheduler:
                                    # this cluster bit-exactly
                                    pads=(pow2_bucket(p_next),
                                          pow2_bucket(e_next)))
-            # residency-ledger seam (utils/devstats.py): the speculative
-            # chain is a SECOND full cluster resident until the next
-            # cycle consumes it — the capacity planner must count it.
-            # Memoized on (profile, pads, n_nodes): identical shapes
-            # register identical bytes, so the per-table walk runs only
-            # when the pad buckets actually move
-            ds = udevstats.devstats()
-            if ds is not None:
-                lkey = (fwk.profile_name, pow2_bucket(p_next),
-                        pow2_bucket(e_next), n_nodes)
-                # the has_group check backstops a bind-thread discard
-                # racing this registration (the memo alone could read
-                # fresh while the entry was just dropped)
-                if self._chain_ledger_key != lkey \
-                        or not ds.has_group("chain"):
-                    udevstats.register_cluster(
-                        "chain", fwk.profile_name, next_cluster, n_nodes,
-                        meta={"pads": [pow2_bucket(p_next),
-                                       pow2_bucket(e_next)]})
-                    self._chain_ledger_key = lkey
         elif self.config.mode == "gang":
             with self._chain_lock:
                 self._chain = None
-            self._drop_chain_residency()
         return res
 
     # ----------------------------------------------------------- recovery
@@ -1432,7 +1303,6 @@ class Scheduler:
         with self._chain_lock:
             self._chain = None
             self._chain_seq += 1
-        self._drop_chain_residency()
         self._delta.pop(prep.fwk.profile_name, None)
         for qp in prep.live:
             try:
@@ -1521,10 +1391,8 @@ class Scheduler:
             # finish() inside the phase: handing the record over is the
             # commit's tail, and the phase closes as the record lands
             if self.config.mode == "gang":
-                # per-cycle auction rounds as cycle meta: bench aggregates
-                # the histogram across cycles and traceview shows a digest
-                # column, so the round-count reduction ROADMAP item 3
-                # claims is directly observable per run, not just as a max
+                # per-cycle auction rounds as cycle meta: the benchmark's
+                # readers and traceview's digest column take it from here
                 prep.trace.finish(auction_rounds=self.last_gang_rounds)
             else:
                 prep.trace.finish()
@@ -1548,10 +1416,7 @@ class Scheduler:
         with prep.trace.phase("packed-readback", ann="readback") as sp:
             t_dev = utrace.wallclock()
             packed = np.asarray(res.packed)
-            t_done = utrace.wallclock()
-            wait = t_done - t_dev
-            prep.readback_done_t = t_done
-            prep.device_wait = wait
+            wait = utrace.wallclock() - t_dev
             if sp is not None:
                 # per-span device-wait attribution: the readback is the
                 # cycle's only observable device sync
@@ -1591,7 +1456,7 @@ class Scheduler:
         per-pod loop's split lands on that phase's span as SUMS --
         recheck_s, reserve_s, assume_s, permit_s, submit_s (the stamp and
         the append a pod, plus the hand-over), records_s (decision audit,
-        SLO prefix, the cycle context's note), pods, loop_s, loop_cpu_s --
+        the cycle context's note), pods, loop_s, loop_cpu_s --
         not as a span a pod; _hand_over adds bind_jobs, binds_pooled and
         handover_wait_s.  loop_s is the loop's last stamp less its first,
         so the six sums add up to it."""
@@ -1605,22 +1470,8 @@ class Scheduler:
         if self.config.mode != "gang":
             self._next_start_node_index = int(packed[3 * B])
         else:
-            # auction round count (diagnostics; bench reports it)
+            # auction round count (diagnostics; the cycle meta reads it)
             self.last_gang_rounds = int(packed[3 * B])
-            from .utils.flops import gang_cycle_flops
-            cyc_flops = gang_cycle_flops(
-                prep.cluster, prep.batch, prep.cfg, self.last_gang_rounds,
-                intra_batch_topology=prep.needs_topo)
-            self.device_flops += cyc_flops
-            if prep.devstats_fenced:
-                # pair the cycle's analytic FLOP count with ITS OWN
-                # fence's measured seconds (the round count — and so the
-                # FLOPs — is only known after the readback, and newer
-                # fence samples may have landed since)
-                ds = udevstats.devstats()
-                if ds is not None:
-                    ds.attribute_flops("run_auction", cyc_flops,
-                                       seconds=prep.devstats_fence_s)
         # one .tolist() per field: the commit loop below reads every entry,
         # and plain Python ints beat a numpy scalar box per access at 4k
         # pods/cycle (kubelint host-sync audit)
@@ -1637,28 +1488,12 @@ class Scheduler:
         commit_failed = False
         audit = self.decisions.enabled
         flight = trace.rec
-        # per-pod latency SLO (utils/slo.py): one tracker read per cycle;
-        # disarmed, no stage vectors are built and no clock is read — the
-        # zero-new-locks hot-path contract (tests/test_slo.py)
-        slo_trk = uslo.tracker()
         # durable cycle journal (utils/journal.py): reserve this cycle's
-        # record id UP FRONT so the SLO exemplars of its pods can carry
-        # it (the record itself appends after the commit loop, once the
-        # outputs and audit summary exist).  Disarmed: one attribute read
+        # record id up front (the record itself appends after the commit
+        # loop, once the outputs and audit summary exist).  Disarmed: one
+        # attribute read
         jr = ujournal.journal()
         jr_seq = jr.next_seq() if jr is not None else 0
-        slo_host_dispatch = 0.0
-        if slo_trk is not None and prep.dispatch_t0:
-            # host share of the dispatch->readback window (program
-            # enqueue); the device share is prep.device_wait.  The
-            # window's HOST-EXEMPT share — other ring slots' commit
-            # loops and readbacks, pipelined parking — is subtracted so
-            # depth-k overlap doesn't double-count the same wall-clock
-            # seconds into every in-flight cycle's pods (per-slot stage
-            # attribution, utils/slo.py)
-            slo_host_dispatch = max(prep.readback_done_t - prep.dispatch_t0
-                                    - prep.device_wait
-                                    - prep.host_exempt_s, 0.0)
         # the split of the loop below, armed only: seconds summed per
         # step over the cycle's pods, each stamp closing one step and
         # opening the next (so the sums cover the loop), five stamps a
@@ -1682,15 +1517,10 @@ class Scheduler:
                                  not unres[i]))
                 continue
             node_name = node_infos[chosen[i]].node_name
-            slo = (self._slo_prefix(qp, prep, slo_host_dispatch, flight,
-                                    jr_seq)
-                   if slo_trk is not None and qp.pop_timestamp else None)
-            if acc is not None and slo is not None:
-                _lap(acc, 5)
             outcome = self._commit(fwk, qp, state, node_name,
                                    n_feas[i], pinfo=pinfos[i],
                                    host_relevant=prep.host_relevant[qp.pod.uid],
-                                   flight=flight, slo=slo, row=i, acc=acc,
+                                   flight=flight, row=i, acc=acc,
                                    job=job)
             if outcome.node:
                 # preemption for pods failing later in this batch must see
@@ -1781,20 +1611,6 @@ class Scheduler:
                     nominated_node=qp.pod.status.nominated_node_name or "",
                     host_reasons=prep.host_reject.get(qp.pod.uid),
                     **info)
-            if (slo_trk is not None and not mh and qp.pop_timestamp
-                    and not qp.slo_unres_observed):
-                # terminally unresolvable this cycle (no plugin verdict
-                # can change and preemption cannot help): record the
-                # vector now — there is no bind stage to wait for.
-                # Once per pod: the requeue path retries it every
-                # cluster event, and re-recording each failing cycle
-                # would multi-count the pod in the sketches
-                qp.slo_unres_observed = True
-                self._slo_observe_terminal(
-                    slo_trk,
-                    self._slo_prefix(qp, prep, slo_host_dispatch, flight,
-                                     jr_seq),
-                    qp, "unresolvable")
         # a commit-path failure invalidates the speculative chain (and any
         # later cycle already dispatched against it — the pipelined drain
         # reads _last_commit_failed and re-runs that cycle)
@@ -1802,7 +1618,6 @@ class Scheduler:
         if commit_failed and self.config.mode == "gang":
             with self._chain_lock:
                 self._chain = None
-            self._drop_chain_residency()
         if jr is not None:
             # one self-contained replayable record per committed cycle;
             # ANY failure (unpicklable capture, disk, injected chaos)
@@ -1819,54 +1634,6 @@ class Scheduler:
         trace.step("Committing placements done")
         trace.log_if_long()
         return outcomes
-
-    @staticmethod
-    def _slo_prefix(qp: QueuedPodInfo, prep: PreparedCycle,
-                    host_dispatch: float, flight,
-                    journal_seq: int = 0) -> Dict[str, float]:
-        """The cycle-side half of a pod's per-stage latency vector
-        (utils/slo.py): queue_wait/backoff/cycle_wait/dispatch/device,
-        plus underscore-prefixed meta keys the terminal observer pops
-        before recording (the readback anchor for the commit stage, the
-        flight-recorder cycle seq the exemplar links to, and the cycle's
-        journal record id when KUBETPU_JOURNAL is armed).  Called only
-        with the tracker armed and a stamped pop time."""
-        return {
-            "queue_wait": max(qp.pop_timestamp - qp.timestamp, 0.0),
-            "backoff": max(qp.timestamp - qp.initial_attempt_timestamp,
-                           0.0),
-            "cycle_wait": max((prep.dispatch_t0 or qp.pop_timestamp)
-                              - qp.pop_timestamp, 0.0),
-            "dispatch": host_dispatch,
-            "device": prep.device_wait,
-            "_readback_done_t": prep.readback_done_t,
-            "_flight_seq": float(flight.seq) if flight is not None else 0.0,
-            "_journal_seq": float(journal_seq),
-        }
-
-    def _slo_observe_terminal(self, trk, prefix: Dict[str, float],
-                              qp: QueuedPodInfo, outcome: str,
-                              bind_start: Optional[float] = None) -> None:
-        """Complete a pod's cycle-side stage vector (_slo_prefix) with
-        the terminal stages — commit (readback -> bind start, or ->
-        now for failures), bind (when one ran), e2e — and record it.
-        The ONLY consumer of the prefix's underscore meta keys."""
-        now = utrace.wallclock()
-        stages = dict(prefix)
-        seq = stages.pop("_flight_seq", 0)
-        jseq = stages.pop("_journal_seq", 0)
-        rb = stages.pop("_readback_done_t", 0.0)
-        end = bind_start if bind_start is not None else now
-        stages["commit"] = max(end - rb, 0.0)
-        if bind_start is not None:
-            stages["bind"] = max(now - bind_start, 0.0)
-        stages["e2e"] = now - qp.initial_attempt_timestamp
-        pod = qp.pod
-        trk.observe_pod(stages, pod=pod.metadata.name,
-                        namespace=pod.namespace, uid=pod.uid,
-                        outcome=outcome, attempts=qp.attempts,
-                        cycle=self.cycle_count, flight_seq=int(seq),
-                        journal_seq=int(jseq))
 
     def _journal_note_discard(self, prep: PreparedCycle) -> None:
         """A prepared cycle is being discarded without committing (the
@@ -2254,7 +2021,7 @@ class Scheduler:
                 node_name: str, n_feasible: int,
                 binder_override=None, pinfo: Optional[PodInfo] = None,
                 host_relevant: Optional[bool] = None,
-                flight=None, slo=None, row: int = -1,
+                flight=None, row: int = -1,
                 acc: Optional[List[float]] = None,
                 job: Optional[BindJob] = None) -> ScheduleOutcome:
         """Serving thread only: Reserve, assume and Permit for one pod,
@@ -2334,7 +2101,7 @@ class Scheduler:
         err = None
         if not self._async_binding:
             err = self._bind_cycle(fwk, qp, state, assumed, node_name,
-                                   binder_override, flight, slo, row)
+                                   binder_override, flight, row)
         else:
             own = job is None
             if own:
@@ -2342,12 +2109,12 @@ class Scheduler:
             if (job.lane_ok and binder_override is None
                     and st.code != Code.WAIT):
                 job.entries.append((fwk, qp, state, assumed, node_name,
-                                    slo, row))
+                                    row))
             else:
                 # this bind would block the lane (it waits on Permit or
                 # on a network): it takes a pool thread of its own
                 args = (fwk, qp, state, assumed, node_name,
-                        binder_override, flight, slo, row)
+                        binder_override, flight, row)
                 try:
                     job.pooled.append(
                         self._bind_pool.submit(self._bind_cycle, *args))
@@ -2434,9 +2201,9 @@ class Scheduler:
                         batched += len(run)
                         self._bind_batch(fwk, binder, hooks_s, run, fold)
                         continue
-                    _, qp, state, assumed, node_name, slo, row = run[0]
+                    _, qp, state, assumed, node_name, row = run[0]
                     self._bind_cycle(fwk, qp, state, assumed, node_name,
-                                     None, job.flight, slo, row, fold)
+                                     None, job.flight, row, fold)
                 except Exception as e:
                     import logging
                     logging.getLogger("kubetpu").exception(
@@ -2492,7 +2259,7 @@ class Scheduler:
         time, after the rest: the retry ladder's gate, then the pool for
         its sleeps, or the failure path."""
         flight = fold.job.flight
-        done = [e[6] for e in rows]
+        done = [e[5] for e in rows]
         if flight is not None:
             flight.stamp_binds(done, utrace.BIND_STARTED)
         rejected: List[tuple] = []
@@ -2506,7 +2273,7 @@ class Scheduler:
                 bound = [e for e, st in zip(rows, sts) if st.is_success()]
                 rejected = [(e, st) for e, st in zip(rows, sts)
                             if not st.is_success()]
-                done = [e[6] for e in bound]    # theirs: _bind_cycle's
+                done = [e[5] for e in bound]    # theirs: _bind_cycle's
             fold.finished.extend(e[3] for e in bound)
             if self.metrics:
                 now = utrace.wallclock()
@@ -2514,26 +2281,20 @@ class Scheduler:
                 fold.scheduled.extend(
                     (qp.attempts, now - qp.initial_attempt_timestamp,
                      now - qp.timestamp) for _, qp, *_ in bound)
-            trk = uslo.tracker()
-            if trk is not None:
-                for _, qp, _, _, _, slo, _ in bound:
-                    if slo is not None:
-                        self._slo_observe_terminal(trk, slo, qp, "bound",
-                                                   bind_start=bind_start)
             if self.recorder:
                 self.recorder.events([
                     (qp.pod, "Normal", "Scheduled",
                      f"Successfully assigned {qp.pod.namespace}/"
                      f"{qp.pod.metadata.name} to {node_name}")
-                    for _, qp, _, _, node_name, _, _ in bound])
+                    for _, qp, _, _, node_name, _ in bound])
         finally:
             if flight is not None:
                 flight.stamp_binds(done, utrace.BIND_DONE)
         raised = None
-        for (_, qp, state, assumed, node_name, slo, row), st in rejected:
+        for (_, qp, state, assumed, node_name, row), st in rejected:
             try:
                 self._bind_cycle(fwk, qp, state, assumed, node_name, None,
-                                 flight, slo, row, fold,
+                                 flight, row, fold,
                                  owed=_LadderOwed(st, bind_start))
             except Exception as e:      # the other rejected rows still go on
                 raised = raised or e
@@ -2553,8 +2314,7 @@ class Scheduler:
 
     def _bind_cycle(self, fwk: Framework, qp: QueuedPodInfo, state: CycleState,
                     assumed: api.Pod, node_name: str,
-                    binder_override=None, flight=None,
-                    slo=None, row: int = -1,
+                    binder_override=None, flight=None, row: int = -1,
                     fold: Optional[BindFold] = None,
                     owed: Optional["_LadderOwed"] = None) -> Optional[str]:
         """reference: scheduler.go:628-687.  Called from three threads:
@@ -2566,12 +2326,9 @@ class Scheduler:
         ``async_binding=False`` or when close() has raced the cycle.
         flight, row: the cycle's CycleRecord and this pod's row of its
         bind table — the bind's start and end are stamped there from
-        whichever thread runs it, lock-free (None when disarmed).  slo:
-        the pod's cycle-side stage vector (_slo_prefix) — the bind
-        completes it with commit/bind/e2e and records the terminal pod
-        (None when the tracker is disarmed).  The lane never sleeps: a
-        bind whose retry ladder owes a sleep leaves it here for the
-        pool (_bind_resume), which also stamps its end."""
+        whichever thread runs it, lock-free (None when disarmed).  The
+        lane never sleeps: a bind whose retry ladder owes a sleep leaves
+        it here for the pool (_bind_resume), which also stamps its end."""
         if flight is not None:
             flight.stamp_bind(row, utrace.BIND_STARTED)
         on_lane = fold is not None
@@ -2580,13 +2337,11 @@ class Scheduler:
         moved = False
         try:
             out = self._bind_cycle_inner(fwk, qp, state, assumed, node_name,
-                                         binder_override, slo, fold,
-                                         owed=owed)
+                                         binder_override, fold, owed=owed)
             if not isinstance(out, _LadderOwed):
                 return out
             moved = True
-            args = (fwk, qp, state, assumed, node_name, flight, slo, row,
-                    out)
+            args = (fwk, qp, state, assumed, node_name, flight, row, out)
             try:
                 fold.job.pooled.append(
                     self._bind_pool.submit(self._bind_resume, *args))
@@ -2608,15 +2363,14 @@ class Scheduler:
 
     def _bind_resume(self, fwk: Framework, qp: QueuedPodInfo,
                      state: CycleState, assumed: api.Pod, node_name: str,
-                     flight, slo, row: int,
+                     flight, row: int,
                      owed: "_LadderOwed") -> Optional[str]:
         """A pool thread finishes a binding cycle the lane began: the
         retry ladder with its sleeps, then success or failure as ever."""
         fold = BindFold()
         try:
             return self._bind_cycle_inner(fwk, qp, state, assumed,
-                                          node_name, None, slo, fold,
-                                          owed=owed)
+                                          node_name, None, fold, owed=owed)
         finally:
             self._settle_bind_fold(fold)
             if flight is not None:
@@ -2636,7 +2390,7 @@ class Scheduler:
 
     def _bind_cycle_inner(self, fwk: Framework, qp: QueuedPodInfo,
                           state: CycleState, assumed: api.Pod,
-                          node_name: str, binder_override, slo,
+                          node_name: str, binder_override,
                           fold: BindFold,
                           owed: Optional["_LadderOwed"] = None):
         """One pod's binding cycle, the same on every thread.  What it
@@ -2692,11 +2446,6 @@ class Scheduler:
             fold.scheduled.append((qp.attempts,
                                    now - qp.initial_attempt_timestamp,
                                    now - qp.timestamp))
-        if slo is not None:
-            trk = uslo.tracker()
-            if trk is not None:
-                self._slo_observe_terminal(trk, slo, qp, "bound",
-                                           bind_start=bind_start)
         if self.recorder:
             self.recorder.event(pod, "Normal", "Scheduled",
                                 f"Successfully assigned "
@@ -2766,7 +2515,6 @@ class Scheduler:
         with self._chain_lock:
             self._chain = None
             self._chain_seq += 1
-        self._drop_chain_residency()
         try:
             self.cache.forget_pod(assumed)
         except ValueError:
@@ -2848,32 +2596,15 @@ class Scheduler:
         bumps scheduler_framework_rejections_total{plugin} for each pod's
         blocking plugin(s).  Any failure degrades to no attribution — the
         audit must never fail a cycle."""
-        ds = udevstats.devstats()
-        t_ev = 0.0
         try:
-            # devstats timer starts AFTER the jitted call returns (the
-            # dispatch is async but trace/compile happen synchronously
-            # inside it — a first-call compile must not pollute the
-            # measured device time, same discipline as the fence)
-            out_dev = programs.explain_verdicts(
-                prep.cluster, prep.batch, prep.cfg, prep.host_ok_dev)
-            t_ev = time.perf_counter() if ds is not None else 0.0
-            packed = np.asarray(out_dev)
+            packed = np.asarray(programs.explain_verdicts(
+                prep.cluster, prep.batch, prep.cfg, prep.host_ok_dev))
         except Exception:
             import logging
             logging.getLogger("kubetpu").warning(
                 "decision audit failed; failures recorded unattributed",
                 exc_info=True)
             return {}
-        if ds is not None and t_ev:
-            # the audit's packed readback is already a natural device
-            # sync, so the per-program measurement is free — recorded
-            # on every armed failure cycle, no fence needed
-            ds.record_program(
-                "explain_verdicts", time.perf_counter() - t_ev,
-                source="sync",
-                in_bytes=udevstats.pytree_nbytes((prep.cluster,
-                                                  prep.batch)))
         filters = prep.cfg.filters
         F = len(filters)
         counts = packed[:F].tolist()
@@ -3182,14 +2913,6 @@ class Scheduler:
             self.prewarm_report.append(
                 (int(cluster.pod_valid.shape[0]),
                  round(time.time() - t0, 2)))
-            # residency-ledger seam (utils/devstats.py): the ladder's
-            # dry-run clusters are live HBM until GC — register the
-            # deepest rung so restart-time residency is accountable
-            if udevstats.devstats() is not None:
-                udevstats.register_cluster(
-                    "prewarm-ladder", fwk.profile_name, cluster,
-                    int(cluster.allocatable.shape[0]),
-                    meta={"bucket": int(cluster.pod_valid.shape[0])})
 
     def _rung_fits(self, cluster, bucket: int) -> bool:
         """Can the device hold the ladder's next rung beside what is

@@ -10,22 +10,11 @@ ring (``?format=chrome`` returns Perfetto-loadable Chrome trace-event
 JSON), ``/debug/explain?pod=<name>[&namespace=<ns>]`` answers the per-pod
 "why (un)scheduled" audit from the scheduler's DecisionLog (no pod
 parameter lists the most recent decisions; ``?outcome=unschedulable``
-filters), and ``/debug/slo`` serves the per-pod latency SLO document
-(utils/slo.py: per-stage p50/p90/p99/p999 + worst-pod exemplars linking
-to the flight-recorder cycle and decision-audit entry; 404 while the
-tracker is disarmed, ``?stage=`` filters, bad parameters are 400).
-``/debug/journal`` reports the durable cycle journal's status
-(utils/journal.py: records, bytes, drops, window span, linkage
+filters), and ``/debug/journal`` reports the durable cycle journal's
+status (utils/journal.py: records, bytes, drops, window span, linkage
 hit-rates into the live flight/decision rings; ``armed: false`` when
-KUBETPU_JOURNAL is unset).  ``/debug/devicez`` serves device-side
-observability (utils/devstats.py: measured per-program device time with
-the roofline join, the HBM residency ledger, fence-overhead accounting;
-404 while KUBETPU_DEVSTATS is disarmed, ``?program=`` filters, unknown
-programs are 400).  ``/debug/loadz`` serves the sustained-load telemetry
-ring (utils/telemetry.py: per-window stage quantiles, queue depths,
-recovery/demotion events, journal/flight drops, device deltas, plus the
-steady-state digest; 404 while KUBETPU_TELEMETRY is disarmed, ``?n=``
-limits to the newest n windows, bad parameters are 400).
+KUBETPU_JOURNAL is unset).  Per-pod latency is upstream's histograms on
+``/metrics``.
 """
 
 from __future__ import annotations
@@ -37,10 +26,7 @@ from dataclasses import asdict, is_dataclass
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
-from .utils import devstats as udevstats
 from .utils import journal as ujournal
-from .utils import slo as uslo
-from .utils import telemetry as utelemetry
 from .utils import trace as utrace
 
 
@@ -83,14 +69,7 @@ class SchedulerServer:
                 if fmt in ("chrome", "perfetto"):
                     self._send_json(200, fr.to_chrome_trace())
                 else:
-                    doc = fr.to_dict()
-                    # a saved flightz dump feeds traceview's "SLO:"
-                    # digest too when the latency tracker is armed
-                    trk = uslo.tracker()
-                    if trk is not None:
-                        doc["slo"] = {"stages": trk.stage_quantiles(),
-                                      "shares": trk.shares()}
-                    self._send_json(200, doc)
+                    self._send_json(200, fr.to_dict())
 
             def _explain(self, query) -> None:
                 log = getattr(sched, "decisions", None)
@@ -121,79 +100,6 @@ class SchedulerServer:
                                 "was evicted"})
                     return
                 self._send_json(200, decision.to_dict())
-
-            def _slo(self, query) -> None:
-                trk = uslo.tracker()
-                if trk is None:
-                    self._send_json(404, {
-                        "armed": False,
-                        "error": "the SLO tracker is disarmed",
-                        "hint": "arm with KUBETPU_SLO=1 or "
-                                "kubetpu.utils.slo.arm_slo_tracker()"})
-                    return
-                doc = trk.to_dict()
-                stage = (query.get("stage") or [None])[0]
-                if stage is not None:
-                    if stage not in doc["stages"]:
-                        self._send_json(400, {
-                            "error": f"unknown stage {stage!r}",
-                            "stages": sorted(doc["stages"])})
-                        return
-                    doc["stages"] = {stage: doc["stages"][stage]}
-                raw_n = (query.get("n") or [None])[0]
-                if raw_n is not None:
-                    try:
-                        n = int(raw_n)
-                        if n < 0:
-                            raise ValueError
-                    except ValueError:
-                        self._send_json(400, {
-                            "error": "n must be a non-negative integer"})
-                        return
-                    doc["exemplars"] = doc["exemplars"][:n]
-                self._send_json(200, doc)
-
-            def _devicez(self, query) -> None:
-                ds = udevstats.devstats()
-                if ds is None:
-                    self._send_json(404, {
-                        "armed": False,
-                        "error": "device-side observability is disarmed",
-                        "hint": "arm with KUBETPU_DEVSTATS=1 or "
-                                "kubetpu.utils.devstats.arm_devstats()"})
-                    return
-                doc = ds.to_dict()
-                program = (query.get("program") or [None])[0]
-                if program is not None:
-                    if program not in doc["programs"]:
-                        self._send_json(400, {
-                            "error": f"unknown program {program!r}",
-                            "programs": sorted(doc["programs"])})
-                        return
-                    doc["programs"] = {program: doc["programs"][program]}
-                self._send_json(200, doc)
-
-            def _loadz(self, query) -> None:
-                tel = utelemetry.ring()
-                if tel is None:
-                    self._send_json(404, {
-                        "armed": False,
-                        "error": "the telemetry ring is disarmed",
-                        "hint": "arm with KUBETPU_TELEMETRY=1 or "
-                                "kubetpu.utils.telemetry.arm_telemetry()"})
-                    return
-                raw_n = (query.get("n") or [None])[0]
-                last = None
-                if raw_n is not None:
-                    try:
-                        last = int(raw_n)
-                        if last < 0:
-                            raise ValueError
-                    except ValueError:
-                        self._send_json(400, {
-                            "error": "n must be a non-negative integer"})
-                        return
-                self._send_json(200, tel.to_dict(last=last))
 
             def _journal(self, query) -> None:
                 jr = ujournal.journal()
@@ -238,14 +144,8 @@ class SchedulerServer:
                     self._flightz(query)
                 elif path == "/debug/explain":
                     self._explain(query)
-                elif path == "/debug/slo":
-                    self._slo(query)
                 elif path == "/debug/journal":
                     self._journal(query)
-                elif path == "/debug/devicez":
-                    self._devicez(query)
-                elif path == "/debug/loadz":
-                    self._loadz(query)
                 else:
                     self._send(404, "not found")
 

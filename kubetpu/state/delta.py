@@ -80,13 +80,11 @@ from __future__ import annotations
 
 import os
 import pickle
-import time
 from types import MappingProxyType
 from typing import Dict, List, Mapping, NamedTuple, Optional, Tuple
 
 import numpy as np
 
-from ..utils import devstats as udevstats
 from ..utils import journal as ujournal
 from ..utils.intern import pow2_bucket
 from ..utils.trace import wallclock
@@ -155,7 +153,7 @@ def _wrapsum_dev(x):
 
 
 class DeltaStats(NamedTuple):
-    """One refresh()'s outcome — the flight-recorder/bench feed."""
+    """One refresh()'s outcome — the flight recorder's feed."""
     delta_rows: int                 # dirty node rows + the pod rows that
                                     # were refilled or cleared
     resync: bool
@@ -207,7 +205,6 @@ class DeltaTensorizer:
     """
 
     def __init__(self, hard_pod_affinity_weight: int = 1, mesh=None,
-                 profile: str = "",
                  resync_interval: Optional[int] = None,
                  max_delta_frac: Optional[float] = None,
                  verify_interval: Optional[int] = None):
@@ -215,7 +212,6 @@ class DeltaTensorizer:
             hard_pod_affinity_weight=hard_pod_affinity_weight)
         self.hard_pod_affinity_weight = hard_pod_affinity_weight
         self.mesh = mesh
-        self.profile = profile
         self.resync_interval = (resync_interval if resync_interval is not None
                                 else int(os.environ.get(
                                     RESYNC_INTERVAL_ENV,
@@ -662,10 +658,6 @@ class DeltaTensorizer:
         self.cluster = self._apply(delta, donate=donate,
                                    replace_terms=terms_dirty,
                                    spans=upload_span)
-        if terms_dirty:
-            # wholesale term replacement can change the term-table
-            # shapes — the only delta-path event that moves residency
-            self._register_residency()
         self.cycles_since_resync += 1
         spans = ((("delta-build", t0, t_build),) + term_span
                  + tuple(upload_span)
@@ -764,19 +756,6 @@ class DeltaTensorizer:
             from ..parallel import mesh as pmesh
             cluster = pmesh.shard_cluster(cluster, self.mesh)
         self.cluster = cluster
-        self._register_residency()
-
-    def _register_residency(self) -> None:
-        """Residency-ledger seam (utils/devstats.py): register the
-        resident cluster's per-table bytes under this profile — the
-        shape walk happens only when residency can have CHANGED (resync,
-        pod-axis growth, wholesale term replacement; scatters keep
-        shapes).  Disarmed: one attribute read."""
-        if udevstats.devstats() is None or self.cluster is None:
-            return
-        udevstats.register_cluster(
-            "delta-resident", self.profile or "default", self.cluster,
-            len(self.node_names), meta={"resyncs": self.resync_count})
 
     def _owner(self, uid: str, row: int, pi) -> Optional[TermOwner]:
         """What the pod gives ``_build_terms``, or None for a pod that
@@ -917,20 +896,4 @@ class DeltaTensorizer:
                                                donate=donate)
         if act == "corrupt":
             new = new._replace(requested=new.requested.at[0, 0].add(1.0))
-        ds = udevstats.devstats()
-        if ds is not None and ds.deep_active():
-            # deep-timing micro-fence (utils/devstats.py): on the
-            # sampled cycles, measure the scatter's actual device time —
-            # normally it completes invisibly behind the auction's
-            # dispatch.  Completion is observed by reading back ONE
-            # small output ([N] node_valid — a single executable's
-            # outputs complete together), the same kind of sync the
-            # serving path's packed readback is.  Waiting changes no value
-            # (armed-vs-disarmed parity golden); the overhead is
-            # counted in fence_wait_s
-            t_f = time.perf_counter()
-            np.asarray(new.node_valid)
-            ds.record_program("apply_cluster_delta",
-                              time.perf_counter() - t_f, source="fence",
-                              in_bytes=udevstats.pytree_nbytes(delta))
         return new

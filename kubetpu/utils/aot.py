@@ -245,21 +245,6 @@ def pod_bucket_of(args: tuple) -> Optional[int]:
         return None
 
 
-def _note_resident_executable(row: dict) -> None:
-    """Residency-ledger seam (utils/devstats.py): a deserialized AOT
-    executable is a live device-program allocation — register its
-    serialized size (the closest committed proxy for the loaded program
-    binary) so the capacity planner counts the resident executable set.
-    Disarmed: one attribute read."""
-    from . import devstats as _devstats
-    ds = _devstats.devstats()
-    if ds is None:
-        return
-    ds.record_bytes("aot-executables", "",
-                    str(row.get("row") or row.get("artifact") or "?"),
-                    int(row.get("bytes") or 0))
-
-
 # ------------------------------------------------------------------ store
 
 
@@ -540,7 +525,6 @@ class AotRuntime:
                 with self._lock:
                     self._execs[key] = fn
                     self.loads += 1
-                _note_resident_executable(row)
             else:
                 with self._lock:
                     self._missing.add(key)
@@ -583,7 +567,6 @@ class AotRuntime:
         with self._lock:
             self._execs[key] = fn
             self.loads += 1
-        _note_resident_executable(row)
         return fn
 
     # ---- capture (build) side ------------------------------------------

@@ -64,8 +64,8 @@ def _cache_option(option: str, value, restore) -> Iterator[None]:
 def cache_subdir(name: str) -> Iterator[str]:
     """Point the persistent cache at ``<active cache root>/<name>``,
     emptied first, for the duration — a PRIVATE cold cache at a fixed,
-    placeable path (bench.py warm_restart measures cold vs cache-warm
-    restarts against it)."""
+    placeable path (a cold-vs-cache-warm restart is measured against
+    it)."""
     root = enable_persistent_cache()
     sub = os.path.join(root, name)
     shutil.rmtree(sub, ignore_errors=True)

@@ -1,11 +1,11 @@
 """Durable cycle journal: every committed scheduling cycle, on disk.
 
-The flight recorder (utils/trace.py) and SLO sketches (utils/slo.py) are
-in-memory rings that die with the process — a production incident or an
+The flight recorder (utils/trace.py) and the decision log are in-memory
+rings that die with the process — a production incident or an
 interesting placement decision cannot be re-examined after the fact, let
 alone re-EXECUTED.  This module is the persistence substrate under both:
-when armed (``KUBETPU_JOURNAL=<dir>``, mirroring the KUBETPU_FLIGHT /
-KUBETPU_SLO arming discipline), every committed cycle appends ONE
+when armed (``KUBETPU_JOURNAL=<dir>``, mirroring the KUBETPU_FLIGHT
+arming discipline), every committed cycle appends ONE
 self-contained record to a bounded, size-capped on-disk journal —
 
   INPUTS   the cycle's exact device-program inputs: the applied
@@ -47,7 +47,7 @@ delta/chain records behind it, which kubereplay skips with reason
 ``broken-lineage`` until the next anchor.
 
 Arming contract (the poison test in tests/test_journal.py enforces it
-exactly like trace's and slo's): DISARMED (the default) every seam is
+exactly like trace's): DISARMED (the default) every seam is
 one module-attribute read — the serving hot path takes ZERO new locks
 and allocates no journal state; armed-vs-disarmed placements are
 bit-identical (the journal only observes).  Importing this module never
@@ -194,9 +194,8 @@ class CycleJournal:
     # -- write side (serving thread) ---------------------------------------
 
     def next_seq(self) -> int:
-        """Reserve the next record id.  Called at commit start so the SLO
-        exemplars of the cycle's pods can carry the id the record will be
-        appended under."""
+        """Reserve the next record id.  Called at commit start: the id
+        the cycle's record will be appended under."""
         with self._lock:
             self._seq += 1
             return self._seq
@@ -372,8 +371,7 @@ def config_digest(mode: str, profile: str, cfg,
 
 # ---------------------------------------------------------------- arming
 #
-# Same contract as trace.py's recorder, slo.py's tracker and chaos.py's
-# registry: _journal is read WITHOUT a lock on the hot path (rebinding a
+# Same contract as trace.py's recorder and chaos.py's registry: _journal is read WITHOUT a lock on the hot path (rebinding a
 # reference is atomic; a racing reader sees old or new), arm/disarm
 # serialize through _journal_lock.
 

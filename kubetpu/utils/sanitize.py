@@ -400,9 +400,9 @@ def sanitized():
 
 def install_compile_watchdog() -> CompileWatchdog:
     """Attach ONLY the compile-count watchdog (no debug_nans, no
-    rank-promotion, no warnings hook): the observer bench.py's
-    BENCH_GATE=1 census cross-check needs — compile events must be
-    recorded without perturbing the measured numerics.  If the full
+    rank-promotion, no warnings hook): the observer the benchmark
+    (perfbench/lib/drive.py) and chip_smoke.py need — compile events
+    must be recorded without perturbing the measured numerics.  If the full
     sanitizer is already armed, its watchdog is shared.  Pair with
     uninstall_compile_watchdog()."""
     with _state_lock:
@@ -436,10 +436,8 @@ def uninstall_compile_watchdog(wd: CompileWatchdog) -> None:
 #   /jax/compilation_cache/cache_retrieval_time_sec   fires on hits only
 #   /jax/compilation_cache/cache_hits | cache_misses  the counts
 #
-# so true compile seconds = backend total - retrieval total.  bench.py's
-# compile_estimate used first-minus-best wall clock, which goes NEGATIVE
-# on cache-warm runs; the timer reports compile_s and cache_load_s
-# separately and exactly.
+# so true compile seconds = backend total - retrieval total: the timer
+# reports compile_s and cache_load_s separately and exactly.
 
 _COMPILE_DURATION_EV = "/jax/core/compile/backend_compile_duration"
 _CACHE_RETRIEVAL_EV = "/jax/compilation_cache/cache_retrieval_time_sec"
@@ -450,7 +448,7 @@ _CACHE_MISS_EV = "/jax/compilation_cache/cache_miss"
 class CompileTimer:
     """Cumulative compile/cache-load seconds from jax.monitoring events.
     Thread-safe; read with snapshot() and diff two snapshots with delta()
-    to attribute cost to a measured phase (bench attempt 0, a prewarm)."""
+    to attribute cost to a measured phase (a warm-up pass, a prewarm)."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -522,7 +520,7 @@ def install_compile_timer() -> CompileTimer:
 def maybe_enable_from_env() -> Optional[CompileWatchdog]:
     """Serving-path hook: enables the sanitizer iff KUBETPU_SANITIZE=1.
     Called from kubetpu/__init__.py so every entry point (scheduler,
-    server, bench, harness) gets it without its own wiring.  Importing
+    server, harness) gets it without its own wiring.  Importing
     this module never imports jax; enabling does."""
     if sanitize_enabled():
         return enable_sanitizer()

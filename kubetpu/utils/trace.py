@@ -104,14 +104,6 @@ def capture_device_trace(log_dir: str, profiler_options=None):
     finally:
         _PROFILE_ACTIVE = False
         jax.profiler.stop_trace()
-        # devstats xplane hook: when device-side observability is armed,
-        # fold the capture into per-program device-time records (or
-        # record WHY the tooling can't — never silently); disarmed this
-        # is one attribute read
-        from . import devstats as _devstats
-        ds = _devstats.devstats()
-        if ds is not None:
-            ds.ingest_xplane(log_dir)
 
 
 def _emit_clock(cycle: int) -> None:
@@ -527,8 +519,8 @@ class FlightRecorder:
                 "cycles": [r.to_dict() for r in recs]}
 
     def to_pipeline_doc(self, workload: str = "") -> Dict[str, Any]:
-        """The PIPELINE_TRACE.json document: a flat stage/cycle span list
-        (the shape tools/traceview.py and the committed artifact consume).
+        """The pipeline document: a flat stage/cycle span list (the shape
+        tools/traceview.py and tools/kubeaot --prune consume).
         ``span_total`` equals the number of ``ph: "X"`` events in
         ``to_chrome_trace()`` for the same ring content — the two exports
         describe the same spans.  Still-OPEN spans (e.g. an async bind in
@@ -564,15 +556,6 @@ class FlightRecorder:
         if recs:
             doc["total_s"] = round(max((r.t1 or r.t0) for r in recs)
                                    - t_base, 3)
-        # per-pod latency meta (utils/slo.py): when the SLO tracker is
-        # armed alongside the recorder, the pipeline doc carries the
-        # per-stage quantiles + shares so traceview can print the "SLO:"
-        # digest from the committed artifact alone
-        from . import slo as _slo
-        trk = _slo.tracker()
-        if trk is not None:
-            doc["slo"] = {"stages": trk.stage_quantiles(),
-                          "shares": trk.shares()}
         # durable-journal digest (utils/journal.py): when the journal is
         # armed alongside the recorder, the pipeline doc carries its
         # status — records, bytes, drops, window span and the linkage
@@ -583,25 +566,6 @@ class FlightRecorder:
         if jr is not None:
             doc["journal"] = jr.status(
                 flight_seqs={r.seq for r in recs})
-        # device-side observability digest (utils/devstats.py): when
-        # armed alongside the recorder, the pipeline doc carries the
-        # measured per-program device times + roofline join and the
-        # residency-ledger totals so traceview can print the "device:"
-        # digest from the committed artifact alone
-        from . import devstats as _devstats
-        ds = _devstats.devstats()
-        if ds is not None:
-            doc["device"] = ds.summary()
-        # sustained-load digest (utils/telemetry.py): when the windowed
-        # telemetry ring is armed alongside the recorder, the pipeline
-        # doc carries its digest — window count/cadence, steady-state
-        # span + p99, demotions, worst window with flight_seq link — so
-        # traceview can print the "load:" digest from the committed
-        # artifact alone
-        from . import telemetry as _telemetry
-        tel = _telemetry.ring()
-        if tel is not None:
-            doc["load"] = tel.digest()
         return doc
 
     @staticmethod

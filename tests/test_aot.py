@@ -556,58 +556,6 @@ def test_cli_check_mode(tmp_path):
         m.MANIFEST_PATH = old
 
 
-# -------------------------------------------------- cold_restart_s gate
-
-
-def test_gate_entries_records_cold_restart_ceiling():
-    import bench
-    detail = {"warm_restart": {"cold_restart_s": 2.5},
-              "gang": {"pods_per_sec": 100.0,
-                       "spread": {"min_s": 1.0, "median_s": 1.0}}}
-    gate = bench.gate_entries(detail)
-    assert gate["warm_restart.cold_restart_s"] == {"seconds": 2.5,
-                                                   "max_frac": 2.0}
-
-
-def test_northstar_gate_seconds_ceiling(tmp_path):
-    import bench
-    path = tmp_path / "NORTHSTAR.json"
-    path.write_text(json.dumps(
-        {"gate": {"warm_restart.cold_restart_s":
-                  {"seconds": 2.0, "max_frac": 2.0}}}))
-    ok = {"warm_restart": {"cold_restart_s": 3.9}}
-    bad = {"warm_restart": {"cold_restart_s": 4.1}}
-    assert bench.northstar_gate(ok, path=str(path)) == []
-    failures = bench.northstar_gate(bad, path=str(path))
-    assert len(failures) == 1 and "ceiling" in failures[0]
-
-
-def test_northstar_gate_fails_on_placement_divergence(tmp_path):
-    """Bit-identity is a GATE failure, not just a recorded field — and it
-    needs no recorded floor (a gate-less NORTHSTAR.json still fails it)."""
-    import bench
-    detail = {"warm_restart": {"cold_restart_s": 1.0,
-                               "placements_match": False}}
-    failures = bench.northstar_gate(detail,
-                                    path=str(tmp_path / "absent.json"))
-    assert len(failures) == 1 and "diverged" in failures[0]
-    detail["warm_restart"]["placements_match"] = True
-    assert bench.northstar_gate(
-        detail, path=str(tmp_path / "absent.json")) == []
-
-
-def test_northstar_gate_throughput_floor_still_works(tmp_path):
-    import bench
-    path = tmp_path / "NORTHSTAR.json"
-    path.write_text(json.dumps(
-        {"gate": {"gang.pods_per_sec":
-                  {"pods_per_sec": 100.0, "min_frac": 0.8}}}))
-    assert bench.northstar_gate(
-        {"gang": {"pods_per_sec": 90.0}}, path=str(path)) == []
-    assert len(bench.northstar_gate(
-        {"gang": {"pods_per_sec": 70.0}}, path=str(path))) == 1
-
-
 # --------------------------------------------------- restart end-to-end
 
 

@@ -14,9 +14,7 @@
   * compile-once watchdog — a 50-cycle delta drain compiles the scatter
     program at most once per pow2 bucket (utils/sanitize.py);
   * the serving loop — a multi-cycle gang drain with chaining OFF runs
-    ONE full build (the initial resync) and scatters the rest;
-  * bench satellites — the NORTHSTAR drift gate and the single-point
-    compile_s clamp (BENCH_r05's chain_on case reported -0.3).
+    ONE full build (the initial resync) and scatters the rest.
 """
 
 import copy
@@ -1745,65 +1743,3 @@ def test_flight_recorder_surfaces_delta_spans():
         sched.close()
     finally:
         utrace.disarm_flight_recorder()
-
-
-# ---------------------------------------------------------------------------
-# bench satellites: compile_s clamp + NORTHSTAR drift gate
-
-
-def test_compile_estimate_clamped_at_zero():
-    """Regression for BENCH_r05's chain_on `compile_s: -0.3`: with the
-    persistent XLA cache the first run can beat the warm best; the single
-    point where compile_s is computed clamps at zero."""
-    import bench
-    assert bench.compile_estimate(2.066, 2.335) == 0.0
-    assert bench.compile_estimate(9.291, 1.866) == 7.4
-    # every reporting path flows through mode_summary -> compile_estimate
-    d, _ = bench.mode_summary("gang", best=2.335, first=2.066,
-                              outcomes=[], sched=None, stats={})
-    assert d["compile_s"] == 0.0
-
-
-def test_northstar_gate_detects_regression(tmp_path):
-    import bench
-    path = tmp_path / "NORTHSTAR.json"
-    path.write_text("""{
-      "gate": {
-        "gang.pods_per_sec": {"pods_per_sec": 1000.0, "min_frac": 0.9},
-        "chain_drain.pipelined.pods_per_sec":
-            {"pods_per_sec": 2000.0, "min_frac": 0.8}
-      }
-    }""")
-    ok = {"gang": {"pods_per_sec": 950.0},
-          "chain_drain": {"pipelined": {"pods_per_sec": 1900.0}}}
-    assert bench.northstar_gate(ok, path=str(path)) == []
-    bad = {"gang": {"pods_per_sec": 850.0},
-           "chain_drain": {"pipelined": {"pods_per_sec": 1500.0}}}
-    failures = bench.northstar_gate(bad, path=str(path))
-    assert len(failures) == 2
-    assert any("gang.pods_per_sec" in f for f in failures)
-    # metrics missing on either side are skipped, not failed
-    assert bench.northstar_gate({}, path=str(path)) == []
-    assert bench.northstar_gate(ok, path=str(tmp_path / "missing.json")) == []
-
-
-def test_gate_entries_derive_floor_from_spread():
-    import bench
-    detail = {
-        "gang": {"pods_per_sec": 1694.5,
-                 "spread": {"min_s": 2.417, "median_s": 2.609}},
-        "chain_drain": {
-            "pipelined": {"pods_per_sec": 2195.0,
-                          "spread": {"min_s": 1.866, "median_s": 1.9}},
-            "chain_on": {"pods_per_sec": 1753.9, "spread": {}},
-        },
-    }
-    gate = bench.gate_entries(detail)
-    assert set(gate) == {"gang.pods_per_sec",
-                         "chain_drain.pipelined.pods_per_sec",
-                         "chain_drain.chain_on.pods_per_sec"}
-    for ref in gate.values():
-        assert 0.7 <= ref["min_frac"] < 1.0
-    # a run matching its own recording passes its own gate
-    import json as _json
-    assert bench.northstar_gate(detail, path="/nonexistent") == []

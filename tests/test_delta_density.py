@@ -20,12 +20,20 @@ from kubetpu.harness import hollow
 from kubetpu.scheduler import Scheduler
 from kubetpu.state.cache import Snapshot
 from kubetpu.state.delta import DeltaTensorizer
-from kubetpu.utils import devstats as udevstats
 from kubetpu.utils import trace as utrace
 
 from test_delta import assert_matches_fresh, build_cache
 
 N_NODES = 8
+
+
+def _shape_bytes(tree) -> int:
+    """A pytree's bytes from its leaves' shapes and dtypes alone: the
+    count ``ClusterTensors.nbytes`` is held to."""
+    import jax
+    import numpy as np
+    return sum(int(np.prod(x.shape)) * np.dtype(x.dtype).itemsize
+               for x in jax.tree.leaves(tree))
 
 
 def _arrive(cache, node, name):
@@ -52,7 +60,7 @@ def test_arrivals_and_departures_stay_golden_and_say_what_they_walked(
     assert snap.pods_copied == N_NODES * per_node
     dt.refresh(snap.node_info_list)
     assert_matches_fresh(dt, snap.node_info_list)
-    assert dt.cluster.nbytes == udevstats.pytree_nbytes(dt.cluster) > 0
+    assert dt.cluster.nbytes == _shape_bytes(dt.cluster) > 0
     bytes0 = dt.cluster.nbytes
     last = []
     for cycle in range(6):
@@ -157,4 +165,4 @@ def test_the_cycle_record_says_rows_live_bytes_walked_and_copied(
             == build["node_rows_dirty"] + build["pod_rows_seen"]
     # bytes are the resident cluster's leaves, from shapes
     assert records[-1]["meta"]["cluster_device_bytes"] \
-        == udevstats.pytree_nbytes(sched._delta["default-scheduler"].cluster)
+        == _shape_bytes(sched._delta["default-scheduler"].cluster)

@@ -2,6 +2,7 @@
 (kubetpu/utils/trace.py, kubetpu/utils/decisions.py, the /debug
 endpoints, and the disarmed-hot-path no-op contract)."""
 import json
+import time
 import urllib.error
 import urllib.request
 
@@ -305,3 +306,50 @@ def test_contention_loser_reports_best_feasible(flight):
         assert "best feasible score" in d.why()
     finally:
         sched.close()
+
+
+# -------------------------------------------------- monotonic clock fix
+
+
+def test_trace_spans_survive_backwards_wall_clock(monkeypatch):
+    """The satellite regression: an NTP step that moves time.time()
+    BACKWARDS mid-cycle must not produce negative span durations —
+    span stamps read trace.wallclock() (perf_counter anchored to the
+    import-time wall epoch), which time.time() cannot move."""
+    utrace.disarm_flight_recorder()
+    fr = utrace.arm_flight_recorder(capacity=4)
+    try:
+        stepped = {"n": 0}
+        real_time = time.time
+
+        def ntp_step_backwards():
+            stepped["n"] += 1
+            return real_time() - 3600.0 * stepped["n"]
+
+        monkeypatch.setattr(time, "time", ntp_step_backwards)
+        tr = utrace.Trace("Scheduling", profile="p", pods=1)
+        tr.step("first step done")
+        with tr.stage("dispatch") as sp:
+            assert sp is not None
+        tr.step("second step done")
+        assert tr.total() >= 0.0
+        tr.finish()
+        recs = fr.cycles()
+        assert recs, "cycle record must commit"
+        rec = recs[-1]
+        assert rec.t1 is not None and rec.t1 >= rec.t0
+        spans = rec.spans()
+        assert spans
+        for s in spans:
+            assert s.t1 is not None and s.t1 >= s.t0, s.name
+    finally:
+        utrace.disarm_flight_recorder()
+
+
+def test_wallclock_monotonic_and_wall_anchored():
+    a = utrace.wallclock()
+    b = utrace.wallclock()
+    assert b >= a
+    # anchored to the wall epoch: agrees with time.time() closely on a
+    # box whose clock has not stepped since import
+    assert abs(utrace.wallclock() - time.time()) < 5.0

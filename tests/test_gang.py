@@ -902,8 +902,8 @@ def test_auction_signatures_hold_no_kernel_backend():
 
 
 def test_cycle_meta_records_rounds_and_no_backend():
-    """Flight-recorder cycle meta carries auction_rounds (traceview and
-    bench aggregate the round histogram) and no kernel_backend."""
+    """Flight-recorder cycle meta carries auction_rounds (traceview
+    aggregates the round histogram) and no kernel_backend."""
     from kubetpu.apis.config import (KubeSchedulerConfiguration,
                                      KubeSchedulerProfile)
     from kubetpu.client.store import ClusterStore
@@ -937,12 +937,6 @@ def test_cycle_meta_records_rounds_and_no_backend():
         assert line.startswith("auction rounds:") and "backend" not in line
     finally:
         utrace.disarm_flight_recorder()
-
-
-def test_bench_rounds_hist():
-    import bench
-    assert bench._rounds_hist([1, 4, 4, 2, 4]) == {"1": 1, "2": 1, "4": 3}
-    assert bench._rounds_hist([]) == {}
 
 
 # ---- PR 37: the term-set gates change no result.  Four batches through

@@ -53,21 +53,6 @@ def test_once_exits_nonzero_after_a_recovered_cycle():
     assert proc.returncode == 3, proc.stderr[-2000:]
 
 
-def test_bench_finds_errored_cases_at_any_depth():
-    """bench.py records a failed case as {"error": ...} in its artifact
-    and exits 4 on any of them — including one nested inside a case that
-    otherwise finished (chain_drain's delta_sparse)."""
-    import bench
-    detail = {"gang": {"pods_per_sec": 1.0},
-              "pv_heavy": {"error": "boom"},
-              "chain_drain": {"chain_on": {"e2e_best_s": 1.0},
-                              "delta_sparse": {"error": "late"}},
-              "northstar": {"rescore_stream": {"error": "x"}, "gate": {}}}
-    assert bench.errored_cases(detail) == [
-        "pv_heavy", "chain_drain.delta_sparse", "northstar.rescore_stream"]
-    assert bench.errored_cases({"gang": {"pods_per_sec": 1.0}}) == []
-
-
 def test_bad_config_exits_2(tmp_path):
     cfg = tmp_path / "bad.yaml"
     cfg.write_text("kind: NotASchedulerConfig\n")

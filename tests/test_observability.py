@@ -1,7 +1,12 @@
 """Metrics, tracing, leader election, cache debugger, serving endpoints
 (reference: pkg/scheduler/metrics, utils/trace, client-go leaderelection,
 internal/cache/debugger, cmd/kube-scheduler/app/server.go:167-199)."""
+import json
+import os
+import urllib.error
 import urllib.request
+
+import pytest
 
 from kubetpu.apis.config import (KubeSchedulerConfiguration,
                                  KubeSchedulerProfile)
@@ -10,9 +15,33 @@ from kubetpu.harness import hollow
 from kubetpu.scheduler import Scheduler
 from kubetpu.server import SchedulerServer
 from kubetpu.state.debugger import CacheComparer, CacheDumper
+from kubetpu.utils import journal as ujournal
+from kubetpu.utils import trace as utrace
 from kubetpu.utils.leaderelection import InMemoryLock, LeaderElector
-from kubetpu.utils.metrics import SchedulerMetrics
+from kubetpu.utils.metrics import Counter, Histogram, SchedulerMetrics
 from kubetpu.utils.trace import Trace
+
+
+def _drain(sched):
+    outs = []
+    while True:
+        got = sched.schedule_pending(timeout=0.0)
+        if not got:
+            break
+        outs.extend(got)
+    return outs
+
+
+def _world(n_nodes=2, n_pods=6, batch=8, metrics=None):
+    store = ClusterStore()
+    for n in hollow.make_nodes(n_nodes):
+        store.add(n)
+    sched = Scheduler(store, config=KubeSchedulerConfiguration(
+        profiles=[KubeSchedulerProfile()], batch_size=batch),
+        async_binding=False, metrics=metrics)
+    for p in hollow.make_pods(n_pods):
+        store.add(p)
+    return store, sched
 
 
 def test_metrics_through_scheduling():
@@ -39,26 +68,87 @@ def test_metrics_through_scheduling():
     assert "scheduler_pending_pods" in text
 
 
-def test_endpoints_serve():
+# every path SchedulerServer serves, with the answer README.md documents
+# for a scheduler that has nothing armed: (status, content type, a check
+# of the body)
+ROUTES = {
+    "/healthz": (200, "text/plain", lambda b: b == "ok"),
+    "/metrics": (200, "text/plain; version=0.0.4",
+                 lambda b: "# TYPE scheduler_e2e_scheduling_duration_seconds"
+                 " histogram" in b),
+    "/configz": (200, "application/json",
+                 lambda b: "profiles" in json.loads(b)),
+    "/debug/flightz": (200, "application/json", lambda b: (
+        json.loads(b)["armed"] is False
+        and "KUBETPU_FLIGHT" in json.loads(b)["hint"])),
+    "/debug/explain": (200, "application/json", lambda b: (
+        json.loads(b)["enabled"] is True
+        and json.loads(b)["decisions"] == [])),
+    "/debug/journal": (200, "application/json", lambda b: (
+        json.loads(b)["armed"] is False
+        and "KUBETPU_JOURNAL" in json.loads(b)["hint"])),
+}
+# a documented parameter of a served path, and the paths PR 45 removed
+OTHERS = {
+    "/debug/flightz?format=chrome": (
+        200, "application/json", lambda b: json.loads(b)["armed"] is False),
+    "/debug/explain?pod=no-such-pod": (
+        404, "application/json", lambda b: "no recorded decision" in b),
+    "/debug/slo": (404, "text/plain", lambda b: b == "not found"),
+    "/debug/loadz": (404, "text/plain", lambda b: b == "not found"),
+    "/debug/devicez": (404, "text/plain", lambda b: b == "not found"),
+}
+
+
+@pytest.fixture(scope="module")
+def served():
+    """One disarmed scheduler behind its server; yields a GET."""
+    utrace.disarm_flight_recorder()
+    ujournal.disarm_journal()
     store = ClusterStore()
     store.add(hollow.make_node("n1"))
-    m = SchedulerMetrics()
-    sched = Scheduler(store, async_binding=False, metrics=m)
+    sched = Scheduler(store, async_binding=False, metrics=SchedulerMetrics())
     srv = SchedulerServer(sched, port=0)
     port = srv.start()
-    try:
-        def get(path):
+
+    def get(path):
+        try:
             with urllib.request.urlopen(
                     f"http://127.0.0.1:{port}{path}") as r:
-                return r.status, r.read().decode()
-        code, body = get("/healthz")
-        assert (code, body) == (200, "ok")
-        code, body = get("/metrics")
-        assert code == 200 and "# TYPE" in body
-        code, body = get("/configz")
-        assert code == 200 and "profiles" in body
+                return r.status, r.headers["Content-Type"], r.read().decode()
+        except urllib.error.HTTPError as e:
+            return e.code, e.headers["Content-Type"], e.read().decode()
+    try:
+        yield get
     finally:
         srv.stop()
+        sched.close()
+
+
+@pytest.mark.parametrize("path", list(ROUTES) + list(OTHERS))
+def test_endpoints_serve(served, path):
+    status, ctype, check = {**ROUTES, **OTHERS}[path]
+    got_status, got_ctype, body = served(path)
+    assert got_status == status, body
+    assert got_ctype.startswith(ctype), got_ctype
+    assert check(body), body
+
+
+def test_the_routes_are_the_ones_readme_lists():
+    """The paths ``do_GET`` compares against, the table above and
+    README.md's table of endpoints are one set."""
+    import inspect
+    import re
+
+    from kubetpu import server
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    in_code = set(re.findall(r'path == "(/[^"]*)"',
+                             inspect.getsource(server)))
+    with open(os.path.join(root, "README.md")) as f:
+        section = f.read().split("`SchedulerServer` serves", 1)[1]
+    table = section.split("\n\n", 2)[1]
+    in_readme = set(re.findall(r"^\| `(/[^`]*)` \|", table, re.M))
+    assert in_code == set(ROUTES) == in_readme
 
 
 def test_trace_slow_log():
@@ -182,3 +272,108 @@ def test_jax_profiler_capture(tmp_path):
         found.extend(files)
     assert found, "jax.profiler capture produced no files"
     sched.close()
+
+
+# -------------------------------------------------- /metrics hardening
+
+
+def test_metrics_label_escaping_and_histogram_conventions():
+    c = Counter("t_total", 'help with "quotes"\nand newline',
+                ("reason",))
+    c.inc('bad "value" \\ with\nnewline')
+    lines = c.expose()
+    assert lines[0] == 't_total help with "quotes"\\nand newline' \
+        .join(["# HELP ", ""]) or lines[0].startswith("# HELP t_total")
+    assert "\n" not in lines[0]
+    body = "\n".join(lines)
+    assert '\\"value\\"' in body and "\\\\" in body and "\\n" in body
+    h = Histogram("d_seconds", "latency", buckets=(0.1, 1.0))
+    h.observe(0.05)
+    h.observe(50.0)
+    text = "\n".join(h.expose())
+    assert 'le="+Inf"} 2' in text
+    assert "d_seconds_sum 50.05" in text
+    assert "d_seconds_count 2" in text
+    assert "# TYPE d_seconds histogram" in text
+
+
+def test_metrics_content_type_and_exposition():
+    m = SchedulerMetrics()
+    store, sched = _world(metrics=m)
+    srv = SchedulerServer(sched, port=0)
+    port = srv.start()
+    try:
+        _drain(sched)
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/metrics") as r:
+            assert r.status == 200
+            assert r.headers.get("Content-Type").startswith(
+                "text/plain; version=0.0.4")
+            body = r.read().decode()
+        assert "# HELP scheduler_binding_duration_seconds" in body
+        assert "# TYPE scheduler_binding_duration_seconds histogram" in body
+        assert 'scheduler_binding_duration_seconds_bucket{le="+Inf"} 6' \
+            in body
+        # the extension-point histogram is now observed on the commit
+        # path (Reserve/Permit/PreBind/Bind/PostBind per bound pod)
+        for point in ("Reserve", "Permit", "PreBind", "Bind", "PostBind"):
+            assert m.framework_extension_point_duration.count(
+                point, "Success") == 6, point
+        assert m.framework_extension_point_duration.count(
+            "PreFilter", "Success") == 6
+    finally:
+        srv.stop()
+        sched.close()
+
+
+def test_permit_wait_and_preemption_metrics_wired():
+    """The previously-dormant metrics observe through the real seams:
+    permit_wait via a Wait permit plugin, preemption attempts/victims
+    via a priority pod preempting a filler."""
+    from kubetpu.framework.interface import Code, PermitPlugin, Status
+
+    class WaitingPermit(PermitPlugin):
+        def name(self):
+            return "WaitingPermit"
+
+        def permit(self, state, pod, node_name):
+            return Status(Code.WAIT), 0.05   # times out -> rejected
+
+    m = SchedulerMetrics()
+    store = ClusterStore()
+    store.add(hollow.make_node("n1", cpu_milli=1000))
+    from kubetpu.plugins.intree import new_in_tree_registry
+    registry = new_in_tree_registry()
+    registry["WaitingPermit"] = lambda args, fw: WaitingPermit()
+    from kubetpu.apis.config import PluginSet, Plugin, Plugins
+    prof = KubeSchedulerProfile(plugins=Plugins(
+        permit=PluginSet(enabled=[Plugin(name="WaitingPermit")])))
+    sched = Scheduler(store, config=KubeSchedulerConfiguration(
+        profiles=[prof], batch_size=4), registry=registry,
+        async_binding=False, metrics=m)
+    try:
+        store.add(hollow.make_pod("w1", cpu_milli=100))
+        _drain(sched)
+        assert m.permit_wait_duration.count("rejected") == 1
+    finally:
+        sched.close()
+
+    # preemption: fill the node, then a higher-priority pod evicts
+    m2 = SchedulerMetrics()
+    store2 = ClusterStore()
+    store2.add(hollow.make_node("n1", cpu_milli=1000))
+    sched2 = Scheduler(store2, config=KubeSchedulerConfiguration(
+        profiles=[KubeSchedulerProfile()], batch_size=4),
+        async_binding=False, metrics=m2)
+    try:
+        filler = hollow.make_pod("filler", cpu_milli=900)
+        store2.add(filler)
+        _drain(sched2)
+        high = hollow.make_pod("high", cpu_milli=900)
+        high.spec.priority = 100
+        store2.add(high)
+        _drain(sched2)
+        assert m2.preemption_attempts.value() >= 1
+        assert m2.preemption_victims.count() >= 1
+    finally:
+        sched2.close()

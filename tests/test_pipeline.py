@@ -1,7 +1,7 @@
 """Depth-k pipelined executor (kubetpu/pipeline.py): depth-parity
 placement goldens, the gather-window gating on free ring slots, per-slot
-exemption accounting, ring-slot flight-recorder tags, config/env depth
-plumbing, and the bench bit-identity gate."""
+exemption accounting, ring-slot flight-recorder tags, and config/env
+depth plumbing."""
 import os
 from types import SimpleNamespace
 
@@ -288,22 +288,6 @@ def test_env_depth_override(monkeypatch):
     assert depth_from_env(3) == 3          # unparseable -> config value
     monkeypatch.delenv("KUBETPU_PIPELINE_DEPTH")
     assert depth_from_env(2) == 2
-
-
-# ------------------------------------------------------------- bench gate
-
-
-def test_northstar_gate_fails_on_depth_placement_mismatch(tmp_path):
-    from bench import northstar_gate
-
-    failures = northstar_gate(
-        {"pipeline_depth": {"placements_match": False}},
-        path=str(tmp_path / "missing.json"))
-    assert any("pipeline_depth" in f and "bit-identity" in f
-               for f in failures)
-    assert northstar_gate(
-        {"pipeline_depth": {"placements_match": True}},
-        path=str(tmp_path / "missing.json")) == []
 
 
 def test_flush_pipeline_returns_every_parked_outcome():

@@ -276,7 +276,7 @@ def test_maybe_enable_from_env_off_by_default(monkeypatch):
 
 def test_watchdog_uninstall_restores_and_respects_active_sanitizer():
     """The pxla-logger arming is refcounted across the standalone compile
-    watchdog and the full sanitizer: uninstalling the bench watchdog
+    watchdog and the full sanitizer: uninstalling the standalone watchdog
     while the sanitizer is active must leave the logger armed (DEBUG,
     records flowing to the sanitizer's watchdog), and the ORIGINAL
     level/propagate come back only when the last handler detaches."""

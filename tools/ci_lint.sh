@@ -20,27 +20,18 @@ python -m tools.kubelint kubetpu/ --json
 # future refactor can't hide a violation behind an unrelated suppression.
 # The chaos registry rides the same pass: its fire counters are
 # guarded-by annotated and its decide/act split must never sleep or
-# raise under the lock (blocking-under-lock).  The SLO tracker
-# (utils/slo.py) joins it: its sketch/exemplar state is guarded-by
-# annotated and observed from both the serving thread and binder pool.
+# raise under the lock (blocking-under-lock).
 # The depth-k pipelined executor (kubetpu/pipeline.py) joins it too: its
 # in-flight ring is guarded-by annotated, and no device dispatch,
 # readback or sleep may ever run under the ring lock.  The durable cycle
 # journal (utils/journal.py) joins it: its file-index/counter state is
 # guarded-by annotated and record I/O runs outside the lock
-# devstats (utils/devstats.py) joins it: per-program timing + ledger
-# state is guarded-by annotated, and every record seam does its shape
-# walks / byte sums OUTSIDE the lock
 # The shard_map mesh module (kubetpu/parallel/shardmap.py) joins it:
 # its trace-time Mesh registry is guarded-by annotated and read only at
 # trace time (never under a traced computation)
-# The telemetry ring (utils/telemetry.py) joins it: its window deque is
-# guarded-by annotated, the roll gathers run under a separate roll lock
-# (never the ring lock), and the disarmed hot path takes zero locks
 python -m tools.kubelint kubetpu/utils/trace.py kubetpu/utils/decisions.py \
-	kubetpu/utils/chaos.py kubetpu/utils/slo.py kubetpu/pipeline.py \
-	kubetpu/utils/journal.py kubetpu/utils/devstats.py \
-	kubetpu/parallel/shardmap.py kubetpu/utils/telemetry.py \
+	kubetpu/utils/chaos.py kubetpu/pipeline.py \
+	kubetpu/utils/journal.py kubetpu/parallel/shardmap.py \
 	--rules concurrency --json
 # explicit delta-family pass over the serving loop: the cycle path must
 # stay scatter-only (full-retensorize-in-loop), independent of any
@@ -105,21 +96,6 @@ JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
 # adds zero locks and zero readbacks to the hot path).
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
 	tests/test_chaos.py -q -m 'not slow' -p no:cacheprovider
-# Per-pod latency SLO layer (utils/slo.py): quantile-sketch property vs
-# numpy.percentile, bounded memory, the disarmed zero-lock poison test,
-# /debug/slo round trip, exemplar->flight-record linkage, and the
-# armed-vs-disarmed placement-parity golden.
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
-	tests/test_slo.py -q -m 'not slow' -p no:cacheprovider
-# Sustained-load telemetry plane (utils/telemetry.py + the open-loop
-# harness streams in kubetpu/harness/hollow.py + perf.py's
-# SustainedLoadRunner): window-delta merge exactness vs the numpy order
-# statistic, ring wrap + drop counting, the disarmed zero-cost poison
-# test, the armed-vs-disarmed placement-parity golden, seeded
-# chaos-storm attribution to the firing window, /debug/loadz round
-# trip, and the /metrics scheduler_load_* window series.
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
-	tests/test_telemetry.py -q -m 'not slow' -p no:cacheprovider
 # Depth-k pipelined executor (kubetpu/pipeline.py): depth-parity
 # placement goldens (depth 1 == 2 == 4 bit-identical), the
 # gather-window/free-slot gate, per-slot exemption accounting, ring-slot
@@ -139,14 +115,6 @@ JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
 # zero divergence).
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
 	tests/test_replay.py -q -m 'not slow' -p no:cacheprovider
-# Device-side observability (kubetpu/utils/devstats.py): sampled
-# deep-timing fences measure per-program device time, the residency
-# ledger feeds the capacity planner (projection vs measured bytes must
-# agree within 10% at bench shapes), the roofline join resolves against
-# COMPILE_MANIFEST.json, and the house contract holds (disarmed zero-
-# lock poison test, armed-vs-disarmed placement parity golden).
-JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
-	tests/test_devstats.py -q -m 'not slow' -p no:cacheprovider
 # Exactness prover gate, full half (tools/kubeexact): re-traces every
 # exact-marked mesh root, re-proves each cross-shard
 # reduction exact (float max/min or int-valued sum < 2**24 via the
@@ -171,8 +139,3 @@ JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
 # members of the committed closure.
 JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}" python -m pytest \
 	tests/test_kubeclose.py -q -m 'not slow' -p no:cacheprovider
-# Bench-trend CI check (tools/benchtrend.py, pure JSON, no jax): no bench
-# run is committed, so this validates the NORTHSTAR.json gate schema;
-# saved BENCH_*.json runs in the repo root, if any, must stay
-# schema-compatible with the trend tooling and inside the gate.
-python -m tools.benchtrend --check

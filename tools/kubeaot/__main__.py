@@ -15,11 +15,11 @@
               artifact, is flagged as a prune/closure disagreement.
               No jax, safe in ci_lint.sh
 --shape NxB   deploy-shaped capture: run Scheduler.prewarm at N nodes /
-              B-pod waves under a capture runtime (what bench.py's
-              aot-artifact restart mode builds from); --ladder K chains
+              B-pod waves under a capture runtime (what a restart with
+              KUBETPU_AOT_DIR set loads from); --ladder K chains
               K dry-run rungs
 --prune       drop serving rows whose pod bucket the flight recorder
-              never saw (--trace PIPELINE_TRACE.json), census rows the
+              never saw (--trace <flightz dump>), census rows the
               manifest no longer carries, and census rows whose rung
               the committed closure proves unreachable (proof-driven:
               observation says what WAS served, the closure says what

@@ -157,10 +157,11 @@ def build_census(out_dir: str = DEFAULT_OUT,
 
 def build_shape(out_dir: str, n_nodes: int, wave: int, ladder: int = 2,
                 existing_per_node: int = 2) -> dict:
-    """Deploy-shaped capture: bench.py warm_restart_case's deterministic
-    world and wave (hollow.restart_world / restart_wave — the SAME
-    builders, so the store insertion order, label vocab, and topology-term
-    mix are identical by construction), a capture-armed
+    """Deploy-shaped capture: the deterministic warm-restart world and
+    wave (hollow.restart_world / restart_wave — the SAME builders a
+    restart of that shape uses, so the store insertion order, label
+    vocab, and topology-term mix are identical by construction), a
+    capture-armed
     ``Scheduler.prewarm``, and then a REAL drained wave.  The drain is
     what makes the serve-time lookup hit: prewarm's synthetic dry-run
     batch differs from a live wave in exactly the statics a signature
@@ -205,7 +206,7 @@ def build_shape(out_dir: str, n_nodes: int, wave: int, ladder: int = 2,
 
 def trace_buckets(doc: dict) -> Set[int]:
     """Pod-axis buckets a flight-recorder export actually served: the
-    per-cycle ``pod_bucket`` meta of PIPELINE_TRACE.json (or a
+    per-cycle ``pod_bucket`` meta of a to_pipeline_doc() document (or a
     /debug/flightz dump) — prewarm records carry no bucket and scheduling
     records always do, so this is exactly the recorder's bucket-hit set."""
     buckets: Set[int] = set()

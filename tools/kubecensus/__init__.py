@@ -14,11 +14,9 @@ The manifest is version-controlled.  CI regenerates it in memory and
 fails on drift in either direction: a traced variant missing from the
 committed manifest (the surface grew — a recompile hazard and an AOT
 gap) or a committed row no trace reproduces (a dead ladder bucket —
-exactly what AOT prewarm should prune).  At runtime, bench.py
-cross-checks that every compile event the sanitize watchdog observes
-for a registered kernel root matches a manifest row, closing the loop
-between static census and observed reality.  The manifest is verbatim
-the compile list a future AOT pass feeds to ``lower().compile()``.
+exactly what AOT prewarm should prune).  The manifest is verbatim the
+compile list the AOT pass (tools/kubeaot) feeds to
+``lower().compile()``.
 
 On top of the traced jaxprs a semantic rule family runs checks AST lint
 cannot express — see tools/kubecensus/README.md for the rule catalog.
@@ -27,12 +25,11 @@ cannot express — see tools/kubecensus/README.md for the rule catalog.
 from .census import (Finding, audit_entry, audit_callable, run_census,
                      CensusResult)
 from .manifest import (MANIFEST_PATH, load_manifest, write_manifest,
-                       diff_manifest, match_compile_events)
+                       diff_manifest)
 from .registry import ENTRIES, DEFAULT_LADDER, Rung, build_world
 
 __all__ = [
     "Finding", "audit_entry", "audit_callable", "run_census",
     "CensusResult", "MANIFEST_PATH", "load_manifest", "write_manifest",
-    "diff_manifest", "match_compile_events", "ENTRIES", "DEFAULT_LADDER",
-    "Rung", "build_world",
+    "diff_manifest", "ENTRIES", "DEFAULT_LADDER", "Rung", "build_world",
 ]

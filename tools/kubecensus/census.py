@@ -61,8 +61,8 @@ def _split_kwargs(kwargs: dict,
 
 def aval_strs(tree) -> List[str]:
     """Flattened 'dtype[d0,d1]' signatures, matching the spelling of
-    jax's own compile-log ShapedArray repr so the runtime cross-check
-    (manifest.match_compile_events) compares like with like.  Python
+    jax's own compile-log ShapedArray repr (what the compile watchdog,
+    utils/sanitize.py, records).  Python
     scalars are traced as weak-typed rank-0 avals of the default dtype —
     record them the way the log will report them."""
     import jax
@@ -259,8 +259,8 @@ _exact_surface_cache: Optional[dict] = None
 def _collective_bytes(entry: Entry, rung: Rung) -> Optional[dict]:
     """Per-collective DCN byte attribution for this variant, joined from
     the committed exactness surface (EXACT_MANIFEST.json, written by
-    ``python -m tools.kubeexact --write``).  Lets devstats/benchtrend
-    split a program's roofline into arithmetic vs cross-device transfer.
+    ``python -m tools.kubeexact --write``).  Lets a reader split a
+    program's roofline into arithmetic vs cross-device transfer.
     Programs outside the exactness registry (or a missing manifest)
     contribute nothing — never an error."""
     global _exact_surface_cache

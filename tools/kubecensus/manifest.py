@@ -1,45 +1,20 @@
-"""COMPILE_MANIFEST.json: serialization, drift diffing, and the runtime
-compile-event cross-check.
+"""COMPILE_MANIFEST.json: serialization and drift diffing.
 
-The committed manifest is the version-controlled compile surface.  Two
-consumers:
-
-* CI (``python -m tools.kubecensus --check``): regenerates the rows in
-  memory and fails on drift in either direction — a traced variant
-  absent from the committed file (surface grew silently) or a committed
-  row no trace reproduces (dead ladder bucket).
-* bench.py under ``BENCH_GATE=1``: every compile event the sanitize
-  watchdog observes for a REGISTERED kernel program must match a
-  manifest row.  Exact-shape matches pin census rungs.  At non-census
-  rungs, programs inside the committed compile-surface closure
-  (CLOSURE_MANIFEST.json, tools/kubeclose) classify by CLOSURE
-  MEMBERSHIP: every leaf's (dtype, rank) must appear among the
-  program's committed leaves and every dim must be licensed — a dim
-  some committed row of the program carries, or a pow2 ladder rung at
-  or below the north-star caps (tools/kubeexact/northstar.py).
-  Programs outside the closure (non-seamed kernel roots) fall back to
-  the legacy structural heuristic (ordered (dtype, rank) subsequence of
-  a committed signature), as does everything when no closure is
-  committed.  Events for unregistered names (jax-internal eager ops,
-  test helpers) are counted but exempt; unregistered KERNEL roots
-  cannot hide there because the static census fails on them first
-  (census/unregistered-root).
+The committed manifest is the version-controlled compile surface.  CI
+(``python -m tools.kubecensus --check``) regenerates the rows in memory
+and fails on drift in either direction — a traced variant absent from
+the committed file (surface grew silently) or a committed row no trace
+reproduces (dead ladder bucket).
 """
 
 from __future__ import annotations
 
 import json
 import os
-import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 MANIFEST_PATH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "COMPILE_MANIFEST.json")
-CLOSURE_PATH = os.path.join(os.path.dirname(MANIFEST_PATH),
-                            "CLOSURE_MANIFEST.json")
-
-_AVAL_RE = re.compile(r"([A-Za-z_][A-Za-z_0-9]*)\[([\d,\s]*)\]")
-
 
 def row_id(row: dict) -> str:
     tag = ":" + row["tag"] if row.get("tag") else ""
@@ -72,18 +47,6 @@ def load_manifest(path: str = None) -> Optional[List[dict]]:
         return None
 
 
-def load_closure(path: str = None) -> Optional[dict]:
-    """The committed compile-surface closure (tools/kubeclose), or None
-    when no CLOSURE_MANIFEST.json is committed — the event matcher then
-    falls back to the structural heuristic everywhere."""
-    path = path or CLOSURE_PATH
-    try:
-        with open(path) as f:
-            return json.load(f)
-    except (OSError, ValueError):
-        return None
-
-
 def diff_manifest(current: List[dict],
                   committed: Optional[List[dict]]) -> Dict[str, list]:
     """Three-way drift: added (traced, not committed), removed (committed,
@@ -105,157 +68,3 @@ def diff_manifest(current: List[dict],
                 changed.append("%s (%s)" % (rid, k))
                 break
     return {"added": added, "removed": removed, "changed": changed}
-
-
-# ------------------------------------------------- runtime event matching
-
-
-def _parse_sig(sig: str) -> List[Tuple[str, int]]:
-    """'[ShapedArray(float32[8,16]), ...]' -> [(dtype, rank), ...]."""
-    out = []
-    for dt, dims in _AVAL_RE.findall(sig):
-        rank = 0 if not dims.strip() else len(dims.split(","))
-        out.append((dt, rank))
-    return out
-
-
-def match_compile_events(events: Dict[Tuple[str, str], int],
-                         rows: List[dict],
-                         closure: Optional[dict] = None
-                         ) -> Dict[str, object]:
-    """Classify watchdog compile events against the manifest.
-
-    events: CompileWatchdog.counts — {(program, shapes-sig): count}.
-    closure: the committed CLOSURE_MANIFEST.json doc (``load_closure``);
-    when given, events for programs the closure proves replace the
-    structural-subsequence heuristic with closure-membership
-    classification (``_closure_match``).  Returns {kernel_events,
-    matched_exact, matched_closure, matched_structural, outside: [...],
-    auxiliary} — ``outside`` non-empty means a registered kernel program
-    compiled a variant neither the manifest nor the closure licenses."""
-    by_program: Dict[str, List[dict]] = {}
-    for r in rows:
-        by_program.setdefault(r["program"], []).append(r)
-    exact = {}
-    for r in rows:
-        exact.setdefault(
-            (r["program"], tuple(r.get("compiled_in_avals")
-                                 or r["in_avals"])), r)
-    closed = set((closure or {}).get("programs") or {})
-
-    kernel = matched_exact = matched_closure = matched_structural = 0
-    auxiliary = 0
-    outside: List[str] = []
-    for (program, sig), _count in sorted(events.items()):
-        cands = by_program.get(program)
-        if cands is None:
-            auxiliary += 1
-            continue
-        kernel += 1
-        parsed = _parse_sig(sig)
-        sig_key = tuple("%s[%s]" % (dt, dims.replace(" ", ""))
-                        for dt, dims in _AVAL_RE.findall(sig))
-        if (program, sig_key) in exact:
-            matched_exact += 1
-            continue
-        if program in closed:
-            # proved program: the closure enumerates its reachable
-            # signatures, so membership — committed leaf structure +
-            # licensed dims — replaces the subsequence heuristic
-            if _closure_match(sig, cands):
-                matched_closure += 1
-                continue
-        elif any(_structural_match(parsed, r) for r in cands):
-            matched_structural += 1
-            continue
-        outside.append("%s %s" % (program, sig))
-    return {"kernel_events": kernel, "matched_exact": matched_exact,
-            "matched_closure": matched_closure,
-            "matched_structural": matched_structural,
-            "auxiliary": auxiliary, "outside": outside}
-
-
-def _closure_match(sig: str, cands: List[dict]) -> bool:
-    """Closure-membership at non-census rungs for a program the
-    committed compile-surface closure proves.
-
-    The closure's static axes are finite by proof, so a legitimate
-    serving compile of a closed program can only differ from the census
-    rungs in its ARRAY shapes — and those walk the pow2 ladders the
-    serving path buckets every dim onto.  Membership therefore demands:
-    every event leaf's (dtype, rank) appears among the program's
-    committed leaves (no new dtypes, no new array structure), and every
-    dim is licensed — equal to a dim some committed row of the program
-    carries, or a BUCKET SUM at or below the north-star caps
-    (tools/kubeexact/northstar.py N/P, the largest buckets the roadmap
-    commits to serving).  A bucket sum is a sum of at most three powers
-    of two (popcount <= 3): every padded axis in the serving path is
-    either one ``pow2_bucket`` or a ``concat_selector_sets`` /
-    ExistingTerms join of at most three independently bucketed sets
-    (models/gang.py splices batch pref + required-affinity terms into
-    the snapshot's score terms), so e.g. U=3 (1+2), U=5 (1+4) selector
-    planes and S=4097 (4096+1) slot axes are reachable, while an
-    unbucketed raw count (popcount climbs with entropy) is not.
-    Anything else — an off-ladder dim, a dim past the committed
-    deployment target, a novel dtype — stays ``outside``: with the
-    statics proved finite there is no benign explanation left."""
-    from tools.kubeexact.northstar import NORTHSTAR_ENV
-
-    pairs = set()
-    licensed = set()
-    for r in cands:
-        for s in list(r.get("compiled_in_avals") or ()) + list(
-                r.get("in_avals") or ()):
-            m = _AVAL_RE.match(s)
-            if not m:
-                continue
-            dt, dims = m.groups()
-            dvals = [int(d) for d in dims.replace(" ", "").split(",")
-                     if d]
-            pairs.add((dt, len(dvals)))
-            licensed.update(dvals)
-    cap = int(max(NORTHSTAR_ENV.get("N", 0.0),
-                  NORTHSTAR_ENV.get("P", 0.0)))
-    for dt, dims in _AVAL_RE.findall(sig):
-        dvals = [int(d) for d in dims.replace(" ", "").split(",") if d]
-        if (dt, len(dvals)) not in pairs:
-            return False
-        for d in dvals:
-            if d in licensed:
-                continue
-            if 0 < d <= cap and bin(d).count("1") <= 3:
-                continue
-            return False
-    return True
-
-
-def _structural_match(parsed: List[Tuple[str, int]], row: dict) -> bool:
-    """The event's (dtype, rank) sequence must be an ORDERED SUBSEQUENCE
-    of the row's full (unpruned) call signature.
-
-    Why subsequence, not equality: jit prunes arguments the traced
-    program never reads, and the pruned set depends on batch CONTENT
-    (e.g. a wave with no preferred-affinity terms drops those weight
-    leaves) — so two legitimate compiles of one variant differ in which
-    leaves survive, but both are order-preserving subsets of the full
-    flatten.  A genuinely NEW argument structure (extra arrays, dtype
-    drift, reordered layout) cannot embed into the recorded signature
-    and stays ``outside``.  Exact-shape matching at the census rungs is
-    handled separately (compiled_in_avals equality)."""
-    want = []
-    for s in row["in_avals"]:
-        m = _AVAL_RE.match(s)
-        if not m:
-            return False     # non-array leaf recorded; never runtime-match
-        dt, dims = m.groups()
-        want.append((dt, 0 if not dims.strip() else len(dims.split(","))))
-    if len(parsed) > len(want):
-        return False
-    i = 0
-    for p in parsed:
-        while i < len(want) and want[i] != p:
-            i += 1
-        if i == len(want):
-            return False
-        i += 1
-    return True

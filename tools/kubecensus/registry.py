@@ -77,7 +77,7 @@ class CensusWorld:
         pending = hollow.make_pods(rung.n_pods, prefix="pend-",
                                    group_labels=8)
         for i, p in enumerate(pending):
-            # bench.py's blended topology mix: 1/3 soft zone spread, 1/5
+            # the blended topology mix: 1/3 soft zone spread, 1/5
             # hostname anti-affinity, 1/7 zone affinity
             if i % 3 == 0:
                 hollow.with_spread(p, api.LABEL_ZONE, when="ScheduleAnyway")
@@ -520,7 +520,7 @@ def _densify_pod_kv(w):
 
 def _volume_mask(w):
     """The device volume-family mask, built from a PVC-carrying twin of
-    the rung world (mirrors bench.pv_heavy_case at rung scale)."""
+    the rung world."""
     import jax
     import random
 

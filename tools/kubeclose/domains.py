@@ -108,8 +108,8 @@ EXEMPTIONS: Tuple[Tuple[str, str, str], ...] = (
     # term-free route) fall back at the seam to the traced
     # jit dispatch: ONE bounded compile per (program, bucket), warmed by
     # Scheduler.prewarm's score_bias=warm_bias pass when the profile
-    # declares host score plugins, and fenced by the BENCH_GATE watchdog
-    # + the per-(program, shape) recompile watchdog.
+    # declares host score plugins, and fenced by the per-(program,
+    # shape) recompile watchdog.
     ("close/uncaptured-signature",
      "_schedule_gang host_ok=absent intra_batch_topology=True "
      "score_bias=present",

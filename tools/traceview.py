@@ -1,11 +1,10 @@
-"""Text flame summary for flight-recorder traces (`make trace`).
+"""Text flame summary for flight-recorder traces.
 
-Reads either export of kubetpu's flight recorder:
+Reads any export of kubetpu's flight recorder:
 
-  * the flat span-list document (PIPELINE_TRACE.json — bench.py /
-    tools/trace_pipeline.py / /debug/flightz?format=json cycles), or
-  * Chrome traceEvents JSON (PIPELINE_TRACE.perfetto.json /
-    /debug/flightz?format=chrome)
+  * the flat span-list document (FlightRecorder.to_pipeline_doc),
+  * a saved /debug/flightz dump (nested per-cycle span trees), or
+  * Chrome traceEvents JSON (/debug/flightz?format=chrome)
 
 and prints (1) a per-stage aggregate table — count, total/mean wall
 time, share of the trace window, attributed device wait — and (2) the
@@ -13,7 +12,7 @@ span tree of the slowest cycles, indented by parent linkage with per-span
 durations and thread tags.
 
 Usage:
-  python tools/traceview.py [TRACE.json] [--cycles N] [--threshold-ms M]
+  python tools/traceview.py TRACE.json [--cycles N] [--threshold-ms M]
 """
 from __future__ import annotations
 
@@ -119,33 +118,6 @@ def delta_summary(spans: List[dict]) -> str:
             f"(rows p50 {p50}), {resyncs} resyncs")
 
 
-def slo_summary(doc) -> str:
-    """One-line per-pod latency digest under the stage table: per-stage
-    p50/p99 from the SLO block the pipeline doc (or a /debug/slo-merged
-    flightz dump) carries when the KUBETPU_SLO tracker was armed for the
-    run (kubetpu/utils/slo.py)."""
-    slo = doc.get("slo")
-    if not isinstance(slo, dict):
-        return ""
-    stages = slo.get("stages") or {}
-
-    def ms(v):
-        return f"{1000 * v:.1f}ms" if v < 1.0 else f"{v:.2f}s"
-
-    parts = []
-    order = ["e2e", "queue_wait", "backoff", "cycle_wait", "dispatch",
-             "device", "commit", "bind"]
-    for name in order + sorted(set(stages) - set(order)):
-        st = stages.get(name)
-        if not st or not st.get("count"):
-            continue
-        parts.append(f"{name} p50 {ms(st.get('p50_s', 0.0))} "
-                     f"p99 {ms(st.get('p99_s', 0.0))}")
-    if not parts:
-        return ""
-    return "SLO: " + " | ".join(parts)
-
-
 def auction_summary(doc) -> str:
     """One-line auction digest under the stage table: the per-cycle round
     HISTOGRAM (rounds -> cycles), read from cycle meta (Scheduler
@@ -190,70 +162,6 @@ def journal_summary(doc) -> str:
     if "decision_live_rate" in j:
         parts.append(f"decision-link {100 * j['decision_live_rate']:.0f}%")
     return "journal: " + ", ".join(parts)
-
-
-def device_summary(doc) -> str:
-    """One-line device observability digest under the stage table:
-    measured per-program device time (mean per fenced dispatch, sample
-    count) with the roofline fraction where the join resolved, plus the
-    residency-ledger total — read from the "device" block the pipeline
-    doc carries when KUBETPU_DEVSTATS was armed for the run
-    (kubetpu/utils/devstats.py; live twin at /debug/devicez)."""
-    d = doc.get("device")
-    if not isinstance(d, dict):
-        return ""
-    parts = []
-    for name, p in sorted((d.get("programs") or {}).items()):
-        if not p.get("count"):
-            continue
-        seg = (f"{name} {1000 * p.get('mean_s', 0.0):.1f}ms "
-               f"x{p['count']}")
-        frac = p.get("roofline_fraction")
-        if isinstance(frac, (int, float)):
-            seg += f" ({100 * frac:.1f}% of roofline)"
-        parts.append(seg)
-    lb = d.get("ledger_bytes")
-    if isinstance(lb, (int, float)) and lb > 0:
-        parts.append(f"HBM resident {lb / 1048576.0:.1f} MiB")
-    if not parts:
-        return ""
-    return "device: " + " | ".join(parts)
-
-
-def load_summary(doc) -> str:
-    """One-line sustained-load digest under the stage table: window
-    count and cadence, the steady-state span with its EXACT windowed
-    p50/p99 (warmup cut by the slope test), total recovery demotions,
-    and the worst window's p99 with its flight-recorder seq cross-link —
-    read from the "load" block the pipeline doc carries when the
-    KUBETPU_TELEMETRY ring was armed for the run
-    (kubetpu/utils/telemetry.py; live twin at /debug/loadz)."""
-    ld = doc.get("load")
-    if not isinstance(ld, dict) or not ld.get("windows"):
-        return ""
-
-    def ms(v):
-        return f"{1000 * v:.1f}ms" if v < 1.0 else f"{v:.2f}s"
-
-    parts = [f"{ld['windows']} windows x {ld.get('window_s', 0.0):g}s"
-             + (f" ({ld['dropped']} dropped)" if ld.get("dropped")
-                else "")]
-    steady = ld.get("steady")
-    if isinstance(steady, dict):
-        parts.append(f"steady [{steady.get('start', 0)}+"
-                     f"{steady.get('windows', 0)}] "
-                     f"p50 {ms(steady.get('p50_s', 0.0))} "
-                     f"p99 {ms(steady.get('p99_s', 0.0))}")
-    else:
-        parts.append("no steady state reached")
-    if ld.get("demotions"):
-        parts.append(f"{ld['demotions']} demotions")
-    worst = ld.get("worst_window")
-    if isinstance(worst, dict) and worst.get("p99_s"):
-        parts.append(f"worst w{worst.get('seq', 0)} "
-                     f"p99 {ms(worst['p99_s'])} "
-                     f"(flight seq {worst.get('flight_seq', 0)})")
-    return "load: " + ", ".join(parts)
 
 
 def pipeline_summary(doc) -> str:
@@ -319,7 +227,7 @@ def main(argv=None) -> int:
         prog="traceview",
         description="text flame summary for kubetpu flight-recorder "
                     "traces")
-    ap.add_argument("trace", nargs="?", default="PIPELINE_TRACE.json")
+    ap.add_argument("trace")
     ap.add_argument("--cycles", type=int, default=2,
                     help="show the span tree of the N slowest cycles")
     ap.add_argument("--threshold-ms", type=float, default=0.5,
@@ -335,18 +243,9 @@ def main(argv=None) -> int:
     pipe = pipeline_summary(doc)
     if pipe:
         print(pipe)
-    dev = device_summary(doc)
-    if dev:
-        print(dev)
-    slo = slo_summary(doc)
-    if slo:
-        print(slo)
     jnl = journal_summary(doc)
     if jnl:
         print(jnl)
-    ld = load_summary(doc)
-    if ld:
-        print(ld)
     if not spans:
         return 0
     wall: Dict[int, float] = {}
