@@ -190,6 +190,14 @@ class GangResult(NamedTuple):
                             # a program that does not re-evaluate
                             # PodTopologySpread in its rounds.
                             # Diagnostics, read back as capacity_deferred is
+    affinity_bootstrap_admits: Optional[jnp.ndarray] = None  # i32 pods
+                            # admitted, summed over the rounds, through
+                            # the self-match bootstrap alone
+                            # (filtering.go:356: their required-affinity
+                            # terms matched no pod anywhere as their round
+                            # started).  None for a program that does not
+                            # re-evaluate InterPodAffinity in its rounds.
+                            # Diagnostics, read back as capacity_deferred is
 
 
 def _segment_base(values: jnp.ndarray, is_start: jnp.ndarray) -> jnp.ndarray:
@@ -679,6 +687,9 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         # re-opens feasibility (see _round below)
         retired=jnp.zeros((B,), bool),
     )
+    if use_ipa:
+        # GangResult.affinity_bootstrap_admits
+        carry0["boot_admits"] = jnp.int32(0)
     if use_sph:
         # the round proposes over the STRICT spread verdict (set by a
         # widened round that admitted nobody, for the one round after it)
@@ -841,7 +852,7 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
             _seg_prefix(e_b[order], is_start))
         return defer | (jnp.any((pref_b > 0) & mu.T, axis=1) & pair_ok)
 
-    def topology_deferral(sb, admit_cap, prop, boot_live):
+    def topology_deferral(sb, admit_cap, prop, boot):
         """Selector-precise intra-round serialization of InterPodAffinity:
         see module docstring.  One stable sort by landing pair per
         topology key; the per-pair exclusive prefix sums run in
@@ -871,9 +882,7 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         # count path applies and co-admission is monotone-safe
         # (placements only add matches), so no deferral.
         earlier_any = jnp.cumsum(_f(admit_cap)) - _f(admit_cap)
-        live = (sb["ra_boot"] if boot_live is None
-                else (sb["ra_boot"] & boot_live))
-        defer = defer | (live & (earlier_any > 0))
+        defer = defer | (boot & (earlier_any > 0))
         return defer
 
     def spread_turns(sb, cand, prop, room):
@@ -1012,7 +1021,9 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         if use_ipa:
             # intra-round topology serialization (conservative; deferred
             # pods re-check against exact committed counts next round)
-            admit = admit & ~topology_deferral(sb, admit, prop, boot_live)
+            # the pods only the self-match bootstrap lets in this round
+            boot = sb["ra_boot"] & boot_live
+            admit = admit & ~topology_deferral(sb, admit, prop, boot)
         if use_sph:
             # last, on what every other rule admits: a pod held back must
             # lift nobody's minimum
@@ -1044,6 +1055,9 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
         new["rounds"] = c["rounds"] + 1
         new["admits"] = c["admits"] + admitted_any.astype(jnp.int32)
         new["cap_deferred"] = c["cap_deferred"] + cap_deferred
+        if use_ipa:
+            new["boot_admits"] = c["boot_admits"] + jnp.sum(
+                admit & boot, dtype=jnp.int32)
         # did this round propose past the strict spread verdict?  Such a
         # round proves nothing by admitting nobody: the next one is strict
         # (its first proposer always fits), and only a strict round's
@@ -1137,4 +1151,5 @@ def _gang_program(cluster, batch, cfg: ProgramConfig, rng,
                       all_unresolvable=all_unres, packed=packed,
                       capacity_deferred=out["cap_deferred"],
                       soft_spread_skew=soft_skew,
-                      spread_late_admits=out.get("late"))
+                      spread_late_admits=out.get("late"),
+                      affinity_bootstrap_admits=out.get("boot_admits"))

@@ -871,8 +871,11 @@ class Scheduler:
             spread_rows = int(batch.spread.valid.sum())
             soft_spread_rows = int(batch.spread_soft.valid.sum())
             term_sets_live = live_term_sets(batch)
+            # valid required pod-affinity term rows of the incoming pods
+            ra_rows = int(batch.ra.valid.sum())
             if build_span is not None:
                 build_span.args["spread_rows"] = spread_rows
+                build_span.args["ra_rows"] = ra_rows
                 build_span.args["term_sets_live"] = term_sets_live
         batch_dev = None
         if self._mesh is not None:
@@ -921,6 +924,7 @@ class Scheduler:
             # auction's recount runs over, padding included
             trace.rec.meta["spread_constraints"] = spread_rows
             trace.rec.meta["spread_soft_constraints"] = soft_spread_rows
+            trace.rec.meta["required_affinity_terms"] = ra_rows
             # the term sets whose existing-pod products this batch's
             # auction runs (ops/kernels.py _if_live); the rest are gated off
             trace.rec.meta["term_sets_live"] = term_sets_live
@@ -1440,6 +1444,12 @@ class Scheduler:
                     # DoNotSchedule constraint
                     prep.trace.rec.meta["spread_late_admits"] = int(
                         res.spread_late_admits)
+                if (prep.trace.rec.meta.get("required_affinity_terms")
+                        and res.affinity_bootstrap_admits is not None):
+                    # and for the pods the self-match bootstrap admitted,
+                    # where the batch carries a required affinity term
+                    prep.trace.rec.meta["affinity_bootstrap_admits"] = int(
+                        res.affinity_bootstrap_admits)
         self.device_wait_s += wait
         return packed
 

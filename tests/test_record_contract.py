@@ -52,6 +52,10 @@ CONTRACT = [
      "soft_spread_constraints_per_cycle.sat"),
     ("meta", "spread_soft_skew", int, "soft_spread_zone_skew_per_cycle.sat"),
     ("meta", "spread_late_admits", int, "spread_late_admits_per_cycle.sat"),
+    ("meta", "required_affinity_terms", int,
+     "affinity_bootstrap_pods_per_cycle.sat"),
+    ("meta", "affinity_bootstrap_admits", int,
+     "affinity_bootstrap_pods_per_cycle.sat"),
     ("meta", "heap_handoffs", int, "heap_handoffs_per_cycle.sat"),
     ("meta", "thread_cpu_s", dict, "python_cpu_ms_per_cycle.sat"),
     ("meta", "thread_cpu_window_s", NUMBER,
@@ -71,6 +75,8 @@ CONTRACT = [
     ("span", "delta-terms", None, "term_refresh_ms_per_cycle.sat"),
     ("span", "delta-terms-upload", None, "terms_upload_ms_per_cycle.sat"),
     ("span", "bind-job", None, "lane_blocked_pct.sat"),
+    ("arg", "batch-build.ra_rows", int,
+     "required_affinity_terms_per_cycle.sat"),
     ("arg", "packed-readback.device_wait_s", NUMBER,
      "readback_wait_ms_per_cycle.sat"),
     ("arg", "commit.assume_s", NUMBER, "commit_assume_ms_per_cycle.sat"),
@@ -134,6 +140,10 @@ def _waves():
         [_pod(f"burst-{i}") for i in range(40)],
         [hollow.with_anti_affinity(_pod(f"anti2-{i}", "green"),
                                    api.LABEL_HOSTNAME) for i in range(4)],
+        # a required zone affinity to the pod's own label: the auction
+        # says how many came in by the self-match bootstrap
+        [hollow.with_affinity(_pod(f"aff-{i}", "purple"), api.LABEL_ZONE)
+         for i in range(4)],
         [_pod(f"late-{i}") for i in range(6)],
     ]
 
