@@ -230,6 +230,7 @@ def explain_verdicts(cluster, batch, cfg: ProgramConfig, host_ok=None):
 EXPLAIN_PROGRAM = "explain_verdicts"
 WHATIF_PROGRAM = "whatif_wave"
 DELTA_PROGRAM = "apply_cluster_delta"
+TERMS_DELTA_PROGRAM = "apply_terms_delta"
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -532,6 +533,43 @@ def apply_cluster_delta(cluster, delta, donate: bool = True):
     fn = (_apply_cluster_delta_donated if donate
           else _apply_cluster_delta_shared)
     return fn(cluster, delta)
+
+
+def _apply_terms_delta(filter_slots, score_slots, filter_delta, score_delta):
+    """Scatter one cycle's written rows (state/tensors.py TermsDelta, one a
+    table) into the per-row leaves of the two resident existing-term tables
+    (``term_slots``: ``sel.index``, ``ns_hot``, ``topo_key``, ``pod_idx``,
+    ``weight``, ``valid``).  A program of its own beside
+    _apply_cluster_delta: that one compiles per (node rows, pod rows) pair
+    already, and an owner comes or goes in few of the cycles it serves.
+    Pads are one-past-capacity rows, dropped; a table nothing was written
+    to takes an all-pad delta."""
+    def scat(slots, delta):
+        return tuple(x.at[delta.rows].set(v, mode="drop")
+                     for x, v in zip(slots, delta[1:]))
+    return scat(filter_slots, filter_delta), scat(score_slots, score_delta)
+
+
+# donated and shared as _apply_cluster_delta's two are, and for its reasons
+_apply_terms_delta_donated = jax.jit(_apply_terms_delta,
+                                     donate_argnums=(0, 1))
+_apply_terms_delta_shared = jax.jit(_apply_terms_delta)
+
+
+def apply_terms_delta(filter_terms, score_terms, filter_delta, score_delta,
+                      donate: bool = True):
+    """Apply a TermsDelta to each resident term table on device; returns
+    the two ExistingTerms.  The deltas' rows are bucketed by the caller
+    (state/tensors.gather_terms_delta), so a steady drain hits one compiled
+    program.  donate=False keeps the input buffers alive."""
+    from ..state.tensors import term_slots, with_term_slots
+    fn = (_apply_terms_delta_donated if donate
+          else _apply_terms_delta_shared)
+    # the deltas go in as the numpy leaves they are: the call transfers
+    # them together, where a jnp.asarray a leaf would one at a time
+    fs, ss = fn(term_slots(filter_terms), term_slots(score_terms),
+                filter_delta, score_delta)
+    return with_term_slots(filter_terms, fs), with_term_slots(score_terms, ss)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))

@@ -197,6 +197,31 @@ def _reqs_of(sel: SelectorLike) -> Optional[List[_Req]]:
     raise TypeError(f"unsupported selector type {type(sel)}")
 
 
+def selector_key(sel: SelectorLike) -> Optional[tuple]:
+    """A selector's requirement content as a hashable key, ``(op, key,
+    values)`` a requirement; None for a nil selector.  Selectors with
+    equal keys compile to ONE unique row (``SelectorCompiler.compile``),
+    and a table kept by row finds a selector it has seen by this key
+    (state/tensors.py TermTable)."""
+    reqs = _reqs_of(sel)
+    if reqs is None:
+        return None
+    return tuple((r.op, r.key, tuple(r.values)) for r in reqs)
+
+
+def empty_unique_rows(U: int, Q: int, L: int, K: int) -> dict:
+    """The nine unique-selector leaves of a SelectorSet, all padding."""
+    return dict(vals_hot=np.zeros((U, Q, L), bool),
+                key_hot=np.zeros((U, Q, K), bool),
+                negate=np.zeros((U, Q), bool),
+                use_key=np.zeros((U, Q), bool),
+                req_valid=np.zeros((U, Q), bool),
+                num_key=np.zeros((U, Q), np.int32),
+                num_op=np.zeros((U, Q), np.int32),
+                num_val=np.zeros((U, Q), np.float32),
+                sel_valid=np.zeros((U,), bool))
+
+
 class SelectorCompiler:
     """Compiles host selector objects into a SelectorSet of numpy arrays."""
 
@@ -205,7 +230,8 @@ class SelectorCompiler:
 
     def compile(self, selectors: Sequence[SelectorLike],
                 pad_s: Optional[int] = None,
-                intern_new: bool = True) -> SelectorSet:
+                intern_new: bool = True,
+                keys_out: Optional[dict] = None) -> SelectorSet:
         """intern_new: selectors may introduce vocab entries (normally the
         snapshot builder has already interned all cluster labels; pod
         selectors referencing unknown values simply never match, so lookups
@@ -213,79 +239,72 @@ class SelectorCompiler:
 
         Identical requirement lists compile to ONE unique row shared via the
         slot index — both the numpy build work and the device tensors scale
-        with the number of distinct selectors, not the batch size."""
-        all_req_lists = [_reqs_of(s) for s in selectors]
+        with the number of distinct selectors, not the batch size.
+
+        keys_out: an empty dict that takes ``selector_key -> unique row``
+        for every unique row compiled (the nil selector's under None)."""
+        keys = [selector_key(s) for s in selectors]
         S = pad_s if pad_s is not None else pow2_bucket(len(selectors), 1)
         if S < len(selectors):
             raise ValueError("pad_s smaller than selector count")
 
-        uniq: dict = {}
+        uniq: dict = {} if keys_out is None else keys_out
         index = np.zeros((S,), np.int32)
-        req_lists: List[Optional[List[_Req]]] = []
         for i in range(S):
-            reqs = all_req_lists[i] if i < len(all_req_lists) else None
-            k = None if reqs is None else tuple(
-                (r.op, r.key, tuple(r.values)) for r in reqs)
-            u = uniq.get(k)
-            if u is None:
-                u = len(req_lists)
-                uniq[k] = u
-                req_lists.append(reqs)
+            k = keys[i] if i < len(keys) else None
+            u = uniq.get(k, -1)
+            if u < 0:
+                u = uniq[k] = len(uniq)
             index[i] = u
 
-        max_q = max((len(r) for r in req_lists if r), default=1)
-        Q = pow2_bucket(max_q, 2)
-        U = pow2_bucket(len(req_lists), 1)
-        L, K = self.table.kv.cap, self.table.key.cap
+        max_q = max((len(k) for k in uniq if k), default=1)
+        sel = SelectorSet(index=index, **empty_unique_rows(
+            pow2_bucket(len(uniq), 1), pow2_bucket(max_q, 2),
+            self.table.kv.cap, self.table.key.cap))
+        for k, u in uniq.items():
+            self.fill_unique(sel, u, k, intern_new=intern_new)
+        return sel
 
-        vals_hot = np.zeros((U, Q, L), bool)
-        key_hot = np.zeros((U, Q, K), bool)
-        negate = np.zeros((U, Q), bool)
-        use_key = np.zeros((U, Q), bool)
-        req_valid = np.zeros((U, Q), bool)
-        num_key = np.zeros((U, Q), np.int32)
-        num_op = np.zeros((U, Q), np.int32)
-        num_val = np.zeros((U, Q), np.float32)
-        sel_valid = np.zeros((U,), bool)
-
+    def fill_unique(self, sel: SelectorSet, i: int, key: Optional[tuple],
+                    intern_new: bool = True) -> None:
+        """Write unique row ``i`` (all padding so far) of ``sel``'s numpy
+        leaves from a ``selector_key``.  An id past the leaves' width is
+        left out: it was interned here, across a cap, so no label that any
+        pod or node carries reads it yet, and the owner of the leaves
+        rebuilds them at the new width before one does (the
+        DeltaTensorizer's vocab-growth resync)."""
+        if key is None:
+            return  # matches nothing
         kv_id = (self.table.kv.intern if intern_new else self.table.kv.get)
         key_id = (self.table.key.intern if intern_new else self.table.key.get)
-
-        for i, reqs in enumerate(req_lists):
-            if reqs is None:
-                continue  # matches nothing
-            sel_valid[i] = True
-            for q, r in enumerate(reqs):
-                req_valid[i, q] = True
-                if r.op in ("In", "NotIn"):
-                    for v in r.values:
-                        j = kv_id((r.key, v))
-                        if j >= 0:
-                            vals_hot[i, q, j] = 1.0
-                    negate[i, q] = (r.op == "NotIn")
-                elif r.op in ("Exists", "DoesNotExist"):
-                    j = key_id(r.key)
-                    if j >= 0:
-                        key_hot[i, q, j] = 1.0
-                    use_key[i, q] = True
-                    negate[i, q] = (r.op == "DoesNotExist")
-                elif r.op in ("Gt", "Lt"):
-                    j = key_id(r.key)
-                    num_key[i, q] = max(j, 0)
-                    num_op[i, q] = 1 if r.op == "Gt" else 2
-                    try:
-                        num_val[i, q] = float(int(r.values[0]))
-                    except (ValueError, IndexError):
-                        # unparsable constant never matches: impossible compare
-                        num_op[i, q] = 1
-                        num_val[i, q] = np.inf
-                    if j < 0:
-                        # unknown key can never be numeric-matched
-                        num_val[i, q] = np.inf if r.op == "Gt" else -np.inf
-                else:
-                    raise ValueError(f"unknown selector op {r.op}")
-
-        return SelectorSet(vals_hot=vals_hot, key_hot=key_hot, negate=negate,
-                           use_key=use_key, req_valid=req_valid, num_key=num_key,
-                           num_op=num_op, num_val=num_val, sel_valid=sel_valid,
-                           index=index)
+        L, K = sel.vals_hot.shape[2], sel.key_hot.shape[2]
+        sel.sel_valid[i] = True
+        for q, (op, rkey, values) in enumerate(key):
+            sel.req_valid[i, q] = True
+            if op in ("In", "NotIn"):
+                for v in values:
+                    j = kv_id((rkey, v))
+                    if 0 <= j < L:
+                        sel.vals_hot[i, q, j] = True
+                sel.negate[i, q] = (op == "NotIn")
+            elif op in ("Exists", "DoesNotExist"):
+                j = key_id(rkey)
+                if 0 <= j < K:
+                    sel.key_hot[i, q, j] = True
+                sel.use_key[i, q] = True
+                sel.negate[i, q] = (op == "DoesNotExist")
+            elif op in ("Gt", "Lt"):
+                j = key_id(rkey)
+                sel.num_key[i, q] = max(j, 0)
+                sel.num_op[i, q] = 1 if op == "Gt" else 2
+                try:
+                    sel.num_val[i, q] = float(int(values[0]))
+                except (ValueError, IndexError):
+                    # unparsable constant never matches: impossible compare
+                    sel.num_op[i, q] = 1
+                    sel.num_val[i, q] = np.inf
+                if j < 0:
+                    # unknown key can never be numeric-matched
+                    sel.num_val[i, q] = np.inf if op == "Gt" else -np.inf
+            else:
+                raise ValueError(f"unknown selector op {op}")

@@ -40,13 +40,16 @@ Triggers (each counted and reported through ``DeltaStats.reason``):
                               build() walk (the host-walk cost is the
                               bottleneck, not the transfer)
 
-Term-carrying pod churn is NOT a resync trigger: the flattened
-``ExistingTerms`` rebuild from the term OWNERS alone (``_refresh_terms``,
-the ``delta-terms`` span) and replace wholesale — they are small, and a
-1-in-5-pods-with-anti-affinity drain would otherwise resync every cycle.
-They rebuild only when a dirty node's ordered OWNERS changed
-(``node_owners``): plain pods coming and going beside long-lived owners
-keep the resident tables, buffers and all.
+Term-carrying pod churn is NOT a resync trigger: the two flattened
+``ExistingTerms`` tables are kept by ROW (``state/tensors.py TermTable``,
+the ``delta-terms`` span): an owner that comes compiles its terms into
+free rows, one that goes tombstones its rows, and only the rows written
+cross to the device, through a small scatter program of their own
+(``models/programs.py apply_terms_delta``, the ``delta-terms-upload``
+span).  A table crosses whole only when it changes shape (its rows, its
+unique selectors or their requirements outgrow a bucket) or gains a unique
+selector.  Plain pods coming and going beside long-lived owners touch
+neither table, buffers and all.
 
 A dirty node is one whose ``generation`` moved, which a pod bound to it
 or deleted from it does.  Its mirror rows are refilled only where what
@@ -59,9 +62,8 @@ not what the dirty nodes hold: a node's arrivals and departures are the
 difference of two sets of PodInfo objects (``node_pods``), so no Python
 statement runs for a pod that stayed, and the ``ClusterDelta`` carries
 every dirty node's row but only the pod rows that were refilled or
-cleared — a row that was not is byte for byte what the device holds.  A
-node's pods are walked one at a time only to re-read its ordered term
-owners, and only where an owner came or went.  So that the steady churn
+cleared — a row that was not is byte for byte what the device holds.  So
+that the steady churn
 of a serving loop (a batch of arrivals, about as many departures) and its
 jitter land in ONE compiled scatter, the delta's pod rows are padded to
 at least four times the pending batch's pow2 bucket (``refresh``).
@@ -71,7 +73,11 @@ sequence of deltas, the resident tensors match a from-scratch ``build()``
 of the same NodeInfos against the same InternTable byte-for-byte, up to
 the documented stable-row permutation of the existing-pod axis (a fresh
 build packs pods in node-walk order; the delta path keeps rows stable and
-reuses freed rows lowest-first).  Known deviation: when several nodes
+reuses freed rows lowest-first).  The two term tables hold the same
+MULTISET of valid rows as the build's, each row read through its
+selector's requirement content and its owner's uid, and every other row
+is a padding row (a build packs them in node-walk order; the delta path
+keeps an owner's rows where they are).  Known deviation: when several nodes
 report the SAME image with DIFFERENT sizes, build() keeps the last walked
 node's size while the delta path keeps the last updated node's.
 """
@@ -88,9 +94,10 @@ import numpy as np
 from ..utils import journal as ujournal
 from ..utils.intern import pow2_bucket
 from ..utils.trace import wallclock
-from .tensors import (ClusterDelta, HostClusterArrays, SnapshotBuilder,
-                      clear_pod_row, fill_node_static, fill_node_usage,
-                      fill_pod_row, gather_delta, pod_has_terms,
+from .tensors import (TERM_KINDS, ClusterDelta, HostClusterArrays,
+                      SnapshotBuilder, TermTable, clear_pod_row,
+                      fill_node_static, fill_node_usage, fill_pod_row,
+                      gather_delta, gather_terms_delta, pod_has_terms,
                       vocab_signature)
 
 RESYNC_INTERVAL_ENV = "KUBETPU_RESYNC_INTERVAL"
@@ -165,24 +172,27 @@ class DeltaStats(NamedTuple):
     # no scatter ran
     delta_buckets: Tuple[int, ...] = ()
     # span name -> the args it is recorded with besides delta_rows: what
-    # the term refresh rebuilt ("delta-terms": filter_rows, score_rows,
-    # their buckets Et / Es, pods_walked, owners_changed) and what the
-    # build did ("delta-build": terms_kept, whether it kept the term
-    # tables beside a dirty owner; node_rows_dirty / node_rows_refilled;
+    # the term update did ("delta-terms": filter_rows, score_rows, the
+    # tables' LIVE rows; their buckets Et / Es; owners_changed, the owners
+    # that came, went or were replaced; rows_written, rows tombstoned plus
+    # rows appended; rows_free; wholesale, 1 where a table crossed to the
+    # device whole; pods_walked as below) and what the build did
+    # ("delta-build": terms_kept, whether the tables hold live rows and
+    # none was written; node_rows_dirty / node_rows_refilled;
     # pod_rows_seen, the pod rows in the delta: refilled or cleared;
     # pod_rows_refilled; pods_walked, the dirty nodes' pods the host
-    # visited one at a time: the arrivals, and every pod of a node whose
-    # owners it re-read)
+    # visited one at a time: the arrivals)
     span_args: Mapping[str, Mapping[str, int]] = MappingProxyType({})
 
 
 class TermOwner(NamedTuple):
     """Everything one existing pod gives the two term tables: its stable
     delta row (``pod_idx``) and the parsed term lists its rows compile
-    from, under PodInfo's names (``_build_terms`` reads an owner through
-    them).  Compared by VALUE with the lists themselves held, so an
-    unchanged PodInfo compares by identity and a pod replaced in place
-    under its uid by the content of its terms."""
+    from, under PodInfo's names (``owner_terms`` reads an owner through
+    them).  Compared by VALUE with the lists themselves held, so a pod
+    replaced in place under its uid keeps its term rows where its row and
+    the content of its terms stayed, and is an owner gone and an owner
+    come where either changed."""
     uid: str
     row: int
     required_anti_affinity_terms: list
@@ -228,10 +238,9 @@ class DeltaTensorizer:
         # event puts a new one on its node): what came and went on a dirty
         # node is the difference of two such sets
         self.node_pods: Dict[str, set] = {}
-        # name -> the node's term owners in pod order, as the resident
-        # term tables were last built from them: what ``terms_dirty`` and
-        # ``owners_changed`` compare, and what a rebuild compiles
-        self.node_owners: Dict[str, Tuple[TermOwner, ...]] = {}
+        # the two term tables' rows by owner ("filter_terms" /
+        # "score_terms" -> TermTable over the mirror), set by _resync
+        self.term_tables: Dict[str, TermTable] = {}
         self.pod_row: Dict[str, int] = {}        # uid -> row
         # what each mirror row was last filled FROM, so that refresh()
         # refills a row only when that changed.  name -> the
@@ -267,7 +276,8 @@ class DeltaTensorizer:
         # is armed, each refresh() stashes the exact input it applied to
         # the resident cluster — ("resync", pickled mirror) on any full
         # rebuild/re-upload, ("delta", pickled (ClusterDelta, terms)) on
-        # a scatter cycle, ("noop", None) on zero-dirty cycles — and the
+        # a scatter cycle (terms: None, or what _apply_terms put on the
+        # device), ("noop", None) on zero-dirty cycles — and the
         # scheduler pops it into the cycle's journal record
         # (take_capture).  Disarmed this stays None: zero allocations.
         self.capture = None
@@ -445,7 +455,7 @@ class DeltaTensorizer:
         b = self.builder
         pod_row, pod_src = self.pod_row, self.pod_src
         node_pods, node_src = self.node_pods, self.node_src
-        node_owners, row_uids = self.node_owners, self.row_uids
+        row_uids = self.row_uids
 
         # ---- what came and went on each dirty node: the difference of
         # two sets of PodInfo objects, so nothing runs a pod that stayed.
@@ -456,7 +466,9 @@ class DeltaTensorizer:
         # refill with no row.  A pod replaced on its node under its uid
         # (update_pod's remove and add) keeps its row: it is refilled
         touched_pods: set = set()
-        walk = []       # (node row, NodeInfo, arrivals, an owner went)
+        owners_went: List[str] = []     # uids whose term rows go
+        owners_came: List[TermOwner] = []
+        walk = []       # (node row, NodeInfo, arrivals)
         for i, ni in dirty:
             name = names[i]
             pods = ni.pods
@@ -466,50 +478,43 @@ class DeltaTensorizer:
             if len(came) > 1:
                 # rows are assigned in pod order, not in a set's
                 came = sorted(came, key=pods.index)
-            owner_went = False
             went = was - now
             if went:
                 stays = {pi.pod.metadata.uid for pi in came}
                 for pi in went:
                     uid = pi.pod.metadata.uid
-                    if pod_src[uid][2] is not None:
-                        owner_went = True
                     if uid in stays:
                         continue
                     row = pod_row.pop(uid)
-                    del pod_src[uid]
+                    if pod_src.pop(uid)[2] is not None:
+                        owners_went.append(uid)
                     clear_pod_row(a, row)
                     row_uids[row] = None
                     touched_pods.add(row)
                     self.free_rows.append(row)
             node_pods[name] = now
-            walk.append((i, ni, came, owner_went))
+            walk.append((i, ni, came))
         self.free_rows.sort()
 
         # ---- the arrivals of each dirty node: a row for each that has
         # none (lowest free row first, in walk order), the rows to
-        # (re)fill, and the node's term owners where one came or went
+        # (re)fill, and the term owners among them
         MLn = a["_kv_ids"].shape[1]
         MLp = a["_pod_kv_ids"].shape[1]
         free, n_free, k_free = self.free_rows, len(self.free_rows), 0
         reset_nodes: List[Tuple[int, object]] = []
         fills: List[Tuple[int, object, int]] = []   # (row, PodInfo, node row)
-        # term-carrying pod churn does NOT force a full resync: the
-        # flattened ExistingTerms rebuild from the term OWNERS alone and
-        # replace wholesale, and only when a dirty node's owners are not
-        # the ones the tables were built from (_refresh_terms says why
-        # that is the whole condition).  A node's ordered owners can
-        # differ from the noted ones only where an owner came or went
-        # (a plain pod beside them moves no owner's row, terms or place
-        # among the owners), so only there are the node's pods walked.
-        # The anti-entropy verifier cannot catch a table kept wrongly: it
-        # compares the device with the mirror, and both would be stale
-        # together — tests/test_delta.py holds the kept tables to a fresh
-        # build()
-        owners_were: Dict[str, Tuple[TermOwner, ...]] = {}
-        owner_seen = False
+        # term-carrying pod churn does NOT force a full resync: an owner
+        # that comes or goes is noted here and its rows are written below
+        # (_update_terms).  ``pod_src`` says whether a pod that went was
+        # an owner; one replaced in place under its uid is an owner gone
+        # and an owner come unless its row and its terms' content stayed
+        # (TermOwner compares by value).  The anti-entropy verifier cannot
+        # catch a row kept wrongly: it compares the device with the
+        # mirror, and both would be stale together — tests/test_delta.py
+        # holds the kept tables to a fresh build()
         pods_walked = 0
-        for i, ni, came, owner_went in walk:
+        for i, ni, came in walk:
             name = names[i]
             if ni.node_generation != node_src.get(name):
                 if len(ni.node.metadata.labels) + 1 > MLn:
@@ -518,7 +523,7 @@ class DeltaTensorizer:
                 b.intern_node(ni)
                 reset_nodes.append((i, ni))
             b.intern_node_usage(ni)
-            owner_came = False
+            pods_walked += len(came)
             for pi in came:
                 if len(pi.pod.metadata.labels) > MLp:
                     return self._resync(node_infos, names,
@@ -541,26 +546,15 @@ class DeltaTensorizer:
                         # time; _grow_pod_axis pads it to the new bucket
                         row_uids.append(uid)
                 owner = self._owner(uid, row, pi)
-                if owner is not None:
-                    owner_came = True
+                was = pod_src.get(uid)
+                if was is None or was[2] != owner:
+                    if was is not None and was[2] is not None:
+                        owners_went.append(uid)
+                    if owner is not None:
+                        owners_came.append(owner)
                 pod_src[uid] = (pi, i, owner)
                 fills.append((row, pi, i))
                 touched_pods.add(row)
-            was = node_owners[name]
-            if owner_came or owner_went:
-                owners = tuple(o for o in (pod_src[pi.pod.metadata.uid][2]
-                                           for pi in ni.pods)
-                               if o is not None)
-                pods_walked += len(ni.pods)
-                if owners != was:
-                    owners_were[name] = was
-                    node_owners[name] = owners
-                elif owners:
-                    owner_seen = True
-            else:
-                pods_walked += len(came)
-                if was:
-                    owner_seen = True
         del free[:k_free]
         # AFTER the interning, BEFORE any fill: new strings from what is
         # about to be filled count against the caps the resident tensors
@@ -611,10 +605,12 @@ class DeltaTensorizer:
             node_gen[names[i]] = ni.generation
             node_rows.append(i)
 
-        terms_dirty = bool(owners_were)
+        terms_dirty = bool(owners_went or owners_came)
         term_span = ()
+        term_rows = None
         span_args: Dict[str, Dict[str, int]] = {"delta-build": {
-            "terms_kept": int(owner_seen and not terms_dirty),
+            "terms_kept": int(not terms_dirty and any(
+                tt.live for tt in self.term_tables.values())),
             "node_rows_dirty": len(dirty),
             "node_rows_refilled": len(reset_nodes),
             "pod_rows_seen": len(touched_pods),
@@ -622,8 +618,13 @@ class DeltaTensorizer:
             "pods_walked": pods_walked}}
         if terms_dirty:
             t_terms = wallclock()
-            span_args["delta-terms"] = dict(
-                self._refresh_terms(owners_were), pods_walked=pods_walked)
+            term_rows, said = self._update_terms(owners_went, owners_came)
+            # a selector compiled just now interns the values it names: a
+            # cap they crossed is the resync it is at the top of refresh()
+            if self.signature() != self.caps:
+                return self._resync(node_infos, names, "vocab-growth", t0,
+                                    pending)
+            span_args["delta-terms"] = dict(said, pods_walked=pods_walked)
             term_span = (("delta-terms", t_terms, wallclock()),)
 
         pod_rows = sorted(touched_pods)
@@ -651,12 +652,12 @@ class DeltaTensorizer:
         # one bucket, and more rows take the next as before
         if batch is None:
             batch = len(pending)
-        delta = gather_delta(self.host, node_rows, pod_rows,
-                             pod_floor=4 * pow2_bucket(batch, 8))
+        floor = 4 * pow2_bucket(batch, 8)
+        delta = gather_delta(self.host, node_rows, pod_rows, pod_floor=floor)
         t_build = wallclock()
         upload_span: list = []
         self.cluster = self._apply(delta, donate=donate,
-                                   replace_terms=terms_dirty,
+                                   term_rows=term_rows, floor=floor,
                                    spans=upload_span)
         self.cycles_since_resync += 1
         spans = ((("delta-build", t0, t_build),) + term_span
@@ -702,24 +703,24 @@ class DeltaTensorizer:
         # with: the rebuild packed the pod rows anew (an owner carries
         # its row) and, compacting, moved the intern ids
         self.node_gen, self.node_src = {}, {}
-        self.node_pods, self.node_owners, self.pod_src = {}, {}, {}
+        self.node_pods, self.pod_src = {}, {}
+        # the build packed both term tables in node-walk order: no
+        # tombstone, no selector that no owner names, and the owner ->
+        # rows maps are what it built
+        self.term_tables = {field: TermTable(self.builder, a, field)
+                            for field in TERM_KINDS}
         self.row_uids = [None] * a["pod_node"].shape[0]
         for i, (name, ni) in enumerate(zip(names, node_infos)):
-            owners = []
             for pi in ni.pods:
                 uid = pi.pod.metadata.uid
                 row = self.pod_row.get(uid)
                 if row is None:
                     continue         # build() gives a node-less info no row
                 self.row_uids[row] = uid
-                owner = self._owner(uid, row, pi)
-                self.pod_src[uid] = (pi, i, owner)
-                if owner is not None:
-                    owners.append(owner)
+                self.pod_src[uid] = (pi, i, self._owner(uid, row, pi))
             self.node_gen[name] = ni.generation
             self.node_src[name] = ni.node_generation
             self.node_pods[name] = set(ni.pods)
-            self.node_owners[name] = tuple(owners)
         self.caps = self.signature()
         self.cycles_since_resync = 0
         # a resync re-uploads the mirror wholesale, so device == mirror
@@ -758,10 +759,9 @@ class DeltaTensorizer:
         self.cluster = cluster
 
     def _owner(self, uid: str, row: int, pi) -> Optional[TermOwner]:
-        """What the pod gives ``_build_terms``, or None for a pod that
-        gives it no row (required affinity owns one only at a nonzero
-        hard weight — ``pod_has_terms``).  A node's owners are these in
-        pod order."""
+        """What the pod gives the term tables, or None for a pod that
+        gives them no row (required affinity owns one only at a nonzero
+        hard weight — ``pod_has_terms``)."""
         if not pod_has_terms(pi, self.hard_pod_affinity_weight):
             return None
         return TermOwner(uid, row, pi.required_anti_affinity_terms,
@@ -769,114 +769,107 @@ class DeltaTensorizer:
                          pi.preferred_anti_affinity_terms,
                          pi.required_affinity_terms)
 
-    def _refresh_terms(self, owners_were) -> Dict[str, int]:
-        """Term-only rebuild: recompile the flattened ExistingTerms from
-        the term OWNERS (a small subset of the existing pods, noted a node
-        in ``node_owners``) against the persistent table, and stage them
-        in the mirror for wholesale replacement — the owners are taken in
-        the same node-walk order as build(), so row content matches a
-        rebuild exactly (term pod_idx points at the stable delta rows).
-        This demotes "topology-term structural change" from a full-resync
-        trigger to a bounded partial rebuild.  ``owners_were``: the dirty
-        nodes whose owners changed, each with its owners as the tables
-        still have them (refresh() has noted the new ones).  Returns what
-        the rebuild did, as args of the ``delta-terms`` span (refresh()
-        adds ``pods_walked``, the dirty nodes' pods it read the owners
-        from): the term rows recompiled, the buckets they are padded to,
-        and ``owners_changed``: the owners whose node, place among their
-        node's owners, row or terms are not what the tables were built
-        from, plus the owners gone (never 0).
+    def _update_terms(self, went: List[str], came: List[TermOwner]):
+        """Write one cycle's owner churn into the mirror's two term
+        tables (TermTable.update: ``went``'s rows tombstoned, ``came``'s
+        terms compiled into free rows).  Returns (field -> the rows
+        written, None for a table that has to cross whole; the
+        ``delta-terms`` span's args, refresh() adds ``pods_walked``).
 
-        Why refresh() may KEEP the tables while no dirty node's owners
-        changed: both tables are a function of the ordered owner list
-        and of nothing else that moves between resyncs.  A row reads its
-        selector as kv / key ids (``SelectorCompiler.compile(
-        intern_new=True)`` interns what it meets, so no id is ever "not
-        yet known", and ids are append-only), its namespaces and topology
-        key as ids interned WITH the owner (``intern_pod`` as its row
-        is filled), ``pod_idx`` as the owner's delta row
-        (stable while the uid stays on its node; a move frees and
-        re-assigns it, and is a changed owner on both nodes) and its
-        weight (the term's own, or the tensorizer's constant hard
-        weight).  The unique-selector index, ``Et`` / ``U`` / ``Q`` follow
-        from the ordered list; the widths are vocab CAPS, never lengths,
-        and a cap crossing is a resync.  Nothing reads the owner's node
-        (the match looks ``pod_node[pod_idx]`` up on the device), another
-        pod, or a node's labels.  Clean nodes cannot have changed owners
-        (any pod churn bumps the node's generation), so the dirty nodes'
-        owners are all there is to compare, and to note."""
-        filter_owners, score_owners = [], []
-        for name in self.node_names:
-            for o in self.node_owners[name]:
-                if o.required_anti_affinity_terms:
-                    filter_owners.append((o, o.row))
-                if (o.preferred_affinity_terms
-                        or o.preferred_anti_affinity_terms
-                        or o.required_affinity_terms):
-                    score_owners.append((o, o.row))
+        Why nothing else moves a row between resyncs: a row reads its
+        selector as kv / key ids (``fill_unique`` interns what it meets
+        and ids are append-only), its namespaces and topology key as ids
+        interned WITH the owner (``intern_pod`` as its row is filled),
+        ``pod_idx`` as the owner's delta row (stable while the uid stays
+        on its node; a move frees and re-assigns it, and is an owner gone
+        and an owner come) and its weight (the term's own, or the
+        tensorizer's constant hard weight).  The widths are vocab CAPS,
+        never lengths, and a cap crossing is a resync.  Nothing reads the
+        owner's node (the match looks ``pod_node[pod_idx]`` up on the
+        device), another pod, or a node's labels."""
+        ft, st = (self.term_tables[f] for f in TERM_KINDS)
+        rows, written = {}, 0
+        for tt in (ft, st):
+            rows[tt.field], n = tt.update(went, came)
+            written += n
+        return rows, {
+            "owners_changed": len(set(went).union(o.uid for o in came)),
+            "filter_rows": ft.live, "score_rows": st.live,
+            "Et": int(ft.terms.valid.shape[0]),
+            "Es": int(st.terms.valid.shape[0]),
+            "rows_written": written,
+            "rows_free": len(ft.free) + len(st.free),
+            "wholesale": int(any(r is None for r in rows.values()))}
 
-        def placed(nodes):
-            return {o.uid: (name, k, o) for name, owners in nodes
-                    for k, o in enumerate(owners)}
-        old = placed(owners_were.items())
-        new = placed((name, self.node_owners[name]) for name in owners_were)
-        changed = (sum(1 for uid, at in new.items() if old.get(uid) != at)
-                   + len(old.keys() - new.keys()))
-        a = self.host.arrays
-        a["filter_terms"] = self.builder._build_terms(filter_owners,
-                                                      kind="filter")
-        a["score_terms"] = self.builder._build_terms(score_owners,
-                                                     kind="score")
-        ft, st = a["filter_terms"].valid, a["score_terms"].valid
-        return {"owners_changed": changed,
-                "filter_rows": int(ft.sum()), "score_rows": int(st.sum()),
-                "Et": int(ft.shape[0]), "Es": int(st.shape[0])}
-
-    def _device_terms(self):
-        """The mirror's term tensors as device (mesh: replicated) arrays —
-        terms replace wholesale, no scatter needed."""
+    def _device_terms(self, field: str):
+        """One of the mirror's term tables as device (mesh: replicated)
+        arrays, for a table that crosses whole."""
         import jax
         import jax.numpy as jnp
-        a = self.host.arrays
         # jnp.array, not asarray: these leaves join the DONATED cluster
         # (see HostClusterArrays.to_device) — an aliased mirror buffer
         # would be clobbered by the scatter's buffer reuse
-        ft = jax.tree.map(jnp.array, a["filter_terms"])
-        st = jax.tree.map(jnp.array, a["score_terms"])
+        terms = jax.tree.map(jnp.array, self.host.arrays[field])
         if self.mesh is not None:
             from ..parallel import mesh as pmesh
-            ft = pmesh.replicate(ft, self.mesh)
-            st = pmesh.replicate(st, self.mesh)
-        return ft, st
+            terms = pmesh.replicate(terms, self.mesh)
+        return terms
 
-    def _apply(self, delta: ClusterDelta, donate: bool,
-               replace_terms: bool = False, spans: Optional[list] = None):
-        """``spans``: where given, the ``delta-terms-upload`` span of a
-        wholesale term replacement is appended to it."""
+    def _apply_terms(self, cluster, term_rows, floor: int, donate: bool):
+        """Bring the resident term tables up to the mirror's: a table
+        whose shape or unique selectors changed (rows None) crosses whole,
+        and the rows written to the others are gathered into TermsDeltas
+        of at least ``floor`` rows each and scattered by ONE program, so a
+        steady drain runs one variant of it.  Returns (cluster, what went
+        to the device: the journal's capture of the cycle's terms)."""
+        from ..models import programs
+        a = self.host.arrays
+        whole = {f: a[f] for f, rows in term_rows.items() if rows is None}
+        if whole:
+            cluster = cluster._replace(
+                **{f: self._device_terms(f) for f in whole})
+        # (filter, score), as apply_terms_delta takes them; a table that
+        # crossed whole has no row left to send
+        deltas = tuple(
+            gather_terms_delta(a[f], () if f in whole else term_rows[f],
+                               floor)
+            for f in TERM_KINDS)
+        dev = deltas
+        if self.mesh is not None:
+            from ..parallel import mesh as pmesh
+            dev = pmesh.replicate(deltas, self.mesh)
+        ft, st = programs.apply_terms_delta(
+            cluster.filter_terms, cluster.score_terms, *dev, donate=donate)
+        return (cluster._replace(filter_terms=ft, score_terms=st),
+                (whole, deltas))
+
+    def _apply(self, delta: ClusterDelta, donate: bool, term_rows=None,
+               floor: int = 8, spans: Optional[list] = None):
+        """``term_rows``: what _update_terms wrote, where an owner came
+        or went.  ``spans``: where given, the ``delta-terms-upload`` span
+        around the transfer and dispatch of the term rows is appended to
+        it."""
         from ..models import programs
         from ..utils import chaos
         cluster = self.cluster
-        if replace_terms:
-            # swap the term pytrees BEFORE the jit call: the scatter
-            # program passes terms through untouched, and a donated
-            # pass-through of the OLD terms would invalidate buffers the
-            # new cluster no longer uses anyway
+        terms = None
+        if term_rows is not None:
+            # BEFORE the cluster's own scatter: that program passes the
+            # term tables through, donated
             t_up = wallclock()
-            ft, st = self._device_terms()
-            cluster = cluster._replace(filter_terms=ft, score_terms=st)
+            cluster, terms = self._apply_terms(cluster, term_rows, floor,
+                                               donate)
             if spans is not None:
                 spans.append(("delta-terms-upload", t_up, wallclock()))
         if ujournal.journal() is not None:
-            # journal capture: the exact scatter tables (and wholesale
-            # term replacement) this cycle applies — pickled eagerly, the
-            # mirror the term pytrees alias mutates in place next cycle.
+            # journal capture: the exact scatter tables (and the term
+            # rows, with any table that crossed whole) this cycle applies
+            # — pickled eagerly, the mirror a whole table aliases mutates
+            # in place next cycle.
             # Captured BEFORE the chaos seam below: the journal records
             # applied INTENT, so a chaos-dropped scatter replays as a
             # detectable divergence (the fault class the replay rig
             # exists to expose)
-            a = self.host.arrays
-            terms = ((a["filter_terms"], a["score_terms"])
-                     if replace_terms else None)
             self.capture = ("delta", pickle.dumps((delta, terms),
                                                   protocol=4))
         # chaos seam (utils/chaos.py "delta"): "drop" loses the scatter

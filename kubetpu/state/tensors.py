@@ -30,7 +30,8 @@ import numpy as np
 from ..api import types as api
 from ..api.resource import Resource
 from ..framework.types import NodeInfo, PodInfo
-from ..ops.selectors import FIELD_PREFIX, SelectorCompiler, SelectorSet
+from ..ops.selectors import (FIELD_PREFIX, SelectorCompiler, SelectorSet,
+                             selector_key)
 from ..utils.intern import InternTable, pow2_bucket
 
 MIB = float(2 ** 20)
@@ -73,6 +74,13 @@ class ExistingTerms(NamedTuple):
     pod_idx: jnp.ndarray       # [Et] i32 owning existing-pod row
     weight: jnp.ndarray        # [Et] f32 (signed; 1.0 for filter terms)
     valid: jnp.ndarray         # [Et] bool
+
+
+# which of an owner's term lists each of the two tables takes
+TERM_KINDS = {"filter_terms": "filter", "score_terms": "score"}
+# the leaves with one entry a ROW of a term table, in TermsDelta's order
+# (``sel.index`` first); the rest of ``sel`` has one entry a UNIQUE selector
+TERM_SLOTS = ("ns_hot", "topo_key", "pod_idx", "weight", "valid")
 
 
 class ClusterTensors(NamedTuple):
@@ -364,42 +372,45 @@ class SnapshotBuilder:
         n_valid = max(float(len(nodes)), 1.0)
         d["image_spread"] = image_nodes / n_valid
 
-        d["filter_terms"] = self._build_terms(filter_owners, kind="filter")
-        d["score_terms"] = self._build_terms(score_owners, kind="score")
         # delta-maintenance metadata (state/delta.py DeltaTensorizer):
         # stable row assignments + per-image node counts, so incremental
-        # updates can start exactly where this build left off
+        # updates can start exactly where this build left off; for each
+        # term table the rows of every owner and the unique row of every
+        # selector key (TermTable)
+        d["_term_rows"] = {}
+        for field, owners in (("filter_terms", filter_owners),
+                              ("score_terms", score_owners)):
+            d[field], d["_term_rows"][field] = self._build_terms(
+                owners, kind=TERM_KINDS[field])
         d["_pod_rows"] = pod_rows
         d["_image_nodes"] = image_nodes
         return HostClusterArrays(arrays=d)
 
-    def _build_terms(self, owners: List[Tuple[PodInfo, int]], kind: str) -> ExistingTerms:
+    def _build_terms(self, owners: List[Tuple[PodInfo, int]], kind: str
+                     ) -> Tuple[ExistingTerms, dict]:
+        """The owners' terms packed in the order given, and what a
+        TermTable keeps such a table by: ``owner_rows`` (uid -> its rows)
+        and ``sel_keys`` (selector key -> unique row)."""
         t = self.table
         NS = t.ns.cap
         sels, nss, topos, pods, weights = [], [], [], [], []
-
-        def add(term, pod_row, weight):
-            sels.append(term.selector)
-            nss.append(term.namespaces)
-            topos.append(t.topokey.get(term.topology_key))
-            pods.append(pod_row)
-            weights.append(float(weight))
-
+        owner_rows: Dict[str, Tuple[int, ...]] = {}
         for pi, row in owners:
-            if kind == "filter":
-                for term in pi.required_anti_affinity_terms:
-                    add(term, row, 1.0)
-            else:
-                for w in pi.preferred_affinity_terms:
-                    add(w.term, row, w.weight)
-                for w in pi.preferred_anti_affinity_terms:
-                    add(w.term, row, -w.weight)
-                if self.hard_pod_affinity_weight:
-                    for term in pi.required_affinity_terms:
-                        add(term, row, self.hard_pod_affinity_weight)
+            at = len(sels)
+            for term, weight in owner_terms(pi, kind,
+                                            self.hard_pod_affinity_weight):
+                sels.append(term.selector)
+                nss.append(term.namespaces)
+                topos.append(t.topokey.get(term.topology_key))
+                pods.append(row)
+                weights.append(weight)
+            if len(sels) > at:
+                owner_rows[pi.pod.uid] = tuple(range(at, len(sels)))
 
         Et = pow2_bucket(len(sels), 1)
-        sel_set = self.compiler.compile(sels + [None] * (Et - len(sels)), pad_s=Et)
+        sel_keys: dict = {}
+        sel_set = self.compiler.compile(sels + [None] * (Et - len(sels)),
+                                        pad_s=Et, keys_out=sel_keys)
         ns_hot = np.zeros((Et, NS), np.float32)
         topo_key = np.zeros((Et,), np.int32)
         pod_idx = np.zeros((Et,), np.int32)
@@ -414,16 +425,221 @@ class SnapshotBuilder:
             pod_idx[i] = pods[i]
             weight[i] = weights[i]
             valid[i] = True
-        return ExistingTerms(sel=sel_set, ns_hot=ns_hot, topo_key=topo_key,
-                             pod_idx=pod_idx, weight=weight, valid=valid)
+        return (ExistingTerms(sel=sel_set, ns_hot=ns_hot, topo_key=topo_key,
+                              pod_idx=pod_idx, weight=weight, valid=valid),
+                {"owner_rows": owner_rows, "sel_keys": sel_keys})
+
+
+def owner_terms(owner, kind: str, hard_pod_affinity_weight: int) -> list:
+    """(term, weight) of the rows an existing pod gives one table, in row
+    order: ``filter`` its required anti-affinity terms (reference:
+    filtering.go:166), ``score`` its preferred terms at their signed
+    weights and its required affinity terms at the hard weight, none at
+    weight 0 (scoring.go:128).  ``owner``: a PodInfo or a TermOwner."""
+    if kind == "filter":
+        return [(term, 1.0) for term in owner.required_anti_affinity_terms]
+    out = [(w.term, float(w.weight)) for w in owner.preferred_affinity_terms]
+    out += [(w.term, -float(w.weight))
+            for w in owner.preferred_anti_affinity_terms]
+    if hard_pod_affinity_weight:
+        out += [(term, float(hard_pod_affinity_weight))
+                for term in owner.required_affinity_terms]
+    return out
+
+
+def term_slots(terms: ExistingTerms) -> tuple:
+    """The per-row leaves of a term table, ``sel.index`` first: what a
+    TermsDelta scatters into."""
+    return (terms.sel.index,) + tuple(getattr(terms, f) for f in TERM_SLOTS)
+
+
+def with_term_slots(terms: ExistingTerms, slots) -> ExistingTerms:
+    """``terms`` with its per-row leaves replaced (term_slots' order)."""
+    return terms._replace(sel=terms.sel._replace(index=slots[0]),
+                          **dict(zip(TERM_SLOTS, slots[1:])))
+
+
+class TermsDelta(NamedTuple):
+    """The rows of ONE term table that a cycle wrote (appended or
+    tombstoned), gathered from the mirror after the writes: applied on
+    device by models/programs.py apply_terms_delta with
+    ``x.at[rows].set(..., mode="drop")``.  ``rows`` is padded to its
+    bucket with the table's row count, one past capacity, which "drop"
+    discards (ClusterDelta says why not -1)."""
+    rows: np.ndarray               # [Dt] i32 (pad = Et: dropped)
+    sel_index: np.ndarray          # [Dt] i32
+    ns_hot: np.ndarray             # [Dt, NS] f32
+    topo_key: np.ndarray           # [Dt] i32
+    pod_idx: np.ndarray            # [Dt] i32
+    weight: np.ndarray             # [Dt] f32
+    valid: np.ndarray              # [Dt] bool
+
+
+def gather_terms_delta(terms: ExistingTerms, rows, floor: int) -> TermsDelta:
+    """Slice ``rows`` (no duplicates) of a mirror table into a TermsDelta
+    of at least ``floor`` rows, a power of two."""
+    rows = np.asarray(rows, np.intp)
+    Dt = pow2_bucket(len(rows), floor)
+    r = np.full((Dt,), terms.valid.shape[0], np.int32)
+    r[:len(rows)] = rows
+
+    def g(arr):
+        out = np.zeros((Dt,) + arr.shape[1:], arr.dtype)
+        out[:len(rows)] = arr[rows]
+        return out
+    return TermsDelta(r, *map(g, term_slots(terms)))
+
+
+class TermTable:
+    """ONE existing-term table of the host mirror (``arrays[field]``) kept
+    by ROW between builds: an owner that comes compiles ITS terms into
+    free rows (lowest first, as pod rows are handed out), one that goes
+    tombstones its rows, every leaf back to what a build's padding row
+    holds.  So the table is no function of the ORDER owners are walked in:
+    it holds the same multiset of valid rows as a fresh ``_build_terms``
+    (each row read through its selector's requirement content and its
+    owner's uid, not through ``sel.index`` and ``pod_idx``), and every
+    other row is a padding row.  Every consumer masks by ``valid``
+    (ops/kernels.py existing_terms_match, _owner_pairs) and sums integer
+    weights in f32, so row order moves no placement.
+
+    ``Et`` is the pow2 bucket of the high-water row and never shrinks;
+    the unique-selector leaves grow a row for a selector key not seen
+    since the build, and nothing is ever taken out of them.  A build
+    (the DeltaTensorizer's resync) is what packs the table again."""
+
+    def __init__(self, builder: SnapshotBuilder, arrays: dict, field: str):
+        meta = arrays["_term_rows"][field]
+        self.builder = builder
+        self.arrays = arrays
+        self.field = field
+        self.kind = TERM_KINDS[field]
+        self.owner_rows: Dict[str, Tuple[int, ...]] = meta["owner_rows"]
+        self.sel_keys: dict = meta["sel_keys"]
+        self.live = self.high = sum(map(len, self.owner_rows.values()))
+        self.free: List[int] = []            # kept sorted, pop lowest
+        self._whole = False                  # update()'s, set as it grows
+
+    @property
+    def terms(self) -> ExistingTerms:
+        return self.arrays[self.field]
+
+    def update(self, went, came) -> Tuple[Optional[np.ndarray], int]:
+        """Tombstone the rows of the owners ``went`` (uids), then compile
+        the owners that ``came`` (TermOwners) into free rows, all with one
+        fancy-indexed assignment a leaf.  Returns (the rows written, None
+        where the table changed SHAPE or gained a unique selector and has
+        to cross to the device whole; rows tombstoned + rows appended)."""
+        t = self.builder.table
+        hw = self.builder.hard_pod_affinity_weight
+        self._whole = False
+        tomb = [r for uid in went for r in self.owner_rows.pop(uid, ())]
+        sel_u, topos, pods, weights, ns_at, ns_id = [], [], [], [], [], []
+        counts = []
+        for o in came:
+            at = len(sel_u)
+            for term, weight in owner_terms(o, self.kind, hw):
+                key = selector_key(term.selector)
+                u = self.sel_keys.get(key, -1)
+                if u < 0:
+                    u = self._add_unique(key)
+                for ns in term.namespaces:
+                    j = t.ns.get(ns)
+                    if j >= 0:
+                        ns_at.append(len(sel_u))
+                        ns_id.append(j)
+                sel_u.append(u)
+                topos.append(max(t.topokey.get(term.topology_key), 0))
+                pods.append(o.row)
+                weights.append(weight)
+            if len(sel_u) > at:
+                counts.append((o.uid, len(sel_u) - at))
+        if tomb:
+            nil = self._nil()
+            for leaf in term_slots(self.terms):
+                leaf[tomb] = 0
+            self.terms.sel.index[tomb] = nil
+            self.free.extend(tomb)
+            self.free.sort()
+        n = len(sel_u)
+        rows = self.free[:n]
+        del self.free[:n]
+        if n > len(rows):
+            top = self.high + n - len(rows)
+            rows.extend(range(self.high, top))
+            self.high = top
+            if top > self.terms.valid.shape[0]:
+                self._grow_rows(pow2_bucket(top, 1))
+        if n:
+            terms = self.terms
+            at = np.asarray(rows, np.intp)
+            terms.sel.index[at] = sel_u
+            terms.ns_hot[at[ns_at], ns_id] = 1.0
+            terms.topo_key[at] = topos
+            terms.pod_idx[at] = pods
+            terms.weight[at] = weights
+            terms.valid[at] = True
+            k = 0
+            for uid, c in counts:
+                self.owner_rows[uid] = tuple(rows[k:k + c])
+                k += c
+        self.live += n - len(tomb)
+        written = (None if self._whole
+                   else np.union1d(tomb, rows).astype(np.intp))
+        return written, len(tomb) + n
+
+    def _nil(self) -> int:
+        """The unique row of the selector that matches nothing, which
+        padding rows name: a build that filled its bucket compiled none."""
+        nil = self.sel_keys.get(None, -1)
+        return nil if nil >= 0 else self._add_unique(None)
+
+    def _add_unique(self, key: Optional[tuple]) -> int:
+        """A unique-selector row for a key the table has not compiled
+        since its build: the next row of the unique leaves, which grow to
+        the next bucket of U (rows) or Q (requirements) where it does not
+        fit.  The device holds no such row yet and the scatter carries
+        none: the table crosses whole."""
+        self._whole = True
+        terms = self.terms
+        sel = terms.sel
+        u = len(self.sel_keys)
+        U, Q = sel.req_valid.shape
+        need = (pow2_bucket(u + 1, 1), max(Q, pow2_bucket(len(key or ()), 2)))
+        if need != (U, Q):
+            def pad(x):
+                width = [(0, need[0] - U)] + [(0, 0)] * (x.ndim - 1)
+                if x.ndim > 1:
+                    width[1] = (0, need[1] - Q)
+                return np.pad(x, width)
+            sel = SelectorSet(index=sel.index, **{
+                f: pad(getattr(sel, f)) for f in SelectorSet._fields
+                if f != "index"})
+            self.arrays[self.field] = terms._replace(sel=sel)
+        self.builder.compiler.fill_unique(sel, u, key)
+        self.sel_keys[key] = u
+        return u
+
+    def _grow_rows(self, Et: int) -> None:
+        """Pad the per-row leaves with padding rows up to ``Et``."""
+        self._whole = True
+        nil = self._nil()
+        terms = self.terms
+        n = Et - terms.valid.shape[0]
+        slots = [np.concatenate([x, np.zeros((n,) + x.shape[1:], x.dtype)])
+                 for x in term_slots(terms)]
+        slots[0][-n:] = nil
+        self.arrays[self.field] = with_term_slots(terms, slots)
 
 
 # --------------------------------------------------------------------------
 # Per-row fills, shared by SnapshotBuilder.build (the from-scratch walk) and
 # state/delta.py DeltaTensorizer (the incremental path).  Bit-exactness
-# contract: filling a row through these helpers produces byte-identical
-# arrays to a fresh build of the same NodeInfo against the same InternTable,
-# so delta-maintained tensors never drift from a rebuild.
+# contract: filling a node or pod row through these helpers produces
+# byte-identical arrays to a fresh build of the same NodeInfo against the
+# same InternTable, so delta-maintained tensors never drift from a rebuild.
+# The two term tables are kept by row (TermTable above) and equal a fresh
+# build's as MULTISETS of valid rows, every other row a padding row.
 
 
 def fill_node_static(d: dict, n_idx: int, ni: NodeInfo,
@@ -534,8 +750,8 @@ def vocab_signature(table: InternTable) -> tuple:
 
 def pod_has_terms(pi: PodInfo, hard_pod_affinity_weight: int = 1) -> bool:
     """True when this existing pod contributes rows to filter_terms or
-    score_terms — the delta path resyncs when such a pod churns, because
-    the flattened term tensors are only rebuilt on a full build()."""
+    score_terms: a term OWNER, whose coming or going the delta path
+    writes into the two tables by row (TermTable)."""
     return bool(pi.required_anti_affinity_terms
                 or pi.preferred_affinity_terms
                 or pi.preferred_anti_affinity_terms

@@ -82,7 +82,10 @@ RECORD_VERSION = 1
 #           anchor: initial build, anti-entropy, vocab growth, pod-axis
 #           growth, verify-divergence)
 #   delta   payload = pickled (ClusterDelta, terms-or-None) applied to the
-#           previous record's cluster by programs.apply_cluster_delta
+#           previous record's cluster by programs.apply_cluster_delta;
+#           terms = (the term tables that crossed whole, by field; one
+#           TermsDelta a table: the rows written), applied first by
+#           programs.apply_terms_delta
 #   chain   payload = (pad_pods, pad_terms): the cluster is the PREVIOUS
 #           record's auction materialized at these pow2 pad buckets
 #           (models/gang.materialize_assigned, extend_score_terms=True)

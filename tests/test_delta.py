@@ -5,7 +5,8 @@
     ``SnapshotBuilder.build()`` of the same NodeInfos against the same
     InternTable, up to the documented stable-row permutation of the
     existing-pod axis (fresh builds pack pods in node-walk order; the
-    delta path keeps rows stable and reuses freed rows lowest-first);
+    delta path keeps rows stable and reuses freed rows lowest-first) and
+    the term tables as multisets of valid rows beside padding rows;
   * fallback triggers — intern-table growth, term-carrying pod churn,
     node-set changes and pod-axis exhaustion all take the blessed resync
     path and still land on golden state;
@@ -17,10 +18,12 @@
     ONE full build (the initial resync) and scatters the rest.
 """
 
+import collections
 import copy
 import random
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -49,7 +52,9 @@ def assert_matches_fresh(dt: DeltaTensorizer, node_infos) -> None:
     """The golden assertion: the resident device tensors equal a fresh
     build() against a COPY of the persistent intern table (ids fixed),
     bit-for-bit — node axis directly, pod axis under the uid-row
-    permutation, remaining delta rows at build defaults."""
+    permutation, remaining delta rows at build defaults; the term tables
+    as canonical multisets of rows (``term_rows``), every other row a
+    padding row."""
     fresh_b = SnapshotBuilder(
         table=copy.deepcopy(dt.builder.table),
         hard_pod_affinity_weight=dt.hard_pod_affinity_weight)
@@ -74,26 +79,42 @@ def assert_matches_fresh(dt: DeltaTensorizer, node_infos) -> None:
         if r not in used:
             assert not gotp["pod_valid"][r], r
             assert gotp["pod_node"][r] == -1, r
-    # term tensors: owner collection follows the same node-walk order in
-    # both paths, so every leaf matches directly EXCEPT pod_idx, which
-    # points at rows — compare it through the uid permutation
-    import jax
-    inv_d = {r: u for u, r in drow.items()}
-    inv_f = {r: u for u, r in frow.items()}
+    # term tensors: the delta path keeps them by ROW (an owner's rows stay
+    # where they were put, a departed owner's are tombstoned), a build
+    # packs them in node-walk order: the same MULTISET of valid rows, each
+    # read through what its ids stand for, and every other row padding
     for kind in ("filter_terms", "score_terms"):
         dterm, fterm = getattr(got, kind), getattr(fresh, kind)
-        for leaf in ("ns_hot", "topo_key", "weight", "valid"):
-            a = np.asarray(getattr(dterm, leaf))
-            b = np.asarray(getattr(fterm, leaf))
-            assert np.array_equal(a, b), (kind, leaf)
-        for a, b in zip(jax.tree.leaves(dterm.sel),
-                        jax.tree.leaves(fterm.sel)):
-            assert np.array_equal(np.asarray(a), np.asarray(b)), (kind,
-                                                                  "sel")
-        dp, fp = np.asarray(dterm.pod_idx), np.asarray(fterm.pod_idx)
-        valid = np.asarray(dterm.valid)
-        for i in np.nonzero(valid)[0]:
-            assert inv_d[int(dp[i])] == inv_f[int(fp[i])], (kind, i)
+        assert term_rows(dterm, drow) == term_rows(fterm, frow), kind
+        assert_padding_rows(dterm, kind)
+
+
+def term_rows(terms, pod_row) -> collections.Counter:
+    """The valid rows of a term table as a multiset of (the selector's
+    requirement content, namespaces, topology key, owner uid, weight):
+    nothing of a row's place, its ``sel.index`` or its ``pod_idx``."""
+    uid_of = {r: u for u, r in pod_row.items()}
+    sel = jax.tree.map(np.asarray, terms.sel)
+    uniq = [tuple(leaf[u].tobytes() for leaf in sel[:-1])
+            for u in range(sel.sel_valid.shape[0])]
+    ns_hot, topo_key, pod_idx, weight, valid = (
+        np.asarray(getattr(terms, f))
+        for f in ("ns_hot", "topo_key", "pod_idx", "weight", "valid"))
+    return collections.Counter(
+        (uniq[sel.index[i]], ns_hot[i].tobytes(), int(topo_key[i]),
+         uid_of[int(pod_idx[i])], float(weight[i]))
+        for i in np.nonzero(valid)[0])
+
+
+def assert_padding_rows(terms, kind="") -> None:
+    """Every row that is not valid holds what a build's padding row holds:
+    zeros, and a selector that matches nothing."""
+    pad = ~np.asarray(terms.valid)
+    for f in ("ns_hot", "topo_key", "pod_idx", "weight"):
+        assert not np.asarray(getattr(terms, f))[pad].any(), (kind, f)
+    nil = np.asarray(terms.sel.index)[pad]
+    assert not np.asarray(terms.sel.sel_valid)[nil].any(), kind
+    assert not np.asarray(terms.sel.req_valid)[nil].any(), kind
 
 
 def build_cache(n_nodes=6, pods_per_node=2, zones=3):
@@ -236,7 +257,7 @@ class OwnerWorld:
     label value the steps use is in the vocab from the first build, so no
     step crosses a cap."""
 
-    def __init__(self, n_nodes=6, hard_pod_affinity_weight=1):
+    def __init__(self, n_nodes=6, hard_pod_affinity_weight=1, mesh=None):
         self.cache = SchedulerCache()
         self.nodes = hollow.make_nodes(n_nodes, zones=3)
         self.owners, self.plains = [], []
@@ -248,7 +269,7 @@ class OwnerWorld:
                                tier="ab"[i % 2]))
         self.add(owner_pod("own-second", self.nodes[-1].name, "red"))
         self.dt = DeltaTensorizer(
-            hard_pod_affinity_weight=hard_pod_affinity_weight)
+            hard_pod_affinity_weight=hard_pod_affinity_weight, mesh=mesh)
         self.seq = 0
         _, st = self.dt.refresh(snapshot_of(self.cache))
         assert st.resync
@@ -300,8 +321,9 @@ class OwnerWorld:
     def refresh(self, expect=None):
         """One refresh, held to a from-scratch build() leaf for leaf; and
         to what the step expects of the term tables: ``kept`` (a dirty
-        node held an owner, nothing of the term refresh ran), ``rebuilt``,
-        or ``untouched`` (no dirty node held an owner)."""
+        was written to tables that hold live rows, nothing of the term
+        update ran), ``rebuilt`` (rows were written: an owner came or
+        went), or ``untouched`` (the tables hold no live row)."""
         infos = snapshot_of(self.cache)
         _, st = self.dt.refresh(infos)
         assert_matches_fresh(self.dt, infos)
@@ -367,7 +389,9 @@ def _two_owners_swap_order(w):
     last = w.nodes[-1].name
     first = next(p for p in w.owners if p.spec.node_name == last)
     w.move(first, last)                # same node, same row: now walked last
-    yield "rebuilt"
+    # until PR 47 the tables were a function of the ORDER the owners are
+    # walked in and this rebuilt them; kept by row, nothing is written
+    yield "kept"
     w.add(plain_pod("after", last))
     yield "kept"
 
@@ -431,7 +455,9 @@ def _plain_pod_on_a_node_without_owner(w):
     w.remove(w.owners[0])
     yield "rebuilt"
     w.add(plain_pod("after", w.nodes[0].name))
-    yield "untouched"
+    # no owner on the dirty node; the tables' live rows (the other nodes'
+    # owners') are kept all the same
+    yield "kept"
 
 
 OWNER_CHANGES = [
@@ -740,18 +766,24 @@ def test_no_marker_survives_a_resync(reason):
                                     if r != "pod-axis-growth"])
 def test_the_golden_fails_when_a_resync_keeps_the_markers(reason,
                                                           monkeypatch):
-    """The mutation: _resync() leaves ``pod_src`` / ``node_src`` as they
-    were.  A rebuild packs the pod rows anew, so a kept owner would hand
-    the term tables the row it had BEFORE.  (pod-axis growth does not
-    pass through _resync: it moves no row and no id, and the markers the
-    same refresh noted stand.)"""
+    """The mutation: _resync() leaves ``pod_src`` / ``node_src`` and the
+    term tables' rows by owner (``term_tables``) as they were.  A rebuild
+    packs the term rows anew in a new mirror, so an owner that goes would
+    tombstone the rows it had BEFORE, in the mirror of before.  (The two
+    markers alone fail nothing since PR 47: a stale ``pod_src`` makes an
+    owner replaced in place an owner gone and an owner come, which writes
+    the rows it would have kept.  pod-axis growth does not pass through
+    _resync: it moves no row and no id, and the markers the same refresh
+    noted stand.)"""
     orig = DeltaTensorizer._resync
 
     def keeps(self, *a, **kw):
         pod_src, node_src = self.pod_src, self.node_src
+        term_tables = self.term_tables
         out = orig(self, *a, **kw)
         if pod_src:                       # not the initial build
             self.pod_src, self.node_src = pod_src, node_src
+            self.term_tables = term_tables
         return out
     monkeypatch.setattr(DeltaTensorizer, "_resync", keeps)
     with pytest.raises(AssertionError):
@@ -836,6 +868,212 @@ def test_randomized_owner_churn_stays_golden(hw):
             assert st.reason in ("pod-axis-growth", "anti-entropy"), \
                 st.reason
     assert seen["kept"] >= 10 and seen["rebuilt"] >= 10, seen
+
+
+# ---------------------------------------------------------------------------
+# the term tables are kept by ROW (PR 47): what a row's life looks like from
+# the tensorizer's own books, each refresh still held to a fresh build()
+
+
+def _said(st):
+    return st.span_args["delta-terms"]
+
+
+def _rows_of(w, p, field="filter_terms"):
+    return w.dt.term_tables[field].owner_rows.get(p.uid, ())
+
+
+def _a_tombstoned_row_is_reused_by_the_next_arrival(w):
+    gone = w.owners[0]                                   # green: one row
+    row, = _rows_of(w, gone)
+    w.remove(gone)
+    st, _ = w.refresh("rebuilt")
+    assert (_said(st)["rows_written"], _said(st)["rows_free"]) == (1, 1)
+    assert w.dt.term_tables["filter_terms"].free == [row]
+    assert not np.asarray(w.dt.cluster.filter_terms.valid)[row]
+    late = w.add(owner_pod("late-green", w.nodes[3].name, "green"))
+    st, _ = w.refresh("rebuilt")
+    assert _rows_of(w, late) == (row,)
+    assert (_said(st)["rows_written"], _said(st)["rows_free"]) == (1, 0)
+    # gone and come in ONE refresh: the row is tombstoned and taken again
+    w.remove(late)
+    again = w.add(owner_pod("again-green", w.nodes[1].name, "green"))
+    st, _ = w.refresh("rebuilt")
+    assert _rows_of(w, again) == (row,)
+    assert (_said(st)["rows_written"], _said(st)["owners_changed"]) == (2, 2)
+
+
+def _an_owner_with_several_terms(w):
+    p = owner_pod("many", w.nodes[2].name, "both")       # filter + score
+    hollow.with_anti_affinity(p, topo_key=api.LABEL_ZONE,
+                              match={"color": "red"})
+    hollow.with_anti_affinity(p, match={"tier": "a"})
+    w.add(p)
+    f0 = w.dt.term_tables["filter_terms"].live
+    s0 = w.dt.term_tables["score_terms"].live
+    st, _ = w.refresh("rebuilt")
+    assert len(_rows_of(w, p)) == 3
+    assert len(_rows_of(w, p, "score_terms")) == 1
+    assert (_said(st)["filter_rows"], _said(st)["score_rows"],
+            _said(st)["rows_written"], _said(st)["owners_changed"]) \
+        == (f0 + 3, s0 + 1, 4, 1)
+    w.remove(p)
+    st, _ = w.refresh("rebuilt")
+    assert (_said(st)["filter_rows"], _said(st)["score_rows"],
+            _said(st)["rows_written"], _said(st)["rows_free"]) \
+        == (f0, s0, 4, 4)
+    assert not _rows_of(w, p) and not _rows_of(w, p, "score_terms")
+
+
+def _a_new_unique_selector(w):
+    tt = w.dt.term_tables["filter_terms"]
+    # the build's two green rows fill their bucket: the first arrival
+    # grows it, and with it comes the nil selector that padding rows name
+    w.add(owner_pod("grows-Et", w.nodes[0].name, "green"))
+    w.refresh("rebuilt")
+    u0 = len(tt.sel_keys)
+    U0 = tt.terms.sel.sel_valid.shape[0]
+    seen = w.add(owner_pod("seen", w.nodes[0].name, "green"))
+    st, _ = w.refresh("rebuilt")
+    assert _said(st)["wholesale"] == 0 and len(tt.sel_keys) == u0
+    added = 0
+    while tt.terms.sel.sel_valid.shape[0] == U0:         # until U grows
+        p = owner_pod(w.fresh_name("new-sel"), w.nodes[1].name, "green")
+        # a selector over values the vocab holds: no cap is crossed
+        hollow.with_anti_affinity(p, match={
+            "color": COLORS[added % 4], "tier": "ab"[added // 4]})
+        w.add(p)
+        added += 1
+        st, _ = w.refresh("rebuilt")
+        assert not st.resync, st.reason
+        # a selector the table has not seen crosses whole, inside U too
+        assert _said(st)["wholesale"] == 1
+        assert len(tt.sel_keys) == u0 + added
+    assert tt.terms.sel.sel_valid.shape[0] == 2 * U0
+    assert w.dt.cluster.filter_terms.sel.sel_valid.shape[0] == 2 * U0
+    w.remove(seen)
+    st, _ = w.refresh("rebuilt")
+    assert _said(st)["wholesale"] == 0
+
+
+def _Et_crosses_its_bucket(w):
+    tt = w.dt.term_tables["filter_terms"]
+    assert tt.high == tt.terms.valid.shape[0] == 2      # the build's: full
+    w.add(owner_pod("grows-Et", w.nodes[0].name, "green"))
+    st, _ = w.refresh("rebuilt")
+    assert (_said(st)["Et"], _said(st)["wholesale"]) == (4, 1)
+    Et0 = 4
+    for k in range(Et0 - tt.high):
+        w.add(owner_pod(f"fill-{k}", w.nodes[k % 6].name, "green"))
+    st, _ = w.refresh("rebuilt")
+    assert (_said(st)["Et"], _said(st)["wholesale"]) == (Et0, 0)
+    assert tt.high == tt.live == Et0
+    w.add(owner_pod("one-more", w.nodes[0].name, "green"))
+    st, _ = w.refresh("rebuilt")
+    assert not st.resync
+    assert (_said(st)["Et"], _said(st)["wholesale"],
+            _said(st)["filter_rows"]) == (2 * Et0, 1, Et0 + 1)
+    assert w.dt.cluster.filter_terms.valid.shape[0] == 2 * Et0
+    # never shrinking: the owners go, the bucket stays until a resync
+    for p in [p for p in w.owners if p.metadata.name.startswith("fill-")]:
+        w.remove(p)
+    st, _ = w.refresh("rebuilt")
+    assert (_said(st)["Et"], _said(st)["wholesale"]) == (2 * Et0, 0)
+    assert _said(st)["rows_free"] == tt.high - tt.live == 1
+
+
+def _a_resync_after_tombstones_packs_again(w):
+    for p in [w.owners[0], w.owners[4]]:                 # green, green
+        w.remove(p)
+    p = owner_pod("dead-selector", w.nodes[1].name, "green")
+    hollow.with_anti_affinity(p, match={"color": "red", "tier": "b"})
+    w.add(p)
+    w.refresh("rebuilt")
+    w.remove(p)
+    st, _ = w.refresh("rebuilt")
+    tt = w.dt.term_tables["filter_terms"]
+    assert tt.free and tt.high > tt.live
+    dead = len(tt.sel_keys)
+    w.dt.cycles_since_resync = w.dt.resync_interval
+    w.add(plain_pod("tick", w.nodes[3].name))
+    st, _ = w.refresh("resync")
+    assert st.reason == "anti-entropy"
+    for field, tt in w.dt.term_tables.items():
+        valid = np.asarray(getattr(w.dt.cluster, field).valid)
+        assert tt.free == [] and tt.high == tt.live == int(valid.sum())
+        assert valid[:tt.live].all()                     # packed again
+        assert sorted(r for rows in tt.owner_rows.values() for r in rows) \
+            == list(range(tt.live))
+    # and the selector no owner names any more is gone with them
+    assert len(w.dt.term_tables["filter_terms"].sel_keys) < dead
+    late = w.add(owner_pod("late", w.nodes[0].name, "green"))
+    w.refresh("rebuilt")
+    assert _rows_of(w, late) == (w.dt.term_tables["filter_terms"].live - 1,)
+
+
+def _a_placement_equal_on_the_kept_and_the_fresh_tables(w):
+    from kubetpu.framework.types import PodInfo
+    from kubetpu.models import programs
+    from kubetpu.models.batch import PodBatchBuilder
+    # scramble the rows: owners of every colour go and come
+    for p in [w.owners[0], w.owners[1], w.owners[3]]:
+        w.remove(p)
+    w.refresh("rebuilt")
+    for k, kind in enumerate(("yellow", "blue", "green", "red", "both")):
+        w.add(owner_pod(f"mix-{k}", w.nodes[(2 * k) % 6].name, kind))
+    w.remove(w.owners[0])
+    w.refresh("rebuilt")
+    pending = []
+    for k, color in enumerate(COLORS + COLORS):
+        p = hollow.make_pod(f"pending-{k}")
+        p.metadata.labels = {"color": color, "tier": "ab"[k % 2]}
+        pending.append(PodInfo(p))
+    infos = snapshot_of(w.cache)
+    _, st = w.dt.refresh(infos, pending=pending)
+    assert not st.resync
+    fresh = SnapshotBuilder(
+        table=copy.deepcopy(w.dt.builder.table)).build(infos).to_device()
+    batch = jax.tree.map(np.asarray,
+                         PodBatchBuilder(w.dt.builder.table).build(pending))
+    cfg = programs.ProgramConfig(
+        filters=("InterPodAffinity",), scores=(("InterPodAffinity", 1),),
+        hostname_topokey=max(
+            w.dt.builder.table.topokey.get(api.LABEL_HOSTNAME), 0))
+    kept = programs.filter_and_score(w.dt.cluster, batch, cfg)
+    built = programs.filter_and_score(fresh, batch, cfg)
+    for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(built)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    # the tables decide something: a node is refused, a score differs
+    feasible = np.asarray(kept.feasible)[:len(pending), :len(w.nodes)]
+    assert feasible.any() and not feasible.all()
+    assert len(np.unique(np.asarray(kept.scores)[:len(pending)])) > 1
+
+
+TERM_ROWS = [_a_tombstoned_row_is_reused_by_the_next_arrival,
+             _an_owner_with_several_terms, _a_new_unique_selector,
+             _Et_crosses_its_bucket, _a_resync_after_tombstones_packs_again,
+             _a_placement_equal_on_the_kept_and_the_fresh_tables]
+
+
+@pytest.mark.parametrize("case", TERM_ROWS, ids=_ids(TERM_ROWS))
+def test_the_term_tables_are_kept_by_row(case):
+    case(OwnerWorld())
+
+
+@pytest.mark.parametrize("case", TERM_ROWS[:5], ids=_ids(TERM_ROWS[:5]))
+def test_the_term_tables_are_kept_by_row_on_a_mesh(case):
+    """The same lives of a row with the resident cluster sharded over a
+    2 x 4 mesh: the tables ride replicated, the written rows are
+    replicated to them and scattered by the same program, a table that
+    crosses whole is replicated again; the verifier's per-leaf sums of
+    device and mirror agree after the last refresh."""
+    from kubetpu.parallel import mesh as pmesh
+    w = OwnerWorld(n_nodes=8, mesh=pmesh.make_mesh((2, 4)))
+    case(w)
+    for leaf in jax.tree.leaves((w.dt.cluster.filter_terms,
+                                 w.dt.cluster.score_terms)):
+        assert leaf.sharding.is_fully_replicated
+    assert w.dt.verify()
 
 
 # ---------------------------------------------------------------------------
@@ -1020,8 +1258,8 @@ def test_anti_entropy_resync_interval():
 
 # ---------------------------------------------------------------------------
 # a refresh costs what CHANGED (PR 44): at thirty pods a node it visits the
-# arrivals, sends the rows it refilled or cleared and no other, and walks a
-# node's pods only where an owner came or went
+# arrivals, sends the rows it refilled or cleared and no other, and (PR 47)
+# walks no node's pods, an owner's coming or going included
 
 
 class DenseWorld:
@@ -1078,8 +1316,6 @@ class DenseWorld:
         infos = snapshot_of(self.cache)
         dirty = [ni for ni in infos
                  if ni.generation != self.dt.node_gen.get(ni.node_name)]
-        owned_before = {ni.node_name for ni in dirty
-                        if self.dt.node_owners.get(ni.node_name)}
         _, st = self.dt.refresh(infos)
         assert_matches_fresh(self.dt, infos)
         uids = self.dt.pod_uid_list()
@@ -1095,11 +1331,7 @@ class DenseWorld:
         assert st.delta_rows == len(dirty) + len(rows)
         if not st.resync:       # growth re-uploads: nothing is gathered
             assert self.sent == [sorted(rows)]
-        with_owners = sum(
-            len(ni.pods) for ni in dirty
-            if ni.node_name in owned_before
-            or self.dt.node_owners[ni.node_name])
-        assert said["pods_walked"] <= len(self.filled) + with_owners
+        assert said["pods_walked"] == len(self.filled)
         return st
 
 
@@ -1148,8 +1380,10 @@ def _an_owner_arrives_where_none_was_and_leaves(w):
     owner = w.arrive(4, owner_pod("late-owner", kind="green"))
     st = yield
     assert "delta-terms" in st.span_args
-    # the owner's node is read for its owners: its thirty and the owner
-    assert st.span_args["delta-build"]["pods_walked"] == w.PER_NODE + 1
+    # the owner alone: until PR 47 its node's thirty were read as well,
+    # for the node's ordered owners
+    assert st.span_args["delta-build"]["pods_walked"] == 1
+    assert st.span_args["delta-terms"]["rows_written"] == 1
     w.arrive(4)
     w.arrive(5)
     st = yield
@@ -1161,8 +1395,9 @@ def _an_owner_arrives_where_none_was_and_leaves(w):
     w.arrive(5)
     st = yield
     assert st.span_args["delta-terms"]["owners_changed"] == 1
-    assert st.span_args["delta-build"]["pods_walked"] == w.PER_NODE + 1 + 1
-    assert w.dt.node_owners[w.nodes[4].name] == ()
+    assert st.span_args["delta-build"]["pods_walked"] == 1
+    assert all(owner.uid not in tt.owner_rows and not tt.live
+               for tt in w.dt.term_tables.values())
     w.arrive(4)
     st = yield
     said = st.span_args["delta-build"]
@@ -1210,9 +1445,8 @@ CHANGES = [_one_in_one_out, _replaced_on_its_node_under_its_uid,
 def test_a_refresh_visits_and_sends_what_changed(steps, monkeypatch):
     """After EVERY refresh the resident tensors are a fresh build()'s,
     ``pod_rows_seen`` is the rows refilled or cleared (and they are what
-    ``gather_delta`` was given), and ``pods_walked`` is at most the
-    arrivals and the pods of the dirty nodes that hold owners: never the
-    thirty of a node where only plain pods came and went."""
+    ``gather_delta`` was given), and ``pods_walked`` is the arrivals:
+    never the thirty of a node, with or without an owner among them."""
     w = DenseWorld(monkeypatch)
     gen = steps(w)
     next(gen)

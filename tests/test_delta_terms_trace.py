@@ -1,11 +1,14 @@
-"""What the term refresh says about itself (PR 29): the ``delta-terms``
-span's args (rows rebuilt, their buckets, pods walked, owners changed),
-the ``delta-terms-upload`` span around the wholesale replacement on the
-device, and the ``(Et, Es)`` the auction ran with on the cycle's meta.
-Since PR 30 the refresh runs only when a dirty node's owners changed: a
-kept cycle carries neither span and says ``terms_kept`` 1 on its
-``delta-build``; the journal, the replay rig and a pipelined drain see
-the kept tables as they saw a cycle without owners."""
+"""What the term update says about itself (PR 29): the ``delta-terms``
+span's args (the tables' live rows, their buckets, pods walked, owners
+changed; since PR 47 the rows written, the rows free and whether a table
+crossed whole), the ``delta-terms-upload`` span around the transfer and
+dispatch of the written rows, and the ``(Et, Es)`` the auction ran with
+on the cycle's meta.  Since PR 30 the update runs only when an owner came
+or went: a kept cycle carries neither span and says ``terms_kept`` 1 on
+its ``delta-build``; the journal, the replay rig and a pipelined drain
+see the kept tables as they saw a cycle without owners.  Since PR 47 the
+tables are kept by ROW: the journal captures the written rows, the replay
+rig scatters them, and a steady drain runs one variant of that scatter."""
 
 import copy
 import pickle
@@ -18,16 +21,18 @@ from kubetpu.apis.config import (KubeSchedulerConfiguration,
                                  KubeSchedulerProfile)
 from kubetpu.client.store import ClusterStore
 from kubetpu.harness import hollow
+from kubetpu.models import programs
 from kubetpu.scheduler import Scheduler
 from kubetpu.state.cache import SchedulerCache, Snapshot
 from kubetpu.state.delta import DeltaTensorizer
 from kubetpu.utils import journal as ujournal
+from kubetpu.utils import sanitize
 from kubetpu.utils import trace as utrace
 from kubetpu.utils.journal import read_records
 from tools.kubereplay import replay_journal
 
 ARGS = {"filter_rows", "score_rows", "Et", "Es", "pods_walked",
-        "owners_changed"}
+        "owners_changed", "rows_written", "rows_free", "wholesale"}
 
 
 def _owner(name, node="", preferred=False):
@@ -105,17 +110,23 @@ def test_a_refresh_says_what_it_rebuilt_and_how_many_owners_changed():
     extra = _owner("green-extra", nodes[1].name)
     cache.add_pod(extra)
     got = refresh()
-    # walked: an owner came, so the dirty node's pods (its green, its
-    # red, the new one) are read for their owners
+    # walked: the arrival alone (until PR 47 the dirty node's three pods,
+    # read for their owners).  Its one row is appended to the six live
+    # ones, at the first of the build's two padding rows
     assert got == {"filter_rows": 7, "score_rows": 3, "Et": 8, "Es": 4,
-                   "pods_walked": 3, "owners_changed": 1}
+                   "pods_walked": 1, "owners_changed": 1,
+                   "rows_written": 1, "rows_free": 0, "wholesale": 0}
     cache.remove_pod(extra)
     extra.spec.node_name = nodes[2].name
     cache.add_pod(extra)
-    assert refresh()["owners_changed"] == 1
+    got = refresh()
+    # one uid, gone and come: its row tombstoned and taken again
+    assert (got["owners_changed"], got["rows_written"], got["rows_free"],
+            got["wholesale"]) == (1, 2, 0, 0)
     cache.remove_pod(extra)
     got = refresh()
-    assert (got["filter_rows"], got["owners_changed"]) == (6, 1)
+    assert (got["filter_rows"], got["owners_changed"], got["rows_written"],
+            got["rows_free"], got["Et"]) == (6, 1, 1, 1, 8)
     # nothing dirty: no refresh, nothing to say
     _, st = dt.refresh(_snapshot(cache))
     assert st.delta_rows == 0 and st.span_args == {}
@@ -284,17 +295,20 @@ def test_the_cycle_record_carries_the_refreshs_args_and_the_term_buckets(
     assert records[3]["meta"]["term_buckets"] == [args["Et"], args["Es"]]
     assert (args["filter_rows"], args["score_rows"], args["Et"], args["Es"],
             args["owners_changed"]) == (12, 13, 16, 16, 1)
-    # the pods of the node the owner came to (its two and the owner: a
-    # batch of one), not the departure's node's and not the cluster's 38
-    assert args["pods_walked"] == 3
+    # the owner that came (a batch of one), not its node's three, not the
+    # departure's node's and not the cluster's 38; its one score row
+    assert args["pods_walked"] == 1
+    assert (args["rows_written"], args["rows_free"], args["wholesale"]) \
+        == (1, 0, 0)
     assert records[4]["meta"]["term_buckets"] == [16, 16]
 
 
 def test_the_journal_captures_no_terms_on_a_kept_cycle_and_replays_it(
         tmp_path):
     """A kept cycle journals ``("delta", (delta, None))`` as a cycle
-    without owners does, a rebuilt one both tables; kubereplay carries the
-    resident tables over the kept records and bit-matches the
+    without owners does, one that wrote term rows the ROWS it wrote (no
+    table whole, one TermsDelta a table); kubereplay carries the resident
+    tables over the kept records, scatters the rows and bit-matches the
     kept-rebuilt-kept window."""
     d = str(tmp_path / "journal")
     ujournal.disarm_journal()
@@ -312,8 +326,14 @@ def test_the_journal_captures_no_terms_on_a_kept_cycle_and_replays_it(
     kept, rebuilt, last = (pickle.loads(recs[i]["input_payload"])[1]
                            for i in (1, 3, 4))
     assert kept is None and last is None
-    ft, st = rebuilt
-    assert (int(ft.valid.sum()), int(st.valid.sum())) == (12, 13)
+    whole, (fd, sd) = rebuilt
+    assert whole == {}
+    # the one score row of the owner that bound, at the table's first
+    # padding row; nothing of the filter table
+    Et = Es = 16
+    assert not (fd.rows < Et).any()
+    assert sd.rows[sd.rows < Es].tolist() == [12]
+    assert sd.valid[:1].tolist() == [True] and not sd.valid[1:].any()
     rep = replay_journal(d)
     assert rep["replayed"] == rep["matched"] == 5 and rep["skipped"] == []
     assert rep["bit_match"] is True and rep["first_divergence"] is None
@@ -381,3 +401,108 @@ def test_a_pipelined_drain_over_owner_nodes_places_as_the_serial_one():
         pod = store.get_pod("default", name)
         if pod.metadata.labels.get("color") == "green":
             assert int(node.rsplit("-", 1)[1]) % 2 == 0, (name, node)
+
+
+def _owner_churn(cache, nodes, rng, cycles):
+    """Owners of both tables come and go, 1-4 a refresh, each table's
+    count level: yields after each refresh's churn is in the cache."""
+    live = []
+    for k in range(6):
+        p = _owner(f"seed-{k}", nodes[k % len(nodes)].name,
+                   preferred=bool(k % 2))
+        cache.add_pod(p)
+        live.append(p)
+    yield
+    for c in range(cycles):
+        gone = [live.pop(rng.randrange(len(live)))
+                for _ in range(rng.randrange(1, 5))]
+        for j, old in enumerate(gone):
+            cache.remove_pod(old)
+            p = _owner(f"churn-{c}-{j}", rng.choice(nodes).name,
+                       preferred=old.spec.affinity.pod_affinity is not None)
+            cache.add_pod(p)
+            live.append(p)
+        yield
+
+
+def _term_leaves(cluster):
+    return jax.tree.leaves((cluster.filter_terms, cluster.score_terms))
+
+
+def test_the_journals_term_capture_is_a_row_delta_and_replays_onto_the_same_tables(
+        tmp_path):
+    """Armed, a refresh that wrote term rows captures ``(whole, deltas)``:
+    the tables that crossed whole (none on a steady cycle) and one
+    TermsDelta a table.  ``tools/kubereplay`` applies the captures in
+    order to the journaled anchor and lands on the tensorizer's own
+    resident tables leaf for leaf, the cycles that crossed a table whole
+    (the bucket of rows outgrown; the first nil selector) included."""
+    import random
+
+    from tools.kubereplay import _apply_delta
+    ujournal.disarm_journal()
+    ujournal.arm_journal(str(tmp_path / "journal"))
+    try:
+        cache = SchedulerCache()
+        nodes = hollow.make_nodes(4, zones=2)
+        for n in nodes:
+            cache.add_node(n)
+        dt = DeltaTensorizer()
+        _, st = dt.refresh(_snapshot(cache))
+        kind, payload = dt.take_capture()
+        assert kind == "resync"
+        resident = pickle.loads(payload).to_device()
+        wholes = rows = 0
+        for _ in _owner_churn(cache, nodes, random.Random(47), 12):
+            _, st = dt.refresh(_snapshot(cache))
+            assert not st.resync, st.reason
+            kind, payload = dt.take_capture()
+            assert kind == "delta"
+            whole, deltas = pickle.loads(payload)[1]
+            assert set(whole) <= {"filter_terms", "score_terms"}
+            assert bool(whole) == bool(
+                st.span_args["delta-terms"]["wholesale"])
+            wholes += bool(whole)
+            # what the cycle sent: the rows it wrote, a row tombstoned and
+            # taken again once
+            sent = sum(int((d.rows < t.valid.shape[0]).sum())
+                       for d, t in zip(deltas, (dt.cluster.filter_terms,
+                                                dt.cluster.score_terms)))
+            assert sent <= st.span_args["delta-terms"]["rows_written"]
+            rows += sent
+            resident = _apply_delta({"input_payload": payload}, resident)
+            for a, b in zip(_term_leaves(resident),
+                            _term_leaves(dt.cluster)):
+                assert a.shape == b.shape and (a == b).all()
+        # both ways were replayed: whole tables early, rows after
+        assert 0 < wholes < 12 and rows > 0
+    finally:
+        ujournal.disarm_journal()
+
+
+def test_a_steady_drain_runs_one_variant_of_the_term_scatter():
+    """Thirty refreshes with 1-4 owners coming and as many going, the
+    tables' shapes settled: the term scatter compiled ONCE, every refresh
+    ran it (the rows' bucket stands on four times the batch's, as the pod
+    rows' does), and no table crossed whole."""
+    import random
+    cache = SchedulerCache()
+    nodes = hollow.make_nodes(4, zones=2)
+    for n in nodes:
+        cache.add_node(n)
+    churn = _owner_churn(cache, nodes, random.Random(7), 30)
+    with sanitize.sanitized() as wd:
+        dt = DeltaTensorizer(resync_interval=1000)
+        dt.refresh(_snapshot(cache))
+        for k, _ in enumerate(churn):
+            _, st = dt.refresh(_snapshot(cache))
+            assert not st.resync, st.reason
+            said = st.span_args["delta-terms"]
+            # the seeds outgrow the empty build's one-row tables, once
+            assert said["wholesale"] == (k == 0)
+            assert said["rows_written"] >= 2
+            assert "delta-terms-upload" in [n for n, _, _ in st.spans]
+        compiles = {k: c for k, c in wd.counts.items()
+                    if programs.TERMS_DELTA_PROGRAM in k[0]}
+        assert sum(compiles.values()) == 1, compiles
+        wd.assert_no_recompilation()

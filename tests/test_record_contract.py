@@ -101,6 +101,8 @@ CONTRACT = [
      "term_rows_rebuilt_per_cycle.sat"),
     ("arg", "delta-terms.score_rows", int,
      "term_rows_rebuilt_per_cycle.sat"),
+    ("arg", "delta-terms.rows_written", int,
+     "term_rows_written_per_cycle.sat"),
     ("arg", "bind-job.cpu_s", NUMBER, "lane_cpu_ms_per_cycle.sat"),
     ("arg", "bind-job.batched", int, "lane_batched_pct.sat"),
     ("event", "xla-compile.seconds", NUMBER, "window_compile_stall_ms.sat"),

@@ -500,6 +500,27 @@ def _apply_delta_shared(w):
     return (programs._apply_cluster_delta_shared, (w.cluster, delta), {})
 
 
+def _terms_delta_args(w):
+    import jax
+
+    from kubetpu.state.tensors import gather_terms_delta, term_slots
+    a = w.host.arrays
+    tables = (a["filter_terms"], a["score_terms"])
+    deltas = tuple(gather_terms_delta(t, [0], 8) for t in tables)
+    return jax.tree.map(jax.numpy.asarray,
+                        tuple(map(term_slots, tables)) + deltas)
+
+
+def _apply_terms_donated(w):
+    from kubetpu.models import programs
+    return (programs._apply_terms_delta_donated, _terms_delta_args(w), {})
+
+
+def _apply_terms_shared(w):
+    from kubetpu.models import programs
+    return (programs._apply_terms_delta_shared, _terms_delta_args(w), {})
+
+
 def _densify_kv(w):
     import jax.numpy as jnp
 
@@ -644,6 +665,15 @@ ENTRIES: List[Entry] = [
     Entry("_apply_cluster_delta",
           "kubetpu.models.programs:_apply_cluster_delta",
           _apply_delta_shared, tag="shared", static_argnames=(),
+          closure_statics=(("donate", "False"),)),
+    Entry("_apply_terms_delta",
+          "kubetpu.models.programs:_apply_terms_delta",
+          _apply_terms_donated, tag="donated", donate_argnums=(0, 1),
+          static_argnames=(),
+          closure_statics=(("donate", "True"),)),
+    Entry("_apply_terms_delta",
+          "kubetpu.models.programs:_apply_terms_delta",
+          _apply_terms_shared, tag="shared", static_argnames=(),
           closure_statics=(("donate", "False"),)),
     Entry("_densify_ids", "kubetpu.state.tensors:_densify_ids",
           _densify_kv, tag="kv", static_argnames=("L",)),

@@ -61,6 +61,11 @@ EXTRA_ROOTS = (
         "axes": {"donate": "donate"},
     },
     {
+        "program": "_apply_terms_delta",
+        "entry": "kubetpu.models.programs:apply_terms_delta",
+        "axes": {"donate": "donate"},
+    },
+    {
         "program": "_apply_delta_body",
         "entry": "kubetpu.parallel.shardmap:apply_cluster_delta_mesh",
         "axes": {"donate": "donate"},

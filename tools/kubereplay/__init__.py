@@ -14,9 +14,10 @@ serving loop maintained them:
 
   * the RESIDENT lineage — ``resync`` records re-upload the journaled
     host mirror (``HostClusterArrays.to_device``), ``delta`` records
-    scatter the journaled ``ClusterDelta`` (and wholesale term
-    replacement) onto it via ``programs.apply_cluster_delta``, ``noop``
-    records leave it untouched;
+    scatter the journaled ``ClusterDelta`` (and the term tables' written
+    rows, ``programs.apply_terms_delta``) onto it via
+    ``programs.apply_cluster_delta``, ``noop`` records leave it
+    untouched;
   * the CHAIN lineage — a ``chain`` record's cluster is the PREVIOUS
     record's replayed auction materialized at the journaled pad buckets
     (``models/gang.materialize_assigned``, ``extend_score_terms=True``).
@@ -136,16 +137,22 @@ def _materialize_chain(rec: Dict[str, Any], prev_cluster, prev_batch,
 
 def _apply_delta(rec: Dict[str, Any], resident):
     """Replay one ``delta`` record onto the resident lineage — the exact
-    twin of DeltaTensorizer._apply (terms replaced wholesale BEFORE the
-    scatter; donation irrelevant to values, so replay never donates)."""
+    twin of DeltaTensorizer._apply (a term table that crossed whole
+    replaced, then the term rows scattered, BEFORE the cluster's scatter;
+    donation irrelevant to values, so replay never donates)."""
     import jax
     import jax.numpy as jnp
 
     from kubetpu.models import programs
     delta, terms = _load_payload(rec)
     if terms is not None:
-        ft = jax.tree.map(jnp.array, terms[0])
-        st = jax.tree.map(jnp.array, terms[1])
+        whole, deltas = terms
+        resident = resident._replace(**{
+            field: jax.tree.map(jnp.array, table)
+            for field, table in whole.items()})
+        ft, st = programs.apply_terms_delta(
+            resident.filter_terms, resident.score_terms, *deltas,
+            donate=False)
         resident = resident._replace(filter_terms=ft, score_terms=st)
     return programs.apply_cluster_delta(resident, delta, donate=False)
 
