@@ -13,6 +13,16 @@ points below therefore run ONLY host plugins; the tensor side's results
 arrive as dense masks/scores from kubetpu/models/programs.py.  That keeps
 the device fast path pure while preserving the reference's plugin contract
 for everything else.
+
+The contract of ``relevant(pod)``: it may read only the pod's namespace,
+labels, annotations, owner references and ``spec`` (and the plugin's own
+arguments) -- never its name, uid, resource version, timestamps or status,
+and nothing of the cluster.  Those fields are the key of a pod CLASS
+(framework/types.py ``classify_pods``): the scheduler asks a plugin once a
+class of a cycle's pods whether it cares and takes the answer for every pod
+of the class.  The extension points themselves (``pre_filter``, ``filter``,
+``reserve``, ``permit``...) still run once a pod, with the pod's own
+CycleState.
 """
 
 from __future__ import annotations
@@ -179,14 +189,26 @@ class Framework:
         rel = getattr(plugin, "relevant", None)
         return rel(pod) if rel is not None else True
 
+    @classmethod
+    def relevant_plugins(cls, plugins, pod: api.Pod) -> List[fw.Plugin]:
+        """Those of ``plugins`` that are ``relevant`` to the pod -- and to
+        every pod of its class (module docstring), so a caller with many
+        pods of one class asks once."""
+        return [p for p in plugins if cls._relevant(p, pod)]
+
     # -- extension points (host plugins only; see module docstring) ---------
 
     @_timed_point("PreFilter")
-    def run_pre_filter_plugins(self, state: CycleState, pod: api.Pod) -> Status:
-        # reference: framework.go:369
-        for p in self.host_pre_filter_plugins:
-            if not self._relevant(p, pod):
-                continue
+    def run_pre_filter_plugins(self, state: CycleState, pod: api.Pod,
+                               relevant: Optional[List[fw.Plugin]] = None
+                               ) -> Status:
+        """reference: framework.go:369.  relevant: the pod's class's
+        ``relevant_plugins`` of the host PreFilter plugins, where the
+        caller holds them."""
+        if relevant is None:
+            relevant = self.relevant_plugins(self.host_pre_filter_plugins,
+                                             pod)
+        for p in relevant:
             st = p.pre_filter(state, pod)
             if not st.is_success():
                 if st.is_unschedulable():

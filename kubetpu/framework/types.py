@@ -14,7 +14,7 @@ from __future__ import annotations
 import itertools
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Set, Tuple
 
 from ..api import types as api
 from ..utils.trace import wallclock
@@ -184,6 +184,84 @@ class PodInfo:
         pi.non_zero_cpu = self.non_zero_cpu
         pi.non_zero_mem = self.non_zero_mem
         return pi
+
+
+# ---------------------------------------------------------------------------
+# pod classes
+
+
+class PodClasses(NamedTuple):
+    """A batch of pending pods grouped by value (``classify_pods``)."""
+    class_of: List[int]   # pod i -> its class, classes numbered as first met
+    reps: List[int]       # class k -> index of its first pod, the representative
+
+
+# equality tests a pod of the batch; past it a batch of alike but distinct
+# pods would go quadratic, and is taken for all distinct instead
+_COMPARES_A_POD = 16
+
+
+def classify_pods(pods: Sequence[api.Pod],
+                  also: Optional[Sequence] = None) -> PodClasses:
+    """Group pending pods that are equal, by value, in everything the
+    scheduler and its plugins may read of a pending pod except its
+    identity and status: namespace, labels (and their order, which the
+    batch rows keep), annotations, owner references and the whole spec.
+    Name, uid, resource version, timestamps and status are no part of it.
+    What is computed from those fields alone for a class's representative
+    holds for each of its pods (``class_pod_infos``, the batch rows of
+    models/batch.py, a plugin's ``relevant``).  also: one more value a
+    pod that must be equal (``is`` or ``==``) within a class.
+
+    Nothing outlives the call: a pod updated in place is grouped by what
+    it holds when it is next asked about.  Only immutable strings are
+    hashed (namespace and label items, to find the candidates); the rest
+    is dataclass ``==`` against the representatives met so far.
+
+    A batch whose classes number more than half its pods shares too
+    little to pay for the gather: every pod is then its own class, which
+    is always a correct answer (a finer grouping shares less and nothing
+    else), and callers run their per-pod code on every pod as before."""
+    n = len(pods)
+    class_of: List[int] = []
+    reps: List[int] = []
+    buckets: Dict[tuple, List[int]] = {}
+    budget = _COMPARES_A_POD * n
+    for i, pod in enumerate(pods):
+        m = pod.metadata
+        key = (m.namespace, tuple(m.labels.items()))
+        cands = buckets.get(key)
+        if cands is None:
+            cands = buckets[key] = []
+        for k in cands:
+            r = reps[k]
+            o = pods[r]
+            budget -= 1
+            if ((o.spec is pod.spec or o.spec == pod.spec)
+                    and o.metadata.annotations == m.annotations
+                    and o.metadata.owner_references == m.owner_references
+                    and (also is None or also[r] is also[i]
+                         or also[r] == also[i])):
+                break
+        else:
+            k = len(reps)
+            reps.append(i)
+            cands.append(k)
+        class_of.append(k)
+        if 2 * len(reps) > n or budget < 0:
+            return PodClasses(list(range(n)), list(range(n)))
+    return PodClasses(class_of, reps)
+
+
+def class_pod_infos(pods: Sequence[api.Pod],
+                    classes: PodClasses) -> List["PodInfo"]:
+    """``PodInfo(pod)`` of every pod, parsed once a class: the others of
+    a class share their representative's terms and resources (the terms'
+    default namespace is the pod's, which the class holds equal)."""
+    rep_infos = [PodInfo(pods[r]) for r in classes.reps]
+    return [rep_infos[k] if classes.reps[k] == i
+            else rep_infos[k].with_pod(pod)
+            for i, (pod, k) in enumerate(zip(pods, classes.class_of))]
 
 
 @dataclass

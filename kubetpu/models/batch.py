@@ -15,7 +15,8 @@ from typing import List, NamedTuple, Optional, Sequence
 import numpy as np
 
 from ..api import types as api
-from ..framework.types import PodInfo, compute_pod_resource_limits
+from ..framework.types import (PodClasses, PodInfo, classify_pods,
+                               compute_pod_resource_limits)
 from ..ops.selectors import FIELD_PREFIX, SelectorCompiler, SelectorSet
 from ..state.tensors import (MIB, N_FIXED_CHANNELS, CH_PODS, port_ids_pod,
                              resource_to_channels, _norm_image)
@@ -237,16 +238,50 @@ class PodBatchBuilder:
     def __init__(self, table: InternTable):
         self.table = table
         self.compiler = SelectorCompiler(table)
+        self.pod_classes = self.rows_built = 0   # of the last build()
 
     def build(self, pods: Sequence[PodInfo], pad_b: Optional[int] = None,
-              spread_selectors: Optional[Sequence] = None) -> PodBatch:
+              spread_selectors: Optional[Sequence] = None,
+              classes: Optional[PodClasses] = None) -> PodBatch:
         """spread_selectors: per-pod combined service/RC/RS/SS selector for
         DefaultPodTopologySpread (reference: plugins/helper/spread.go
-        DefaultSelector), or None per pod when nothing selects it."""
-        t = self.table
-        B = pad_b if pad_b is not None else pow2_bucket(len(pods), 8)
-        if B < len(pods):
+        DefaultSelector), or None per pod when nothing selects it.
+
+        The rows are built once a CLASS of pods (framework/types.py
+        classify_pods, the pod's spread selector held equal too) and
+        gathered out to the batch: what comes out is, leaf for leaf, the
+        batch ``_build_rows`` gives for the pods themselves.  classes: the
+        grouping of exactly these pods and selectors, where the caller has
+        made it already.  ``pod_classes`` and ``rows_built`` say afterwards
+        how many classes the batch had and how many rows were built (the
+        pod count where the classes are too many to share: classify_pods)."""
+        n = len(pods)
+        B = pad_b if pad_b is not None else pow2_bucket(n, 8)
+        if B < n:
             raise ValueError("pad_b smaller than batch")
+        if spread_selectors is None:
+            spread_selectors = [None] * n
+        if classes is None:
+            classes = classify_pods([pi.pod for pi in pods],
+                                    also=spread_selectors)
+        reps = classes.reps
+        self.pod_classes = self.rows_built = K = len(reps)
+        if K == n:
+            return self._build_rows(pods, B, spread_selectors)
+        # the representatives in first-met order, then ONE padding row
+        # where the batch has any: each selector set's unique rows come
+        # out in the order the whole batch would meet them
+        rows = self._build_rows([pods[r] for r in reps],
+                                K + (1 if B > n else 0),
+                                [spread_selectors[r] for r in reps])
+        take = np.full((B,), K, np.int32)
+        take[:n] = classes.class_of
+        return gather_batch_rows(rows, take)
+
+    def _build_rows(self, pods: Sequence[PodInfo], B: int,
+                    spread_selectors: Sequence) -> PodBatch:
+        """One row a pod, in order, then padding up to B."""
+        t = self.table
         R = N_FIXED_CHANNELS + t.rname.cap
         L, K, NS, P = t.kv.cap, t.key.cap, t.ns.cap, t.port.cap
         T, I = t.taint.cap, t.image.cap
@@ -390,8 +425,6 @@ class PodBatchBuilder:
                     pna_flat.append(None)
         pna_sel = self.compiler.compile(pna_flat, pad_s=B * Tp, intern_new=False)
 
-        if spread_selectors is None:
-            spread_selectors = [None] * len(pods)
         spread_sel_list = list(spread_selectors) + [None] * (B - len(pods))
         spread_selector = self.compiler.compile(spread_sel_list, pad_s=B,
                                                 intern_new=False)

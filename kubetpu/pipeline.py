@@ -56,6 +56,7 @@ import os
 import threading
 from typing import List, Optional, Tuple
 
+from .framework.types import classify_pods
 from .utils import trace as utrace
 
 PIPELINE_DEPTH_ENV = "KUBETPU_PIPELINE_DEPTH"
@@ -232,7 +233,8 @@ class PipelinedExecutor:
             fwk = s.profiles[name]
             # ONE relevance walk per cycle, shared with _prepare_group's
             # host-mask gates (the round-5 ADVICE double-walk finding)
-            relevance = s._host_relevance(fwk, group)
+            classes = classify_pods([qp.pod for qp in group])
+            relevance = s._host_relevance(fwk, group, classes)
             if len(ring) and any(rel for rel, _ in relevance.values()):
                 # host filter masks and the volume overlay build from the
                 # CACHE, which excludes every uncommitted in-flight
@@ -247,7 +249,8 @@ class PipelinedExecutor:
             # work (preemption wave, decision audit) runs
             prep, early = s._prepare_group(fwk, group,
                                            uncommitted=ring.preps(),
-                                           relevance=relevance, pop=pop)
+                                           relevance=relevance, pop=pop,
+                                           classes=classes)
             returned += early
             if prep is None:
                 outcomes = returned + self.flush()

@@ -38,7 +38,8 @@ from .client.store import ClusterStore
 from .framework import interface as fw
 from .framework.interface import Code, CycleState, Status
 from .framework.runtime import Framework
-from .framework.types import NodeInfo, PodInfo, QueuedPodInfo
+from .framework.types import (NodeInfo, PodClasses, PodInfo, QueuedPodInfo,
+                              class_pod_infos, classify_pods)
 from .models import programs
 from .models.batch import (PodBatchBuilder, batch_score_sets,
                            live_term_sets, score_rows_spliced)
@@ -641,31 +642,33 @@ class Scheduler:
         return outcomes + self._finish_group(prep, res)
 
     @staticmethod
-    def _host_relevance(fwk: Framework, qpods: List[QueuedPodInfo]
+    def _host_relevance(fwk: Framework, qpods: List[QueuedPodInfo],
+                        classes: Optional[PodClasses] = None
                         ) -> Dict[str, Tuple[bool, bool]]:
         """ONE walk of the host filter plugins' relevance predicates per
-        pod: uid -> (any relevant, any relevant beyond the device-covered
-        volume family).  The walk is measurable at 4k pods/cycle, so every
-        consumer — the pipelined drain's serialize decision, the host-mask
-        loop gate, and the commit-time re-check — shares this map instead
-        of re-walking (the round-5 ADVICE double-walk finding)."""
+        CLASS of pods (``classes``: classify_pods of exactly these pods,
+        made here where not given): uid -> (any relevant, any relevant
+        beyond the device-covered volume family).  Every consumer — the
+        pipelined drain's serialize decision, the host-mask loop gate, and
+        the commit-time re-check — shares this map instead of re-walking
+        (the round-5 ADVICE double-walk finding)."""
         from .state.volumes import DEVICE_COVERED_PLUGINS
-        out: Dict[str, Tuple[bool, bool]] = {}
-        for qp in qpods:
-            rel = unc = False
-            for p in fwk.host_filter_plugins:
-                if fwk._relevant(p, qp.pod):
-                    rel = True
-                    if p.name() not in DEVICE_COVERED_PLUGINS:
-                        unc = True
-                        break
-            out[qp.pod.uid] = (rel, unc)
-        return out
+        if classes is None:
+            classes = classify_pods([qp.pod for qp in qpods])
+        per_class: List[Tuple[bool, bool]] = []
+        for r in classes.reps:
+            names = [p.name() for p in fwk.relevant_plugins(
+                fwk.host_filter_plugins, qpods[r].pod)]
+            per_class.append((bool(names), any(
+                name not in DEVICE_COVERED_PLUGINS for name in names)))
+        return {qp.pod.uid: per_class[k]
+                for qp, k in zip(qpods, classes.class_of)}
 
     def _prepare_group(self, fwk: Framework, qpods: List[QueuedPodInfo],
                        uncommitted: Optional[List[PreparedCycle]] = None,
                        relevance: Optional[Dict[str, Tuple[bool, bool]]]
-                       = None, pop=None):
+                       = None, pop=None,
+                       classes: Optional[PodClasses] = None):
         """Host half of a cycle, up to (but excluding) the device dispatch:
         snapshot, PreFilter, tensorize-or-chain, host filter masks,
         nominated overlay -- the phases ``snapshot``, ``prefilter``,
@@ -675,7 +678,12 @@ class Scheduler:
         depth-k executor's in-flight ring) whose device buffers must
         survive this prepare (gates delta donation).  pop: the ``pop``
         phase that fed this cycle (_pop_grouped), for its Trace to close
-        and record."""
+        and record.  classes: classify_pods of ``qpods``, where the caller
+        has grouped them already (made here otherwise): what prepare
+        computes from a pending pod's namespace, labels, annotations,
+        owner references and spec alone -- its PodInfo, its batch row, its
+        default spread selector, which host plugins care about it -- it
+        computes once a class."""
         # queue depths ride the cycle record; the read takes the queue's
         # condition lock, so it is GATED on the recorder being armed (the
         # disarmed hot path must take no new locks)
@@ -709,21 +717,37 @@ class Scheduler:
         states: Dict[str, CycleState] = {}
         live: List[QueuedPodInfo] = []
         outcomes: List[ScheduleOutcome] = []
-        for qp in qpods:
+        with trace.stage("classify", pods=len(qpods)):
+            if classes is None:
+                classes = classify_pods([qp.pod for qp in qpods])
+            pre_relevant = [
+                fwk.relevant_plugins(fwk.host_pre_filter_plugins,
+                                     qpods[r].pod) for r in classes.reps]
+        timed: list = []   # the PreFilter point's observations, a run a row
+        for qp, k in zip(qpods, classes.class_of):
             state = CycleState()
-            st = fwk.run_pre_filter_plugins(state, qp.pod)
-            if not st.is_success():
-                outcomes.append(self._fail(fwk, qp, state, "",
-                                           st.message() or "prefilter failed",
-                                           preemption_may_help=not st.code
-                                           == Code.UNSCHEDULABLE_AND_UNRESOLVABLE))
-                self._record_decision(qp.pod, "unschedulable",
-                                      message=st.message()
-                                      or "prefilter failed",
-                                      blocking=["PreFilter"])
-                continue
+            if pre_relevant[k]:
+                st = fwk.run_pre_filter_plugins(
+                    state, qp.pod, relevant=pre_relevant[k], sink=timed)
+                if not st.is_success():
+                    outcomes.append(self._fail(
+                        fwk, qp, state, "",
+                        st.message() or "prefilter failed",
+                        preemption_may_help=not st.code
+                        == Code.UNSCHEDULABLE_AND_UNRESOLVABLE))
+                    self._record_decision(qp.pod, "unschedulable",
+                                          message=st.message()
+                                          or "prefilter failed",
+                                          blocking=["PreFilter"])
+                    continue
             states[qp.pod.uid] = state
             live.append(qp)
+        if fwk.metrics is not None:
+            # observed as one; for a pod that no PreFilter plugin cares
+            # about the point does nothing, in no time, and was not run
+            fwk.metrics.framework_extension_point_duration.observe_many(
+                timed + [(0.0, "PreFilter", "Success")]
+                * (len(qpods) - len(timed)))
         if not live:
             trace.finish()
             return None, outcomes
@@ -741,7 +765,17 @@ class Scheduler:
         # cycle's materialized tensors already ARE this snapshot (no
         # unaccounted event landed), so skip the full rebuild entirely
         trace.phase("tensorize")
-        pinfos = [PodInfo(qp.pod) for qp in live]
+        with trace.stage("classify", pods=len(live)):
+            pods = [qp.pod for qp in live]
+            if len(live) != len(qpods):
+                classes = classify_pods(pods)    # PreFilter failed some
+            reps = classes.reps
+            pinfos = class_pod_infos(pods, classes)
+            rep_infos = [pinfos[r] for r in reps]
+            # inside the cycle: a Service added since the last one is seen
+            rep_sels = [self.store.default_spread_selector(pods[r])
+                        for r in reps]
+            spread_sels = [rep_sels[k] for k in classes.class_of]
         # nominated pods join the tensor world too (labels/terms for the
         # addNominatedPods topology overlay) — their vocab must be interned
         # before snapshot arrays are sized
@@ -755,7 +789,7 @@ class Scheduler:
                      and chain["n_nodes"] == n_nodes)
         if use_chain:
             builder = chain["builder"]
-            builder.intern_pending(pinfos + nom_pinfos)
+            builder.intern_pending(rep_infos + nom_pinfos)
             if _vocab_caps(builder.table) != chain["caps"]:
                 use_chain = False   # vocab bucket overflow: rebuild
         if use_chain:
@@ -801,7 +835,7 @@ class Scheduler:
             # resync re-interns them into its fresh table); the delta's
             # pod-row floor stands on the batch alone
             cluster, dstats = delta.refresh(
-                node_infos, pending=pinfos + nom_pinfos, donate=donate,
+                node_infos, pending=rep_infos + nom_pinfos, donate=donate,
                 batch=len(pinfos))
             # AFTER refresh: a compacting resync swaps the builder
             builder = delta.builder
@@ -860,12 +894,11 @@ class Scheduler:
                     self._journal_force_anchor.discard(fwk.profile_name)
             with self._chain_lock:
                 self._chain = None
-        spread_sels = [self.store.default_spread_selector(pi.pod)
-                       for pi in pinfos]
         pb = PodBatchBuilder(builder.table)
         with trace.stage("batch-build", pods=len(pinfos)) as build_span:
             batch = self._jax.tree.map(
-                np.asarray, pb.build(pinfos, spread_selectors=spread_sels))
+                np.asarray, pb.build(pinfos, spread_selectors=spread_sels,
+                                     classes=classes))
             # valid DoNotSchedule constraint rows the builder compiled,
             # and the ScheduleAnyway ones beside them
             spread_rows = int(batch.spread.valid.sum())
@@ -877,6 +910,8 @@ class Scheduler:
                 build_span.args["spread_rows"] = spread_rows
                 build_span.args["ra_rows"] = ra_rows
                 build_span.args["term_sets_live"] = term_sets_live
+                build_span.args["pod_classes"] = pb.pod_classes
+                build_span.args["rows_built"] = pb.rows_built
         batch_dev = None
         if self._mesh is not None:
             # DOUBLE-BUFFERED transfer: start the sharded upload of this
@@ -928,6 +963,10 @@ class Scheduler:
             # the term sets whose existing-pod products this batch's
             # auction runs (ops/kernels.py _if_live); the rest are gated off
             trace.rec.meta["term_sets_live"] = term_sets_live
+            # the batch's pod classes and the rows the builder built for
+            # them (the pod count where the classes were too many to share)
+            trace.rec.meta["pod_classes"] = pb.pod_classes
+            trace.rec.meta["rows_built"] = pb.rows_built
             trace.rec.meta["spread_buckets"] = [
                 int(batch.spread.valid.shape[1]),
                 int(batch.spread.sel.sel_valid.shape[0])]
@@ -950,7 +989,7 @@ class Scheduler:
         from .state.volumes import (DEVICE_COVERED_PLUGINS,
                                     build_volume_overlay, volume_mask)
         if relevance is None:
-            relevance = self._host_relevance(fwk, live)
+            relevance = self._host_relevance(fwk, live, classes)
         host_relevant: Dict[str, bool] = {}
         host_uncovered: Dict[str, bool] = {}
         for qp in live:
@@ -964,7 +1003,7 @@ class Scheduler:
         enabled_hosts = {p.name() for p in fwk.host_filter_plugins}
         vol_mask_dev = None
         if (DEVICE_COVERED_PLUGINS & enabled_hosts
-                and any(qp.pod.spec.volumes for qp in live)):
+                and any(pods[r].spec.volumes for r in reps)):
             overlay = build_volume_overlay(
                 self.store, node_infos, [qp.pod for qp in live],
                 builder.table, enabled_hosts)
@@ -996,7 +1035,7 @@ class Scheduler:
         # AND contribute topology terms (anti-affinity/spread).  The mask
         # stays a DEVICE array — it is consumed on the device, and a
         # [B, N] readback would be a second host sync per cycle
-        batch_topo_keys = self._batch_topo_keys(builder.table, pinfos)
+        batch_topo_keys = self._batch_topo_keys(builder.table, rep_infos)
         nom_mask = self._nominated_overlay_mask(fwk, builder, cluster,
                                                 batch, live, node_infos,
                                                 batch_topo_keys)
@@ -1011,9 +1050,10 @@ class Scheduler:
             nodes_raw = [ni.node for ni in node_infos]
             bias = np.zeros((B, N), np.float32)
             any_bias = False
-            for i, qp in enumerate(live):
-                if not any(fwk._relevant(p, qp.pod)
-                           for p in fwk.host_score_plugins):
+            scored = [bool(fwk.relevant_plugins(fwk.host_score_plugins,
+                                                pods[r])) for r in reps]
+            for i, (qp, k) in enumerate(zip(live, classes.class_of)):
+                if not scored[k]:
                     continue
                 state = states[qp.pod.uid]
                 st = fwk.run_pre_score_plugins(state, qp.pod, nodes_raw)
@@ -1083,13 +1123,13 @@ class Scheduler:
         # per-round topology re-evaluation only pays off when some pod
         # actually carries topology terms; a term-free batch takes the
         # cheaper static path (round-0 verdicts are provably invariant)
-        needs_topo = (any(pod_with_affinity(qp.pod)
-                          or qp.pod.spec.topology_spread_constraints
-                          for qp in live)
+        needs_topo = (any(pod_with_affinity(pods[r])
+                          or pods[r].spec.topology_spread_constraints
+                          for r in reps)
                       # service/RC replicas score via
                       # DefaultPodTopologySpread even without explicit
                       # terms — they need intra-batch placements too
-                      or any(s is not None for s in spread_sels))
+                      or any(s is not None for s in rep_sels))
         if trace.rec is not None:
             trace.rec.meta["needs_topo"] = int(needs_topo)
             # valid batch rows the auction splices into score_terms
@@ -1948,10 +1988,13 @@ class Scheduler:
         one pass — documented bounded deviation).  None when no nominated
         pod is relevant."""
         from .models.batch import build_nominated
+        nominated = self.queue.all_nominated()
+        if not nominated:
+            return None
         uid_to_row = {qp.pod.uid: i for i, qp in enumerate(live)}
         node_row = {ni.node_name: j for j, ni in enumerate(node_infos)}
         entries = []
-        for pod, nn in self.queue.all_nominated():
+        for pod, nn in nominated:
             row = node_row.get(nn)
             if row is None:
                 continue

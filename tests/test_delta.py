@@ -1515,8 +1515,9 @@ def test_steady_churn_at_a_batch_of_1024_dispatches_one_bucket_pair(
 
 def test_the_scheduler_hands_refresh_the_batch_apart_from_the_nominated(
         monkeypatch):
-    """``_prepare_group`` interns the nominated pods with the batch and
-    says how many of ``pending`` the batch is."""
+    """``_prepare_group`` interns the nominated pods with the batch's
+    class representatives (PR 48: four label groups, four classes a batch)
+    and says how many pods the batch is."""
     from kubetpu.apis.config import (KubeSchedulerConfiguration,
                                      KubeSchedulerProfile)
     from kubetpu.client.store import ClusterStore
@@ -1544,7 +1545,7 @@ def test_the_scheduler_hands_refresh_the_batch_apart_from_the_nominated(
     assert len(drain(sched)) == 20
     sched.close()
     assert [batch for _, batch in seen] == [8, 8, 4]
-    assert [n for n, _ in seen] == [9, 9, 5]
+    assert [n for n, _ in seen] == [5, 5, 5]
 
 
 def test_the_cycles_row_maps_are_the_tensorizers_and_a_copy(monkeypatch):

@@ -61,6 +61,8 @@ CONTRACT = [
     ("meta", "thread_cpu_window_s", NUMBER,
      "threads.thread_cpu_ms_by_name (interp_report)"),
     ("meta", "gc_collections", int, "gc_pause_ms_per_cycle.sat"),
+    ("meta", "pod_classes", int, "batch_rows_shared_pct.sat (its K)"),
+    ("meta", "rows_built", int, "batch_rows_shared_pct.sat"),
 ] + [
     ("arg", f"{phase}.cpu_s", NUMBER,
      f"{phase.replace('-', '_')}_ms_per_cycle.sat / spans.blocked_pct")
@@ -77,6 +79,11 @@ CONTRACT = [
     ("span", "bind-job", None, "lane_blocked_pct.sat"),
     ("arg", "batch-build.ra_rows", int,
      "required_affinity_terms_per_cycle.sat"),
+    ("span", "classify", None, "classify_ms_per_cycle.sat"),
+    ("arg", "batch-build.pods", int, "batch_rows_shared_pct.sat"),
+    ("arg", "batch-build.pod_classes", int,
+     "batch_rows_shared_pct.sat (its K)"),
+    ("arg", "batch-build.rows_built", int, "batch_rows_shared_pct.sat"),
     ("arg", "packed-readback.device_wait_s", NUMBER,
      "readback_wait_ms_per_cycle.sat"),
     ("arg", "commit.assume_s", NUMBER, "commit_assume_ms_per_cycle.sat"),
