@@ -906,9 +906,16 @@ class Scheduler:
             term_sets_live = live_term_sets(batch)
             # valid required pod-affinity term rows of the incoming pods
             ra_rows = int(batch.ra.valid.sum())
+            # valid required node-selector terms of the batch's rows (after
+            # the class gather), and the unique selector rows compiled for
+            # them: the node-affinity match is O(unique x nodes)
+            rna_rows = int(batch.rna_valid.sum())
+            rna_unique = int(batch.rna_sel.sel_valid.sum())
             if build_span is not None:
                 build_span.args["spread_rows"] = spread_rows
                 build_span.args["ra_rows"] = ra_rows
+                build_span.args["rna_rows"] = rna_rows
+                build_span.args["rna_unique"] = rna_unique
                 build_span.args["term_sets_live"] = term_sets_live
                 build_span.args["pod_classes"] = pb.pod_classes
                 build_span.args["rows_built"] = pb.rows_built
@@ -960,6 +967,8 @@ class Scheduler:
             trace.rec.meta["spread_constraints"] = spread_rows
             trace.rec.meta["spread_soft_constraints"] = soft_spread_rows
             trace.rec.meta["required_affinity_terms"] = ra_rows
+            trace.rec.meta["node_affinity_terms"] = rna_rows
+            trace.rec.meta["node_affinity_unique_selectors"] = rna_unique
             # the term sets whose existing-pod products this batch's
             # auction runs (ops/kernels.py _if_live); the rest are gated off
             trace.rec.meta["term_sets_live"] = term_sets_live

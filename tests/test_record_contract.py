@@ -56,6 +56,10 @@ CONTRACT = [
      "affinity_bootstrap_pods_per_cycle.sat"),
     ("meta", "affinity_bootstrap_admits", int,
      "affinity_bootstrap_pods_per_cycle.sat"),
+    ("meta", "node_affinity_terms", int,
+     "node_affinity_terms_per_cycle.sat (the span arg's twin)"),
+    ("meta", "node_affinity_unique_selectors", int,
+     "node_affinity_unique_selectors_per_cycle.sat"),
     ("meta", "heap_handoffs", int, "heap_handoffs_per_cycle.sat"),
     ("meta", "thread_cpu_s", dict, "python_cpu_ms_per_cycle.sat"),
     ("meta", "thread_cpu_window_s", NUMBER,
@@ -79,6 +83,10 @@ CONTRACT = [
     ("span", "bind-job", None, "lane_blocked_pct.sat"),
     ("arg", "batch-build.ra_rows", int,
      "required_affinity_terms_per_cycle.sat"),
+    ("arg", "batch-build.rna_rows", int,
+     "node_affinity_terms_per_cycle.sat"),
+    ("arg", "batch-build.rna_unique", int,
+     "node_affinity_unique_selectors_per_cycle.sat (the meta's twin)"),
     ("span", "classify", None, "classify_ms_per_cycle.sat"),
     ("arg", "batch-build.pods", int, "batch_rows_shared_pct.sat"),
     ("arg", "batch-build.pod_classes", int,
@@ -133,6 +141,17 @@ def _prefer(pod):
     return pod
 
 
+def _in_zones(pod, *zones):
+    """One required node-affinity term, ``In`` over the zone label."""
+    pod.spec.affinity = api.Affinity(node_affinity=api.NodeAffinity(
+        required_during_scheduling_ignored_during_execution=(
+            api.NodeSelector(node_selector_terms=[api.NodeSelectorTerm(
+                match_expressions=[api.NodeSelectorRequirement(
+                    key=api.LABEL_ZONE, operator="In",
+                    values=list(zones))])]))))
+    return pod
+
+
 def _waves():
     """Arrivals a wave at a time; every wave is bound before the next
     arrives, so later cycles refresh the resident cluster by delta."""
@@ -152,6 +171,10 @@ def _waves():
         # a required zone affinity to the pod's own label: the auction
         # says how many came in by the self-match bootstrap
         [hollow.with_affinity(_pod(f"aff-{i}", "purple"), api.LABEL_ZONE)
+         for i in range(4)],
+        # a required node-affinity term, the same on every pod: the
+        # batch says how many rows carry one and how many it compiled
+        [_in_zones(_pod(f"zoned-{i}"), "zone-0", "zone-1")
          for i in range(4)],
         [_pod(f"late-{i}") for i in range(6)],
     ]
@@ -233,6 +256,24 @@ def test_a_cycle_record_carries_what_the_benchmark_reads(
         for v in got:
             assert v and all(isinstance(k, str) and isinstance(x, NUMBER)
                              for k, x in v.items())
+
+
+def test_a_batch_of_one_node_affinity_term_counts_its_rows_and_one_selector(
+        cycles):
+    """The four ``zoned-`` pods share one term: four valid rows, ONE
+    unique compiled selector; every other cycle reads 0 and 0."""
+    said = [(c["meta"]["node_affinity_terms"],
+             c["meta"]["node_affinity_unique_selectors"])
+            for c in cycles if "node_affinity_terms" in c["meta"]]
+    assert sum(n for n, _ in said) == 4
+    assert {u for n, u in said if n} == {1}
+    assert all(u == 0 for n, u in said if not n)
+    for c in cycles:
+        for s in c["spans"]:
+            if s["name"] == "batch-build":
+                assert (s["args"]["rna_rows"], s["args"]["rna_unique"]) == (
+                    c["meta"]["node_affinity_terms"],
+                    c["meta"]["node_affinity_unique_selectors"])
 
 
 def test_every_cycle_has_one_root_and_the_eight_phases(cycles):
