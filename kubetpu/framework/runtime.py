@@ -400,7 +400,27 @@ class Framework:
             if self._relevant(p, pod):
                 p.post_bind(state, pod, node_name)
 
-    # -- the binding cycle of many pods at once ------------------------------
+    # -- the commit and the binding cycle of many pods at once ---------------
+
+    def commits_bare(self, pods: List[api.Pod]
+                     ) -> Tuple[List[bool], Tuple[float, float]]:
+        """Per pod: would Reserve, Unreserve and Permit do nothing for
+        it?  (No plugin of the three is ``relevant`` to it, so it cannot
+        come back from Permit with WAIT either.)  Such a pod's commit is
+        its assume alone, which ``Scheduler._commit_run`` does for many.
+        ``relevant`` holds for a pod's whole class (module docstring):
+        the caller asks of one pod a class.  Beside the flags, the
+        seconds the walk of Reserve's (with Unreserve's) and of Permit's
+        plugins took in all: all those points do for such pods, so what
+        the caller observes for them, shared out."""
+        t0 = time.time()
+        res = self._any_relevant(self.reserve_plugins, pods)
+        unres = self._any_relevant(self.unreserve_plugins, pods)
+        t1 = time.time()
+        permit = self._any_relevant(self.permit_plugins, pods)
+        t2 = time.time()
+        return ([not (a or b or c) for a, b, c in zip(res, unres, permit)],
+                (t1 - t0, t2 - t1))
 
     def batch_binder(self):
         """The profile's Bind plugin where the binding cycle of many pods

@@ -190,6 +190,26 @@ class CycleContext:
             np.asarray(self.batch.ports_asnode_hot[row]) > 0.5)
         self.commits += 1
 
+    def note_commits(self, rows: Sequence[int],
+                     node_rows: Sequence[int]) -> None:
+        """``note_commit`` for each (batch row, node row) pair, in order,
+        as ONE scatter an overlay (the commit loop notes a run of pods at
+        once: Scheduler._commit_run).  Unbuffered (``ufunc.at``): two
+        pods of a run may share a node, and their requests add up in the
+        order given, as a ``+=`` a pod adds them."""
+        if self.batch is None or not len(rows):
+            return
+        self._ensure_overlay()
+        rows = np.asarray(rows, np.intp)
+        node_rows = np.asarray(node_rows, np.intp)
+        batch = self.batch
+        np.add.at(self.commit_req, node_rows, np.asarray(batch.req)[rows])
+        np.add.at(self.commit_nz, node_rows,
+                  np.asarray(batch.nonzero_req)[rows])
+        np.logical_or.at(self.commit_ports, node_rows,
+                         np.asarray(batch.ports_asnode_hot)[rows] > 0.5)
+        self.commits += len(rows)
+
     def note_evict(self, node_row: int, req_vec: np.ndarray,
                    nz_vec: np.ndarray) -> None:
         """Record a deleted preemption victim so later wave rounds (and

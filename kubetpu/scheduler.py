@@ -21,7 +21,6 @@ recordSchedulingFailure exactly like the reference (scheduler.go:586-687).
 
 from __future__ import annotations
 
-import copy
 import itertools
 import threading
 import time
@@ -63,6 +62,21 @@ def _lap(acc: List[float], step: int) -> None:
     now = time.perf_counter()
     acc[step] += now - acc[-1]
     acc[-1] = now
+
+
+def _assumed(pod: api.Pod, node_name: str) -> api.Pod:
+    """The pod as the cache assumes it: a new object with the pod's
+    fields and a new spec with the spec's, ``node_name`` set -- field for
+    field what ``copy.copy`` of the two gives, without its reduce
+    protocol.  A shallow clone is enough: the cache reads spec,
+    containers and labels, which the scheduler never mutates."""
+    assumed = object.__new__(type(pod))
+    assumed.__dict__ = pod.__dict__.copy()
+    spec = object.__new__(type(pod.spec))
+    spec.__dict__ = pod.spec.__dict__.copy()
+    spec.node_name = node_name
+    assumed.spec = spec
+    return assumed
 
 
 class _LadderOwed(NamedTuple):
@@ -107,6 +121,9 @@ class PreparedCycle:
     host_ok_dev: object
     cfg: programs.ProgramConfig
     cycle_ctx: object
+    # classify_pods of ``live``'s pods, as prepare grouped them: what the
+    # commit loop asks a class and not a pod (Framework.commits_bare)
+    classes: PodClasses
     needs_topo: bool = True
     used_chain: bool = False
     chain_pod_uids: list = field(default_factory=list)
@@ -1151,7 +1168,7 @@ class Scheduler:
             node_infos=node_infos, states=states, live=live, pinfos=pinfos,
             builder=builder, cluster=cluster, batch=batch,
             host_relevant=host_relevant, host_ok_dev=host_ok_dev, cfg=cfg,
-            cycle_ctx=cycle_ctx, needs_topo=needs_topo,
+            cycle_ctx=cycle_ctx, classes=classes, needs_topo=needs_topo,
             used_chain=use_chain, chain_pod_uids=chain_pod_uids,
             score_bias=score_bias, host_reject=host_reject,
             relevance=relevance, journal_input=journal_input,
@@ -1506,17 +1523,31 @@ class Scheduler:
                       packed: np.ndarray) -> List[ScheduleOutcome]:
         """Serving thread only (_finish_group for the serial loop, the
         pipelined executor's drain for a ring of cycles), inside the
-        cycle's ``commit`` phase.  The loop assumes the cycle's pods one
-        by one (_commit) and collects their binds; as it ends they go to
-        the binder lane as ONE job (_hand_over), never a hand-over a pod.
+        cycle's ``commit`` phase.  The loop assumes the cycle's pods in
+        scan order and collects their binds; as it ends they go to the
+        binder lane as ONE job (_hand_over), never a hand-over a pod.
         One job at the loop's end, not chunks as the loop runs: the lane
         then works under the next cycle's pop, prepare and readback
-        instead of taking the interpreter from this loop.  Armed, the
-        per-pod loop's split lands on that phase's span as SUMS --
-        recheck_s, reserve_s, assume_s, permit_s, submit_s (the stamp and
-        the append a pod, plus the hand-over), records_s (decision audit,
-        the cycle context's note), pods, loop_s, loop_cpu_s --
-        not as a span a pod; _hand_over adds bind_jobs, binds_pooled and
+        instead of taking the interpreter from this loop.
+
+        Consecutive pods whose commit is their assume alone (placed, no
+        host filter to re-check, no Reserve / Unreserve / Permit plugin
+        that cares about their class: Framework.commits_bare) are
+        committed as a RUN, a step at a time over all of them
+        (_commit_run); any other placed pod is committed by _commit, all
+        steps a pod, between the run before it and the run after it, so
+        what it re-checks is a cache in which every earlier pod of the
+        batch is assumed.  Read off the pods and the cycle's verdicts,
+        never a knob.  A cycle whose binds cannot ride the lane (no
+        ``async_binding``, a job that is not ``lane_ok``) has no runs:
+        those binds run inside _commit.
+
+        Armed, the loop's split lands on that phase's span as SUMS --
+        recheck_s, reserve_s, assume_s, permit_s, submit_s (the stamps
+        and the appends, plus the hand-over), records_s (decision audit,
+        the cycle context's notes, the outcomes), pods, batched (those of
+        ``pods`` a run committed), loop_s, loop_cpu_s -- not as a span a
+        pod; _hand_over adds bind_jobs, binds_pooled and
         handover_wait_s.  loop_s is the loop's last stamp less its first,
         so the six sums add up to it."""
         fwk, trace = prep.fwk, prep.trace
@@ -1555,8 +1586,9 @@ class Scheduler:
         jr_seq = jr.next_seq() if jr is not None else 0
         # the split of the loop below, armed only: seconds summed per
         # step over the cycle's pods, each stamp closing one step and
-        # opening the next (so the sums cover the loop), five stamps a
-        # pod -- _commit takes four, the loop's tail the fifth.
+        # opening the next (so the sums cover the loop): a stamp a step
+        # a run, five a pod outside one -- _commit takes four, the loop's
+        # tail the fifth.
         # acc = [recheck, reserve, assume, permit, submit, records, last
         # stamp]; disarmed it is None and no clock is read
         acc = None
@@ -1567,18 +1599,40 @@ class Scheduler:
             acc = [0.0, 0.0, 0.0, 0.0, 0.0, 0.0, loop_t0]
         job = (self._new_bind_job(fwk, flight) if self._async_binding
                else None)
+        # which classes' pods may ride a run, asked once a class
+        class_of, walk_s = prep.classes.class_of, (0.0, 0.0)
+        bare = [False] * len(prep.classes.reps)
+        if job is not None and job.lane_ok:
+            bare, walk_s = fwk.commits_bare(
+                [live[r].pod for r in prep.classes.reps])
+            if acc is not None:
+                _lap(acc, 1)
+                permit_s = min(walk_s[1], acc[1])   # two clocks
+                acc[1] -= permit_s
+                acc[3] += permit_s
+        host_relevant = prep.host_relevant
+        run: List[int] = []     # the open run's rows of ``live``
+        batched = run_failed = 0
         for i, qp in enumerate(live):
-            state = states[qp.pod.uid]
             if chosen[i] < 0:
                 outcomes.append(None)
-                deferred.append((len(outcomes) - 1, qp, state,
+                deferred.append((i, qp, states[qp.pod.uid],
                                  f"0/{n_nodes} nodes are available",
                                  not unres[i]))
                 continue
+            if bare[class_of[i]] and not host_relevant[qp.pod.uid]:
+                outcomes.append(None)
+                run.append(i)
+                batched += 1
+                continue
+            if run:
+                run_failed += self._commit_run(prep, run, chosen, n_feas,
+                                               outcomes, job, acc)
+                run = []
             node_name = node_infos[chosen[i]].node_name
-            outcome = self._commit(fwk, qp, state, node_name,
+            outcome = self._commit(fwk, qp, states[qp.pod.uid], node_name,
                                    n_feas[i], pinfo=pinfos[i],
-                                   host_relevant=prep.host_relevant[qp.pod.uid],
+                                   host_relevant=host_relevant[qp.pod.uid],
                                    flight=flight, row=i, acc=acc,
                                    job=job)
             if outcome.node:
@@ -1599,6 +1653,20 @@ class Scheduler:
             outcomes.append(outcome)
             if acc is not None:
                 _lap(acc, 5)
+        if run:
+            run_failed += self._commit_run(prep, run, chosen, n_feas,
+                                           outcomes, job, acc)
+        commit_failed = commit_failed or bool(run_failed)
+        if batched and fwk.metrics is not None:
+            # what the per-pod walk gives the extension-point histogram
+            # for these pods: one Reserve observation a pod and one Permit
+            # a pod that was assumed, the class walk's seconds shared out
+            fwk.metrics.framework_extension_point_duration.observe_many(
+                [(walk_s[0] / batched, "Reserve", "Success")] * batched
+                + [(walk_s[1] / batched, "Permit", "Success")]
+                * (batched - run_failed))
+            if acc is not None:
+                _lap(acc, 1)
         if job is not None:
             self._hand_over(job)
         if acc is not None:
@@ -1607,7 +1675,7 @@ class Scheduler:
                 recheck_s=round(acc[0], 6), reserve_s=round(acc[1], 6),
                 assume_s=round(acc[2], 6), permit_s=round(acc[3], 6),
                 submit_s=round(acc[4], 6), records_s=round(acc[5], 6),
-                pods=len(live) - len(deferred),
+                pods=len(live) - len(deferred), batched=batched,
                 loop_s=round(acc[-1] - loop_t0, 6),
                 loop_cpu_s=round(time.thread_time() - loop_cpu0, 6))
         # ---- preemption WAVE: every preemption-eligible FitError of this
@@ -2129,13 +2197,8 @@ class Scheduler:
         if acc is not None:
             _lap(acc, 1)
 
-        # assume (reference: scheduler.go:435,593).  A shallow clone with a
-        # fresh spec is enough: the cache reads spec/containers/labels,
-        # which the scheduler never mutates — the deep copy burned ~1.5s
-        # per 4k-pod cycle for nothing.
-        assumed = copy.copy(pod)
-        assumed.spec = copy.copy(pod.spec)
-        assumed.spec.node_name = node_name
+        # assume (reference: scheduler.go:435,593)
+        assumed = _assumed(pod, node_name)
         try:
             self.cache.assume_pod(
                 assumed,
@@ -2191,6 +2254,69 @@ class Scheduler:
             _lap(acc, 4)
         return ScheduleOutcome(pod=pod, node=node_name if err is None else "",
                                err=err, n_feasible=n_feasible)
+
+    def _commit_run(self, prep: PreparedCycle, rows: List[int],
+                    chosen: List[int], n_feas: List[int],
+                    outcomes: List[Optional[ScheduleOutcome]],
+                    job: BindJob, acc: Optional[List[float]]) -> int:
+        """Serving thread only: the commit of ``rows`` (rows of
+        ``prep.live`` in scan order, each placed on ``chosen[row]`` and
+        bare as _commit_group reads it) a step at a time over all of
+        them, where _commit runs all steps a pod: the assumed clones,
+        ONE hold of the cache's lock, one stamp of the bind table, one
+        extension of ``job``, one scatter into the cycle context's
+        overlays, one write of the decision audit.  Fills ``outcomes`` at
+        ``rows``.  A pod the cache refuses is failed as _commit fails it
+        (no Unreserve: no such plugin cares about a bare pod) and the
+        rest of the run stands; returns how many were.  acc as in
+        _commit."""
+        fwk, live, states = prep.fwk, prep.live, prep.states
+        node_infos, pinfos = prep.node_infos, prep.pinfos
+        flight = prep.trace.rec
+        nodes = [node_infos[chosen[i]].node_name for i in rows]
+        assumed = [_assumed(live[i].pod, node)
+                   for i, node in zip(rows, nodes)]
+        errs = self.cache.assume_pods_many(
+            assumed, [pinfos[i].with_pod(a) for i, a in zip(rows, assumed)])
+        if acc is not None:
+            _lap(acc, 2)
+        failed = len(errs) - errs.count(None)
+        ok = rows if not failed else [
+            i for i, err in zip(rows, errs) if err is None]
+        if flight is not None:
+            flight.stamp_binds(ok, utrace.BIND_SUBMITTED)
+        job.entries.extend(
+            (fwk, live[i], states[live[i].pod.uid], a, node, i)
+            for i, a, node, err in zip(rows, assumed, nodes, errs)
+            if err is None)
+        if acc is not None:
+            _lap(acc, 4)
+        # preemption for pods failing later in this batch must see these
+        # placements (CycleContext.cluster_now overlay)
+        prep.cycle_ctx.note_commits(ok, [chosen[i] for i in ok])
+        cycle = self.cycle_count
+        decisions = [] if self.decisions.enabled else None
+        for i, node, err in zip(rows, nodes, errs):
+            qp = live[i]
+            pod = qp.pod
+            if err is None:
+                outcomes[i] = ScheduleOutcome(pod=pod, node=node,
+                                              n_feasible=n_feas[i])
+            else:
+                outcomes[i] = self._fail(fwk, qp, states[pod.uid], node, err,
+                                         preemption_may_help=False)
+            if decisions is not None:
+                m = pod.metadata
+                decisions.append(PodDecision(
+                    m.name, m.namespace, m.uid,
+                    "scheduled" if err is None else "unschedulable",
+                    node=outcomes[i].node, message=err or "",
+                    n_feasible=n_feas[i], cycle=cycle))
+        if decisions:
+            self.decisions.record_many(decisions)
+        if acc is not None:
+            _lap(acc, 5)
+        return failed
 
     def _new_bind_job(self, fwk: Framework, flight=None) -> BindJob:
         """An empty hand-over for binds through ``fwk``.  Whether they may

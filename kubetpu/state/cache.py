@@ -142,13 +142,29 @@ class SchedulerCache:
     def assume_pod(self, pod: api.Pod, pinfo=None) -> None:
         """reference: cache.go:338 AssumePod.  pinfo: optional pre-parsed
         PodInfo wrapping this pod (hot-path callers avoid a re-parse)."""
+        err, = self.assume_pods_many((pod,), (pinfo,))
+        if err is not None:
+            raise ValueError(err)
+
+    def assume_pods_many(self, pods, pinfos) -> List[Optional[str]]:
+        """AssumePod for each of ``pods`` (``pinfos``: its pre-parsed
+        PodInfo or None, a pod), in order, under ONE hold of the lock
+        (the commit loop assumes a run of pods at once:
+        Scheduler._commit_run).  A pod: None, or why it was not assumed
+        (``assume_pod``'s ValueError); the others of the list stand."""
+        errs: List[Optional[str]] = [None] * len(pods)
         with self._lock:
-            if pod.uid in self.pod_states:
-                raise ValueError(f"pod {pod.uid} is in the cache, "
-                                 "so can't be assumed")
-            self._add_pod(pod, pinfo)
-            self.pod_states[pod.uid] = _PodState(pod=pod)
-            self.assumed_pods[pod.uid] = True
+            states, assumed = self.pod_states, self.assumed_pods
+            for i, pod in enumerate(pods):
+                uid = pod.uid
+                if uid in states:
+                    errs[i] = (f"pod {uid} is in the cache, "
+                               "so can't be assumed")
+                    continue
+                self._add_pod(pod, pinfos[i])
+                states[uid] = _PodState(pod=pod)
+                assumed[uid] = True
+        return errs
 
     def finish_binding(self, pod: api.Pod, now: Optional[float] = None) -> None:
         """reference: cache.go:359 FinishBinding — starts the expiry TTL."""

@@ -141,13 +141,21 @@ class DecisionLog:
         return f"{namespace}/{name}"
 
     def record(self, decision: PodDecision) -> None:
-        key = self._key(decision.name, decision.namespace)
+        self.record_many((decision,))
+
+    def record_many(self, decisions) -> None:
+        """``record`` for each of ``decisions``, in order, under ONE hold
+        of the lock (the commit loop records a run of pods at once:
+        Scheduler._commit_run)."""
         with self._lock:
-            self._entries.pop(key, None)
-            self._entries[key] = decision
-            while len(self._entries) > self.capacity:
-                self._entries.popitem(last=False)
-                self._evicted += 1
+            entries, capacity = self._entries, self.capacity
+            for d in decisions:
+                key = self._key(d.name, d.namespace)
+                entries.pop(key, None)
+                entries[key] = d
+                while len(entries) > capacity:
+                    entries.popitem(last=False)
+                    self._evicted += 1
 
     def get(self, name: str,
             namespace: Optional[str] = None) -> Optional[PodDecision]:

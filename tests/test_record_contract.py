@@ -101,6 +101,8 @@ CONTRACT = [
     ("arg", "commit.submit_s", NUMBER, "commit_submit_ms_per_cycle.sat"),
     ("arg", "commit.handover_wait_s", NUMBER,
      "handover_wait_ms_per_cycle.sat"),
+    ("arg", "commit.pods", int, "commit_batched_pct.sat"),
+    ("arg", "commit.batched", int, "commit_batched_pct.sat"),
     ("arg", "pop.wait_s", NUMBER, "queue_empty_wait_ms_per_cycle.sat"),
     ("arg", "pop.teardown_s", NUMBER, "pop_teardown_ms_per_cycle.sat"),
     ("arg", "snapshot.pods_copied", int,
