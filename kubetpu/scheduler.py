@@ -556,7 +556,14 @@ class Scheduler:
         # survivors are handed to the permanent generation
         heap = self._heap
         if heap is not None:
-            heap.boundary(self.cycle_count)
+            # armed, with a cycle just behind: the teardown's second child
+            since = utrace.teardown_mark()
+            did = heap.boundary(self.cycle_count)
+            if since is not None:
+                utrace.teardown_child(
+                    utrace.HEAP_SPAN, since,
+                    handoff=int(did != uheap.NOTHING),
+                    sweep=int(did == uheap.SWEPT))
         max_batch = max_batch or self.config.batch_size
         if self.extenders:
             # extenders are a per-pod HTTP round trip; keep the reference's
@@ -586,9 +593,19 @@ class Scheduler:
         still open, it is handed to the Trace of the cycle it fed, which
         closes it as the cycle's own first phase opens."""
         pop = utrace.begin_pop()
+        # the recorder's stamps (a capture alone opens a phase too)
+        timed = pop is not None and pop.cpu0 is not None
         waited0 = self.queue.pop_wait_s if pop is not None else 0.0
+        t_queue = utrace.wallclock() if timed else 0.0
         qpods = self.queue.pop_batch(max_batch, timeout=timeout)
+        t_group = utrace.wallclock() if timed else 0.0
         by_profile = self._group_by_profile(qpods)
+        if timed:
+            # the rest of the pop in its two parts: the queue (its wait
+            # included: queue_s - wait_s is its own work) and the skip
+            # check a pod with the grouping
+            pop.args.update(queue_s=round(t_group - t_queue, 6),
+                            group_s=round(utrace.wallclock() - t_group, 6))
         if pop is not None:
             pop.args.update(
                 wait_s=round(self.queue.pop_wait_s - waited0, 6),
@@ -3194,7 +3211,13 @@ class Scheduler:
             while not self._stop.is_set():
                 t_pass = time.monotonic()
                 try:
-                    self.schedule_pending(timeout=0.2)
+                    out = self.schedule_pending(timeout=0.2)
+                    # the drop is a statement of its own: the cycle's
+                    # outcomes die HERE, where the teardown's first
+                    # child (``teardown-release``) ends
+                    dropped = len(out)
+                    del out
+                    utrace.teardown_released(dropped)
                     self._longest_pass_s = max(
                         self._longest_pass_s, time.monotonic() - t_pass)
                 except Exception:  # the serving loop must never die

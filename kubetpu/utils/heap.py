@@ -75,6 +75,8 @@ SWEEP_GAP_S = 60.0
 # over a million objects), so ``heap_frozen`` is read this many cycles
 # apart at the least, never every cycle
 FROZEN_READ_EVERY = 16
+# what one ``boundary()`` did (a sweep is a hand-off too)
+NOTHING, HANDED_OFF, SWEPT = 0, 1, 2
 
 # process-wide: the policies started and not stopped.  Reentrant: a
 # finalizer run by a pass made under the lock may close a scheduler.
@@ -125,25 +127,28 @@ class HeapPolicy:
 
     # ------------------------------------------------------- between cycles
 
-    def boundary(self, cycle_count: int) -> None:
+    def boundary(self, cycle_count: int) -> int:
         """The serving thread, with no cycle open.  cycle_count: the
         scheduler's; it has moved when a cycle ran since the last call,
-        and stands still when the pop came back empty."""
+        and stands still when the pop came back empty.  Returns what it
+        did: NOTHING, HANDED_OFF or SWEPT (the ``heap-boundary`` span's
+        args, utils/trace.py)."""
         ran = cycle_count - self._cycle_seen
         if ran:
             self._cycle_seen = cycle_count
             self._since_handoff += ran
             self._since_read += ran
             if self._since_handoff < HANDOFF_EVERY:
-                return
+                return NOTHING
         elif not (self._unswept or self._sweep_due):
-            return              # idle, and nothing frozen since a full pass
+            return NOTHING      # idle, and nothing frozen since a full pass
         since = time.monotonic() - self._last_full
         if since >= SWEEP_EVERY_S or (since >= SWEEP_GAP_S
                                       and (self._sweep_due or not ran)):
-            self._sweep()
-        elif ran:
-            self._hand_off()
+            return self._sweep()
+        if ran:
+            return self._hand_off()
+        return NOTHING
 
     def want_sweep(self) -> None:
         """A recovered cycle dropped residents (the chain, the profile's
@@ -151,21 +156,22 @@ class HeapPolicy:
         cyclic."""
         self._sweep_due = True
 
-    def _hand_off(self) -> None:
+    def _hand_off(self) -> int:
         with _lock:
             if not self.started:
-                return
+                return NOTHING
             gc.collect(1)
             gc.freeze()
         self._since_handoff = 0
         self._unswept = True
         self.handoffs += 1
         self._note(1, read=self._since_read >= FROZEN_READ_EVERY)
+        return HANDED_OFF
 
-    def _sweep(self) -> None:
+    def _sweep(self) -> int:
         with _lock:
             if not self.started:
-                return
+                return NOTHING
             gc.unfreeze()
             found = gc.collect()
             gc.freeze()
@@ -176,6 +182,7 @@ class HeapPolicy:
         self.sweeps += 1
         self.sweep_collected += found
         self._note(1, read=True, swept=found)
+        return SWEPT
 
     def _note(self, handoffs: int, read: bool, swept: int = 0) -> None:
         """Armed only: tell the flight recorder; read: walk the permanent

@@ -10,12 +10,14 @@ On top of the reference's threshold log, this module is the structured
 observability layer: every ``Trace`` carries a span id, parent linkage and
 thread tag, and — when the flight recorder is ARMED — the full span tree
 of each scheduling cycle (the eight PHASES that partition the serving
-thread's cycle: pop, snapshot, prefilter, tensorize, host-masks,
-dispatch, packed-readback with device-wait attribution, commit; the
-utiltrace steps; preemption wave; the per-pod BIND TABLE; recompile
-events fed by the sanitize watchdog, and the queue depths at cycle
-start) lands in a lock-guarded ring buffer of the last N cycles
-(``KUBETPU_FLIGHT_N``, default 64).  The ring serializes to
+thread's cycle: pop -- with the previous cycle's TEARDOWN inside it as
+a span of its own, who ran during it and its two children --, snapshot,
+prefilter, tensorize, host-masks, dispatch, packed-readback with
+device-wait attribution, commit; the utiltrace steps; preemption wave;
+the per-pod BIND TABLE; recompile events fed by the sanitize watchdog,
+and the queue depths at cycle start) lands in a lock-guarded ring buffer
+of the last N cycles (``KUBETPU_FLIGHT_N``, default 64).  The ring
+serializes to
 the Chrome ``traceEvents`` JSON format (one pid per component, one tid
 per thread, ``ph: "X"`` spans) loadable in Perfetto/chrome://tracing,
 alongside the existing ``jax.profiler`` XPlane capture.
@@ -76,10 +78,12 @@ DEFAULT_FLIGHT_SPANS = 512
 # While a capture is active (capture_device_trace below), every PHASE of
 # the serving thread's cycle opens ONE jax.profiler.TraceAnnotation named
 # "Scheduling:<phase>" for exactly its own extent -- the phase that is
-# OPEN, never two at once -- so device-idle gaps can be charged to what
-# the host was doing, and every cycle drops a CLOCK_ANNOTATION carrying
-# wallclock(), which puts any flight-recorder stamp of any thread on the
-# profiler's timeline (offset = event start - wallclock_s).
+# OPEN, never two at once; the pop phase two, one after the other
+# ("Scheduling:teardown", then "Scheduling:pop") -- so device-idle gaps
+# can be charged to what the host was doing, and every cycle drops a
+# CLOCK_ANNOTATION carrying wallclock(), which puts any flight-recorder
+# stamp of any thread on the profiler's timeline (offset = event start -
+# wallclock_s).
 _PROFILE_ACTIVE = False
 CYCLE_TRACE = "Scheduling"
 CLOCK_ANNOTATION = "kubetpu.clock"
@@ -444,7 +448,8 @@ class FlightRecorder:
                 self._heap_frozen = frozen
             self._heap_swept += swept
 
-    def note_interpreter(self, rec: CycleRecord) -> None:
+    def note_interpreter(self, rec: CycleRecord
+                         ) -> Optional[Dict[threading.Thread, float]]:
         """Who held the interpreter since the cycle before (Trace.finish
         calls this on the serving thread): meta ``thread_cpu_s`` =
         {thread name: CPU seconds} of every live Python thread above 0.1
@@ -459,7 +464,8 @@ class FlightRecorder:
         permanent generation's size when it was last read, and
         ``heap_sweep_collected``, what a sweep found, where not zero.  A
         thread that ends between two readings takes its last slice with
-        it."""
+        it.  Returns the reading it took (None where there is no such
+        clock): it is the start of the teardown that follows, too."""
         now = time.perf_counter()
         cpu = _read_thread_cpu()
         with self._lock:
@@ -483,17 +489,10 @@ class FlightRecorder:
         if d_n:
             rec.meta["gc_collections"] = d_n
         if cpu is None or not last_t:
-            return
-        by_name: Dict[str, float] = {}
-        for t, c in cpu.items():
-            # a thread new since the last reading spent all it has since
-            d = c - last.get(t, 0.0)
-            if d > 1e-4:
-                name = _fold_name(t.name)
-                by_name[name] = by_name.get(name, 0.0) + d
-        rec.meta["thread_cpu_s"] = {k: round(v, 6)
-                                    for k, v in by_name.items()}
+            return cpu
+        rec.meta["thread_cpu_s"] = _cpu_by_name(cpu, last)
         rec.meta["thread_cpu_window_s"] = round(now - last_t, 6)
+        return cpu
 
     def cycles(self) -> List[CycleRecord]:
         with self._lock:
@@ -668,6 +667,20 @@ def _read_thread_cpu() -> Optional[Dict[threading.Thread, float]]:
         except (OSError, TypeError, OverflowError):
             pass            # it ended since enumerate() listed it
     return out
+
+
+def _cpu_by_name(cpu: Dict[threading.Thread, float],
+                 last: Dict[threading.Thread, float]) -> Dict[str, float]:
+    """{thread name: CPU seconds} between two readings, for the threads
+    above 0.1 ms, pool threads summed under their prefix."""
+    by_name: Dict[str, float] = {}
+    for t, c in cpu.items():
+        # a thread new since the last reading spent all it has since
+        d = c - last.get(t, 0.0)
+        if d > 1e-4:
+            name = _fold_name(t.name)
+            by_name[name] = by_name.get(name, 0.0) + d
+    return {k: round(v, 6) for k, v in by_name.items()}
 
 
 # ------------------------------------------------------- the collector hook
@@ -849,12 +862,12 @@ class _Phase:
     attach args before the exit closes it."""
 
     __slots__ = ("name", "rec", "span", "ann", "t0", "t1", "cpu0", "cpu_s",
-                 "args", "closed", "gc0_s", "gc0_full", "gc_events")
+                 "args", "closed", "gc0_s", "gc0_full", "gc_events", "td")
 
     def __init__(self, name: str, ann: str, rec: Optional[CycleRecord],
                  parent_id: int, args: Dict[str, Any]):
         self.name, self.rec, self.args = name, rec, args
-        self.span = self.ann = self.cpu0 = None
+        self.span = self.ann = self.cpu0 = self.td = None
         self.t0 = self.t1 = self.cpu_s = 0.0
         self.closed = False
         self.gc_events: Sequence[Tuple[float, float, int]] = ()
@@ -933,19 +946,130 @@ def begin_pop():
     per-pod skip check, grouping by profile -- which runs BEFORE the
     cycle's Trace exists.  ``Trace.finish()`` opens it as it closes a
     cycle's last phase, so the partition has no hole between two cycles;
-    here the caller picks that one up (``teardown_s``: how long ago it
-    opened) or, on a thread that has just started, opens one.  Returns
-    None when neither the recorder nor a capture is on (no clock read, no
+    here the caller picks that one up, which ends its teardown (see
+    "teardown" below; ``teardown_s``: how long ago the phase opened) or,
+    on a thread that has just started, opens one.  Returns None when
+    neither the recorder nor a capture is on (no clock read, no
     allocation); otherwise the caller hands the phase to the ``Trace`` of
     the cycle the pop fed (``pop=``), which closes and records it."""
     if _flight is None and not _PROFILE_ACTIVE:
         return None
     cur = getattr(_tls, "phase", None)
     if cur is not None and cur.name == "pop" and cur.rec is None:
-        if cur.cpu0 is not None:
-            cur.args["teardown_s"] = round(wallclock() - cur.t0, 6)
+        if cur.td is not None and cur.td.args is None:
+            _end_teardown(cur, cur.td)
         return cur
     return _open_phase("pop", "pop", None, 0, {})
+
+
+# ------------------------------------------------------------------ teardown
+#
+# A ``pop`` phase that Trace.finish() opened begins with the TEARDOWN of
+# the cycle before: the frames' unwinding and the frees of what the cycle
+# held, the serving loop dropping the outcomes, the heap policy's boundary
+# -- on the serving thread, with the binder lane's job and every other
+# Python thread running beside it.  It ends at begin_pop()'s pick-up.  It
+# is a span ``teardown`` under ``pop`` (extent = ``pop.teardown_s``) with
+# the serving thread's ``cpu_s``, the collector's ``gc_s`` / ``gc_full``
+# and ``thread_cpu_s`` = {thread name: CPU seconds inside it} from a
+# second reading of every thread's CPU clock (the first is the one
+# Trace.finish() took for the cycle's meta; ``read_s``: what the second
+# took, wall), and two children the serving thread stamps as it passes
+# them: ``teardown-release`` (to the loop's drop of the outcomes) and
+# ``heap-boundary``.  In a capture the phase's annotation is
+# "Scheduling:teardown" up to the pick-up and "Scheduling:pop" from it:
+# one after the other, no hole, never nested.  A pop closes before its
+# cycle's record exists, so all three wait on the open phase for the Trace
+# that records the pop.
+TEARDOWN_SPAN = "teardown"
+RELEASE_SPAN = "teardown-release"
+HEAP_SPAN = "heap-boundary"
+
+
+class _Teardown:
+    __slots__ = ("threads0", "kids", "t1", "args")
+
+    def __init__(self, threads0: Optional[Dict[threading.Thread, float]]):
+        self.threads0 = threads0    # every thread's CPU clock as it began
+        self.kids: List[Tuple[str, float, float, Dict[str, Any]]] = []
+        self.t1 = 0.0
+        self.args: Optional[Dict[str, Any]] = None      # None: still open
+
+
+def _end_teardown(ph: _Phase, td: _Teardown) -> None:
+    td.args = args = {}
+    timed = ph.cpu0 is not None     # a capture alone: annotations, no stamps
+    if timed:
+        cpu_s = time.thread_time() - ph.cpu0
+        td.t1 = wallclock()
+    if ph.ann is not None:
+        ph.ann.__exit__(None, None, None)
+        ph.ann = None
+    if _PROFILE_ACTIVE:
+        import jax
+        ph.ann = jax.profiler.TraceAnnotation(f"{CYCLE_TRACE}:{ph.name}")
+        ph.ann.__enter__()
+    if not timed:
+        return
+    ph.args["teardown_s"] = round(td.t1 - ph.t0, 6)
+    args["cpu_s"] = round(cpu_s, 6)
+    _gc_args(_gc_sums(), ph.gc0_s, ph.gc0_full, args)
+    if td.threads0 is not None:
+        t_read = time.perf_counter()
+        threads1 = _read_thread_cpu()
+        if threads1 is not None:
+            args["thread_cpu_s"] = _cpu_by_name(threads1, td.threads0)
+            args["read_s"] = round(time.perf_counter() - t_read, 6)
+
+
+def _stamped_teardown() -> Optional[_Phase]:
+    """The phase whose teardown is open on this thread and stamped (the
+    recorder was armed as it opened), else None: no clock read."""
+    cur = getattr(_tls, "phase", None)
+    if (cur is None or cur.td is None or cur.td.args is not None
+            or cur.cpu0 is None):
+        return None
+    return cur
+
+
+def _child(ph: _Phase, name: str, since: Tuple[float, float, float],
+           args: Dict[str, Any]) -> None:
+    t0, cpu0, gc0 = since
+    args["cpu_s"] = round(time.thread_time() - cpu0, 6)
+    t1 = wallclock()
+    gc_s = _gc_sums().seconds - gc0
+    if gc_s > 0.0:
+        args["gc_s"] = round(gc_s, 6)
+    ph.td.kids.append((name, t0, t1, args))
+
+
+def teardown_mark() -> Optional[Tuple[float, float, float]]:
+    """(wallclock(), this thread's CPU seconds, its collector seconds)
+    while a stamped teardown is open on this thread, for a child that
+    starts now (teardown_child); None otherwise, with no clock read."""
+    if _stamped_teardown() is None:
+        return None
+    return wallclock(), time.thread_time(), _gc_sums().seconds
+
+
+def teardown_child(name: str, since: Tuple[float, float, float],
+                   **args) -> None:
+    """A finished child of the open teardown, from ``since`` (a
+    teardown_mark() of this thread) to now, with ``cpu_s`` and the
+    collector's pauses inside it (``gc_s``)."""
+    cur = _stamped_teardown()
+    if cur is not None:
+        _child(cur, name, since, args)
+
+
+def teardown_released(outcomes: int) -> None:
+    """The serving loop has dropped the cycle's return value: the
+    ``teardown-release`` child, from the phase's opening to now (disarmed,
+    or with no cycle behind it: nothing, and no clock read)."""
+    cur = _stamped_teardown()
+    if cur is not None:
+        _child(cur, RELEASE_SPAN, (cur.t0, cur.cpu0, cur.gc0_s),
+               {"outcomes": outcomes})
 
 
 # ------------------------------------------------------------------ bind job
@@ -954,12 +1078,11 @@ def begin_pop():
 # next cycle's phases, so its job is no phase: ONE finished span
 # ``bind-job`` on the record of the cycle whose binds it applies, written
 # by the thread that ran it when it is done (the record is in the ring by
-# then, as it is when the bind table's stamps land).  In a capture the
-# job opens one TraceAnnotation too, "Binding:bind-job" -- NOT under
-# CYCLE_TRACE's prefix, which the capture's readers take as the serving
-# thread's partition.
+# then, as it is when the bind table's stamps land).  It opens no
+# TraceAnnotation: the capture's readers take every CYCLE_TRACE name as the
+# serving thread's partition, and the span is on the profiler's clock
+# through CLOCK_ANNOTATION.
 JOB_SPAN = "bind-job"
-JOB_TRACE = "Binding"
 
 
 class JobSpan:
@@ -968,19 +1091,13 @@ class JobSpan:
     the thread was blocked on the interpreter, a lock or a wake-up) and
     the collector's pauses on that thread."""
 
-    __slots__ = ("rec", "parent_id", "ann", "t0", "cpu0", "gc0_s",
-                 "gc0_full", "args", "t_settle")
+    __slots__ = ("rec", "parent_id", "t0", "cpu0", "gc0_s", "gc0_full",
+                 "args", "t_settle")
 
     def __init__(self, rec: CycleRecord, parent: Optional[FlightSpan],
                  handed_t: float = 0.0):
         self.rec = rec
         self.parent_id = parent.span_id if parent is not None else 0
-        self.ann = None
-        if _PROFILE_ACTIVE:
-            import jax
-            self.ann = jax.profiler.TraceAnnotation(
-                f"{JOB_TRACE}:{JOB_SPAN}")
-            self.ann.__enter__()
         _tls.job = self
         g = _gc_sums()
         self.gc0_s, self.gc0_full = g.seconds, g.full
@@ -1010,9 +1127,6 @@ class JobSpan:
                                     parent_id=self.parent_id, **a)
         _record_gc_events(self.rec, span.span_id if span is not None else 0,
                           _take_gc_events(g))
-        if self.ann is not None:
-            self.ann.__exit__(None, None, None)
-            self.ann = None
 
 
 # --------------------------------------------------------------------- Trace
@@ -1063,6 +1177,15 @@ class Trace:
                 _record_gc_events(self.rec,
                                   sp.span_id if sp is not None else 0,
                                   pop.gc_events)
+                td = pop.td
+                if td is not None and td.args is not None and sp is not None:
+                    tsp = self.rec.record_span(
+                        TEARDOWN_SPAN, pop.t0, td.t1, parent_id=sp.span_id,
+                        **td.args)
+                    for name, t0, t1, args in td.kids:
+                        self.rec.record_span(
+                            name, t0, t1, parent_id=tsp.span_id
+                            if tsp is not None else sp.span_id, **args)
         self._last_mark = self.start
         if _PROFILE_ACTIVE:
             _emit_clock(self.rec.seq if self.rec is not None else 0)
@@ -1118,23 +1241,26 @@ class Trace:
         away)."""
         rec, fr = self.rec, self._fr
         self.rec = None
+        threads0 = None
         if rec is not None and fr is not None:
             if meta:
                 rec.meta.update(meta)
             CycleRecord.end_span(self._root)
             rec.t1 = wallclock()
-            fr.note_interpreter(rec)
+            threads0 = fr.note_interpreter(rec)
             fr.commit_cycle(rec)
         # the phase still open is this cycle's last (commit, when the
         # cycle ran to its end): closed here, it takes in the hand-over
         # of the record too, and the thread's next pop phase opens at
-        # once -- what follows (the cycle's teardown, the serving loop)
-        # is on the way to the next pop
+        # once, with the cycle's teardown (see "teardown" above) -- what
+        # follows is on the way to the next pop
         cur = getattr(_tls, "phase", None)
         if cur is not None and cur.name != "pop" \
                 and (cur.rec is rec or rec is None):
             cur.close()
-            begin_pop()
+            if _flight is not None or _PROFILE_ACTIVE:
+                ph = _open_phase("pop", TEARDOWN_SPAN, None, 0, {})
+                ph.td = _Teardown(threads0)
 
     def __del__(self):
         # a cycle that unwound on an exception still commits its

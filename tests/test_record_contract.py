@@ -123,6 +123,24 @@ CONTRACT = [
     ("arg", "bind-job.cpu_s", NUMBER, "lane_cpu_ms_per_cycle.sat"),
     ("arg", "bind-job.batched", int, "lane_batched_pct.sat"),
     ("event", "xla-compile.seconds", NUMBER, "window_compile_stall_ms.sat"),
+    # PR 51: the inside of ``pop``
+    ("span", "teardown", None, "teardown_report (its extent = teardown_s)"),
+    ("arg", "teardown.cpu_s", NUMBER,
+     "teardown_serving_cpu_ms_per_cycle.sat"),
+    ("arg", "teardown.thread_cpu_s", dict,
+     "teardown_lane_cpu_ms_per_cycle.sat / "
+     "teardown_other_threads_cpu_ms_per_cycle.sat"),
+    ("arg", "teardown.read_s", NUMBER,
+     "teardown_report (the second thread-clock reading's cost)"),
+    ("span", "teardown-release", None, "teardown_release_ms_per_cycle.sat"),
+    ("arg", "teardown-release.cpu_s", NUMBER, "teardown_report"),
+    ("arg", "teardown-release.outcomes", int, "teardown_report"),
+    ("span", "heap-boundary", None, "heap_boundary_ms_per_cycle.sat"),
+    ("arg", "heap-boundary.cpu_s", NUMBER, "teardown_report"),
+    ("arg", "heap-boundary.handoff", int, "teardown_report"),
+    ("arg", "heap-boundary.sweep", int, "teardown_report"),
+    ("arg", "pop.queue_s", NUMBER, "pop_queue_ms_per_cycle.sat"),
+    ("arg", "pop.group_s", NUMBER, "pop_group_ms_per_cycle.sat"),
 ]
 
 
@@ -254,10 +272,13 @@ def test_a_cycle_record_carries_what_the_benchmark_reads(
         return
     for v in got:
         assert isinstance(v, typ) and not isinstance(v, bool), (name, v)
-    if name == "thread_cpu_s":
+    if name.endswith("thread_cpu_s"):     # the meta's, and the teardown's
         for v in got:
-            assert v and all(isinstance(k, str) and isinstance(x, NUMBER)
-                             for k, x in v.items())
+            assert all(isinstance(k, str) and isinstance(x, NUMBER)
+                       for k, x in v.items())
+        # over a whole period somebody ran (a toy teardown can be too
+        # short for any thread to pass the 0.1 ms a name takes)
+        assert all(got) or name != "thread_cpu_s"
 
 
 def test_a_batch_of_one_node_affinity_term_counts_its_rows_and_one_selector(

@@ -20,7 +20,9 @@ PHASES = ("pop", "snapshot", "prefilter", "tensorize", "host-masks",
 ANNOTATIONS = {"Scheduling:pop", "Scheduling:snapshot",
                "Scheduling:prefilter", "Scheduling:tensorize",
                "Scheduling:host-masks", "Scheduling:dispatch",
-               "Scheduling:readback", "Scheduling:commit"}
+               "Scheduling:readback", "Scheduling:commit",
+               # the stretch of ``pop`` before begin_pop()'s pick-up (PR 51)
+               "Scheduling:teardown"}
 # what the benchmark of PR 25 reads, letter for letter
 LEGACY_STEP = "Tensorizing snapshot and pod batch done"
 
@@ -301,6 +303,9 @@ def test_annotations_carry_the_open_phase_and_never_nest(
         assert order == ["Scheduling:" + ("readback" if p ==
                                           "packed-readback" else p)
                          for p in PHASES]
+        # ...and the next cycle's pop opens with the teardown of this one
+        assert seen[8:11] == ["Scheduling:teardown", "Scheduling:pop",
+                              "Scheduling:snapshot"]
 
 
 def test_every_cycle_drops_a_clock_event_into_the_capture(annotations,
